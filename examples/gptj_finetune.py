@@ -57,11 +57,11 @@ def train_loop(config: dict) -> None:
     # Per-device peak matmul FLOP/s for the MFU estimate (same table as
     # bench.py); meaningless on the CPU smoke run, labeled accordingly.
     import jax
-    kind = getattr(jax.devices()[0], "device_kind", "cpu").lower()
+    kind = mesh.devices.flat[0].device_kind.lower()
     peaks = {"tpu v4": 275e12, "tpu v5 lite": 197e12, "tpu v5": 459e12,
              "tpu v6 lite": 918e12}
     peak = next((v for k, v in peaks.items() if k in kind), None)
-    n_devices = max(jax.device_count(), 1)
+    n_devices = mesh.size
 
     for i in range(config["steps"]):
         toks = rng.integers(0, cfg.vocab_size, (batch, seq + 1))
@@ -69,8 +69,9 @@ def train_loop(config: dict) -> None:
                 "targets": jnp.asarray(toks[:, 1:], jnp.int32)}
         t0 = time.perf_counter()
         state, metrics = step(state, data)
-        loss = float(metrics["loss"])  # full sync
+        jax.block_until_ready(metrics)
         dt = time.perf_counter() - t0
+        loss = float(metrics["loss"])
         tokens_per_s = batch * seq / dt
         report = {
             "step": i,
@@ -98,17 +99,19 @@ def main():
     parser.add_argument("--sp", type=int, default=1)
     parser.add_argument("--lr", type=float, default=1e-5)
     parser.add_argument("--cpu-mesh", action="store_true",
-                        help="pin JAX to the virtual CPU platform in-process"
-                             " (env vars can be overridden by site hooks)")
+                        help="run on JAX's virtual CPU devices and reserve "
+                             "no chips")
     args = parser.parse_args()
 
+    import ray_tpu
+    from ray_tpu._private.jax_compat import enable_compile_cache
+    from ray_tpu.air import ScalingConfig
+    from ray_tpu.train import JaxTrainer
+
+    enable_compile_cache()
     if args.cpu_mesh:
         import jax
         jax.config.update("jax_platforms", "cpu")
-
-    import ray_tpu
-    from ray_tpu.air import ScalingConfig
-    from ray_tpu.train import JaxTrainer
 
     ray_tpu.init()
     sizes = {"gpt-tiny": (4, 128), "gpt-410m": (16, 1024),
@@ -132,11 +135,14 @@ def main():
             "lr": args.lr,
             "seed": 0,
         },
+        # Each worker reserves the chips its mesh spans. Without them the
+        # trainer cannot be placed, and says so; only the CPU-mesh smoke
+        # run (fake-TPU strategy) asks for none.
         scaling_config=ScalingConfig(
             num_workers=args.num_workers,
-            # Reserve chips when the cluster has them; the CPU-mesh smoke
-            # run (fake-TPU strategy) schedules on CPU only.
-            use_tpu=ray_tpu.cluster_resources().get("TPU", 0) >= 1),
+            use_tpu=not args.cpu_mesh,
+            tpus_per_worker=(args.dp * args.fsdp * args.tp * args.sp
+                             // args.num_workers)),
     )
     result = trainer.fit()
     print("final metrics:", result.metrics)

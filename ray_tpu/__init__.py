@@ -16,7 +16,8 @@ from ray_tpu._private.object_ref import ObjectRef
 from ray_tpu._private.worker import (ClientContext, available_resources,
                                      broadcast, cluster_usage,
                                      cancel, cluster_resources, free, get,
-                                     get_actor, get_tpu_ids, init,
+                                     get_actor, get_tpu_devices,
+                                     get_tpu_ids, init,
                                      is_initialized, kill, nodes, put,
                                      shutdown, start_head_server, wait)
 from ray_tpu.actor import ActorClass, ActorHandle, method
@@ -47,6 +48,7 @@ __all__ = [
     "get_actor",
     "get_gpu_ids",
     "get_runtime_context",
+    "get_tpu_devices",
     "get_tpu_ids",
     "init",
     "is_initialized",

@@ -3453,17 +3453,23 @@ class NodeDaemon:
         return True
 
 
-def run_node(address: str, *, num_cpus: float = 1.0, num_tpus: float = 0.0,
+def run_node(address: str, *, num_cpus: float = 1.0,
+             num_tpus: Optional[float] = None,
              memory: float = 1 << 30,
              resources: Optional[Dict[str, float]] = None,
              labels: Optional[dict] = None,
              object_store_memory: int = 1 << 28,
              spill_dir: Optional[str] = None) -> None:
     """Entry point for `ray-tpu start --address host:port` and
-    `python -m ray_tpu._private.multinode`."""
+    `python -m ray_tpu._private.multinode`. ``num_tpus=None`` counts the
+    host's chips: this daemon is then the process that owns them (TPU
+    tasks run in its threads, never in its worker subprocesses)."""
     host, _, port = address.rpartition(":")
     node_resources: Dict[str, float] = {"CPU": float(num_cpus),
                                         "memory": float(memory)}
+    if num_tpus is None:
+        from ray_tpu._private.resource_spec import autodetect_num_tpus
+        num_tpus, _ = autodetect_num_tpus()
     if num_tpus:
         node_resources["TPU"] = float(num_tpus)
     if resources:
@@ -3500,7 +3506,12 @@ def _main() -> None:
     parser.add_argument("--address", required=True,
                         help="head host:port (ray_tpu.start_head_server)")
     parser.add_argument("--num-cpus", type=float, default=1.0)
-    parser.add_argument("--num-tpus", type=float, default=0.0)
+    parser.add_argument("--num-tpus", type=float, default=0.0,
+                        help="chips this daemon owns. 0 unless given: this "
+                             "module entry starts extra daemons beside a "
+                             "driver (tests, benches, cluster_utils), and "
+                             "those must not claim the chips the driver "
+                             "owns. `ray-tpu start` counts the host's chips")
     parser.add_argument("--memory", type=float, default=float(1 << 30))
     parser.add_argument("--resources", type=str, default=None,
                         help='extra resources as JSON, e.g. \'{"spot": 1}\'')

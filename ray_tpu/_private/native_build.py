@@ -48,19 +48,37 @@ def cleanup_artifacts(build_dir: str, prefix: str, keep: Optional[str],
         pass
 
 
+def _library_path(name: str) -> Optional[str]:
+    """build/lib<name>-<srchash>-<machine>.so for the current source, or
+    None when there is no such component."""
+    src = os.path.join(_SRC, f"{name}.cc")
+    if not os.path.exists(src):
+        return None
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(
+        _BUILD_DIR, f"lib{name}-{digest}-{platform.machine()}.so")
+
+
+def built_components() -> Dict[str, bool]:
+    """For each native component, whether its library for the current
+    source is in build/. False once the runtime has used the component
+    means g++ failed and it runs on its Python twin (components build on
+    first use, so False before that only means "not needed yet")."""
+    return {name: os.path.exists(_library_path(name) or "")
+            for name in STRESS_COMPONENTS}
+
+
 def build_library(name: str, extra_flags: Optional[List[str]] = None
                   ) -> Optional[str]:
     """Compile src/ray_tpu_native/<name>.cc into a shared library and return
     its path (cached by source hash + machine). None if unbuildable."""
-    src = os.path.join(_SRC, f"{name}.cc")
-    if not os.path.exists(src):
+    out = _library_path(name)
+    if out is None:
         return None
+    src = os.path.join(_SRC, f"{name}.cc")
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    with open(src, "rb") as f:
-        digest = hashlib.sha256(f.read()).hexdigest()[:12]
     prefix = f"lib{name}-"
-    out = os.path.join(
-        _BUILD_DIR, f"{prefix}{digest}-{platform.machine()}.so")
     with _lock_for(name):
         if os.path.exists(out):
             return out

@@ -2,10 +2,9 @@
 
 The reference autodetects GPUs and assigns CUDA_VISIBLE_DEVICES
 (python/ray/_private/resource_spec.py:175 _autodetect_num_gpus). Here the
-accelerator layer is TPU-native: chips come from ``jax.devices()``; the ICI
-topology (e.g. v4-8) is exposed as an ``accelerator_type:TPU-<gen>`` marker
-resource plus node metadata used by placement groups to map bundles onto mesh
-slices.
+accelerator layer is TPU-native: chips are counted from the host's PCI
+devices and device nodes (``autodetect_num_tpus``), exposed as ``TPU`` plus an
+``accelerator_type:TPU-<gen>`` marker resource.
 """
 
 from __future__ import annotations
@@ -35,31 +34,83 @@ class NodeResources:
         return resources
 
 
-def _autodetect_num_tpus() -> tuple[float, str]:
-    """Count local TPU chips without initializing a TPU runtime if possible.
+# How a TPU chip shows on its host: a PCI function with Google's vendor id
+# and a per-generation device id (the table libtpu's own loader goes by),
+# opened through /dev/vfio/<its IOMMU group> (v5e on) or /dev/accel<N>
+# (up to v4). A host may list more chips on the bus than it was granted
+# device nodes for, so the nodes are what is counted.
+_SYSFS_PCI_DEVICES = "/sys/bus/pci/devices"
+_DEV = "/dev"
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = {
+    "0x0027": "TPU v3", "0x005e": "TPU v4", "0x0062": "TPU v5p",
+    "0x0063": "TPU v5 lite", "0x006f": "TPU v6 lite", "0x0076": "TPU7x",
+}
 
-    Honors TPU_VISIBLE_CHIPS/TPU_CHIPS_PER_HOST overrides; otherwise asks JAX
-    (only if JAX has already been imported or detection is explicitly enabled,
-    to keep `init()` cheap on CPU-only hosts and to avoid grabbing the chips
-    from the scheduler process).
+
+def _read_sysfs(path: str) -> str:
+    try:
+        with open(path) as f:
+            return f.read().strip()
+    except OSError:
+        return ""
+
+
+def _jax_would_use_tpu() -> bool:
+    """False when this process's JAX is pinned away from the TPU (the
+    ``JAX_PLATFORMS`` variable, or ``jax_platforms`` set in code once jax
+    is imported): chips it cannot drive are not this node's resources."""
+    import sys
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        platforms = jax.config.jax_platforms or platforms
+    return not platforms or "tpu" in platforms.split(",")
+
+
+def autodetect_num_tpus() -> tuple[float, str]:
+    """Count the TPU chips this host may open, from sysfs and /dev.
+
+    The probe reads directory entries only: it neither imports JAX nor
+    opens a chip, so it is safe in any process. A chip serves one process
+    at a time, and that process is the one that runs TPU tasks in its own
+    threads: the driver in local mode, the node daemon under
+    ``ray-tpu start`` (worker subprocesses are pinned to the CPU). JAX
+    takes the chip there on first device use; counting must not take it
+    earlier or elsewhere.
+
+    ``RAY_TPU_NUM_CHIPS`` and ``TPU_VISIBLE_CHIPS`` fake or narrow the
+    count; neither is needed on a TPU host.
     """
     env = os.environ.get("RAY_TPU_NUM_CHIPS")
     if env is not None:
         return float(env), os.environ.get("RAY_TPU_PLATFORM", "tpu")
+    if not _jax_would_use_tpu():
+        return 0.0, ""
+    import glob
+    kind, vfio_chips = "", 0
+    for dev in glob.glob(os.path.join(_SYSFS_PCI_DEVICES, "*")):
+        if _read_sysfs(os.path.join(dev, "vendor")) != _GOOGLE_PCI_VENDOR:
+            continue
+        dev_kind = _TPU_PCI_DEVICES.get(
+            _read_sysfs(os.path.join(dev, "device")))
+        if dev_kind is None:
+            continue
+        kind = dev_kind
+        try:
+            group = os.path.basename(
+                os.readlink(os.path.join(dev, "iommu_group")))
+        except OSError:
+            continue
+        vfio_chips += os.path.exists(os.path.join(_DEV, "vfio", group))
+    chips = vfio_chips or len(
+        glob.glob(os.path.join(_DEV, "accel[0-9]*")))
+    if not kind or not chips:
+        return 0.0, ""
     visible = os.environ.get("TPU_VISIBLE_CHIPS")
     if visible:
-        return float(len([c for c in visible.split(",") if c.strip() != ""])), "tpu"
-    import sys
-    if "jax" in sys.modules:
-        try:
-            import jax
-            devices = [d for d in jax.devices() if d.platform == "tpu"]
-            if devices:
-                return float(len(devices)), getattr(
-                    devices[0], "device_kind", "tpu")
-        except Exception:  # noqa: BLE001 - no TPU runtime present
-            pass
-    return 0.0, ""
+        chips = len([c for c in visible.split(",") if c.strip()])
+    return float(chips), kind
 
 
 def detect_node_resources(
@@ -72,7 +123,7 @@ def detect_node_resources(
         num_cpus = float(os.cpu_count() or 1)
     platform = ""
     if num_tpus is None:
-        num_tpus, platform = _autodetect_num_tpus()
+        num_tpus, platform = autodetect_num_tpus()
     if memory is None:
         try:
             page = os.sysconf("SC_PAGE_SIZE")

@@ -2972,30 +2972,43 @@ class Runtime:
     # Cancellation
     # ------------------------------------------------------------------
 
+    def _cancel_queued_locked(self, task_id) -> bool:
+        """Drop a task that has not been launched from whichever queue
+        holds it, sealing TaskCancelledError; False if none does."""
+        for i, spec in enumerate(self._ready):
+            if spec.task_id == task_id:
+                self._ready.pop(i)
+                self._store_error(spec, TaskCancelledError(task_id))
+                return True
+        for dq in self._ready_by_class.values():
+            for spec in dq:
+                if spec.task_id == task_id:
+                    dq.remove(spec)
+                    self._store_error(spec, TaskCancelledError(task_id))
+                    return True
+        for waiters in self._pending_by_oid.values():
+            for pending in waiters:
+                if pending.spec.task_id == task_id:
+                    pending.cancelled = True
+                    self._store_error(pending.spec,
+                                      TaskCancelledError(task_id))
+                    if pending.spec.kind == TaskKind.ACTOR_TASK:
+                        self._abort_actor_task_seq(pending.spec)
+                    return True
+        return False
+
     def cancel(self, ref: ObjectRef, force: bool = False) -> None:
         oid = ref.object_id()
         task_id = oid.task_id()
+        # A finished task has nothing to cancel and a launched one sits in
+        # no queue: neither is worth a scan of every queue under the lock
+        # (which made dropping a 100k-task backlog quadratic).
+        if self.store.contains(oid):
+            return
         with self._lock:
-            for i, spec in enumerate(self._ready):
-                if spec.task_id == task_id:
-                    self._ready.pop(i)
-                    self._store_error(spec, TaskCancelledError(task_id))
-                    return
-            for dq in self._ready_by_class.values():
-                for spec in dq:
-                    if spec.task_id == task_id:
-                        dq.remove(spec)
-                        self._store_error(spec, TaskCancelledError(task_id))
-                        return
-            for waiters in self._pending_by_oid.values():
-                for pending in waiters:
-                    if pending.spec.task_id == task_id:
-                        pending.cancelled = True
-                        self._store_error(pending.spec,
-                                          TaskCancelledError(task_id))
-                        if pending.spec.kind == TaskKind.ACTOR_TASK:
-                            self._abort_actor_task_seq(pending.spec)
-                        return
+            if task_id not in self._inflight and \
+                    self._cancel_queued_locked(task_id):
+                return
         # Running tasks: a task on a worker PROCESS is force-killable for
         # real — SIGKILL the worker, the blocked executor thread raises
         # and seals TaskCancelledError (reference: worker process kill on

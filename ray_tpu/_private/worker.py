@@ -350,3 +350,15 @@ def get_tpu_ids() -> List[int]:
         if state is not None:
             ids = getattr(state.creation_spec, "_tpu_ids", None)
     return sorted(ids or [])
+
+
+def get_tpu_devices() -> list:
+    """The ``jax.Device``s behind ``get_tpu_ids()``: chip ``i`` is
+    ``jax.local_devices()[i]`` of the process that owns the node's chips
+    (the driver in local mode, the node daemon under ``ray-tpu start``).
+    Code that reserved no whole chip (the driver outside any task, a
+    CPU-mesh test) gets every local device."""
+    import jax
+    devices = jax.local_devices()
+    ids = get_tpu_ids()
+    return [devices[i] for i in ids] if ids else devices

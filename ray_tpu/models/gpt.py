@@ -300,13 +300,43 @@ def _dot_attention(q, k, v, cfg: GPTConfig):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _attention_specs(mesh, n_heads: int, n_kv_heads: int, seq_axis):
+    """shard_map specs for [B, S, H, D] activations: batch over (dp, fsdp),
+    sequence over ``seq_axis``, heads over tp. A head count tp does not
+    divide (GQA/MQA KV heads) stays replicated, and the per-shard op must
+    then bridge sharded-q / replicated-kv heads itself (ring_attention's
+    _repeat_kv does)."""
+    tp = dict(zip(mesh.axis_names, mesh.devices.shape)).get("tp", 1)
+    q_spec = PartitionSpec(("dp", "fsdp"), seq_axis,
+                           "tp" if n_heads % tp == 0 else None, None)
+    kv_spec = PartitionSpec(("dp", "fsdp"), seq_axis,
+                            "tp" if n_kv_heads % tp == 0 else None, None)
+    return q_spec, kv_spec
+
+
 def _attention(q, k, v, cfg: GPTConfig):
     if cfg.attn_impl == "dot":
         return _dot_attention(q, k, v, cfg)
     if cfg.attn_impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=True,
-                               blk_q=cfg.attn_blk_q, blk_k=cfg.attn_blk_k)
+        from ray_tpu.parallel.mesh import current_mesh
+        fn = partial(flash_attention, causal=True,
+                     blk_q=cfg.attn_blk_q, blk_k=cfg.attn_blk_k)
+        mesh = current_mesh()
+        if mesh is None or mesh.size == 1:
+            return fn(q, k, v)
+        # GSPMD cannot partition a Mosaic kernel, so under a mesh it runs
+        # per shard. Attention is independent per (batch row, head): each
+        # shard sees whole sequences, and heads split over tp only when
+        # the KV heads split with them (the kernel pairs q and kv heads
+        # by position within the shard).
+        from ray_tpu._private.jax_compat import shard_map
+        q_spec, kv_spec = _attention_specs(
+            mesh, q.shape[2], k.shape[2], seq_axis=None)
+        if kv_spec[2] is None:
+            q_spec = kv_spec
+        return shard_map(fn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)(q, k, v)
     if cfg.attn_impl == "ring":
         from ray_tpu.ops.ring_attention import make_ring_attention
         from ray_tpu.parallel.mesh import current_mesh
@@ -316,17 +346,8 @@ def _attention(q, k, v, cfg: GPTConfig):
                 "attn_impl='ring' needs a registered mesh with an 'sp' "
                 "axis (parallel.mesh.set_current_mesh; make_train_step/"
                 "make_eval_step do this automatically)")
-        # Activation layout [B, S, H, D]: batch over (dp, fsdp), sequence
-        # over the ring axis, heads over tp. Head axes whose size doesn't
-        # divide tp (GQA/MQA) stay replicated; ring_attention's local
-        # _repeat_kv bridges sharded-q / replicated-kv heads.
-        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        tp = sizes.get("tp", 1)
-        H, kvh = q.shape[2], k.shape[2]
-        q_spec = PartitionSpec(("dp", "fsdp"), "sp",
-                               "tp" if H % tp == 0 else None, None)
-        kv_spec = PartitionSpec(("dp", "fsdp"), "sp",
-                                "tp" if kvh % tp == 0 else None, None)
+        q_spec, kv_spec = _attention_specs(
+            mesh, q.shape[2], k.shape[2], seq_axis="sp")
         fn = make_ring_attention(mesh, "sp", causal=True, q_spec=q_spec,
                                  kv_spec=kv_spec)
         return fn(q, k, v)

@@ -13,7 +13,10 @@ what lets the GPT train step run "selective" rematerialisation instead of
 full-block recompute (models/gpt.py GPTConfig.remat_policy).
 
 On non-TPU backends the kernels run in interpreter mode so the same code
-path is testable on the CPU mesh (SURVEY.md §4: fake-TPU strategy).
+path is testable on the CPU mesh (SURVEY.md §4: fake-TPU strategy), and
+sequences the kernels cannot tile (not a multiple of 128) take the jnp
+blockwise path there. On a TPU such a sequence is an error: the chip never
+runs anything but the kernels under this name.
 """
 
 from __future__ import annotations
@@ -219,9 +222,15 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int):
     blk_q = _pick_block(S, blk_q)
     blk_k = _pick_block(S, blk_k)
     if blk_q < 128 or blk_k < 128:
-        # Ragged sequence (not a multiple of 128): fall back to the jnp
-        # blockwise path (no lse output — the custom VJP then differentiates
-        # the blockwise recurrence instead of running the Pallas backward).
+        if not _interpret():
+            raise ValueError(
+                f"flash_attention on TPU needs a sequence length that is a "
+                f"multiple of 128, got S={S}: pad the sequence or use "
+                "attn_impl='dot'")
+        # Short or ragged sequence on the CPU test backend, where there is
+        # no kernel to lose: the jnp blockwise path (no lse output — the
+        # custom VJP then differentiates the blockwise recurrence instead
+        # of running the Pallas backward).
         return blockwise_attention(q, k, v, causal=causal), None
     qf, kf, vf = _to_bh(q), _to_bh(k), _to_bh(v)
 

@@ -22,6 +22,7 @@ over sp; experts over ep; pipeline stages over pp.
 from __future__ import annotations
 
 import math
+import threading
 from dataclasses import dataclass, field
 from typing import Optional, Sequence, Tuple
 
@@ -94,26 +95,26 @@ def build_mesh(config: Optional[MeshConfig] = None,
                devices: Optional[Sequence] = None):
     """Build a `jax.sharding.Mesh` from a MeshConfig.
 
-    Uses `mesh_utils.create_device_mesh` when the requested shape matches the
-    platform topology (so tp/sp land on ICI neighbors); falls back to a plain
-    reshape for virtual/CPU device sets.
+    `mesh_utils.create_device_mesh` lays the axes over the platform
+    topology (tp/sp land on ICI neighbours; virtual CPU devices have none
+    and keep enumeration order). A shape the topology cannot hold raises:
+    a mesh silently laid out against the wiring would only show as slow
+    collectives.
     """
     import jax
+    from jax.experimental import mesh_utils
     from jax.sharding import Mesh
 
+    from ray_tpu._private.jax_compat import enable_compile_cache
+
+    enable_compile_cache()
     if devices is None:
         devices = jax.devices()
     config = (config or MeshConfig()).resolve(len(devices))
-    shape = config.shape()
     if config.slices > 1:
         return _build_multi_slice_mesh(config, list(devices))
-    try:
-        from jax.experimental import mesh_utils
-        dev_array = mesh_utils.create_device_mesh(
-            shape, devices=list(devices))
-    except Exception:  # noqa: BLE001 - virtual platforms may reject topology
-        dev_array = np.array(list(devices)).reshape(shape)
-    return Mesh(dev_array, AXIS_ORDER)
+    return Mesh(mesh_utils.create_device_mesh(
+        config.shape(), devices=list(devices)), AXIS_ORDER)
 
 
 def _build_multi_slice_mesh(config: MeshConfig, devices: list):
@@ -124,7 +125,7 @@ def _build_multi_slice_mesh(config: MeshConfig, devices: list):
     group by their hardware ``slice_index`` when the platform reports it
     (real multi-slice TPU), falling back to contiguous equal splits
     (virtual/CPU validation meshes)."""
-    import jax
+    from jax.experimental import mesh_utils
     from jax.sharding import Mesh
 
     n_slices = config.slices
@@ -151,14 +152,8 @@ def _build_multi_slice_mesh(config: MeshConfig, devices: list):
     dp_in = config.dp // n_slices
     inner_shape = (dp_in, config.fsdp, config.tp, config.sp,
                    config.ep, config.pp)
-    slabs = []
-    for group in groups:
-        try:
-            from jax.experimental import mesh_utils
-            slabs.append(mesh_utils.create_device_mesh(
-                inner_shape, devices=group))
-        except Exception:  # noqa: BLE001 - virtual platforms
-            slabs.append(np.array(group).reshape(inner_shape))
+    slabs = [mesh_utils.create_device_mesh(inner_shape, devices=group)
+             for group in groups]
     dev_array = np.stack(slabs, axis=0).reshape(config.shape())
     return Mesh(dev_array, AXIS_ORDER)
 
@@ -170,18 +165,19 @@ def single_device_mesh():
 
 
 # -- current-mesh registry ----------------------------------------------
-# Ops that need an explicit shard_map (ring attention) read the ambient
-# mesh here; make_train_step / user code set it. A registry rather than a
-# parameter because the mesh must be static at trace time while model code
-# only receives (params, cfg, batch).
+# Ops that need an explicit shard_map (the flash kernels, ring attention)
+# read the ambient mesh here; make_train_step / user code set it. A
+# registry rather than a parameter because the mesh must be static at
+# trace time while model code only receives (params, cfg, batch). Tracing
+# runs on the calling thread, so the registry is per thread: two actors
+# stepping over different chips in one process never see each other's mesh.
 
-_CURRENT_MESH = None
+_current = threading.local()
 
 
 def set_current_mesh(mesh) -> None:
-    global _CURRENT_MESH
-    _CURRENT_MESH = mesh
+    _current.mesh = mesh
 
 
 def current_mesh():
-    return _CURRENT_MESH
+    return getattr(_current, "mesh", None)

@@ -10,6 +10,7 @@ model never materializes unsharded on any single host.
 
 from __future__ import annotations
 
+import functools
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -18,7 +19,9 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import NamedSharding, PartitionSpec
 
+from ray_tpu._private.jax_compat import enable_compile_cache
 from ray_tpu.models import gpt
+from ray_tpu.parallel import mesh as mesh_mod
 from ray_tpu.parallel.sharding import ShardingRules, tree_shardings
 
 
@@ -54,54 +57,99 @@ def memory_efficient_optimizer(learning_rate=1e-4,
     )
 
 
+def _state_layout(cfg: gpt.GPTConfig, mesh, rules: ShardingRules,
+                  optimizer: optax.GradientTransformation):
+    """(shapes, shardings) of the train state {params, opt_state, step}.
+
+    Params shard by the rules. An optimizer sub-tree shaped like the params
+    (adam's moments) shards leaf for leaf like them; every other leaf
+    (adafactor's factored moments, counters) is replicated. One layout,
+    stated up front, for what init builds, what a step takes and what it
+    returns: a state that came back from a step with shardings the compiler
+    picked would make the next call a different program.
+    """
+    replicated = NamedSharding(mesh, PartitionSpec())
+    pshard = tree_shardings(mesh, gpt.param_specs(cfg, rules))
+    params = jax.eval_shape(partial(gpt.init, cfg), jax.random.PRNGKey(0))
+    opt_state = jax.eval_shape(optimizer.init, params)
+    like_params = jax.tree.structure(params)
+
+    def is_param_shaped(node):
+        return jax.tree.structure(node) == like_params
+
+    def shard(node):
+        if is_param_shaped(node):
+            return jax.tree.map(
+                lambda leaf, p, sharding:
+                    sharding if leaf.shape == p.shape else replicated,
+                node, params, pshard)
+        return replicated
+
+    shapes = {"params": params, "opt_state": opt_state,
+              "step": jax.ShapeDtypeStruct((), jnp.int32)}
+    shardings = {"params": pshard,
+                 "opt_state": jax.tree.map(shard, opt_state,
+                                           is_leaf=is_param_shaped),
+                 "step": replicated}
+    return shapes, shardings
+
+
 def init_train_state(cfg: gpt.GPTConfig, mesh,
                      rules: Optional[ShardingRules] = None,
                      optimizer: Optional[optax.GradientTransformation] = None,
                      seed: int = 0) -> Dict[str, Any]:
     """Build {params, opt_state, step}, created directly in sharded form."""
+    enable_compile_cache()
     rules = rules or ShardingRules()
     optimizer = optimizer or default_optimizer()
-    pspecs = gpt.param_specs(cfg, rules)
-    pshard = tree_shardings(mesh, pspecs)
+    _, shardings = _state_layout(cfg, mesh, rules, optimizer)
 
-    @partial(jax.jit, out_shardings=pshard)
-    def _init_params(key):
-        return gpt.init(cfg, key)
+    @partial(jax.jit, out_shardings=shardings)
+    def init(key):
+        params = gpt.init(cfg, key)
+        return {"params": params, "opt_state": optimizer.init(params),
+                "step": jnp.zeros((), jnp.int32)}
 
-    params = _init_params(jax.random.PRNGKey(seed))
-    # Optimizer state inherits param shardings through GSPMD propagation —
-    # except leaves with no data dependence on params (e.g. adam's step
-    # count), which XLA places on a single device; replicate those onto the
-    # mesh so the train step sees one consistent device set.
-    opt_state = jax.jit(optimizer.init)(params)
+    return init(jax.random.PRNGKey(seed))
 
-    def _ensure_on_mesh(x):
-        sharding = getattr(x, "sharding", None)
-        if sharding is not None and getattr(
-                sharding, "num_devices", 1) == mesh.size:
-            return x
-        return jax.device_put(x, NamedSharding(mesh, PartitionSpec()))
 
-    opt_state = jax.tree.map(_ensure_on_mesh, opt_state)
-    step = jax.device_put(jnp.zeros((), jnp.int32),
-                          NamedSharding(mesh, PartitionSpec()))
-    return {"params": params, "opt_state": opt_state, "step": step}
+def abstract_train_state(cfg: gpt.GPTConfig, mesh,
+                         rules: Optional[ShardingRules] = None,
+                         optimizer: Optional[
+                             optax.GradientTransformation] = None
+                         ) -> Dict[str, Any]:
+    """init_train_state's result as ShapeDtypeStructs carrying shardings:
+    what a step is lowered with when no device can hold the arrays (a
+    compile for a described TPU topology, a per-device memory proof)."""
+    shapes, shardings = _state_layout(
+        cfg, mesh, rules or ShardingRules(), optimizer or default_optimizer())
+    return jax.tree.map(
+        lambda shape, sharding: jax.ShapeDtypeStruct(
+            shape.shape, shape.dtype, sharding=sharding),
+        shapes, shardings)
 
 
 def _with_mesh_registered(jitted, mesh):
     """Register ``mesh`` as the current mesh around every call, not once at
     build time: jit traces lazily (first call / new shapes), so the registry
     must hold THIS step's mesh whenever a trace may happen — two steps built
-    over different meshes would otherwise trace against the wrong one."""
-    import functools
+    over different meshes would otherwise trace against the wrong one. The
+    previous mesh comes back afterwards, so model code called outside any
+    step never sees a stale one. ``.lower`` traces too and gets the same
+    treatment."""
+    def under_mesh(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            previous = mesh_mod.current_mesh()
+            mesh_mod.set_current_mesh(mesh)
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                mesh_mod.set_current_mesh(previous)
+        return call
 
-    from ray_tpu.parallel import mesh as mesh_mod
-
-    @functools.wraps(jitted)
-    def wrapped(*args, **kwargs):
-        mesh_mod.set_current_mesh(mesh)
-        return jitted(*args, **kwargs)
-
+    wrapped = under_mesh(jitted)
+    wrapped.lower = under_mesh(jitted.lower)
     return wrapped
 
 
@@ -116,6 +164,7 @@ def make_train_step(cfg: gpt.GPTConfig, mesh,
     dim must be divisible by it; microbatches run in a lax.scan (the
     microbatching substrate pipeline parallelism reuses).
     """
+    enable_compile_cache()
     rules = rules or ShardingRules()
     optimizer = optimizer or default_optimizer()
     bspec = gpt.batch_spec(rules)
@@ -158,11 +207,17 @@ def make_train_step(cfg: gpt.GPTConfig, mesh,
         return ({"params": params, "opt_state": opt_state,
                  "step": state["step"] + 1}, metrics)
 
-    return _with_mesh_registered(jax.jit(step, donate_argnums=(0,)), mesh)
+    # The state goes out as it came in (see _state_layout), so every call
+    # after the first finds the same program, and donation can alias.
+    _, shardings = _state_layout(cfg, mesh, rules, optimizer)
+    return _with_mesh_registered(
+        jax.jit(step, donate_argnums=(0,), out_shardings=(shardings, None)),
+        mesh)
 
 
 def make_eval_step(cfg: gpt.GPTConfig, mesh,
                    rules: Optional[ShardingRules] = None) -> Callable:
+    enable_compile_cache()
     rules = rules or ShardingRules()
     bspec = gpt.batch_spec(rules)
 

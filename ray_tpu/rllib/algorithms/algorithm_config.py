@@ -18,8 +18,8 @@ class AlgorithmConfig:
         # rollouts
         self.num_rollout_workers: int = 2
         # jax platform rollout workers pin THEIR process to ("cpu" —
-        # samplers never grab the learner's chip or a remote-TPU
-        # tunnel; None = leave the process default alone).
+        # samplers never grab the learner's chip; None = leave the
+        # process default alone).
         self.rollout_backend: Optional[str] = "cpu"
         self.num_envs_per_worker = 1
         self.rollout_fragment_length: int = 256

@@ -936,7 +936,9 @@ def main(argv=None) -> int:
                    help="head host:port to join as a node daemon")
     p.add_argument("--port", type=int, default=6380)
     p.add_argument("--num-cpus", type=float, default=1.0)
-    p.add_argument("--num-tpus", type=float, default=0.0)
+    p.add_argument("--num-tpus", type=float, default=None,
+                   help="TPU chips this node offers (default: the chips "
+                        "the host exposes to this process)")
     p.add_argument("--memory", type=float, default=float(1 << 30))
     p.add_argument("--resources", default=None,
                    help="extra resources as JSON")

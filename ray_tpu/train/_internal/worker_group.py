@@ -111,11 +111,17 @@ class TrainWorker:
         # the first cross-process collective deadlocks.
         import os
         env_snapshot = dict(os.environ)
+        # The loop is this actor's work on a side thread: it keeps the
+        # actor's task context, so get_tpu_ids()/get_tpu_devices() in the
+        # loop name the chips this worker reserved.
+        from ray_tpu._private import runtime as _runtime
+        task_spec = _runtime.current_task_spec()
 
         def _run():
             for k, v in env_snapshot.items():
                 if os.environ.get(k) != v:
                     os.environ[k] = v
+            _runtime._task_context.spec = task_spec
             air_session._set_session(sess)
             try:
                 try:

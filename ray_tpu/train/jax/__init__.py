@@ -94,11 +94,7 @@ def distributed_init_if_needed() -> None:
     if platform:
         jax.config.update("jax_platforms", platform)
         if platform == "cpu":
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception:  # noqa: BLE001 - older jax: no gloo knob
-                pass
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
     if os.environ.get("JAX_COORDINATOR_ADDRESS"):
         try:
             jax.distributed.initialize(
@@ -115,10 +111,19 @@ def prepare_mesh(mesh_config=None):
     The TPU-native analog of the reference's prepare_model
     (train_loop_utils.py:51): instead of wrapping a model in DDP/FSDP, the
     worker gets a mesh and expresses DP/FSDP/TP/SP as sharding rules.
+    The mesh spans the chips this worker reserved (``tpus_per_worker``),
+    not every device of the process: two workers sharing a chip-owning
+    process each get their own. A multi-process gang builds the global
+    mesh over every rank's devices.
     """
+    import jax
+
+    import ray_tpu
     from ray_tpu.parallel import MeshConfig, build_mesh
     distributed_init_if_needed()
-    return build_mesh(mesh_config or MeshConfig())
+    devices = (jax.devices() if jax.process_count() > 1
+               else ray_tpu.get_tpu_devices())
+    return build_mesh(mesh_config or MeshConfig(), devices=devices)
 
 
 class JaxTrainer(DataParallelTrainer):

@@ -2,12 +2,13 @@
 
 Two layers:
 
-* Wiring — the next bench round (BENCH_r06+) will actually be produced
-  with ``regression_baseline`` set against a USABLE prior round:
-  ``_prior_round_bench`` must skip records that carry no comparable
-  numbers (BENCH_r05's ``parsed`` is null — its values survive only in
-  a truncated log tail), and ``_regression_gate`` must stamp the
-  baseline name into the extras it is given.
+* Wiring — a bench round is produced with ``regression_baseline`` set
+  against a USABLE prior round: ``_prior_round_bench`` must skip records
+  that carry no comparable numbers (BENCH_r05's and r06's ``parsed`` is
+  null — their values survive only in a truncated log tail), and
+  ``_regression_gate`` must stamp the baseline name into the extras it is
+  given. Both are checked on records the test writes itself, so they hold
+  whichever rounds the repo still keeps.
 
 * Enforcement — the latest recorded ``BENCH_r*.json`` may not carry a
   non-empty ``regressions`` list unless every regressed metric is
@@ -100,15 +101,26 @@ def _waived_metrics(path, rec_round):
     return waived
 
 
-def test_prior_round_baseline_is_usable(bench):
-    """The next round's gate has a real baseline: extras with numbers
-    to compare, not a truncated record."""
+@pytest.fixture
+def recorded_rounds(bench, tmp_path, monkeypatch):
+    """bench.py looks for BENCH_r*.json beside itself: move "itself" to a
+    directory holding a usable round 7 under a truncated round 8."""
+    usable = {"metric": "m", "value": 100.0,
+              "extra": {"tasks_per_sec": 1000.0}}
+    (tmp_path / "BENCH_r07.json").write_text(
+        json.dumps({"n": 7, "rc": 0, "parsed": usable}))
+    (tmp_path / "BENCH_r08.json").write_text(
+        json.dumps({"n": 8, "rc": 0, "tail": "...", "parsed": None}))
+    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
+    return usable
+
+
+def test_prior_round_baseline_is_usable(bench, recorded_rounds):
+    """The gate baselines against the newest round with numbers to
+    compare, not a newer truncated record."""
     prev, name = bench._prior_round_bench()
-    if prev is None:
-        pytest.skip("no BENCH_r*.json recorded yet")
-    assert isinstance(name, str) and name.startswith("BENCH_r")
-    assert isinstance(prev.get("extra"), dict) or \
-        isinstance(prev.get("value"), (int, float))
+    assert name == "BENCH_r07.json"
+    assert prev == recorded_rounds
 
 
 def test_unusable_rounds_are_skipped_as_baseline(bench):
@@ -131,16 +143,33 @@ def test_unusable_rounds_are_skipped_as_baseline(bench):
             assert name != os.path.basename(path)
 
 
-def test_regression_gate_stamps_baseline(bench):
+def test_regression_gate_stamps_baseline(bench, recorded_rounds):
     """bench.py main() calls _regression_gate(extra, headline): the
-    produced record must carry regression_baseline whenever any prior
-    usable round exists — BENCH_r06 will be comparable by construction."""
-    prev, name = bench._prior_round_bench()
-    if prev is None:
-        pytest.skip("no BENCH_r*.json recorded yet")
-    extra = {}
-    bench._regression_gate(extra, headline_value=None)
-    assert extra.get("regression_baseline") == name
+    produced record carries regression_baseline whenever a prior usable
+    round exists, and the drops against it."""
+    extra = {"tasks_per_sec": 500.0}
+    bench._regression_gate(extra, headline_value=100.0)
+    assert extra["regression_baseline"] == "BENCH_r07.json"
+    assert [r["metric"] for r in extra["regressions"]] == ["tasks_per_sec"]
+
+
+def test_main_refuses_to_measure_without_a_tpu(bench):
+    """No CPU headline: the measurement path fails where JAX finds no
+    accelerator (tier-1 runs on the CPU)."""
+    with pytest.raises(SystemExit) as exit_info:
+        bench.main([])
+    assert "found none" in str(exit_info.value)
+
+
+def test_unknown_device_kind_is_an_error(bench):
+    """Peak FLOP/s and HBM size come from the table or not at all."""
+    import types
+    v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
+    assert bench._chip_spec(bench.PEAK_FLOPS, v5e) == 197e12
+    assert bench._chip_spec(bench.HBM_BYTES, v5e) == 16 << 30
+    for table in (bench.PEAK_FLOPS, bench.HBM_BYTES):
+        with pytest.raises(ValueError, match="no published figure"):
+            bench._chip_spec(table, types.SimpleNamespace(device_kind="cpu"))
 
 
 def test_check_regressions_flag_wired(bench):

@@ -336,6 +336,15 @@ def test_sigkill_daemon_declared_dead_fast():
                 dead.set()
 
         runtime = global_worker.runtime
+        # The hard path needs the daemon's health channel, which opens
+        # some 40 ms after its resources show: a kill inside that window
+        # is bounded by the lease instead (a different path, and this
+        # test's commonest flake).
+        deadline = time.monotonic() + 10
+        while not all(conn.health_sock is not None
+                      for conn in list(runtime._remote_nodes.values())):
+            assert time.monotonic() < deadline, "no health channel"
+            time.sleep(0.01)
         runtime.membership.subscribe(on_event)
         try:
             p.send_signal(signal.SIGKILL)

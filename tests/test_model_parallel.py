@@ -83,6 +83,42 @@ def test_train_step_loss_decreases():
     assert int(state["step"]) == 11
 
 
+@pytest.mark.parametrize("mesh_cfg,n_devices", [
+    (MeshConfig(dp=1, fsdp=1, tp=1), 1), (MeshConfig(dp=1, fsdp=2, tp=2), 4)])
+def test_train_step_compiles_once(mesh_cfg, n_devices):
+    """The state a step returns has the layout of the state it took, so
+    the second call is the first call's program. (It used to be a second
+    compile: init's state and the step's output disagreed on shardings,
+    11-14 s per cold start of gpt-1.3b on the chip.) Adafactor: its
+    factored moments were what the compiler laid out differently."""
+    from ray_tpu.parallel.train_step import memory_efficient_optimizer
+    cfg = gpt.config("gpt-tiny")
+    mesh = build_mesh(mesh_cfg, devices=jax.devices()[:n_devices])
+    opt = memory_efficient_optimizer(warmup_steps=1)
+    state = init_train_state(cfg, mesh, ShardingRules(), opt, seed=0)
+    step = make_train_step(cfg, mesh, ShardingRules(), opt)
+    rng = np.random.default_rng(0)
+    batch = {
+        "tokens": jnp.asarray(rng.integers(0, 256, (8, 32)), jnp.int32),
+        "targets": jnp.asarray(rng.integers(0, 256, (8, 32)), jnp.int32),
+    }
+    state, _ = step(state, batch)
+    compiles = []
+
+    def on_compile(event, duration_secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration_secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        for _ in range(2):
+            state, metrics = step(state, batch)
+        jax.block_until_ready(metrics)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert compiles == []
+
+
 def test_sharding_strategies_agree():
     """DP-only and TP+FSDP must compute the same loss (GSPMD correctness)."""
     cfg = gpt.config("gpt-tiny")
