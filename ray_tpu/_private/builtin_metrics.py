@@ -785,16 +785,6 @@ def train_ckpt_save_seconds() -> Histogram:
         boundaries=(0.01, 0.05, 0.25, 1.0, 5.0, 30.0, 120.0, 600.0))
 
 
-def train_ckpt_restore_seconds() -> Histogram:
-    from ray_tpu.util.metrics import Histogram
-    return Histogram(
-        "ray_tpu_train_ckpt_restore_seconds",
-        "Per-rank sharded checkpoint restore wall time (byte-range "
-        "reads + reassembly; includes reshard overlap math when the "
-        "mesh changed).",
-        boundaries=(0.01, 0.05, 0.25, 1.0, 5.0, 30.0, 120.0, 600.0))
-
-
 def train_reshards() -> Counter:
     from ray_tpu.util.metrics import Counter
     return Counter(

@@ -40,6 +40,12 @@ _STAGE_BY_PREFIX = (
     ("serve::replica_handler", "serve_handle"),
     ("task::", "execute"),
     ("actor_task::", "execute"),
+    # Train's loop: a save with its phases, a report, a batch.
+    ("train::report_sharded", "train_save"),
+    ("train::report", "train_report"),
+    ("ckpt::", "ckpt"),
+    ("data::next_batch", "train_ingest"),
+    ("data::to_device", "train_ingest"),
 )
 
 

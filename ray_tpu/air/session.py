@@ -14,6 +14,7 @@ import threading
 from typing import Any, Dict, Optional
 
 from ray_tpu.air.checkpoint import Checkpoint
+from ray_tpu.util import tracing
 
 
 class StopSession(BaseException):
@@ -58,12 +59,15 @@ class _Session:
                shard: Optional[dict] = None) -> None:
         if self.stop_requested:
             raise StopSession()
-        result = {"metrics": dict(metrics), "checkpoint": checkpoint}
-        if shard is not None:
-            result["shard"] = shard
-        self.result_queue.put(result)
-        self.continue_event.wait()
-        self.continue_event.clear()
+        with tracing.start_span("train::report"):
+            result = {"metrics": dict(metrics), "checkpoint": checkpoint}
+            if shard is not None:
+                result["shard"] = shard
+            self.result_queue.put(result)
+            # The driver's drain: it takes the result and lets us go on.
+            with tracing.child_span("train::report_wait"):
+                self.continue_event.wait()
+            self.continue_event.clear()
         if self.stop_requested:
             raise StopSession()
 
@@ -97,25 +101,33 @@ class _Session:
         self._shard_reports += 1
         if axes_items is None:
             axes_items = [("fsdp", self.world_size)]
-        flat, structure = sc.flatten_tree(state)
-        if specs is None:
-            specs = sc.default_specs(flat, axis=axes_items[0][0])
-        try:
-            local = sc.extract_local_shard(flat, specs, axes_items,
-                                           self.world_rank)
-            record = sc.write_shard(self._shard_backend, ctx["run"], seq,
-                                    self.world_rank, local)
-        except chaos.ChaosKill:
-            if self.on_chaos_kill is not None:
-                self.on_chaos_kill()
-            raise
-        except spill.SpillFailure as exc:
-            record = {"seq": seq, "rank": self.world_rank,
-                      "error": str(exc)}
-        if self.world_rank == 0 and "error" not in record:
-            record["tree_meta"] = sc.build_tree_meta(
-                flat, structure, specs, axes_items, extra)
-        self.report(metrics, shard=record)
+        # The phases below are child spans (ckpt::meta / gather / copy /
+        # checksum / write, then the ack as a nested train::report), so
+        # this span's self time is what no phase accounts for.
+        with tracing.start_span("train::report_sharded") as span:
+            if span is not None:
+                span.attributes.update(seq=seq, rank=self.world_rank)
+            with tracing.child_span("ckpt::meta"):
+                flat, structure = sc.flatten_tree(state)
+                if specs is None:
+                    specs = sc.default_specs(flat, axis=axes_items[0][0])
+            try:
+                local = sc.extract_local_shard(flat, specs, axes_items,
+                                               self.world_rank)
+                record = sc.write_shard(self._shard_backend, ctx["run"],
+                                        seq, self.world_rank, local)
+            except chaos.ChaosKill:
+                if self.on_chaos_kill is not None:
+                    self.on_chaos_kill()
+                raise
+            except spill.SpillFailure as exc:
+                record = {"seq": seq, "rank": self.world_rank,
+                          "error": str(exc)}
+            if self.world_rank == 0 and "error" not in record:
+                with tracing.child_span("ckpt::meta"):
+                    record["tree_meta"] = sc.build_tree_meta(
+                        flat, structure, specs, axes_items, extra)
+            self.report(metrics, shard=record)
 
 
 # One session per OS thread: train workers are actor threads, so

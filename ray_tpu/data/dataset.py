@@ -24,6 +24,7 @@ from ray_tpu.data._internal.plan import (AllToAllStage, ExecutionPlan,
 from ray_tpu.data._internal.shuffle import shuffle_blocks, sort_blocks
 from ray_tpu.data.block import (VALUE_COL, Block, BlockAccessor,
                                 BlockMetadata)
+from ray_tpu.util import tracing
 
 BatchUDF = Callable[[Any], Any]
 RowUDF = Callable[[Any], Any]
@@ -488,14 +489,25 @@ class Dataset:
         """Batches as jax Arrays (device_put onto ``device``); the analog of
         the reference's iter_torch_batches (dataset.py) for the JaxTrainer."""
         import jax
-        for batch in self.iter_batches(batch_size=batch_size,
-                                       batch_format="numpy",
-                                       drop_last=drop_last, **kwargs):
-            out = {}
-            for k, v in batch.items():
-                if dtypes and k in dtypes:
-                    v = v.astype(dtypes[k])
-                out[k] = jax.device_put(v, device)
+        batches = iter(self.iter_batches(batch_size=batch_size,
+                                         batch_format="numpy",
+                                         drop_last=drop_last, **kwargs))
+        while True:
+            # One span a batch, closed before the consumer runs: the host
+            # batch, then the transfer as its child.
+            with tracing.start_span("data::next_batch") as span:
+                batch = next(batches, None)
+                if batch is None:
+                    return
+                out = {}
+                with tracing.child_span("data::to_device"):
+                    for k, v in batch.items():
+                        if dtypes and k in dtypes:
+                            v = v.astype(dtypes[k])
+                        out[k] = jax.device_put(v, device)
+                if span is not None:
+                    span.attributes["bytes"] = sum(
+                        v.nbytes for v in out.values())
             yield out
 
     iter_torch_batches = iter_jax_batches  # capability alias

@@ -419,14 +419,16 @@ def _block(cfg: GPTConfig, x, layer, positions):
     dt = cfg.dtype
     h = _layernorm(x, layer["ln1_scale"], layer["ln1_bias"],
                    cfg.layernorm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
-    q = checkpoint_name(_rotary(q, positions, cfg.rotary_dim), "attn_q")
-    k = checkpoint_name(_rotary(k, positions, cfg.rotary_dim), "attn_k")
-    v = checkpoint_name(v, "attn_v")
-    attn = checkpoint_name(_attention(q, k, v, cfg), "attn_raw")
-    attn_out = jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(dt))
+    # Scope names are metadata: they name the operations in a profile.
+    with jax.named_scope("attention"):
+        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
+        q = checkpoint_name(_rotary(q, positions, cfg.rotary_dim), "attn_q")
+        k = checkpoint_name(_rotary(k, positions, cfg.rotary_dim), "attn_k")
+        v = checkpoint_name(v, "attn_v")
+        attn = checkpoint_name(_attention(q, k, v, cfg), "attn_raw")
+        attn_out = jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(dt))
 
     if cfg.parallel_block:
         mlp_in = h  # GPT-J: shared LN feeds both branches
@@ -435,15 +437,17 @@ def _block(cfg: GPTConfig, x, layer, positions):
         mlp_in = _layernorm(x, layer["ln2_scale"], layer["ln2_bias"],
                             cfg.layernorm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if cfg.is_moe:
-        mlp_out, aux = _moe_ffn(cfg, mlp_in, layer)
-    else:
-        ff = checkpoint_name(
-            jnp.einsum("bsd,df->bsf", mlp_in, layer["w_in"].astype(dt)),
-            "ffn_in")
-        ff = jax.nn.gelu(ff + layer["b_in"].astype(dt))
-        mlp_out = jnp.einsum("bsf,fd->bsd", ff, layer["w_out"].astype(dt))
-    mlp_out = mlp_out + layer["b_out"].astype(dt)
+    with jax.named_scope("moe" if cfg.is_moe else "mlp"):
+        if cfg.is_moe:
+            mlp_out, aux = _moe_ffn(cfg, mlp_in, layer)
+        else:
+            ff = checkpoint_name(
+                jnp.einsum("bsd,df->bsf", mlp_in, layer["w_in"].astype(dt)),
+                "ffn_in")
+            ff = jax.nn.gelu(ff + layer["b_in"].astype(dt))
+            mlp_out = jnp.einsum("bsf,fd->bsd", ff,
+                                 layer["w_out"].astype(dt))
+        mlp_out = mlp_out + layer["b_out"].astype(dt)
 
     if cfg.parallel_block:
         return x + attn_out + mlp_out, aux
@@ -474,7 +478,8 @@ def hidden_states(params: Dict[str, Any], cfg: GPTConfig,
 
     def scan_body(carry, layer):
         x, aux = carry
-        x, a = block(x, layer, positions)
+        with jax.named_scope("block"):
+            x, a = block(x, layer, positions)
         return (x, aux + a), None
 
     (x, aux), _ = jax.lax.scan(
@@ -538,31 +543,32 @@ def loss_fn(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
         mask32 = mask.astype(jnp.float32)
     denom = jnp.maximum(mask32.sum(), 1.0)
 
-    T = B * S
-    chunk = cfg.loss_chunk
-    if chunk and T % chunk and T > chunk:
-        # Requested chunk doesn't divide the token count: use the largest
-        # divisor <= chunk rather than silently materializing full logits
-        # (defeating the feature's memory bound).
-        chunk = max(c for c in range(1, chunk + 1) if T % c == 0)
-    if chunk and T > chunk:
-        d = x.shape[-1]
-        xf = x.reshape(T // chunk, chunk, d)
-        tf = targets.reshape(T // chunk, chunk)
-        mf = mask32.reshape(T // chunk, chunk)
+    with jax.named_scope("head_loss"):
+        T = B * S
+        chunk = cfg.loss_chunk
+        if chunk and T % chunk and T > chunk:
+            # Requested chunk doesn't divide the token count: use the largest
+            # divisor <= chunk rather than silently materializing full logits
+            # (defeating the feature's memory bound).
+            chunk = max(c for c in range(1, chunk + 1) if T % c == 0)
+        if chunk and T > chunk:
+            d = x.shape[-1]
+            xf = x.reshape(T // chunk, chunk, d)
+            tf = targets.reshape(T // chunk, chunk)
+            mf = mask32.reshape(T // chunk, chunk)
 
-        @jax.checkpoint
-        def chunk_stats(carry, xtm):
-            x_c, t_c, m_c = xtm
+            @jax.checkpoint
+            def chunk_stats(carry, xtm):
+                x_c, t_c, m_c = xtm
+                nll_sum, hit_sum = _ce_stats(
+                    _head(params, cfg, x_c), t_c, m_c, z_loss)
+                return (carry[0] + nll_sum, carry[1] + hit_sum), None
+
+            (nll_sum, hit_sum), _ = jax.lax.scan(
+                chunk_stats, (jnp.zeros((), jnp.float32),) * 2, (xf, tf, mf))
+        else:
             nll_sum, hit_sum = _ce_stats(
-                _head(params, cfg, x_c), t_c, m_c, z_loss)
-            return (carry[0] + nll_sum, carry[1] + hit_sum), None
-
-        (nll_sum, hit_sum), _ = jax.lax.scan(
-            chunk_stats, (jnp.zeros((), jnp.float32),) * 2, (xf, tf, mf))
-    else:
-        nll_sum, hit_sum = _ce_stats(
-            _head(params, cfg, x), targets, mask32, z_loss)
+                _head(params, cfg, x), targets, mask32, z_loss)
 
     ce = nll_sum / denom
     loss = ce

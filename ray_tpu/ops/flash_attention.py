@@ -254,6 +254,7 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int):
             jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(qf, kf, vf)
     return _from_bh(out, B, H), lse
 
@@ -285,6 +286,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
         out_specs=pl.BlockSpec((None, blk_q, D), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(qf, kf, vf, gf, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -307,6 +309,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
             jax.ShapeDtypeStruct((B * H, S, D), v.dtype),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(qf, kf, vf, gf, lse, delta)
 
     dq = _from_bh(dq, B, H)

@@ -201,9 +201,10 @@ def make_train_step(cfg: gpt.GPTConfig, mesh,
                 micro_body, (zeros_g, zeros_m), micros)
             grads = jax.tree.map(lambda g: g / accum_steps, grads)
             metrics = jax.tree.map(lambda m: m / accum_steps, metrics)
-        updates, opt_state = optimizer.update(
-            grads, state["opt_state"], params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state["opt_state"], params)
+            params = optax.apply_updates(params, updates)
         return ({"params": params, "opt_state": opt_state,
                  "step": state["step"] + 1}, metrics)
 

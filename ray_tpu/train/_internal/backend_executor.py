@@ -47,6 +47,7 @@ from ray_tpu.air.result import Result
 from ray_tpu.exceptions import RayError, is_system_failure
 from ray_tpu.train._internal.worker_group import WorkerGroup
 from ray_tpu.train.backend import BackendConfig
+from ray_tpu.util import tracing
 
 logger = logging.getLogger("ray_tpu.train")
 
@@ -405,8 +406,14 @@ class BackendExecutor:
         seq = seqs.pop()
         meta = next(r["tree_meta"] for r in records if "tree_meta" in r)
         t0 = time.perf_counter()
-        handle = self.checkpoint_manager.register_sharded(
-            seq, meta, records, metrics=metrics)
+        # On the driver's thread, beside the loop: the ranks were let go
+        # when their acks were taken.
+        with tracing.start_span("ckpt::commit") as span:
+            if span is not None:
+                span.attributes.update(seq=seq, bytes=sum(
+                    int(r["bytes"]) for r in records))
+            handle = self.checkpoint_manager.register_sharded(
+                seq, meta, records, metrics=metrics)
         if handle is not None:
             # Wall time of the save: the slowest rank's shard write
             # plus the manifest commit.

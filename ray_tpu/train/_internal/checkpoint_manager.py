@@ -40,6 +40,7 @@ from typing import Any, Dict, List, Optional
 from ray_tpu._private import spill
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.air.config import CheckpointConfig
+from ray_tpu.util import tracing
 
 logger = logging.getLogger("ray_tpu.train")
 
@@ -338,9 +339,17 @@ class CheckpointManager:
             kept = sorted(self._tracked,
                           key=lambda e: e["seq"])[-keep:]
         kept_uris = {e["uri"] for e in kept}
-        for entry in self._tracked:
-            if entry["uri"] not in kept_uris:
+        # A span only inside a sharded save's ckpt::commit.
+        with tracing.child_span("ckpt::prune") as span:
+            dropped = [e for e in self._tracked
+                       if e["uri"] not in kept_uris]
+            for entry in dropped:
                 self._delete_entry(entry)
+            if span is not None:
+                span.attributes.update(
+                    seqs=[e["seq"] for e in dropped],
+                    files=sum(1 + len(e.get("files", []))
+                              for e in dropped))
         self._tracked = sorted(kept, key=lambda e: e["seq"])
 
     # -- resume ------------------------------------------------------------

@@ -46,6 +46,7 @@ from ray_tpu._private.ray_config import runtime_config_value
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.parallel.sharding import (axis_split_bounds,  # noqa: F401
                                        shard_slices, slices_overlap)
+from ray_tpu.util import tracing
 
 logger = logging.getLogger("ray_tpu.train")
 
@@ -162,8 +163,9 @@ def default_specs(flat: Dict[str, Any], axis: str = "fsdp"
     (the ZeRO-3 analog); scalars stay replicated."""
     specs = {}
     for path, leaf in flat.items():
-        ndim = np.asarray(leaf).ndim
-        specs[path] = [[axis] if d == 0 else [] for d in range(ndim)]
+        # np.ndim reads a device array's own ``ndim``: no gather here.
+        specs[path] = [[axis] if d == 0 else []
+                       for d in range(np.ndim(leaf))]
     return specs
 
 
@@ -193,11 +195,19 @@ def extract_local_shard(flat: Dict[str, Any],
     coords = rank_coords(rank, axes_items)
     out = {}
     for path, leaf in flat.items():
-        a = np.asarray(leaf)
-        spec = normalize_spec(specs.get(path), a.ndim)
-        block = a[shard_slices(a.shape, spec, axes, coords)]
-        # ascontiguousarray promotes 0-d to (1,); keep scalar shapes.
-        out[path] = np.ascontiguousarray(block).reshape(np.shape(block))
+        # Device to host, leaf by leaf (spans: under report_sharded only).
+        with tracing.child_span("ckpt::gather") as span:
+            a = np.asarray(leaf)
+            if span is not None:
+                span.attributes.update(leaf=path, bytes=a.nbytes)
+        with tracing.child_span("ckpt::copy") as span:
+            spec = normalize_spec(specs.get(path), a.ndim)
+            block = a[shard_slices(a.shape, spec, axes, coords)]
+            # ascontiguousarray promotes 0-d to (1,); keep scalar shapes.
+            out[path] = np.ascontiguousarray(block).reshape(np.shape(block))
+            if span is not None:
+                span.attributes.update(leaf=path, bytes=out[path].nbytes,
+                                       what="slice")
     return out
 
 
@@ -224,17 +234,25 @@ def write_shard(backend: spill.SpillBackend, run: str, seq: int, rank: int,
     offset = 0
     file_crc = 0
     for path in sorted(local_flat):
-        a = np.ascontiguousarray(np.asarray(local_flat[path]))
-        raw = a.tobytes()
+        with tracing.child_span("ckpt::copy") as span:
+            a = np.ascontiguousarray(np.asarray(local_flat[path]))
+            raw = a.tobytes()
+            if span is not None:
+                span.attributes.update(leaf=path, bytes=len(raw),
+                                       what="tobytes")
+        with tracing.child_span("ckpt::checksum") as span:
+            block_crc = zlib.crc32(raw) & 0xFFFFFFFF
+            file_crc = zlib.crc32(raw, file_crc)
+            if span is not None:
+                span.attributes.update(leaf=path, bytes=2 * len(raw))
         blocks[path] = {
             "offset": offset,
             "length": len(raw),
-            "crc32": zlib.crc32(raw) & 0xFFFFFFFF,
+            "crc32": block_crc,
             "shape": [int(s) for s in a.shape],
             "dtype": str(a.dtype),
         }
         parts.append(raw)
-        file_crc = zlib.crc32(raw, file_crc)
         offset += len(raw)
     filename = shard_filename(run, seq, rank)
     t0 = time.perf_counter()
@@ -247,7 +265,11 @@ def write_shard(backend: spill.SpillBackend, run: str, seq: int, rank: int,
     except OSError as exc:
         raise spill.SpillFailure(
             f"shard write of {filename} failed: {exc}") from exc
-    uri = backend.write(filename, parts)
+    with tracing.child_span("ckpt::write") as span:
+        uri = backend.write(filename, parts)
+        if span is not None:
+            span.attributes.update(bytes=offset, seq=int(seq),
+                                   rank=int(rank))
     elapsed = time.perf_counter() - t0
     try:
         from ray_tpu._private import builtin_metrics
@@ -457,6 +479,17 @@ class ShardedCheckpoint(Checkpoint):
 
     def _load_local(self, new_axes: List[Tuple[str, int]], rank: int,
                     verify: Optional[bool]) -> Any:
+        with tracing.start_span("ckpt::restore") as span:
+            flat = self._read_blocks(new_axes, rank, verify)
+            if span is not None:
+                span.attributes.update(
+                    seq=self.seq, rank=rank,
+                    bytes=sum(a.nbytes for a in flat.values()))
+            return unflatten_tree(self.manifest["structure"], flat)
+
+    def _read_blocks(self, new_axes: List[Tuple[str, int]], rank: int,
+                     verify: Optional[bool]) -> Dict[str, np.ndarray]:
+        """``{path: array}`` of this rank's blocks under ``new_axes``."""
         verify = verify_checksums_default() if verify is None else verify
         backend = spill.reader_for_uri(self._uri)
         if backend is None:
@@ -467,7 +500,6 @@ class ShardedCheckpoint(Checkpoint):
         coords = rank_coords(rank, new_axes)
         old_coord_cache = {s["rank"]: rank_coords(s["rank"], old_axes)
                            for s in manifest["shards"]}
-        t0 = time.perf_counter()
 
         def load_param(path: str) -> np.ndarray:
             meta = manifest["params"][path]
@@ -536,13 +568,7 @@ class ShardedCheckpoint(Checkpoint):
         else:
             for path in paths:
                 flat[path] = load_param(path)
-        try:
-            from ray_tpu._private import builtin_metrics
-            builtin_metrics.train_ckpt_restore_seconds().observe(
-                time.perf_counter() - t0)
-        except Exception:  # noqa: BLE001
-            pass
-        return unflatten_tree(manifest["structure"], flat)
+        return flat
 
     def __repr__(self):
         return (f"ShardedCheckpoint(id={self.id}, run="

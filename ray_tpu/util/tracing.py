@@ -20,13 +20,21 @@ Timing: ``start_time`` is a wall-clock ANCHOR (for cross-process
 alignment on one timeline); ``duration`` is measured monotonically so an
 NTP step mid-span cannot corrupt it. ``end_time`` is derived
 (anchor + duration), never a second wall-clock read.
+
+Device profiles: in a process that has imported JAX, spans also record
+while ``jax.profiler`` is recording a trace (between ``start_trace`` and
+``stop_trace``), with no call to ``enable_tracing()``, and each recorded
+span is then also a ``jax.profiler.TraceAnnotation`` of the same name: an
+event on its thread's line of ``/host:CPU`` in the ``.xplane.pb``, on the
+device trace's clock. This module never imports JAX itself
+(``ray_tpu.init()`` runs without it).
 """
 
 from __future__ import annotations
 
-import contextlib
 import os
 import random
+import sys
 import threading
 import time
 import uuid
@@ -56,12 +64,16 @@ class Span:
     # Set once the span has been drained into a metrics_batch frame, so a
     # long-open span ahead of it in the buffer cannot cause re-shipping.
     shipped: bool = field(default=False, repr=False, compare=False)
-    # Monotonic start, never serialized (meaningless across processes).
-    _mono: float = field(default=0.0, repr=False, compare=False)
+    # In-process only, never serialized (meaningless across processes):
+    # the start on ``time.perf_counter()``, which is monotonic and is the
+    # clock an in-process reader times its own windows on (0.0 for a span
+    # recorded after the fact), and the name of the recording thread.
+    perf_start: float = field(default=0.0, repr=False, compare=False)
+    thread: str = field(default="", repr=False, compare=False)
 
     def end(self) -> None:
         if self.end_time is None:
-            self.duration = time.monotonic() - self._mono
+            self.duration = time.perf_counter() - self.perf_start
             self.end_time = self.start_time + self.duration
 
     def to_dict(self) -> Dict[str, Any]:
@@ -163,43 +175,115 @@ def _new_span(name: str, trace_id: str, parent_id: Optional[str],
         parent_id=parent_id,
         start_time=time.time(),
         attributes=dict(attributes or {}),
-        _mono=time.monotonic(),
+        perf_start=time.perf_counter(),
+        thread=threading.current_thread().name,
     )
 
 
-@contextlib.contextmanager
+#: ``jax.profiler.TraceAnnotation`` once this process has imported JAX.
+_annotation: Any = None
+
+
+def _profiling() -> bool:
+    """Is ``jax.profiler`` recording a trace right now (true exactly between
+    ``start_trace`` and ``stop_trace``)? JAX is found in ``sys.modules``,
+    never imported from here."""
+    global _annotation
+    if _annotation is None:
+        try:
+            _annotation = sys.modules["jax"].profiler.TraceAnnotation
+        except (KeyError, AttributeError):  # not imported, or half-way
+            return False
+    return _annotation.is_enabled()
+
+
+class _NoSpan:
+    """What a span site gets when nothing records: one shared object, so
+    the off path allocates nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _UnsampledScope:
+    """A root that drew not-sampled: marks the thread for its lifetime."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        _state.span = _UNSAMPLED
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        _state.span = None
+        return False
+
+
+_UNSAMPLED_SCOPE = _UnsampledScope()
+
+
+class _SpanScope:
+    """One recorded span as the thread's active context for a ``with``
+    block. While a device profile is recorded the span is also a
+    ``TraceAnnotation`` of the same name, so it lies on the profile's
+    clock, on this thread's line of the host plane."""
+
+    __slots__ = ("_args", "_span", "_prev", "_annotated")
+
+    def __init__(self, name: str, trace_id: str, parent_id: Optional[str],
+                 attributes: Optional[Dict[str, Any]]):
+        self._args = (name, trace_id, parent_id, attributes)
+
+    def __enter__(self) -> Span:
+        self._span = span = _new_span(*self._args)
+        _record(span)
+        self._prev = getattr(_state, "span", None)
+        _state.span = span
+        self._annotated = None
+        if _profiling():
+            self._annotated = _annotation(span.name)
+            self._annotated.__enter__()
+        return span
+
+    def __exit__(self, *exc) -> bool:
+        self._span.end()
+        if self._annotated is not None:
+            self._annotated.__exit__(*exc)
+        _state.span = self._prev
+        return False
+
+
 def start_span(name: str, attributes: Optional[Dict[str, Any]] = None):
     """Open a span as the thread's active context; nested spans (and remote
     tasks submitted inside) are parented to it. A ROOT span (no active
     parent) makes the head-of-trace sampling decision; the verdict sticks
-    for everything nested under it."""
-    if not _enabled:
-        yield None
-        return
+    for everything nested under it.
+
+    Records after ``enable_tracing()`` or while ``jax.profiler`` records a
+    device profile (every root is sampled then: the profile is the
+    operator's request). Otherwise the cost is two flag reads, and the
+    caller gets a shared no-op: set attributes on the yielded span, if it
+    is not None, not through a dict built at the call site."""
+    if not _enabled and not _profiling():
+        return _NO_SPAN
     prev = getattr(_state, "span", None)
     if prev is _UNSAMPLED:
-        yield None
-        return
-    if prev is None and not _draw_sampled():
-        _state.span = _UNSAMPLED
-        try:
-            yield None
-        finally:
-            _state.span = None
-        return
-    span = _new_span(
+        return _NO_SPAN
+    if prev is None and not _draw_sampled() and not _profiling():
+        return _UNSAMPLED_SCOPE
+    return _SpanScope(
         name,
         trace_id=prev.trace_id if prev else uuid.uuid4().hex[:16],
         parent_id=prev.span_id if prev else None,
-        attributes=attributes,
-    )
-    _record(span)
-    _state.span = span
-    try:
-        yield span
-    finally:
-        span.end()
-        _state.span = prev
+        attributes=attributes)
 
 
 def inject_context() -> Optional[Dict[str, Any]]:
@@ -238,7 +322,6 @@ def _ctx_sampled(ctx: Optional[Dict[str, Any]]) -> bool:
     return bool(ctx) and bool(ctx.get("sampled", True))
 
 
-@contextlib.contextmanager
 def continue_context(ctx: Optional[Dict[str, Any]], name: str,
                      attributes: Optional[Dict[str, Any]] = None):
     """Worker-side: run a task under the caller's trace context.
@@ -248,19 +331,9 @@ def continue_context(ctx: Optional[Dict[str, Any]], name: str,
     decision, and daemons/workers (where enable_tracing was never
     called) record purely because the request asked them to."""
     if not _ctx_sampled(ctx):
-        yield None
-        return
-    span = _new_span(name, trace_id=ctx["trace_id"],
-                     parent_id=ctx.get("parent_id"),
-                     attributes=attributes)
-    _record(span)
-    prev = getattr(_state, "span", None)
-    _state.span = span
-    try:
-        yield span
-    finally:
-        span.end()
-        _state.span = prev
+        return _NO_SPAN
+    return _SpanScope(name, trace_id=ctx["trace_id"],
+                      parent_id=ctx.get("parent_id"), attributes=attributes)
 
 
 def record_complete_span(name: str, ctx: Optional[Dict[str, Any]], *,
@@ -284,12 +357,12 @@ def record_complete_span(name: str, ctx: Optional[Dict[str, Any]], *,
         end_time=wall_start + duration,
         duration=duration,
         attributes=dict(attributes or {}),
+        thread=threading.current_thread().name,
     )
     _record(span)
     return span
 
 
-@contextlib.contextmanager
 def child_span(name: str, attributes: Optional[Dict[str, Any]] = None):
     """A span recorded ONLY under an active sampled parent (data-plane
     helpers like object pulls: traced when a traced task triggers them,
@@ -298,18 +371,9 @@ def child_span(name: str, attributes: Optional[Dict[str, Any]] = None):
     remote span record too."""
     parent = current_span()
     if parent is None:
-        yield None
-        return
-    span = _new_span(name, trace_id=parent.trace_id,
-                     parent_id=parent.span_id, attributes=attributes)
-    _record(span)
-    prev = parent
-    _state.span = span
-    try:
-        yield span
-    finally:
-        span.end()
-        _state.span = prev
+        return _NO_SPAN
+    return _SpanScope(name, trace_id=parent.trace_id,
+                      parent_id=parent.span_id, attributes=attributes)
 
 
 def drain_finished_spans(cursor: int = 0) -> tuple:
