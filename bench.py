@@ -1465,7 +1465,7 @@ def bench_sharded_checkpoint() -> dict:
         with ThreadPoolExecutor(max_workers=world) as pool:
             records = list(pool.map(
                 lambda r: sc.write_shard(mgr._backend, "bench-shard",
-                                         seq, r, shards[r]),
+                                         seq, r, shards[r], {}, ()),
                 range(world)))
         meta = sc.build_tree_meta(flat, structure, specs, axes,
                                   extra={"step": 1})

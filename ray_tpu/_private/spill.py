@@ -137,6 +137,13 @@ class SpillBackend:
                 from exc
         return self.uri_for(filename)
 
+    def open_writer(self, filename: str) -> "SpillWriter":
+        """:meth:`write` for a payload that arrives in parts: the parts
+        go to ``<path>.tmp`` as the caller hands them over, and
+        :meth:`SpillWriter.commit` fsyncs and renames."""
+        self._ensure_root()
+        return SpillWriter(self, filename)
+
     # -- read -------------------------------------------------------------
 
     def read(self, uri: str, expected_size: int = 0) -> Optional[bytes]:
@@ -247,6 +254,73 @@ class SpillBackend:
             os.rmdir(self._root)
         except OSError:
             pass
+
+
+class SpillWriter:
+    """A crash-safe write in parts (``SpillBackend.open_writer``), under
+    :meth:`SpillBackend.write`'s contract: nothing is visible under the
+    final name before :meth:`commit`; any ``OSError``, real or injected
+    at ``spill.write_error`` (evaluated at the open and at every part, so
+    ``after=k`` fails a write mid-stream), unlinks the ``.tmp``, counts
+    one write failure and surfaces as :class:`SpillFailure`. Leaving the
+    ``with`` block without a commit aborts."""
+
+    def __init__(self, backend: SpillBackend, filename: str):
+        self._filename = filename
+        self._uri = backend.uri_for(filename)
+        self._path = os.path.join(backend.root, os.path.basename(filename))
+        self._tmp = self._path + ".tmp"
+        self._file = None
+        self._guarded(self._open)
+
+    def _guarded(self, op, *args) -> None:
+        try:
+            if chaos.ACTIVE:
+                chaos.maybe_inject("spill.write_error")
+            op(*args)
+        except OSError as exc:
+            self.abort()
+            _count_failure("write")
+            raise SpillFailure(
+                f"spill write of {self._filename} failed: {exc}") from exc
+
+    def _open(self) -> None:
+        self._file = open(self._tmp, "wb")
+
+    def _commit(self) -> None:
+        self._file.flush()
+        os.fsync(self._file.fileno())
+        self._file.close()
+        os.replace(self._tmp, self._path)
+        self._file = None
+
+    def write(self, part) -> None:
+        """Append one buffer (anything ``file.write`` takes)."""
+        self._guarded(self._file.write, part)
+
+    def commit(self) -> str:
+        """fsync, rename to the final name; returns the spill URI."""
+        self._guarded(self._commit)
+        return self._uri
+
+    def abort(self) -> None:
+        if self._file is not None:
+            try:
+                self._file.close()
+            except OSError:
+                pass
+            self._file = None
+        try:
+            os.unlink(self._tmp)
+        except OSError:
+            pass
+
+    def __enter__(self) -> "SpillWriter":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._file is not None:
+            self.abort()
 
 
 class SpillLanding:

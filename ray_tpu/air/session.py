@@ -77,13 +77,14 @@ class _Session:
                        extra: Optional[Dict[str, Any]] = None) -> None:
         """Report metrics plus THIS RANK's checkpoint shard.
 
-        Phase one of the two-phase sharded save: the rank extracts its
-        local parameter blocks from ``state`` (per ``specs``; default:
+        Phase one of the two-phase sharded save: the rank streams its
+        local parameter blocks of ``state`` (per ``specs``; default:
         dim 0 of every array over an ``fsdp`` axis of ``world_size``)
-        and writes one ``.shard-<rank>`` file through the run's spill
-        backend. The shard record rides the ordinary result payload to
-        the driver as the write's ack; the driver commits the manifest
-        only once every rank acked. A failed write reports
+        from the device into one ``.shard-<rank>`` file through the run's
+        spill backend, fsynced and renamed before this returns. The shard
+        record rides the ordinary result payload to the driver as the
+        write's ack; the driver commits the manifest only once every rank
+        acked. A failed write reports
         ``{"error": ...}`` instead — the driver fails that save attempt
         cleanly and training continues from the previous checkpoint.
         """
@@ -101,9 +102,10 @@ class _Session:
         self._shard_reports += 1
         if axes_items is None:
             axes_items = [("fsdp", self.world_size)]
-        # The phases below are child spans (ckpt::meta / gather / copy /
-        # checksum / write, then the ack as a nested train::report), so
-        # this span's self time is what no phase accounts for.
+        # The phases below are child spans (ckpt::meta, write_shard's
+        # prefetch / gather / copy / checksum / write, then the ack as a
+        # nested train::report), so this span's self time is what no
+        # phase accounts for.
         with tracing.start_span("train::report_sharded") as span:
             if span is not None:
                 span.attributes.update(seq=seq, rank=self.world_rank)
@@ -112,10 +114,9 @@ class _Session:
                 if specs is None:
                     specs = sc.default_specs(flat, axis=axes_items[0][0])
             try:
-                local = sc.extract_local_shard(flat, specs, axes_items,
-                                               self.world_rank)
                 record = sc.write_shard(self._shard_backend, ctx["run"],
-                                        seq, self.world_rank, local)
+                                        seq, self.world_rank, flat, specs,
+                                        axes_items)
             except chaos.ChaosKill:
                 if self.on_chaos_kill is not None:
                     self.on_chaos_kill()

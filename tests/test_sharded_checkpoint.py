@@ -12,7 +12,9 @@ manifest + all shards, the mock-s3 backend, and the new config knobs.
 """
 
 import os
+import random
 import sys
+import zlib
 
 import cloudpickle
 import numpy as np
@@ -74,8 +76,7 @@ def _save_sharded(backend, run, seq, state, world, extra=None):
     axes = [("fsdp", world)]
     specs = sc.default_specs(flat)
     records = [
-        sc.write_shard(backend, run, seq, rank,
-                       sc.extract_local_shard(flat, specs, axes, rank))
+        sc.write_shard(backend, run, seq, rank, flat, specs, axes)
         for rank in range(world)
     ]
     meta = sc.build_tree_meta(flat, structure, specs, axes, extra=extra)
@@ -205,9 +206,8 @@ def test_uncommitted_shards_invisible_and_gcd(tmp_path):
     flat, structure = sc.flatten_tree(_state_at(1))
     specs = sc.default_specs(flat)
     for rank in range(2):  # both shards land, the manifest never does
-        sc.write_shard(backend, "torn", 1, rank,
-                       sc.extract_local_shard(flat, specs,
-                                              [("fsdp", 2)], rank))
+        sc.write_shard(backend, "torn", 1, rank, flat, specs,
+                       [("fsdp", 2)])
     assert mgr.latest() is None
     assert len(backend.list_files("train-torn-ckpt-")) == 2
     orphans_before = _counter_total(builtin_metrics.train_ckpt_orphans_gc())
@@ -268,9 +268,8 @@ def test_register_sharded_commits_and_prunes_all_files(tmp_path):
         flat, structure = sc.flatten_tree(state)
         specs = sc.default_specs(flat)
         records = [
-            sc.write_shard(backend, "prune", seq, rank,
-                           sc.extract_local_shard(flat, specs,
-                                                  [("fsdp", 2)], rank))
+            sc.write_shard(backend, "prune", seq, rank, flat, specs,
+                           [("fsdp", 2)])
             for rank in range(2)
         ]
         meta = sc.build_tree_meta(flat, structure, specs,
@@ -290,9 +289,8 @@ def test_register_sharded_refuses_partial_gang(tmp_path):
     mgr = CheckpointManager(str(tmp_path), "partial")
     flat, structure = sc.flatten_tree(_state_at(0))
     specs = sc.default_specs(flat)
-    rec = sc.write_shard(mgr._backend, "partial", 1, 1,
-                         sc.extract_local_shard(flat, specs,
-                                                [("fsdp", 2)], 1))
+    rec = sc.write_shard(mgr._backend, "partial", 1, 1, flat, specs,
+                         [("fsdp", 2)])
     meta = sc.build_tree_meta(flat, structure, specs, [("fsdp", 2)])
     with pytest.raises(ValueError, match="contiguous"):
         mgr.register_sharded(1, meta, [rec])  # rank 0 missing
@@ -311,9 +309,8 @@ def test_chaos_io_oserror_fails_write_keeps_prior(tmp_path):
         "io_oserror:site=train.ckpt_shard_write_error:times=1")
     try:
         with pytest.raises(spill.SpillFailure):
-            sc.write_shard(backend, "io", 2, 0,
-                           sc.extract_local_shard(flat, specs,
-                                                  [("fsdp", 2)], 0))
+            sc.write_shard(backend, "io", 2, 0, flat, specs,
+                           [("fsdp", 2)])
     finally:
         chaos.reset()
     prior = ShardedCheckpoint.from_manifest_uri(uri)
@@ -326,9 +323,8 @@ def test_mock_s3_backend_roundtrip(tmp_path, monkeypatch):
     flat, structure = sc.flatten_tree(_state_at(1))
     specs = sc.default_specs(flat)
     records = [
-        sc.write_shard(mgr._backend, "cloudy", 1, rank,
-                       sc.extract_local_shard(flat, specs,
-                                              [("fsdp", 2)], rank))
+        sc.write_shard(mgr._backend, "cloudy", 1, rank, flat, specs,
+                       [("fsdp", 2)])
         for rank in range(2)
     ]
     meta = sc.build_tree_meta(flat, structure, specs, [("fsdp", 2)],
@@ -340,6 +336,320 @@ def test_mock_s3_backend_roundtrip(tmp_path, monkeypatch):
     latest = CheckpointManager("mock-s3://ckpt-bucket", "cloudy").latest()
     assert isinstance(latest, ShardedCheckpoint)
     assert _trees_equal(latest.load_full(), _state_at(1))
+
+
+# ---------------------------------------------------------------------------
+# The streamed shard: byte-identical to the parent's writer
+# ---------------------------------------------------------------------------
+
+
+def _tobytes_shard(flat, specs, axes_items, rank):
+    """The writer as it was before the shard was streamed, kept as the
+    oracle: slice, ``ascontiguousarray``, ``tobytes``, and two CRC passes
+    over every byte. Returns the file's bytes and the record's fields."""
+    axes = dict(axes_items)
+    coords = sc.rank_coords(rank, axes_items)
+    blocks, parts, offset, file_crc = {}, [], 0, 0
+    for path in sorted(flat):
+        a = np.asarray(flat[path])
+        spec = sc.normalize_spec(specs.get(path), a.ndim)
+        block = a[sc.shard_slices(a.shape, spec, axes, coords)]
+        block = np.ascontiguousarray(block).reshape(np.shape(block))
+        raw = block.tobytes()
+        blocks[path] = {"offset": offset, "length": len(raw),
+                        "crc32": zlib.crc32(raw) & 0xFFFFFFFF,
+                        "shape": [int(n) for n in block.shape],
+                        "dtype": str(block.dtype)}
+        file_crc = zlib.crc32(raw, file_crc)
+        parts.append(raw)
+        offset += len(raw)
+    return b"".join(parts), {"bytes": offset, "blocks": blocks,
+                             "crc32": file_crc & 0xFFFFFFFF}
+
+
+def _leaf(kind, seed=0):
+    import ml_dtypes
+    rng = np.random.default_rng(seed)
+    if kind == "bf16":
+        return rng.standard_normal((6, 5)).astype(ml_dtypes.bfloat16)
+    if kind == "scalar":
+        return np.float32(rng.standard_normal())
+    if kind == "empty":
+        return np.empty((0, 3), np.float32)
+    if kind == "fortran":  # whole, in the transposed memory order
+        return np.asfortranarray(
+            rng.standard_normal((7, 300)).astype(ml_dtypes.bfloat16))
+    if kind == "fortran3d":
+        return np.asfortranarray(rng.integers(0, 99, (3, 4, 5)))
+    assert kind == "dim1"  # a rank's block is a strided slice
+    return rng.standard_normal((4, 9)).astype(np.float32)
+
+
+LEAF_KINDS = ["bf16", "scalar", "empty", "fortran", "fortran3d", "dim1"]
+
+
+def _tree_of(kinds):
+    flat = {f"{i}-{kind}": _leaf(kind, seed=i)
+            for i, kind in enumerate(kinds)}
+    specs = sc.default_specs(flat)
+    for path in flat:
+        if path.endswith("dim1"):
+            specs[path] = [[], ["fsdp"]]
+    return flat, specs
+
+
+@pytest.mark.parametrize("world,rank", [(1, 0), (2, 0), (2, 1)])
+@pytest.mark.parametrize("kinds", [[k] for k in LEAF_KINDS] + [LEAF_KINDS],
+                         ids=LEAF_KINDS + ["all"])
+def test_streamed_shard_is_byte_identical(tmp_path, kinds, world, rank):
+    """The shard file and every field the manifest takes from its record
+    equal what the ``tobytes`` writer produced for the same state."""
+    flat, specs = _tree_of(kinds)
+    axes = [("fsdp", world)]
+    backend = spill.FileSpillBackend(str(tmp_path))
+    record = sc.write_shard(backend, "same", 3, rank, flat, specs, axes)
+    want_bytes, want = _tobytes_shard(flat, specs, axes, rank)
+    with open(backend.path_for(record["uri"]), "rb") as f:
+        assert f.read() == want_bytes
+    assert {k: record[k] for k in want} == want
+    assert record["file"] == sc.shard_filename("same", 3, rank)
+    assert record["write_s"] >= 0.0
+    assert os.listdir(tmp_path) == [record["file"]]  # no .tmp left
+    for path, block in sc.extract_local_shard(flat, specs, axes,
+                                              rank).items():
+        assert block.flags.c_contiguous
+        assert block.tobytes() == want_bytes[
+            want["blocks"][path]["offset"]:][:want["blocks"][path]["length"]]
+
+
+@pytest.mark.parametrize("world", [1, 2])
+def test_streamed_checkpoint_restores_and_reshards(tmp_path, world):
+    flat, specs = _tree_of(LEAF_KINDS)
+    axes = [("fsdp", world)]
+    backend = spill.FileSpillBackend(str(tmp_path))
+    _, structure = sc.flatten_tree(flat)
+    records = [sc.write_shard(backend, "rt", 1, rank, flat, specs, axes)
+               for rank in range(world)]
+    meta = sc.build_tree_meta(flat, structure, specs, axes)
+    uri = sc.write_manifest(backend, "rt", 1,
+                            sc.build_manifest("rt", 1, meta, records))
+    ck = ShardedCheckpoint.from_manifest_uri(uri)
+    assert sc.validate_shards(backend, ck.manifest, verify_checksums=True)
+    assert _trees_equal(ck.load_full(verify=True), flat)
+    for rank in range(3):  # reshard onto three ranks
+        got = ck.load_for_rank(rank, world_size=3, verify=True)
+        want = sc.extract_local_shard(flat, specs, [("fsdp", 3)], rank)
+        assert _trees_equal(got, want)
+
+
+def test_checkpoint_of_the_tobytes_writer_restores(tmp_path):
+    """A checkpoint written before the shard was streamed restores."""
+    flat, specs = _tree_of(LEAF_KINDS)
+    axes = [("fsdp", 2)]
+    backend = spill.FileSpillBackend(str(tmp_path))
+    _, structure = sc.flatten_tree(flat)
+    records = []
+    for rank in range(2):
+        data, fields = _tobytes_shard(flat, specs, axes, rank)
+        name = sc.shard_filename("old", 1, rank)
+        backend.write(name, data)
+        records.append(dict(fields, rank=rank, file=name))
+    meta = sc.build_tree_meta(flat, structure, specs, axes)
+    uri = sc.write_manifest(backend, "old", 1,
+                            sc.build_manifest("old", 1, meta, records))
+    ck = ShardedCheckpoint.from_manifest_uri(uri)
+    assert _trees_equal(ck.load_full(verify=True), flat)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_crc32_combine_equals_zlib_on_random_splits(seed):
+    rng = random.Random(seed)
+    for _ in range(25):
+        parts = [rng.randbytes(rng.choice([0, 0, 1, 2, 31, 1024, 70001]))
+                 for _ in range(rng.randint(1, 5))]
+        crc = 0
+        for part in parts:
+            crc = sc.crc32_combine(crc, zlib.crc32(part), len(part))
+        assert crc == zlib.crc32(b"".join(parts))
+    # Bits above 32 in an input are not the caller's to clear.
+    assert sc.crc32_combine(zlib.crc32(b"ab"), zlib.crc32(b"c"), 1) == \
+        zlib.crc32(b"abc")
+
+
+def test_device_leaves_are_prefetched_and_host_leaves_skipped(tmp_path):
+    """Every ``jax.Array`` leaf's transfer is started before the first
+    leaf is read; numpy and scalar leaves have no transfer to start."""
+    import jax.numpy as jnp
+    started, read = [], []
+
+    class Leaf:
+        def __init__(self, name, value):
+            self.name, self.value = name, value
+
+        def copy_to_host_async(self):
+            assert not read, "a leaf was read before every transfer began"
+            started.append(self.name)
+
+        def __array__(self, dtype=None, copy=None):
+            read.append(self.name)
+            return self.value
+
+    flat = {"b": Leaf("b", np.arange(6.0)), "a": Leaf("a", np.ones(3)),
+            "host": np.arange(4), "scalar": 2.5,
+            "device": jnp.arange(8, dtype=jnp.bfloat16)}
+    backend = spill.FileSpillBackend(str(tmp_path))
+    record = sc.write_shard(backend, "pre", 1, 0, flat,
+                            {p: [] for p in flat}, [("fsdp", 1)])
+    assert started == ["a", "b"] and read == ["a", "b"]  # sorted paths
+    want_bytes, want = _tobytes_shard(flat, {}, [("fsdp", 1)], 0)
+    assert record["crc32"] == want["crc32"]
+    assert backend.read(record["uri"]) == want_bytes
+
+
+# ---------------------------------------------------------------------------
+# Failure paths under streaming
+# ---------------------------------------------------------------------------
+
+
+def _write_failures():
+    return _counter_total(builtin_metrics.object_spill_failures(), "write")
+
+
+def _shard_session(tmp_path, run="fail"):
+    """A rank-0 session as the BackendExecutor hands it to a worker, whose
+    report returns at once (nobody drains the queue here)."""
+    s = session._Session(ckpt_ctx={
+        "run": run, "storage_uri": "file://" + str(tmp_path),
+        "seq_base": 1})
+    s.continue_event.set()
+    return s
+
+
+@pytest.mark.parametrize("fault", ["chaos_after_2_leaves", "chaos_at_open",
+                                   "fsync_oserror", "write_oserror"])
+def test_failed_stream_leaves_nothing_and_reports_error(tmp_path,
+                                                        monkeypatch, fault):
+    """An ``OSError``, real or injected at ``spill.write_error``, at the
+    open, after k leaves or at the fsync: no ``.tmp``, no shard, one write
+    failure counted, and the rank reports ``{"error": ...}``."""
+    state = {f"w{i}": np.full((8, 4), float(i), np.float32)
+             for i in range(5)}
+    if fault == "fsync_oserror":
+        def refuse(fd):
+            raise OSError(28, "No space left on device")
+        monkeypatch.setattr(os, "fsync", refuse)
+    elif fault == "write_oserror":
+        real_open = open
+
+        class Full:
+            def __init__(self, f):
+                self.f, self.calls = f, 0
+
+            def write(self, part):
+                self.calls += 1
+                if self.calls == 3:
+                    raise OSError(28, "No space left on device")
+                return self.f.write(part)
+
+            def __getattr__(self, name):
+                return getattr(self.f, name)
+
+        monkeypatch.setattr(
+            spill, "open", lambda *a, **k: Full(real_open(*a, **k)),
+            raising=False)
+    else:
+        # The site is evaluated at the open, then once a part.
+        after = 3 if fault == "chaos_after_2_leaves" else 0
+        chaos.configure(
+            f"io_oserror:site=spill.write_error:after={after}:times=1")
+    before = _write_failures()
+    s = _shard_session(tmp_path)
+    try:
+        s.report_sharded({"step": 1}, state)
+    finally:
+        chaos.reset()
+        monkeypatch.undo()
+    shard = s.result_queue.get_nowait()["shard"]
+    assert shard["seq"] == 1 and shard["rank"] == 0
+    assert "spill write of train-fail-ckpt-000001.shard-0000 failed" in \
+        shard["error"]
+    assert "blocks" not in shard and "tree_meta" not in shard
+    assert os.listdir(tmp_path) == []
+    assert _write_failures() == before + 1
+    # The next save of the same session goes through.
+    s.continue_event.set()
+    s.report_sharded({"step": 2}, state)
+    shard = s.result_queue.get_nowait()["shard"]
+    assert "error" not in shard and shard["seq"] == 2
+    assert os.listdir(tmp_path) == [shard["file"]]
+
+
+def test_a_failure_that_is_no_oserror_aborts_the_stream(tmp_path):
+    """A leaf that cannot be read (a donated device array, say) is the
+    caller's error, not a spill failure: it propagates, and the writer
+    still leaves no ``.tmp`` behind."""
+    class Gone:
+        shape, ndim, dtype = (2,), 1, np.dtype(np.float32)
+
+        def __array__(self, dtype=None, copy=None):
+            raise RuntimeError("Array has been deleted")
+
+    flat = {"a": np.ones(4, np.float32), "b": Gone()}
+    backend = spill.FileSpillBackend(str(tmp_path))
+    before = _write_failures()
+    with pytest.raises(RuntimeError, match="deleted"):
+        sc.write_shard(backend, "gone", 1, 0, flat, {}, [("fsdp", 1)])
+    assert os.listdir(tmp_path) == []
+    assert _write_failures() == before
+
+
+def test_chaos_kill_leaves_the_shard_unwritten(tmp_path):
+    """``train.ckpt_shard_kill`` fires before the first byte: no shard, no
+    ``.tmp``, the rank is told to play dead, and the previous manifest
+    stays the newest."""
+    mgr = CheckpointManager(str(tmp_path), "fail")
+    _save_sharded(mgr._backend, "fail", 1, _state_at(1), 1,
+                  extra={"step": 1})
+    names = sorted(os.listdir(tmp_path))
+    s = _shard_session(tmp_path)
+    s._shard_reports = 1  # this save is seq 2
+    played_dead = []
+    s.on_chaos_kill = lambda: played_dead.append(True)
+    chaos.configure("kill:site=train.ckpt_shard_kill:times=1")
+    try:
+        with pytest.raises(chaos.ChaosKill):
+            s.report_sharded({"step": 2}, _state_at(2))
+    finally:
+        chaos.reset()
+    assert played_dead == [True] and s.result_queue.empty()
+    assert sorted(os.listdir(tmp_path)) == names
+    latest = CheckpointManager(str(tmp_path), "fail").latest()
+    assert latest.seq == 1 and latest.extra == {"step": 1}
+
+
+def test_a_reader_never_sees_a_partial_shard(tmp_path):
+    """While the leaves stream, the bytes are under ``.tmp`` only: the
+    final name appears with the rename, after the fsync."""
+    backend = spill.FileSpillBackend(str(tmp_path))
+    name = sc.shard_filename("part", 1, 0)
+    seen = []
+
+    class Watching:
+        """A leaf that looks at the storage when its turn comes."""
+
+        def __init__(self, value):
+            self.value = value
+
+        def __array__(self, dtype=None, copy=None):
+            seen.append((backend.list_files(), sorted(os.listdir(tmp_path)),
+                         backend.size_of(backend.uri_for(name))))
+            return self.value
+
+    flat = {f"w{i}": Watching(np.full((16,), float(i))) for i in range(3)}
+    record = sc.write_shard(backend, "part", 1, 0, flat, {}, [("fsdp", 1)])
+    assert seen == [([], [name + ".tmp"], None)] * 3
+    assert backend.list_files() == [name]
+    assert backend.size_of(record["uri"]) == record["bytes"] == 3 * 16 * 8
 
 
 def test_config_knobs_present():

@@ -161,6 +161,83 @@ def test_chaos_write_error_raises_spill_failure(tmp_path):
     assert backend.read(uri, 128) == b"y" * 128
 
 
+# -- the incremental writer: write()'s contract, part by part --------------
+
+
+@pytest.mark.parametrize("make", [
+    lambda tmp: FileSpillBackend(str(tmp)),
+    lambda tmp: MockS3SpillBackend("parts"),
+], ids=["file", "mock-s3"])
+def test_writer_is_invisible_until_commit(tmp_path, monkeypatch, make):
+    import numpy as np
+    monkeypatch.setenv("RAY_TPU_MOCK_S3_DIR", str(tmp_path / "s3"))
+    backend = make(tmp_path)
+    parts = [b"abc", bytearray(b"defg"), memoryview(b"hi"),
+             np.arange(5, dtype=np.uint8)]
+    with backend.open_writer("big.bin") as writer:
+        for part in parts:
+            writer.write(part)
+            assert backend.list_files() == []  # .tmp turds are not listed
+            assert backend.size_of(backend.uri_for("big.bin")) is None
+        uri = writer.commit()
+    assert uri == backend.uri_for("big.bin")
+    assert backend.list_files() == ["big.bin"]
+    assert backend.read(uri) == b"abcdefghi" + bytes(range(5))
+    assert os.listdir(backend.root) == ["big.bin"]
+    # The same bytes as one write() of the same parts.
+    backend.write("whole.bin", parts)
+    assert backend.read(backend.uri_for("whole.bin")) == backend.read(uri)
+
+
+@pytest.mark.parametrize("after", [0, 1, 3, 4],
+                         ids=["open", "part-1", "part-3", "commit"])
+def test_writer_chaos_error_at_any_point_leaves_nothing(tmp_path, after):
+    """``spill.write_error`` is evaluated at the open, at every part and at
+    the commit: wherever it fires, the ``.tmp`` is unlinked, one write
+    failure is counted and the caller gets ``SpillFailure``."""
+    backend = FileSpillBackend(str(tmp_path))
+    backend.write("kept.bin", b"old")
+    chaos.configure(
+        f"io_oserror:site=spill.write_error:after={after}:times=1")
+    before = _write_failures()
+    with pytest.raises(SpillFailure, match="spill write of new.bin failed"):
+        with backend.open_writer("new.bin") as writer:
+            for part in (b"1", b"2", b"3"):
+                writer.write(part)
+            writer.commit()
+    assert _write_failures() == before + 1
+    assert os.listdir(tmp_path) == ["kept.bin"]
+    chaos.reset()
+    with backend.open_writer("new.bin") as writer:
+        writer.write(b"again")
+        assert backend.read(writer.commit()) == b"again"
+
+
+def test_writer_left_without_commit_aborts(tmp_path):
+    """Another exception than an ``OSError`` (or no commit at all) is not a
+    spill failure, but the ``.tmp`` goes all the same."""
+    backend = FileSpillBackend(str(tmp_path))
+    before = _write_failures()
+    with pytest.raises(KeyError):
+        with backend.open_writer("half.bin") as writer:
+            writer.write(b"x" * 64)
+            raise KeyError("the producer failed")
+    with backend.open_writer("never.bin") as writer:
+        writer.write(b"y")
+    assert os.listdir(tmp_path) == []
+    assert _write_failures() == before
+
+
+def test_writer_replaces_an_older_payload_atomically(tmp_path):
+    backend = FileSpillBackend(str(tmp_path))
+    uri = backend.write("same.bin", b"old payload")
+    with backend.open_writer("same.bin") as writer:
+        writer.write(b"new")
+        assert backend.read(uri) == b"old payload"
+        writer.commit()
+    assert backend.read(uri) == b"new"
+
+
 def test_chaos_restore_error_is_tier_miss(tmp_path):
     backend = FileSpillBackend(str(tmp_path))
     uri = backend.write("r.bin", b"z" * 128)

@@ -20,6 +20,7 @@ from ray_tpu.util import tracing
 
 LOOP = "train-rank-0"
 LEAF_ELEMS = 2 * 1024 * 1024  # 8 MB of float32 a leaf: phases, not overhead
+HEAD_SHAPE = (1024, 2048)  # 8 MB too, in the transposed memory order
 
 
 @pytest.fixture
@@ -35,8 +36,13 @@ def tracing_off():
 
 def _loop(config):
     import jax.numpy as jnp
+    # ``head`` arrives as a leaf can come off the chip: whole, but
+    # column-major, so it is the one leaf whose bytes the save copies.
     state = {"params": {"w": jnp.ones((LEAF_ELEMS,), jnp.float32),
-                        "b": jnp.arange(LEAF_ELEMS, dtype=jnp.float32)},
+                        "b": jnp.arange(LEAF_ELEMS, dtype=jnp.float32),
+                        "head": np.asfortranarray(
+                            np.arange(LEAF_ELEMS, dtype=np.float32).reshape(
+                                HEAD_SHAPE))},
              "step": jnp.int32(0)}
     batches = session.get_dataset_shard("train").iter_jax_batches(
         batch_size=4)
@@ -165,6 +171,7 @@ TABLE = [
     ("train::report_wait", "train::report", LOOP),
     ("train::report_sharded", None, LOOP),
     ("ckpt::meta", "train::report_sharded", LOOP),
+    ("ckpt::prefetch", "train::report_sharded", LOOP),
     ("ckpt::gather", "train::report_sharded", LOOP),
     ("ckpt::copy", "train::report_sharded", LOOP),
     ("ckpt::checksum", "train::report_sharded", LOOP),
@@ -198,26 +205,87 @@ def test_report_is_a_root_and_a_save_s_ack(traced_run):
     assert names.count("train::report_sharded") == 2
 
 
+def _children(traced_run, save):
+    return sorted((s for s in traced_run["spans"]
+                   if s.parent_id == save.span_id),
+                  key=lambda s: s.perf_start)
+
+
 def test_the_phases_cover_the_save(traced_run):
     saves = [s for s in traced_run["spans"]
              if s.name == "train::report_sharded"]
     assert [s.attributes["seq"] for s in saves] == [1, 2]
     for save in saves:
-        children = [s for s in traced_run["spans"]
-                    if s.parent_id == save.span_id]
+        children = _children(traced_run, save)
         covered = sum(s.duration for s in children)
         assert 0.95 * save.duration <= covered <= save.duration
-        # Per leaf: a gather, two copies (slice, tobytes), one checksum.
+        # One after the other on the loop's thread: the save's self time
+        # is a true remainder only if no two phases overlap.
+        for a, b in zip(children, children[1:]):
+            assert a.perf_start + a.duration <= b.perf_start
+        # Per leaf: the wait for its transfer, one checksum, one write;
+        # a copy for the one leaf that is not C-contiguous; one prefetch;
+        # a last write for fsync and rename.
         per_name = {n: sum(s.name == n for s in children)
-                    for n in ("ckpt::gather", "ckpt::copy",
+                    for n in ("ckpt::prefetch", "ckpt::gather", "ckpt::copy",
                               "ckpt::checksum", "ckpt::write")}
-        assert per_name == {"ckpt::gather": 3, "ckpt::copy": 6,
-                            "ckpt::checksum": 3, "ckpt::write": 1}
-        nbytes = 2 * LEAF_ELEMS * 4 + 4
-        assert sum(s.attributes["bytes"] for s in children
-                   if s.name == "ckpt::gather") == nbytes
-        [write] = [s for s in children if s.name == "ckpt::write"]
-        assert write.attributes["bytes"] == nbytes
+        assert per_name == {"ckpt::prefetch": 1, "ckpt::gather": 4,
+                            "ckpt::copy": 1, "ckpt::checksum": 4,
+                            "ckpt::write": 5}
+        nbytes = 3 * LEAF_ELEMS * 4 + 4
+        for name in ("ckpt::gather", "ckpt::checksum"):
+            assert sum(s.attributes["bytes"] for s in children
+                       if s.name == name) == nbytes  # each byte once
+        writes = [s for s in children if s.name == "ckpt::write"]
+        assert [s.attributes.get("what") for s in writes] == \
+            [None] * 4 + ["commit"]
+        assert sum(s.attributes["bytes"] for s in writes[:-1]) == nbytes
+        assert writes[-1].attributes["bytes"] == nbytes
+        assert [s.attributes["leaf"] for s in writes[:-1]] == [
+            "params/b", "params/head", "params/w", "step"]  # sorted paths
+
+
+def test_prefetch_comes_first_and_counts_the_device_leaves(traced_run):
+    for save in (s for s in traced_run["spans"]
+                 if s.name == "train::report_sharded"):
+        names = [s.name for s in _children(traced_run, save)]
+        assert names[:3] == ["ckpt::meta", "ckpt::prefetch", "ckpt::gather"]
+        [prefetch] = [s for s in _children(traced_run, save)
+                      if s.name == "ckpt::prefetch"]
+        # ``head`` is a numpy leaf: nothing to start for it.
+        assert prefetch.attributes == {"leaves": 3}
+
+
+def test_copy_is_recorded_only_for_the_leaf_that_was_copied(traced_run):
+    copies = [s for s in traced_run["spans"] if s.name == "ckpt::copy"]
+    assert [s.attributes for s in copies] == [
+        {"leaf": "params/head", "what": "relayout",
+         "bytes": LEAF_ELEMS * 4}] * 2  # one a save
+
+
+def test_a_rank_s_strided_slice_is_a_copy_called_slice(tracing_off,
+                                                       tmp_path):
+    """The other copy a save can make: a rank's block along a non-leading
+    dim. Blocks along dim 0, scalars and whole leaves are written from
+    their own memory, with no ``ckpt::copy``."""
+    from ray_tpu._private import spill
+    from ray_tpu.train._internal import sharded_checkpoint as sc
+    flat = {"cols": np.arange(48, dtype=np.float32).reshape(4, 12),
+            "rows": np.arange(48, dtype=np.float32).reshape(12, 4),
+            "whole": np.ones((5,), np.float32), "scalar": np.float32(3)}
+    specs = {"cols": [[], ["fsdp"]], "rows": [["fsdp"], []]}
+    tracing.enable_tracing()
+    with tracing.start_span("train::report_sharded"):
+        sc.write_shard(spill.FileSpillBackend(str(tmp_path)), "t", 1, 1,
+                       flat, specs, [("fsdp", 2)])
+    copies = [s for s in tracing.get_spans() if s.name == "ckpt::copy"]
+    assert [s.attributes for s in copies] == [
+        {"leaf": "cols", "what": "slice", "bytes": 4 * 6 * 4}]
+    # Outside a save the writer records nothing.
+    tracing.clear_spans()
+    sc.write_shard(spill.FileSpillBackend(str(tmp_path)), "t", 2, 1, flat,
+                   specs, [("fsdp", 2)])
+    assert tracing.get_spans() == []
 
 
 def test_commit_carries_the_seq_and_prune_what_it_dropped(traced_run):
