@@ -265,8 +265,12 @@ def _layernorm(x, scale, bias, eps):
 
 
 def _rotary(x, positions, rotary_dim):
-    """Apply GPT-J (interleaved) rotary embedding to the first rotary_dim
-    dims of each head. x: [B, S, H, D], positions: [B, S]."""
+    """Rotary embedding on the first rotary_dim dims of each head, pairing
+    dimension i with i + rotary_dim/2 (the GPT-NeoX "rotate half" pairing).
+    The published GPT-J pairs 2i with 2i+1: same frequencies, and the same
+    result up to a fixed permutation of Wq's and Wk's columns inside each
+    head (the GPT-J configuration files list it as a departure).
+    x: [B, S, H, D], positions: [B, S]."""
     if rotary_dim == 0:
         return x
     rot, rest = x[..., :rotary_dim], x[..., rotary_dim:]
@@ -281,8 +285,9 @@ def _rotary(x, positions, rotary_dim):
     return jnp.concatenate([rot_out, rest], axis=-1)
 
 
-def _dot_attention(q, k, v, cfg: GPTConfig):
-    """Causal attention; fp32 softmax. q,k,v: [B, S, H, D]/[B, S, KVH, D]."""
+def _dot_attention(q, k, v):
+    """Causal attention; fp32 softmax. q: [B, S, H, D], k: [B, S, KVH, D],
+    v: [B, S, KVH, Dv] (Dv may differ from D) -> [B, S, H, Dv]."""
     B, S, H, D = q.shape
     kvh = k.shape[2]
     if kvh != H:  # GQA: repeat KV heads
@@ -314,9 +319,12 @@ def _attention_specs(mesh, n_heads: int, n_kv_heads: int, seq_axis):
     return q_spec, kv_spec
 
 
-def _attention(q, k, v, cfg: GPTConfig):
+def _attention(q, k, v, cfg):
+    """Causal attention by ``cfg.attn_impl`` (and, for the flash kernels,
+    ``cfg.attn_blk_q`` / ``cfg.attn_blk_k``): the one dispatch every model
+    of this package goes through. cfg is any model's config."""
     if cfg.attn_impl == "dot":
-        return _dot_attention(q, k, v, cfg)
+        return _dot_attention(q, k, v)
     if cfg.attn_impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention
         from ray_tpu.parallel.mesh import current_mesh
@@ -454,16 +462,12 @@ def _block(cfg: GPTConfig, x, layer, positions):
     return x + mlp_out, aux
 
 
-def hidden_states(params: Dict[str, Any], cfg: GPTConfig,
-                  tokens: jax.Array,
-                  positions: Optional[jax.Array] = None):
-    """tokens [B, S] int32 → (final-layernormed hidden [B, S, d], aux)."""
-    B, S = tokens.shape
-    if positions is None:
-        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = jnp.take(params["wte"], tokens, axis=0).astype(cfg.dtype)
-
-    block = partial(_block, cfg)
+def scan_blocks(cfg, block, x, layers, positions):
+    """``block(x, layer, positions) -> (x, aux)`` over stacked layer
+    parameters in one ``lax.scan``, each block rematerialised by
+    ``cfg.remat`` / ``cfg.remat_policy``. Returns (x, aux stacked over
+    layers). Shared by every model that scans its layers; cfg is that
+    model's config."""
     if cfg.remat:
         if cfg.remat_policy == "selective":
             policy = jax.checkpoint_policies.save_only_these_names(
@@ -476,17 +480,26 @@ def hidden_states(params: Dict[str, Any], cfg: GPTConfig,
                 "expected 'full' or 'selective'")
         block = jax.checkpoint(block, policy=policy)
 
-    def scan_body(carry, layer):
-        x, aux = carry
+    def scan_body(x, layer):
         with jax.named_scope("block"):
-            x, a = block(x, layer, positions)
-        return (x, aux + a), None
+            return block(x, layer, positions)
 
-    (x, aux), _ = jax.lax.scan(
-        scan_body, (x, jnp.zeros((), jnp.float32)), params["layers"])
+    return jax.lax.scan(scan_body, x, layers)
+
+
+def hidden_states(params: Dict[str, Any], cfg: GPTConfig,
+                  tokens: jax.Array,
+                  positions: Optional[jax.Array] = None):
+    """tokens [B, S] int32 → (final-layernormed hidden [B, S, d], aux)."""
+    B, S = tokens.shape
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    x = jnp.take(params["wte"], tokens, axis=0).astype(cfg.dtype)
+    x, aux = scan_blocks(cfg, partial(_block, cfg), x, params["layers"],
+                         positions)
     x = _layernorm(x, params["lnf_scale"], params["lnf_bias"],
                    cfg.layernorm_eps)
-    return x, aux
+    return x, aux.sum()
 
 
 def _head(params: Dict[str, Any], cfg: GPTConfig, x: jax.Array) -> jax.Array:
@@ -526,6 +539,40 @@ def _ce_stats(logits: jax.Array, targets: jax.Array, mask: jax.Array,
     return (nll * mask).sum(), (hits * mask).sum()
 
 
+def chunked_ce(head, x: jax.Array, targets: jax.Array, mask32: jax.Array,
+               chunk: int, z_loss: float = 0.0
+               ) -> Tuple[jax.Array, jax.Array]:
+    """(Σ nll·mask, Σ hit·mask) of ``head(x)`` against targets, in fp32.
+
+    ``head`` maps hidden [..., d] to logits [..., vocab]. With ``chunk > 0``
+    the head matmul and the fp32 softmax run ``chunk`` tokens at a time
+    under a rematerialised lax.scan, so the [tokens, vocab] fp32 logits
+    never exist whole. Shared by every language model of this package."""
+    with jax.named_scope("head_loss"):
+        T = targets.size
+        if chunk and T % chunk and T > chunk:
+            # Requested chunk doesn't divide the token count: use the largest
+            # divisor <= chunk rather than silently materializing full logits
+            # (defeating the feature's memory bound).
+            chunk = max(c for c in range(1, chunk + 1) if T % c == 0)
+        if not (chunk and T > chunk):
+            return _ce_stats(head(x), targets, mask32, z_loss)
+        d = x.shape[-1]
+        xf = x.reshape(T // chunk, chunk, d)
+        tf = targets.reshape(T // chunk, chunk)
+        mf = mask32.reshape(T // chunk, chunk)
+
+        @jax.checkpoint
+        def chunk_stats(carry, xtm):
+            x_c, t_c, m_c = xtm
+            nll_sum, hit_sum = _ce_stats(head(x_c), t_c, m_c, z_loss)
+            return (carry[0] + nll_sum, carry[1] + hit_sum), None
+
+        sums, _ = jax.lax.scan(
+            chunk_stats, (jnp.zeros((), jnp.float32),) * 2, (xf, tf, mf))
+        return sums
+
+
 def loss_fn(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
             targets: jax.Array, mask: Optional[jax.Array] = None,
             z_loss: float = 0.0) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -533,42 +580,15 @@ def loss_fn(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
     for MoE configs, the router load-balancing aux term).
 
     With ``cfg.loss_chunk > 0`` the head matmul + fp32 softmax run chunked
-    under a rematerialized lax.scan, so the [tokens, vocab] fp32 logits
-    never exist whole (see GPTConfig.loss_chunk)."""
+    (see ``chunked_ce`` and GPTConfig.loss_chunk)."""
     x, aux = hidden_states(params, cfg, tokens)
-    B, S = tokens.shape
     if mask is None:
-        mask32 = jnp.ones((B, S), jnp.float32)
+        mask32 = jnp.ones(tokens.shape, jnp.float32)
     else:
         mask32 = mask.astype(jnp.float32)
     denom = jnp.maximum(mask32.sum(), 1.0)
-
-    with jax.named_scope("head_loss"):
-        T = B * S
-        chunk = cfg.loss_chunk
-        if chunk and T % chunk and T > chunk:
-            # Requested chunk doesn't divide the token count: use the largest
-            # divisor <= chunk rather than silently materializing full logits
-            # (defeating the feature's memory bound).
-            chunk = max(c for c in range(1, chunk + 1) if T % c == 0)
-        if chunk and T > chunk:
-            d = x.shape[-1]
-            xf = x.reshape(T // chunk, chunk, d)
-            tf = targets.reshape(T // chunk, chunk)
-            mf = mask32.reshape(T // chunk, chunk)
-
-            @jax.checkpoint
-            def chunk_stats(carry, xtm):
-                x_c, t_c, m_c = xtm
-                nll_sum, hit_sum = _ce_stats(
-                    _head(params, cfg, x_c), t_c, m_c, z_loss)
-                return (carry[0] + nll_sum, carry[1] + hit_sum), None
-
-            (nll_sum, hit_sum), _ = jax.lax.scan(
-                chunk_stats, (jnp.zeros((), jnp.float32),) * 2, (xf, tf, mf))
-        else:
-            nll_sum, hit_sum = _ce_stats(
-                _head(params, cfg, x), targets, mask32, z_loss)
+    nll_sum, hit_sum = chunked_ce(partial(_head, params, cfg), x, targets,
+                                  mask32, cfg.loss_chunk, z_loss)
 
     ce = nll_sum / denom
     loss = ce

@@ -6,13 +6,27 @@ in DDP/FSDP modules, we jit one functional train step whose inputs carry
 NamedShardings; XLA inserts the gradient psums / param all-gathers over ICI.
 Parameters are *initialized inside jit with out_shardings* so a 6B-param
 model never materializes unsharded on any single host.
+
+The step is typed to no model. Every builder here takes ``model``: anything
+(a module of ``ray_tpu.models``, as a rule) that offers
+
+    init(cfg, key) -> params
+    param_specs(cfg, rules) -> PartitionSpec tree like params
+    loss_fn(params, cfg, tokens, targets, mask) -> (loss, metrics)
+    batch_spec(rules) -> PartitionSpec of a [batch, seq] token array
+
+and, optionally, ``SUMMED_METRICS``: the names of metrics that are counts
+of a batch (summed, not averaged, over accumulation microbatches). ``cfg``
+is that model's own config object and is only handed back to it. The
+default is ``models/gpt.py``, so callers that train a ``GPTConfig`` say
+nothing.
 """
 
 from __future__ import annotations
 
 import functools
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +34,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ray_tpu._private.jax_compat import enable_compile_cache
-from ray_tpu.models import gpt
+from ray_tpu.models import gpt as _default_model
 from ray_tpu.parallel import mesh as mesh_mod
 from ray_tpu.parallel.sharding import ShardingRules, tree_shardings
 
@@ -57,8 +71,8 @@ def memory_efficient_optimizer(learning_rate=1e-4,
     )
 
 
-def _state_layout(cfg: gpt.GPTConfig, mesh, rules: ShardingRules,
-                  optimizer: optax.GradientTransformation):
+def _state_layout(cfg: Any, mesh, rules: ShardingRules,
+                  optimizer: optax.GradientTransformation, model: Any):
     """(shapes, shardings) of the train state {params, opt_state, step}.
 
     Params shard by the rules. An optimizer sub-tree shaped like the params
@@ -69,8 +83,8 @@ def _state_layout(cfg: gpt.GPTConfig, mesh, rules: ShardingRules,
     picked would make the next call a different program.
     """
     replicated = NamedSharding(mesh, PartitionSpec())
-    pshard = tree_shardings(mesh, gpt.param_specs(cfg, rules))
-    params = jax.eval_shape(partial(gpt.init, cfg), jax.random.PRNGKey(0))
+    pshard = tree_shardings(mesh, model.param_specs(cfg, rules))
+    params = jax.eval_shape(partial(model.init, cfg), jax.random.PRNGKey(0))
     opt_state = jax.eval_shape(optimizer.init, params)
     like_params = jax.tree.structure(params)
 
@@ -94,69 +108,104 @@ def _state_layout(cfg: gpt.GPTConfig, mesh, rules: ShardingRules,
     return shapes, shardings
 
 
-def init_train_state(cfg: gpt.GPTConfig, mesh,
+def init_train_state(cfg: Any, mesh,
                      rules: Optional[ShardingRules] = None,
                      optimizer: Optional[optax.GradientTransformation] = None,
-                     seed: int = 0) -> Dict[str, Any]:
+                     seed: int = 0, model: Any = None) -> Dict[str, Any]:
     """Build {params, opt_state, step}, created directly in sharded form."""
     enable_compile_cache()
     rules = rules or ShardingRules()
     optimizer = optimizer or default_optimizer()
-    _, shardings = _state_layout(cfg, mesh, rules, optimizer)
+    model = model or _default_model
+    _, shardings = _state_layout(cfg, mesh, rules, optimizer, model)
 
     @partial(jax.jit, out_shardings=shardings)
     def init(key):
-        params = gpt.init(cfg, key)
+        params = model.init(cfg, key)
         return {"params": params, "opt_state": optimizer.init(params),
                 "step": jnp.zeros((), jnp.int32)}
 
     return init(jax.random.PRNGKey(seed))
 
 
-def abstract_train_state(cfg: gpt.GPTConfig, mesh,
+def abstract_train_state(cfg: Any, mesh,
                          rules: Optional[ShardingRules] = None,
                          optimizer: Optional[
-                             optax.GradientTransformation] = None
-                         ) -> Dict[str, Any]:
+                             optax.GradientTransformation] = None,
+                         model: Any = None) -> Dict[str, Any]:
     """init_train_state's result as ShapeDtypeStructs carrying shardings:
     what a step is lowered with when no device can hold the arrays (a
     compile for a described TPU topology, a per-device memory proof)."""
     shapes, shardings = _state_layout(
-        cfg, mesh, rules or ShardingRules(), optimizer or default_optimizer())
+        cfg, mesh, rules or ShardingRules(), optimizer or default_optimizer(),
+        model or _default_model)
     return jax.tree.map(
         lambda shape, sharding: jax.ShapeDtypeStruct(
             shape.shape, shape.dtype, sharding=sharding),
         shapes, shardings)
 
 
-def _with_mesh_registered(jitted, mesh):
+def _with_mesh_registered(jitted, mesh, after_call=None):
     """Register ``mesh`` as the current mesh around every call, not once at
     build time: jit traces lazily (first call / new shapes), so the registry
     must hold THIS step's mesh whenever a trace may happen — two steps built
     over different meshes would otherwise trace against the wrong one. The
     previous mesh comes back afterwards, so model code called outside any
     step never sees a stale one. ``.lower`` traces too and gets the same
-    treatment."""
-    def under_mesh(fn):
+    treatment. ``after_call`` sees what each call returns."""
+    def under_mesh(fn, then=None):
         @functools.wraps(fn)
         def call(*args, **kwargs):
             previous = mesh_mod.current_mesh()
             mesh_mod.set_current_mesh(mesh)
             try:
-                return fn(*args, **kwargs)
+                out = fn(*args, **kwargs)
             finally:
                 mesh_mod.set_current_mesh(previous)
+            if then is not None:
+                then(out)
+            return out
         return call
 
-    wrapped = under_mesh(jitted)
+    wrapped = under_mesh(jitted, after_call)
     wrapped.lower = under_mesh(jitted.lower)
     return wrapped
 
 
-def make_train_step(cfg: gpt.GPTConfig, mesh,
+class _MoeCounters:
+    """Feeds the ``moe_*`` scalars a step returns (models/deepseek.py) to
+    ``ray_tpu_train_moe_*`` without a device sync in the loop: a call's
+    scalars are read once they are ready, or by the next call at the
+    latest (that step is then queued behind them on the device, so the
+    read waits for nothing the device would not do anyway). The newest
+    call's may therefore still be pending when the loop ends."""
+
+    KEYS = ("moe_assignments", "moe_tokens", "moe_load_max_over_mean")
+
+    def __init__(self) -> None:
+        self.pending: list = []
+
+    def __call__(self, out) -> None:
+        metrics = out[1]
+        if self.KEYS[0] not in metrics:
+            return
+        from ray_tpu._private import builtin_metrics
+        scalars = [metrics[k] for k in self.KEYS]
+        for scalar in scalars:
+            scalar.copy_to_host_async()
+        self.pending.append(scalars)
+        while self.pending and (len(self.pending) > 1 or all(
+                scalar.is_ready() for scalar in self.pending[0])):
+            assigned, tokens, load = (float(x) for x in self.pending.pop(0))
+            builtin_metrics.train_moe_assignments().inc(assigned)
+            builtin_metrics.train_moe_tokens().inc(tokens)
+            builtin_metrics.train_moe_expert_load().set(load)
+
+
+def make_train_step(cfg: Any, mesh,
                     rules: Optional[ShardingRules] = None,
                     optimizer: Optional[optax.GradientTransformation] = None,
-                    accum_steps: int = 1) -> Callable:
+                    accum_steps: int = 1, model: Any = None) -> Callable:
     """Returns jitted step(state, batch) -> (state, metrics).
 
     batch = {"tokens": [B, S] int32, "targets": [B, S] int32,
@@ -167,11 +216,13 @@ def make_train_step(cfg: gpt.GPTConfig, mesh,
     enable_compile_cache()
     rules = rules or ShardingRules()
     optimizer = optimizer or default_optimizer()
-    bspec = gpt.batch_spec(rules)
+    model = model or _default_model
+    bspec = model.batch_spec(rules)
+    summed = frozenset(getattr(model, "SUMMED_METRICS", ()))
 
     def loss_for(params, micro):
-        return gpt.loss_fn(params, cfg, micro["tokens"], micro["targets"],
-                           micro.get("mask"))
+        return model.loss_fn(params, cfg, micro["tokens"], micro["targets"],
+                             micro.get("mask"))
 
     def step(state, batch):
         params = state["params"]
@@ -195,12 +246,15 @@ def make_train_step(cfg: gpt.GPTConfig, mesh,
                 lambda x: x.reshape((accum_steps, -1) + x.shape[1:]), batch)
             zeros_g = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            zeros_m = {"loss": 0.0, "accuracy": 0.0, "perplexity": 0.0}
-            zeros_m = jax.tree.map(jnp.float32, zeros_m)
+            one = jax.tree.map(lambda x: x[0], micros)
+            zeros_m = jax.tree.map(
+                lambda m: jnp.zeros(m.shape, jnp.float32),
+                jax.eval_shape(grad_fn, params, one)[0][1])
             (grads, metrics), _ = jax.lax.scan(
                 micro_body, (zeros_g, zeros_m), micros)
             grads = jax.tree.map(lambda g: g / accum_steps, grads)
-            metrics = jax.tree.map(lambda m: m / accum_steps, metrics)
+            metrics = {k: m if k in summed else m / accum_steps
+                       for k, m in metrics.items()}
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(
                 grads, state["opt_state"], params)
@@ -210,17 +264,19 @@ def make_train_step(cfg: gpt.GPTConfig, mesh,
 
     # The state goes out as it came in (see _state_layout), so every call
     # after the first finds the same program, and donation can alias.
-    _, shardings = _state_layout(cfg, mesh, rules, optimizer)
+    _, shardings = _state_layout(cfg, mesh, rules, optimizer, model)
     return _with_mesh_registered(
         jax.jit(step, donate_argnums=(0,), out_shardings=(shardings, None)),
-        mesh)
+        mesh, after_call=_MoeCounters())
 
 
-def make_eval_step(cfg: gpt.GPTConfig, mesh,
-                   rules: Optional[ShardingRules] = None) -> Callable:
+def make_eval_step(cfg: Any, mesh,
+                   rules: Optional[ShardingRules] = None,
+                   model: Any = None) -> Callable:
     enable_compile_cache()
     rules = rules or ShardingRules()
-    bspec = gpt.batch_spec(rules)
+    model = model or _default_model
+    bspec = model.batch_spec(rules)
 
     def step(params, batch):
         batch = {
@@ -228,8 +284,8 @@ def make_eval_step(cfg: gpt.GPTConfig, mesh,
                 v, NamedSharding(mesh, bspec))
             for k, v in batch.items()
         }
-        _, metrics = gpt.loss_fn(params, cfg, batch["tokens"],
-                                 batch["targets"], batch.get("mask"))
+        _, metrics = model.loss_fn(params, cfg, batch["tokens"],
+                                   batch["targets"], batch.get("mask"))
         return metrics
 
     return _with_mesh_registered(jax.jit(step), mesh)

@@ -7,8 +7,9 @@ partition. These compiles can, at no chip time (on-chip-measurement
 guide §2.3). Nothing runs, so they say nothing about results or speed.
 
 One file, one process: two processes describing a TPU topology at once
-collide on libtpu's lock. The whole file skips where the topology cannot
-be described (no libtpu).
+collide on libtpu's lock, so the topology is described in a fixture, by the
+worker that runs this file, and never at import. Every test skips where it
+cannot be described (no libtpu).
 """
 
 import os
@@ -36,19 +37,17 @@ from ray_tpu.parallel.train_step import (abstract_train_state,  # noqa: E402
 flash_mod = sys.modules["ray_tpu.ops.flash_attention"]
 
 
-def _describe_topology():
+@pytest.fixture(scope="module")
+def topo():
+    """The described topology, made when the first test of this file runs
+    and never while a module is imported: only the worker that is given
+    this file loads the TPU's library (on-chip-measurement guide §2)."""
     try:
         from jax.experimental import topologies
         return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as exc:  # noqa: BLE001 - any failure means "no libtpu"
-        return exc
-
-
-_TOPO = _describe_topology()
-pytestmark = pytest.mark.skipif(
-    isinstance(_TOPO, Exception),
-    reason=f"v5e:2x2 topology cannot be described here: {_TOPO!r}")
+        pytest.skip(f"v5e:2x2 topology cannot be described here: {exc!r}")
 
 
 @pytest.fixture(autouse=True)
@@ -78,10 +77,16 @@ PRESET_SHAPES = {
 }
 
 
-def _qkv(shape):
-    one_chip = SingleDeviceSharding(_TOPO.devices[0])
-    return [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
-            for _ in range(3)]
+# Latent attention in training (models/deepseek.py): q/k of 192, v of 128,
+# at the sequence length whose K/V no longer fit a kernel's VMEM whole.
+MLA_SHAPE, MLA_V = (2, 8192, 16, 192), 128
+
+
+def _qkv(topo, shape, v_dim=None):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shapes = [shape, shape, shape[:-1] + (v_dim or shape[-1],)]
+    return [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in shapes]
 
 
 def _attend(q, k, v):
@@ -89,35 +94,93 @@ def _attend(q, k, v):
 
 
 @pytest.mark.parametrize("preset", PRESET_SHAPES)
-def test_flash_forward_compiles(preset):
+def test_flash_forward_compiles(topo, preset):
     text = jax.jit(_attend).lower(
-        *_qkv(PRESET_SHAPES[preset])).compile().as_text()
+        *_qkv(topo, PRESET_SHAPES[preset])).compile().as_text()
     assert text.count("tpu_custom_call") >= 1
 
 
-@pytest.mark.parametrize("preset", PRESET_SHAPES)
-def test_flash_backward_compiles(preset):
-    """Forward + the dq and dk/dv kernels: three Mosaic calls."""
-    def loss(q, k, v):
-        return _attend(q, k, v).astype(jnp.float32).sum()
+def _attend_loss(q, k, v):
+    return _attend(q, k, v).astype(jnp.float32).sum()
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        *_qkv(PRESET_SHAPES[preset])).compile().as_text()
+
+@pytest.mark.parametrize("preset", PRESET_SHAPES)
+def test_flash_backward_compiles(topo, preset):
+    """Forward + the dq and dk/dv kernels: three Mosaic calls."""
+    text = jax.jit(jax.grad(_attend_loss, argnums=(0, 1, 2))).lower(
+        *_qkv(topo, PRESET_SHAPES[preset])).compile().as_text()
     assert text.count("tpu_custom_call") >= 3
 
 
-def test_ragged_sequence_is_an_error_on_tpu():
+@pytest.mark.parametrize("shape,v_dim", [
+    (MLA_SHAPE, MLA_V), ((2, 8192, 16, 256), 256)])
+def test_flash_compiles_at_8k_with_two_head_sizes(topo, shape, v_dim):
+    """S = 8192: K and V (in the dk/dv kernel Q and dO) of a head are
+    2-4 MB each and came whole into VMEM before they were streamed by the
+    grid; q/k of 192 beside v of 128 is latent attention, 256 | 256 GPT-J
+    at four times its context."""
+    grads = jax.jit(jax.grad(_attend_loss, argnums=(0, 1, 2))).lower(
+        *_qkv(topo, shape, v_dim)).compile()
+    assert grads.as_text().count("tpu_custom_call") >= 3
+
+
+def test_flash_compiles_at_8k_under_shard_map(topo):
+    """The same kernels per shard of an fsdp=2 x tp=2 mesh, through the
+    models' one attention dispatch."""
+    from ray_tpu.models import deepseek
+    from ray_tpu.parallel import mesh as mesh_mod
+    mesh = build_mesh(MeshConfig(dp=1, fsdp=2, tp=2), devices=topo.devices)
+    cfg = deepseek.config("moonlight-16b-a3b", attn_impl="flash")
+    sharding = NamedSharding(mesh, PartitionSpec(("dp", "fsdp"), None, "tp",
+                                                 None))
+    q, k, v = (jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding)
+               for s in _qkv(topo, (4,) + MLA_SHAPE[1:], MLA_V))
+
+    def loss(q, k, v):
+        return gpt._attention(q, k, v, cfg).astype(jnp.float32).sum()
+
+    previous = mesh_mod.current_mesh()
+    mesh_mod.set_current_mesh(mesh)
+    try:
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, k, v).compile().as_text()
+    finally:
+        mesh_mod.set_current_mesh(previous)
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_grouped_matmul_compiles_at_the_published_widths(topo):
+    """The expert layer's grouped matmul at Moonlight's widths: the megablox
+    kernels, forward (gmm) and both cotangents (gmm, tgmm), inside the
+    scoped VMEM at the tile sizes ops/moe.py picks."""
+    from ray_tpu.ops import moe
+    rows, d, f, experts = 98304, 2048, 1408, 64
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = (jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((experts, d, f), jnp.bfloat16,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=one_chip))
+
+    def loss(x, w, sizes):
+        return moe.grouped_matmul(x, w, sizes).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2  # dlhs and drhs
+
+
+def test_ragged_sequence_is_an_error_on_tpu(topo):
     """No silent switch to the jnp blockwise path where a kernel exists."""
     with pytest.raises(ValueError, match="multiple of 128"):
-        jax.jit(_attend).lower(*_qkv((2, 1000, 16, 128)))
+        jax.jit(_attend).lower(*_qkv(topo, (2, 1000, 16, 128)))
 
 
-def test_flash_step_compiles_on_four_chips():
+def test_flash_step_compiles_on_four_chips(topo):
     """The gpt-1.3b train step, attn_impl='flash', on an fsdp=2 x tp=2
     mesh. Before the kernels ran under shard_map this failed in under a
     second: "Mosaic kernels cannot be automatically partitioned"."""
     mesh = build_mesh(MeshConfig(dp=1, fsdp=2, tp=2),
-                      devices=_TOPO.devices)
+                      devices=topo.devices)
     cfg = gpt.config("gpt-1.3b", max_seq_len=1024, attn_impl="flash",
                      remat_policy="full", loss_chunk=4096,
                      param_dtype=jnp.bfloat16)

@@ -1,0 +1,319 @@
+"""models/deepseek.py (latent attention, the dropless expert layer of
+ops/moe.py) against a copy of the benchmark's plain reference, the flash
+kernels with two head sizes, and the train step typed to no model.
+
+Everything runs on the CPU at tiny widths in float32 under the highest
+matmul precision, where both sides compute the same sums in another order:
+tolerances of 1e-4 (relative, on gradients: of a leaf's norm) leave room for
+float32 reassociation across a few hundred terms and nothing else. On the
+chip the program runs in bfloat16 and routes some tokens differently: that is
+measured there (benchmark/check_routing.py), not here.
+"""
+
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import reference_deepseek_v3 as reference
+from ray_tpu.models import deepseek, gpt
+from ray_tpu.ops import moe
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.parallel import MeshConfig, build_mesh
+from ray_tpu.parallel import mesh as mesh_mod
+from ray_tpu.parallel.train_step import init_train_state, make_train_step
+
+CFG = deepseek.config("deepseek-tiny")
+PUBLISHED = {"qk_nope_head_dim": CFG.qk_nope_head_dim,
+             "kv_lora_rank": CFG.kv_lora_rank, "rope_theta": CFG.rope_theta,
+             "num_experts_per_tok": CFG.num_experts_per_tok,
+             "routed_scaling_factor": CFG.routed_scaling_factor,
+             "norm_topk_prob": CFG.norm_topk_prob,
+             "rms_norm_eps": CFG.rms_norm_eps}
+SEQ = 128
+
+
+def _params(seed=0, bias=None):
+    """Seeded weights with every RMSNorm scale drawn around one and the
+    correction bias drawn (or given): a dropped vector or a bias that
+    reached the weights would show."""
+    params = deepseek.init(CFG, jax.random.PRNGKey(seed))
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+
+    def draw(name, leaf):
+        if name == "router_bias":
+            return 0.3 * jax.random.normal(next(keys), leaf.shape) \
+                if bias is None else jnp.broadcast_to(bias, leaf.shape)
+        if name.endswith("_scale"):
+            return leaf + 0.1 * jax.random.normal(next(keys), leaf.shape)
+        return leaf
+
+    return {k: {n: draw(n, a) for n, a in v.items()} if isinstance(v, dict)
+            else draw(k, v) for k, v in params.items()}
+
+
+def _batch(seed=0, n_seq=2):
+    rows = np.random.default_rng(seed).integers(
+        0, CFG.vocab_size, (n_seq, SEQ + 1), dtype=np.int32)
+    return jnp.asarray(rows[:, :-1]), jnp.asarray(rows[:, 1:])
+
+
+def _reference_forward(params, tokens, targets):
+    where = jnp.broadcast_to(jnp.arange(SEQ, dtype=jnp.int32), tokens.shape)
+    return reference.forward(params, tokens, targets, where,
+                             with_picked=True, **reference.arguments(PUBLISHED))
+
+
+@pytest.mark.parametrize("what", ["logits", "loss_per_sequence", "picked"])
+def test_program_matches_reference_forward(what):
+    params, (tokens, targets) = _params(), _batch()
+    want_logits, want_loss, _, want_picked = _reference_forward(
+        params, tokens, targets)
+    with jax.default_matmul_precision("highest"):
+        if what == "logits":
+            got = deepseek.forward(params, CFG, tokens)
+            np.testing.assert_allclose(got, want_logits, atol=1e-5)
+        elif what == "picked":
+            _, aux = deepseek.forward_with_aux(params, CFG, tokens)
+            np.testing.assert_array_equal(np.sort(aux["picked"], -1),
+                                          np.sort(want_picked, -1))
+        else:
+            for row in range(tokens.shape[0]):
+                mask = jnp.zeros(tokens.shape).at[row].set(1.0)
+                got = deepseek.loss_fn(params, CFG, tokens, targets, mask)[0]
+                np.testing.assert_allclose(got, want_loss[row], rtol=1e-5)
+
+
+def _grad_errors(got, want):
+    return {jax.tree_util.keystr(path): float(
+        jnp.linalg.norm((g - w).ravel())
+        / jnp.maximum(jnp.linalg.norm(w.ravel()), 1e-30))
+        for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want),
+                                jax.tree.leaves(got))}
+
+
+@pytest.mark.parametrize("variant", ["dot", "flash_remat_chunked"])
+def test_program_gradients_match_reference_per_leaf(variant):
+    """Every leaf, the correction bias among them (no gradient on either
+    side). The second variant is the chip's recipe: flash kernels
+    (interpreted), full remat, the chunked loss."""
+    cfg = CFG if variant == "dot" else deepseek.config(
+        "deepseek-tiny", attn_impl="flash", remat=True, loss_chunk=64)
+    params, (tokens, targets) = _params(), _batch()
+    want = jax.grad(lambda p: reference.loss(
+        p, tokens, targets, **reference.arguments(PUBLISHED)))(params)
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(lambda p: deepseek.loss_fn(
+            p, cfg, tokens, targets)[0])(params)
+    for stack in ("moe_layers",):
+        assert not np.any(got[stack]["router_bias"])
+        assert not np.any(want[stack]["router_bias"])
+    errors = {k: v for k, v in _grad_errors(got, want).items()
+              if "router_bias" not in k}
+    assert max(errors.values()) < 1e-4, errors
+
+
+def test_skewed_bias_drops_nothing_and_builds_no_capacity_tensor():
+    """One expert's bias far above the others: it takes every token, the
+    busiest expert has E / K (>= 3) times the mean load, every assignment is
+    still computed, and the result is still the reference's."""
+    bias = jnp.zeros((CFG.n_routed_experts,)).at[5].set(4.0)
+    params, (tokens, targets) = _params(bias=bias), _batch()
+    asked = tokens.size * CFG.num_experts_per_tok * CFG.n_moe_layers
+    with jax.default_matmul_precision("highest"):
+        logits, aux = deepseek.forward_with_aux(params, CFG, tokens)
+        _, metrics = deepseek.loss_fn(params, CFG, tokens, targets)
+    assert int(aux["group_sizes"].sum()) == asked
+    assert float(metrics["moe_assignments"]) == asked == \
+        float(metrics["moe_tokens"])
+    assert float(metrics["moe_load_max_over_mean"]) >= 2.6  # E / K = 8 / 3
+    assert (aux["group_sizes"][:, 5] == tokens.size).all()
+    want_logits, _, _, _ = _reference_forward(params, tokens, targets)
+    np.testing.assert_allclose(logits, want_logits, atol=1e-5)
+
+    # No intermediate of tokens x experts x anything: the largest arrays of
+    # the layer are the tokens x K rows, and nothing has rank above 2.
+    layer = {k: v[0] for k, v in params["moe_layers"].items()}
+    x = jnp.zeros((tokens.size, CFG.hidden_size))
+    jaxpr = jax.make_jaxpr(lambda x: moe.routed_experts(
+        x, layer["router"], layer["router_bias"], layer["w_gate"],
+        layer["w_up"], layer["w_down"], top_k=CFG.num_experts_per_tok,
+        scaling=1.0)[0])(x)
+    t, e, k = tokens.size, CFG.n_routed_experts, CFG.num_experts_per_tok
+    widest = max(CFG.hidden_size, CFG.moe_intermediate_size)
+
+    def shapes(jaxpr):
+        for eqn in jaxpr.eqns:
+            for var in eqn.outvars:
+                yield tuple(var.aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from shapes(sub)
+
+    for shape in shapes(jaxpr.jaxpr):
+        assert math.prod(shape) <= t * k * widest, shape
+        assert not (len(shape) >= 3 and t in shape and e in shape), shape
+
+
+@pytest.mark.parametrize("sizes", [[100, 0, 300, 112], [512, 0, 0, 0]])
+def test_grouped_matmul_kernels_match_ragged_dot(sizes):
+    """Where the shapes tile, the grouped matmul is the megablox kernels
+    (interpreted here): the same products as ``ragged_dot``, forward and
+    both cotangents, with an empty group and with one group holding all."""
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    rows = jax.random.normal(keys[0], (512, 128))
+    weights = jax.random.normal(keys[1], (4, 128, 256))
+    sizes = jnp.asarray(sizes, jnp.int32)
+
+    def loss(fn, rows, weights):
+        return (fn(rows, weights, sizes) ** 2).sum()
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(partial(loss, moe.grouped_matmul),
+                                 argnums=(0, 1))(rows, weights)
+        want = jax.value_and_grad(partial(loss, jax.lax.ragged_dot),
+                                  argnums=(0, 1))(rows, weights)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-4)
+
+
+def _attention_inputs(seq=512, heads=2, d_qk=192, d_v=128, batch=2):
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(keys[0], (batch, seq, heads, d_qk))
+    k = jax.random.normal(keys[1], (batch, seq, heads, d_qk))
+    v = jax.random.normal(keys[2], (batch, seq, heads, d_v))
+    g = jax.random.normal(keys[3], (batch, seq, heads, d_v))
+    return q, k, v, g
+
+
+@pytest.mark.parametrize("under", ["plain", "shard_map"])
+@pytest.mark.parametrize("what", ["forward", "backward"])
+def test_flash_kernels_two_head_sizes_several_blocks(under, what):
+    """q/k of 192 and v of 128, four 128-row tiles a side (so K/V, and in
+    the dk/dv kernel Q and dO, stream over the grid and causal tiles are
+    skipped), against the dot product; under a CPU mesh the kernels run per
+    shard through the models' one attention dispatch."""
+    q, k, v, g = _attention_inputs()
+    cfg = deepseek.config("deepseek-tiny", attn_impl="flash",
+                          attn_blk_q=128, attn_blk_k=128)
+    mesh = build_mesh(MeshConfig(dp=1, fsdp=2, tp=2),
+                      devices=jax.devices()[:4]) if under == "shard_map" \
+        else None
+
+    def flash(q, k, v):
+        return gpt._attention(q, k, v, cfg)
+
+    def dot(q, k, v):
+        return gpt._dot_attention(q, k, v)
+
+    previous = mesh_mod.current_mesh()
+    mesh_mod.set_current_mesh(mesh)
+    try:
+        with jax.default_matmul_precision("highest"):
+            if what == "forward":
+                got, want = jax.jit(flash)(q, k, v), dot(q, k, v)
+                assert got.shape == (2, 512, 2, 128)
+                np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-4)
+            else:
+                got = jax.jit(lambda *a: jax.vjp(flash, *a)[1](g))(q, k, v)
+                want = jax.vjp(dot, q, k, v)[1](g)
+                for a, b in zip(got, want):
+                    assert a.shape == b.shape
+                    np.testing.assert_allclose(a, b, atol=2e-4, rtol=1e-3)
+    finally:
+        mesh_mod.set_current_mesh(previous)
+
+
+def test_flash_blocks_of_unequal_size():
+    q, k, v, _ = _attention_inputs(seq=512, heads=1, batch=1)
+    with jax.default_matmul_precision("highest"):
+        got = flash_attention(q, k, v, True, 256, 128)
+        grads = jax.grad(lambda *a: (flash_attention(
+            *a, True, 128, 256) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: (gpt._dot_attention(*a) ** 2).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got, gpt._dot_attention(q, k, v), atol=2e-5,
+                               rtol=1e-4)
+    for a, b in zip(grads, want):
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=1e-3)
+
+
+def _one_chip():
+    return build_mesh(MeshConfig(dp=1, fsdp=1, tp=1),
+                      devices=jax.devices()[:1])
+
+
+def _counters():
+    from ray_tpu._private import builtin_metrics as bm
+    return (sum(bm.train_moe_assignments().series().values()),
+            sum(bm.train_moe_tokens().series().values()),
+            sum(bm.train_moe_expert_load().series().values()))
+
+
+@pytest.mark.parametrize("accum_steps", [1, 2])
+def test_train_step_trains_the_new_model_and_feeds_the_counters(accum_steps):
+    mesh = _one_chip()
+    state = init_train_state(CFG, mesh, seed=0, model=deepseek)
+    step = make_train_step(CFG, mesh, accum_steps=accum_steps, model=deepseek)
+    tokens, targets = _batch(n_seq=4)
+    asked = tokens.size * CFG.num_experts_per_tok * CFG.n_moe_layers
+    before = _counters()
+    losses = []
+    for _ in range(4):
+        state, metrics = step(state, {"tokens": tokens, "targets": targets})
+        losses.append(float(metrics["loss"]))
+        assert float(metrics["moe_assignments"]) == asked == \
+            float(metrics["moe_tokens"])
+    assert losses[-1] < losses[0] and all(map(math.isfinite, losses))
+    assigned, tokens_total, load = _counters()
+    # Fed one call late at most: after four blocking steps, three or four.
+    assert assigned - before[0] == tokens_total - before[1]
+    assert assigned - before[0] in (3 * asked, 4 * asked)
+    assert 1.0 <= load <= CFG.n_routed_experts / CFG.num_experts_per_tok
+
+
+@pytest.mark.parametrize("accum_steps,want", [
+    (1, [5.555258750915527, 5.555258750915527, 5.554657936096191]),
+    (2, [5.555259704589844, 5.555259704589844, 5.554657936096191])])
+def test_train_step_on_gpt_tiny_is_unchanged(accum_steps, want):
+    """The losses the step gave before it took a model as an argument
+    (commit 88e3af0, this seed and batch), and the same three metrics."""
+    mesh = _one_chip()
+    cfg = gpt.config("gpt-tiny")
+    state = init_train_state(cfg, mesh, seed=0)
+    step = make_train_step(cfg, mesh, accum_steps=accum_steps)
+    rows = np.random.default_rng(0).integers(0, 256, (4, 65), dtype=np.int32)
+    batch = {"tokens": jnp.asarray(rows[:, :-1]),
+             "targets": jnp.asarray(rows[:, 1:])}
+    got = []
+    for _ in range(3):
+        state, metrics = step(state, batch)
+        got.append(float(metrics["loss"]))
+    assert sorted(metrics) == ["accuracy", "loss", "perplexity"]
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+
+
+def test_expert_parallel_mesh_is_refused():
+    mesh = build_mesh(MeshConfig(dp=1, fsdp=1, tp=1, ep=2),
+                      devices=jax.devices()[:2])
+    step = make_train_step(CFG, mesh, model=deepseek)
+    state = init_train_state(CFG, mesh, seed=0, model=deepseek)
+    tokens, targets = _batch()
+    with pytest.raises(NotImplementedError, match="expert parallelism"):
+        step(state, {"tokens": tokens, "targets": targets})
+
+
+def test_param_specs_match_init_and_count():
+    from ray_tpu.parallel.sharding import ShardingRules
+    params = jax.eval_shape(lambda k: deepseek.init(CFG, k),
+                            jax.random.PRNGKey(0))
+    specs = deepseek.param_specs(CFG, ShardingRules())
+    assert jax.tree.structure(params) == jax.tree.structure(
+        specs, is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
+    full = deepseek.config("moonlight-16b-a3b")
+    shapes = jax.eval_shape(lambda k: deepseek.init(full, k),
+                            jax.random.PRNGKey(0))
+    total = sum(math.prod(a.shape) for a in jax.tree.leaves(shapes))
+    assert 15.9e9 < total < 16.1e9, total  # "16B"
