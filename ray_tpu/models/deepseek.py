@@ -51,7 +51,7 @@ from jax.sharding import PartitionSpec
 
 from ray_tpu.models import gpt as _gpt
 from ray_tpu.ops.moe import routed_experts
-from ray_tpu.parallel.sharding import ShardingRules
+from ray_tpu.parallel.sharding import ShardingRules, constrain
 
 #: Metrics of ``loss_fn`` that count a batch: summed over accumulation
 #: microbatches where the others are averaged (parallel/train_step.py).
@@ -300,10 +300,11 @@ def hidden_states(params: Dict[str, Any], cfg: DeepseekConfig,
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = jnp.take(params["wte"], tokens, axis=0).astype(cfg.dtype)
+    x = _gpt.embed(params["wte"], tokens, cfg.dtype)  # batch-split
     block = partial(_block, cfg)
     x, _ = _gpt.scan_blocks(cfg, block, x, params["dense_layers"], positions)
     x, aux = _gpt.scan_blocks(cfg, block, x, params["moe_layers"], positions)
+    x = constrain(x, "batch", "sequence", None)
     return _rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps), aux
 
 
@@ -337,8 +338,9 @@ def loss_fn(params: Dict[str, Any], cfg: DeepseekConfig, tokens: jax.Array,
     mask32 = jnp.ones(tokens.shape, jnp.float32) if mask is None \
         else mask.astype(jnp.float32)
     denom = jnp.maximum(mask32.sum(), 1.0)
-    nll_sum, hit_sum = _gpt.chunked_ce(partial(_head, params, cfg), x,
-                                       targets, mask32, cfg.loss_chunk)
+    head = partial(_head, _gpt.head_gathered(params, tied=False), cfg)
+    nll_sum, hit_sum = _gpt.chunked_ce(head, x, targets, mask32,
+                                       cfg.loss_chunk)
     loss = nll_sum / denom
     sizes = aux["group_sizes"].astype(jnp.float32)  # [L_moe, E]
     return loss, {

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec
 
-from ray_tpu.parallel.sharding import ShardingRules
+from ray_tpu.parallel.sharding import ShardingRules, ambient_spec, constrain
 
 
 @dataclass(frozen=True)
@@ -306,15 +306,17 @@ def _dot_attention(q, k, v):
 
 
 def _attention_specs(mesh, n_heads: int, n_kv_heads: int, seq_axis):
-    """shard_map specs for [B, S, H, D] activations: batch over (dp, fsdp),
-    sequence over ``seq_axis``, heads over tp. A head count tp does not
-    divide (GQA/MQA KV heads) stays replicated, and the per-shard op must
-    then bridge sharded-q / replicated-kv heads itself (ring_attention's
+    """shard_map specs for [B, S, H, D] activations: batch as the rules
+    have it (where ``hidden_states`` puts the residual stream), sequence
+    over ``seq_axis``, heads over tp. A head count tp does not divide
+    (GQA/MQA KV heads) stays replicated, and the per-shard op must then
+    bridge sharded-q / replicated-kv heads itself (ring_attention's
     _repeat_kv does)."""
     tp = dict(zip(mesh.axis_names, mesh.devices.shape)).get("tp", 1)
-    q_spec = PartitionSpec(("dp", "fsdp"), seq_axis,
+    batch = ambient_spec(mesh, "batch")[0]
+    q_spec = PartitionSpec(batch, seq_axis,
                            "tp" if n_heads % tp == 0 else None, None)
-    kv_spec = PartitionSpec(("dp", "fsdp"), seq_axis,
+    kv_spec = PartitionSpec(batch, seq_axis,
                             "tp" if n_kv_heads % tp == 0 else None, None)
     return q_spec, kv_spec
 
@@ -455,11 +457,14 @@ def _block(cfg: GPTConfig, x, layer, positions):
             ff = jax.nn.gelu(ff + layer["b_in"].astype(dt))
             mlp_out = jnp.einsum("bsf,fd->bsd", ff,
                                  layer["w_out"].astype(dt))
-        mlp_out = mlp_out + layer["b_out"].astype(dt)
 
+    b_out = layer["b_out"].astype(dt)
     if cfg.parallel_block:
-        return x + attn_out + mlp_out, aux
-    return x + mlp_out, aux
+        # Under tp both products are partial sums. Added to each other
+        # before anything else they are reduced over tp together: one
+        # all-reduce of [B, S, d] a layer, not one each.
+        return x + ((attn_out + mlp_out) + b_out), aux
+    return x + (mlp_out + b_out), aux
 
 
 def scan_blocks(cfg, block, x, layers, positions):
@@ -487,6 +492,21 @@ def scan_blocks(cfg, block, x, layers, positions):
     return jax.lax.scan(scan_body, x, layers)
 
 
+def embed(wte, tokens, dtype):
+    """wte[tokens] in ``dtype``, batch-split: the residual stream is split
+    by batch (and sequence, under context parallelism) from the lookup to
+    the loss and says so at both ends of the layer scan; derived from the
+    weights it would be d over fsdp, resharded wherever an operation wants
+    the batch split. The lookup itself leaves d split as the table has it.
+    Stated so first, rows are cut where they lie, and the change that
+    follows is one all-to-all over those axes (from the lookup's own
+    layout the partitioner can only replicate x whole when dp and fsdp
+    are both above 1). Shared by every language model of this package."""
+    x = jnp.take(wte, tokens, axis=0).astype(dtype)
+    x = constrain(x, "batch", "sequence", "embed")
+    return constrain(x, "batch", "sequence", None)
+
+
 def hidden_states(params: Dict[str, Any], cfg: GPTConfig,
                   tokens: jax.Array,
                   positions: Optional[jax.Array] = None):
@@ -494,9 +514,10 @@ def hidden_states(params: Dict[str, Any], cfg: GPTConfig,
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = jnp.take(params["wte"], tokens, axis=0).astype(cfg.dtype)
+    x = embed(params["wte"], tokens, cfg.dtype)
     x, aux = scan_blocks(cfg, partial(_block, cfg), x, params["layers"],
                          positions)
+    x = constrain(x, "batch", "sequence", None)
     x = _layernorm(x, params["lnf_scale"], params["lnf_bias"],
                    cfg.layernorm_eps)
     return x, aux.sum()
@@ -544,33 +565,53 @@ def chunked_ce(head, x: jax.Array, targets: jax.Array, mask32: jax.Array,
                ) -> Tuple[jax.Array, jax.Array]:
     """(Σ nll·mask, Σ hit·mask) of ``head(x)`` against targets, in fp32.
 
-    ``head`` maps hidden [..., d] to logits [..., vocab]. With ``chunk > 0``
-    the head matmul and the fp32 softmax run ``chunk`` tokens at a time
-    under a rematerialised lax.scan, so the [tokens, vocab] fp32 logits
-    never exist whole. Shared by every language model of this package."""
+    ``head`` maps hidden [..., d] to logits [..., vocab]; x is [B, S, d].
+    With ``chunk > 0`` the head matmul and the fp32 softmax run ``chunk``
+    tokens at a time under a rematerialised lax.scan, so the [tokens, vocab]
+    fp32 logits never exist whole. A chunk is a slice of S across the whole
+    batch, [B, S / n, d]: the scanned dimension is not the one the batch's
+    sharding lies on, so every data shard walks its own tokens and no chip
+    sees another's (chunks of whole rows put the sharding on the scanned
+    dimension, and the partitioner then splits d and sums every chunk's
+    logits instead). Shared by every language model of this package."""
     with jax.named_scope("head_loss"):
-        T = targets.size
-        if chunk and T % chunk and T > chunk:
-            # Requested chunk doesn't divide the token count: use the largest
-            # divisor <= chunk rather than silently materializing full logits
-            # (defeating the feature's memory bound).
-            chunk = max(c for c in range(1, chunk + 1) if T % c == 0)
-        if not (chunk and T > chunk):
+        B, S = targets.shape
+        if not (chunk and B * S > chunk):
             return _ce_stats(head(x), targets, mask32, z_loss)
-        d = x.shape[-1]
-        xf = x.reshape(T // chunk, chunk, d)
-        tf = targets.reshape(T // chunk, chunk)
-        mf = mask32.reshape(T // chunk, chunk)
+        # The fewest slices of S that hold at most ``chunk`` tokens each:
+        # where ``chunk`` does not divide, the largest slice under it that
+        # does, never the whole logits (the feature's memory bound stands).
+        n = next((n for n in range(2, S)
+                  if S % n == 0 and B * S // n <= chunk), S)
+
+        def slices(a):
+            a = a.reshape(B, n, S // n, *a.shape[2:]).swapaxes(0, 1)
+            return constrain(a, None, "batch", "sequence",
+                             *[None] * (a.ndim - 3))
 
         @jax.checkpoint
         def chunk_stats(carry, xtm):
-            x_c, t_c, m_c = xtm
+            # One row of tokens: [B * S / n, ...], as head and loss see it
+            # on one device too.
+            x_c, t_c, m_c = (a.reshape(-1, *a.shape[2:]) for a in xtm)
             nll_sum, hit_sum = _ce_stats(head(x_c), t_c, m_c, z_loss)
             return (carry[0] + nll_sum, carry[1] + hit_sum), None
 
         sums, _ = jax.lax.scan(
-            chunk_stats, (jnp.zeros((), jnp.float32),) * 2, (xf, tf, mf))
+            chunk_stats, (jnp.zeros((), jnp.float32),) * 2,
+            (slices(x), slices(targets), slices(mask32)))
         return sums
+
+
+def head_gathered(params: Dict[str, Any], tied: bool) -> Dict[str, Any]:
+    """params with the head's weight (``wte`` if ``tied``, else ``lm_head``)
+    whole along d and split over the vocabulary alone: stated before the
+    chunk loop, it is gathered over fsdp once a step and its gradient
+    summed over the chunks before it is reduced, once; left to the
+    partitioner both happen in every chunk."""
+    if tied:
+        return dict(params, wte=constrain(params["wte"], "vocab", None))
+    return dict(params, lm_head=constrain(params["lm_head"], None, "vocab"))
 
 
 def loss_fn(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
@@ -587,8 +628,9 @@ def loss_fn(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
     else:
         mask32 = mask.astype(jnp.float32)
     denom = jnp.maximum(mask32.sum(), 1.0)
-    nll_sum, hit_sum = chunked_ce(partial(_head, params, cfg), x, targets,
-                                  mask32, cfg.loss_chunk, z_loss)
+    head = partial(_head, head_gathered(params, cfg.tie_embeddings), cfg)
+    nll_sum, hit_sum = chunked_ce(head, x, targets, mask32, cfg.loss_chunk,
+                                  z_loss)
 
     ce = nll_sum / denom
     loss = ce

@@ -166,18 +166,26 @@ def single_device_mesh():
 
 # -- current-mesh registry ----------------------------------------------
 # Ops that need an explicit shard_map (the flash kernels, ring attention)
-# read the ambient mesh here; make_train_step / user code set it. A
-# registry rather than a parameter because the mesh must be static at
-# trace time while model code only receives (params, cfg, batch). Tracing
-# runs on the calling thread, so the registry is per thread: two actors
-# stepping over different chips in one process never see each other's mesh.
+# read the ambient mesh here, and a model reads the sharding rules beside it
+# to state where its activations live (sharding.constrain);
+# make_train_step / user code set them. A registry rather than a parameter
+# because both must be static at trace time while model code only receives
+# (params, cfg, batch). Tracing runs on the calling thread, so the registry
+# is per thread: two actors stepping over different chips in one process
+# never see each other's mesh.
 
 _current = threading.local()
 
 
-def set_current_mesh(mesh) -> None:
+def set_current_mesh(mesh, rules=None) -> None:
+    """``rules``: the step's ShardingRules (None: the default table)."""
     _current.mesh = mesh
+    _current.rules = rules
 
 
 def current_mesh():
     return getattr(_current, "mesh", None)
+
+
+def current_rules():
+    return getattr(_current, "rules", None)

@@ -134,6 +134,40 @@ def slices_overlap(a, b):
     return tuple(out)
 
 
+# Activations -----------------------------------------------------------
+# Parameters get their sharding from the rules when the state is built. An
+# activation gets one only where the model states it: left alone, the
+# partitioner derives it from the weights (``embed -> fsdp`` splits the
+# hidden states' d), and reshards at every operation that wants the batch
+# split instead. So a model states it at the lookup, at the layer scan's
+# exit and in the loss, through these two.
+
+def ambient_spec(mesh, *logical_axes: Optional[str]) -> PartitionSpec:
+    """The spec of logical axes under the rules registered beside the
+    current mesh (``mesh.set_current_mesh``; the default table if none
+    were), with the mesh axes ``mesh`` lacks left out. A mesh axis that two
+    dimensions name stays with the later one: ("batch", "embed") is batch
+    over dp and d over fsdp, as a lookup in an fsdp-split table leaves it."""
+    from ray_tpu.parallel.mesh import current_rules
+    spec = (current_rules() or ShardingRules()).spec(*logical_axes)
+    free, dims = set(mesh.axis_names), []
+    for dim in reversed(spec):
+        dims.append(tuple(a for a in _spec_dim_axes(dim) if a in free))
+        free -= set(dims[-1])
+    return PartitionSpec(*(dim or None for dim in reversed(dims)))
+
+
+def constrain(x, *logical_axes: Optional[str]):
+    """``x`` with its sharding stated in logical axes, under the mesh of
+    the step being traced. Nothing without a mesh, or on one device."""
+    from ray_tpu.parallel.mesh import current_mesh
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, ambient_spec(mesh, *logical_axes)))
+
+
 # Helpers ---------------------------------------------------------------
 
 def named_sharding(mesh, spec: PartitionSpec) -> NamedSharding:

@@ -145,8 +145,9 @@ def abstract_train_state(cfg: Any, mesh,
         shapes, shardings)
 
 
-def _with_mesh_registered(jitted, mesh, after_call=None):
-    """Register ``mesh`` as the current mesh around every call, not once at
+def _with_mesh_registered(jitted, mesh, rules, after_call=None):
+    """Register ``mesh`` (and the step's ``rules``, by which a model states
+    its activations' sharding) as current around every call, not once at
     build time: jit traces lazily (first call / new shapes), so the registry
     must hold THIS step's mesh whenever a trace may happen — two steps built
     over different meshes would otherwise trace against the wrong one. The
@@ -156,12 +157,12 @@ def _with_mesh_registered(jitted, mesh, after_call=None):
     def under_mesh(fn, then=None):
         @functools.wraps(fn)
         def call(*args, **kwargs):
-            previous = mesh_mod.current_mesh()
-            mesh_mod.set_current_mesh(mesh)
+            previous = mesh_mod.current_mesh(), mesh_mod.current_rules()
+            mesh_mod.set_current_mesh(mesh, rules)
             try:
                 out = fn(*args, **kwargs)
             finally:
-                mesh_mod.set_current_mesh(previous)
+                mesh_mod.set_current_mesh(*previous)
             if then is not None:
                 then(out)
             return out
@@ -267,7 +268,7 @@ def make_train_step(cfg: Any, mesh,
     _, shardings = _state_layout(cfg, mesh, rules, optimizer, model)
     return _with_mesh_registered(
         jax.jit(step, donate_argnums=(0,), out_shardings=(shardings, None)),
-        mesh, after_call=_MoeCounters())
+        mesh, rules, after_call=_MoeCounters())
 
 
 def make_eval_step(cfg: Any, mesh,
@@ -288,4 +289,4 @@ def make_eval_step(cfg: Any, mesh,
                                    batch["targets"], batch.get("mask"))
         return metrics
 
-    return _with_mesh_registered(jax.jit(step), mesh)
+    return _with_mesh_registered(jax.jit(step), mesh, rules)

@@ -198,3 +198,61 @@ def test_flash_step_compiles_on_four_chips(topo):
     mem = compiled.memory_analysis()
     per_device = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert per_device < 16 * 2 ** 30, per_device
+
+
+@pytest.mark.parametrize("parallel_block", [True, False])
+def test_step_sends_what_fsdp_x_tp_needs(topo, parallel_block):
+    """The GPT-J step (every width as published, two layers: the scan's
+    body is what depth repeats) on fsdp=2 x tp=2, read by census: the
+    model states where its activations live, so the partitioner emits the
+    layout's own traffic and no more. A parallel block reduces its two
+    tp-partial products together; the head and loss run on each data
+    shard's own tokens, so nothing as wide as the vocabulary crosses chips
+    inside the chunk loop; the hidden states are never resharded."""
+    from ray_tpu.parallel.collectives import census
+    mesh = build_mesh(MeshConfig(dp=1, fsdp=2, tp=2), devices=topo.devices)
+    cfg = gpt.config("gptj-6b", n_layers=2, attn_impl="flash",
+                     remat_policy="full", loss_chunk=4096,
+                     param_dtype=jnp.bfloat16, parallel_block=parallel_block)
+    batch, fsdp, tp = 16, 2, 2
+    rules = ShardingRules()
+    optimizer = memory_efficient_optimizer(learning_rate=1e-4)
+    state = abstract_train_state(cfg, mesh, rules, optimizer)
+    tokens = jax.ShapeDtypeStruct(
+        (batch, cfg.max_seq_len), jnp.int32,
+        sharding=NamedSharding(mesh, PartitionSpec(("dp", "fsdp"), None)))
+    ops = census(make_train_step(cfg, mesh, rules, optimizer).lower(
+        state, {"tokens": tokens, "targets": tokens}).compile().as_text())
+
+    def dims(op):
+        return [d for _, d in op["arrays"]]
+
+    hidden = (batch // fsdp, cfg.max_seq_len, cfg.d_model)
+    reduced = [op for op in ops if op["kind"] == "all-reduce"
+               and op["in_loop"] and dims(op) == [hidden]]
+    forward = [op for op in reduced if "transpose(" not in op["op_name"]]
+    backward = len(reduced) - len(forward)
+    if parallel_block:
+        assert (len(forward), backward) == (1, 1), reduced
+    else:  # it needs x + attention before the second norm: the recomputed
+        # forward's reduction too, beside the backward's two
+        assert len(forward) == 2 and backward >= 2, reduced
+
+    exchanged = [op for op in ops if op["kind"] == "all-to-all"]
+    assert len(exchanged) <= 2, exchanged  # the wte lookup and its scatter
+    assert all(dtype == "bf16" for op in exchanged
+               for dtype, _ in op["arrays"]), exchanged
+
+    vocab = cfg.vocab_size // tp
+
+    def wide(op):
+        return any(vocab in d for d in dims(op))
+
+    assert not [op for op in ops if op["in_loop"] and wide(op)]
+    head = (cfg.d_model, vocab)
+    gathered = [op for op in ops if op["kind"] == "all-gather"
+                and dims(op) == [head]]
+    summed = [op for op in ops
+              if op["kind"] in ("all-reduce", "reduce-scatter") and wide(op)
+              and len(dims(op)[0]) == 2]
+    assert len(gathered) == 1 and len(summed) == 1, (gathered, summed)

@@ -225,3 +225,184 @@ def test_multi_slice_mesh_runs_train_step():
     }
     _, metrics = step(state, batch)
     assert np.isfinite(float(metrics["loss"]))
+
+
+# -- what the model states about its activations (sharding.constrain) ----
+
+def _loss_and_grads(cfg, mesh_cfg, n_devices, params, tokens, targets, mask):
+    """gpt.loss_fn's loss and gradients, traced under a mesh and its rules
+    as a train step traces it."""
+    from ray_tpu.parallel import mesh as mesh_mod, shard_tree
+    mesh = build_mesh(mesh_cfg, devices=jax.devices()[:n_devices])
+    rules = ShardingRules()
+    params = shard_tree(params, mesh, gpt.param_specs(cfg, rules))
+    fn = jax.jit(jax.value_and_grad(
+        lambda p: gpt.loss_fn(p, cfg, tokens, targets, mask)[0]))
+    previous = mesh_mod.current_mesh(), mesh_mod.current_rules()
+    mesh_mod.set_current_mesh(mesh, rules)
+    try:
+        return jax.device_get(fn(params))
+    finally:
+        mesh_mod.set_current_mesh(*previous)
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("loss_chunk", [64, 100])  # 100 does not divide
+@pytest.mark.parametrize("parallel_block", [True, False])
+def test_fsdp_x_tp_matches_one_device(parallel_block, loss_chunk, masked):
+    """The reordered sum of the parallel block, the chunks cut inside each
+    data shard, the gathered head and the stated hidden states are the
+    same numbers in another order: loss and every gradient leaf on
+    fsdp=2 x tp=2 are the one-device values."""
+    cfg = gpt.config("gpt-tiny", parallel_block=parallel_block,
+                     loss_chunk=loss_chunk)
+    params = gpt.init(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(3)
+    tokens = jnp.asarray(rng.integers(0, 256, (4, 32)), jnp.int32)
+    targets = jnp.asarray(rng.integers(0, 256, (4, 32)), jnp.int32)
+    mask = jnp.asarray(rng.integers(0, 2, (4, 32)), jnp.float32) \
+        if masked else None
+    one_loss, one_grads = _loss_and_grads(
+        cfg, MeshConfig(dp=1, fsdp=1, tp=1), 1, params, tokens, targets, mask)
+    loss, grads = _loss_and_grads(
+        cfg, MeshConfig(dp=1, fsdp=2, tp=2), 4, params, tokens, targets, mask)
+    assert float(loss) == pytest.approx(float(one_loss), rel=1e-4)
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                 jax.tree.leaves(one_grads)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("chunk", [0, 3, 64, 100, 128, 4096])
+def test_chunked_ce_equals_the_unchunked_loss(chunk):
+    """Whatever the chunk: none, fewer tokens than rows (one position a
+    slice), a divisor, a non-divisor, one slice, more than there is."""
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(size=(4, 32, 16)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(16, 50)), jnp.float32)
+    targets = jnp.asarray(rng.integers(0, 50, (4, 32)), jnp.int32)
+    mask = jnp.asarray(rng.integers(0, 2, (4, 32)), jnp.float32)
+
+    def sums(x, w, chunk):
+        return gpt.chunked_ce(lambda h: h @ w, x, targets, mask, chunk,
+                              z_loss=1e-4)
+
+    want = gpt._ce_stats(x @ w, targets, mask, 1e-4)
+    np.testing.assert_allclose(sums(x, w, chunk), want, rtol=1e-5)
+    got_g = jax.grad(lambda x, w: sums(x, w, chunk)[0], (0, 1))(x, w)
+    want_g = jax.grad(lambda x, w: sums(x, w, 0)[0], (0, 1))(x, w)
+    for got, wanted in zip(got_g, want_g):
+        np.testing.assert_allclose(got, wanted, rtol=1e-4, atol=1e-5)
+
+
+def test_constrain_is_nothing_without_a_mesh_or_on_one_device():
+    from ray_tpu.parallel import mesh as mesh_mod
+    from ray_tpu.parallel.sharding import ambient_spec, constrain
+    x = jnp.ones((4, 8, 2))
+    assert constrain(x, "batch", "sequence", None) is x
+    one = build_mesh(MeshConfig(dp=1, fsdp=1, tp=1),
+                     devices=jax.devices()[:1])
+    four = build_mesh(MeshConfig(dp=1, fsdp=2, tp=1, sp=2),
+                      devices=jax.devices()[:4])
+    previous = mesh_mod.current_mesh(), mesh_mod.current_rules()
+    try:
+        mesh_mod.set_current_mesh(one)
+        assert constrain(x, "batch", "sequence", None) is x
+        # The rules registered beside the mesh decide; none: the defaults.
+        mesh_mod.set_current_mesh(four)
+        assert ambient_spec(four, "batch", "sequence", None) == \
+            jax.sharding.PartitionSpec(("dp", "fsdp"), None, None)
+        mesh_mod.set_current_mesh(four, ShardingRules(sequence="sp"))
+        y = jax.jit(lambda a: constrain(a, "batch", "sequence", None))(x)
+        assert y.sharding.is_equivalent_to(jax.sharding.NamedSharding(
+            four, jax.sharding.PartitionSpec(("dp", "fsdp"), "sp", None)),
+            x.ndim)
+        # A mesh built by hand may lack axes the rules name.
+        bare = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("fsdp",))
+        assert ambient_spec(bare, "batch", "heads") == \
+            jax.sharding.PartitionSpec(("fsdp",), None)
+    finally:
+        mesh_mod.set_current_mesh(*previous)
+
+
+# What the TPU compiler prints, cut to what census reads: a synchronous
+# all-reduce in a loop's body, an asynchronous all-gather whose start,
+# carry and finish are three fusions repeating it under one channel_id, a
+# variadic all-to-all in ENTRY, and the -start/-done form of other backends.
+CENSUS_HLO = """\
+HloModule jit_step
+
+%add.1 (x: f32[], y: f32[]) -> f32[] {
+  %x = f32[] parameter(0)
+  %y = f32[] parameter(1)
+  ROOT %add.2 = f32[] add(%x, %y)
+}
+
+%fused_start (p: bf16[2048,256]) -> (bf16[2048,256], bf16[4096,256]) {
+  %p = bf16[2048,256]{1,0:T(8,128)(2,1)} parameter(0)
+  %all-gather.1 = bf16[4096,256]{1,0:T(8,128)(2,1)} all-gather(%p), channel_id=7, replica_groups=[2,2]<=[2,2]T(1,0), dimensions={0}, use_global_device_ids=true, metadata={op_name="jit(step)/jvp()/while/body/block/attention/dot_general"}
+  ROOT %custom-call.1 = (bf16[2048,256]{1,0}, bf16[4096,256]{1,0}) custom-call(%all-gather.1), custom_call_target="AsyncCollectiveStart"
+}
+
+%fused_done (p.1: bf16[2048,256]) -> bf16[4096,256] {
+  %p.1 = bf16[2048,256]{1,0} parameter(0)
+  %all-gather.2 = bf16[4096,256]{1,0} all-gather(%p.1), channel_id=7, replica_groups=[2,2]<=[2,2]T(1,0), dimensions={0}, use_global_device_ids=true
+  ROOT %custom-call.2 = bf16[4096,256]{1,0} custom-call(%all-gather.2), custom_call_target="AsyncCollectiveDone"
+}
+
+%body (carry: (s32[], bf16[8,128,256])) -> (s32[], bf16[8,128,256]) {
+  %carry = (s32[], bf16[8,128,256]{2,1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%carry), index=0
+  %h = bf16[8,128,256]{2,1,0} get-tuple-element(%carry), index=1
+  %w = bf16[2048,256]{1,0} constant(0)
+  %start = (bf16[2048,256]{1,0}, bf16[4096,256]{1,0}) fusion(%w), kind=kCustom, calls=%fused_start
+  %done = bf16[4096,256]{1,0} fusion(%w), kind=kCustom, calls=%fused_done
+  %all-reduce.3 = bf16[8,128,256]{2,1,0:T(8,128)(2,1)} all-reduce(%h), channel_id=9, replica_groups={{0,1},{2,3}}, use_global_device_ids=true, to_apply=%add.1, metadata={op_name="jit(step)/jvp()/while/body/block/mlp/dot_general"}
+  ROOT %tuple.1 = (s32[], bf16[8,128,256]{2,1,0}) tuple(%i, %all-reduce.3)
+}
+
+%cond (carry.1: (s32[], bf16[8,128,256])) -> pred[] {
+  %carry.1 = (s32[], bf16[8,128,256]{2,1,0}) parameter(0)
+  ROOT %lt = pred[] constant(true)
+}
+
+ENTRY %main (a: bf16[8,128,256], b: f32[8,128,256]) -> bf16[8,128,256] {
+  %a = bf16[8,128,256]{2,1,0} parameter(0)
+  %b = f32[8,128,256]{2,1,0} parameter(1)
+  %zero = s32[] constant(0)
+  %init = (s32[], bf16[8,128,256]{2,1,0}) tuple(%zero, %a)
+  %while.1 = (s32[], bf16[8,128,256]{2,1,0}) while(%init), condition=%cond, body=%body
+  %all-to-all.4 = (f32[4,128,256]{2,1,0}, /*index=1*/f32[4,128,256]{2,1,0}) all-to-all(%b, %b), channel_id=11, replica_groups={{0,2},{1,3}}
+  %all-gather-start.5 = (bf16[8,128,256]{2,1,0}, bf16[32,128,256]{2,1,0}) all-gather-start(%a), channel_id=12, replica_groups=[1,4]<=[4], dimensions={0}
+  %all-gather-done.5 = bf16[32,128,256]{2,1,0} all-gather-done(%all-gather-start.5)
+  %collective-permute.6 = bf16[8,128,256]{2,1,0} collective-permute(%a), channel_id=13, source_target_pairs={{0,1},{1,0}}
+  ROOT %out = bf16[8,128,256]{2,1,0} get-tuple-element(%while.1), index=1
+}
+"""
+
+
+def test_census_reads_hlo_text():
+    from ray_tpu.parallel.collectives import census
+    ops = {op["name"]: op for op in census(CENSUS_HLO)}
+    # One per collective: the fusions' repeat and the -done are not others.
+    assert sorted(ops) == ["all-gather-start.5", "all-gather.1",
+                           "all-reduce.3", "all-to-all.4",
+                           "collective-permute.6"]
+    inner = ops["all-gather.1"]  # inside a fusion the loop's body calls
+    assert (inner["kind"], inner["computation"], inner["in_loop"]) == \
+        ("all-gather", "body", True)
+    assert inner["bytes"] == 4096 * 256 * 2 and inner["group_size"] == 2
+    assert inner["op_name"].endswith("attention/dot_general")
+    reduced = ops["all-reduce.3"]
+    assert reduced["arrays"] == [("bf16", (8, 128, 256))]
+    assert reduced["in_loop"] and reduced["group_size"] == 2
+    exchanged = ops["all-to-all.4"]  # a tuple: its arrays summed
+    assert exchanged["bytes"] == 2 * 4 * 128 * 256 * 4
+    assert [dtype for dtype, _ in exchanged["arrays"]] == ["f32", "f32"]
+    assert (exchanged["computation"], exchanged["in_loop"]) == \
+        ("main", False)
+    started = ops["all-gather-start.5"]  # the result's half of the pair
+    assert (started["kind"], started["bytes"], started["group_size"]) == \
+        ("all-gather", 32 * 128 * 256 * 2, 4)
+    assert ops["collective-permute.6"]["group_size"] == 2
+    assert ops["collective-permute.6"]["op_name"] == ""
