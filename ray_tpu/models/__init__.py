@@ -1,3 +1,3 @@
-from ray_tpu.models import bert, diffusion, gpt, llama, t5, vit
+from ray_tpu.models import bert, deepseek, diffusion, gpt, lm, t5, vit
 
-__all__ = ["bert", "diffusion", "gpt", "llama", "t5", "vit"]
+__all__ = ["bert", "deepseek", "diffusion", "gpt", "lm", "t5", "vit"]

@@ -28,13 +28,14 @@ with 2i+1 as published and, as published, leaves the rotated halves
 de-interleaved (all first members, then all second): q and k alike, so
 scores are unchanged.
 
-What is shared with ``models/gpt.py`` and lives there: the layer scan with
-remat (``scan_blocks``), the chunked head and loss (``chunked_ce``), the
-attention dispatch (``_attention``: dot, or the flash kernels with two head
-sizes). The expert layer is ``ops/moe.py``. Every assignment is computed:
-no capacity, no drop. The correction bias ``b`` steers selection only and is
-not trained by the gradient (its gradient is zero; the published update
-rule's step size is not in the config, so no rule moves it here either).
+What every language model here shares is ``models/lm.py``'s: the lookup, the
+layer scan with remat (``scan_blocks``), the chunked head and loss
+(``next_token_loss``), the attention dispatch (``attention``: dot, or the
+flash kernels with two head sizes). The expert layer is ``ops/moe.py``.
+Every assignment is computed: no capacity, no drop. The correction bias
+``b`` steers selection only and is not trained by the gradient (its gradient
+is zero; the published update rule's step size is not in the config, so no
+rule moves it here either).
 Expert parallelism (an ``ep`` mesh axis > 1) is not implemented: the experts
 of a layer live whole on every chip of the ``ep`` axis's group.
 """
@@ -47,15 +48,27 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec
 
-from ray_tpu.models import gpt as _gpt
+from ray_tpu._private import builtin_metrics
+from ray_tpu.models import lm
 from ray_tpu.ops.moe import routed_experts
 from ray_tpu.parallel.sharding import ShardingRules, constrain
 
 #: Metrics of ``loss_fn`` that count a batch: summed over accumulation
 #: microbatches where the others are averaged (parallel/train_step.py).
 SUMMED_METRICS = ("moe_assignments", "moe_tokens")
+
+#: Metrics of ``loss_fn`` that feed the registry, each with what records
+#: its value there: a train step reads them off the device without a sync
+#: (parallel/train_step.py) and calls these.
+RECORDED_METRICS = {
+    "moe_assignments":
+        lambda value: builtin_metrics.train_moe_assignments().inc(value),
+    "moe_tokens":
+        lambda value: builtin_metrics.train_moe_tokens().inc(value),
+    "moe_load_max_over_mean":
+        lambda value: builtin_metrics.train_moe_expert_load().set(value),
+}
 
 
 @dataclass(frozen=True)
@@ -201,10 +214,6 @@ def param_specs(cfg: DeepseekConfig, rules: ShardingRules) -> Dict[str, Any]:
     return specs
 
 
-def batch_spec(rules: ShardingRules) -> PartitionSpec:
-    return rules.spec("batch", "sequence")
-
-
 # -- forward ------------------------------------------------------------
 
 def _rmsnorm(x, scale, eps):
@@ -242,7 +251,7 @@ def _mla(cfg: DeepseekConfig, x, layer, positions):
     k = jnp.concatenate(
         [kv[..., :nope],
          jnp.broadcast_to(k_rope, q_rope.shape)], -1)
-    attn = _gpt._attention(q, k, kv[..., nope:], cfg)
+    attn = lm.attention(q, k, kv[..., nope:], cfg)
     return jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(dt))
 
 
@@ -297,13 +306,12 @@ def hidden_states(params: Dict[str, Any], cfg: DeepseekConfig,
     the expert layers' ``picked`` [L_moe, B, S, K] and ``group_sizes``
     [L_moe, E]."""
     _no_expert_parallelism()
-    B, S = tokens.shape
     if positions is None:
-        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = _gpt.embed(params["wte"], tokens, cfg.dtype)  # batch-split
+        positions = lm.positions_of(tokens)
+    x = lm.embed(params["wte"], tokens, cfg.dtype)  # batch-split
     block = partial(_block, cfg)
-    x, _ = _gpt.scan_blocks(cfg, block, x, params["dense_layers"], positions)
-    x, aux = _gpt.scan_blocks(cfg, block, x, params["moe_layers"], positions)
+    x, _ = lm.scan_blocks(cfg, block, x, params["dense_layers"], positions)
+    x, aux = lm.scan_blocks(cfg, block, x, params["moe_layers"], positions)
     x = constrain(x, "batch", "sequence", None)
     return _rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps), aux
 
@@ -335,17 +343,12 @@ def loss_fn(params: Dict[str, Any], cfg: DeepseekConfig, tokens: jax.Array,
     something was dropped) and ``moe_load_max_over_mean`` (the busiest
     expert's load over the mean, worst layer)."""
     x, aux = hidden_states(params, cfg, tokens)
-    mask32 = jnp.ones(tokens.shape, jnp.float32) if mask is None \
-        else mask.astype(jnp.float32)
-    denom = jnp.maximum(mask32.sum(), 1.0)
-    head = partial(_head, _gpt.head_gathered(params, tied=False), cfg)
-    nll_sum, hit_sum = _gpt.chunked_ce(head, x, targets, mask32,
-                                       cfg.loss_chunk)
-    loss = nll_sum / denom
+    head = partial(_head, lm.head_gathered(params, tied=False), cfg)
+    loss, metrics = lm.next_token_loss(head, x, targets, mask,
+                                       cfg.loss_chunk, 0.0)
     sizes = aux["group_sizes"].astype(jnp.float32)  # [L_moe, E]
     return loss, {
-        "loss": loss, "accuracy": hit_sum / denom,
-        "perplexity": jnp.exp(jnp.minimum(loss, 20.0)),
+        **metrics,
         "moe_assignments": sizes.sum(),
         "moe_tokens": jnp.float32(
             tokens.size * cfg.num_experts_per_tok * cfg.n_moe_layers),

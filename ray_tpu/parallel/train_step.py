@@ -7,24 +7,27 @@ NamedShardings; XLA inserts the gradient psums / param all-gathers over ICI.
 Parameters are *initialized inside jit with out_shardings* so a 6B-param
 model never materializes unsharded on any single host.
 
-The step is typed to no model. Every builder here takes ``model``: anything
-(a module of ``ray_tpu.models``, as a rule) that offers
+The step is typed to no model and imports none. Every builder here takes
+``model``: anything (a module of ``models/``, as a rule) that offers
 
     init(cfg, key) -> params
     param_specs(cfg, rules) -> PartitionSpec tree like params
     loss_fn(params, cfg, tokens, targets, mask) -> (loss, metrics)
-    batch_spec(rules) -> PartitionSpec of a [batch, seq] token array
 
-and, optionally, ``SUMMED_METRICS``: the names of metrics that are counts
-of a batch (summed, not averaged, over accumulation microbatches). ``cfg``
-is that model's own config object and is only handed back to it. The
-default is ``models/gpt.py``, so callers that train a ``GPTConfig`` say
-nothing.
+and, optionally, ``SUMMED_METRICS``, the names of metrics that are counts
+of a batch (summed, not averaged, over accumulation microbatches), and
+``RECORDED_METRICS``, {name of a metric: what records its value in the
+registry}. ``cfg`` is that model's own config object and is only handed
+back to it. The default is the module that defines ``type(cfg)``, so
+callers that train a ``GPTConfig`` or a ``DeepseekConfig`` say nothing. A
+batch is [batch, seq] token arrays, laid over the mesh as the rules lay
+("batch", "sequence").
 """
 
 from __future__ import annotations
 
 import functools
+import sys
 from functools import partial
 from typing import Any, Callable, Dict, Optional
 
@@ -34,7 +37,6 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ray_tpu._private.jax_compat import enable_compile_cache
-from ray_tpu.models import gpt as _default_model
 from ray_tpu.parallel import mesh as mesh_mod
 from ray_tpu.parallel.sharding import ShardingRules, tree_shardings
 
@@ -69,6 +71,11 @@ def memory_efficient_optimizer(learning_rate=1e-4,
         optax.clip_by_global_norm(1.0),
         optax.adafactor(learning_rate=schedule, momentum=None),
     )
+
+
+def _model_of(cfg: Any, model: Any) -> Any:
+    """``model``, or the module that defines ``type(cfg)``."""
+    return model or sys.modules[type(cfg).__module__]
 
 
 def _state_layout(cfg: Any, mesh, rules: ShardingRules,
@@ -116,7 +123,7 @@ def init_train_state(cfg: Any, mesh,
     enable_compile_cache()
     rules = rules or ShardingRules()
     optimizer = optimizer or default_optimizer()
-    model = model or _default_model
+    model = _model_of(cfg, model)
     _, shardings = _state_layout(cfg, mesh, rules, optimizer, model)
 
     @partial(jax.jit, out_shardings=shardings)
@@ -138,7 +145,7 @@ def abstract_train_state(cfg: Any, mesh,
     compile for a described TPU topology, a per-device memory proof)."""
     shapes, shardings = _state_layout(
         cfg, mesh, rules or ShardingRules(), optimizer or default_optimizer(),
-        model or _default_model)
+        _model_of(cfg, model))
     return jax.tree.map(
         lambda shape, sharding: jax.ShapeDtypeStruct(
             shape.shape, shape.dtype, sharding=sharding),
@@ -173,34 +180,29 @@ def _with_mesh_registered(jitted, mesh, rules, after_call=None):
     return wrapped
 
 
-class _MoeCounters:
-    """Feeds the ``moe_*`` scalars a step returns (models/deepseek.py) to
-    ``ray_tpu_train_moe_*`` without a device sync in the loop: a call's
-    scalars are read once they are ready, or by the next call at the
-    latest (that step is then queued behind them on the device, so the
-    read waits for nothing the device would not do anyway). The newest
-    call's may therefore still be pending when the loop ends."""
+class _DeferredRecorder:
+    """Feeds the scalars a step returns under the names of ``recorded``
+    ({metric: what records its value}, a model's ``RECORDED_METRICS``) to
+    the registry without a device sync in the loop: a call's scalars are
+    read once they are ready, or by the next call at the latest (that step
+    is then queued behind them on the device, so the read waits for nothing
+    the device would not do anyway). The newest call's may therefore still
+    be pending when the loop ends."""
 
-    KEYS = ("moe_assignments", "moe_tokens", "moe_load_max_over_mean")
-
-    def __init__(self) -> None:
+    def __init__(self, recorded: Dict[str, Callable[[float], None]]) -> None:
+        self.recorded = recorded
         self.pending: list = []
 
     def __call__(self, out) -> None:
-        metrics = out[1]
-        if self.KEYS[0] not in metrics:
-            return
-        from ray_tpu._private import builtin_metrics
-        scalars = [metrics[k] for k in self.KEYS]
+        scalars = [out[1][name] for name in self.recorded]
         for scalar in scalars:
             scalar.copy_to_host_async()
         self.pending.append(scalars)
         while self.pending and (len(self.pending) > 1 or all(
                 scalar.is_ready() for scalar in self.pending[0])):
-            assigned, tokens, load = (float(x) for x in self.pending.pop(0))
-            builtin_metrics.train_moe_assignments().inc(assigned)
-            builtin_metrics.train_moe_tokens().inc(tokens)
-            builtin_metrics.train_moe_expert_load().set(load)
+            for record, scalar in zip(self.recorded.values(),
+                                      self.pending.pop(0)):
+                record(float(scalar))
 
 
 def make_train_step(cfg: Any, mesh,
@@ -217,9 +219,10 @@ def make_train_step(cfg: Any, mesh,
     enable_compile_cache()
     rules = rules or ShardingRules()
     optimizer = optimizer or default_optimizer()
-    model = model or _default_model
-    bspec = model.batch_spec(rules)
+    model = _model_of(cfg, model)
+    bspec = rules.spec("batch", "sequence")
     summed = frozenset(getattr(model, "SUMMED_METRICS", ()))
+    recorded = getattr(model, "RECORDED_METRICS", None)
 
     def loss_for(params, micro):
         return model.loss_fn(params, cfg, micro["tokens"], micro["targets"],
@@ -268,7 +271,8 @@ def make_train_step(cfg: Any, mesh,
     _, shardings = _state_layout(cfg, mesh, rules, optimizer, model)
     return _with_mesh_registered(
         jax.jit(step, donate_argnums=(0,), out_shardings=(shardings, None)),
-        mesh, rules, after_call=_MoeCounters())
+        mesh, rules,
+        after_call=_DeferredRecorder(recorded) if recorded else None)
 
 
 def make_eval_step(cfg: Any, mesh,
@@ -276,8 +280,8 @@ def make_eval_step(cfg: Any, mesh,
                    model: Any = None) -> Callable:
     enable_compile_cache()
     rules = rules or ShardingRules()
-    model = model or _default_model
-    bspec = model.batch_spec(rules)
+    model = _model_of(cfg, model)
+    bspec = rules.spec("batch", "sequence")
 
     def step(params, batch):
         batch = {

@@ -24,7 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec, \
     SingleDeviceSharding  # noqa: E402
 
 import ray_tpu.ops  # noqa: E402,F401 - loads ray_tpu.ops.flash_attention
-from ray_tpu.models import gpt  # noqa: E402
+from ray_tpu.models import gpt, lm  # noqa: E402
 from ray_tpu.parallel import MeshConfig, ShardingRules, \
     build_mesh  # noqa: E402
 from ray_tpu.parallel.train_step import (abstract_train_state,  # noqa: E402
@@ -137,7 +137,7 @@ def test_flash_compiles_at_8k_under_shard_map(topo):
                for s in _qkv(topo, (4,) + MLA_SHAPE[1:], MLA_V))
 
     def loss(q, k, v):
-        return gpt._attention(q, k, v, cfg).astype(jnp.float32).sum()
+        return lm.attention(q, k, v, cfg).astype(jnp.float32).sum()
 
     previous = mesh_mod.current_mesh()
     mesh_mod.set_current_mesh(mesh)

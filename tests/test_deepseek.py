@@ -19,12 +19,14 @@ import numpy as np
 import pytest
 
 import reference_deepseek_v3 as reference
-from ray_tpu.models import deepseek, gpt
+from ray_tpu.models import deepseek, gpt, lm
 from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.parallel import MeshConfig, build_mesh
 from ray_tpu.parallel import mesh as mesh_mod
-from ray_tpu.parallel.train_step import init_train_state, make_train_step
+from ray_tpu.parallel.train_step import (abstract_train_state,
+                                         init_train_state, make_eval_step,
+                                         make_train_step)
 
 CFG = deepseek.config("deepseek-tiny")
 PUBLISHED = {"qk_nope_head_dim": CFG.qk_nope_head_dim,
@@ -203,10 +205,10 @@ def test_flash_kernels_two_head_sizes_several_blocks(under, what):
         else None
 
     def flash(q, k, v):
-        return gpt._attention(q, k, v, cfg)
+        return lm.attention(q, k, v, cfg)
 
     def dot(q, k, v):
-        return gpt._dot_attention(q, k, v)
+        return lm.dot_attention(q, k, v)
 
     previous = mesh_mod.current_mesh()
     mesh_mod.set_current_mesh(mesh)
@@ -232,9 +234,9 @@ def test_flash_blocks_of_unequal_size():
         got = flash_attention(q, k, v, True, 256, 128)
         grads = jax.grad(lambda *a: (flash_attention(
             *a, True, 128, 256) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(lambda *a: (gpt._dot_attention(*a) ** 2).sum(),
+        want = jax.grad(lambda *a: (lm.dot_attention(*a) ** 2).sum(),
                         argnums=(0, 1, 2))(q, k, v)
-    np.testing.assert_allclose(got, gpt._dot_attention(q, k, v), atol=2e-5,
+    np.testing.assert_allclose(got, lm.dot_attention(q, k, v), atol=2e-5,
                                rtol=1e-4)
     for a, b in zip(grads, want):
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=1e-3)
@@ -293,6 +295,29 @@ def test_train_step_on_gpt_tiny_is_unchanged(accum_steps, want):
         got.append(float(metrics["loss"]))
     assert sorted(metrics) == ["accuracy", "loss", "perplexity"]
     np.testing.assert_allclose(got, want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("model,cfg", [
+    (gpt, gpt.config("gpt-tiny")), (deepseek, CFG)],
+    ids=["gpt-tiny", "deepseek-tiny"])
+def test_the_builders_find_the_model_that_defines_the_config(model, cfg):
+    """Without ``model=`` every builder takes the module ``type(cfg)`` lives
+    in: the same state, the same step and the same metrics as with it."""
+    mesh = _one_chip()
+    tokens, targets = _batch()
+    batch = {"tokens": tokens, "targets": targets}
+
+    def run(**named):
+        state = init_train_state(cfg, mesh, seed=0, **named)
+        abstract = abstract_train_state(cfg, mesh, **named)
+        assert jax.tree.map(lambda a: (a.shape, a.dtype), state) == \
+            jax.tree.map(lambda a: (a.shape, a.dtype), abstract)
+        evaluated = make_eval_step(cfg, mesh, **named)(state["params"], batch)
+        _, stepped = make_train_step(cfg, mesh, **named)(state, batch)
+        return jax.device_get((evaluated, stepped))
+
+    found, named = run(), run(model=model)
+    assert "loss" in found[1] and found == named
 
 
 def test_expert_parallel_mesh_is_refused():

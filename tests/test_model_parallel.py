@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import gpt
+from ray_tpu.models import gpt, lm
 from ray_tpu.parallel import (MeshConfig, ShardingRules, build_mesh, dp_rules,
                               tp_fsdp_rules)
 from ray_tpu.parallel.train_step import (default_optimizer, init_train_state,
@@ -246,16 +246,7 @@ def _loss_and_grads(cfg, mesh_cfg, n_devices, params, tokens, targets, mask):
         mesh_mod.set_current_mesh(*previous)
 
 
-@pytest.mark.parametrize("masked", [False, True])
-@pytest.mark.parametrize("loss_chunk", [64, 100])  # 100 does not divide
-@pytest.mark.parametrize("parallel_block", [True, False])
-def test_fsdp_x_tp_matches_one_device(parallel_block, loss_chunk, masked):
-    """The reordered sum of the parallel block, the chunks cut inside each
-    data shard, the gathered head and the stated hidden states are the
-    same numbers in another order: loss and every gradient leaf on
-    fsdp=2 x tp=2 are the one-device values."""
-    cfg = gpt.config("gpt-tiny", parallel_block=parallel_block,
-                     loss_chunk=loss_chunk)
+def _assert_fsdp_x_tp_matches_one_device(cfg, masked):
     params = gpt.init(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(3)
     tokens = jnp.asarray(rng.integers(0, 256, (4, 32)), jnp.int32)
@@ -273,6 +264,62 @@ def test_fsdp_x_tp_matches_one_device(parallel_block, loss_chunk, masked):
                                    err_msg=jax.tree_util.keystr(path))
 
 
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("loss_chunk", [64, 100])  # 100 does not divide
+@pytest.mark.parametrize("parallel_block", [True, False])
+def test_fsdp_x_tp_matches_one_device(parallel_block, loss_chunk, masked):
+    """The reordered sum of the parallel block, the chunks cut inside each
+    data shard, the gathered head and the stated hidden states are the
+    same numbers in another order: loss and every gradient leaf on
+    fsdp=2 x tp=2 are the one-device values."""
+    _assert_fsdp_x_tp_matches_one_device(
+        gpt.config("gpt-tiny", parallel_block=parallel_block,
+                   loss_chunk=loss_chunk), masked)
+
+
+# -- the tied head: wte is the lookup's table and the head's weight --------
+
+TIED = gpt.config("gpt-tiny", tie_embeddings=True)
+
+
+def test_tied_param_specs_match_init():
+    params = jax.eval_shape(lambda k: gpt.init(TIED, k),
+                            jax.random.PRNGKey(0))
+    assert "lm_head" not in params and "lm_head_bias" not in params
+    specs = gpt.param_specs(TIED, ShardingRules())
+    assert jax.tree.structure(params) == jax.tree.structure(
+        specs, is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
+    assert sum(a.size for a in jax.tree.leaves(params)) == TIED.num_params()
+
+
+def test_tied_chunked_loss_equals_unchunked():
+    params = gpt.init(TIED, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(5)
+    tokens = jnp.asarray(rng.integers(0, 256, (4, 32)), jnp.int32)
+    targets = jnp.asarray(rng.integers(0, 256, (4, 32)), jnp.int32)
+
+    def loss_and_grads(chunk):
+        cfg = gpt.config("gpt-tiny", tie_embeddings=True, loss_chunk=chunk)
+        return jax.value_and_grad(
+            lambda p: gpt.loss_fn(p, cfg, tokens, targets)[0])(params)
+
+    want, want_g = loss_and_grads(0)
+    got, got_g = loss_and_grads(64)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    # wte's gradient sums the lookup's and the head's, chunk by chunk.
+    np.testing.assert_allclose(got_g["wte"], want_g["wte"], rtol=1e-4,
+                               atol=1e-6)
+
+
+def test_tied_head_on_fsdp_x_tp_matches_one_device():
+    """``lm.head_gathered(tied=True)``: wte gathered along d for the chunk
+    loop, split over the vocabulary for the head and by fsdp for the
+    lookup, its two gradients summed."""
+    _assert_fsdp_x_tp_matches_one_device(
+        gpt.config("gpt-tiny", tie_embeddings=True, loss_chunk=64),
+        masked=True)
+
+
 @pytest.mark.parametrize("chunk", [0, 3, 64, 100, 128, 4096])
 def test_chunked_ce_equals_the_unchunked_loss(chunk):
     """Whatever the chunk: none, fewer tokens than rows (one position a
@@ -284,10 +331,10 @@ def test_chunked_ce_equals_the_unchunked_loss(chunk):
     mask = jnp.asarray(rng.integers(0, 2, (4, 32)), jnp.float32)
 
     def sums(x, w, chunk):
-        return gpt.chunked_ce(lambda h: h @ w, x, targets, mask, chunk,
+        return lm.chunked_ce(lambda h: h @ w, x, targets, mask, chunk,
                               z_loss=1e-4)
 
-    want = gpt._ce_stats(x @ w, targets, mask, 1e-4)
+    want = lm.ce_stats(x @ w, targets, mask, 1e-4)
     np.testing.assert_allclose(sums(x, w, chunk), want, rtol=1e-5)
     got_g = jax.grad(lambda x, w: sums(x, w, chunk)[0], (0, 1))(x, w)
     want_g = jax.grad(lambda x, w: sums(x, w, 0)[0], (0, 1))(x, w)

@@ -1,68 +1,68 @@
-"""Model-zoo tests: Llama, ViT, diffusion UNet (GPT is covered in
-test_model_parallel.py)."""
+"""Model-zoo tests: GPT's grouped-query variants, ViT, diffusion UNet."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import diffusion, llama, vit
+from ray_tpu.models import diffusion, gpt, vit
 from ray_tpu.parallel import MeshConfig, build_mesh
 from ray_tpu.parallel.sharding import (ShardingRules, shard_tree,
                                        tp_fsdp_rules)
 
 
-# -- Llama --------------------------------------------------------------
+# -- GPT with grouped-query attention ------------------------------------
+# (the plain decoder is covered in test_model_parallel.py)
 
-def test_llama_forward_shape():
-    cfg = llama.config("llama-tiny")
-    params = llama.init(cfg, jax.random.PRNGKey(0))
+GQA = gpt.config("gpt-tiny", n_kv_heads=2)
+
+
+def test_gqa_forward_shape():
+    params = gpt.init(GQA, jax.random.PRNGKey(0))
     tokens = jnp.zeros((2, 16), jnp.int32)
-    logits = llama.forward(params, cfg, tokens)
-    assert logits.shape == (2, 16, cfg.vocab_size)
+    logits = gpt.forward(params, GQA, tokens)
+    assert logits.shape == (2, 16, GQA.vocab_size)
     assert np.isfinite(np.asarray(logits, np.float32)).all()
 
 
-def test_llama_causality():
-    cfg = llama.config("llama-tiny")
-    params = llama.init(cfg, jax.random.PRNGKey(1))
+def test_gqa_causality():
+    params = gpt.init(GQA, jax.random.PRNGKey(1))
     rng = np.random.default_rng(0)
-    toks = rng.integers(0, cfg.vocab_size, (1, 12))
-    a = llama.forward(params, cfg, jnp.asarray(toks, jnp.int32))
+    toks = rng.integers(0, GQA.vocab_size, (1, 12))
+    a = gpt.forward(params, GQA, jnp.asarray(toks, jnp.int32))
     toks2 = toks.copy()
-    toks2[0, -1] = (toks2[0, -1] + 1) % cfg.vocab_size
-    b = llama.forward(params, cfg, jnp.asarray(toks2, jnp.int32))
+    toks2[0, -1] = (toks2[0, -1] + 1) % GQA.vocab_size
+    b = gpt.forward(params, GQA, jnp.asarray(toks2, jnp.int32))
     # Changing the last token must not affect logits at earlier positions.
     np.testing.assert_allclose(np.asarray(a[0, :-1]), np.asarray(b[0, :-1]),
                                atol=1e-5)
 
 
-def test_llama_param_count_matches_init():
-    cfg = llama.config("llama-tiny")
-    params = llama.init(cfg, jax.random.PRNGKey(0))
+def test_gqa_param_count_matches_init():
+    params = gpt.init(GQA, jax.random.PRNGKey(0))
     actual = sum(x.size for x in jax.tree.leaves(params))
-    assert actual == cfg.num_params()
+    assert actual == GQA.num_params() < gpt.config("gpt-tiny").num_params()
 
 
-def test_llama_gqa_fewer_kv_heads():
-    cfg = llama.config("llama-tiny")
-    assert cfg.kv_heads == 2 and cfg.n_heads == 4
-    params = llama.init(cfg, jax.random.PRNGKey(0))
-    assert params["layers"]["wk"].shape == (
-        cfg.n_layers, cfg.d_model, 2, cfg.head_dim)
+def test_gqa_fewer_kv_heads():
+    assert GQA.kv_heads == 2 and GQA.n_heads == 4
+    layers = gpt.init(GQA, jax.random.PRNGKey(0))["layers"]
+    assert layers["wq"].shape == (GQA.n_layers, GQA.d_model, 4, GQA.head_dim)
+    for name in ("wk", "wv"):
+        assert layers[name].shape == (
+            GQA.n_layers, GQA.d_model, 2, GQA.head_dim), name
 
 
-def test_llama_loss_decreases():
-    cfg = llama.config("llama-tiny")
-    params = llama.init(cfg, jax.random.PRNGKey(0))
+def test_gqa_loss_decreases():
+    params = gpt.init(GQA, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
-    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 17)), jnp.int32)
+    toks = jnp.asarray(rng.integers(0, GQA.vocab_size, (4, 17)), jnp.int32)
     tokens, targets = toks[:, :-1], toks[:, 1:]
 
     @jax.jit
     def step(params):
         (loss, m), grads = jax.value_and_grad(
-            lambda p: llama.loss_fn(p, cfg, tokens, targets),
+            lambda p: gpt.loss_fn(p, GQA, tokens, targets),
             has_aux=True)(params)
         params = jax.tree.map(lambda p, g: p - 0.1 * g, params, grads)
         return params, loss
@@ -73,17 +73,33 @@ def test_llama_loss_decreases():
     assert float(loss) < float(first)
 
 
-def test_llama_sharded_forward():
-    mesh = build_mesh(MeshConfig(dp=2, fsdp=2, tp=2))
-    cfg = llama.config("llama-micro")
-    rules = tp_fsdp_rules()
-    params = llama.init(cfg, jax.random.PRNGKey(0))
-    specs = llama.param_specs(cfg, rules)
-    sharded = shard_tree(params, mesh, specs)
-    tokens = jnp.zeros((4, 16), jnp.int32)
-    expect = llama.forward(params, cfg, tokens)
-    with mesh:
-        got = jax.jit(lambda p, t: llama.forward(p, cfg, t))(sharded, tokens)
+@pytest.mark.parametrize("attn_impl", ["dot", "flash"])
+@pytest.mark.parametrize("tp,n_kv_heads", [(2, 1), (4, 2)])
+def test_kv_heads_tp_does_not_divide_sharded_forward(tp, n_kv_heads,
+                                                     attn_impl):
+    """KV heads that tp does not divide stay whole on every chip (the rules
+    say so for the weights, ``lm.attention_specs`` for the kernel's
+    shard_map) under four query heads that tp splits; with two of them a
+    shard that kept only its own query heads would pair them wrongly. 128
+    positions are one whole tile, so ``flash`` runs its kernel and not the
+    fallback."""
+    from ray_tpu.parallel import mesh as mesh_mod
+    mesh = build_mesh(MeshConfig(dp=1, fsdp=2, tp=tp),
+                      devices=jax.devices()[:2 * tp])
+    plain = gpt.config("gpt-tiny", n_kv_heads=n_kv_heads)
+    cfg = gpt.config("gpt-tiny", n_kv_heads=n_kv_heads, attn_impl=attn_impl)
+    rules = ShardingRules(kv_heads=None)
+    params = gpt.init(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (4, 128)), jnp.int32)
+    expect = gpt.forward(params, plain, tokens)
+    sharded = shard_tree(params, mesh, gpt.param_specs(cfg, rules))
+    previous = mesh_mod.current_mesh(), mesh_mod.current_rules()
+    mesh_mod.set_current_mesh(mesh, rules)
+    try:
+        got = jax.jit(lambda p, t: gpt.forward(p, cfg, t))(sharded, tokens)
+    finally:
+        mesh_mod.set_current_mesh(*previous)
     np.testing.assert_allclose(np.asarray(expect), np.asarray(got),
                                atol=2e-3)
 

@@ -1,4 +1,4 @@
-"""Expert parallelism (MoE), pipeline parallelism, Ulysses attention.
+"""Pipeline parallelism, Ulysses attention.
 
 All run on the 8-device virtual CPU mesh (conftest.py). These cover the
 parallelism strategies the reference lacks entirely (SURVEY.md §2.5:
@@ -10,73 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import gpt
-from ray_tpu.parallel import (MeshConfig, ShardingRules, build_mesh,
-                              make_pipeline_fn, sequential_apply,
-                              stage_param_specs)
-from ray_tpu.parallel.train_step import (default_optimizer, init_train_state,
-                                         make_train_step)
-
-
-def test_moe_forward_and_aux():
-    cfg = gpt.config("gpt-moe-tiny")
-    params = gpt.init(cfg, jax.random.PRNGKey(0))
-    tokens = jnp.zeros((2, 16), jnp.int32)
-    logits, aux = jax.jit(
-        lambda p, t: gpt.forward_with_aux(p, cfg, t))(params, tokens)
-    assert logits.shape == (2, 16, cfg.vocab_size)
-    # Balanced-ish routing at init: aux ≈ 1 (perfect balance) per layer sum.
-    assert np.isfinite(float(aux))
-    assert float(aux) > 0.5
-
-
-def test_moe_train_step_with_expert_parallelism():
-    cfg = gpt.config("gpt-moe-tiny")
-    mesh = build_mesh(MeshConfig(dp=2, fsdp=1, tp=1, sp=1, ep=4))
-    rules = ShardingRules()
-    optimizer = default_optimizer(learning_rate=1e-3)
-    state = init_train_state(cfg, mesh, rules, optimizer, seed=0)
-    # Expert weights must actually be sharded over ep.
-    win_sharding = state["params"]["layers"]["w_in"].sharding
-    assert "ep" in str(win_sharding.spec)
-    step = make_train_step(cfg, mesh, rules, optimizer)
-    rng = np.random.default_rng(0)
-    batch = {
-        "tokens": jnp.asarray(
-            rng.integers(0, cfg.vocab_size, (4, 32)), jnp.int32),
-        "targets": jnp.asarray(
-            rng.integers(0, cfg.vocab_size, (4, 32)), jnp.int32),
-    }
-    losses = []
-    for _ in range(3):
-        state, metrics = step(state, batch)
-        losses.append(float(metrics["loss"]))
-    assert all(np.isfinite(l) for l in losses)
-    assert losses[-1] < losses[0]  # learns the (repeated) batch
-
-
-def test_moe_matches_dense_when_one_expert():
-    """A 1-expert MoE with top_k=1 and ample capacity is exactly a dense
-    FFN routed through einsum dispatch — logits must match the dense path
-    with identical weights."""
-    dense_cfg = gpt.config("gpt-tiny")
-    moe_cfg = gpt.config("gpt-tiny", n_experts=1, expert_top_k=1,
-                         capacity_factor=float(2))
-    dense = gpt.init(dense_cfg, jax.random.PRNGKey(1))
-    moe = gpt.init(moe_cfg, jax.random.PRNGKey(1))
-    # Copy dense FFN weights into the single expert.
-    moe["layers"]["w_in"] = dense["layers"]["w_in"][:, None]
-    moe["layers"]["b_in"] = dense["layers"]["b_in"][:, None]
-    moe["layers"]["w_out"] = dense["layers"]["w_out"][:, None]
-    for k in ("wte", "lnf_scale", "lnf_bias", "lm_head", "lm_head_bias"):
-        moe[k] = dense[k]
-    for k in ("ln1_scale", "ln1_bias", "wq", "wk", "wv", "wo", "b_out"):
-        moe["layers"][k] = dense["layers"][k]
-    tokens = jnp.arange(32, dtype=jnp.int32).reshape(1, 32) % 256
-    out_dense = gpt.forward(dense, dense_cfg, tokens)
-    out_moe = gpt.forward(moe, moe_cfg, tokens)
-    np.testing.assert_allclose(np.asarray(out_dense), np.asarray(out_moe),
-                               rtol=2e-4, atol=2e-4)
+from ray_tpu.parallel import (MeshConfig, build_mesh, make_pipeline_fn,
+                              sequential_apply, stage_param_specs)
 
 
 def test_pipeline_matches_sequential():

@@ -342,11 +342,3 @@ def test_the_lowered_step_names_its_parts():
     for scope in ("block/attention", "block/mlp", "head_loss", "optimizer",
                   "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert scope in text, scope
-    moe = gpt.config("gpt-moe-tiny") if "gpt-moe-tiny" in gpt.PRESETS \
-        else None
-    if moe is not None:
-        params = jax.eval_shape(lambda k: gpt.init(moe, k),
-                                jax.random.PRNGKey(0))
-        lowered = jax.jit(lambda p, t: gpt.forward(p, moe, t)).lower(
-            params, jax.ShapeDtypeStruct((2, 32), jnp.int32))
-        assert "block/moe" in lowered.as_text(debug_info=True)
