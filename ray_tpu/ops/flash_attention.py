@@ -1,21 +1,34 @@
 """Flash attention as Pallas TPU kernels (forward + backward).
 
-Forward tiles Q and KV over the grid: one Q tile meets one KV tile per
-step, the KV axis last and sequential, with the online-softmax state in
-VMEM scratch, keeping the MXU fed with [blk_q, D] x [D, blk_k] matmuls
-(pallas_guide.md: grid/BlockSpec + scratch accumulators), and emits the
-per-row logsumexp needed by the backward pass. Nothing of length S is ever
-resident in VMEM, so the sequence length is bounded by HBM alone, and q/k
-may have another head size (D) than v and the output (Dv): latent
-attention trains with D = 192, Dv = 128.
+One grid step is one tile: a [blk_q, D] Q tile against a [blk_k, D] /
+[blk_k, Dv] KV tile. The grid is ``(batch*heads, pairs)``: its second axis
+walks a table of the (Q tile, KV tile) pairs that have work, built with
+numpy at trace time from S, the two tile sizes and ``causal``, and handed
+to the kernel as two scalar-prefetched int32 arrays that the index maps
+and the kernel body read. A tile above the causal diagonal is not in the
+table, so it costs no step and no fetch (a step of a rectangular grid
+that ``pl.when`` skips costs 0.36-0.38 us on a v5e, PERF.md §6, PR 28);
+``causal=False`` is the same code over the table of all pairs. Every causal
+tile in the table builds and applies the mask, though only a *diagonal* one
+has anything to mask and a *full* one (its last column at or before its
+first row) has not: leaving the mask out of full tiles was measured and
+bought nothing, the backward kernels being bound by their products and the
+forward by its row maximum. ``causal_tile_census`` counts the classes.
+
+The forward kernel walks the table Q-major, KV tiles ascending, with the
+online-softmax state in VMEM scratch, keeping the MXU fed with
+[blk_q, D] x [D, blk_k] matmuls (pallas_guide.md: grid/BlockSpec + scratch
+accumulators), and emits the per-row logsumexp needed by the backward
+pass. Nothing of length S is ever resident in VMEM, so the sequence length
+is bounded by HBM alone, and q/k may have another head size (D) than v and
+the output (Dv): latent attention trains with D = 192, Dv = 128.
 
 Backward is the standard two-kernel FlashAttention scheme: a dQ kernel
-(grid over Q tiles, KV tiles streamed) and a dK/dV kernel (grid over KV
-tiles; Q, dO, lse and delta tiles streamed), both recomputing probabilities
-from q, k and the saved logsumexp — O(S) memory, no S x S tensor ever
-materializes in HBM. This is
-what lets the GPT train step run "selective" rematerialisation instead of
-full-block recompute (models/gpt.py GPTConfig.remat_policy).
+(the forward's table) and a dK/dV kernel (the table KV-major, Q tiles
+ascending from the first that sees the KV tile), both recomputing
+probabilities from q, k and the saved logsumexp: O(S) memory, no S x S
+tensor ever materializes in HBM. The one dispatch is models/lm.py
+``attention``; every model reaches the kernels through it.
 
 On non-TPU backends the kernels run in interpreter mode so the same code
 path is testable on the CPU mesh (SURVEY.md §4: fake-TPU strategy), and
@@ -31,6 +44,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -52,150 +66,182 @@ def _causal_mask(qi, ki, blk_q: int, blk_k: int):
     return q_pos >= k_pos
 
 
-def _last_k_block(qi, blk_q: int, blk_k: int):
-    """The last KV tile a causal Q tile sees (its last row's column)."""
-    return ((qi + 1) * blk_q - 1) // blk_k
+def _is_empty(qi, ki, blk_q: int, blk_k: int):
+    """Causal tile (qi, ki) allows no pair: its first column is past its
+    last row."""
+    return ki * blk_k > (qi + 1) * blk_q - 1
 
 
-def _first_q_block(ki, blk_q: int, blk_k: int):
-    """The first Q tile that sees a causal KV tile (its first column)."""
-    return (ki * blk_k) // blk_q
+def _is_full(qi, ki, blk_q: int, blk_k: int):
+    """Causal tile (qi, ki) allows every pair: its last column is at or
+    before its first row."""
+    return (ki + 1) * blk_k - 1 <= qi * blk_q
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                      o_scr, *, blk_q: int, blk_k: int, causal: bool,
-                      scale: float):
-    """Grid: (batch*heads, num_q_blocks, num_k_blocks), the last axis
-    sequential. One [blk_q, D] Q tile against one [blk_k, D] / [blk_k, Dv]
-    KV tile per step, the online-softmax state (m, l, o) carried in VMEM
-    scratch across the KV axis; o_ref [blk_q, Dv] and lse_ref [1, blk_q]
-    are written at the last KV step. KV tiles past the diagonal of a
-    causal Q tile are skipped (their index map repeats the last one seen,
-    so nothing is fetched for them either)."""
-    qi, ki = pl.program_id(1), pl.program_id(2)
+def _tile_grid(S: int, blk_q: int, blk_k: int):
+    return np.meshgrid(np.arange(S // blk_q, dtype=np.int32),
+                       np.arange(S // blk_k, dtype=np.int32), indexing="ij")
 
-    @pl.when(ki == 0)
+
+def _tile_pairs(S: int, blk_q: int, blk_k: int, causal: bool,
+                kv_major: bool):
+    """The tiles with work as two int32 tables (qi_tab, ki_tab), one entry
+    a grid step: Q-major with KV tiles ascending, or KV-major with Q tiles
+    ascending, so every sum a kernel carries keeps its order."""
+    qi, ki = _tile_grid(S, blk_q, blk_k)
+    keep = ~_is_empty(qi, ki, blk_q, blk_k) if causal \
+        else np.ones_like(qi, bool)
+    if kv_major:
+        qi, ki, keep = qi.T, ki.T, keep.T
+    return qi[keep], ki[keep]
+
+
+def causal_tile_census(S: int, blk_q: int, blk_k: int) -> dict:
+    """How many of a causal S x S attention's tiles are of each class:
+    ``executed`` (= ``diagonal`` + ``full``) is the length of the kernels'
+    table, ``empty`` the tiles that get no grid step."""
+    qi, ki = _tile_grid(S, blk_q, blk_k)
+    empty = int(_is_empty(qi, ki, blk_q, blk_k).sum())
+    full = int(_is_full(qi, ki, blk_q, blk_k).sum())
+    executed = qi.size - empty
+    return {"executed": executed, "diagonal": executed - full,
+            "full": full, "empty": empty}
+
+
+def _row_ends(row_tab):
+    """(first, last): whether this grid step opens / closes its row of
+    tiles, a run of equal entries in ``row_tab``."""
+    t, last_t = pl.program_id(1), pl.num_programs(1) - 1
+    row = row_tab[t]
+    first = jnp.logical_or(
+        t == 0, row_tab[jnp.maximum(t - 1, 0)] != row)
+    last = jnp.logical_or(
+        t == last_t, row_tab[jnp.minimum(t + 1, last_t)] != row)
+    return first, last
+
+
+def _flash_fwd_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                      m_scr, l_scr, o_scr, *, blk_q: int, blk_k: int,
+                      causal: bool, scale: float):
+    """Grid: (batch*heads, pairs), Q-major, the pair axis sequential. One
+    [blk_q, D] Q tile against one [blk_k, D] / [blk_k, Dv] KV tile per
+    step, the online-softmax state (m, l, o) carried in VMEM scratch along
+    a row of tiles; o_ref [blk_q, Dv] and lse_ref [1, blk_q] are written at
+    the row's last step."""
+    t = pl.program_id(1)
+    qi, ki = qi_tab[t], ki_tab[t]
+    first, last = _row_ends(qi_tab)
+
+    @pl.when(first)
     def _():
         m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         o_scr[...] = jnp.zeros(o_scr.shape, jnp.float32)
 
-    def accumulate():
-        q = q_ref[...].astype(jnp.float32) * scale
-        k_blk = k_ref[...].astype(jnp.float32)
-        v_blk = v_ref[...].astype(jnp.float32)
-        logits = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            mask = _causal_mask(qi, ki, blk_q, blk_k)
-            logits = jnp.where(mask, logits, _NEG_INF)
-        m = m_scr[...]
-        m_new = jnp.maximum(m, logits.max(-1, keepdims=True))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(logits - m_new)
-        if causal:
-            p = jnp.where(mask, p, 0.0)
-        l_scr[...] = l_scr[...] * corr + p.sum(-1, keepdims=True)
-        o_scr[...] = o_scr[...] * corr + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
+    q = q_ref[...].astype(jnp.float32) * scale
+    k_blk = k_ref[...].astype(jnp.float32)
+    v_blk = v_ref[...].astype(jnp.float32)
+    logits = jax.lax.dot_general(
+        q, k_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
     if causal:
-        pl.when(ki <= _last_k_block(qi, blk_q, blk_k))(accumulate)
-    else:
-        accumulate()
+        logits = jnp.where(_causal_mask(qi, ki, blk_q, blk_k), logits,
+                           _NEG_INF)
+    m = m_scr[...]
+    m_new = jnp.maximum(m, logits.max(-1, keepdims=True))
+    corr = jnp.exp(m - m_new)
+    # A masked logit is -1e30 and every row has seen column 0 by now (a
+    # row of tiles starts at KV tile 0), so m_new is a real logit and
+    # exp(-1e30 - m_new) is 0.0 exactly: p needs no select of its own.
+    p = jnp.exp(logits - m_new)
+    l_scr[...] = l_scr[...] * corr + p.sum(-1, keepdims=True)
+    o_scr[...] = o_scr[...] * corr + jax.lax.dot_general(
+        p, v_blk, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         o_ref[...] = (o_scr[...] / l_safe).astype(o_ref.dtype)
         lse_ref[...] = (m_scr[...] + jnp.log(l_safe))[:, 0][None, :]
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                         dq_ref, dq_scr, *, blk_q: int, blk_k: int,
-                         causal: bool, scale: float):
-    """Grid: (batch*heads, num_q_blocks, num_k_blocks), the last axis
-    sequential: dq for one Q tile, accumulated over streamed KV tiles."""
-    qi, ki = pl.program_id(1), pl.program_id(2)
+def _flash_bwd_dq_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref,
+                         lse_ref, delta_ref, dq_ref, dq_scr, *, blk_q: int,
+                         blk_k: int, causal: bool, scale: float):
+    """Grid: (batch*heads, pairs), Q-major, the pair axis sequential: dq
+    for one Q tile, accumulated over its row of KV tiles."""
+    t = pl.program_id(1)
+    qi, ki = qi_tab[t], ki_tab[t]
+    first, last = _row_ends(qi_tab)
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    def accumulate():
-        q = q_ref[...].astype(jnp.float32) * scale
-        g = g_ref[...].astype(jnp.float32)
-        k_blk = k_ref[...].astype(jnp.float32)
-        v_blk = v_ref[...].astype(jnp.float32)
-        logits = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        p = jnp.exp(logits - lse_ref[0, :][:, None])
-        if causal:
-            p = jnp.where(_causal_mask(qi, ki, blk_q, blk_k), p, 0.0)
-        dp = jax.lax.dot_general(
-            g, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, :][:, None])
-        dq_scr[...] += jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
+    q = q_ref[...].astype(jnp.float32) * scale
+    g = g_ref[...].astype(jnp.float32)
+    k_blk = k_ref[...].astype(jnp.float32)
+    v_blk = v_ref[...].astype(jnp.float32)
+    logits = jax.lax.dot_general(
+        q, k_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    p = jnp.exp(logits - lse_ref[0, :][:, None])
     if causal:
-        pl.when(ki <= _last_k_block(qi, blk_q, blk_k))(accumulate)
-    else:
-        accumulate()
+        p = jnp.where(_causal_mask(qi, ki, blk_q, blk_k), p, 0.0)
+    dp = jax.lax.dot_general(
+        g, v_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0, :][:, None])
+    dq_scr[...] += jax.lax.dot_general(
+        ds, k_blk, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _():
         dq_ref[...] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_scr, dv_scr, *, blk_q: int,
-                          blk_k: int, causal: bool, scale: float):
-    """Grid: (batch*heads, num_k_blocks, num_q_blocks), the last axis
-    sequential: dk/dv for one KV tile, accumulated over streamed Q, dO, lse
-    and delta tiles (only those at or after the diagonal when causal)."""
-    ki, qi = pl.program_id(1), pl.program_id(2)
+def _flash_bwd_dkv_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref,
+                          lse_ref, delta_ref, dk_ref, dv_ref, dk_scr,
+                          dv_scr, *, blk_q: int, blk_k: int, causal: bool,
+                          scale: float):
+    """Grid: (batch*heads, pairs), KV-major, the pair axis sequential:
+    dk/dv for one KV tile, accumulated over the Q, dO, lse and delta tiles
+    of its row (those at or after the diagonal when causal)."""
+    t = pl.program_id(1)
+    qi, ki = qi_tab[t], ki_tab[t]
+    first, last = _row_ends(ki_tab)
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    def accumulate():
-        k = k_ref[...].astype(jnp.float32)
-        v = v_ref[...].astype(jnp.float32)
-        q_blk = q_ref[...].astype(jnp.float32) * scale
-        g_blk = g_ref[...].astype(jnp.float32)
-        logits = jax.lax.dot_general(
-            q_blk, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        p = jnp.exp(logits - lse_ref[0, :][:, None])
-        if causal:
-            p = jnp.where(_causal_mask(qi, ki, blk_q, blk_k), p, 0.0)
-        dv_scr[...] += jax.lax.dot_general(
-            p, g_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            g_blk, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, :][:, None])
-        # q_blk carries one factor of scale: scale * ds^T @ q is dk.
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
+    k = k_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    q_blk = q_ref[...].astype(jnp.float32) * scale
+    g_blk = g_ref[...].astype(jnp.float32)
+    logits = jax.lax.dot_general(
+        q_blk, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    p = jnp.exp(logits - lse_ref[0, :][:, None])
     if causal:
-        pl.when(qi >= _first_q_block(ki, blk_q, blk_k))(accumulate)
-    else:
-        accumulate()
+        p = jnp.where(_causal_mask(qi, ki, blk_q, blk_k), p, 0.0)
+    dv_scr[...] += jax.lax.dot_general(
+        p, g_blk, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dp = jax.lax.dot_general(
+        g_blk, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0, :][:, None])
+    # q_blk carries one factor of scale: scale * ds^T @ q is dk.
+    dk_scr[...] += jax.lax.dot_general(
+        ds, q_blk, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _():
         dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
@@ -229,34 +275,37 @@ def _pick_block(S: int, want: int) -> int:
     return b
 
 
-def _streamed(causal: bool, blk_q: int, blk_k: int):
-    """Index maps of a (batch*heads, Q tile, KV tile) grid and of its
-    transpose. A tile that a causal step skips maps to the nearest one it
-    does not, so consecutive skipped steps fetch nothing new."""
-    if causal:
-        def kv_of(b, i, j):
-            return b, jnp.minimum(j, _last_k_block(i, blk_q, blk_k)), 0
-
-        def q_of(b, j, i):
-            return b, jnp.maximum(i, _first_q_block(j, blk_q, blk_k)), 0
-
-        def row_of(b, j, i):
-            return b, 0, jnp.maximum(i, _first_q_block(j, blk_q, blk_k))
-    else:
-        def kv_of(b, i, j):
-            return b, j, 0
-
-        def q_of(b, j, i):
-            return b, i, 0
-
-        def row_of(b, j, i):
-            return b, 0, i
-    return kv_of, q_of, row_of
+def _q_tile(b, t, qi_tab, ki_tab):
+    return b, qi_tab[t], 0
 
 
-def _compiler_params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+def _kv_tile(b, t, qi_tab, ki_tab):
+    return b, ki_tab[t], 0
+
+
+def _q_row(b, t, qi_tab, ki_tab):
+    return b, 0, qi_tab[t]
+
+
+def _tiled_call(kernel, name: str, batch_heads: int, pairs, in_specs,
+                out_specs, out_shape, scratch_shapes):
+    """One kernel over the grid (batch*heads, pairs), to be called on its
+    operands: the first axis parallel, the pair axis sequential, the two
+    tables scalar-prefetched."""
+    qi_tab, ki_tab = pairs
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(batch_heads, len(qi_tab)),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(),
+        name=name,
+    )
+    return functools.partial(call, jnp.asarray(qi_tab), jnp.asarray(ki_tab))
 
 
 def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int):
@@ -278,22 +327,21 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int):
         # of running the Pallas backward).
         return blockwise_attention(q, k, v, causal=causal), None
     qf, kf, vf = _to_bh(q), _to_bh(k), _to_bh(v)
-    kv_of, _, _ = _streamed(causal, blk_q, blk_k)
 
     kernel = functools.partial(
         _flash_fwd_kernel, blk_q=blk_q, blk_k=blk_k, causal=causal,
         scale=scale)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B * H, S // blk_q, S // blk_k),
+    out, lse = _tiled_call(
+        kernel, "flash_fwd", B * H,
+        _tile_pairs(S, blk_q, blk_k, causal, False),
         in_specs=[
-            pl.BlockSpec((None, blk_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, blk_k, D), kv_of),
-            pl.BlockSpec((None, blk_k, Dv), kv_of),
+            pl.BlockSpec((None, blk_q, D), _q_tile),
+            pl.BlockSpec((None, blk_k, D), _kv_tile),
+            pl.BlockSpec((None, blk_k, Dv), _kv_tile),
         ],
         out_specs=[
-            pl.BlockSpec((None, blk_q, Dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, 1, blk_q), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((None, blk_q, Dv), _q_tile),
+            pl.BlockSpec((None, 1, blk_q), _q_row),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
@@ -302,9 +350,6 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int):
         scratch_shapes=[pltpu.VMEM((blk_q, 1), jnp.float32),
                         pltpu.VMEM((blk_q, 1), jnp.float32),
                         pltpu.VMEM((blk_q, Dv), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=_interpret(),
-        name="flash_fwd",
     )(qf, kf, vf)
     return _from_bh(out, B, H), lse
 
@@ -320,42 +365,33 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
     gf, of = _to_bh(g), _to_bh(out)
     delta = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32),
                     axis=-1)[:, None, :]  # [BH, 1, S]
-    kv_of, q_of, row_of = _streamed(causal, blk_q, blk_k)
-
     common = dict(blk_q=blk_q, blk_k=blk_k, causal=causal, scale=scale)
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **common),
-        grid=(B * H, S // blk_q, S // blk_k),
-        in_specs=[
-            pl.BlockSpec((None, blk_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, blk_k, D), kv_of),
-            pl.BlockSpec((None, blk_k, Dv), kv_of),
-            pl.BlockSpec((None, blk_q, Dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, 1, blk_q), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((None, 1, blk_q), lambda b, i, j: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((None, blk_q, D), lambda b, i, j: (b, i, 0)),
+    # The six operands of both backward kernels, tiled alike in both.
+    operands = (qf, kf, vf, gf, lse, delta)
+    in_specs = [
+        pl.BlockSpec((None, blk_q, D), _q_tile),
+        pl.BlockSpec((None, blk_k, D), _kv_tile),
+        pl.BlockSpec((None, blk_k, Dv), _kv_tile),
+        pl.BlockSpec((None, blk_q, Dv), _q_tile),
+        pl.BlockSpec((None, 1, blk_q), _q_row),
+        pl.BlockSpec((None, 1, blk_q), _q_row),
+    ]
+    dq = _tiled_call(
+        functools.partial(_flash_bwd_dq_kernel, **common), "flash_bwd_dq",
+        B * H, _tile_pairs(S, blk_q, blk_k, causal, False),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, blk_q, D), _q_tile),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=_interpret(),
-        name="flash_bwd_dq",
-    )(qf, kf, vf, gf, lse, delta)
+    )(*operands)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **common),
-        grid=(B * H, S // blk_k, S // blk_q),
-        in_specs=[
-            pl.BlockSpec((None, blk_q, D), q_of),
-            pl.BlockSpec((None, blk_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((None, blk_k, Dv), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((None, blk_q, Dv), q_of),
-            pl.BlockSpec((None, 1, blk_q), row_of),
-            pl.BlockSpec((None, 1, blk_q), row_of),
-        ],
+    dk, dv = _tiled_call(
+        functools.partial(_flash_bwd_dkv_kernel, **common), "flash_bwd_dkv",
+        B * H, _tile_pairs(S, blk_q, blk_k, causal, True),
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((None, blk_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((None, blk_k, Dv), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((None, blk_k, D), _kv_tile),
+            pl.BlockSpec((None, blk_k, Dv), _kv_tile),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
@@ -363,10 +399,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
         ],
         scratch_shapes=[pltpu.VMEM((blk_k, D), jnp.float32),
                         pltpu.VMEM((blk_k, Dv), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=_interpret(),
-        name="flash_bwd_dkv",
-    )(qf, kf, vf, gf, lse, delta)
+    )(*operands)
 
     dq = _from_bh(dq, B, H)
     dk = _from_bh(dk, B, H)

@@ -180,6 +180,133 @@ def test_flash_kernel_backward_gqa():
                                        atol=2e-3, rtol=1e-3)
 
 
+# --- the kernels' table of (Q tile, KV tile) pairs and the causal mask ---
+
+BLOCK_PAIRS = [(128, 128), (256, 128), (128, 256), (512, 512)]
+
+
+def _flash_and_grads(q, k, v, g, causal, blk_q, blk_k):
+    """out and (dq, dk, dv) for the cotangent g, through the kernels."""
+    out, vjp = jax.vjp(
+        lambda q_, k_, v_: flash_attention(q_, k_, v_, causal, blk_q, blk_k),
+        q, k, v)
+    return (out,) + vjp(g)
+
+
+def _reference_and_grads(q, k, v, g, causal):
+    from ray_tpu.models import lm
+    ref = lm.dot_attention if causal else (
+        lambda q_, k_, v_: _dot_reference(q_, k_, v_, causal=False))
+    out, vjp = jax.vjp(ref, q, k, v)
+    return (out,) + vjp(g)
+
+
+@pytest.mark.parametrize("on_boundary", [True, False])
+@pytest.mark.parametrize("S", [512, 1024])
+@pytest.mark.parametrize("blk_q,blk_k", BLOCK_PAIRS)
+def test_flash_future_poison(blk_q, blk_k, S, on_boundary):
+    """k and v after position t are replaced by large finite values (not
+    NaN: 0 x NaN is NaN) in the second batch entry; the cotangent is zero
+    after t. Nothing at or before t may change, bit for bit: a mask built
+    for another tile than the table's entry, or a masked probability that
+    is not 0.0 exactly, lets a row see its future."""
+    t = S // 2 - 1 if on_boundary else S // 2 + 37
+    q, k, v = _qkv(B=1, S=S, H=1, D=32, seed=7)
+    future = (jnp.arange(S) > t)[None, :, None, None]
+    q2 = jnp.concatenate([q, q])
+    k2 = jnp.concatenate([k, jnp.where(future, 1e3, k)])
+    v2 = jnp.concatenate([v, jnp.where(future, -7e3, v)])
+    g = jax.random.normal(jax.random.PRNGKey(8), v.shape, v.dtype)
+    g2 = jnp.where(future, 0.0, jnp.concatenate([g, g]))
+    for name, x in zip(("out", "dq", "dk", "dv"), _flash_and_grads(
+            q2, k2, v2, g2, True, blk_q, blk_k)):
+        x = np.asarray(x)
+        assert np.isfinite(x).all(), name
+        np.testing.assert_array_equal(x[1, :t + 1], x[0, :t + 1], name)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blk_q,blk_k", BLOCK_PAIRS)
+def test_flash_sharp_matches_dot(blk_q, blk_k, causal):
+    """q and k sharpened, so a row's weight sits on a few columns wherever
+    they are: a masking fault far from the diagonal shows (at a seeded init
+    attention is soft and it hides). Forward and all three gradients
+    against the dot reference, in float32; causal=False is the table of
+    all pairs, no mask."""
+    with jax.default_matmul_precision("highest"):
+        q, k, v = _qkv(B=1, S=1024, H=2, D=32, seed=11)
+        q, k = 3.0 * q, 3.0 * k
+        g = jax.random.normal(jax.random.PRNGKey(12), v.shape, v.dtype)
+        got = _flash_and_grads(q, k, v, g, causal, blk_q, blk_k)
+        want = _reference_and_grads(q, k, v, g, causal)
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4, rtol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("blk_q,blk_k", [(128, 128), (256, 128), (128, 256)])
+def test_flash_two_head_sizes(blk_q, blk_k):
+    """q/k of 192 beside v of 128 (latent attention) through the table."""
+    with jax.default_matmul_precision("highest"):
+        ks = jax.random.split(jax.random.PRNGKey(13), 4)
+        q = jax.random.normal(ks[0], (1, 512, 2, 192))
+        k = jax.random.normal(ks[1], (1, 512, 2, 192))
+        v = jax.random.normal(ks[2], (1, 512, 2, 128))
+        g = jax.random.normal(ks[3], v.shape)
+        got = _flash_and_grads(q, k, v, g, True, blk_q, blk_k)
+        want = _reference_and_grads(q, k, v, g, True)
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            assert a.shape == b.shape, name
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4, rtol=2e-4, err_msg=name)
+
+
+def _brute_census(S, blk_q, blk_k):
+    """Classes of the tiles of the S x S causal mask, counted on it."""
+    allowed = np.arange(S)[:, None] >= np.arange(S)[None, :]
+    tiles = allowed.reshape(S // blk_q, blk_q, S // blk_k, blk_k)
+    share = tiles.mean(axis=(1, 3))
+    counts = {"empty": int((share == 0).sum()),
+              "full": int((share == 1).sum())}
+    counts["diagonal"] = share.size - counts["empty"] - counts["full"]
+    counts["executed"] = counts["diagonal"] + counts["full"]
+    return counts
+
+
+@pytest.mark.parametrize("S", [512, 1024, 1536])
+@pytest.mark.parametrize("blk_q,blk_k", BLOCK_PAIRS)
+def test_causal_tile_census_matches_the_mask(blk_q, blk_k, S):
+    from ray_tpu.ops.flash_attention import (_tile_pairs,
+                                             causal_tile_census)
+    census = causal_tile_census(S, blk_q, blk_k)
+    assert census == _brute_census(S, blk_q, blk_k)
+    # benchmark/flops_deepseek.py causal_tiles, restated: what the
+    # kernels' rooflines count as executed.
+    assert census["executed"] == sum(
+        min(-(-((qi + 1) * blk_q) // blk_k), S // blk_k)
+        for qi in range(S // blk_q))
+    # The tables are the executed tiles, each once, in the order that
+    # keeps every carried sum's order: rows ascending, and ascending
+    # within a row.
+    for kv_major in (False, True):
+        qi, ki = _tile_pairs(S, blk_q, blk_k, True, kv_major)
+        assert len(qi) == len(ki) == census["executed"]
+        pairs = list(zip(ki, qi) if kv_major else zip(qi, ki))
+        assert pairs == sorted(set(pairs))
+    n_all = (S // blk_q) * (S // blk_k)
+    assert len(_tile_pairs(S, blk_q, blk_k, False, False)[0]) == n_all
+    assert census["executed"] + census["empty"] == n_all
+
+
+@pytest.mark.parametrize("S,census", [
+    (8192, {"executed": 136, "diagonal": 16, "full": 120, "empty": 120}),
+    (2048, {"executed": 10, "diagonal": 4, "full": 6, "empty": 6})])
+def test_causal_tile_census_of_the_cells(S, census):
+    """What the benchmark's cells run, at tiles of 512 x 512."""
+    from ray_tpu.ops.flash_attention import causal_tile_census
+    assert causal_tile_census(S, 512, 512) == census
+
+
 def _sp_mesh(n=4):
     devices = np.array(jax.devices("cpu")[:n])
     return jax.sharding.Mesh(devices, ("sp",))
