@@ -1,9 +1,10 @@
 """What a causal language model of this package is made of, and no model
 owns: the embedding lookup, the layer scan with rematerialisation, the
-attention dispatch (which attention runs, and how it is laid over a mesh)
-and the chunked head and loss. ``models/gpt.py`` and ``models/deepseek.py``
-are built from these; a new family brings its config, parameters, block and
-head and is written against this module, not against another model.
+attention dispatch (which attention runs, and how it is laid over a mesh),
+the state-space scan's, and the chunked head and loss. ``models/gpt.py``,
+``models/deepseek.py`` and ``models/granite.py`` are built from these; a new
+family brings its config, parameters, block and head and is written against
+this module, not against another model.
 
 A model's config is read here for the program's own choices only, under the
 names ``GPTConfig`` gives them: ``attn_impl``, ``attn_blk_q``,
@@ -14,6 +15,7 @@ model module.
 
 from __future__ import annotations
 
+import itertools
 import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -48,11 +50,35 @@ def embed(wte, tokens, dtype):
     return constrain(x, "batch", "sequence", None)
 
 
-def scan_blocks(cfg, block, x, layers, positions):
+def layer_runs(layer_types):
+    """[(kind, layers)] of every run of one kind in ``layer_types``."""
+    return [(kind, len(list(run)))
+            for kind, run in itertools.groupby(layer_types)]
+
+
+def scan_blocks(cfg, block, x, layers, positions, layer_types=None):
     """``block(x, layer, positions) -> (x, aux)`` over stacked layer
     parameters in one ``lax.scan``, each block rematerialised by
     ``cfg.remat`` / ``cfg.remat_policy``. Returns (x, aux stacked over
-    layers; None where the block returns None)."""
+    layers; None where the block returns None).
+
+    A stack of several kinds of layer gives ``layer_types``, the kind of
+    every layer in order, ``block`` as a dict by kind and ``layers`` as a
+    sequence with one stack for every run of one kind, in order (a model
+    keeps its parameters that way: a slice of one stack of all a kind's
+    layers is a copy, and the slices' gradients a second one). Every run
+    is one scan, the runs one after the other. Returns (x, [each run's
+    aux])."""
+    if layer_types is not None:
+        runs = layer_runs(layer_types)
+        depths = [jax.tree.leaves(stack)[0].shape[0] for stack in layers]
+        if depths != [n for _, n in runs]:
+            raise ValueError(f"stacks of {depths} layers for runs {runs}")
+        auxes = []
+        for (kind, _), stack in zip(runs, layers):
+            x, aux = scan_blocks(cfg, block[kind], x, stack, positions)
+            auxes.append(aux)
+        return x, auxes
     if cfg.remat:
         if cfg.remat_policy == "selective":
             policy = jax.checkpoint_policies.save_only_these_names(
@@ -74,16 +100,18 @@ def scan_blocks(cfg, block, x, layers, positions):
 
 # -- attention ------------------------------------------------------------
 
-def dot_attention(q, k, v):
+def dot_attention(q, k, v, scale: Optional[float] = None):
     """Causal attention; fp32 softmax. q: [B, S, H, D], k: [B, S, KVH, D],
-    v: [B, S, KVH, Dv] (Dv may differ from D) -> [B, S, H, Dv]."""
+    v: [B, S, KVH, Dv] (Dv may differ from D) -> [B, S, H, Dv]. Scores are
+    multiplied by ``scale`` (1/sqrt(D) if None)."""
     B, S, H, D = q.shape
     kvh = k.shape[2]
     if kvh != H:  # GQA: repeat KV heads
         rep = H // kvh
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     logits = logits.astype(jnp.float32)
     qpos = jax.lax.broadcasted_iota(jnp.int32, (S, S), 0)
@@ -110,17 +138,19 @@ def attention_specs(mesh, n_heads: int, n_kv_heads: int, seq_axis):
     return q_spec, kv_spec
 
 
-def attention(q, k, v, cfg):
+def attention(q, k, v, cfg, scale: Optional[float] = None):
     """Causal attention by ``cfg.attn_impl`` (and, for the flash kernels,
     ``cfg.attn_blk_q`` / ``cfg.attn_blk_k``): the one dispatch every model
-    of this package goes through. cfg is any model's config."""
+    of this package goes through. cfg is any model's config. Scores are
+    multiplied by ``scale``, the model's own where its config publishes
+    one, 1/sqrt(D) if None (``dot`` and ``flash`` only)."""
     if cfg.attn_impl == "dot":
-        return dot_attention(q, k, v)
+        return dot_attention(q, k, v, scale)
     if cfg.attn_impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention
         from ray_tpu.parallel.mesh import current_mesh
         fn = partial(flash_attention, causal=True,
-                     blk_q=cfg.attn_blk_q, blk_k=cfg.attn_blk_k)
+                     blk_q=cfg.attn_blk_q, blk_k=cfg.attn_blk_k, scale=scale)
         mesh = current_mesh()
         if mesh is None or mesh.size == 1:
             return fn(q, k, v)
@@ -136,6 +166,10 @@ def attention(q, k, v, cfg):
             q_spec = kv_spec
         return shard_map(fn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
                          out_specs=q_spec, check_vma=False)(q, k, v)
+    if scale is not None:
+        raise NotImplementedError(
+            f"attn_impl={cfg.attn_impl!r} scales scores by 1/sqrt(D) only; "
+            "a model with its own score scale needs 'dot' or 'flash'")
     if cfg.attn_impl == "ring":
         from ray_tpu.ops.ring_attention import make_ring_attention
         from ray_tpu.parallel.mesh import current_mesh
@@ -160,6 +194,30 @@ def attention(q, k, v, cfg):
                 "axis (parallel.mesh.set_current_mesh)")
         return make_ulysses_attention(mesh)(q, k, v)
     raise ValueError(f"Unknown attn_impl {cfg.attn_impl!r}")
+
+
+# -- state-space scan -----------------------------------------------------
+
+def state_space(u, dt, A, B, C, D, chunk: int):
+    """The Mamba-2 recurrence ``S_t = exp(dt_t A) S_(t-1) + dt_t u_t B_t^T``,
+    ``y_t = S_t C_t + D u_t`` by ``ops/ssd.py``'s chunked scan. u: [B, S, H,
+    P], dt: [B, S, H] (positive), A, D: [H], B, C: [B, S, N] -> [B, S, H,
+    P]. Under a mesh the kernels run per shard, as the flash kernels do:
+    the recurrence is independent per batch row, so each shard scans its
+    own rows whole, with every head."""
+    from ray_tpu.ops.ssd import ssd
+    from ray_tpu.parallel.mesh import current_mesh
+    fn = partial(ssd, chunk=chunk)
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(u, dt, A, B, C, D)
+    from ray_tpu._private.jax_compat import shard_map
+    batch = ambient_spec(mesh, "batch")[0]
+    rows = lambda rank: PartitionSpec(batch, *[None] * (rank - 1))
+    head = PartitionSpec(None)
+    return shard_map(fn, mesh=mesh,
+                     in_specs=(rows(4), rows(3), head, rows(3), rows(3), head),
+                     out_specs=rows(4), check_vma=False)(u, dt, A, B, C, D)
 
 
 # -- head and loss --------------------------------------------------------

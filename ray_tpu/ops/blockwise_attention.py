@@ -51,17 +51,20 @@ def attention_chunk(q, k, v, m, l, o, q_pos, k_pos, causal: bool,
     return m_new, l_new, o_new
 
 
-@partial(jax.jit, static_argnames=("causal", "chunk_size"))
+@partial(jax.jit, static_argnames=("causal", "chunk_size", "scale"))
 def blockwise_attention(q, k, v, causal: bool = True,
                         chunk_size: int = 512,
-                        q_offset: int = 0, kv_offset: int = 0) -> jax.Array:
+                        q_offset: int = 0, kv_offset: int = 0,
+                        scale: Optional[float] = None) -> jax.Array:
     """Causal attention over KV chunks. q,k,v: [B, S, H|KVH, D] →
     [B, S, H, D]. ``q_offset``/``kv_offset`` shift global positions (used by
-    ring attention when q and kv live on different sequence shards)."""
+    ring attention when q and kv live on different sequence shards);
+    ``scale`` multiplies the scores (1/sqrt(D) if None)."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     k, v = _repeat_kv(k, v, H)
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)

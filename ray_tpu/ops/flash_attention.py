@@ -308,11 +308,16 @@ def _tiled_call(kernel, name: str, batch_heads: int, pairs, in_specs,
     return functools.partial(call, jnp.asarray(qi_tab), jnp.asarray(ki_tab))
 
 
-def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int):
+def _score_scale(scale, D: int) -> float:
+    return 1.0 / math.sqrt(D) if scale is None else float(scale)
+
+
+def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int,
+                   scale=None):
     B, S, H, D = q.shape
     Dv = v.shape[-1]
     k, v = _repeat_heads(k, v, H)
-    scale = 1.0 / math.sqrt(D)
+    scale = _score_scale(scale, D)
     blk_q = _pick_block(S, blk_q)
     blk_k = _pick_block(S, blk_k)
     if blk_q < 128 or blk_k < 128:
@@ -325,7 +330,7 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int):
         # no kernel to lose: the jnp blockwise path (no lse output — the
         # custom VJP then differentiates the blockwise recurrence instead
         # of running the Pallas backward).
-        return blockwise_attention(q, k, v, causal=causal), None
+        return blockwise_attention(q, k, v, causal=causal, scale=scale), None
     qf, kf, vf = _to_bh(q), _to_bh(k), _to_bh(v)
 
     kernel = functools.partial(
@@ -355,12 +360,12 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int):
 
 
 def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
-                    blk_k: int):
+                    blk_k: int, scale=None):
     B, S, H, D = q.shape
     Dv = v.shape[-1]
     kvh = k.shape[2]
     k_rep, v_rep = _repeat_heads(k, v, H)
-    scale = 1.0 / math.sqrt(D)
+    scale = _score_scale(scale, D)
     qf, kf, vf = _to_bh(q), _to_bh(k_rep), _to_bh(v_rep)
     gf, of = _to_bh(g), _to_bh(out)
     delta = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32),
@@ -412,32 +417,35 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True, blk_q: int = 1024,
-                    blk_k: int = 1024):
+                    blk_k: int = 1024, scale=None):
     """q: [B, S, H, D], k: [B, S, KVH, D], v: [B, S, KVH, Dv] →
-    [B, S, H, Dv]; scores are scaled by 1/sqrt(D)."""
-    return _flash_forward(q, k, v, causal, blk_q, blk_k)[0]
+    [B, S, H, Dv]. Query head i reads KV head i // (H // KVH). Scores are
+    multiplied by ``scale``: the model's own (a Python float), or
+    1/sqrt(D) where it is None."""
+    return _flash_forward(q, k, v, causal, blk_q, blk_k, scale)[0]
 
 
-def _fwd(q, k, v, causal, blk_q, blk_k):
-    out, lse = _flash_forward(q, k, v, causal, blk_q, blk_k)
+def _fwd(q, k, v, causal, blk_q, blk_k, scale):
+    out, lse = _flash_forward(q, k, v, causal, blk_q, blk_k, scale)
     if lse is None:
         # Ragged fallback: differentiate the jnp blockwise recurrence.
         return out, (q, k, v, None, None)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, blk_q, blk_k, residuals, g):
+def _bwd(causal, blk_q, blk_k, scale, residuals, g):
     q, k, v, out, lse = residuals
     if lse is None:
         _, vjp = jax.vjp(
-            lambda q_, k_, v_: blockwise_attention(q_, k_, v_, causal=causal),
-            q, k, v)
+            lambda q_, k_, v_: blockwise_attention(
+                q_, k_, v_, causal=causal, scale=scale), q, k, v)
         return vjp(g)
     S = q.shape[1]
     return _flash_backward(q, k, v, out, lse, g, causal,
-                           _pick_block(S, blk_q), _pick_block(S, blk_k))
+                           _pick_block(S, blk_q), _pick_block(S, blk_k),
+                           scale)
 
 
 flash_attention.defvjp(_fwd, _bwd)

@@ -1,0 +1,404 @@
+"""The Mamba-2 state-space recurrence in its chunked ("state-space dual")
+form, as a pair of Pallas TPU kernels (forward, backward) and a plain
+``jax.numpy`` form of the same algorithm.
+
+Per head, with a state ``S`` [P, N] that is zero before the first token::
+
+    S_t = exp(dt_t A) S_(t-1) + dt_t u_t B_t^T
+    y_t = S_t C_t + D u_t
+
+``u`` [batch, S, H, P] holds H heads of P channels, ``dt`` [batch, S, H] the
+step sizes (positive: after the softplus), ``A`` [H] a negative scalar a
+head, ``B`` and ``C`` [batch, S, N] one group shared by every head, ``D`` [H]
+the skip. The literal recurrence is S sequential steps; the chunked form
+does a chunk of L steps as matrix products. With ``cum_t`` the sum of
+``dt A`` from the chunk's first position to t, inside a chunk
+
+    y_t = sum_{s<=t} exp(cum_t - cum_s) (C_t . B_s) dt_s u_s      quadratic in L
+          + exp(cum_t) C_t S_in + D u_t                            the carried state
+    S_out = exp(cum_L) S_in + sum_s exp(cum_L - cum_s) dt_s u_s B_s^T
+
+and only ``S_in -> S_out`` runs along the sequence, once a chunk.
+
+The kernels' grid is ``(batch, chunks, head blocks)``, the last two
+sequential: a grid step is one chunk of one block of ``heads_per_block``
+heads, whose [L, L] decay matrices live in VMEM only (as einsums they are a
+float32 [heads, chunks, L, L] array in HBM). The states of all heads stay in
+VMEM scratch from chunk to chunk as the flash kernels carry their sums;
+``C B^T`` is computed once a chunk, at the first head block, and kept in
+scratch for the others. The forward also writes each chunk's entry state,
+which the backward reads: it walks the chunks in reverse with the cotangent
+of the state carried the same way, and sums the cotangents of ``B`` and ``C``
+over the head blocks in its output block. ``dt A`` and its running sum are
+made outside the kernels, by XLA, which differentiates them too: the kernels
+take ``dt`` and ``cum`` and return their cotangents. Products run in the
+dtype of ``u`` with float32 accumulation; decays, states and sums are
+float32.
+
+``ssd`` is the one entry: the kernels where the shapes tile (S a multiple of
+the chunk, the chunk and N multiples of 128, a block of heads a multiple of
+128 lanes), else ``ssd_chunked``, the ``jax.numpy`` form, which is also the
+kernels' oracle in the tests. On backends other than the TPU the kernels run
+in interpreter mode.
+"""
+
+from __future__ import annotations
+
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+F32 = jnp.float32
+_NEG = -1e30
+
+
+def _interpret() -> bool:
+    """The flash kernels' answer (interpreter mode off the TPU), asked of
+    that module each time so that one switch steers every kernel of
+    ``ops/``."""
+    return importlib.import_module(
+        "ray_tpu.ops.flash_attention")._interpret()
+
+
+# -- the chunked form in jax.numpy -----------------------------------------
+
+def _chunk_sums(dt, A, chunk: int):
+    """(dt, cum) [batch, chunks, L, H] float32: the step sizes by chunk and
+    the running sum of ``dt A`` inside each chunk."""
+    batch, seq, heads = dt.shape
+    dt = dt.astype(F32).reshape(batch, seq // chunk, chunk, heads)
+    return dt, jnp.cumsum(dt * A.astype(F32), axis=2)
+
+
+def ssd_chunked(u, dt, A, B, C, D, chunk: int = 256):
+    """The chunked algorithm as einsums, any length (the tail is padded with
+    steps of size 0, which leave the state as it is): float32 throughout,
+    the decay matrix [batch, chunks, L, L, H] whole. The kernels' oracle
+    and the path for shapes they cannot tile."""
+    batch, seq, heads, width = u.shape
+    pad = -seq % chunk
+    if pad:
+        u, dt, B, C = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                       for a in (u, dt, B, C))
+    n = (seq + pad) // chunk
+    dt_c, cum = _chunk_sums(dt, A, chunk)
+    u_c = u.astype(F32).reshape(batch, n, chunk, heads, width)
+    B_c, C_c = (a.astype(F32).reshape(batch, n, chunk, -1) for a in (B, C))
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None]
+    decay = jnp.exp(jnp.where(
+        causal, cum[:, :, :, None, :] - cum[:, :, None, :, :], _NEG))
+    scores = jnp.einsum("bcln,bcsn->bcls", C_c, B_c)
+    mixed = scores[..., None] * decay * dt_c[:, :, None, :, :]
+    y = jnp.einsum("bclsh,bcshp->bclhp", mixed, u_c)
+    # Each chunk's own contribution to its exit state, then the entry
+    # states by the recurrence over chunks.
+    total = cum[:, :, -1, :]
+    own = jnp.einsum("bclh,bclhp,bcln->bchpn",
+                     jnp.exp(total[:, :, None, :] - cum) * dt_c, u_c, B_c)
+
+    def carry(state, chunk_terms):
+        decay_c, own_c = chunk_terms
+        return decay_c[..., None, None] * state + own_c, state
+
+    _, entry = jax.lax.scan(
+        carry, jnp.zeros(own.shape[:1] + own.shape[2:], F32),
+        (jnp.exp(total).swapaxes(0, 1), own.swapaxes(0, 1)))
+    y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+        "bcln,cbhpn->bclhp", C_c, entry)
+    y = y + D.astype(F32)[:, None] * u_c
+    return y.reshape(batch, seq + pad, heads, width)[:, :seq].astype(u.dtype)
+
+
+# -- the kernels -------------------------------------------------------------
+
+def _mm(a, b, contract_a: int, contract_b: int):
+    return jax.lax.dot_general(
+        a, b, (((contract_a,), (contract_b,)), ((), ())),
+        preferred_element_type=F32)
+
+
+def _causal(chunk: int):
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    return rows >= cols
+
+
+def _ssd_fwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref, etot_ref,
+                    y_ref, entry_ref, scores_scr, state_scr, scaled_scr, *,
+                    heads: int, width: int):
+    """One chunk of one block of ``heads`` heads. u/y [L, heads * width];
+    b/c [L, N]; cols [3, L, heads] holds (dt, cum, cum_L - cum) as columns
+    and rows [2, heads, L] (dt, cum) as rows; d [1, heads * width] is D and
+    etot [1, heads * width] exp(cum_L), a value a lane; entry [N, heads *
+    width] is the block's state on entry, transposed (the state of head h
+    is ``[:, h * width:(h + 1) * width]``)."""
+    ci, hi = pl.program_id(1), pl.program_id(2)
+    chunk = u_ref.shape[0]
+    dtype = u_ref.dtype
+
+    @pl.when(ci == 0)
+    def _():
+        state_scr[hi] = jnp.zeros(state_scr.shape[1:], F32)
+
+    @pl.when(hi == 0)
+    def _():
+        scores_scr[...] = _mm(c_ref[...], b_ref[...], 1, 1)
+
+    state = state_scr[hi]
+    entry_ref[...] = state
+    from_state = _mm(c_ref[...], state.astype(dtype), 1, 0)  # [L, W]
+    scores, causal = scores_scr[...], _causal(chunk)
+    for h in range(heads):
+        lanes = slice(h * width, (h + 1) * width)
+        u = u_ref[:, lanes]
+        dt_col, cum_col = cols_ref[0, :, h:h + 1], cols_ref[1, :, h:h + 1]
+        dt_row, cum_row = rows_ref[0, h:h + 1, :], rows_ref[1, h:h + 1, :]
+        decay = jnp.exp(jnp.where(causal, cum_col - cum_row, _NEG))
+        mixed = (scores * decay * dt_row).astype(dtype)
+        y = (_mm(mixed, u, 1, 0) + jnp.exp(cum_col) * from_state[:, lanes]
+             + d_ref[:, lanes] * u.astype(F32))
+        y_ref[:, lanes] = y.astype(y_ref.dtype)
+        scaled_scr[:, lanes] = (
+            u.astype(F32) * (jnp.exp(cols_ref[2, :, h:h + 1]) * dt_col)
+        ).astype(dtype)
+    state_scr[hi] = etot_ref[...] * state + _mm(
+        b_ref[...], scaled_scr[...], 0, 0)
+
+
+def _ssd_bwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref, etot_ref,
+                    entry_ref, dy_ref, du_ref, db_ref, dc_ref, dcols_ref,
+                    drows_ref, scores_scr, dscores_scr, dstate_scr,
+                    scaled_scr, dys_scr, *, heads: int, width: int):
+    """The forward's grid step with the chunks in reverse (the index maps
+    turn them round): the cotangent of the block's exit state is carried in
+    ``dstate_scr``. db/dc [L, N] float32 are summed over the head blocks in
+    place; dcols [3, L, heads] are the cotangents of dt, cum and D (the
+    last to be summed over L) found as columns, drows [2, heads, L] those
+    of dt and cum found as rows."""
+    ti, hi = pl.program_id(1), pl.program_id(2)
+    chunk = u_ref.shape[0]
+    dtype = u_ref.dtype
+
+    @pl.when(ti == 0)
+    def _():
+        dstate_scr[hi] = jnp.zeros(dstate_scr.shape[1:], F32)
+
+    @pl.when(hi == 0)
+    def _():
+        scores_scr[...] = _mm(c_ref[...], b_ref[...], 1, 1)
+        dscores_scr[...] = jnp.zeros(dscores_scr.shape, F32)
+        db_ref[...] = jnp.zeros(db_ref.shape, F32)
+        dc_ref[...] = jnp.zeros(dc_ref.shape, F32)
+
+    state, dstate = entry_ref[...], dstate_scr[hi]
+    from_state = _mm(c_ref[...], state.astype(dtype), 1, 0)    # [L, W]
+    from_dstate = _mm(b_ref[...], dstate.astype(dtype), 1, 0)  # [L, W]
+    state_dots = state * dstate * etot_ref[...]                # [N, W]
+    scores, causal = scores_scr[...], _causal(chunk)
+    last = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+    for h in range(heads):
+        lanes = slice(h * width, (h + 1) * width)
+        u, dy = u_ref[:, lanes], dy_ref[:, lanes]
+        u32, dy32 = u.astype(F32), dy.astype(F32)
+        dt_col, cum_col = cols_ref[0, :, h:h + 1], cols_ref[1, :, h:h + 1]
+        dt_row, cum_row = rows_ref[0, h:h + 1, :], rows_ref[1, h:h + 1, :]
+        decay = jnp.exp(jnp.where(causal, cum_col - cum_row, _NEG))
+        decayed = scores * decay
+        mixed = (decayed * dt_row).astype(dtype)
+        dmixed = _mm(dy, u, 1, 1)                               # dY u^T
+        dscores_scr[...] += dmixed * decay * dt_row
+        by_dt = dmixed * decayed                 # d mixed / d dt_s, per pair
+        by_cum = by_dt * dt_row                  # d mixed / d cum_t, -d cum_s
+        ddt_row = by_dt.sum(0, keepdims=True)
+        dcum_row = -by_cum.sum(0, keepdims=True)
+        dcum_col = by_cum.sum(1, keepdims=True)
+        # y's part from the entry state: exp(cum) (C state).
+        dys = dy32 * jnp.exp(cum_col)
+        dcum_col += (dys * from_state[:, lanes]).sum(1, keepdims=True)
+        # The exit state's part: weights w_s dt_s on u_s B_s^T.
+        w = jnp.exp(cols_ref[2, :, h:h + 1])
+        v = from_dstate[:, lanes]
+        du = _mm(mixed, dy, 0, 0) + (dt_col * w) * v \
+            + d_ref[:, lanes] * dy32
+        du_ref[:, lanes] = du.astype(du_ref.dtype)
+        by_weight = (v * u32).sum(1, keepdims=True)
+        ddt_col = by_weight * w
+        by_w = by_weight * dt_col * w
+        dtotal = by_w.sum(0, keepdims=True) + state_dots[:, lanes].sum(
+            1, keepdims=True).sum(0, keepdims=True)
+        dcum_col += jnp.where(last, dtotal, 0.0) - by_w
+        dcols_ref[0, :, h:h + 1] = ddt_col
+        dcols_ref[1, :, h:h + 1] = dcum_col
+        dcols_ref[2, :, h:h + 1] = (dy32 * u32).sum(1, keepdims=True)
+        drows_ref[0, h:h + 1, :] = ddt_row
+        drows_ref[1, h:h + 1, :] = dcum_row
+        dys_scr[:, lanes] = dys.astype(dtype)
+        scaled_scr[:, lanes] = (u32 * (dt_col * w)).astype(dtype)
+    # Products over all the block's lanes sum over its heads.
+    dys_all = dys_scr[...]
+    dc_ref[...] += _mm(dys_all, state.astype(dtype), 1, 1)
+    db_ref[...] += _mm(scaled_scr[...], dstate.astype(dtype), 1, 1)
+    dstate_scr[hi] = etot_ref[...] * dstate + _mm(c_ref[...], dys_all, 0, 0)
+
+    @pl.when(hi == pl.num_programs(2) - 1)
+    def _():
+        dscores = dscores_scr[...].astype(dtype)
+        dc_ref[...] += _mm(dscores, b_ref[...], 1, 0)
+        db_ref[...] += _mm(dscores, c_ref[...], 0, 0)
+
+
+def heads_per_block(heads: int, width: int) -> int:
+    """Heads a grid step takes: the most of 8, 4, 2, 1 that divide the head
+    count into blocks of whole 128-lane tiles (0 if none does)."""
+    return next((n for n in (8, 4, 2, 1)
+                 if heads % n == 0 and n * width % 128 == 0), 0)
+
+
+def _layouts(dt_c, cum, D, block: int, width: int):
+    """The per-head vectors as the kernels read them: cols [batch, chunks,
+    blocks, 3, L, block] of (dt, cum, cum_L - cum), rows [.., 2, block, L]
+    of (dt, cum), D and exp(cum_L) a value a lane ([blocks, 1, block *
+    width] and [batch, chunks, blocks, 1, block * width])."""
+    batch, n, chunk, heads = dt_c.shape
+    blocks = heads // block
+    stacked = jnp.stack([dt_c, cum, cum[:, :, -1:, :] - cum], axis=2).reshape(
+        batch, n, 3, chunk, blocks, block)
+    cols = stacked.transpose(0, 1, 4, 2, 3, 5)
+    rows = stacked[:, :, :2].transpose(0, 1, 4, 2, 5, 3)
+    d_lanes = jnp.repeat(D.astype(F32), width).reshape(blocks, 1, -1)
+    etot = jnp.repeat(jnp.exp(cum[:, :, -1, :]), width, axis=-1).reshape(
+        batch, n, blocks, 1, -1)
+    return cols, rows, d_lanes, etot
+
+
+def _specs(chunk: int, lanes: int, state: int, block: int, n_chunks: int,
+           reverse: bool):
+    """BlockSpecs over the grid (batch, chunks, head blocks), by operand
+    kind; ``reverse`` walks the chunks from the last."""
+    def at(t):
+        return n_chunks - 1 - t if reverse else t
+
+    return {
+        "wide": pl.BlockSpec((None, chunk, lanes),
+                             lambda b, t, j: (b, at(t), j)),
+        "bc": pl.BlockSpec((None, chunk, state),
+                           lambda b, t, j: (b, at(t), 0)),
+        "cols": lambda k: pl.BlockSpec(
+            (None, None, None, k, chunk, block),
+            lambda b, t, j: (b, at(t), j, 0, 0, 0)),
+        "rows": lambda k: pl.BlockSpec(
+            (None, None, None, k, block, chunk),
+            lambda b, t, j: (b, at(t), j, 0, 0, 0)),
+        "d": pl.BlockSpec((None, 1, lanes), lambda b, t, j: (j, 0, 0)),
+        "etot": pl.BlockSpec((None, None, None, 1, lanes),
+                             lambda b, t, j: (b, at(t), j, 0, 0)),
+        "state": pl.BlockSpec((None, None, None, state, lanes),
+                              lambda b, t, j: (b, at(t), j, 0, 0)),
+    }
+
+
+_PARAMS = dict(dimension_semantics=("parallel", "arbitrary", "arbitrary"))
+
+
+def _forward(u, dt_c, cum, B, C, D, block: int):
+    """(y [batch, S, H * P], entry states [batch, chunks, blocks, N, block *
+    P]) by the forward kernel; u is [batch, S, H * P]."""
+    batch, n, chunk, heads = dt_c.shape
+    seq, state = B.shape[1], B.shape[2]
+    width = u.shape[-1] // heads
+    lanes, blocks = block * width, heads // block
+    spec = _specs(chunk, lanes, state, block, n, reverse=False)
+    return pl.pallas_call(
+        functools.partial(_ssd_fwd_kernel, heads=block, width=width),
+        grid=(batch, n, blocks),
+        in_specs=[spec["wide"], spec["bc"], spec["bc"], spec["cols"](3),
+                  spec["rows"](2), spec["d"], spec["etot"]],
+        out_specs=[spec["wide"], spec["state"]],
+        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
+                   jax.ShapeDtypeStruct((batch, n, blocks, state, lanes),
+                                        F32)],
+        scratch_shapes=[pltpu.VMEM((chunk, chunk), F32),
+                        pltpu.VMEM((blocks, state, lanes), F32),
+                        pltpu.VMEM((chunk, lanes), u.dtype)],
+        compiler_params=pltpu.CompilerParams(**_PARAMS),
+        interpret=_interpret(),
+        name="ssd_fwd",
+    )(u, B, C, *_layouts(dt_c, cum, D, block, width))
+
+
+def _backward(u, dt_c, cum, B, C, D, entry, dy, block: int):
+    """Cotangents (du, ddt, dcum [batch, chunks, L, H], dB, dC, dD) by the
+    backward kernel."""
+    batch, n, chunk, heads = dt_c.shape
+    state = B.shape[2]
+    width = u.shape[-1] // heads
+    lanes, blocks = block * width, heads // block
+    spec = _specs(chunk, lanes, state, block, n, reverse=True)
+    small = (batch, n, blocks)
+    du, dB, dC, dcols, drows = pl.pallas_call(
+        functools.partial(_ssd_bwd_kernel, heads=block, width=width),
+        grid=(batch, n, blocks),
+        in_specs=[spec["wide"], spec["bc"], spec["bc"], spec["cols"](3),
+                  spec["rows"](2), spec["d"], spec["etot"], spec["state"],
+                  spec["wide"]],
+        out_specs=[spec["wide"], spec["bc"], spec["bc"], spec["cols"](3),
+                   spec["rows"](2)],
+        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
+                   jax.ShapeDtypeStruct(B.shape, F32),
+                   jax.ShapeDtypeStruct(C.shape, F32),
+                   jax.ShapeDtypeStruct(small + (3, chunk, block), F32),
+                   jax.ShapeDtypeStruct(small + (2, block, chunk), F32)],
+        scratch_shapes=[pltpu.VMEM((chunk, chunk), F32),
+                        pltpu.VMEM((chunk, chunk), F32),
+                        pltpu.VMEM((blocks, state, lanes), F32),
+                        pltpu.VMEM((chunk, lanes), u.dtype),
+                        pltpu.VMEM((chunk, lanes), u.dtype)],
+        compiler_params=pltpu.CompilerParams(**_PARAMS),
+        interpret=_interpret(),
+        name="ssd_bwd",
+    )(u, B, C, *_layouts(dt_c, cum, D, block, width), entry, dy)
+    # [batch, chunks, blocks, k, L, block] -> k x [batch, chunks, L, H]
+    from_cols = dcols.transpose(3, 0, 1, 4, 2, 5).reshape(
+        3, batch, n, chunk, heads)
+    from_rows = drows.transpose(3, 0, 1, 5, 2, 4).reshape(
+        2, batch, n, chunk, heads)
+    ddt, dcum = from_cols[0] + from_rows[0], from_cols[1] + from_rows[1]
+    return (du, ddt, dcum, dB.astype(B.dtype), dC.astype(C.dtype),
+            from_cols[2].sum((0, 1, 2)).astype(D.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _ssd_kernels(u, dt_c, cum, B, C, D, block: int):
+    return _forward(u, dt_c, cum, B, C, D, block)[0]
+
+
+def _ssd_kernels_fwd(u, dt_c, cum, B, C, D, block):
+    y, entry = _forward(u, dt_c, cum, B, C, D, block)
+    return y, (u, dt_c, cum, B, C, D, entry)
+
+
+def _ssd_kernels_bwd(block, residuals, dy):
+    return _backward(*residuals, dy, block)
+
+
+_ssd_kernels.defvjp(_ssd_kernels_fwd, _ssd_kernels_bwd)
+
+
+def ssd(u, dt, A, B, C, D, chunk: int = 256):
+    """y [batch, S, H, P] of the recurrence at the top of this file, by
+    chunks of ``chunk`` positions. u [batch, S, H, P]; dt [batch, S, H]
+    positive; A [H] negative; B, C [batch, S, N]; D [H]. The kernels where
+    the shapes tile, else ``ssd_chunked``."""
+    batch, seq, heads, width = u.shape
+    block = heads_per_block(heads, width)
+    if seq % chunk or chunk % 128 or B.shape[-1] % 128 or not block:
+        return ssd_chunked(u, dt, A, B, C, D, chunk)
+    with jax.named_scope("ssd"):
+        dt_c, cum = _chunk_sums(dt, A, chunk)
+        y = _ssd_kernels(u.reshape(batch, seq, heads * width), dt_c, cum,
+                         B.astype(u.dtype), C.astype(u.dtype), D, block)
+        return y.reshape(u.shape)

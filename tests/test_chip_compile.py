@@ -169,6 +169,55 @@ def test_grouped_matmul_compiles_at_the_published_widths(topo):
     assert text.count("tpu_custom_call") >= 2  # dlhs and drhs
 
 
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["ssd_fwd", "ssd_fwd_and_bwd"])
+def test_state_space_scan_compiles_at_the_published_widths(topo, backward):
+    """granite-4.0-h-micro's Mamba-2 layer at 32k tokens: 64 heads of 64, a
+    state of 128, chunks of 256 (ops/ssd.py). Slices at 64 of a tile's 128
+    lanes, columns broadcast from a lane, the states of all heads in VMEM
+    scratch: what the interpreter lets through and Mosaic may not."""
+    from ray_tpu.ops import ssd
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    batch, seq, heads, width, state = 1, 32768, 64, 64, 128
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (arg((batch, seq, heads, width), jnp.bfloat16),
+            arg((batch, seq, heads), jnp.float32), arg((heads,), jnp.float32),
+            arg((batch, seq, state), jnp.bfloat16),
+            arg((batch, seq, state), jnp.bfloat16), arg((heads,), jnp.float32))
+
+    def scan(*a):
+        return ssd.ssd(*a, chunk=256)
+
+    def loss(*a):
+        return scan(*a).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=tuple(range(6))) if backward else scan
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == (2 if backward else 1)
+
+
+def test_flash_compiles_at_32k_with_grouped_kv_heads(topo):
+    """granite-4.0-h-micro's attention layer: 32 query heads over 8 KV heads
+    of 64 at S = 32768 (2,080 executed tiles a head), the model's own score
+    scale, forward and both backward kernels."""
+    shape = (1, 32768, 32, 64)
+    q, k, v = _qkv(topo, shape)
+    k = v = jax.ShapeDtypeStruct((1, 32768, 8, 64), jnp.bfloat16,
+                                 sharding=k.sharding)
+
+    def loss(q, k, v):
+        return flash_mod.flash_attention(
+            q, k, v, True, 512, 512, 1.0 / 64).astype(jnp.float32).sum()
+
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, v).compile()
+    assert grads.as_text().count("tpu_custom_call") >= 3
+    assert flash_mod.causal_tile_census(32768, 512, 512)["executed"] == 2080
+
+
 def test_ragged_sequence_is_an_error_on_tpu(topo):
     """No silent switch to the jnp blockwise path where a kernel exists."""
     with pytest.raises(ValueError, match="multiple of 128"):

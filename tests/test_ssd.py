@@ -1,0 +1,99 @@
+"""ops/ssd.py: the chunked state-space scan's kernel pair (interpreted on the
+CPU) against the chunked ``jax.numpy`` form and against the literal
+recurrence, forward and every cotangent, at lengths that are and are not a
+multiple of the chunk.
+
+float32 throughout: the three compute the same sums in another order, over a
+few hundred terms, so 1e-5 of each array's largest entry is reassociation
+and nothing else.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import ssd as ssd_mod
+import reference_granitemoehybrid as reference
+
+
+def _recurrence(u, dt, A, B, C, D):
+    """The reference's literal recurrence from a zero state."""
+    state = jnp.zeros(u.shape[:1] + u.shape[2:] + B.shape[-1:])
+    return reference._recurrence(state, u, dt, A, B, C, D)[1]
+
+NAMES = ("y", "du", "ddt", "dA", "dB", "dC", "dD")
+HEADS, WIDTH, STATE, CHUNK = 16, 64, 128, 128  # two blocks of 8 heads
+
+
+def _data(seed, batch, seq):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    u = jax.random.normal(ks[0], (batch, seq, HEADS, WIDTH))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (batch, seq, HEADS)) - 2.0)
+    A = -jnp.exp(0.5 * jax.random.normal(ks[2], (HEADS,)))
+    B = 0.3 * jax.random.normal(ks[3], (batch, seq, STATE))
+    C = 0.3 * jax.random.normal(ks[4], (batch, seq, STATE))
+    D = jax.random.normal(ks[5], (HEADS,))
+    return (u, dt, A, B, C, D), jax.random.normal(ks[6], u.shape)
+
+
+def _all(fn, args, g):
+    y, vjp = jax.vjp(fn, *args)
+    return (y,) + vjp(g)
+
+
+@pytest.fixture(scope="module")
+def results():
+    """{seq: {path: (y, du, ddt, dA, dB, dC, dD)}} for a length that is a
+    multiple of the chunk (three chunks: the kernels) and one that is not
+    (``ssd`` takes the chunked form, padded)."""
+    out = {}
+    for seq in (3 * CHUNK, 200):
+        args, g = _data(seq, 2, seq)
+        out[seq] = {
+            "literal": _all(_recurrence, args, g),
+            "chunked": _all(lambda *a: ssd_mod.ssd_chunked(
+                *a, chunk=CHUNK), args, g),
+            "ssd": _all(lambda *a: ssd_mod.ssd(*a, chunk=CHUNK), args, g)}
+    return out
+
+
+@pytest.mark.parametrize("index", range(len(NAMES)), ids=NAMES)
+@pytest.mark.parametrize("against", ["literal", "chunked"])
+@pytest.mark.parametrize("seq", [3 * CHUNK, 200])
+def test_ssd_matches(results, seq, against, index):
+    got, want = results[seq]["ssd"][index], results[seq][against][index]
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+
+
+def test_the_kernels_ran_where_the_shapes_tile(monkeypatch):
+    """A multiple of the chunk goes to the kernels, anything else to the
+    chunked form: the comparison above is not the oracle with itself."""
+    calls = []
+    real = ssd_mod._ssd_kernels
+    monkeypatch.setattr(ssd_mod, "_ssd_kernels",
+                        lambda *a: calls.append(a[0].shape) or real(*a))
+    for seq, expected in ((2 * CHUNK, 1), (200, 1)):
+        args, _ = _data(0, 1, seq)
+        ssd_mod.ssd(*args, chunk=CHUNK)
+        assert len(calls) == expected
+    assert ssd_mod.heads_per_block(64, 64) == 8
+    assert ssd_mod.heads_per_block(4, 64) == 4
+    assert ssd_mod.heads_per_block(3, 64) == 0
+
+
+def test_a_state_is_carried_across_chunks():
+    """With slow decay the output late in the sequence depends on the first
+    chunk's input: the carried state does the work, not the chunk's own
+    quadratic form."""
+    (u, dt, A, B, C, D), _ = _data(1, 1, 3 * CHUNK)
+    dt, A = 0.01 * jnp.ones_like(dt), -jnp.ones_like(A)
+    y = ssd_mod.ssd(u, dt, A, B, C, D, chunk=CHUNK)
+    moved = ssd_mod.ssd(u.at[:, :CHUNK].multiply(2.0), dt, A, B, C, D,
+                        chunk=CHUNK)
+    assert float(jnp.abs(moved - y)[:, 2 * CHUNK:].max()) > 1e-3
+    np.testing.assert_allclose(
+        moved, _recurrence(u.at[:, :CHUNK].multiply(2.0), dt, A, B, C, D),
+        atol=1e-4)
