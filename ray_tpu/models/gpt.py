@@ -57,12 +57,14 @@ class GPTConfig:
     dtype: Any = jnp.bfloat16  # activation/compute dtype
     param_dtype: Any = jnp.float32
     remat: bool = True  # checkpoint each block (HBM ⇄ FLOPs trade)
-    # "full": save only block boundaries, recompute everything in backward
-    # (lowest memory). "selective": additionally save the named tensors
-    # tagged in _block (rotary q/k/v, attention output, pre-activation FFN)
-    # — the expensive-to-recompute matmul outputs — cutting backward
-    # recompute to layernorms + the attention quadratic term for ~2.5x less
-    # activation memory than no remat at all.
+    # "full": save block boundaries and, at long sequences, the flash
+    # forward kernel's output and log-sum-exp (O(S) bytes that cost an
+    # O(S^2) kernel to get back: lm.scan_blocks), recompute everything
+    # else in backward (lowest memory). "selective": additionally save the named tensors tagged in
+    # _block (rotary q/k/v, attention output, pre-activation FFN) — the
+    # expensive-to-recompute matmul outputs — cutting backward recompute
+    # to layernorms and the elementwise rest for ~2.5x less activation
+    # memory than no remat at all.
     remat_policy: str = "full"  # "full" | "selective"
     # Tokens per cross-entropy chunk (0 = unchunked). The [tokens, vocab]
     # fp32 logits and their cotangent are the single largest activation in

@@ -81,6 +81,9 @@ class GraniteConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
+    # "full" keeps of a block only what the flash forward kernel returns
+    # (output and log-sum-exp, at long sequences: lm.scan_blocks). "selective" adds the values
+    # a block names for it, and this model's blocks name none.
     remat_policy: str = "full"
     loss_chunk: int = 0
     attn_impl: str = "dot"  # "dot" | "flash"

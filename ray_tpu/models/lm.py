@@ -62,6 +62,21 @@ def scan_blocks(cfg, block, x, layers, positions, layer_types=None):
     ``cfg.remat`` / ``cfg.remat_policy``. Returns (x, aux stacked over
     layers; None where the block returns None).
 
+    ``"full"`` keeps the block's input and, of everything inside it, only
+    the flash forward kernel's output and log-sum-exp
+    (``flash_attention.RESIDUAL_NAMES``): the backward pass recomputes the
+    block's XLA operations (norms, projections, rope, the MLP's first
+    product) and not the kernel, which is O(S^2) work for O(S) bytes. The
+    kernel names them only from the S / Dv at which a kept byte buys
+    enough (``flash_attention.worth_keeping``, with the v5e's numbers:
+    887 ms of step a GB at S = 32768 and heads of 64, 12-16 at 2048 and
+    256, where the output of a 4096-wide matmul would buy 21); below
+    it, and in a block without the kernels (``dot``, a ragged
+    sequence's blockwise path, a state-space layer), ``"full"`` keeps
+    nothing. ``"selective"`` keeps the same two beside the five values a
+    model names in its block (``attn_q``, ``attn_k``, ``attn_v``,
+    ``attn_raw``, ``ffn_in``).
+
     A stack of several kinds of layer gives ``layer_types``, the kind of
     every layer in order, ``block`` as a dict by kind and ``layers`` as a
     sequence with one stack for every run of one kind, in order (a model
@@ -80,15 +95,17 @@ def scan_blocks(cfg, block, x, layers, positions, layer_types=None):
             auxes.append(aux)
         return x, auxes
     if cfg.remat:
+        from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
         if cfg.remat_policy == "selective":
-            policy = jax.checkpoint_policies.save_only_these_names(
-                "attn_q", "attn_k", "attn_v", "attn_raw", "ffn_in")
+            kept = ("attn_q", "attn_k", "attn_v", "attn_raw", "ffn_in")
         elif cfg.remat_policy == "full":
-            policy = jax.checkpoint_policies.nothing_saveable
+            kept = ()
         else:
             raise ValueError(
                 f"Unknown remat_policy {cfg.remat_policy!r}; "
                 "expected 'full' or 'selective'")
+        policy = jax.checkpoint_policies.save_only_these_names(
+            *kept, *RESIDUAL_NAMES)
         block = jax.checkpoint(block, policy=policy)
 
     def scan_body(x, layer):

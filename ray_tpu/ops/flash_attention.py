@@ -45,12 +45,22 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.blockwise_attention import blockwise_attention
 
 _NEG_INF = -1e30
+
+# The names of the forward kernel's two outputs among the custom VJP's
+# residuals. A rematerialisation policy that keeps them
+# (``save_only_these_names(*RESIDUAL_NAMES)``, as models/lm.py
+# ``scan_blocks`` does) recomputes q, k and v in the backward pass and not
+# the kernel: O(S^2) work for O(S) bytes, ``out`` [B, S, H, Dv] in the
+# activations' dtype and ``lse`` [B*H, 1, S] in f32. The outputs carry the
+# names only where ``worth_keeping`` says a kept byte buys enough.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def _interpret() -> bool:
@@ -106,6 +116,22 @@ def causal_tile_census(S: int, blk_q: int, blk_k: int) -> dict:
     executed = qi.size - empty
     return {"executed": executed, "diagonal": executed - full,
             "full": full, "empty": empty}
+
+
+def worth_keeping(S: int, Dv: int) -> bool:
+    """Whether the forward kernel's outputs are worth their bytes to a
+    backward pass that could run the kernel again instead: from
+    S / Dv = 32. A head's causal forward is S^2 / (2 blk^2) tiles for
+    2 S Dv bytes of output, so a kept byte buys kernel time in proportion
+    to S / Dv, about 2 ms a GB for each unit of it at 512 x 512 tiles of
+    1.9-2.3 us. Measured on a v5e, step time saved over bytes kept
+    (PERF.md §6, PR 30): 887 ms a GB at S / Dv = 512 (S 32768, heads of
+    64), 220 at 64 (S 8192, Dv 128), but 12-16 at 8 (S 2048, heads of
+    256), less than the 21 ms a GB that the output of a 4096-wide matmul
+    buys, and there the 1.35 GB a chip crowded a checkpoint's transfers
+    (a save stalled the loop 10.3 s, not 8.9). Nothing was measured
+    between 8 and 64; at 32 the estimate is three times a matmul's."""
+    return S >= 32 * Dv
 
 
 def _row_ends(row_tab):
@@ -432,6 +458,11 @@ def _fwd(q, k, v, causal, blk_q, blk_k, scale):
     if lse is None:
         # Ragged fallback: differentiate the jnp blockwise recurrence.
         return out, (q, k, v, None, None)
+    if worth_keeping(q.shape[1], v.shape[-1]):
+        # Named once, before ``out`` goes out both as the primal and as a
+        # residual, so that both are the one kept value.
+        out = checkpoint_name(out, RESIDUAL_NAMES[0])
+        lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return out, (q, k, v, out, lse)
 
 

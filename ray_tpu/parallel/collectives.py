@@ -5,7 +5,10 @@ decided by the partitioner; what it decided is only in the compiled
 program. ``census(compiled.as_text())`` lists every collective there once,
 with the bytes a chip holds of its result, the size of its replica group,
 where it runs and the JAX operation it came from.
-``tests/test_chip_compile.py`` holds the GPT-J step to its census. The
+``tests/test_chip_compile.py`` holds the GPT-J step to its census.
+``kernel_census`` counts the same program's Pallas kernel calls by name:
+how often a step runs ``flash_fwd`` says whether its backward pass keeps
+the kernel's outputs or runs it again (``models/lm.py: scan_blocks``). The
 four-chip benchmark cell's real step, compiled for a described ``v5e:2x2``
 without a chip (25 s) and read:
 
@@ -148,13 +151,48 @@ def census(hlo_text: str) -> List[Dict[str, Any]]:
     return found_ops
 
 
+_KERNEL_CALL = re.compile(
+    r'custom_call_target="tpu_custom_call".*?'
+    r'op_name="[^"]*?([\w.\-]+)\)*/pallas_call"')
+
+
+def kernel_census(program: Any) -> Dict[str, int]:
+    """How many calls of each Pallas kernel a program holds, by the name
+    the kernel was given (``pallas_call(name=...)``). ``program`` is a
+    compiled program's HLO text, where every ``tpu_custom_call`` counts, or
+    a jaxpr, where every ``pallas_call`` equation does, the interpreted
+    ones of a CPU trace too, sub-jaxprs looked through. A call in a loop's
+    body is one call, however often the loop runs: a layer scan with its
+    backward scan holds each kernel of its block once or twice."""
+    if isinstance(program, str):
+        return dict(collections.Counter(_KERNEL_CALL.findall(program)))
+    counts: collections.Counter = collections.Counter()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                counts[eqn.params["name"]] += 1
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (list, tuple)) \
+                        else (param,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(getattr(program, "jaxpr", program))
+    return dict(counts)
+
+
 def main(argv: Optional[List[str]] = None) -> None:
-    """Print the census of an HLO text file, largest first; an optional
-    second argument is the least size in MB worth a line."""
+    """Print the census of an HLO text file, largest first, under its
+    count of kernel calls; an optional second argument is the least size
+    in MB worth a line."""
     argv = sys.argv[1:] if argv is None else argv
     with open(argv[0]) as f:
-        ops = census(f.read())
+        text = f.read()
+    ops = census(text)
     least = float(argv[1]) * 1e6 if len(argv) > 1 else 0.0
+    print(kernel_census(text))
     print(dict(collections.Counter(op["kind"] for op in ops)))
     for op in sorted(ops, key=lambda op: -op["bytes"]):
         if op["bytes"] >= least:

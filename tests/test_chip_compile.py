@@ -249,6 +249,52 @@ def test_flash_step_compiles_on_four_chips(topo):
     assert per_device < 16 * 2 ** 30, per_device
 
 
+@pytest.mark.parametrize("shaped_like", ["granite-4.0-h-micro",
+                                         "moonlight-16b-a3b", "gptj-6b"])
+def test_step_runs_the_flash_forward_once_a_layer(topo, shaped_like):
+    """The benchmark's three models, every width, sequence and batch
+    theirs, cut to one layer of each kind that attends (granite: its
+    attention layer; Moonlight: the dense layer and one expert layer, a
+    scan each; GPT-J: one block), in the whole train step under full remat.
+    At granite's S / Dv = 512 and Moonlight's 64 the layer scan keeps the
+    forward kernel's output and log-sum-exp
+    (``flash_attention.RESIDUAL_NAMES``), so the compiled step holds
+    ``flash_fwd`` once a layer beside the two backward kernels; at GPT-J's
+    8 a kept byte buys too little (``worth_keeping``) and the step runs
+    the kernel again, as every step does with the names taken out
+    (``tests/test_remat_residuals.py``)."""
+    from ray_tpu.models import deepseek, granite
+    from ray_tpu.parallel.collectives import kernel_census
+    common = dict(attn_impl="flash", remat_policy="full", loss_chunk=4096,
+                  param_dtype=jnp.bfloat16)
+    if shaped_like == "granite-4.0-h-micro":
+        cfg = granite.config(shaped_like, num_hidden_layers=1,
+                             layer_types=("attention",), **common)
+        layers, shape, kept = 1, (1, 32768), True
+    elif shaped_like == "moonlight-16b-a3b":
+        cfg = deepseek.config(shaped_like, num_hidden_layers=2, **common)
+        layers, shape, kept = 2, (2, 8192), True
+    else:
+        cfg = gpt.config(shaped_like, n_layers=1, **common)
+        layers, shape, kept = 1, (8, 2048), False
+    assert cfg.remat
+    mesh = build_mesh(MeshConfig(dp=1, fsdp=1, tp=1),
+                      devices=topo.devices[:1])
+    rules = ShardingRules()
+    optimizer = memory_efficient_optimizer(learning_rate=1e-4)
+    state = abstract_train_state(cfg, mesh, rules, optimizer)
+    tokens = jax.ShapeDtypeStruct(
+        shape, jnp.int32,
+        sharding=NamedSharding(mesh, PartitionSpec(("dp", "fsdp"), None)))
+
+    calls = kernel_census(
+        make_train_step(cfg, mesh, rules, optimizer).lower(
+            state, {"tokens": tokens, "targets": tokens}).compile().as_text())
+    assert [calls[name] for name in ("flash_fwd", "flash_bwd_dq",
+                                     "flash_bwd_dkv")] == \
+        [layers if kept else 2 * layers, layers, layers]
+
+
 @pytest.mark.parametrize("parallel_block", [True, False])
 def test_step_sends_what_fsdp_x_tp_needs(topo, parallel_block):
     """The GPT-J step (every width as published, two layers: the scan's
