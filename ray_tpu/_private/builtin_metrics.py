@@ -806,32 +806,46 @@ def train_ckpt_orphans_gc() -> Counter:
 
 # -- expert layers ---------------------------------------------------------
 # Fed by parallel/train_step.py from the scalars a step of an expert model
-# returns (models/deepseek.py), one call late at most, never by a sync.
+# returns (models/deepseek.py, models/afmoe.py), one call late at most,
+# never by a sync.
 
 
 def train_moe_assignments() -> Counter:
     from ray_tpu.util.metrics import Counter
     return Counter(
         "ray_tpu_train_moe_assignments_total",
-        "Token-to-expert assignments the expert layers computed (the sum "
-        "of their group sizes). Equal to ray_tpu_train_moe_tokens_total, "
-        "or an assignment was dropped.")
+        "Token-to-expert assignments the expert layers computed: the rows "
+        "of their grouped matmuls (the sum of the group sizes of the "
+        "experts held here). Equal to ray_tpu_train_moe_tokens_total, or "
+        "an assignment was dropped.")
 
 
 def train_moe_tokens() -> Counter:
     from ray_tpu.util.metrics import Counter
     return Counter(
         "ray_tpu_train_moe_tokens_total",
-        "Assignments the routing asked for: tokens x experts per token x "
-        "expert layers, per step.")
+        "Assignments the routing gave to experts held here. With every "
+        "expert held: tokens x experts per token x expert layers, per "
+        "step.")
+
+
+def train_moe_routed() -> Counter:
+    from ray_tpu.util.metrics import Counter
+    return Counter(
+        "ray_tpu_train_moe_routed_total",
+        "Every assignment the routing made, to experts held here or not: "
+        "tokens x experts per token x expert layers, per step. "
+        "ray_tpu_train_moe_tokens_total over it is this chip's share of "
+        "the routing's work.")
 
 
 def train_moe_expert_load() -> Gauge:
     from ray_tpu.util.metrics import Gauge
     return Gauge(
         "ray_tpu_train_moe_expert_load_max_over_mean",
-        "Busiest expert's assignments over the mean, worst expert layer "
-        "of the last recorded step (1.0 = balanced).")
+        "Busiest expert's assignments over the mean of the experts held "
+        "here, worst expert layer of the last recorded step (1.0 = "
+        "balanced).")
 
 
 def channel_bytes_sent() -> Counter:

@@ -1,5 +1,5 @@
-from ray_tpu.models import (bert, deepseek, diffusion, gpt, granite, lm, t5,
-                            vit)
+from ray_tpu.models import (afmoe, bert, deepseek, diffusion, gpt, granite,
+                            lm, t5, vit)
 
-__all__ = ["bert", "deepseek", "diffusion", "gpt", "granite", "lm", "t5",
-           "vit"]
+__all__ = ["afmoe", "bert", "deepseek", "diffusion", "gpt", "granite", "lm",
+           "t5", "vit"]

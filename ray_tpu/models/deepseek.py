@@ -36,8 +36,12 @@ Every assignment is computed: no capacity, no drop. The correction bias
 ``b`` steers selection only and is not trained by the gradient (its gradient
 is zero; the published update rule's step size is not in the config, so no
 rule moves it here either).
-Expert parallelism (an ``ep`` mesh axis > 1) is not implemented: the experts
-of a layer live whole on every chip of the ``ep`` axis's group.
+Every expert of a layer is held here: the layer tells ``ops/moe.py`` so
+(``held=(0, n_routed_experts)``, the whole layer; a chip's share of a layer,
+``held=(first, count)`` with the weights of those experts alone, is what
+``models/afmoe.py`` runs). Holding a share is not expert parallelism: there
+is no exchange of tokens between chips in either model, and a mesh with an
+``ep`` axis > 1 still raises ``NotImplementedError`` here.
 """
 
 from __future__ import annotations
@@ -283,7 +287,7 @@ def _block(cfg: DeepseekConfig, h, layer, positions):
         x.reshape(B * S, d), layer["router"], layer["router_bias"],
         layer["w_gate"], layer["w_up"], layer["w_down"],
         top_k=cfg.num_experts_per_tok, scaling=cfg.routed_scaling_factor,
-        normalize=cfg.norm_topk_prob)
+        normalize=cfg.norm_topk_prob, held=(0, cfg.n_routed_experts))
     with jax.named_scope("shared_expert"):
         shared = _swiglu(x, layer["shared_w_gate"], layer["shared_w_up"],
                          layer["shared_w_down"])

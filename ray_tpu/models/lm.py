@@ -2,9 +2,9 @@
 owns: the embedding lookup, the layer scan with rematerialisation, the
 attention dispatch (which attention runs, and how it is laid over a mesh),
 the state-space scan's, and the chunked head and loss. ``models/gpt.py``,
-``models/deepseek.py`` and ``models/granite.py`` are built from these; a new
-family brings its config, parameters, block and head and is written against
-this module, not against another model.
+``models/deepseek.py``, ``models/granite.py`` and ``models/afmoe.py`` are
+built from these; a new family brings its config, parameters, block and head
+and is written against this module, not against another model.
 
 A model's config is read here for the program's own choices only, under the
 names ``GPTConfig`` gives them: ``attn_impl``, ``attn_blk_q``,
@@ -117,10 +117,12 @@ def scan_blocks(cfg, block, x, layers, positions, layer_types=None):
 
 # -- attention ------------------------------------------------------------
 
-def dot_attention(q, k, v, scale: Optional[float] = None):
+def dot_attention(q, k, v, scale: Optional[float] = None,
+                  window: Optional[int] = None):
     """Causal attention; fp32 softmax. q: [B, S, H, D], k: [B, S, KVH, D],
     v: [B, S, KVH, Dv] (Dv may differ from D) -> [B, S, H, Dv]. Scores are
-    multiplied by ``scale`` (1/sqrt(D) if None)."""
+    multiplied by ``scale`` (1/sqrt(D) if None). With ``window`` a query
+    sees itself and the ``window - 1`` keys before it."""
     B, S, H, D = q.shape
     kvh = k.shape[2]
     if kvh != H:  # GQA: repeat KV heads
@@ -134,6 +136,8 @@ def dot_attention(q, k, v, scale: Optional[float] = None):
     qpos = jax.lax.broadcasted_iota(jnp.int32, (S, S), 0)
     kpos = jax.lax.broadcasted_iota(jnp.int32, (S, S), 1)
     causal = qpos >= kpos
+    if window is not None:
+        causal &= qpos - kpos < window
     logits = jnp.where(causal[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -155,19 +159,23 @@ def attention_specs(mesh, n_heads: int, n_kv_heads: int, seq_axis):
     return q_spec, kv_spec
 
 
-def attention(q, k, v, cfg, scale: Optional[float] = None):
+def attention(q, k, v, cfg, scale: Optional[float] = None,
+              window: Optional[int] = None):
     """Causal attention by ``cfg.attn_impl`` (and, for the flash kernels,
     ``cfg.attn_blk_q`` / ``cfg.attn_blk_k``): the one dispatch every model
     of this package goes through. cfg is any model's config. Scores are
     multiplied by ``scale``, the model's own where its config publishes
-    one, 1/sqrt(D) if None (``dot`` and ``flash`` only)."""
+    one, 1/sqrt(D) if None; ``window`` is the layer's own where it has
+    one: a query then sees itself and the ``window - 1`` keys before it
+    (both ``dot`` and ``flash`` only)."""
     if cfg.attn_impl == "dot":
-        return dot_attention(q, k, v, scale)
+        return dot_attention(q, k, v, scale, window)
     if cfg.attn_impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention
         from ray_tpu.parallel.mesh import current_mesh
         fn = partial(flash_attention, causal=True,
-                     blk_q=cfg.attn_blk_q, blk_k=cfg.attn_blk_k, scale=scale)
+                     blk_q=cfg.attn_blk_q, blk_k=cfg.attn_blk_k, scale=scale,
+                     window=window)
         mesh = current_mesh()
         if mesh is None or mesh.size == 1:
             return fn(q, k, v)
@@ -187,6 +195,10 @@ def attention(q, k, v, cfg, scale: Optional[float] = None):
         raise NotImplementedError(
             f"attn_impl={cfg.attn_impl!r} scales scores by 1/sqrt(D) only; "
             "a model with its own score scale needs 'dot' or 'flash'")
+    if window is not None:
+        raise NotImplementedError(
+            f"attn_impl={cfg.attn_impl!r} has no window (window={window}): "
+            "a layer of sliding-window attention needs 'dot' or 'flash'")
     if cfg.attn_impl == "ring":
         from ray_tpu.ops.ring_attention import make_ring_attention
         from ray_tpu.parallel.mesh import current_mesh

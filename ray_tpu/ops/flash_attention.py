@@ -15,6 +15,16 @@ first row) has not: leaving the mask out of full tiles was measured and
 bought nothing, the backward kernels being bound by their products and the
 forward by its row maximum. ``causal_tile_census`` counts the classes.
 
+A ``window`` (sliding-window attention: key j is seen by query i where
+``0 <= i - j < window``) is the same table with one more edge: the tiles
+wholly behind the window's trailing edge are left out as those above the
+diagonal are, and a tile the trailing edge cuts is masked as one the
+diagonal cuts (``window_tile_census``). With ``window=None``, or a window
+the sequence does not reach, tables, kernels and their names are the causal
+ones; a call with a window that cuts something carries names of its own
+(``flash_fwd_win``, ``flash_bwd_dq_win``, ``flash_bwd_dkv_win``), so that a
+trace tells a window layer's kernels from a full layer's.
+
 The forward kernel walks the table Q-major, KV tiles ascending, with the
 online-softmax state in VMEM scratch, keeping the MXU fed with
 [blk_q, D] x [D, blk_k] matmuls (pallas_guide.md: grid/BlockSpec + scratch
@@ -67,25 +77,36 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _causal_mask(qi, ki, blk_q: int, blk_k: int):
-    """[blk_q, blk_k] bool: query row >= key column, for tiles qi and ki."""
+def _causal_mask(qi, ki, blk_q: int, blk_k: int, window=None):
+    """[blk_q, blk_k] bool: query row >= key column, for tiles qi and ki;
+    with a ``window`` also query row - key column < window."""
     q_pos = qi * blk_q + jax.lax.broadcasted_iota(
         jnp.int32, (blk_q, blk_k), 0)
     k_pos = ki * blk_k + jax.lax.broadcasted_iota(
         jnp.int32, (blk_q, blk_k), 1)
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return (q_pos >= k_pos) & (q_pos - k_pos < window)
 
 
-def _is_empty(qi, ki, blk_q: int, blk_k: int):
+def _is_empty(qi, ki, blk_q: int, blk_k: int, window=None):
     """Causal tile (qi, ki) allows no pair: its first column is past its
-    last row."""
-    return ki * blk_k > (qi + 1) * blk_q - 1
+    last row, or (with a ``window``) its last column is ``window`` or more
+    behind its first row."""
+    above = ki * blk_k > (qi + 1) * blk_q - 1
+    if window is None:
+        return above
+    return above | ((ki + 1) * blk_k - 1 <= qi * blk_q - window)
 
 
-def _is_full(qi, ki, blk_q: int, blk_k: int):
+def _is_full(qi, ki, blk_q: int, blk_k: int, window=None):
     """Causal tile (qi, ki) allows every pair: its last column is at or
-    before its first row."""
-    return (ki + 1) * blk_k - 1 <= qi * blk_q
+    before its first row, and (with a ``window``) its first column is less
+    than ``window`` behind its last row."""
+    under = (ki + 1) * blk_k - 1 <= qi * blk_q
+    if window is None:
+        return under
+    return under & ((qi + 1) * blk_q - 1 - ki * blk_k < window)
 
 
 def _tile_grid(S: int, blk_q: int, blk_k: int):
@@ -94,12 +115,12 @@ def _tile_grid(S: int, blk_q: int, blk_k: int):
 
 
 def _tile_pairs(S: int, blk_q: int, blk_k: int, causal: bool,
-                kv_major: bool):
+                kv_major: bool, window=None):
     """The tiles with work as two int32 tables (qi_tab, ki_tab), one entry
     a grid step: Q-major with KV tiles ascending, or KV-major with Q tiles
     ascending, so every sum a kernel carries keeps its order."""
     qi, ki = _tile_grid(S, blk_q, blk_k)
-    keep = ~_is_empty(qi, ki, blk_q, blk_k) if causal \
+    keep = ~_is_empty(qi, ki, blk_q, blk_k, window) if causal \
         else np.ones_like(qi, bool)
     if kv_major:
         qi, ki, keep = qi.T, ki.T, keep.T
@@ -110,12 +131,32 @@ def causal_tile_census(S: int, blk_q: int, blk_k: int) -> dict:
     """How many of a causal S x S attention's tiles are of each class:
     ``executed`` (= ``diagonal`` + ``full``) is the length of the kernels'
     table, ``empty`` the tiles that get no grid step."""
+    return window_tile_census(S, None, blk_q, blk_k)
+
+
+def window_tile_census(S: int, window, blk_q: int, blk_k: int) -> dict:
+    """``causal_tile_census`` under a ``window``: ``diagonal`` counts the
+    tiles either edge cuts (the diagonal or the window's trailing edge),
+    ``empty`` those above the diagonal or wholly behind the window. At
+    tiles of 512 x 512 a window of 4096 executes 252 of a 16384-token
+    head's tiles (causal: 528) and 540 of a 32768-token head's (2,080)."""
     qi, ki = _tile_grid(S, blk_q, blk_k)
-    empty = int(_is_empty(qi, ki, blk_q, blk_k).sum())
-    full = int(_is_full(qi, ki, blk_q, blk_k).sum())
+    empty = int(_is_empty(qi, ki, blk_q, blk_k, window).sum())
+    full = int(_is_full(qi, ki, blk_q, blk_k, window).sum())
     executed = qi.size - empty
     return {"executed": executed, "diagonal": executed - full,
             "full": full, "empty": empty}
+
+
+def _cutting(window, S: int):
+    """``window`` where it cuts something of a causal S x S attention, else
+    None: a window the sequence does not reach is causal attention, by the
+    causal kernels under their own names."""
+    if window is None:
+        return None
+    if window < 1:
+        raise ValueError(f"window must be at least 1, got {window}")
+    return int(window) if window < S else None
 
 
 def worth_keeping(S: int, Dv: int) -> bool:
@@ -148,7 +189,7 @@ def _row_ends(row_tab):
 
 def _flash_fwd_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, lse_ref,
                       m_scr, l_scr, o_scr, *, blk_q: int, blk_k: int,
-                      causal: bool, scale: float):
+                      causal: bool, scale: float, window=None):
     """Grid: (batch*heads, pairs), Q-major, the pair axis sequential. One
     [blk_q, D] Q tile against one [blk_k, D] / [blk_k, Dv] KV tile per
     step, the online-softmax state (m, l, o) carried in VMEM scratch along
@@ -171,14 +212,19 @@ def _flash_fwd_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, lse_ref,
         q, k_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     if causal:
-        logits = jnp.where(_causal_mask(qi, ki, blk_q, blk_k), logits,
-                           _NEG_INF)
+        logits = jnp.where(_causal_mask(qi, ki, blk_q, blk_k, window),
+                           logits, _NEG_INF)
     m = m_scr[...]
     m_new = jnp.maximum(m, logits.max(-1, keepdims=True))
     corr = jnp.exp(m - m_new)
     # A masked logit is -1e30 and every row has seen column 0 by now (a
     # row of tiles starts at KV tile 0), so m_new is a real logit and
     # exp(-1e30 - m_new) is 0.0 exactly: p needs no select of its own.
+    # Under a window a row of tiles starts at the first tile the window
+    # reaches, of which the later query rows may see nothing: m_new is
+    # then still -1e30, p is 1 and l and o gather what they should not,
+    # until the row's first real logit (every row sees itself) makes
+    # corr = exp(-1e30 - m_new) = 0.0 exactly and wipes both.
     p = jnp.exp(logits - m_new)
     l_scr[...] = l_scr[...] * corr + p.sum(-1, keepdims=True)
     o_scr[...] = o_scr[...] * corr + jax.lax.dot_general(
@@ -195,7 +241,8 @@ def _flash_fwd_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_bwd_dq_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref,
                          lse_ref, delta_ref, dq_ref, dq_scr, *, blk_q: int,
-                         blk_k: int, causal: bool, scale: float):
+                         blk_k: int, causal: bool, scale: float,
+                         window=None):
     """Grid: (batch*heads, pairs), Q-major, the pair axis sequential: dq
     for one Q tile, accumulated over its row of KV tiles."""
     t = pl.program_id(1)
@@ -215,7 +262,7 @@ def _flash_bwd_dq_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref,
         preferred_element_type=jnp.float32)
     p = jnp.exp(logits - lse_ref[0, :][:, None])
     if causal:
-        p = jnp.where(_causal_mask(qi, ki, blk_q, blk_k), p, 0.0)
+        p = jnp.where(_causal_mask(qi, ki, blk_q, blk_k, window), p, 0.0)
     dp = jax.lax.dot_general(
         g, v_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -232,7 +279,7 @@ def _flash_bwd_dq_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref,
 def _flash_bwd_dkv_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref,
                           lse_ref, delta_ref, dk_ref, dv_ref, dk_scr,
                           dv_scr, *, blk_q: int, blk_k: int, causal: bool,
-                          scale: float):
+                          scale: float, window=None):
     """Grid: (batch*heads, pairs), KV-major, the pair axis sequential:
     dk/dv for one KV tile, accumulated over the Q, dO, lse and delta tiles
     of its row (those at or after the diagonal when causal)."""
@@ -254,7 +301,7 @@ def _flash_bwd_dkv_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref,
         preferred_element_type=jnp.float32)
     p = jnp.exp(logits - lse_ref[0, :][:, None])
     if causal:
-        p = jnp.where(_causal_mask(qi, ki, blk_q, blk_k), p, 0.0)
+        p = jnp.where(_causal_mask(qi, ki, blk_q, blk_k, window), p, 0.0)
     dv_scr[...] += jax.lax.dot_general(
         p, g_blk, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -338,19 +385,32 @@ def _score_scale(scale, D: int) -> float:
     return 1.0 / math.sqrt(D) if scale is None else float(scale)
 
 
+def _named(name: str, window) -> str:
+    return name + "_win" if window is not None else name
+
+
 def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int,
-                   scale=None):
+                   scale=None, window=None):
     B, S, H, D = q.shape
     Dv = v.shape[-1]
     k, v = _repeat_heads(k, v, H)
     scale = _score_scale(scale, D)
     blk_q = _pick_block(S, blk_q)
     blk_k = _pick_block(S, blk_k)
+    window = _cutting(window, S)
+    if window is not None and not causal:
+        raise ValueError("a window is the causal mask's trailing edge: "
+                         "window needs causal=True")
     if blk_q < 128 or blk_k < 128:
         if not _interpret():
             raise ValueError(
                 f"flash_attention on TPU needs a sequence length that is a "
                 f"multiple of 128, got S={S}: pad the sequence or use "
+                "attn_impl='dot'")
+        if window is not None:
+            raise NotImplementedError(
+                f"flash_attention with window={window}: the blockwise path "
+                f"that a ragged sequence (S={S}) takes has no window; use "
                 "attn_impl='dot'")
         # Short or ragged sequence on the CPU test backend, where there is
         # no kernel to lose: the jnp blockwise path (no lse output — the
@@ -361,10 +421,10 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int,
 
     kernel = functools.partial(
         _flash_fwd_kernel, blk_q=blk_q, blk_k=blk_k, causal=causal,
-        scale=scale)
+        scale=scale, window=window)
     out, lse = _tiled_call(
-        kernel, "flash_fwd", B * H,
-        _tile_pairs(S, blk_q, blk_k, causal, False),
+        kernel, _named("flash_fwd", window), B * H,
+        _tile_pairs(S, blk_q, blk_k, causal, False, window),
         in_specs=[
             pl.BlockSpec((None, blk_q, D), _q_tile),
             pl.BlockSpec((None, blk_k, D), _kv_tile),
@@ -386,8 +446,9 @@ def _flash_forward(q, k, v, causal: bool, blk_q: int, blk_k: int,
 
 
 def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
-                    blk_k: int, scale=None):
+                    blk_k: int, scale=None, window=None):
     B, S, H, D = q.shape
+    window = _cutting(window, S)
     Dv = v.shape[-1]
     kvh = k.shape[2]
     k_rep, v_rep = _repeat_heads(k, v, H)
@@ -396,7 +457,8 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
     gf, of = _to_bh(g), _to_bh(out)
     delta = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32),
                     axis=-1)[:, None, :]  # [BH, 1, S]
-    common = dict(blk_q=blk_q, blk_k=blk_k, causal=causal, scale=scale)
+    common = dict(blk_q=blk_q, blk_k=blk_k, causal=causal, scale=scale,
+                  window=window)
     # The six operands of both backward kernels, tiled alike in both.
     operands = (qf, kf, vf, gf, lse, delta)
     in_specs = [
@@ -408,8 +470,9 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
         pl.BlockSpec((None, 1, blk_q), _q_row),
     ]
     dq = _tiled_call(
-        functools.partial(_flash_bwd_dq_kernel, **common), "flash_bwd_dq",
-        B * H, _tile_pairs(S, blk_q, blk_k, causal, False),
+        functools.partial(_flash_bwd_dq_kernel, **common),
+        _named("flash_bwd_dq", window), B * H,
+        _tile_pairs(S, blk_q, blk_k, causal, False, window),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, blk_q, D), _q_tile),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
@@ -417,8 +480,9 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
     )(*operands)
 
     dk, dv = _tiled_call(
-        functools.partial(_flash_bwd_dkv_kernel, **common), "flash_bwd_dkv",
-        B * H, _tile_pairs(S, blk_q, blk_k, causal, True),
+        functools.partial(_flash_bwd_dkv_kernel, **common),
+        _named("flash_bwd_dkv", window), B * H,
+        _tile_pairs(S, blk_q, blk_k, causal, True, window),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((None, blk_k, D), _kv_tile),
@@ -443,18 +507,20 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True, blk_q: int = 1024,
-                    blk_k: int = 1024, scale=None):
+                    blk_k: int = 1024, scale=None, window=None):
     """q: [B, S, H, D], k: [B, S, KVH, D], v: [B, S, KVH, Dv] →
     [B, S, H, Dv]. Query head i reads KV head i // (H // KVH). Scores are
     multiplied by ``scale``: the model's own (a Python float), or
-    1/sqrt(D) where it is None."""
-    return _flash_forward(q, k, v, causal, blk_q, blk_k, scale)[0]
+    1/sqrt(D) where it is None. With ``window`` (an int; causal only) query
+    i sees the keys j with ``0 <= i - j < window``: itself and the
+    ``window - 1`` before it."""
+    return _flash_forward(q, k, v, causal, blk_q, blk_k, scale, window)[0]
 
 
-def _fwd(q, k, v, causal, blk_q, blk_k, scale):
-    out, lse = _flash_forward(q, k, v, causal, blk_q, blk_k, scale)
+def _fwd(q, k, v, causal, blk_q, blk_k, scale, window):
+    out, lse = _flash_forward(q, k, v, causal, blk_q, blk_k, scale, window)
     if lse is None:
         # Ragged fallback: differentiate the jnp blockwise recurrence.
         return out, (q, k, v, None, None)
@@ -466,7 +532,7 @@ def _fwd(q, k, v, causal, blk_q, blk_k, scale):
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, blk_q, blk_k, scale, residuals, g):
+def _bwd(causal, blk_q, blk_k, scale, window, residuals, g):
     q, k, v, out, lse = residuals
     if lse is None:
         _, vjp = jax.vjp(
@@ -476,7 +542,7 @@ def _bwd(causal, blk_q, blk_k, scale, residuals, g):
     S = q.shape[1]
     return _flash_backward(q, k, v, out, lse, g, causal,
                            _pick_block(S, blk_q), _pick_block(S, blk_k),
-                           scale)
+                           scale, window)
 
 
 flash_attention.defvjp(_fwd, _bwd)

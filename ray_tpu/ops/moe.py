@@ -21,12 +21,28 @@ The backward pass of the two permutations is written out: the cotangent of
 a gather by a permutation is the gather by its inverse, where autodiff would
 emit a scatter-add, which a TPU serialises. So is that of the weighted sum,
 which would otherwise store float32 copies of ``[tokens, top_k, d]``.
+
+**Held experts.** A chip that shares a layer's experts with others holds a
+contiguous run of them, ``held = (first, count)``, and gives
+``routed_experts`` the weights of those alone. The router keeps its whole
+width: scores, ``top_k`` and the normalisation are over all the experts.
+Only the assignments to held experts are multiplied (the grouped matmul
+starts at group ``first`` and computes ``count`` groups; the other rows
+come out zero), and the result is ``sum_{i picked and held} w_i
+Expert_i(x)``: this chip's part of the layer's sum. What the absent
+experts would have added is left out, and nothing stands in for the other
+chips or for an exchange with them: the share is not expert parallelism,
+which a mesh with ``ep`` > 1 would ask for and no model here implements.
+Dropless on the share: every assignment to a held expert is computed, and
+``aux["group_sizes"]`` counts them. The rows are still sorted, gathered
+and combined at ``tokens x top_k``, the only size that holds whatever the
+router decides.
 """
 
 from __future__ import annotations
 
 import importlib
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,18 +58,31 @@ _flash = importlib.import_module("ray_tpu.ops.flash_attention")
 _TILE_M, _TILE_K, _TILE_N_MAX = 512, 512, 1408
 
 
-def grouped_matmul(rows, weights, group_sizes):
+def grouped_matmul(rows, weights, group_sizes, first=None):
     """rows [M, k], sorted into ``len(group_sizes)`` contiguous groups, times
-    each group's own weights [E, k, n] -> [M, n] in rows' dtype."""
+    each group's own weights [E, k, n] -> [M, n] in rows' dtype. With
+    ``first``, ``weights`` are those of the groups ``first`` to ``first +
+    len(weights)`` alone: only their rows are multiplied, the others come
+    out zero."""
     (m, k), n = rows.shape, weights.shape[-1]
     if m % 128 or k % 128 or n % 128:
+        if first is not None:
+            # Three stretches of rows: before, held, after; the outer two
+            # against zero weights.
+            last = first + weights.shape[0]
+            nothing = jnp.zeros((1, k, n), weights.dtype)
+            weights = jnp.concatenate([nothing, weights, nothing])
+            group_sizes = jnp.concatenate([
+                group_sizes[:first].sum()[None], group_sizes[first:last],
+                group_sizes[last:].sum()[None]])
         return jax.lax.ragged_dot(rows, weights, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
     tile_m = next(t for t in (_TILE_M, 256, 128) if m % t == 0)
     tile_n = next(t for t in range(min(n, _TILE_N_MAX), 0, -128)
                   if n % t == 0)
+    offset = None if first is None else jnp.asarray(first, jnp.int32)
     return megablox.gmm(rows, weights, group_sizes, rows.dtype,
-                        (tile_m, min(k, _TILE_K), tile_n),
+                        (tile_m, min(k, _TILE_K), tile_n), offset,
                         interpret=_flash._interpret())
 
 
@@ -152,7 +181,8 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
-                   scaling: float, normalize: bool = True
+                   scaling: float, normalize: bool = True,
+                   held: Optional[Tuple[int, int]] = None
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """sum_i w_i Expert_i(x) over each token's ``top_k`` experts, dropless.
 
@@ -161,9 +191,26 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
     ``(silu(x w_gate_i) * x w_up_i) w_down_i``. Returns (y [T, d], aux) with
     ``aux["picked"]`` [T, K] (the router's choice, for a reference to compare
     with) and ``aux["group_sizes"]`` [E] (assignments each expert computed;
-    their sum is T * K, or something was dropped)."""
+    their sum is T * K, or something was dropped).
+
+    ``held = (first, count)``: the weights are those of experts ``first`` to
+    ``first + count`` alone, [count, ...], of the router's E (the module
+    text). The sum is then over the picked experts that are held,
+    ``aux["group_sizes"]`` is [count], the held experts', and
+    ``aux["asked"]`` counts the assignments the router gave them (equal to
+    their sum, or something was dropped). None, or all E held, is the whole
+    layer."""
     tokens, n_experts = x.shape[0], router.shape[-1]
     dt = x.dtype
+    first = None
+    if held is not None and tuple(held) != (0, n_experts):
+        first, count = held
+        if first < 0 or count < 1 or first + count > n_experts:
+            raise ValueError(f"held={held} of {n_experts} experts")
+    if w_gate.shape[0] != (n_experts if first is None else count):
+        raise ValueError(
+            f"weights of {w_gate.shape[0]} experts, router of {n_experts}, "
+            f"held={held}")
     with jax.named_scope("moe_route"):
         picked, weights = route(x, router, bias, top_k, scaling, normalize)
     with jax.named_scope("moe_dispatch"):
@@ -174,10 +221,14 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
         group_sizes = jnp.zeros((n_experts,), jnp.int32).at[expert_of].add(1)
         rows = _dispatch(x, order, inverse)  # [K*T, d], grouped by expert
     with jax.named_scope("moe_experts"):
-        gate = grouped_matmul(rows, w_gate.astype(dt), group_sizes)
-        up = grouped_matmul(rows, w_up.astype(dt), group_sizes)
+        gate = grouped_matmul(rows, w_gate.astype(dt), group_sizes, first)
+        up = grouped_matmul(rows, w_up.astype(dt), group_sizes, first)
         out = grouped_matmul(jax.nn.silu(gate) * up, w_down.astype(dt),
-                             group_sizes)
+                             group_sizes, first)
     with jax.named_scope("moe_combine"):
         y = _combine(_unsort(out, order, inverse), weights.T)
-    return y, {"picked": picked, "group_sizes": group_sizes}
+    if first is None:
+        return y, {"picked": picked, "group_sizes": group_sizes}
+    asked = ((picked >= first) & (picked < first + count)).sum()
+    return y, {"picked": picked, "asked": asked,
+               "group_sizes": group_sizes[first:first + count]}

@@ -13,6 +13,7 @@ cannot be described (no libtpu).
 """
 
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -216,6 +217,32 @@ def test_flash_compiles_at_32k_with_grouped_kv_heads(topo):
         q, k, v).compile()
     assert grads.as_text().count("tpu_custom_call") >= 3
     assert flash_mod.causal_tile_census(32768, 512, 512)["executed"] == 2080
+
+
+@pytest.mark.parametrize("window,names", [
+    (4096, ("flash_fwd_win", "flash_bwd_dq_win", "flash_bwd_dkv_win")),
+    (None, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))])
+def test_flash_compiles_at_16k_with_and_without_a_window(topo, window, names):
+    """Trinity-Large-Preview's attention layers: 48 query heads over 8 KV
+    heads of 128 at S = 16384, a window layer (4096: 252 executed tiles a
+    head, under the windowed kernels' own names) and a full layer (528),
+    forward and both backward kernels."""
+    q, k, v = _qkv(topo, (1, 16384, 48, 128))
+    k = v = jax.ShapeDtypeStruct((1, 16384, 8, 128), jnp.bfloat16,
+                                 sharding=k.sharding)
+
+    def loss(q, k, v):
+        return flash_mod.flash_attention(
+            q, k, v, True, 512, 512, None, window).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, v).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    for name in names:
+        assert re.search(rf"\b{name}\b", text), name
+    assert "flash_fwd_win" in text if window else "flash_fwd_win" not in text
+    assert flash_mod.window_tile_census(16384, window, 512, 512)[
+        "executed"] == (252 if window else 528)
 
 
 def test_ragged_sequence_is_an_error_on_tpu(topo):
