@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import lm
+from ray_tpu.parallel.mesh import current_rules
 from ray_tpu.parallel.sharding import ShardingRules, constrain
 
 
@@ -247,9 +248,30 @@ def _rotary(x, positions, rotary_dim):
     return jnp.concatenate([rot_out, rest], axis=-1)
 
 
+def _attend(cfg: GPTConfig, q, k, v, positions):
+    q = checkpoint_name(_rotary(q, positions, cfg.rotary_dim), "attn_q")
+    k = checkpoint_name(_rotary(k, positions, cfg.rotary_dim), "attn_k")
+    v = checkpoint_name(v, "attn_v")
+    return checkpoint_name(lm.attention(q, k, v, cfg), "attn_raw")
+
+
+def _mlp_in(cfg: GPTConfig, h, layer):
+    return checkpoint_name(
+        jnp.einsum("bsd,df->bsf", h, layer["w_in"].astype(cfg.dtype)),
+        "ffn_in")
+
+
+def _mlp_out(cfg: GPTConfig, ff, layer):
+    dt = cfg.dtype
+    ff = jax.nn.gelu(ff + layer["b_in"].astype(dt))
+    return jnp.einsum("bsf,fd->bsd", ff, layer["w_out"].astype(dt))
+
+
 def _block(cfg: GPTConfig, x, layer, positions):
     """One transformer block. x: [B, S, D]. Returns (x, None):
-    ``lm.scan_blocks``' contract, and this block has nothing to stack."""
+    ``lm.scan_blocks``' contract, and this block has nothing to stack.
+    Its sums over tp are the partitioner's; on a mesh whose tp divides S
+    the flash path runs ``_block_on_slices`` instead."""
     dt = cfg.dtype
     h = _layernorm(x, layer["ln1_scale"], layer["ln1_bias"],
                    cfg.layernorm_eps)
@@ -258,10 +280,7 @@ def _block(cfg: GPTConfig, x, layer, positions):
         q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
         k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
         v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
-        q = checkpoint_name(_rotary(q, positions, cfg.rotary_dim), "attn_q")
-        k = checkpoint_name(_rotary(k, positions, cfg.rotary_dim), "attn_k")
-        v = checkpoint_name(v, "attn_v")
-        attn = checkpoint_name(lm.attention(q, k, v, cfg), "attn_raw")
+        attn = _attend(cfg, q, k, v, positions)
         attn_out = jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(dt))
 
     if cfg.parallel_block:
@@ -271,11 +290,7 @@ def _block(cfg: GPTConfig, x, layer, positions):
         mlp_in = _layernorm(x, layer["ln2_scale"], layer["ln2_bias"],
                             cfg.layernorm_eps)
     with jax.named_scope("mlp"):
-        ff = checkpoint_name(
-            jnp.einsum("bsd,df->bsf", mlp_in, layer["w_in"].astype(dt)),
-            "ffn_in")
-        ff = jax.nn.gelu(ff + layer["b_in"].astype(dt))
-        mlp_out = jnp.einsum("bsf,fd->bsd", ff, layer["w_out"].astype(dt))
+        mlp_out = _mlp_out(cfg, _mlp_in(cfg, mlp_in, layer), layer)
 
     b_out = layer["b_out"].astype(dt)
     if cfg.parallel_block:
@@ -286,15 +301,116 @@ def _block(cfg: GPTConfig, x, layer, positions):
     return x + (mlp_out + b_out), None
 
 
+def _block_on_slices(cfg: GPTConfig, x, layer, positions):
+    """``_block`` per shard of tp (``lm.exchanged_over_tp``): x is this
+    chip's slice of S, [B, S / tp, D], in and out; ``layer`` holds this
+    chip's heads and columns of the MLP; positions are whole. The same
+    products in the same dtypes on the same rows, in an order that lets
+    the block's tp traffic travel beside them (a slice takes 1.6 ms on
+    the wire, and every collective issued behind it waits for it):
+
+    * the norm runs on the chip's own rows (a row of d is whole here);
+    * its rows go round the ring (``lm.gathered_product``): q, k and v of
+      the slice in hand first, 0.7 ms each, during which the partitioner
+      gathers the next weights; then the slice is sent on beside the MLP's
+      first product on it, 2.9 ms, which needs nothing else from the wire.
+      The MLP is per token: its hidden activations stay in slices;
+    * q, k and v are placed once (``lm.ring_place``) for the kernels, which
+      take whole sequences and this chip's heads, as [b, h, s, k]: the
+      order the products leave them in and the kernels take them in;
+    * ``attn @ wo + ff @ w_out`` is one ``lm.scattered_product``: the other
+      chips' slices first, each partial sum sent on while this chip's own
+      slice is multiplied (0.8 + 3.2 ms), then added to what arrives. In
+      the backward pass its cotangent goes back once the recomputed
+      forward's exchange has landed (``landed``), and its own slice's
+      products run after their recomputed inputs.
+
+    A parallel block is one gather and one sum; a sequential one needs
+    x + attention before its second norm: two of each, through the same
+    helpers. Autodiff's transposes are exchanges too: two a layer forward,
+    three backward beside the two recomputed, all asynchronous (on four
+    v5e chips 2,561 ms a GPT-J step against 2,683 with the two sums as
+    all-reduces; my chip runs, PR 32)."""
+    dt = cfg.dtype
+    h = _layernorm(x, layer["ln1_scale"], layer["ln1_bias"],
+                   cfg.layernorm_eps)
+    # [b, h, s, k]: the order the products leave their heads in, and the
+    # kernels take them in; S is axis 2 there.
+    qkv = [lambda rows, w=w: jnp.einsum("bsd,dhk->bhsk", rows,
+                                        layer[w].astype(dt))
+           for w in ("wq", "wk", "wv")]
+
+    def attend(parts):
+        q, k, v = (a.transpose(0, 2, 1, 3) for a in lm.ring_place(
+            [tuple(part[:3]) for part in parts], 2))
+        attn = _attend(cfg, q, k, v, positions)
+        return lm.ring_split(attn.transpose(0, 2, 1, 3), 2)
+
+    def attn_out(attn, layer):
+        return jnp.einsum("bhsk,hkd->bsd", attn, layer["wo"].astype(dt))
+
+    with jax.named_scope("attention"):
+        parts = lm.gathered_product(
+            h, qkv + [lambda rows: _mlp_in(cfg, rows, layer)]
+            if cfg.parallel_block else qkv)
+        attn = attend(parts)
+    # What the row-split products take of the layer; the first product on
+    # the slice that arrives last says the forward's exchange has landed.
+    shared = {name: layer[name] for name in ("wo", "b_in", "w_out")}
+    landed = parts[-1][0]
+    b_out = layer["b_out"].astype(dt)
+    if cfg.parallel_block:
+        with jax.named_scope("mlp"):
+            out = lm.scattered_product(
+                lambda inputs, layer: attn_out(inputs[0], layer)
+                + _mlp_out(cfg, inputs[1], layer),
+                [(attn_t, part[3]) for attn_t, part in zip(attn, parts)],
+                shared, after=landed)
+        return x + (out + b_out), None
+    with jax.named_scope("attention"):
+        x = x + lm.scattered_product(attn_out, attn, shared, after=landed)
+    h = _layernorm(x, layer["ln2_scale"], layer["ln2_bias"],
+                   cfg.layernorm_eps)
+    with jax.named_scope("mlp"):
+        parts = lm.gathered_product(
+            h, [lambda rows: _mlp_in(cfg, rows, layer)])
+        out = lm.scattered_product(
+            lambda ff, layer: _mlp_out(cfg, ff, layer),
+            [part[0] for part in parts], shared)
+    return x + (out + b_out), None
+
+
+def _exchange_mesh(cfg: GPTConfig, seq_len: int):
+    """``lm.tp_exchange_mesh`` if this config's heads and MLP split over its
+    tp evenly, else None."""
+    mesh = lm.tp_exchange_mesh(cfg, seq_len)
+    if mesh is None:
+        return None
+    tp = mesh.shape["tp"]
+    fits = not (cfg.n_heads % tp or cfg.kv_heads % tp or cfg.d_ff % tp)
+    return mesh if fits else None
+
+
 def hidden_states(params: Dict[str, Any], cfg: GPTConfig,
                   tokens: jax.Array,
                   positions: Optional[jax.Array] = None) -> jax.Array:
-    """tokens [B, S] int32 → final-layernormed hidden [B, S, d]."""
+    """tokens [B, S] int32 → final-layernormed hidden [B, S, d].
+
+    Where the mesh and S allow (``lm.tp_exchange_mesh``) the residual stream
+    is split over tp along S from the lookup to the scan's exit and the
+    blocks run on slices (``_block_on_slices``); it is gathered once,
+    before the final norm and the vocabulary-parallel head."""
     if positions is None:
         positions = lm.positions_of(tokens)
-    x = lm.embed(params["wte"], tokens, cfg.dtype)
-    x, _ = lm.scan_blocks(cfg, partial(_block, cfg), x, params["layers"],
-                          positions)
+    mesh, layers = _exchange_mesh(cfg, tokens.shape[1]), params["layers"]
+    if mesh is None:
+        block, stream = partial(_block, cfg), "sequence"
+    else:
+        (block, layers), stream = lm.exchanged_over_tp(
+            partial(_block_on_slices, cfg), mesh, layers, param_specs(
+                cfg, current_rules() or ShardingRules())["layers"]), "stream"
+    x = lm.embed(params["wte"], tokens, cfg.dtype, stream)
+    x, _ = lm.scan_blocks(cfg, block, x, layers, positions)
     x = constrain(x, "batch", "sequence", None)
     return _layernorm(x, params["lnf_scale"], params["lnf_bias"],
                       cfg.layernorm_eps)

@@ -35,6 +35,10 @@ _ITEMSIZE = {"pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2,
 _ARRAY = re.compile(r"\b(f8\w*|[a-z]+\d+|pred)\[([\d,]*)\]")
 _HEADER = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+# What a schedule lists between two instructions without running anything.
+_BOOKKEEPING = frozenset({"get-tuple-element", "bitcast", "tuple", "constant",
+                          "parameter", "copy-start", "copy-done",
+                          "partition-id", "replica-id"})
 _CALLEES = re.compile(
     r"\b(calls|to_apply|body|condition|branch_computations|"
     r"called_computations)=(?:\{([^}]*)\}|(%?[\w.\-]+))")
@@ -86,10 +90,19 @@ def census(hlo_text: str) -> List[Dict[str, Any]]:
         in_loop      whether that lies, however deep, in a while loop
         op_name      the JAX operation in its metadata ("" if none)
         name         the instruction's
+        is_async     whether it is a pair of a start and a done, between
+                     which the chip is free to run other instructions
+        between      the names of the instructions scheduled between the
+                     two, in order (a compiled TPU program's text is its
+                     schedule), bookkeeping left out; [] if synchronous
+        matmuls_between  how many of those are matmuls: a fusion around a
+                     ``convolution`` / ``dot``, or one on its own
 
-    An asynchronous pair counts at its ``-start``; the TPU compiler's form,
-    in which one collective is repeated in the fusions that start it, carry
-    it and finish it, counts once by its ``channel_id``.
+    An asynchronous pair counts at its ``-start`` (several ``ppermute``s of
+    one ``shard_map`` share a ``channel_id`` and are several pairs); the TPU
+    compiler's other form, in which one collective is repeated in the
+    fusions that start it, carry it and finish it, counts once by its
+    ``channel_id``, from the first of those fusions to the last.
     """
     computations: Dict[str, List[Tuple[str, str]]] = {}
     current = None
@@ -122,22 +135,61 @@ def census(hlo_text: str) -> List[Dict[str, Any]]:
             computation = caller
         return nearest or computation, in_loop
 
-    found_ops, seen_channels = [], set()
+    # fused computation -> (the computation its fusion sits in, where)
+    fused_at: Dict[str, Tuple[str, int]] = {}
+    multiplies = set()
+    for caller, instructions in computations.items():
+        for index, (_, rest) in enumerate(instructions):
+            opcode = _split_shape(rest)[1].partition("(")[0]
+            if opcode in ("convolution", "dot"):
+                multiplies.add(caller)
+            callee = re.search(r"\bcalls=%?([\w.\-]+)", rest)
+            if opcode == "fusion" and callee:
+                fused_at.setdefault(callee.group(1), (caller, index))
+
+    def scheduled_between(computation: str, start: int, done: int):
+        """(names, how many are matmuls) of instructions start+1 .. done-1."""
+        names, matmuls = [], 0
+        for name, rest in computations[computation][start + 1:done]:
+            opcode = _split_shape(rest)[1].partition("(")[0]
+            if opcode in _BOOKKEEPING:
+                continue
+            names.append(name)
+            callee = re.search(r"\bcalls=%?([\w.\-]+)", rest)
+            matmuls += opcode in ("convolution", "dot") or bool(
+                callee and callee.group(1) in multiplies)
+        return names, matmuls
+
+    found_ops, by_channel = [], {}
     for computation, instructions in computations.items():
-        for name, rest in instructions:
+        for index, (name, rest) in enumerate(instructions):
             shape, tail = _split_shape(rest)
             opcode = tail.partition("(")[0]
-            kind = opcode[:-6] if opcode.endswith("-start") else opcode
+            started = opcode.endswith("-start")
+            kind = opcode[:-6] if started else opcode
             if kind not in KINDS:
                 continue
             channel = re.search(r"channel_id=(\d+)", tail)
-            if channel:
-                if (kind, channel.group(1)) in seen_channels:
-                    continue
-                seen_channels.add((kind, channel.group(1)))
+            fused = (kind, channel.group(1)) if channel and not started \
+                else None
+            if fused in by_channel:  # a later fusion of the same one
+                first, entry = by_channel[fused]
+                start, done = fused_at.get(first), fused_at.get(computation)
+                if start and done and start[0] == done[0] \
+                        and start[1] < done[1]:
+                    entry["is_async"] = True
+                    entry["between"], entry["matmuls_between"] = \
+                        scheduled_between(start[0], start[1], done[1])
+                continue
             arrays = _arrays(shape)
             if opcode in ("all-gather-start", "collective-permute-start"):
                 arrays = arrays[1:2]
+            between, matmuls = [], 0
+            if started:
+                done = next((i for i, (_, other) in enumerate(instructions)
+                             if re.search(rf"-done\(%?{re.escape(name)}\)",
+                                          other)), index + 1)
+                between, matmuls = scheduled_between(computation, index, done)
             op_name = re.search(r'op_name="([^"]*)"', tail)
             where, in_loop = place(computation)
             found_ops.append({
@@ -147,7 +199,10 @@ def census(hlo_text: str) -> List[Dict[str, Any]]:
                 "group_size": _group_size(tail), "computation": where,
                 "in_loop": in_loop,
                 "op_name": op_name.group(1) if op_name else "",
-                "name": name})
+                "name": name, "is_async": started, "between": between,
+                "matmuls_between": matmuls})
+            if fused:
+                by_channel[fused] = (computation, found_ops[-1])
     return found_ops
 
 
@@ -198,9 +253,12 @@ def main(argv: Optional[List[str]] = None) -> None:
         if op["bytes"] >= least:
             shapes = " ".join(f"{dtype}{list(dims)}"
                               for dtype, dims in op["arrays"])
+            flight = (f"async, {op['matmuls_between']} matmuls of "
+                      f"{len(op['between'])} between"
+                      if op["is_async"] else "sync")
             print(f"{op['kind']:<18} {op['bytes'] / 1e6:>8.1f} MB  over "
                   f"{op['group_size']}  {'loop' if op['in_loop'] else 'once'}"
-                  f"  {op['name']}  {shapes}  {op['op_name']}")
+                  f"  {flight}  {op['name']}  {shapes}  {op['op_name']}")
 
 
 if __name__ == "__main__":

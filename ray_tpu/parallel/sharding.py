@@ -20,10 +20,22 @@ MeshAxes = Union[None, str, Tuple[str, ...]]
 
 @dataclass(frozen=True)
 class ShardingRules:
-    """Maps logical dimension names to mesh axes (None = replicated)."""
+    """Maps logical dimension names to mesh axes (None = replicated).
+
+    ``sequence`` is S wherever a whole model's activations carry it (the
+    batch, q / k / v, the loss): ``"sp"`` under context parallelism, whose
+    attention works on slices of S. ``stream`` is S of the residual stream
+    alone, between blocks, for a model whose block takes slices of S in and
+    gives slices back (``models/lm.py: exchanged_over_tp``): over tp, the
+    axis the block's weights are split over, so that the block's sum over
+    tp and the gather that undoes it become exchanges of slices. A model
+    states the stream under it only where ``lm.tp_exchange_mesh`` finds the
+    mesh and the shape fit (tp above 1 and dividing S, ``sequence`` over
+    no axis); everywhere else the stream's S is ``sequence``."""
 
     batch: MeshAxes = ("dp", "fsdp")
     sequence: MeshAxes = None  # set to "sp" for context parallelism
+    stream: MeshAxes = "tp"  # S of the residual stream between blocks
     embed: MeshAxes = "fsdp"  # weight-sharding axis (ZeRO-3 analog)
     heads: MeshAxes = "tp"
     kv_heads: MeshAxes = "tp"

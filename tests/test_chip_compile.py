@@ -245,6 +245,22 @@ def test_flash_compiles_at_16k_with_and_without_a_window(topo, window, names):
         "executed"] == (252 if window else 528)
 
 
+@pytest.mark.parametrize("shape,axis", [((8, 8, 1024, 256), 2),
+                                        ((8, 1024, 4096), 1)])
+def test_place_slices_compiles_at_the_published_widths(topo, shape, axis):
+    """``ops/place.py``: DMAs from HBM to HBM at an offset the chip reads
+    from SMEM, for GPT-J's q, k and v halves as [b, h, s, k] in one call
+    (the four-chip cell's) and for halves of the hidden states."""
+    from ray_tpu.ops.place import place_slices
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    half = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    halves = [(half,) * 3] * 2 if axis == 2 else [half] * 2
+    slots = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda halves, slots: place_slices(
+        halves, slots, axis)).lower(halves, slots).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
 def test_ragged_sequence_is_an_error_on_tpu(topo):
     """No silent switch to the jnp blockwise path where a kernel exists."""
     with pytest.raises(ValueError, match="multiple of 128"):
@@ -322,15 +338,26 @@ def test_step_runs_the_flash_forward_once_a_layer(topo, shaped_like):
         [layers if kept else 2 * layers, layers, layers]
 
 
-@pytest.mark.parametrize("parallel_block", [True, False])
+@pytest.mark.parametrize("parallel_block", [True, False],
+                         ids=["parallel", "sequential"])
 def test_step_sends_what_fsdp_x_tp_needs(topo, parallel_block):
     """The GPT-J step (every width as published, two layers: the scan's
     body is what depth repeats) on fsdp=2 x tp=2, read by census: the
-    model states where its activations live, so the partitioner emits the
-    layout's own traffic and no more. A parallel block reduces its two
-    tp-partial products together; the head and loss run on each data
+    model states where its activations live, so the step sends the
+    layout's own traffic and no more. Between blocks the residual stream
+    is split over tp along S, and the block's sum over tp and the gather
+    that undoes it cross as exchanges of slices [B / fsdp, S / tp, d]
+    (``lm.gathered_product``, ``lm.scattered_product``): no all-reduce,
+    all-gather or reduce-scatter of the hidden shape in a scan body, every
+    exchange a start and a done with matmuls scheduled between, and in the
+    forward body no other collective between the two (a synchronous one
+    would wait for the transfer in flight). A parallel block reduces its
+    two tp-partial products together: two exchanges forward, three
+    backward beside the two recomputed. fsdp stays the partitioner's: a
+    layer's twelve weight gathers (ten inside matmul fusions) and six
+    gradient reductions as before. The head and loss run on each data
     shard's own tokens, so nothing as wide as the vocabulary crosses chips
-    inside the chunk loop; the hidden states are never resharded."""
+    inside the chunk loop."""
     from ray_tpu.parallel.collectives import census
     mesh = build_mesh(MeshConfig(dp=1, fsdp=2, tp=2), devices=topo.devices)
     cfg = gpt.config("gptj-6b", n_layers=2, attn_impl="flash",
@@ -349,16 +376,44 @@ def test_step_sends_what_fsdp_x_tp_needs(topo, parallel_block):
     def dims(op):
         return [d for _, d in op["arrays"]]
 
+    def named_collective(name):
+        return name.startswith(("all-", "reduce-scatter", "collective-"))
+
     hidden = (batch // fsdp, cfg.max_seq_len, cfg.d_model)
-    reduced = [op for op in ops if op["kind"] == "all-reduce"
-               and op["in_loop"] and dims(op) == [hidden]]
-    forward = [op for op in reduced if "transpose(" not in op["op_name"]]
-    backward = len(reduced) - len(forward)
+    piece = (batch // fsdp, cfg.max_seq_len // tp, cfg.d_model)
+    in_loop = [op for op in ops if op["in_loop"]]
+    assert not [op for op in in_loop if op["kind"] in (
+        "all-reduce", "all-gather", "reduce-scatter")
+        and {hidden, piece} & set(dims(op))]
+    exchanges = [op for op in in_loop if op["kind"] == "collective-permute"
+                 and dims(op) == [piece]]
+    forward = [op for op in exchanges if "transpose(" not in op["op_name"]]
+    backward = [op for op in exchanges if "transpose(" in op["op_name"]]
+    assert all(op["is_async"] for op in exchanges), exchanges
     if parallel_block:
-        assert (len(forward), backward) == (1, 1), reduced
-    else:  # it needs x + attention before the second norm: the recomputed
-        # forward's reduction too, beside the backward's two
-        assert len(forward) == 2 and backward >= 2, reduced
+        assert (len(forward), len(backward)) == (2, 3), exchanges
+        assert all(op["matmuls_between"] >= 1 for op in exchanges), exchanges
+    else:  # it needs x + attention before the second norm: two gathers and
+        # two sums, and the backward's recomputation holds three of them
+        assert (len(forward), len(backward)) == (4, 7), exchanges
+    assert not [name for op in forward for name in op["between"]
+                if named_collective(name)
+                and not name.startswith("collective-permute")], forward
+
+    # fsdp: the weights' gathers and their gradients' sums, in the two
+    # bodies of the layer scan (where the exchanges are)
+    bodies = {op["computation"] for op in exchanges}
+    assert len(bodies) == 2, bodies
+    weights = [op for op in in_loop if op["computation"] in bodies
+               and op["bytes"] >= 16e6 and op["kind"] != "collective-permute"]
+    gathered = [op for op in weights if op["kind"] == "all-gather"]
+    if parallel_block:
+        assert len(gathered) == 12, gathered
+        assert sum(op["is_async"] for op in gathered) == 10, gathered
+    else:  # 15 before the exchanges: its backward body now gathers wq, wk
+        # and wv for the recomputation and again for their transposes
+        assert len(gathered) == 18, gathered
+    assert len([op for op in weights if op["kind"] == "all-reduce"]) == 6
 
     exchanged = [op for op in ops if op["kind"] == "all-to-all"]
     assert len(exchanged) <= 2, exchanged  # the wte lookup and its scatter

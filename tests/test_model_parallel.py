@@ -1,5 +1,7 @@
 """Model + parallel-layer tests on the virtual 8-device CPU mesh."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -229,15 +231,16 @@ def test_multi_slice_mesh_runs_train_step():
 
 # -- what the model states about its activations (sharding.constrain) ----
 
-def _loss_and_grads(cfg, mesh_cfg, n_devices, params, tokens, targets, mask):
-    """gpt.loss_fn's loss and gradients, traced under a mesh and its rules
-    as a train step traces it."""
+def _loss_and_grads(cfg, mesh_cfg, n_devices, params, tokens, targets, mask,
+                    rules=None, model=gpt):
+    """``model.loss_fn``'s loss and gradients, traced under a mesh and its
+    rules as a train step traces it."""
     from ray_tpu.parallel import mesh as mesh_mod, shard_tree
     mesh = build_mesh(mesh_cfg, devices=jax.devices()[:n_devices])
-    rules = ShardingRules()
-    params = shard_tree(params, mesh, gpt.param_specs(cfg, rules))
+    rules = rules or ShardingRules()
+    params = shard_tree(params, mesh, model.param_specs(cfg, rules))
     fn = jax.jit(jax.value_and_grad(
-        lambda p: gpt.loss_fn(p, cfg, tokens, targets, mask)[0]))
+        lambda p: model.loss_fn(p, cfg, tokens, targets, mask)[0]))
     previous = mesh_mod.current_mesh(), mesh_mod.current_rules()
     mesh_mod.set_current_mesh(mesh, rules)
     try:
@@ -246,21 +249,25 @@ def _loss_and_grads(cfg, mesh_cfg, n_devices, params, tokens, targets, mask):
         mesh_mod.set_current_mesh(*previous)
 
 
-def _assert_fsdp_x_tp_matches_one_device(cfg, masked):
-    params = gpt.init(cfg, jax.random.PRNGKey(0))
+def _assert_fsdp_x_tp_matches_one_device(
+        cfg, masked, mesh_cfg=MeshConfig(dp=1, fsdp=2, tp=2), rules=None,
+        model=gpt, seq=32, rtol=1e-4, atol=1e-6):
+    params = model.init(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(3)
-    tokens = jnp.asarray(rng.integers(0, 256, (4, 32)), jnp.int32)
-    targets = jnp.asarray(rng.integers(0, 256, (4, 32)), jnp.int32)
-    mask = jnp.asarray(rng.integers(0, 2, (4, 32)), jnp.float32) \
+    tokens = jnp.asarray(rng.integers(0, 256, (4, seq)), jnp.int32)
+    targets = jnp.asarray(rng.integers(0, 256, (4, seq)), jnp.int32)
+    mask = jnp.asarray(rng.integers(0, 2, (4, seq)), jnp.float32) \
         if masked else None
     one_loss, one_grads = _loss_and_grads(
-        cfg, MeshConfig(dp=1, fsdp=1, tp=1), 1, params, tokens, targets, mask)
+        cfg, MeshConfig(dp=1, fsdp=1, tp=1), 1, params, tokens, targets, mask,
+        model=model)
     loss, grads = _loss_and_grads(
-        cfg, MeshConfig(dp=1, fsdp=2, tp=2), 4, params, tokens, targets, mask)
+        cfg, mesh_cfg, int(np.prod(mesh_cfg.shape())), params, tokens,
+        targets, mask, rules, model)
     assert float(loss) == pytest.approx(float(one_loss), rel=1e-4)
     for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(grads),
                                  jax.tree.leaves(one_grads)):
-        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6,
+        np.testing.assert_allclose(got, want, rtol=rtol, atol=atol,
                                    err_msg=jax.tree_util.keystr(path))
 
 
@@ -275,6 +282,140 @@ def test_fsdp_x_tp_matches_one_device(parallel_block, loss_chunk, masked):
     _assert_fsdp_x_tp_matches_one_device(
         gpt.config("gpt-tiny", parallel_block=parallel_block,
                    loss_chunk=loss_chunk), masked)
+
+
+# -- the block's tp traffic as exchanges of slices of S --------------------
+
+def _exchanges(cfg, mesh_cfg, seq, rules=None):
+    """How many ``ppermute``s the traced loss holds on this mesh."""
+    from ray_tpu.parallel import mesh as mesh_mod
+    mesh = build_mesh(mesh_cfg,
+                      devices=jax.devices()[:int(np.prod(mesh_cfg.shape()))])
+    tokens = jnp.zeros((4, seq), jnp.int32)
+    params = jax.eval_shape(lambda k: gpt.init(cfg, k), jax.random.PRNGKey(0))
+    previous = mesh_mod.current_mesh(), mesh_mod.current_rules()
+    mesh_mod.set_current_mesh(mesh, rules)
+    try:
+        return str(jax.make_jaxpr(lambda p: gpt.loss_fn(
+            p, cfg, tokens, tokens)[0])(params)).count("ppermute")
+    finally:
+        mesh_mod.set_current_mesh(*previous)
+
+
+@pytest.mark.parametrize("axis", [1, 2])
+def test_place_slices_puts_every_slice_at_its_slot(axis):
+    """``ops/place.py`` (interpreted here): slice t lands in block
+    ``slots[t]`` of ``axis``, whatever the permutation, traced or not."""
+    from ray_tpu.ops.place import place_slices
+    rng = np.random.default_rng(11)
+    slices = [jnp.asarray(rng.normal(size=(2, 8, 8, 4)), jnp.float32)
+              for _ in range(3)]
+    for slots in ([0, 1, 2], [2, 0, 1], [1, 2, 0]):
+        got = jax.jit(partial(place_slices, axis=axis))(
+            slices, jnp.asarray(slots, jnp.int32))
+        want = jnp.concatenate(
+            [slices[slots.index(block)] for block in range(3)], axis=axis)
+        np.testing.assert_array_equal(got, want)
+    # A tree a slice: one call, one result a leaf.
+    pair = jax.jit(partial(place_slices, axis=axis))(
+        [(a, 2 * a) for a in slices], jnp.asarray(slots, jnp.int32))
+    np.testing.assert_array_equal(pair[0], want)
+    np.testing.assert_array_equal(pair[1], 2 * want)
+
+
+@pytest.mark.parametrize("tp", [2, 4])
+def test_ring_products_match_the_plain_products(tp):
+    """``lm.gathered_product`` / ``ring_place`` / ``ring_split`` /
+    ``scattered_product`` over a ring of tp chips against the products they
+    stand for, ``(x @ w1) @ w2`` with w1 split by columns and w2 by rows,
+    and against its gradients: every chip takes its slice of S in and
+    gives its slice of the sum back."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from ray_tpu._private.jax_compat import shard_map
+    mesh = Mesh(np.array(jax.devices()[:tp]), ("tp",))
+    rng = np.random.default_rng(7)
+    x, w1, w2, cot = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                      for shape in ((2, 8 * tp, 16), (16, 4 * tp),
+                                    (4 * tp, 16), (2, 8 * tp, 16)))
+
+    def on_slices(x, w1, w2):
+        parts = lm.gathered_product(x, [lambda rows: rows,
+                                        lambda rows: rows @ w1])
+        whole = lm.ring_place([rows for rows, _ in parts])  # x again
+        again = lm.ring_split(whole)
+        return whole, lm.scattered_product(
+            lambda inputs, w: (inputs[0] + 0 * inputs[1] @ w[0]) @ w[1],
+            [(part[1], rows) for part, rows in zip(parts, again)], (w1, w2),
+            after=parts[-1][1])
+
+    ringed = shard_map(on_slices, mesh=mesh,
+                       in_specs=(P(None, "tp"), P(None, "tp"), P("tp")),
+                       out_specs=(P(), P(None, "tp")), check_vma=False)
+
+    def plain(x, w1, w2):
+        return x, (x @ w1) @ w2
+
+    def loss(fn, *args):
+        whole, out = fn(*args)
+        return (out * cot).sum() + (whole * cot).sum()
+
+    with jax.default_matmul_precision("highest"):
+        for got, want in zip(jax.jit(ringed)(x, w1, w2), plain(x, w1, w2)):
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        grads = jax.jit(jax.grad(lambda *a: loss(ringed, *a),
+                                 argnums=(0, 1, 2)))(x, w1, w2)
+        wanted = jax.grad(lambda *a: loss(plain, *a),
+                          argnums=(0, 1, 2))(x, w1, w2)
+    for got, want in zip(grads, wanted):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("tp", [2, 4])
+@pytest.mark.parametrize("parallel_block", [True, False])
+def test_block_on_slices_matches_one_device(parallel_block, tp):
+    """The flash path on a mesh with tp: the stream split over tp along S,
+    the block per shard of tp, its sum and gather as exchanges (a parallel
+    block: two ``ppermute``s a layer at tp = 2; a sequential one: four).
+    Loss and every gradient leaf are the one-device values, remat on."""
+    cfg = gpt.config("gpt-tiny", attn_impl="flash", remat=True,
+                     parallel_block=parallel_block, loss_chunk=64)
+    mesh_cfg = MeshConfig(dp=1, fsdp=2, tp=tp)
+    assert _exchanges(cfg, mesh_cfg, 32) >= (2 if parallel_block else 4)
+    _assert_fsdp_x_tp_matches_one_device(cfg, masked=True, mesh_cfg=mesh_cfg)
+
+
+@pytest.mark.parametrize("why,cfg,mesh_cfg,rules,seq", [
+    ("dot attention", gpt.config("gpt-tiny"),
+     MeshConfig(dp=1, fsdp=2, tp=2), None, 32),
+    ("tp does not divide S", gpt.config("gpt-tiny", attn_impl="flash"),
+     MeshConfig(dp=1, fsdp=2, tp=4), None, 30),
+    ("S lies over sp", gpt.config("gpt-tiny", attn_impl="ring"),
+     MeshConfig(dp=1, fsdp=1, tp=2, sp=2), ShardingRules(sequence="sp"), 32),
+    ("tp splits no weight", gpt.config("gpt-tiny", attn_impl="flash"),
+     MeshConfig(dp=1, fsdp=2, tp=2), dp_rules(), 32),
+    ("no tp", gpt.config("gpt-tiny", attn_impl="flash"),
+     MeshConfig(dp=2, fsdp=2, tp=1), None, 32)],
+    ids=lambda value: value.replace(" ", "_")
+    if isinstance(value, str) else None)
+def test_block_keeps_the_partitioners_path(why, cfg, mesh_cfg, rules, seq):
+    """Where the mesh, the rules or the shape do not allow the exchange the
+    block is the partitioner's as before: no ``ppermute`` beyond ring
+    attention's own, and the one-device numbers."""
+    ring = 2 if cfg.attn_impl == "ring" else 0  # K and V, in the one scan body
+    assert _exchanges(cfg, mesh_cfg, seq, rules) == ring, why
+    _assert_fsdp_x_tp_matches_one_device(cfg, masked=False, mesh_cfg=mesh_cfg,
+                                         rules=rules, seq=seq)
+
+
+def test_another_family_is_right_on_a_mesh_with_tp():
+    """``lm.embed`` and ``lm.scan_blocks`` are every model's: a family
+    whose block says nothing of slices keeps its stream whole along S on a
+    mesh with tp, and its one-device numbers."""
+    from ray_tpu.models import granite
+    cfg = granite.config("granite-tiny", attn_impl="flash")
+    _assert_fsdp_x_tp_matches_one_device(cfg, masked=False, model=granite,
+                                         seq=64, rtol=2e-3, atol=1e-5)
 
 
 # -- the tied head: wte is the lookup's table and the head's weight --------
@@ -391,6 +532,12 @@ HloModule jit_step
   ROOT %custom-call.1 = (bf16[2048,256]{1,0}, bf16[4096,256]{1,0}) custom-call(%all-gather.1), custom_call_target="AsyncCollectiveStart"
 }
 
+%fused_matmul (x.1: bf16[8,128,256], y.1: bf16[256,256]) -> bf16[8,128,256] {
+  %x.1 = bf16[8,128,256]{2,1,0} parameter(0)
+  %y.1 = bf16[256,256]{1,0} parameter(1)
+  ROOT %convolution.1 = bf16[8,128,256]{2,1,0} convolution(%x.1, %y.1), dim_labels=0bf_io0->0bf
+}
+
 %fused_done (p.1: bf16[2048,256]) -> bf16[4096,256] {
   %p.1 = bf16[2048,256]{1,0} parameter(0)
   %all-gather.2 = bf16[4096,256]{1,0} all-gather(%p.1), channel_id=7, replica_groups=[2,2]<=[2,2]T(1,0), dimensions={0}, use_global_device_ids=true
@@ -403,8 +550,8 @@ HloModule jit_step
   %h = bf16[8,128,256]{2,1,0} get-tuple-element(%carry), index=1
   %w = bf16[2048,256]{1,0} constant(0)
   %start = (bf16[2048,256]{1,0}, bf16[4096,256]{1,0}) fusion(%w), kind=kCustom, calls=%fused_start
-  %done = bf16[4096,256]{1,0} fusion(%w), kind=kCustom, calls=%fused_done
   %all-reduce.3 = bf16[8,128,256]{2,1,0:T(8,128)(2,1)} all-reduce(%h), channel_id=9, replica_groups={{0,1},{2,3}}, use_global_device_ids=true, to_apply=%add.1, metadata={op_name="jit(step)/jvp()/while/body/block/mlp/dot_general"}
+  %done = bf16[4096,256]{1,0} fusion(%w), kind=kCustom, calls=%fused_done
   ROOT %tuple.1 = (s32[], bf16[8,128,256]{2,1,0}) tuple(%i, %all-reduce.3)
 }
 
@@ -421,8 +568,15 @@ ENTRY %main (a: bf16[8,128,256], b: f32[8,128,256]) -> bf16[8,128,256] {
   %while.1 = (s32[], bf16[8,128,256]{2,1,0}) while(%init), condition=%cond, body=%body
   %all-to-all.4 = (f32[4,128,256]{2,1,0}, /*index=1*/f32[4,128,256]{2,1,0}) all-to-all(%b, %b), channel_id=11, replica_groups={{0,2},{1,3}}
   %all-gather-start.5 = (bf16[8,128,256]{2,1,0}, bf16[32,128,256]{2,1,0}) all-gather-start(%a), channel_id=12, replica_groups=[1,4]<=[4], dimensions={0}
+  %y = bf16[256,256]{1,0} constant(0)
+  %matmul.1 = bf16[8,128,256]{2,1,0} fusion(%a, %y), kind=kOutput, calls=%fused_matmul
+  %scaled = bf16[8,128,256]{2,1,0} multiply(%matmul.1, %matmul.1)
   %all-gather-done.5 = bf16[32,128,256]{2,1,0} all-gather-done(%all-gather-start.5)
   %collective-permute.6 = bf16[8,128,256]{2,1,0} collective-permute(%a), channel_id=13, source_target_pairs={{0,1},{1,0}}
+  %collective-permute-start.7 = (bf16[8,128,256]{2,1,0}, bf16[8,128,256]{2,1,0}) collective-permute-start(%a), channel_id=1, source_target_pairs={{0,1},{1,0}}
+  %collective-permute-done.7 = bf16[8,128,256]{2,1,0} collective-permute-done(%collective-permute-start.7)
+  %collective-permute-start.8 = (bf16[8,128,256]{2,1,0}, bf16[8,128,256]{2,1,0}) collective-permute-start(%scaled), channel_id=1, source_target_pairs={{0,1},{1,0}}
+  %collective-permute-done.8 = bf16[8,128,256]{2,1,0} collective-permute-done(%collective-permute-start.8)
   ROOT %out = bf16[8,128,256]{2,1,0} get-tuple-element(%while.1), index=1
 }
 """
@@ -434,6 +588,8 @@ def test_census_reads_hlo_text():
     # One per collective: the fusions' repeat and the -done are not others.
     assert sorted(ops) == ["all-gather-start.5", "all-gather.1",
                            "all-reduce.3", "all-to-all.4",
+                           "collective-permute-start.7",
+                           "collective-permute-start.8",
                            "collective-permute.6"]
     inner = ops["all-gather.1"]  # inside a fusion the loop's body calls
     assert (inner["kind"], inner["computation"], inner["in_loop"]) == \
@@ -453,3 +609,18 @@ def test_census_reads_hlo_text():
         ("all-gather", 32 * 128 * 256 * 2, 4)
     assert ops["collective-permute.6"]["group_size"] == 2
     assert ops["collective-permute.6"]["op_name"] == ""
+    # Which are a start and a done, and what the schedule puts between:
+    # the fused form from its first fusion to its last, a -start to its
+    # -done, bookkeeping left out, matmuls counted; two ppermutes of one
+    # shard_map share a channel_id and are two.
+    assert {name: op["is_async"] for name, op in ops.items()} == {
+        "all-gather.1": True, "all-gather-start.5": True,
+        "collective-permute-start.7": True,
+        "collective-permute-start.8": True, "all-reduce.3": False,
+        "all-to-all.4": False, "collective-permute.6": False}
+    assert (inner["between"], inner["matmuls_between"]) == (
+        ["all-reduce.3"], 0)
+    assert (started["between"], started["matmuls_between"]) == (
+        ["matmul.1", "scaled"], 1)
+    assert ops["collective-permute-start.7"]["between"] == []
+    assert reduced["between"] == []
