@@ -24,6 +24,8 @@ decrements by the amount it read rather than zeroing the cell.
 
 from __future__ import annotations
 
+import threading
+import time
 from typing import Optional
 
 # ray_tpu.util.metrics is imported inside each accessor: importing it at
@@ -846,6 +848,97 @@ def train_moe_expert_load() -> Gauge:
         "Busiest expert's assignments over the mean of the experts held "
         "here, worst expert layer of the last recorded step (1.0 = "
         "balanced).")
+
+
+# -- train set-up ----------------------------------------------------------
+# A few dozen events a process (and again at every gang restart), so their
+# durations are observed whether or not anybody traces; the span beside each
+# observation records under tracing's own rule. ``within`` is the stage that
+# was open on the thread when this one was observed ("none": the outermost),
+# so a reader sums ``within="none"`` and counts no second twice.
+
+_SETUP_BOUNDARIES = (0.01, 0.05, 0.25, 1.0, 5.0, 15.0, 60.0, 300.0)
+_setup = threading.local()
+
+
+def train_setup_seconds() -> Histogram:
+    from ray_tpu.util.metrics import Histogram
+    return Histogram(
+        "ray_tpu_train_setup_seconds",
+        "Seconds of one stage between process start and the first timed "
+        "train step (init, native_build, worker_group, backend, "
+        "loop_start, mesh, state_init, first_call, aot_lower); observed "
+        "again at every gang restart. within = the stage it ran inside, "
+        "or none.",
+        boundaries=_SETUP_BOUNDARIES, tag_keys=("stage", "within"))
+
+
+def jax_compile_seconds() -> Histogram:
+    from ray_tpu.util.metrics import Histogram
+    return Histogram(
+        "ray_tpu_jax_compile_seconds",
+        "Seconds JAX reports for making one program, by phase: trace, "
+        "lower, backend (compile on a miss; key, retrieval and "
+        "deserialise on a hit), each less what it enclosed, so the three "
+        "tile; cache_load (the retrieval alone) lies inside backend. "
+        "within = the set-up stage it fell inside, or none.",
+        boundaries=_SETUP_BOUNDARIES, tag_keys=("phase", "within"))
+
+
+def jax_programs() -> Counter:
+    from ray_tpu.util.metrics import Counter
+    return Counter(
+        "ray_tpu_jax_programs_total",
+        "Programs JAX made in this process: compiled, or loaded from the "
+        "persistent compilation cache.",
+        tag_keys=("outcome",))
+
+
+def setup_stage_open() -> str:
+    """The innermost set-up stage open on this thread, or "none"."""
+    return getattr(_setup, "stage", "none")
+
+
+def enter_setup_stage(stage: str) -> str:
+    """Make ``stage`` the thread's open stage; returns the one it was."""
+    outer = getattr(_setup, "stage", "none")
+    _setup.stage = stage
+    return outer
+
+
+def leave_setup_stage(stage: str, outer: str,
+                      seconds: Optional[float]) -> None:
+    """Give the thread back to ``outer``; observe ``seconds`` of ``stage``
+    unless None (a call that turned out to be no stage)."""
+    _setup.stage = outer
+    if seconds is not None:
+        train_setup_seconds().observe(
+            seconds, tags={"stage": stage, "within": outer})
+
+
+class setup_stage:
+    """One stage of set-up as a ``with`` block: its seconds go to
+    ``ray_tpu_train_setup_seconds{stage, within}`` always, and the block is
+    a ``tracing.start_span(span_name)`` site (yields the span, or None
+    where nothing records: the shared no-op plus one ``observe``)."""
+
+    __slots__ = ("_stage", "_scope", "_outer", "_t0")
+
+    def __init__(self, stage: str, span_name: str):
+        from ray_tpu.util import tracing
+        self._stage = stage
+        self._scope = tracing.start_span(span_name)
+
+    def __enter__(self):
+        self._outer = enter_setup_stage(self._stage)
+        self._t0 = time.perf_counter()
+        return self._scope.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        seconds = time.perf_counter() - self._t0
+        self._scope.__exit__(*exc)
+        leave_setup_stage(self._stage, self._outer, seconds)
+        return False
 
 
 def channel_bytes_sent() -> Counter:

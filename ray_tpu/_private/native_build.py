@@ -17,6 +17,8 @@ import subprocess
 import threading
 from typing import Dict, List, Optional
 
+from ray_tpu._private import builtin_metrics
+
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "src", "ray_tpu_native")
 # <repo>/build — NOT <repo>/src/build (dirname(_SRC) is <repo>/src).
@@ -84,10 +86,15 @@ def build_library(name: str, extra_flags: Optional[List[str]] = None
             return out
         tmp = f"{out}.tmp{os.getpid()}"
         try:
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", tmp,
-                 src] + (extra_flags or []),
-                check=True, capture_output=True, timeout=120)
+            # Only a checkout's first use of the component gets here.
+            with builtin_metrics.setup_stage(
+                    "native_build", "setup::native_build") as span:
+                if span is not None:
+                    span.attributes["lib"] = name
+                subprocess.run(
+                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o",
+                     tmp, src] + (extra_flags or []),
+                    check=True, capture_output=True, timeout=120)
             os.replace(tmp, out)
         except (subprocess.SubprocessError, FileNotFoundError, OSError):
             cleanup_artifacts(_BUILD_DIR, prefix, keep=None, tmp=tmp)

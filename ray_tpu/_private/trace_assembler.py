@@ -46,6 +46,12 @@ _STAGE_BY_PREFIX = (
     ("ckpt::", "ckpt"),
     ("data::next_batch", "train_ingest"),
     ("data::to_device", "train_ingest"),
+    # Train's set-up, stage by stage, and what JAX reports of the programs
+    # made on the way (parallel/compile_events.py).
+    ("setup::", "train_setup"),
+    ("step::first_call", "train_setup"),
+    ("step::lower", "train_setup"),
+    ("compile::", "compile"),
 )
 
 

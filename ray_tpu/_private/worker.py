@@ -13,6 +13,7 @@ import os
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Union
 
+from ray_tpu._private import builtin_metrics
 from ray_tpu._private.ids import JobID
 from ray_tpu._private.object_ref import ObjectRef
 from ray_tpu._private.resource_spec import detect_node_resources
@@ -97,7 +98,8 @@ def init(
     Round 1 runs a single-node in-process cluster; ``address`` other than
     None/"local"/"auto" is reserved for the multi-node control plane.
     """
-    with global_worker._lock:
+    with builtin_metrics.setup_stage("init", "setup::init"), \
+            global_worker._lock:
         if global_worker.connected:
             if ignore_reinit_error:
                 return ClientContext(global_worker)

@@ -103,6 +103,10 @@ _DEFAULT_PANELS = [
     ("Train reshards / s (by direction)",
      "sum by (direction) (rate(ray_tpu_train_reshards_total[5m]))",
      "ops"),
+    ("Train set-up seconds, mean (by stage)",
+     "sum by (stage) (ray_tpu_train_setup_seconds_sum{within=\"none\"}) / "
+     "sum by (stage) (ray_tpu_train_setup_seconds_count{within=\"none\"})",
+     "s"),
     ("Worker pool size", "ray_tpu_worker_pool_size", "short"),
     ("Worker lease wait p95 (s)",
      "histogram_quantile(0.95, "

@@ -36,7 +36,9 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import NamedSharding, PartitionSpec
 
+from ray_tpu._private import builtin_metrics
 from ray_tpu._private.jax_compat import enable_compile_cache
+from ray_tpu.parallel import compile_events
 from ray_tpu.parallel import mesh as mesh_mod
 from ray_tpu.parallel.sharding import ShardingRules, tree_shardings
 
@@ -119,20 +121,29 @@ def init_train_state(cfg: Any, mesh,
                      rules: Optional[ShardingRules] = None,
                      optimizer: Optional[optax.GradientTransformation] = None,
                      seed: int = 0, model: Any = None) -> Dict[str, Any]:
-    """Build {params, opt_state, step}, created directly in sharded form."""
+    """Build {params, opt_state, step}, created directly in sharded form.
+    The arrays are dispatched, not waited for."""
     enable_compile_cache()
+    compile_events.install()
     rules = rules or ShardingRules()
     optimizer = optimizer or default_optimizer()
     model = _model_of(cfg, model)
-    _, shardings = _state_layout(cfg, mesh, rules, optimizer, model)
+    with builtin_metrics.setup_stage("state_init",
+                                     "setup::state_init") as span:
+        _, shardings = _state_layout(cfg, mesh, rules, optimizer, model)
 
-    @partial(jax.jit, out_shardings=shardings)
-    def init(key):
-        params = model.init(cfg, key)
-        return {"params": params, "opt_state": optimizer.init(params),
-                "step": jnp.zeros((), jnp.int32)}
+        @partial(jax.jit, out_shardings=shardings)
+        def init(key):
+            params = model.init(cfg, key)
+            return {"params": params, "opt_state": optimizer.init(params),
+                    "step": jnp.zeros((), jnp.int32)}
 
-    return init(jax.random.PRNGKey(seed))
+        state = init(jax.random.PRNGKey(seed))
+        if span is not None:
+            leaves = jax.tree.leaves(state)
+            span.attributes.update(
+                leaves=len(leaves), bytes=sum(x.nbytes for x in leaves))
+    return state
 
 
 def abstract_train_state(cfg: Any, mesh,
@@ -160,14 +171,22 @@ def _with_mesh_registered(jitted, mesh, rules, after_call=None):
     over different meshes would otherwise trace against the wrong one. The
     previous mesh comes back afterwards, so model code called outside any
     step never sees a stale one. ``.lower`` traces too and gets the same
-    treatment. ``after_call`` sees what each call returns."""
-    def under_mesh(fn, then=None):
+    treatment. ``after_call`` sees what each call returns.
+
+    A call is the set-up stage ``first_call`` when it made a program
+    (``compile_events.first_call``), ``.lower`` the stage ``aot_lower``: what
+    JAX reports of tracing and lowering inside either is the step's, not
+    some other program's."""
+    program = getattr(jitted, "__name__", "step")
+
+    def under_mesh(fn, stage, then=None):
         @functools.wraps(fn)
         def call(*args, **kwargs):
             previous = mesh_mod.current_mesh(), mesh_mod.current_rules()
             mesh_mod.set_current_mesh(mesh, rules)
             try:
-                out = fn(*args, **kwargs)
+                with stage():
+                    out = fn(*args, **kwargs)
             finally:
                 mesh_mod.set_current_mesh(*previous)
             if then is not None:
@@ -175,8 +194,12 @@ def _with_mesh_registered(jitted, mesh, rules, after_call=None):
             return out
         return call
 
-    wrapped = under_mesh(jitted, after_call)
-    wrapped.lower = under_mesh(jitted.lower)
+    wrapped = under_mesh(
+        jitted, lambda: compile_events.first_call(jitted, program),
+        after_call)
+    wrapped.lower = under_mesh(
+        jitted.lower,
+        lambda: builtin_metrics.setup_stage("aot_lower", "step::lower"))
     return wrapped
 
 
@@ -217,6 +240,7 @@ def make_train_step(cfg: Any, mesh,
     microbatching substrate pipeline parallelism reuses).
     """
     enable_compile_cache()
+    compile_events.install()
     rules = rules or ShardingRules()
     optimizer = optimizer or default_optimizer()
     model = _model_of(cfg, model)
@@ -279,6 +303,7 @@ def make_eval_step(cfg: Any, mesh,
                    rules: Optional[ShardingRules] = None,
                    model: Any = None) -> Callable:
     enable_compile_cache()
+    compile_events.install()
     rules = rules or ShardingRules()
     model = _model_of(cfg, model)
     bspec = rules.spec("batch", "sequence")

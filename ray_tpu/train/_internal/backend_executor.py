@@ -35,10 +35,12 @@ the cluster shrank.
 from __future__ import annotations
 
 import logging
+import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from ray_tpu._private import builtin_metrics
 from ray_tpu._private.channel import Backoff
 from ray_tpu._private.ray_config import runtime_config_value
 from ray_tpu.air.checkpoint import Checkpoint
@@ -105,13 +107,19 @@ class BackendExecutor:
         if num_workers is not None:
             self._num_workers = num_workers
         n = self._num_workers
-        self.worker_group = WorkerGroup(
-            n,
-            self.scaling_config.worker_resources(),
-            self.scaling_config.placement_strategy,
-            bundles=self.scaling_config.as_placement_group_bundles()[:n],
-            runtime_env=getattr(self.scaling_config, "runtime_env", None))
-        self.backend.on_start(self.worker_group, self.backend_config)
+        with builtin_metrics.setup_stage(
+                "worker_group", "setup::worker_group") as span:
+            if span is not None:
+                span.attributes["workers"] = n
+            self.worker_group = WorkerGroup(
+                n,
+                self.scaling_config.worker_resources(),
+                self.scaling_config.placement_strategy,
+                bundles=self.scaling_config.as_placement_group_bundles()[:n],
+                runtime_env=getattr(self.scaling_config, "runtime_env",
+                                    None))
+        with builtin_metrics.setup_stage("backend", "setup::backend"):
+            self.backend.on_start(self.worker_group, self.backend_config)
 
     def run(self, train_fn: Callable, config: dict, trial_info: dict,
             checkpoint: Optional[Checkpoint] = None,
@@ -428,6 +436,10 @@ class BackendExecutor:
 
     def _run_once(self, train_fn, config, trial_info, checkpoint,
                   dataset_shards_per_worker, result_callback) -> Result:
+        # Where ``setup::loop_start`` begins; each rank ends it at its train
+        # function's first statement, on a thread of its own.
+        launched = {"wall": time.time(), "perf": time.perf_counter(),
+                    "pid": os.getpid()}
         group = self.worker_group
         latest_checkpoint = checkpoint
         self._reshard_accounting(checkpoint, len(group.workers))
@@ -445,7 +457,7 @@ class BackendExecutor:
                       rank < len(dataset_shards_per_worker) else None)
             starts[worker.start_training.remote(
                 train_fn, config, trial_info, checkpoint, shards,
-                ckpt_ctx)] = rank
+                ckpt_ctx, launched)] = rank
         self._drain(starts, latest_checkpoint, lambda rank, payload: None)
 
         history: List[Dict[str, Any]] = []

@@ -14,10 +14,11 @@ import threading
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
-from ray_tpu._private import chaos
+from ray_tpu._private import builtin_metrics, chaos
 from ray_tpu.air import session as air_session
 from ray_tpu.air.session import StopSession, _Session
 from ray_tpu.exceptions import ActorDiedError
+from ray_tpu.util import tracing
 from ray_tpu.util.placement_group import (PlacementGroup, placement_group,
                                           remove_placement_group)
 
@@ -82,8 +83,8 @@ class TrainWorker:
     def start_training(self, train_fn: Callable, config: dict,
                        trial_info: dict,
                        checkpoint=None, dataset_shards: Optional[dict] = None,
-                       ckpt_ctx: Optional[dict] = None
-                       ) -> None:
+                       ckpt_ctx: Optional[dict] = None,
+                       launched: Optional[dict] = None) -> None:
         self._chaos_gate("train.start_delay_ms")
         self.session = _Session(
             world_rank=self.world_rank,
@@ -125,6 +126,8 @@ class TrainWorker:
             air_session._set_session(sess)
             try:
                 try:
+                    if launched is not None:
+                        _observe_loop_start(launched, self.world_rank)
                     result = train_fn(config) if _wants_config(train_fn) \
                         else train_fn()
                     sess.result_queue.put(
@@ -165,6 +168,26 @@ class TrainWorker:
 
     def shutdown(self) -> None:
         self.request_stop()
+
+
+def _observe_loop_start(launched: dict, rank: int) -> None:
+    """The stage ``loop_start``: from the executor's launch of this attempt
+    (``launched``: both clocks and the pid, read on the driver's thread) to
+    here, the train function's first statement on this rank. It crosses
+    threads, so its span is recorded now that it is over."""
+    import os
+    import time
+    if os.getpid() == launched["pid"]:
+        seconds = time.perf_counter() - launched["perf"]
+        perf_start = launched["perf"]
+    else:  # another process's monotonic clock says nothing here
+        seconds, perf_start = time.time() - launched["wall"], 0.0
+    builtin_metrics.train_setup_seconds().observe(
+        max(0.0, seconds), tags={"stage": "loop_start", "within": "none"})
+    tracing.record_complete_span(
+        "setup::loop_start", tracing.finished_span_context(),
+        wall_start=launched["wall"], duration=seconds,
+        perf_start=perf_start, attributes={"rank": rank})
 
 
 def _wants_config(fn: Callable) -> bool:

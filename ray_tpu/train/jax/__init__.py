@@ -117,13 +117,23 @@ def prepare_mesh(mesh_config=None):
     mesh over every rank's devices.
     """
     import jax
+    from jax._src import xla_bridge
 
     import ray_tpu
-    from ray_tpu.parallel import MeshConfig, build_mesh
-    distributed_init_if_needed()
-    devices = (jax.devices() if jax.process_count() > 1
-               else ray_tpu.get_tpu_devices())
-    return build_mesh(mesh_config or MeshConfig(), devices=devices)
+    from ray_tpu._private import builtin_metrics
+    from ray_tpu.parallel import MeshConfig, build_mesh, compile_events
+    compile_events.install()
+    # Whoever asks for the devices first pays for the TPU client's start.
+    backend_started = not xla_bridge.backends_are_initialized()
+    with builtin_metrics.setup_stage("mesh", "setup::mesh") as span:
+        distributed_init_if_needed()
+        devices = (jax.devices() if jax.process_count() > 1
+                   else ray_tpu.get_tpu_devices())
+        mesh = build_mesh(mesh_config or MeshConfig(), devices=devices)
+        if span is not None:
+            span.attributes.update(devices=mesh.size,
+                                   backend_started=backend_started)
+    return mesh
 
 
 class JaxTrainer(DataParallelTrainer):

@@ -67,7 +67,8 @@ class Span:
     # In-process only, never serialized (meaningless across processes):
     # the start on ``time.perf_counter()``, which is monotonic and is the
     # clock an in-process reader times its own windows on (0.0 for a span
-    # recorded after the fact), and the name of the recording thread.
+    # recorded after the fact by a caller that did not know it), and the
+    # name of the recording thread.
     perf_start: float = field(default=0.0, repr=False, compare=False)
     thread: str = field(default="", repr=False, compare=False)
 
@@ -338,29 +339,53 @@ def continue_context(ctx: Optional[Dict[str, Any]], name: str,
 
 def record_complete_span(name: str, ctx: Optional[Dict[str, Any]], *,
                          wall_start: float, duration: float,
-                         attributes: Optional[Dict[str, Any]] = None
-                         ) -> Optional[Span]:
+                         attributes: Optional[Dict[str, Any]] = None,
+                         perf_start: float = 0.0,
+                         span_id: Optional[str] = None) -> Optional[Span]:
     """Record an already-finished span under ``ctx`` retroactively —
     for stages measured across callbacks (queue wait, result store)
     where no ``with`` block brackets the interval. ``wall_start`` is the
     anchor; ``duration`` must come from monotonic deltas. Like
-    continue_context, gated on the context alone, not ``_enabled``."""
+    continue_context, gated on the context alone, not ``_enabled``.
+    ``perf_start`` places it for in-process readers where the caller knows
+    it; ``span_id`` is for a parent recorded after children that already
+    name it."""
     if not _ctx_sampled(ctx):
         return None
     duration = max(0.0, float(duration))
     span = Span(
         name=name,
         trace_id=ctx["trace_id"],
-        span_id=uuid.uuid4().hex[:8],
+        span_id=span_id or uuid.uuid4().hex[:8],
         parent_id=ctx.get("parent_id"),
         start_time=wall_start,
         end_time=wall_start + duration,
         duration=duration,
         attributes=dict(attributes or {}),
+        perf_start=perf_start,
         thread=threading.current_thread().name,
     )
     _record(span)
     return span
+
+
+def finished_span_context() -> Optional[Dict[str, Any]]:
+    """``start_span``'s rule for a span that is over when its caller learns
+    of it (an interval JAX reports on exit, a stage that crosses threads):
+    the context to hand ``record_complete_span``, parented to the thread's
+    active span, or None where nothing records. Such a span cannot be a
+    ``TraceAnnotation``: the profile takes no event after the fact."""
+    if not _enabled and not _profiling():
+        return None
+    prev = getattr(_state, "span", None)
+    if prev is _UNSAMPLED:
+        return None
+    if prev is not None:
+        return span_context(prev)
+    if not _draw_sampled() and not _profiling():
+        return None
+    return {"trace_id": uuid.uuid4().hex[:16], "parent_id": None,
+            "sampled": True}
 
 
 def child_span(name: str, attributes: Optional[Dict[str, Any]] = None):

@@ -8,6 +8,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -342,3 +343,313 @@ def test_the_lowered_step_names_its_parts():
     for scope in ("block/attention", "block/mlp", "head_loss", "optimizer",
                   "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert scope in text, scope
+
+
+# -- set-up: stages, the step's first call, JAX's compile events -----------
+
+SETUP = "ray_tpu_train_setup_seconds"
+COMPILE = "ray_tpu_jax_compile_seconds"
+TRAIN_STAGES = ("worker_group", "backend", "loop_start")
+LOOP_STAGES = ("mesh", "state_init", "first_call")
+
+
+def _observations(name):
+    """{labels: count} of one of the process's histograms."""
+    from ray_tpu.util import metrics
+    for entry in metrics.snapshot():
+        if entry["name"] == name:
+            return dict(entry.get("counts", {}))
+    return {}
+
+
+def _observed_since(name, before):
+    now = _observations(name)
+    return {k: v - before.get(k, 0) for k, v in now.items()
+            if v != before.get(k, 0)}
+
+
+def _setup_loop(config):
+    """A user's loop on ``gpt-tiny``: mesh, state, step, one first call,
+    twenty warm ones, one with a new batch shape."""
+    if config["fail_once"] and not os.path.exists(config["fail_once"]):
+        open(config["fail_once"], "w").close()
+        raise RuntimeError("the first attempt dies before any set-up")
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt
+    from ray_tpu.parallel import MeshConfig, compile_events
+    from ray_tpu.parallel.train_step import (init_train_state,
+                                             make_train_step)
+    from ray_tpu.train import prepare_mesh
+    mesh = prepare_mesh(MeshConfig(dp=1, fsdp=1, tp=1))
+    cfg = gpt.config("gpt-tiny")
+    state = init_train_state(cfg, mesh)
+    make_train_step(cfg, mesh)  # built and never called
+    step = make_train_step(cfg, mesh)
+
+    def batch(rows):
+        tokens = jnp.zeros((rows, 64), jnp.int32)
+        return {"tokens": tokens, "targets": tokens}
+
+    def first_calls():
+        return sum(s.name == "step::first_call"
+                   for s in tracing.get_spans())
+
+    state, metrics = step(state, batch(2))
+    jax.block_until_ready(metrics)
+    after_first = first_calls(), _observations(SETUP).get(
+        ("first_call", "none"))
+    calls = compile_events.listener_calls
+    for _ in range(20):
+        state, metrics = step(state, batch(2))
+    jax.block_until_ready(metrics)
+    warm = {"listener_calls": compile_events.listener_calls - calls,
+            "spans": first_calls() - after_first[0],
+            "observed": _observations(SETUP).get(
+                ("first_call", "none")) - after_first[1]}
+    state, metrics = step(state, batch(4))
+    from jax._src import monitoring
+    listeners = monitoring.get_event_time_span_listeners()
+    session.report({
+        "warm": warm,
+        "listeners": sum(f is compile_events._on_time_span
+                         for f in listeners)})
+
+
+def _setup_fit(tmp_path, fail_once):
+    from ray_tpu._private import events
+    from ray_tpu.air import FailureConfig
+    rows, emit = [], events.emit
+
+    def capture(source, message, **kwargs):
+        rows.append((source, message, kwargs))
+        emit(source, message, **kwargs)
+
+    ray_tpu.shutdown()
+    before = {name: _observations(name) for name in (SETUP, COMPILE)}
+    events.emit = capture
+    try:
+        ray_tpu.init(num_cpus=4, num_tpus=1, _memory=1e9)
+        result = JaxTrainer(
+            _setup_loop,
+            train_loop_config={
+                "fail_once": str(tmp_path / "died") if fail_once else ""},
+            scaling_config=ScalingConfig(num_workers=1, use_tpu=True,
+                                         tpus_per_worker=1),
+            run_config=RunConfig(failure_config=FailureConfig(
+                max_failures=int(fail_once)))).fit()
+    finally:
+        events.emit = emit
+        ray_tpu.shutdown()
+    return {"metrics": result.metrics, "journal": rows,
+            "setup": _observed_since(SETUP, before[SETUP]),
+            "compile": _observed_since(COMPILE, before[COMPILE]),
+            "driver": threading.current_thread().name}
+
+
+@pytest.fixture(scope="module")
+def setup_traced(tmp_path_factory):
+    """One tiny job with tracing on, no failure."""
+    tracing.clear_spans()
+    tracing.set_sample_rate(None)
+    tracing.enable_tracing()
+    try:
+        run = _setup_fit(tmp_path_factory.mktemp("setup"), fail_once=False)
+    finally:
+        tracing.disable_tracing()
+    spans = tracing.get_spans()
+    tracing.clear_spans()
+    return dict(run, spans=spans, by_id={s.span_id: s for s in spans})
+
+
+@pytest.fixture(scope="module")
+def setup_restarted(tmp_path_factory):
+    """The same job with tracing off; its first attempt dies at the train
+    function's first statement and the gang restarts once."""
+    tracing.disable_tracing()
+    tracing.clear_spans()
+    run = _setup_fit(tmp_path_factory.mktemp("setup"), fail_once=True)
+    return dict(run, spans=tracing.get_spans())
+
+
+@pytest.mark.parametrize("stage", ("init",) + TRAIN_STAGES + LOOP_STAGES)
+def test_a_fit_observes_each_stage_once(setup_traced, stage):
+    want = 2 if stage == "first_call" else 1  # the new batch shape's too
+    assert setup_traced["setup"].get((stage, "none")) == want, \
+        setup_traced["setup"]
+
+
+@pytest.mark.parametrize("stage", ("init",) + TRAIN_STAGES + LOOP_STAGES)
+def test_a_gang_restart_observes_the_train_stages_again(setup_restarted,
+                                                        stage):
+    want = {"first_call": 2}.get(stage, 2 if stage in TRAIN_STAGES else 1)
+    assert setup_restarted["setup"].get((stage, "none")) == want, \
+        setup_restarted["setup"]
+    assert setup_restarted["spans"] == []  # tracing was off
+
+
+# name, parent's name (None: a root), thread ("driver": fit()'s caller).
+SETUP_TABLE = [
+    ("setup::init", None, "driver"),
+    ("setup::worker_group", None, "driver"),
+    ("setup::backend", None, "driver"),
+    ("setup::loop_start", None, LOOP),
+    ("setup::mesh", None, LOOP),
+    ("setup::state_init", None, LOOP),
+    ("step::first_call", None, LOOP),
+]
+
+
+@pytest.mark.parametrize("name,parent,thread", SETUP_TABLE)
+def test_set_up_yields_the_span(setup_traced, name, parent, thread):
+    test_a_save_yields_the_span(setup_traced, name, parent, thread)
+
+
+def test_the_set_up_spans_carry_their_attributes(setup_traced):
+    one = {s.name: s for s in setup_traced["spans"]
+           if s.name.startswith("setup::")}
+    assert one["setup::worker_group"].attributes == {"workers": 1}
+    assert one["setup::loop_start"].attributes == {"rank": 0}
+    assert one["setup::mesh"].attributes["devices"] == 1
+    assert isinstance(one["setup::mesh"].attributes["backend_started"], bool)
+    state = one["setup::state_init"].attributes
+    assert state["leaves"] > 10 and state["bytes"] > 1e5
+    # In order on the clock in-process readers use.
+    starts = [one[n].perf_start for n, _, _ in SETUP_TABLE[:6]]
+    assert starts == sorted(starts) and starts[0] > 0
+
+
+@pytest.mark.parametrize("program,parent", [
+    ("init", "setup::state_init"), ("step", "step::first_call")])
+def test_a_program_s_compile_hangs_under_its_stage(setup_traced, program,
+                                                   parent):
+    """JAX names the jitted function ``f`` while it traces it and
+    ``jit(f)`` from lowering on."""
+    stage = min((s for s in setup_traced["spans"] if s.name == parent),
+                key=lambda s: s.perf_start)
+    inside = [s for s in setup_traced["spans"]
+              if s.name.startswith("compile::") and stage.perf_start - 0.01
+              <= s.perf_start <= stage.perf_start + stage.duration]
+    # Placed from JAX's own stamps, parented by the thread's active span.
+    assert inside and all(s.parent_id == stage.span_id and s.thread == LOOP
+                          and s.perf_start + s.duration
+                          <= stage.perf_start + stage.duration + 0.01
+                          for s in inside)
+    own = {s.name: s for s in inside
+           if s.attributes["program"] in (program, f"jit({program})")}
+    assert set(own) == {"compile::trace", "compile::lower",
+                        "compile::backend"}, sorted(own)
+    assert own["compile::trace"].perf_start <= \
+        own["compile::lower"].perf_start <= own["compile::backend"].perf_start
+    assert own["compile::backend"].attributes["cache"] in (
+        "hit", "miss", "off")
+
+
+def test_a_warm_step_reaches_no_listener_and_records_nothing(setup_traced,
+                                                             setup_restarted):
+    for run in (setup_traced, setup_restarted):
+        assert run["metrics"]["warm"] == {"listener_calls": 0, "spans": 0,
+                                          "observed": 0}
+        # Two steps and an eval-less job built them; JAX holds one each.
+        assert run["metrics"]["listeners"] == 1
+
+
+def test_a_new_batch_shape_is_a_recompile_with_a_journal_row(setup_traced):
+    first, again = sorted(
+        (s for s in setup_traced["spans"] if s.name == "step::first_call"),
+        key=lambda s: s.perf_start)
+    assert first.attributes == {"program": "step", "recompile": False}
+    assert again.attributes == {"program": "step", "recompile": True}
+    children = [s for s in setup_traced["spans"]
+                if s.parent_id == again.span_id]
+    assert {"compile::trace", "compile::backend"} <= {
+        s.name for s in children}
+    [row] = [r for r in setup_traced["journal"]
+             if r[2].get("labels", {}).get("event") == "step_recompile"]
+    assert row[0] == "train" and row[1].startswith("step recompiled")
+
+
+def test_the_compile_series_name_the_stage_they_fell_in(setup_traced):
+    seen = setup_traced["compile"]
+    for within in ("state_init", "first_call"):
+        for phase in ("trace", "lower", "backend"):
+            assert seen.get((phase, within), 0) >= 1, seen
+    # ``program`` is an attribute of a span, never a label.
+    assert all(len(labels) == 2 for labels in seen)
+
+
+def test_a_stage_inside_another_says_so_and_is_summed_once(tracing_off):
+    before = _observations(SETUP)
+    with builtin_metrics.setup_stage("init", "setup::init") as span:
+        assert span is None and builtin_metrics.setup_stage_open() == "init"
+        with builtin_metrics.setup_stage("native_build",
+                                         "setup::native_build"):
+            assert builtin_metrics.setup_stage_open() == "native_build"
+    assert builtin_metrics.setup_stage_open() == "none"
+    assert _observed_since(SETUP, before) == {
+        ("init", "none"): 1, ("native_build", "init"): 1}
+
+
+def test_tracing_off_a_stage_is_the_shared_no_op_and_one_observation(
+        tracing_off):
+    stage = builtin_metrics.setup_stage("mesh", "setup::mesh")
+    assert stage._scope is tracing._NO_SPAN
+    assert tracing.finished_span_context() is None
+    before = _observations(SETUP)
+    with stage as span:
+        assert span is None
+    assert _observed_since(SETUP, before) == {("mesh", "none"): 1}
+    assert tracing.get_spans() == []
+
+
+def test_nested_compile_events_tile(tracing_off):
+    """What JAX reports nests: a trace of 10 s that held an inner trace of
+    1 s and an eager constant's compile of 2 s is observed as 7 + 1 + 2."""
+    from ray_tpu.parallel import compile_events
+    from ray_tpu.util import metrics
+    trace, backend = (e for e, p in compile_events._PHASE_BY_EVENT.items()
+                      if p in ("trace", "backend"))
+
+    def sums():
+        for entry in metrics.snapshot():
+            if entry["name"] == COMPILE:
+                return dict(entry["sums"])
+        return {}
+
+    before = sums()
+    t = time.time() + 1e6  # after everything this thread has reported
+    with builtin_metrics.setup_stage("first_call", "step::first_call"):
+        compile_events._on_time_span(trace, t + 1, t + 2, fun_name="add")
+        compile_events._on_event("/jax/compilation_cache/cache_hits")
+        compile_events._on_duration(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 1.5)
+        compile_events._on_time_span(backend, t + 3, t + 5,
+                                     fun_name="jit(iota)")
+        compile_events._on_time_span(trace, t, t + 10, fun_name="step")
+    grew = {k: v - before.get(k, 0.0) for k, v in sums().items()
+            if v != before.get(k, 0.0)}
+    assert grew == {("trace", "first_call"): pytest.approx(8.0),
+                    ("backend", "first_call"): pytest.approx(2.0),
+                    ("cache_load", "first_call"): pytest.approx(1.5)}
+    tiled = sum(v for (phase, _), v in grew.items() if phase != "cache_load")
+    assert tiled == pytest.approx(10.0)  # the whole, each second once
+
+
+@pytest.mark.parametrize("name,stage", [
+    ("setup::init", "train_setup"),
+    ("setup::loop_start", "train_setup"),
+    ("step::first_call", "train_setup"),
+    ("step::lower", "train_setup"),
+    ("compile::trace", "compile"),
+    ("compile::cache_load", "compile"),
+])
+def test_the_summary_groups_set_up_spans_by_stage(name, stage):
+    assert trace_assembler.span_stage({"name": name}) == stage
+
+
+def test_grafana_has_a_panel_for_set_up_by_stage():
+    from ray_tpu.dashboard.grafana import generate_dashboard
+    exprs = [t["expr"] for p in generate_dashboard()["panels"]
+             for t in p.get("targets", [])]
+    assert any("ray_tpu_train_setup_seconds_sum" in e for e in exprs)
