@@ -850,6 +850,20 @@ def train_moe_expert_load() -> Gauge:
         "balanced).")
 
 
+# -- delta-rule layers -----------------------------------------------------
+# Fed as the expert layers' scalars are (models/kimi_linear.py).
+
+
+def train_kda_decay_floor() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_kda_decay_floor",
+        "Most negative running sum of log-decays any chunk of any Kimi "
+        "Delta Attention layer reached in the last recorded step "
+        "(ops/kda.py forms exp of differences of it): float32's exp "
+        "underflows to 0 below -103.")
+
+
 # -- train set-up ----------------------------------------------------------
 # A few dozen events a process (and again at every gang restart), so their
 # durations are observed whether or not anybody traces; the span beside each

@@ -88,6 +88,9 @@ class DeepseekConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 50000.0
+    #: True: the latent layer carries no positions (q_r and k_r are not
+    #: rotated); the order then comes from other layers of the model.
+    mla_use_nope: bool = False
     intermediate_size: int = 11264
     moe_intermediate_size: int = 1408
     n_routed_experts: int = 64
@@ -223,7 +226,7 @@ def param_specs(cfg: DeepseekConfig, rules: ShardingRules) -> Dict[str, Any]:
 
 # -- forward ------------------------------------------------------------
 
-def _rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps):
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt((x32 ** 2).mean(-1, keepdims=True) + eps)
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
@@ -243,17 +246,22 @@ def _rope(x, positions, theta: float):
                             second * cos + first * sin], -1).astype(x.dtype)
 
 
-def _mla(cfg: DeepseekConfig, x, layer, positions):
-    """Multi-head latent attention on normed x [B, S, d] -> [B, S, d]."""
+def mla(cfg, x, layer, positions):
+    """Multi-head latent attention on normed x [B, S, d] -> [B, S, d]. cfg
+    is this module's config or another model's with the same latent keys
+    (``models/kimi_linear.py``); with ``cfg.mla_use_nope`` the 64 "rope"
+    dimensions of q and of the shared key go unrotated."""
     dt = cfg.dtype
     nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     q = jnp.einsum("bsd,dhk->bshk", x, layer["wq"].astype(dt))
     kv_a = jnp.einsum("bsd,dr->bsr", x, layer["w_kv_a"].astype(dt))
-    latent = _rmsnorm(kv_a[..., :rank], layer["kv_norm_scale"],
+    latent = rmsnorm(kv_a[..., :rank], layer["kv_norm_scale"],
                       cfg.rms_norm_eps)
     kv = jnp.einsum("bsr,rhk->bshk", latent, layer["w_kv_b"].astype(dt))
-    q_rope = _rope(q[..., nope:], positions, cfg.rope_theta)
-    k_rope = _rope(kv_a[..., None, rank:], positions, cfg.rope_theta)
+    rotated = (lambda x: x) if cfg.mla_use_nope else partial(
+        _rope, positions=positions, theta=cfg.rope_theta)
+    q_rope = rotated(q[..., nope:])
+    k_rope = rotated(kv_a[..., None, rank:])
     q = jnp.concatenate([q[..., :nope], q_rope], -1)
     k = jnp.concatenate(
         [kv[..., :nope],
@@ -262,7 +270,7 @@ def _mla(cfg: DeepseekConfig, x, layer, positions):
     return jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(dt))
 
 
-def _swiglu(x, w_gate, w_up, w_down):
+def swiglu(x, w_gate, w_up, w_down):
     dt = x.dtype
     gate = jnp.einsum("...d,df->...f", x, w_gate.astype(dt))
     up = jnp.einsum("...d,df->...f", x, w_up.astype(dt))
@@ -270,29 +278,43 @@ def _swiglu(x, w_gate, w_up, w_down):
                       w_down.astype(dt))
 
 
+def expert_ffn(x, layer, *, top_k: int, scaling: float, normalize: bool,
+               held):
+    """The expert layer on normed x [B, S, d] from a layer's leaves
+    (``router``, ``router_bias``, the held experts' ``w_gate`` / ``w_up`` /
+    ``w_down``, ``shared_*``): (this chip's part of the routed sum, the
+    shared experts, aux), the sums [B, S, d], for the caller to add in that
+    order. ``held`` is ``ops/moe.py``'s. aux: ``picked`` [B, S, K],
+    ``group_sizes`` [held experts] and, on a share, ``asked``."""
+    B, S, d = x.shape
+    routed, aux = routed_experts(
+        x.reshape(B * S, d), layer["router"], layer["router_bias"],
+        layer["w_gate"], layer["w_up"], layer["w_down"],
+        top_k=top_k, scaling=scaling, normalize=normalize, held=held)
+    with jax.named_scope("shared_expert"):
+        shared = swiglu(x, layer["shared_w_gate"], layer["shared_w_up"],
+                        layer["shared_w_down"])
+    aux["picked"] = aux["picked"].reshape(B, S, -1)
+    return routed.reshape(B, S, d), shared, aux
+
+
 def _block(cfg: DeepseekConfig, h, layer, positions):
     """One layer; which kind is read off the layer's own leaves. Returns
     (h, aux): aux is None for a dense layer, else the expert layer's
     ``picked`` [B, S, K] and ``group_sizes`` [E]."""
     with jax.named_scope("mla"):
-        h = h + _mla(cfg, _rmsnorm(h, layer["ln1_scale"], cfg.rms_norm_eps),
-                     layer, positions)
-    x = _rmsnorm(h, layer["ln2_scale"], cfg.rms_norm_eps)
+        h = h + mla(cfg, rmsnorm(h, layer["ln1_scale"], cfg.rms_norm_eps),
+                    layer, positions)
+    x = rmsnorm(h, layer["ln2_scale"], cfg.rms_norm_eps)
     if "router" not in layer:
         with jax.named_scope("mlp"):
-            return h + _swiglu(x, layer["w_gate"], layer["w_up"],
-                               layer["w_down"]), None
-    B, S, d = x.shape
-    routed, aux = routed_experts(
-        x.reshape(B * S, d), layer["router"], layer["router_bias"],
-        layer["w_gate"], layer["w_up"], layer["w_down"],
-        top_k=cfg.num_experts_per_tok, scaling=cfg.routed_scaling_factor,
-        normalize=cfg.norm_topk_prob, held=(0, cfg.n_routed_experts))
-    with jax.named_scope("shared_expert"):
-        shared = _swiglu(x, layer["shared_w_gate"], layer["shared_w_up"],
-                         layer["shared_w_down"])
-    aux["picked"] = aux["picked"].reshape(B, S, -1)
-    return h + routed.reshape(B, S, d) + shared, aux
+            return h + swiglu(x, layer["w_gate"], layer["w_up"],
+                              layer["w_down"]), None
+    routed, shared, aux = expert_ffn(
+        x, layer, top_k=cfg.num_experts_per_tok,
+        scaling=cfg.routed_scaling_factor, normalize=cfg.norm_topk_prob,
+        held=(0, cfg.n_routed_experts))
+    return h + routed + shared, aux
 
 
 def _no_expert_parallelism():
@@ -320,7 +342,7 @@ def hidden_states(params: Dict[str, Any], cfg: DeepseekConfig,
     x, _ = lm.scan_blocks(cfg, block, x, params["dense_layers"], positions)
     x, aux = lm.scan_blocks(cfg, block, x, params["moe_layers"], positions)
     x = constrain(x, "batch", "sequence", None)
-    return _rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps), aux
+    return rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps), aux
 
 
 def _head(params: Dict[str, Any], cfg: DeepseekConfig, x: jax.Array):

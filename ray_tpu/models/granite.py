@@ -243,17 +243,6 @@ def _rmsnorm(x, scale, eps):
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def _causal_conv(x, w, b):
-    """Depthwise causal convolution along S of x [B, S, C] with taps w [K,
-    C] and bias b [C], in float32: y_t = b + sum_k w_k x_(t - K + 1 + k),
-    zeros before the first token."""
-    taps, seq = w.shape[0], x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    w = w.astype(jnp.float32)
-    return b.astype(jnp.float32) + sum(
-        w[k] * padded[:, k:k + seq] for k in range(taps))
-
-
 def _mamba(cfg: GraniteConfig, x, layer):
     """The Mamba-2 mixer on normed x [B, S, d] -> [B, S, d]."""
     dt_, f32 = cfg.dtype, jnp.float32
@@ -261,7 +250,7 @@ def _mamba(cfg: GraniteConfig, x, layer):
     proj = jnp.einsum("bsd,de->bse", x, layer["w_in"].astype(dt_))
     z, xbc, dt = jnp.split(proj, [di, di + cfg.conv_dim], axis=-1)
     with jax.named_scope("conv"):
-        xbc = jax.nn.silu(_causal_conv(
+        xbc = jax.nn.silu(lm.causal_conv(
             xbc, layer["conv_w"], layer["conv_b"])).astype(dt_)
     u, B, C = jnp.split(xbc, [di, di + n], axis=-1)
     dt = jax.nn.softplus(dt.astype(f32) + layer["dt_bias"].astype(f32))

@@ -1,0 +1,518 @@
+"""Kimi Linear language models (``model_type: kimi_linear``): layers of Kimi
+Delta Attention (a gated delta rule with a decay a channel, linear in the
+sequence) with a latent-attention layer without positions after every
+third, and a routed-expert FFN with one shared expert after a leading dense
+layer.
+
+The config keys carry their published names (``KimiLinearConfig``;
+``linear_attn_config`` as published, its layer lists 1-based), so a
+``config.json`` of the family reads straight into ``KimiLinearConfig``. The
+published instance behind the preset is Kimi-Linear-48B-A3B-Instruct
+(https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/main/config.json).
+As ``KimiLinearForCausalLM`` computes it; no bias anywhere, every norm an
+RMSNorm with ``rms_norm_eps``::
+
+    h        = wte[tokens]
+    layer l (1-based), KDA iff l in kda_layers, dense iff l <= first_k_dense_replace:
+    x        = RMSNorm(h; g_in)
+    KDA:     q | k | v = silu(conv(x Wq)) | silu(conv(x Wk)) | silu(conv(x Wv))    heads x head_dim each;
+                         depthwise causal conv, short_conv_kernel_size taps
+             q, k   = q / max(|q|, 1e-6), k / max(|k|, 1e-6)  over a head ;  q = q * head_dim^-0.5
+             a      = -exp(A_log[head]) * softplus((x W_fa) W_fb + dt_bias)        log-decay <= 0, a channel, float32
+             beta   = sigmoid(x W_beta)                                            one a head and token, float32
+             per head, S [keys, values] zero before the first token:
+               S_t  = Diag(exp(a_t)) S_(t-1)
+               S_t  = S_t + beta_t k_t (v_t - S_t^T k_t)^T        the delta rule
+               o_t  = S_t^T q_t
+             m      = (RMSNorm(o; g_o) over a head  *  sigmoid((x W_ga) W_gb)) Wo
+    MLA:     models/deepseek.py's latent attention (q_lora_rank null), and with
+             mla_use_nope no rotation of q_r, k_r: no positions
+    h        = h + m
+    x        = RMSNorm(h; g_2)
+    dense:   W_down(silu(W_gate x) * W_up x)
+    experts: s = sigmoid(x W_r) in float32 ; picked = top num_experts_per_token of (s + b)
+             w = s[picked] / (sum s[picked] + 1e-20) * routed_scaling_factor      (moe_renormalize)
+             Shared(x) + sum_i w_i Expert_i(x)
+    h        = h + that
+    logits   = RMSNorm(h_last; g_f) W_head                 untied
+
+``W_fa`` / ``W_ga`` project to ``head_dim`` and ``W_fb`` / ``W_gb`` from it
+to heads x head_dim; ``A_log`` is one a head, ``dt_bias`` one a channel.
+``b`` (the router's correction bias) steers selection only and is not
+trained by the gradient, and no rule moves it here. The loss is the
+cross-entropy alone.
+
+The recurrence is ``ops/kda.py``'s chunked kernel pair (through
+``lm.delta_rule``), the short convolutions ``lm.causal_conv``, the latent
+attention and the expert FFN ``models/deepseek.py``'s own functions
+(``mla``, ``expert_ffn``: shared code, not a copy), the expert layer
+``ops/moe.py``. A layer's kind is its FFN and its mixer together
+(``dense_kda``, ``moe_kda``, ``moe_mla``, ...); every run of one kind is one
+stack of parameters and one scan (``lm.scan_blocks`` over ``layers``).
+
+**The chip's share.** ``experts_held = (first, count)`` says which of a
+layer's ``num_experts`` live here: the parameters hold those alone, the
+router stays ``num_experts`` wide, and the layer returns the shared expert
+plus this chip's part of the routed sum (``ops/moe.py``, "Held experts").
+None holds them all. A sliced vocabulary is a smaller ``vocab_size``. Expert
+parallelism (an ``ep`` mesh axis > 1) is not implemented: the share runs
+without an exchange.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass, replace
+from functools import partial
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu._private import builtin_metrics
+from ray_tpu.models import deepseek, lm
+from ray_tpu.ops.kda import decay_floor
+from ray_tpu.parallel.sharding import ShardingRules, constrain
+
+#: Metrics of ``loss_fn`` that count a batch: summed over accumulation
+#: microbatches where the others are averaged (parallel/train_step.py).
+SUMMED_METRICS = ("moe_assignments", "moe_tokens", "moe_routed")
+
+#: Metrics of ``loss_fn`` that feed the registry, each with what records
+#: its value there (parallel/train_step.py reads them without a sync).
+RECORDED_METRICS = {
+    "moe_assignments":
+        lambda value: builtin_metrics.train_moe_assignments().inc(value),
+    "moe_tokens":
+        lambda value: builtin_metrics.train_moe_tokens().inc(value),
+    "moe_routed":
+        lambda value: builtin_metrics.train_moe_routed().inc(value),
+    "moe_load_max_over_mean":
+        lambda value: builtin_metrics.train_moe_expert_load().set(value),
+    "kda_decay_floor":
+        lambda value: builtin_metrics.train_kda_decay_floor().set(value),
+}
+
+
+@dataclass(frozen=True)
+class LinearAttnConfig:
+    """The published ``linear_attn_config`` group; layers count from 1."""
+    kda_layers: Tuple[int, ...] = tuple(
+        l for l in range(1, 28) if l % 4 and l != 27)
+    full_attn_layers: Tuple[int, ...] = (4, 8, 12, 16, 20, 24, 27)
+    num_heads: int = 32
+    head_dim: int = 128
+    short_conv_kernel_size: int = 4
+
+    def __post_init__(self):
+        for name in ("kda_layers", "full_attn_layers"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+
+
+@dataclass(frozen=True)
+class KimiLinearConfig:
+    # Published keys, under their published names.
+    vocab_size: int = 163840
+    hidden_size: int = 2304
+    num_hidden_layers: int = 27
+    first_k_dense_replace: int = 1
+    #: Which of the published depth's layers are KDA and which latent
+    #: attention; a model cut to ``num_hidden_layers`` runs the first that
+    #: many. A dict (as ``config.json`` has it) or a ``LinearAttnConfig``.
+    linear_attn_config: Any = LinearAttnConfig()
+    num_attention_heads: int = 32
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mla_use_nope: bool = True
+    rope_theta: float = 10000.0
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    num_experts: int = 256
+    num_experts_per_token: int = 8
+    num_shared_experts: int = 1
+    moe_renormalize: bool = True
+    routed_scaling_factor: float = 2.446
+    rms_norm_eps: float = 1e-5
+    model_max_length: int = 1048576
+    #: (first, count) of the ``num_experts`` whose weights live here; None:
+    #: all of them.
+    experts_held: Optional[Tuple[int, int]] = None
+    # The program's own choices (as GPTConfig has them).
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    remat: bool = True
+    # "full" keeps of a block only what the flash forward kernel returns
+    # (output and log-sum-exp, at long sequences: lm.scan_blocks).
+    # "selective" adds the values a block names for it, and this model's
+    # blocks name none.
+    remat_policy: str = "full"
+    loss_chunk: int = 0
+    attn_impl: str = "dot"  # "dot" | "flash"
+    attn_blk_q: int = 512
+    attn_blk_k: int = 512
+
+    def __post_init__(self):
+        if isinstance(self.linear_attn_config, dict):
+            object.__setattr__(self, "linear_attn_config",
+                               LinearAttnConfig(**self.linear_attn_config))
+        if self.experts_held is not None:
+            object.__setattr__(self, "experts_held",
+                               tuple(self.experts_held))
+            first, count = self.experts_held
+            if first < 0 or count < 1 or first + count > self.num_experts:
+                raise ValueError(f"experts_held={self.experts_held} of "
+                                 f"{self.num_experts} experts")
+        linear = self.linear_attn_config
+        depth = range(1, self.num_hidden_layers + 1)
+        if any((l in linear.kda_layers) == (l in linear.full_attn_layers)
+               for l in depth):
+            raise ValueError(
+                "every layer up to num_hidden_layers must be in exactly one "
+                "of linear_attn_config's kda_layers and full_attn_layers")
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.num_experts if self.experts_held is None \
+            else self.experts_held[1]
+
+    @property
+    def layers(self) -> Tuple[str, ...]:
+        """The kind of each layer that runs: its FFN (``dense`` or ``moe``)
+        and its mixer (``kda`` or ``mla``), as ``dense_kda``."""
+        kda = self.linear_attn_config.kda_layers
+        return tuple(
+            ("dense_" if l <= self.first_k_dense_replace else "moe_")
+            + ("kda" if l in kda else "mla")
+            for l in range(1, self.num_hidden_layers + 1))
+
+    @property
+    def n_moe_layers(self) -> int:
+        return max(self.num_hidden_layers - self.first_k_dense_replace, 0)
+
+
+PRESETS: Dict[str, KimiLinearConfig] = {
+    "kimi-linear-48b-a3b": KimiLinearConfig(),
+    # Test size: all four kinds of layer, heads of 128 and sequences of
+    # whole chunks so that the delta rule's kernels run (interpreted) on the
+    # CPU, 8 experts with 2 a token.
+    "kimi-linear-tiny": KimiLinearConfig(
+        vocab_size=256, hidden_size=64, num_hidden_layers=5,
+        first_k_dense_replace=2,
+        linear_attn_config=LinearAttnConfig(
+            kda_layers=(1, 3, 4), full_attn_layers=(2, 5), num_heads=2,
+            head_dim=128),
+        num_attention_heads=4, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128,
+        moe_intermediate_size=32, num_experts=8, num_experts_per_token=2,
+        model_max_length=512, dtype=jnp.float32, remat=False),
+}
+
+KINDS = tuple(ffn + mixer for ffn in ("dense_", "moe_")
+              for mixer in ("kda", "mla"))
+
+
+def runs(layers) -> Tuple[Tuple[str, str, int], ...]:
+    """(name in the parameter tree, kind, layers) of every run of one kind
+    of layer, in order: ``run00_dense_kda``, ``run01_moe_kda``, ... A run is
+    one stack of parameters and one ``lax.scan``."""
+    return tuple((f"run{i:02d}_{kind}", kind, n)
+                 for i, (kind, n) in enumerate(lm.layer_runs(layers)))
+
+
+def config(name: str, **overrides) -> KimiLinearConfig:
+    cfg = PRESETS[name]
+    return replace(cfg, **overrides) if overrides else cfg
+
+
+# -- parameters ---------------------------------------------------------
+
+def _shapes(cfg: KimiLinearConfig):
+    """{"kda" | "mla" | "dense" | "moe": {leaf: (shape without the layers
+    axis, logical axes, init)}}: one table for ``init`` and ``param_specs``;
+    a layer holds its mixer's leaves and its FFN's. ``init`` is a std for a
+    normal draw, or "ones" | "zeros" | "decay_rate" | "decay_bias"."""
+    d, std = cfg.hidden_size, 0.02
+    linear = cfg.linear_attn_config
+    kh, hd, taps = linear.num_heads, linear.head_dim, \
+        linear.short_conv_kernel_size
+    h = cfg.num_attention_heads
+    norms = {"ln_in_scale": ((d,), ("embed",), "ones"),
+             "ln2_scale": ((d,), ("embed",), "ones")}
+    heads = ("embed", "heads", "head_dim")
+    kda = {
+        "wq": ((d, kh, hd), heads, std),
+        "wk": ((d, kh, hd), heads, std),
+        "wv": ((d, kh, hd), heads, std),
+        # nn.Conv1d's default, uniform(+-K^-1/2), has this variance.
+        **{f"conv_{x}": ((taps, kh * hd), (None, None), (3 * taps) ** -0.5)
+           for x in "qkv"},
+        "w_fa": ((d, hd), ("embed", None), std),
+        "w_fb": ((hd, kh, hd), (None, "heads", "head_dim"), std),
+        "A_log": ((kh,), (None,), "decay_rate"),
+        "dt_bias": ((kh, hd), (None, None), "decay_bias"),
+        "w_beta": ((d, kh), ("embed", None), std),
+        "o_norm_scale": ((hd,), (None,), "ones"),
+        "w_ga": ((d, hd), ("embed", None), std),
+        "w_gb": ((hd, kh, hd), (None, "heads", "head_dim"), std),
+        "wo": ((kh, hd, d), ("heads", "head_dim", "embed"), std),
+    }
+    mla = {
+        "wq": ((d, h, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim), heads,
+               std),
+        "w_kv_a": ((d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+                   ("embed", None), std),
+        "kv_norm_scale": ((cfg.kv_lora_rank,), (None,), "ones"),
+        "w_kv_b": ((cfg.kv_lora_rank, h,
+                    cfg.qk_nope_head_dim + cfg.v_head_dim),
+                   (None, "heads", "head_dim"), std),
+        "wo": ((h, cfg.v_head_dim, d), ("heads", "head_dim", "embed"), std),
+    }
+
+    def swiglu(width, prefix=""):
+        return {prefix + "w_gate": ((d, width), ("embed", "mlp"), std),
+                prefix + "w_up": ((d, width), ("embed", "mlp"), std),
+                prefix + "w_down": ((width, d), ("mlp", "embed"), std)}
+
+    e, held, f = cfg.num_experts, cfg.n_experts_held, \
+        cfg.moe_intermediate_size
+    moe = {
+        "router": ((d, e), ("embed", None), std),
+        # The correction bias: a buffer of zeros that the gradient never
+        # moves.
+        "router_bias": ((e,), (None,), "zeros"),
+        "w_gate": ((held, d, f), ("expert", "embed", "mlp"), std),
+        "w_up": ((held, d, f), ("expert", "embed", "mlp"), std),
+        "w_down": ((held, f, d), ("expert", "mlp", "embed"), std),
+        **swiglu(cfg.num_shared_experts * f, "shared_"),
+    }
+    return {"kda": dict(norms, **kda), "mla": dict(norms, **mla),
+            "dense": swiglu(cfg.intermediate_size), "moe": moe}
+
+
+def _leaves_of(shapes, kind: str):
+    ffn, mixer = kind.split("_")
+    return dict(shapes[mixer], **shapes[ffn])
+
+
+def init(cfg: KimiLinearConfig, key: jax.Array) -> Dict[str, Any]:
+    """Parameters: normal(0, 0.02) matrices, RMSNorm scales of one, a zero
+    correction bias, the convolutions' taps normal with the variance of
+    ``nn.Conv1d``'s default, and the decay's two vectors as published for
+    the gated delta rule: ``A_log`` = log U(1, 16) a head, ``dt_bias`` the
+    inverse softplus of a log-uniform (0.001, 0.1) a channel, so that the
+    log-decays start between -0.001 and -1.6 a step. Every run of one kind
+    of layer (``runs``) is a stack of its own, over a leading layers
+    axis."""
+    pd = cfg.param_dtype
+    k_embed, k_head, k_layers = jax.random.split(key, 3)
+
+    def leaf(k, shape, how):
+        if how == "ones":
+            return jnp.ones(shape, pd)
+        if how == "zeros":
+            return jnp.zeros(shape, pd)
+        if how == "decay_rate":
+            return jnp.log(jax.random.uniform(
+                k, shape, jnp.float32, 1.0, 16.0)).astype(pd)
+        if how == "decay_bias":
+            dt = jnp.exp(jax.random.uniform(
+                k, shape, jnp.float32, math.log(0.001), math.log(0.1)))
+            return (dt + jnp.log(-jnp.expm1(-dt))).astype(pd)
+        return (jax.random.normal(k, shape, jnp.float32) * how).astype(pd)
+
+    params = {
+        "wte": leaf(k_embed, (cfg.vocab_size, cfg.hidden_size), 0.02),
+        "lnf_scale": jnp.ones((cfg.hidden_size,), pd),
+        "lm_head": leaf(k_head, (cfg.hidden_size, cfg.vocab_size), 0.02),
+    }
+    shapes = _shapes(cfg)
+    for index, (run, kind, depth) in enumerate(runs(cfg.layers)):
+        leaves = _leaves_of(shapes, kind)
+        keys = jax.random.split(jax.random.fold_in(k_layers, index),
+                                len(leaves))
+        params[run] = {
+            name: leaf(k, (depth,) + shape, how)
+            for k, (name, (shape, _, how)) in zip(keys, leaves.items())}
+    return params
+
+
+def param_specs(cfg: KimiLinearConfig, rules: ShardingRules
+                ) -> Dict[str, Any]:
+    """PartitionSpec pytree matching init()'s structure."""
+    specs = {"wte": rules.spec("vocab", "embed"),
+             "lnf_scale": rules.spec("embed"),
+             "lm_head": rules.spec("embed", "vocab")}
+    shapes = _shapes(cfg)
+    for run, kind, _ in runs(cfg.layers):
+        specs[run] = {name: rules.spec("layers", *axes)
+                      for name, (_, axes, _) in
+                      _leaves_of(shapes, kind).items()}
+    return specs
+
+
+# -- forward ------------------------------------------------------------
+
+def _unit(y, scale: float = 1.0):
+    """y [..., K] with every vector of K brought to length ``scale`` (its
+    own length held above 1e-6), in float32, in the dtype it came in."""
+    y32 = y.astype(jnp.float32)
+    return (y32 * (scale / jnp.maximum(
+        jnp.sqrt((y32 * y32).sum(-1, keepdims=True)), 1e-6))).astype(y.dtype)
+
+
+def _kda(cfg: KimiLinearConfig, x, layer):
+    """Kimi Delta Attention on normed x [B, S, d] -> (m [B, S, d], the
+    most negative running log-decay a chunk reaches)."""
+    dt, f32 = cfg.dtype, jnp.float32
+    linear = cfg.linear_attn_config
+    heads, hd = linear.num_heads, linear.head_dim
+    split = x.shape[:2] + (heads, hd)
+
+    def short_conv(name):
+        flat = jnp.einsum("bsd,dhk->bshk", x, layer["w" + name].astype(dt)
+                          ).reshape(x.shape[:2] + (heads * hd,))
+        return jax.nn.silu(lm.causal_conv(
+            flat, layer["conv_" + name])).astype(dt).reshape(split)
+
+    with jax.named_scope("conv"):
+        q, k, v = short_conv("q"), short_conv("k"), short_conv("v")
+
+    q, k = _unit(q, hd ** -0.5), _unit(k)
+    with jax.named_scope("kda_gate"):
+        low = jnp.einsum("bsd,dr->bsr", x, layer["w_fa"].astype(dt))
+        rate = jnp.einsum("bsr,rhk->bshk", low, layer["w_fb"].astype(dt))
+        a = -jnp.exp(layer["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+            rate.astype(f32) + layer["dt_bias"].astype(f32))
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dh->bsh", x, layer["w_beta"].astype(dt)).astype(f32))
+        low = jnp.einsum("bsd,dr->bsr", x, layer["w_ga"].astype(dt))
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "bsr,rhk->bshk", low, layer["w_gb"].astype(dt)).astype(f32))
+    out = lm.delta_rule(q, k, v, a, beta)
+    with jax.named_scope("kda_gate"):
+        gated = deepseek.rmsnorm(out.astype(f32), layer["o_norm_scale"],
+                                 cfg.rms_norm_eps) * gate
+    return jnp.einsum("bshk,hkd->bsd", gated.astype(dt),
+                      layer["wo"].astype(dt)), \
+        decay_floor(a)
+
+
+def _block(cfg: KimiLinearConfig, kind: str, h, layer, positions):
+    """One layer of ``kind`` (``runs``). Returns (h, aux): ``decay_floor``
+    (0 for a latent layer) and, of an expert layer, ``picked`` [B, S, K],
+    ``group_sizes`` [held experts] and ``asked`` (assignments the router
+    gave them)."""
+    eps = cfg.rms_norm_eps
+    ffn, mixer = kind.split("_")
+    x = deepseek.rmsnorm(h, layer["ln_in_scale"], eps)
+    with jax.named_scope(mixer):
+        if mixer == "kda":
+            m, floor = _kda(cfg, x, layer)
+        else:
+            m, floor = deepseek.mla(cfg, x, layer, positions), \
+                jnp.float32(0.0)
+        h = h + m
+    aux = {"decay_floor": floor}
+    x = deepseek.rmsnorm(h, layer["ln2_scale"], eps)
+    if ffn == "dense":
+        with jax.named_scope("mlp"):
+            return h + deepseek.swiglu(x, layer["w_gate"], layer["w_up"],
+                                       layer["w_down"]), aux
+    routed, shared, moe = deepseek.expert_ffn(
+        x, layer, top_k=cfg.num_experts_per_token,
+        scaling=cfg.routed_scaling_factor, normalize=cfg.moe_renormalize,
+        held=cfg.experts_held)
+    aux.update(picked=moe["picked"], group_sizes=moe["group_sizes"],
+               # With every expert held the router's assignments are all
+               # asked.
+               asked=moe.get("asked", jnp.int32(moe["picked"].size)))
+    return h + routed + shared, aux
+
+
+def _no_expert_parallelism():
+    from ray_tpu.parallel.mesh import current_mesh
+    mesh = current_mesh()
+    if mesh is not None and mesh.shape.get("ep", 1) > 1:
+        raise NotImplementedError(
+            "models/kimi_linear.py does not implement expert parallelism: "
+            "the mesh has ep > 1, and the expert layer (ops/moe.py) "
+            "computes the experts held here (experts_held) without an "
+            "exchange. Use ep=1 (fsdp and tp shard the expert weights).")
+
+
+def hidden_states(params: Dict[str, Any], cfg: KimiLinearConfig,
+                  tokens: jax.Array,
+                  positions: Optional[jax.Array] = None):
+    """tokens [B, S] int32 -> (final-normed hidden [B, S, d], aux) with aux
+    ``decay_floor`` [L] and the expert layers' ``picked`` [L_moe, B, S, K],
+    ``group_sizes`` [L_moe, held experts] and ``asked`` [L_moe], in layer
+    order. No layer reads ``positions`` under ``mla_use_nope``: the delta
+    rule's layers carry the order."""
+    _no_expert_parallelism()
+    if positions is None:
+        positions = lm.positions_of(tokens)
+    x = lm.embed(params["wte"], tokens, cfg.dtype)  # batch-split
+    x, auxes = lm.scan_blocks(
+        cfg, {kind: partial(_block, cfg, kind) for kind in KINDS}, x,
+        [params[run] for run, _, _ in runs(cfg.layers)], positions,
+        layer_types=cfg.layers)
+    x = constrain(x, "batch", "sequence", None)
+    aux = {name: jnp.concatenate([a[name] for a in auxes if name in a])
+           for name in sorted({name for a in auxes for name in a})}
+    return deepseek.rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps), aux
+
+
+def head(params: Dict[str, Any], cfg: KimiLinearConfig, x: jax.Array):
+    """Logits [..., vocab] of final-normed hidden states x [..., d]."""
+    return jnp.einsum("...d,dv->...v", x, params["lm_head"].astype(cfg.dtype))
+
+
+def forward_with_aux(params: Dict[str, Any], cfg: KimiLinearConfig,
+                     tokens: jax.Array,
+                     positions: Optional[jax.Array] = None):
+    """tokens [B, S] -> (logits [B, S, vocab], aux of ``hidden_states``)."""
+    x, aux = hidden_states(params, cfg, tokens, positions)
+    return head(params, cfg, x), aux
+
+
+def forward(params: Dict[str, Any], cfg: KimiLinearConfig, tokens: jax.Array,
+            positions: Optional[jax.Array] = None) -> jax.Array:
+    return forward_with_aux(params, cfg, tokens, positions)[0]
+
+
+def loss_of_hidden(params: Dict[str, Any], cfg: KimiLinearConfig,
+                   x: jax.Array, aux, targets: jax.Array,
+                   mask: Optional[jax.Array] = None
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """``loss_fn`` from ``hidden_states``' result (x [B, S, d], aux)."""
+    loss, metrics = lm.next_token_loss(
+        partial(head, lm.head_gathered(params, tied=False), cfg), x,
+        targets, mask, cfg.loss_chunk, 0.0)
+    metrics = dict(metrics, kda_decay_floor=aux["decay_floor"].min())
+    if "group_sizes" not in aux:
+        return loss, metrics
+    sizes = aux["group_sizes"].astype(jnp.float32)  # [L_moe, held]
+    return loss, {
+        **metrics,
+        "moe_assignments": sizes.sum(),
+        "moe_tokens": aux["asked"].astype(jnp.float32).sum(),
+        "moe_routed": jnp.float32(
+            targets.size * cfg.num_experts_per_token * cfg.n_moe_layers),
+        "moe_load_max_over_mean": (
+            sizes.max(-1) / jnp.maximum(sizes.mean(-1), 1e-9)).max(),
+    }
+
+
+def loss_fn(params: Dict[str, Any], cfg: KimiLinearConfig, tokens: jax.Array,
+            targets: jax.Array, mask: Optional[jax.Array] = None
+            ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Next-token cross-entropy in fp32 (chunked by ``cfg.loss_chunk``), no
+    balance term. The metrics carry what the expert layers did
+    (``moe_routed``, ``moe_tokens``, ``moe_assignments``,
+    ``moe_load_max_over_mean``, as ``models/afmoe.py``'s) and
+    ``kda_decay_floor``: the most negative running sum of log-decays any
+    chunk of any KDA layer reached this step."""
+    x, aux = hidden_states(params, cfg, tokens)
+    return loss_of_hidden(params, cfg, x, aux, targets, mask)

@@ -527,26 +527,59 @@ def exchanged_over_tp(block, mesh, layers, layer_specs):
 
 # -- state-space scan -----------------------------------------------------
 
+def _over_batch_shards(fn, args, has_rows):
+    """``fn(*args)`` -> [B, S, H, P], per shard of the batch under a mesh
+    (GSPMD cannot partition a Mosaic kernel): a recurrence along S is
+    independent per batch row, so each shard scans its own rows whole, with
+    every head. ``has_rows`` says which arguments lead with the batch axis;
+    the others go in whole."""
+    from ray_tpu.parallel.mesh import current_mesh
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    from ray_tpu._private.jax_compat import shard_map
+    batch = ambient_spec(mesh, "batch")[0]
+    rows = lambda rank: PartitionSpec(batch, *[None] * (rank - 1))
+    return shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(rows(a.ndim) if own else PartitionSpec(None)
+                       for a, own in zip(args, has_rows)),
+        out_specs=rows(4), check_vma=False)(*args)
+
+
 def state_space(u, dt, A, B, C, D, chunk: int):
     """The Mamba-2 recurrence ``S_t = exp(dt_t A) S_(t-1) + dt_t u_t B_t^T``,
     ``y_t = S_t C_t + D u_t`` by ``ops/ssd.py``'s chunked scan. u: [B, S, H,
     P], dt: [B, S, H] (positive), A, D: [H], B, C: [B, S, N] -> [B, S, H,
-    P]. Under a mesh the kernels run per shard, as the flash kernels do:
-    the recurrence is independent per batch row, so each shard scans its
-    own rows whole, with every head."""
+    P]. Under a mesh the kernels run per shard of the batch, as the flash
+    kernels do."""
     from ray_tpu.ops.ssd import ssd
-    from ray_tpu.parallel.mesh import current_mesh
-    fn = partial(ssd, chunk=chunk)
-    mesh = current_mesh()
-    if mesh is None or mesh.size == 1:
-        return fn(u, dt, A, B, C, D)
-    from ray_tpu._private.jax_compat import shard_map
-    batch = ambient_spec(mesh, "batch")[0]
-    rows = lambda rank: PartitionSpec(batch, *[None] * (rank - 1))
-    head = PartitionSpec(None)
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(rows(4), rows(3), head, rows(3), rows(3), head),
-                     out_specs=rows(4), check_vma=False)(u, dt, A, B, C, D)
+    return _over_batch_shards(
+        partial(ssd, chunk=chunk), (u, dt, A, B, C, D),
+        (True, True, False, True, True, False))
+
+
+def causal_conv(x, w, b=None):
+    """Depthwise causal convolution along S of x [B, S, C] with taps w [K,
+    C] and, if given, bias b [C], in float32: y_t = b + sum_k w_k x_(t - K +
+    1 + k), zeros before the first token. The short convolution of a
+    state-space layer (``models/granite.py``) and of a delta-rule layer's q,
+    k and v (``models/kimi_linear.py``)."""
+    taps, seq = w.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    w = w.astype(jnp.float32)
+    bias = 0.0 if b is None else b.astype(jnp.float32)
+    return bias + sum(w[k] * padded[:, k:k + seq] for k in range(taps))
+
+
+def delta_rule(q, k, v, a, beta):
+    """The gated delta rule with a decay a channel, ``S_t = Diag(exp(a_t))
+    S_(t-1)``, ``S_t += beta_t k_t (v_t - S_t^T k_t)^T``, ``o_t = S_t^T
+    q_t``, by ``ops/kda.py``'s chunked kernels. q, k, a: [B, S, H, K], v:
+    [B, S, H, V], beta: [B, S, H] -> [B, S, H, V]. Under a mesh the kernels
+    run per shard of the batch, as ``state_space``'s do."""
+    from ray_tpu.ops.kda import kda
+    return _over_batch_shards(kda, (q, k, v, a, beta), (True,) * 5)
 
 
 # -- head and loss --------------------------------------------------------

@@ -1,0 +1,456 @@
+"""Kimi Delta Attention: a gated delta rule with a decay a channel, in its
+chunked form, as a pair of Pallas TPU kernels (forward, backward), the same
+algorithm in ``jax.numpy``, and the literal recurrence.
+
+Per head, with a state ``S`` [K keys, V values] that is zero before the
+first token::
+
+    S_t = Diag(exp(a_t)) S_(t-1)                           the decay, a channel of the key
+    S_t = S_t + beta_t k_t (v_t - S_t^T k_t)^T             the delta rule: what k_t reads is replaced
+    o_t = S_t^T q_t
+
+``q``, ``k`` [batch, S, H, K] and ``v`` [batch, S, H, V] hold H heads, ``a``
+[batch, S, H, K] the log-decays (<= 0, float32), ``beta`` [batch, S, H] the
+write strengths (0..1, float32). The literal recurrence (``kda_recurrent``)
+is S sequential steps; the chunked form does a chunk of L steps as matrix
+products. With ``cum_t`` the sum of ``a`` from the chunk's first token to t
+(a vector of K) and ``S_0`` the state on entry, the state inside the chunk is
+
+    S_t = Diag(exp(cum_t)) S_0 + sum_{s<=t} Diag(exp(cum_t - cum_s)) k_s u_s^T
+
+for pseudo-values ``u`` [L, V] that the chunk's keys fix among themselves:
+
+    (I + A) U = Diag(beta) (V - (K * exp(cum)) S_0)
+    A_ts      = beta_t sum_c k_t[c] k_s[c] exp(cum_t[c] - cum_s[c])     s < t, else 0
+    O         = (Q * exp(cum)) S_0 + B U
+    B_ts      = sum_c q_t[c] k_s[c] exp(cum_t[c] - cum_s[c])            s <= t, else 0
+    S_L       = Diag(exp(cum_L)) S_0 + (K * exp(cum_L - cum))^T U
+
+and only ``S_0 -> S_L`` runs along the sequence, once a chunk.
+
+**Every decay factor is exp of a difference ``cum_t - cum_s`` with s <= t.**
+``exp(-cum)`` overflows float32 inside one chunk at the published
+initialisation (log-decays down to -1.6 a step, -100 over 64 steps), so A
+and B are not formed as ``(k exp(cum)) (k exp(-cum))^T``. A pair (t, s),
+s < t, is in exactly one level h = 1, 2, 4, ..., L / 2: the one at which t is
+in the second half and s in the first half of the same block of 2h rows.
+There the row r at the end of the first half lies between them, and
+``exp(cum_t - cum_s) = exp(cum_t - cum_r) exp(cum_r - cum_s)``: two factors
+<= 1, both ``exp(-|cum - cum_r|)`` of their own row, so a level is one
+scaling of the rows and one matrix product, masked to its pairs
+(``_pair_products``). The unit lower-triangular
+``(I + A)`` is inverted by the same doubling (``_unit_lower_inverse``: the
+inverse of a block from those of its halves) in float32 products: at the
+highest precision for float32 inputs, and for bfloat16 inputs at sixteen
+bits (each operand as two bfloat16 pieces, three products of pieces: half
+the passes, and still 256 times finer than the operands around it; the
+inverse is 70 % of a forward call at the highest precision on the v5e). The
+other products run in the dtype of ``q`` with float32 accumulation; ``a``,
+``cum``, ``beta`` and the states are float32.
+
+The kernels' grid is ``(batch, heads, chunks)``, the last sequential: a grid
+step is one chunk of one head. The head's state (kept transposed, [V, K],
+so that its decay is a value a lane) stays in VMEM scratch from chunk to
+chunk as ``ops/ssd.py`` and the flash kernels carry theirs. The forward also
+writes each chunk's entry state, which the backward reads: it walks the
+chunks in reverse with the cotangent of the state carried the same way, and
+differentiates the chunk's own function (``_chunk``) where it stands.
+``cum`` is made outside the kernels, by XLA, which differentiates the running
+sum too: the kernels take ``cum`` and return its cotangent. In a trace the
+kernels are ``kda_fwd`` and ``kda_bwd``, under the scope ``kda``.
+
+``kda`` is the one entry: the kernels where the shapes tile (S a multiple of
+the chunk, K and V multiples of 128 lanes), else ``kda_chunked``, the same
+chunk function under ``vmap`` and a ``lax.scan`` over chunks, which is also
+the kernels' oracle in the tests. On backends other than the TPU the kernels
+run in interpreter mode.
+"""
+
+from __future__ import annotations
+
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+F32 = jnp.float32
+_HIGHEST = jax.lax.Precision.HIGHEST
+#: Rows of a float32 sublane tile: blocks of whole tiles are reshaped, the
+#: rows inside one are picked by a mask.
+_TILE = 8
+#: Positions a chunk. The published kernels use 64; on the v5e a call at
+#: 16k tokens of 32 heads of 128 takes 14.6 ms forward and 44 ms forward and
+#: backward at 128 against 20.3 and 57 at 64: half the grid steps, state
+#: updates and entry states for one more level of doubling.
+CHUNK = 128
+
+
+def _interpret() -> bool:
+    """The flash kernels' answer (interpreter mode off the TPU), asked of
+    that module each time so that one switch steers every kernel of
+    ``ops/``."""
+    return importlib.import_module(
+        "ray_tpu.ops.flash_attention")._interpret()
+
+
+# -- the literal recurrence -------------------------------------------------
+
+def kda_recurrent(q, k, v, a, beta):
+    """The recurrence at the top of this file, token by token, in float32:
+    o [batch, S, H, V]. The chunked forms' oracle."""
+    q, k, v, a, beta = (x.astype(F32) for x in (q, k, v, a, beta))
+    batch, _, heads, width = q.shape
+
+    def step(state, token):
+        q_t, k_t, v_t, a_t, beta_t = token
+        state = jnp.exp(a_t)[..., None] * state
+        read = jnp.einsum("bhkv,bhk->bhv", state, k_t)
+        state = state + (beta_t[..., None] * k_t)[..., None] \
+            * (v_t - read)[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
+
+    _, out = jax.lax.scan(
+        step, jnp.zeros((batch, heads, width, v.shape[-1]), F32),
+        tuple(x.swapaxes(0, 1) for x in (q, k, v, a, beta)))
+    return out.swapaxes(0, 1)
+
+
+# -- one chunk of one head --------------------------------------------------
+
+def _mm(a, b, contract_a: int, contract_b: int, precision=None):
+    return jax.lax.dot_general(
+        a, b, (((contract_a,), (contract_b,)), ((), ())),
+        preferred_element_type=F32, precision=precision)
+
+
+def _row_between(cum, half: int):
+    """For every row t of cum [L, K], the row at the end of the first half
+    of t's block of ``2 half`` rows: ``cum[(t // 2 half) 2 half + half -
+    1]``, the row that lies between the pairs of this level."""
+    length, width = cum.shape
+    block = 2 * half
+    if block >= _TILE:
+        blocks = cum.reshape(length // block, block, width)
+        pos = jax.lax.broadcasted_iota(jnp.int32, blocks.shape, 1)
+        row = jnp.where(pos == half - 1, blocks, 0.0).sum(1, keepdims=True)
+        return jnp.broadcast_to(row, blocks.shape).reshape(length, width)
+    tiles = cum.reshape(length // _TILE, _TILE, width)
+    pos = jax.lax.broadcasted_iota(jnp.int32, tiles.shape, 1)
+    wanted = (pos & ~(block - 1)) + (half - 1)
+    out = jnp.zeros_like(tiles)
+    for r in range(half - 1, _TILE, block):
+        row = jnp.where(pos == r, tiles, 0.0).sum(1, keepdims=True)
+        out = jnp.where(wanted == r, row, out)
+    return out.reshape(length, width)
+
+
+def _levels(length: int):
+    """(half, mask [L, L] of the pairs (t, s) of that level) for half = 1,
+    2, ..., L / 2: t in the second half, s in the first half of the same
+    block of ``2 half`` rows. Every pair s < t is in exactly one."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (length, length), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (length, length), 1)
+    out, half, shift = [], 1, 0
+    while half < length:
+        # Neighbouring blocks of ``half`` rows, the column's an even one.
+        row_block, col_block = rows >> shift, cols >> shift
+        out.append((half, (row_block == col_block + 1)
+                    & ((col_block & 1) == 0)))
+        half, shift = 2 * half, shift + 1
+    return rows, cols, out
+
+
+def _pair_products(q32, k32, cum, dtype, rows, cols, levels):
+    """(B [L, L] lower with its diagonal, A / beta [L, L] strictly lower) of
+    the module text: the decayed q.k and k.k products of every pair s <= t,
+    a level a product."""
+    length = q32.shape[0]
+    qk = jnp.where(rows == cols, (q32 * k32).sum(-1, keepdims=True), 0.0)
+    kk = jnp.zeros((length, length), F32)
+    for half, mask in levels:
+        # A row of a second half looks back to the row between, a row of a
+        # first half ahead to it: each by exp(-|cum - between|), one factor
+        # for both (the other half's is masked).
+        near = jnp.exp(-jnp.abs(cum - _row_between(cum, half)))
+        k_near = (k32 * near).astype(dtype)
+        qk = qk + jnp.where(
+            mask, _mm((q32 * near).astype(dtype), k_near, 1, 1), 0.0)
+        kk = kk + jnp.where(mask, _mm(k_near, k_near, 1, 1), 0.0)
+    return qk, kk
+
+
+def _two_pieces(x):
+    """x [.., ..] float32 as two bfloat16 arrays that sum to it within
+    2^-16 of its size."""
+    high = x.astype(jnp.bfloat16)
+    return high, (x - high.astype(F32)).astype(jnp.bfloat16)
+
+
+@jax.custom_vjp
+def _mm_16_bits(a, b):
+    """a [m, k] times b [k, n], float32, carried as two bfloat16 pieces
+    each: three of the four products of pieces (the low ones' product is
+    under 2^-16), float32 accumulation. Half the passes of the highest
+    precision for sixteen bits of its twenty-four; the cotangents go the
+    same way."""
+    (a_hi, a_lo), (b_hi, b_lo) = _two_pieces(a), _two_pieces(b)
+    return _mm(a_hi, b_hi, 1, 0) + (_mm(a_hi, b_lo, 1, 0)
+                                    + _mm(a_lo, b_hi, 1, 0))
+
+
+def _mm_16_bits_fwd(a, b):
+    return _mm_16_bits(a, b), (a, b)
+
+
+def _mm_16_bits_bwd(operands, ct):
+    a, b = operands
+    return _mm_16_bits(ct, b.T), _mm_16_bits(a.T, ct)
+
+
+_mm_16_bits.defvjp(_mm_16_bits_fwd, _mm_16_bits_bwd)
+
+
+def _unit_lower_inverse(lower, rows, cols, levels, exact: bool):
+    """(I + lower)^-1 for a strictly lower-triangular ``lower`` [L, L]: the
+    inverse of a block ``[[M1, 0], [M21, M2]]`` is ``[[T1, 0], [-T2 M21 T1,
+    T2]]``, for all blocks of a level at once as ``T - T M_off T``. In
+    float32 products at the highest precision if ``exact``, else at sixteen
+    bits (``_mm_16_bits``)."""
+    times = (lambda a, b: _mm(a, b, 1, 0, _HIGHEST)) if exact \
+        else _mm_16_bits
+    inverse = jnp.where(rows == cols, 1.0, 0.0).astype(F32)
+    for _, mask in levels:
+        inverse = inverse - times(
+            times(inverse, jnp.where(mask, lower, 0.0)), inverse)
+    return inverse
+
+
+def _chunk(q, k, v, cum, beta, state):
+    """One chunk of one head: q, k [L, K], v [L, V], cum [L, K] float32 (the
+    running sum of ``a`` inside the chunk), beta [L, 1] float32, state [V,
+    K] float32 (the entry state, transposed) -> (o [L, V] float32, the exit
+    state [V, K])."""
+    length = q.shape[0]
+    dtype = q.dtype
+    q32, k32, v32 = q.astype(F32), k.astype(F32), v.astype(F32)
+    rows, cols, levels = _levels(length)
+    qk, kk = _pair_products(q32, k32, cum, dtype, rows, cols, levels)
+    # Sixteen bits for the inverse where everything around it has eight.
+    inverse = _unit_lower_inverse(kk * beta, rows, cols, levels,
+                                  exact=dtype != jnp.bfloat16)
+    from_start = jnp.exp(cum)
+    state_d = state.astype(dtype)
+    rhs = beta * (v32 - _mm((k32 * from_start).astype(dtype), state_d, 1, 1))
+    u = _mm(inverse.astype(dtype), rhs.astype(dtype), 1, 0).astype(dtype)
+    out = _mm((q32 * from_start).astype(dtype), state_d, 1, 1) \
+        + _mm(qk.astype(dtype), u, 1, 0)
+    last = jax.lax.broadcasted_iota(jnp.int32, cum.shape, 0) == length - 1
+    total = jnp.where(last, cum, 0.0).sum(0, keepdims=True)       # [1, K]
+    to_end = (k32 * jnp.exp(jnp.minimum(total - cum, 0.0))).astype(dtype)
+    return out, jnp.exp(total) * state + _mm(u, to_end, 0, 0)
+
+
+# -- the chunked form in jax.numpy -----------------------------------------
+
+def chunk_sums(a, chunk: int):
+    """cum [batch, S, H, K] float32: the running sum of ``a`` inside each
+    chunk of ``chunk`` positions (S a multiple of it)."""
+    batch, seq = a.shape[:2]
+    by_chunk = a.astype(F32).reshape((batch, seq // chunk, chunk)
+                                     + a.shape[2:])
+    return jnp.cumsum(by_chunk, axis=2).reshape(a.shape)
+
+
+def decay_floor(a, chunk: int = CHUNK):
+    """The most negative ``cum`` any chunk reaches: the least of the chunks'
+    sums of ``a`` (a <= 0, so a chunk's last row holds its least). How close
+    the decay products come to underflow: float32's exp is 0 below -103."""
+    batch, seq = a.shape[:2]
+    pad = -seq % chunk
+    a = jnp.pad(a.astype(F32), ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+    return a.reshape((batch, (seq + pad) // chunk, chunk)
+                     + a.shape[2:]).sum(2).min()
+
+
+def kda_chunked(q, k, v, a, beta, chunk: int = CHUNK):
+    """The chunked algorithm outside a kernel, any length (the tail is
+    padded with steps that decay nothing and write nothing): ``_chunk``
+    under ``vmap`` over batch and heads and a ``lax.scan`` over the chunks.
+    The kernels' oracle and the path for shapes they cannot tile."""
+    if chunk < _TILE or chunk & (chunk - 1):
+        raise ValueError(f"chunk={chunk}: a power of two, {_TILE} at least")
+    batch, seq, heads, width = q.shape
+    pad = -seq % chunk
+    if pad:
+        q, k, v, a, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, a, beta))
+    n = (seq + pad) // chunk
+    cum = chunk_sums(a, chunk)
+
+    def by_chunk(x):
+        """[batch, S, H, ...] -> [chunks, batch, H, L, ...]"""
+        x = x.reshape((batch, n, chunk) + x.shape[2:])
+        return jnp.moveaxis(x, (1, 3), (0, 2))
+
+    one = jax.vmap(jax.vmap(_chunk))
+
+    def carry(state, xs):
+        out, state = one(*xs, state)
+        return state, out
+
+    _, out = jax.lax.scan(
+        carry, jnp.zeros((batch, heads, v.shape[-1], width), F32),
+        tuple(by_chunk(x) for x in (
+            q, k, v, cum, beta.astype(F32)[..., None])))
+    out = jnp.moveaxis(out, (0, 2), (1, 3)).reshape(
+        batch, seq + pad, heads, v.shape[-1])
+    return out[:, :seq].astype(v.dtype)
+
+
+# -- the kernels -------------------------------------------------------------
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, o_ref, entry_ref,
+                    state_scr):
+    """One chunk of one head: q/k/cum [L, K], v/o [L, V], beta [L, 1];
+    entry [V, K] is the head's state on entry, transposed."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_scr[...] = jnp.zeros(state_scr.shape, F32)
+
+    state = state_scr[...]
+    entry_ref[...] = state
+    out, state_scr[...] = _chunk(q_ref[...], k_ref[...], v_ref[...],
+                                 cum_ref[...], beta_ref[...], state)
+    o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, entry_ref,
+                    do_ref, dq_ref, dk_ref, dv_ref, dcum_ref, dbeta_ref,
+                    dstate_scr):
+    """The forward's grid step with the chunks in reverse (the index maps
+    turn them round): the chunk's function is differentiated where it
+    stands, from its inputs and the entry state the forward wrote, and the
+    cotangent of the head's state is carried in ``dstate_scr``."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate_scr[...] = jnp.zeros(dstate_scr.shape, F32)
+
+    _, pullback = jax.vjp(_chunk, q_ref[...], k_ref[...], v_ref[...],
+                          cum_ref[...], beta_ref[...], entry_ref[...])
+    dq, dk, dv, dcum, dbeta, dstate_scr[...] = pullback(
+        (do_ref[...].astype(F32), dstate_scr[...]))
+    dq_ref[...] = dq
+    dk_ref[...] = dk
+    dv_ref[...] = dv
+    dcum_ref[...] = dcum
+    dbeta_ref[...] = dbeta
+
+
+def _specs(chunk: int, width: int, v_width: int, n_chunks: int,
+           reverse: bool):
+    """BlockSpecs over the grid (batch, heads, chunks), by operand kind;
+    ``reverse`` walks the chunks from the last."""
+    def at(t):
+        return n_chunks - 1 - t if reverse else t
+
+    return {
+        "key": pl.BlockSpec((None, chunk, width),
+                            lambda b, h, t: (b, at(t), h)),
+        "value": pl.BlockSpec((None, chunk, v_width),
+                              lambda b, h, t: (b, at(t), h)),
+        "beta": pl.BlockSpec((None, None, chunk, 1),
+                             lambda b, h, t: (b, h, at(t), 0)),
+        "state": pl.BlockSpec((None, None, None, v_width, width),
+                              lambda b, h, t: (b, h, at(t), 0, 0)),
+    }
+
+
+def _call(kernel, name: str, reverse: bool, operands, out_kinds, out_shape,
+          chunk: int):
+    """``pl.pallas_call`` of one of the two kernels over the grid (batch,
+    heads, chunks): ``operands`` as (array, kind of ``_specs``) pairs; q
+    first, v third, beta fifth."""
+    (q, _), _, (v, _), _, (beta, _) = operands[:5]
+    batch, seq = q.shape[:2]
+    heads = beta.shape[1]
+    width, v_width = q.shape[-1] // heads, v.shape[-1] // heads
+    n = seq // chunk
+    spec = _specs(chunk, width, v_width, n, reverse)
+    return pl.pallas_call(
+        kernel,
+        grid=(batch, heads, n),
+        in_specs=[spec[kind] for _, kind in operands],
+        out_specs=[spec[kind] for kind in out_kinds],
+        out_shape=out_shape(batch, heads, n, v_width, width),
+        scratch_shapes=[pltpu.VMEM((v_width, width), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name=name,
+    )(*(x for x, _ in operands))
+
+
+_INPUTS = ("key", "key", "value", "key", "beta")
+
+
+def _forward(q, k, v, cum, beta, chunk: int):
+    """(o [batch, S, H * V], entry states [batch, H, chunks, V, K]) by the
+    forward kernel; q, k, cum are [batch, S, H * K], v [batch, S, H * V],
+    beta [batch, H, S, 1]."""
+    return _call(
+        _kda_fwd_kernel, "kda_fwd", False,
+        list(zip((q, k, v, cum, beta), _INPUTS)), ("value", "state"),
+        lambda *state: [jax.ShapeDtypeStruct(v.shape, v.dtype),
+                        jax.ShapeDtypeStruct(state, F32)], chunk)
+
+
+def _backward(q, k, v, cum, beta, entry, do, chunk: int):
+    """Cotangents (dq, dk, dv, dcum, dbeta) by the backward kernel."""
+    inputs = (q, k, v, cum, beta)
+    return _call(
+        _kda_bwd_kernel, "kda_bwd", True,
+        list(zip(inputs + (entry, do), _INPUTS + ("state", "value"))),
+        _INPUTS, lambda *_: [jax.ShapeDtypeStruct(x.shape, x.dtype)
+                             for x in inputs], chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kda_kernels(q, k, v, cum, beta, chunk: int):
+    return _forward(q, k, v, cum, beta, chunk)[0]
+
+
+def _kda_kernels_fwd(q, k, v, cum, beta, chunk):
+    out, entry = _forward(q, k, v, cum, beta, chunk)
+    return out, (q, k, v, cum, beta, entry)
+
+
+def _kda_kernels_bwd(chunk, residuals, do):
+    return _backward(*residuals, do, chunk)
+
+
+_kda_kernels.defvjp(_kda_kernels_fwd, _kda_kernels_bwd)
+
+
+def kda(q, k, v, a, beta, chunk: int = CHUNK):
+    """o [batch, S, H, V] of the recurrence at the top of this file, by
+    chunks of ``chunk`` positions. q, k [batch, S, H, K]; v [batch, S, H,
+    V]; a [batch, S, H, K] <= 0; beta [batch, S, H]. The kernels where the
+    shapes tile, else ``kda_chunked``."""
+    batch, seq, heads, width = q.shape
+    v_width = v.shape[-1]
+    if seq % chunk or width % 128 or v_width % 128:
+        return kda_chunked(q, k, v, a, beta, chunk)
+    if chunk < _TILE or chunk & (chunk - 1):
+        raise ValueError(f"chunk={chunk}: a power of two, {_TILE} at least")
+    with jax.named_scope("kda"):
+        cum = chunk_sums(a, chunk).reshape(batch, seq, heads * width)
+        out = _kda_kernels(
+            q.reshape(batch, seq, heads * width),
+            k.astype(q.dtype).reshape(batch, seq, heads * width),
+            v.reshape(batch, seq, heads * v_width), cum,
+            beta.astype(F32).swapaxes(1, 2)[..., None], chunk)
+        return out.reshape(v.shape)

@@ -200,6 +200,36 @@ def test_state_space_scan_compiles_at_the_published_widths(topo, backward):
     assert text.count("tpu_custom_call") == (2 if backward else 1)
 
 
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["kda_fwd", "kda_fwd_and_bwd"])
+def test_delta_rule_compiles_at_the_published_widths(topo, backward):
+    """Kimi-Linear-48B-A3B's KDA layer at the cell's 16k tokens: 32 heads
+    with keys and values of 128, chunks of ``kda.CHUNK`` (ops/kda.py). Blocks of rows
+    reshaped by sublane tiles, float32 products at the highest precision,
+    and a backward kernel that is the chunk's function differentiated inside
+    the kernel: what the interpreter lets through and Mosaic may not."""
+    from ray_tpu.ops import kda
+    from ray_tpu.parallel.collectives import kernel_census
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    batch, seq, heads, width = 1, 16384, 32, 128
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    wide = (batch, seq, heads, width)
+    args = (arg(wide, jnp.bfloat16),) * 3 + (
+        arg(wide, jnp.float32), arg(wide[:3], jnp.float32))
+
+    def loss(*a):
+        return kda.kda(*a).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=tuple(range(5))) if backward else kda.kda
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert kernel_census(text) == (
+        {"kda_fwd": 1, "kda_bwd": 1} if backward else {"kda_fwd": 1})
+    assert "vmem_limit_bytes" not in text
+
+
 # The forward kernel at every benchmark cell's attention: (B, S, H, D),
 # KV heads, Dv, window.
 CELL_ATTENTION = {
@@ -210,6 +240,8 @@ CELL_ATTENTION = {
     "trinity-large-preview, full layer": ((1, 16384, 48, 128), 8, 128, None),
     "trinity-large-preview, window layer": ((1, 16384, 48, 128), 8, 128,
                                             4096),
+    "kimi-linear-48b-a3b, latent layer": ((1, 16384, 32, 192), 32, 128,
+                                          None),
     # No cell's: lane-dense statistics over an output of one and a half
     # lane tiles, and at Moonlight's head sizes, which run one lane.
     "heads of 192": ((2, 4096, 8, 192), 8, 192, None),

@@ -1,0 +1,209 @@
+"""ops/kda.py: the Pallas kernel pair (interpreted) against the chunked
+``jax.numpy`` form against the literal recurrence, outputs and every
+gradient; at log-decays of -1.6 a step over several chunks, where a factor
+``exp(-cum)`` would overflow float32 inside one chunk; at a length that is
+not a multiple of the chunk; and the literal recurrence against the
+installed ``transformers``' gated delta rule where the decay is equal over a
+head's channels.
+
+Everything runs on the CPU in float32 under the highest matmul precision,
+where all three compute the same sums in another order.
+"""
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import kda
+
+ARGS = "qkvab"
+
+
+def inputs(seed, batch=1, seq=256, heads=2, width=128, strong=False,
+           v_width=None):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    shape = (batch, seq, heads, width)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], shape)) * width ** -0.5
+    k = unit(jax.random.normal(ks[1], shape))
+    v = jax.random.normal(ks[2], shape[:3] + (v_width or width,))
+    # The published initialisation's range, -0.001 to -1.6 a step ...
+    a = -jnp.exp(jax.random.uniform(ks[3], shape, minval=np.log(1e-3),
+                                    maxval=np.log(1.6)))
+    if strong:
+        # ... and half of a head's channels at its strongest throughout:
+        # -102 over a chunk of 64 beside channels that hardly decay.
+        a = a.at[:, :, 0, :width // 2].set(-1.6)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3]))
+    return q, k, v, a, beta
+
+
+def both(fn, args, seed=9):
+    """(output, gradients of a seeded linear functional of it)."""
+    with jax.default_matmul_precision("highest"):
+        out = jax.jit(fn)(*args)
+        w = jax.random.normal(jax.random.PRNGKey(seed), out.shape)
+        grads = jax.jit(jax.grad(lambda *a: (fn(*a) * w).sum(),
+                                 argnums=tuple(range(5))))(*args)
+    return out, grads
+
+
+def assert_close(got, want, tol=1e-5):
+    assert bool(jnp.isfinite(got).all())
+    norm = float(jnp.linalg.norm(want.ravel()))
+    assert norm > 0.0
+    assert float(jnp.linalg.norm((got - want).ravel())) < tol * norm
+
+
+@pytest.fixture(scope="module")
+def strong():
+    """Four chunks of 64 at the strongest decay: kernels, chunked form and
+    recurrence."""
+    args = inputs(0, strong=True)
+    return {"recurrent": both(kda.kda_recurrent, args),
+            "chunked": both(partial(kda.kda_chunked, chunk=64), args),
+            "kernels": both(partial(kda.kda, chunk=64), args)}
+
+
+@pytest.mark.parametrize("which", ["chunked", "kernels"])
+def test_outputs_match_the_recurrence_at_the_strongest_decay(strong, which):
+    assert_close(strong[which][0], strong["recurrent"][0])
+
+
+@pytest.mark.parametrize("arg", range(5), ids=list(ARGS))
+@pytest.mark.parametrize("which", ["chunked", "kernels"])
+def test_gradients_match_the_recurrence_at_the_strongest_decay(
+        strong, which, arg):
+    assert_close(strong[which][1][arg], strong["recurrent"][1][arg])
+
+
+def test_the_kernels_are_the_chunked_form(strong):
+    """One chunk function under both: what the grid carries from chunk to
+    chunk, forward and in reverse, is what the scan carries."""
+    np.testing.assert_allclose(strong["kernels"][0], strong["chunked"][0],
+                               atol=1e-6)
+    for got, want in zip(strong["kernels"][1], strong["chunked"][1]):
+        np.testing.assert_allclose(
+            got, want, atol=1e-5 * float(jnp.abs(want).max()))
+
+
+def test_the_step_runs_both_kernels():
+    from ray_tpu.parallel.collectives import kernel_census
+    args = inputs(1, seq=128)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: kda.kda(*a, chunk=64).sum(), argnums=(0, 1, 2, 3, 4)))(
+            *args)
+    assert kernel_census(jaxpr) == {"kda_fwd": 1, "kda_bwd": 1}
+
+
+@pytest.mark.parametrize("chunk", [32, 128])
+def test_other_chunks_agree(chunk):
+    args = inputs(2, seq=256)
+    want = both(kda.kda_recurrent, args)
+    got = both(partial(kda.kda, chunk=chunk), args)
+    assert_close(got[0], want[0])
+    for g, w in zip(got[1], want[1]):
+        assert_close(g, w)
+
+
+def test_a_length_that_is_no_multiple_of_the_chunk():
+    """200 positions in chunks of 64: the jax.numpy form, its tail padded
+    with steps that decay nothing and write nothing."""
+    args = inputs(3, seq=200, strong=True)
+    want = both(kda.kda_recurrent, args)
+    got = both(partial(kda.kda, chunk=64), args)
+    assert got[0].shape == want[0].shape
+    assert_close(got[0], want[0])
+    for g, w in zip(got[1], want[1]):
+        assert_close(g, w)
+
+
+def test_shapes_that_do_not_tile_take_the_chunked_form():
+    """Heads of 32: no kernel call, same numbers."""
+    args = inputs(4, seq=128, width=32)
+    jaxpr = jax.make_jaxpr(partial(kda.kda, chunk=64))(*args)
+    assert "pallas_call" not in str(jaxpr)
+    assert_close(both(partial(kda.kda, chunk=64), args)[0],
+                 both(kda.kda_recurrent, args)[0])
+
+
+def test_no_factor_overflows_where_exp_of_minus_cum_would():
+    """At -1.6 a step the running sum passes -88 inside one chunk of 64:
+    exp(-cum) is inf in float32 there, and every factor the chunk forms
+    stays finite (bfloat16 inputs, as the step runs them)."""
+    args = inputs(5, seq=128, strong=True)
+    cum = kda.chunk_sums(args[3], 64)
+    assert float(cum.min()) < -100 and bool(jnp.isinf(jnp.exp(-cum)).any())
+    assert float(kda.decay_floor(args[3], 64)) == pytest.approx(
+        float(cum.min()), rel=1e-6)
+    low = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
+    out = kda.kda(*low, chunk=64)
+    grads = jax.grad(lambda *a: kda.kda(*a, chunk=64).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))(*low)
+    assert out.dtype == jnp.bfloat16
+    assert all(bool(jnp.isfinite(x.astype(jnp.float32)).all())
+               for x in (out,) + grads)
+    # bfloat16 inputs take the inverse's products at sixteen bits: output
+    # and gradients stay where bfloat16 operands put them, 0.4 %.
+    want = kda.kda_recurrent(*low)
+    want_grads = jax.grad(lambda *a: kda.kda_recurrent(*a).sum(),
+                          argnums=(0, 1, 2, 3, 4))(*low)
+    for got, ref in zip((out,) + grads, (want,) + want_grads):
+        assert_close(got.astype(jnp.float32), ref, tol=0.02)
+
+
+def test_sixteen_bits_of_a_float32_product():
+    """``_mm_16_bits``: each operand as two bfloat16 pieces, three products:
+    2^-16 of the exact product's size, values and cotangents."""
+    a, b = (jax.random.normal(k, (64, 64)) for k in
+            jax.random.split(jax.random.PRNGKey(0)))
+    with jax.default_matmul_precision("highest"):
+        exact, pull = jax.vjp(jnp.matmul, a, b)
+        got, got_pull = jax.vjp(kda._mm_16_bits, a, b)
+        pairs = [(got, exact)] + list(zip(got_pull(exact), pull(exact)))
+    for found, want in pairs:
+        error = float(jnp.abs(found - want).max() / jnp.abs(want).max())
+        assert 1e-7 < error < 2 ** -14
+    high, low = kda._two_pieces(a)
+    assert high.dtype == low.dtype == jnp.bfloat16
+    assert float(jnp.abs(high.astype(jnp.float32) + low.astype(jnp.float32)
+                         - a).max()) < 2 ** -15 * float(jnp.abs(a).max())
+
+
+def test_a_delta_rule_not_an_additive_state():
+    """Writing the same key twice replaces what it read: with beta 1 and no
+    decay the second value comes back, not the sum."""
+    k = jnp.zeros((1, 2, 1, 128)).at[..., 0].set(1.0)
+    v = jnp.stack([jnp.full((1, 1, 128), 1.0), jnp.full((1, 1, 128), 5.0)],
+                  axis=1)
+    out = kda.kda_recurrent(k, k, v, jnp.zeros_like(k), jnp.ones((1, 2, 1)))
+    np.testing.assert_allclose(out[0, 1, 0], 5.0)
+    np.testing.assert_allclose(kda.kda_chunked(
+        k, k, v, jnp.zeros_like(k), jnp.ones((1, 2, 1)), chunk=8)[0, 1, 0],
+        5.0, atol=1e-6)
+
+
+def test_the_recurrence_is_transformers_gated_delta_rule():
+    """With the decay equal over a head's channels KDA is the gated delta
+    rule of the installed ``transformers`` (qwen3_next's torch loop)."""
+    torch = pytest.importorskip("torch")
+    try:
+        from transformers.models.qwen3_next.modeling_qwen3_next import \
+            torch_recurrent_gated_delta_rule
+    except ImportError as exc:
+        pytest.skip(f"no gated delta rule in transformers: {exc}")
+    q, k, v, a, beta = inputs(6, batch=2, seq=48, heads=3, width=32)
+    g = a[..., 0]                                   # one decay a head
+    with jax.default_matmul_precision("highest"):
+        got = kda.kda_recurrent(
+            q, k, v, jnp.broadcast_to(g[..., None], a.shape), beta)
+    as_torch = lambda x: torch.from_numpy(np.array(x, np.float32))
+    # It scales q by width^-0.5 itself: hand it q without ours.
+    want, _ = torch_recurrent_gated_delta_rule(
+        as_torch(q * 32 ** 0.5), as_torch(k), as_torch(v), as_torch(g),
+        as_torch(beta), initial_state=None, output_final_state=False,
+        use_qk_l2norm_in_kernel=False)
+    np.testing.assert_allclose(got, want.numpy(), atol=2e-6)
