@@ -817,9 +817,9 @@ def train_moe_assignments() -> Counter:
     return Counter(
         "ray_tpu_train_moe_assignments_total",
         "Token-to-expert assignments the expert layers computed: the rows "
-        "of their grouped matmuls (the sum of the group sizes of the "
-        "experts held here). Equal to ray_tpu_train_moe_tokens_total, or "
-        "an assignment was dropped.")
+        "their grouped matmuls were given (on a share of the experts, the "
+        "rows its buffers placed; ops/moe.py). Equal to "
+        "ray_tpu_train_moe_tokens_total, or an assignment was dropped.")
 
 
 def train_moe_tokens() -> Counter:
@@ -839,6 +839,24 @@ def train_moe_routed() -> Counter:
         "tokens x experts per token x expert layers, per step. "
         "ray_tpu_train_moe_tokens_total over it is this chip's share of "
         "the routing's work.")
+
+
+def train_moe_calls() -> Counter:
+    from ray_tpu.util.metrics import Counter
+    return Counter(
+        "ray_tpu_train_moe_calls_total",
+        "Expert-layer calls: expert layers x microbatches, per step.")
+
+
+def train_moe_calls_within_bound() -> Counter:
+    from ray_tpu.util.metrics import Counter
+    return Counter(
+        "ray_tpu_train_moe_calls_within_bound_total",
+        "Expert-layer calls whose assignments to the experts held here fit "
+        "one buffer of the static bound (ops/moe.py: twice the even share), "
+        "so that the layer moved that many rows once. Under "
+        "ray_tpu_train_moe_calls_total, the routing is more uneven than "
+        "the bound allows for and the other calls took further buffers.")
 
 
 def train_moe_expert_load() -> Gauge:

@@ -73,7 +73,8 @@ _PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
 
 #: Metrics of ``loss_fn`` that count a batch: summed over accumulation
 #: microbatches where the others are averaged (parallel/train_step.py).
-SUMMED_METRICS = ("moe_assignments", "moe_tokens", "moe_routed")
+SUMMED_METRICS = ("moe_assignments", "moe_tokens", "moe_routed",
+                  "moe_calls", "moe_calls_within_bound")
 
 #: Metrics of ``loss_fn`` that feed the registry, each with what records
 #: its value there (parallel/train_step.py reads them without a sync).
@@ -84,6 +85,11 @@ RECORDED_METRICS = {
         lambda value: builtin_metrics.train_moe_tokens().inc(value),
     "moe_routed":
         lambda value: builtin_metrics.train_moe_routed().inc(value),
+    "moe_calls":
+        lambda value: builtin_metrics.train_moe_calls().inc(value),
+    "moe_calls_within_bound":
+        lambda value: builtin_metrics.train_moe_calls_within_bound().inc(
+            value),
     "moe_load_max_over_mean":
         lambda value: builtin_metrics.train_moe_expert_load().set(value),
 }
@@ -343,8 +349,9 @@ def _swiglu(x, w_gate, w_up, w_down):
 def _block(cfg: AfmoeConfig, kind: str, h, layer, positions):
     """One layer of ``kind`` (``runs``). Returns (h, aux): aux is None for a
     dense layer, else the expert layer's ``picked`` [B, S, K],
-    ``group_sizes`` [held experts] and ``asked`` (assignments the router
-    gave them)."""
+    ``group_sizes`` [held experts], ``asked`` (assignments the router gave
+    them) and ``within_bound`` (1 where they fit ``ops/moe.py``'s one
+    buffer)."""
     eps = cfg.rms_norm_eps
     ffn, attention_kind = kind.split("_", 1)
     with jax.named_scope(attention_kind):
@@ -368,8 +375,10 @@ def _block(cfg: AfmoeConfig, kind: str, h, layer, positions):
                          layer["shared_w_down"])
     aux = {"picked": aux["picked"].reshape(B, S, -1),
            "group_sizes": aux["group_sizes"],
-           # With every expert held the router's assignments are all asked.
-           "asked": aux.get("asked", jnp.int32(aux["picked"].size))}
+           # With every expert held the router's assignments are all asked,
+           # and the one buffer holds them.
+           "asked": aux.get("asked", jnp.int32(aux["picked"].size)),
+           "within_bound": aux.get("within_bound", jnp.int32(1))}
     m = routed.reshape(B, S, d) + shared
     return h + _rmsnorm(m, layer["ln_post_mlp_scale"], eps), aux
 
@@ -390,7 +399,8 @@ def hidden_states(params: Dict[str, Any], cfg: AfmoeConfig,
                   positions: Optional[jax.Array] = None):
     """tokens [B, S] int32 -> (final-normed hidden [B, S, d], aux) with aux
     the expert layers' ``picked`` [L_moe, B, S, K], ``group_sizes``
-    [L_moe, held experts] and ``asked`` [L_moe], in layer order."""
+    [L_moe, held experts], ``asked`` and ``within_bound`` [L_moe], in layer
+    order."""
     _no_expert_parallelism()
     if positions is None:
         positions = lm.positions_of(tokens)
@@ -443,6 +453,9 @@ def loss_of_hidden(params: Dict[str, Any], cfg: AfmoeConfig, x: jax.Array,
         "moe_tokens": aux["asked"].astype(jnp.float32).sum(),
         "moe_routed": jnp.float32(
             targets.size * cfg.num_experts_per_tok * cfg.n_moe_layers),
+        "moe_calls": jnp.float32(cfg.n_moe_layers),
+        "moe_calls_within_bound":
+            aux["within_bound"].astype(jnp.float32).sum(),
         "moe_load_max_over_mean": (
             sizes.max(-1) / jnp.maximum(sizes.mean(-1), 1e-9)).max(),
     }

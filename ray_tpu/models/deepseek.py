@@ -285,7 +285,8 @@ def expert_ffn(x, layer, *, top_k: int, scaling: float, normalize: bool,
     ``w_down``, ``shared_*``): (this chip's part of the routed sum, the
     shared experts, aux), the sums [B, S, d], for the caller to add in that
     order. ``held`` is ``ops/moe.py``'s. aux: ``picked`` [B, S, K],
-    ``group_sizes`` [held experts] and, on a share, ``asked``."""
+    ``group_sizes`` [held experts] and, on a share, ``asked`` and
+    ``within_bound``."""
     B, S, d = x.shape
     routed, aux = routed_experts(
         x.reshape(B * S, d), layer["router"], layer["router_bias"],

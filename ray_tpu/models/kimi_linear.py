@@ -76,7 +76,8 @@ from ray_tpu.parallel.sharding import ShardingRules, constrain
 
 #: Metrics of ``loss_fn`` that count a batch: summed over accumulation
 #: microbatches where the others are averaged (parallel/train_step.py).
-SUMMED_METRICS = ("moe_assignments", "moe_tokens", "moe_routed")
+SUMMED_METRICS = ("moe_assignments", "moe_tokens", "moe_routed",
+                  "moe_calls", "moe_calls_within_bound")
 
 #: Metrics of ``loss_fn`` that feed the registry, each with what records
 #: its value there (parallel/train_step.py reads them without a sync).
@@ -87,6 +88,11 @@ RECORDED_METRICS = {
         lambda value: builtin_metrics.train_moe_tokens().inc(value),
     "moe_routed":
         lambda value: builtin_metrics.train_moe_routed().inc(value),
+    "moe_calls":
+        lambda value: builtin_metrics.train_moe_calls().inc(value),
+    "moe_calls_within_bound":
+        lambda value: builtin_metrics.train_moe_calls_within_bound().inc(
+            value),
     "moe_load_max_over_mean":
         lambda value: builtin_metrics.train_moe_expert_load().set(value),
     "kda_decay_floor":
@@ -402,8 +408,9 @@ def _kda(cfg: KimiLinearConfig, x, layer):
 def _block(cfg: KimiLinearConfig, kind: str, h, layer, positions):
     """One layer of ``kind`` (``runs``). Returns (h, aux): ``decay_floor``
     (0 for a latent layer) and, of an expert layer, ``picked`` [B, S, K],
-    ``group_sizes`` [held experts] and ``asked`` (assignments the router
-    gave them)."""
+    ``group_sizes`` [held experts], ``asked`` (assignments the router gave
+    them) and ``within_bound`` (1 where they fit ``ops/moe.py``'s one
+    buffer)."""
     eps = cfg.rms_norm_eps
     ffn, mixer = kind.split("_")
     x = deepseek.rmsnorm(h, layer["ln_in_scale"], eps)
@@ -426,8 +433,9 @@ def _block(cfg: KimiLinearConfig, kind: str, h, layer, positions):
         held=cfg.experts_held)
     aux.update(picked=moe["picked"], group_sizes=moe["group_sizes"],
                # With every expert held the router's assignments are all
-               # asked.
-               asked=moe.get("asked", jnp.int32(moe["picked"].size)))
+               # asked, and the one buffer holds them.
+               asked=moe.get("asked", jnp.int32(moe["picked"].size)),
+               within_bound=moe.get("within_bound", jnp.int32(1)))
     return h + routed + shared, aux
 
 
@@ -447,8 +455,8 @@ def hidden_states(params: Dict[str, Any], cfg: KimiLinearConfig,
                   positions: Optional[jax.Array] = None):
     """tokens [B, S] int32 -> (final-normed hidden [B, S, d], aux) with aux
     ``decay_floor`` [L] and the expert layers' ``picked`` [L_moe, B, S, K],
-    ``group_sizes`` [L_moe, held experts] and ``asked`` [L_moe], in layer
-    order. No layer reads ``positions`` under ``mla_use_nope``: the delta
+    ``group_sizes`` [L_moe, held experts], ``asked`` and ``within_bound``
+    [L_moe], in layer order. No layer reads ``positions`` under ``mla_use_nope``: the delta
     rule's layers carry the order."""
     _no_expert_parallelism()
     if positions is None:
@@ -500,6 +508,9 @@ def loss_of_hidden(params: Dict[str, Any], cfg: KimiLinearConfig,
         "moe_tokens": aux["asked"].astype(jnp.float32).sum(),
         "moe_routed": jnp.float32(
             targets.size * cfg.num_experts_per_token * cfg.n_moe_layers),
+        "moe_calls": jnp.float32(cfg.n_moe_layers),
+        "moe_calls_within_bound":
+            aux["within_bound"].astype(jnp.float32).sum(),
         "moe_load_max_over_mean": (
             sizes.max(-1) / jnp.maximum(sizes.mean(-1), 1e-9)).max(),
     }

@@ -34,14 +34,40 @@ experts would have added is left out, and nothing stands in for the other
 chips or for an exchange with them: the share is not expert parallelism,
 which a mesh with ``ep`` > 1 would ask for and no model here implements.
 Dropless on the share: every assignment to a held expert is computed, and
-``aux["group_sizes"]`` counts them. The rows are still sorted, gathered
-and combined at ``tokens x top_k``, the only size that holds whatever the
-router decides.
+``aux["group_sizes"]`` counts the rows that were placed and multiplied.
+
+**The share moves only its own rows.** The sort's key is the expert if it is
+held and one past the last held expert if not, so the assignments to held
+experts are the first rows of the order, and a buffer is a slice of it.
+The buffer has ``_held_bound`` rows: twice what even routing would give the
+held experts, ``2 * tokens * top_k * count / experts`` rounded up to the row
+tile, computed from what ``routed_experts`` sees and set nowhere. The
+gather, the three grouped matmuls (the rows of the buffer past the held
+groups are one more group that no weights multiply: exact zeros), the
+SwiGLU and the way back run over the buffer, not over ``tokens x top_k``.
+The first buffer is computed on every routing; one that gives the held
+experts more rows than it has takes the next rows in a second buffer, and
+so on, ``ceil(asked / bound)`` in all: a loop that does not run on a
+routing within the bound, and nothing is cut on any routing. The way back
+to tokens is a gather from the buffer, a slab of ``tokens`` rows a choice,
+summed in float32 in the order ``_combine`` sums; an assignment that is not
+in the buffer adds an exact zero. (Measured on the v5e on the layer alone,
+forward and backward, against a second sort of the buffer's rows by token
+with a within-run sum and one ``[tokens, d]`` gather: 38.4 against 44.4 ms
+at 16,384 x 8 rows of 2304 with 32 of 256 held, 17.5 against 19.5 at x 4
+rows of 3072 with 8 held, where all ``tokens x top_k`` rows took 51.1 and
+36.1: PERF.md, PR 36.) Forward and backward are each those buffers
+(``_held_experts`` is a ``custom_vjp``: autodiff sees neither the loop nor
+its trip count, and the backward pass multiplies a buffer's rows again
+where storing them would keep every buffer's residuals alive: under a
+block's ``remat`` that is the second forward the block would run anyway),
+and no pass scatters.
 """
 
 from __future__ import annotations
 
 import importlib
+from functools import partial
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -58,31 +84,27 @@ _flash = importlib.import_module("ray_tpu.ops.flash_attention")
 _TILE_M, _TILE_K, _TILE_N_MAX = 512, 512, 1408
 
 
-def grouped_matmul(rows, weights, group_sizes, first=None):
+def grouped_matmul(rows, weights, group_sizes):
     """rows [M, k], sorted into ``len(group_sizes)`` contiguous groups, times
-    each group's own weights [E, k, n] -> [M, n] in rows' dtype. With
-    ``first``, ``weights`` are those of the groups ``first`` to ``first +
-    len(weights)`` alone: only their rows are multiplied, the others come
-    out zero."""
+    each group's own weights [E, k, n] -> [M, n] in rows' dtype. Where there
+    are more groups than weights, the weights are the first groups': only
+    their rows are multiplied, the others come out zero."""
     (m, k), n = rows.shape, weights.shape[-1]
+    given = weights.shape[0]
     if m % 128 or k % 128 or n % 128:
-        if first is not None:
-            # Three stretches of rows: before, held, after; the outer two
-            # against zero weights.
-            last = first + weights.shape[0]
-            nothing = jnp.zeros((1, k, n), weights.dtype)
-            weights = jnp.concatenate([nothing, weights, nothing])
-            group_sizes = jnp.concatenate([
-                group_sizes[:first].sum()[None], group_sizes[first:last],
-                group_sizes[last:].sum()[None]])
+        if group_sizes.shape[0] > given:
+            # One stretch of rows after the last weights, against zeros.
+            weights = jnp.concatenate(
+                [weights, jnp.zeros((1, k, n), weights.dtype)])
+            group_sizes = jnp.concatenate(
+                [group_sizes[:given], group_sizes[given:].sum()[None]])
         return jax.lax.ragged_dot(rows, weights, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
     tile_m = next(t for t in (_TILE_M, 256, 128) if m % t == 0)
     tile_n = next(t for t in range(min(n, _TILE_N_MAX), 0, -128)
                   if n % t == 0)
-    offset = None if first is None else jnp.asarray(first, jnp.int32)
     return megablox.gmm(rows, weights, group_sizes, rows.dtype,
-                        (tile_m, min(k, _TILE_K), tile_n), offset,
+                        (tile_m, min(k, _TILE_K), tile_n),
                         interpret=_flash._interpret())
 
 
@@ -180,6 +202,167 @@ def _combine_bwd(residuals, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _swiglu_groups(rows, w_gate, w_up, w_down, group_sizes):
+    """Every expert's SwiGLU on its own group of the rows; the rows of groups
+    past the last weights come out exact zeros."""
+    gate = grouped_matmul(rows, w_gate, group_sizes)
+    up = grouped_matmul(rows, w_up, group_sizes)
+    return grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes)
+
+
+# -- a share of the experts: the held rows alone --------------------------
+
+
+def _held_bound(tokens: int, top_k: int, count: int, n_experts: int) -> int:
+    """Rows of the buffer the assignments to ``count`` held experts of
+    ``n_experts`` are compacted into: twice their even share, in whole row
+    tiles, and no more than all the assignments."""
+    def tiles(rows):
+        return -(-rows // _TILE_M) * _TILE_M
+    return min(tiles(-(-2 * tokens * top_k * count // n_experts)),
+               tiles(tokens * top_k))
+
+
+def _buffers_needed(asked, bound: int):
+    """Buffers of ``bound`` rows that hold ``asked`` rows: where both of
+    ``_held_experts``' loops end."""
+    return (asked + bound - 1) // bound
+
+
+def _buffer(i, bound: int, order, sizes):
+    """Buffer ``i`` of the held rows: (the assignments of its ``bound``
+    rows, in expert order; its groups [count + 1]: the held experts' rows
+    that lie in it, then the rows past them, which no expert is given)."""
+    lo = i * bound
+    ends = jnp.cumsum(sizes)
+    here = (jnp.clip(ends, lo, lo + bound)
+            - jnp.clip(ends - sizes, lo, lo + bound))
+    return jax.lax.dynamic_slice(order, (lo,), (bound,)), jnp.concatenate(
+        [here, (bound - here.sum())[None]])
+
+
+def _rows(table, at):
+    """table[at] by rows, every index promised in bounds (a gather with no
+    select over its result)."""
+    return table.at[at].get(mode="promise_in_bounds")
+
+
+def _rows_or_zero(table, at):
+    """table[at] by rows, and an exact zero where ``at`` is the table's
+    length: no row, whatever row the gather read in its place."""
+    length = table.shape[0]
+    there = (at < length).reshape(at.shape + (1,) * (table.ndim - 1))
+    return jnp.where(there, _rows(table, jnp.minimum(at, length - 1)), 0)
+
+
+def _at(place, i, bound: int, groups):
+    """Where in buffer ``i`` each of the K * T assignments sits, and ``bound``
+    for one that is not among its held rows."""
+    at = place - i * bound
+    return jnp.where((at >= 0) & (at < groups[:-1].sum()), at, bound)
+
+
+def _to_tokens(rows, at, tokens: int, weights=None):
+    """sum_k [weights[k, t] *] rows[at[k * T + t]] in float32 -> [T, d]: a
+    buffer's rows [bound, d] summed into their tokens by gathers alone, a
+    slab of T rows a choice and in the order ``_combine`` sums them; an
+    assignment that is not in the buffer adds an exact zero."""
+    slabs = at.reshape(-1, tokens)
+    return sum(
+        _rows_or_zero(rows, slabs[k]).astype(jnp.float32)
+        * (1.0 if weights is None else weights[k][:, None])
+        for k in range(slabs.shape[0]))
+
+
+def _over_buffers(one, needed):
+    """one(0) + one(1) + ... + one(needed - 1), leaf by leaf. The first
+    buffer is computed on every routing, and on one within the bound it is
+    all there is and nothing is added to it; the others are a loop autodiff
+    never sees. ``one`` is a jitted function of the buffer's number, so the
+    two places that call it trace and lower it once."""
+    return jax.lax.fori_loop(
+        1, needed,
+        lambda i, total: jax.tree.map(jnp.add, total, one(i)),
+        one(jnp.int32(0)))
+
+
+@partial(jax.jit, static_argnums=(0,))
+def _buffer_forward(bound, i, x, weights, w_gate, w_up, w_down, order, place,
+                    sizes):
+    """Buffer ``i``'s part of ``_held_experts``' sum, in float32, and the
+    rows it gave each held expert."""
+    tokens = x.shape[0]
+    rows_of, groups = _buffer(i, bound, order, sizes)
+    with jax.named_scope("moe_dispatch"):
+        rows = _rows(x, rows_of % tokens)
+    with jax.named_scope("moe_experts"):
+        out = _swiglu_groups(rows, w_gate, w_up, w_down, groups)
+    with jax.named_scope("moe_combine"):
+        return _to_tokens(out, _at(place, i, bound, groups), tokens,
+                          weights), groups[:-1]
+
+
+@partial(jax.jit, static_argnums=(0,))
+def _buffer_backward(bound, i, g, x, weights, w_gate, w_up, w_down, order,
+                     place, sizes):
+    """(d x in float32, d weights, [d w_gate, d w_up, d w_down]) of the rows
+    of buffer ``i``, which it multiplies again, from g = d y."""
+    tokens = x.shape[0]
+    rows_of, groups = _buffer(i, bound, order, sizes)
+    at = _at(place, i, bound, groups)
+    with jax.named_scope("moe_dispatch"):
+        token_of = rows_of % tokens
+        rows = _rows(x, token_of)
+    with jax.named_scope("moe_combine"):
+        g_rows = _rows(g, token_of).astype(jnp.float32)
+        w_rows = _rows(weights.reshape(-1), rows_of)
+        d_out = (g_rows * w_rows[:, None]).astype(x.dtype)
+    with jax.named_scope("moe_experts"):
+        out, experts_vjp = jax.vjp(
+            partial(_swiglu_groups, group_sizes=groups),
+            rows, w_gate, w_up, w_down)
+        d_rows, *d_experts = experts_vjp(d_out)
+    with jax.named_scope("moe_combine"):
+        d_w_rows = (out.astype(jnp.float32) * g_rows).sum(-1)
+        d_weights = _rows_or_zero(d_w_rows, at).reshape(weights.shape)
+    with jax.named_scope("moe_dispatch"):
+        return _to_tokens(d_rows, at, tokens), d_weights, d_experts
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(bound, x, weights, w_gate, w_up, w_down, order, place,
+                  sizes):
+    """sum_{k: picked and held} weights[k, t] * Expert(x[t]) -> ([T, d] in
+    x's dtype, the rows each held expert was given [count]).
+
+    x [T, d]; weights [K, T] float32; the held experts' w_gate, w_up
+    [count, d, f], w_down [count, f, d] in x's dtype; order [K * T, padded
+    to whole buffers], the assignments sorted by held expert, the others
+    after them; place [K * T], its inverse; sizes [count], the router's
+    histogram over the held experts. One buffer of ``bound`` rows at a time
+    (the module text)."""
+    y, placed = _over_buffers(
+        lambda i: _buffer_forward(bound, i, x, weights, w_gate, w_up, w_down,
+                                  order, place, sizes),
+        _buffers_needed(sizes.sum(), bound))
+    return y.astype(x.dtype), placed
+
+
+def _held_experts_fwd(bound, *args):
+    return _held_experts(bound, *args), args
+
+
+def _held_experts_bwd(bound, residuals, cotangents):
+    x, *_, sizes = residuals
+    d_x, d_weights, d_experts = _over_buffers(
+        lambda i: _buffer_backward(bound, i, cotangents[0], *residuals),
+        _buffers_needed(sizes.sum(), bound))
+    return (d_x.astype(x.dtype), d_weights, *d_experts, None, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
 def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
                    scaling: float, normalize: bool = True,
                    held: Optional[Tuple[int, int]] = None
@@ -195,11 +378,15 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
 
     ``held = (first, count)``: the weights are those of experts ``first`` to
     ``first + count`` alone, [count, ...], of the router's E (the module
-    text). The sum is then over the picked experts that are held,
-    ``aux["group_sizes"]`` is [count], the held experts', and
-    ``aux["asked"]`` counts the assignments the router gave them (equal to
-    their sum, or something was dropped). None, or all E held, is the whole
-    layer."""
+    text). The sum is then over the picked experts that are held, computed
+    over buffers of ``_held_bound(T, K, count, E)`` rows: twice the even
+    share, one buffer on a routing within it and as many as the routing
+    needs beyond. ``aux["group_sizes"]`` is [count], the rows of each held
+    expert that the buffers placed and the grouped matmuls were given;
+    ``aux["asked"]`` counts the assignments the router gave the held experts
+    (equal to that sum, or something was cut), and ``aux["within_bound"]``
+    is 1 where one buffer held them all, else 0. None, or all E held, is the
+    whole layer, at ``tokens x top_k`` rows."""
     tokens, n_experts = x.shape[0], router.shape[-1]
     dt = x.dtype
     first = None
@@ -213,6 +400,9 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
             f"held={held}")
     with jax.named_scope("moe_route"):
         picked, weights = route(x, router, bias, top_k, scaling, normalize)
+    if first is not None:
+        return _share(x, picked, weights, w_gate.astype(dt), w_up.astype(dt),
+                      w_down.astype(dt), first, n_experts)
     with jax.named_scope("moe_dispatch"):
         expert_of = picked.T.reshape(-1)  # assignment a = k * T + t
         order = jnp.argsort(expert_of, stable=True).astype(jnp.int32)
@@ -221,14 +411,35 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
         group_sizes = jnp.zeros((n_experts,), jnp.int32).at[expert_of].add(1)
         rows = _dispatch(x, order, inverse)  # [K*T, d], grouped by expert
     with jax.named_scope("moe_experts"):
-        gate = grouped_matmul(rows, w_gate.astype(dt), group_sizes, first)
-        up = grouped_matmul(rows, w_up.astype(dt), group_sizes, first)
-        out = grouped_matmul(jax.nn.silu(gate) * up, w_down.astype(dt),
-                             group_sizes, first)
+        out = _swiglu_groups(rows, w_gate.astype(dt), w_up.astype(dt),
+                             w_down.astype(dt), group_sizes)
     with jax.named_scope("moe_combine"):
         y = _combine(_unsort(out, order, inverse), weights.T)
-    if first is None:
-        return y, {"picked": picked, "group_sizes": group_sizes}
-    asked = ((picked >= first) & (picked < first + count)).sum()
-    return y, {"picked": picked, "asked": asked,
-               "group_sizes": group_sizes[first:first + count]}
+    return y, {"picked": picked, "group_sizes": group_sizes}
+
+
+def _share(x, picked, weights, w_gate, w_up, w_down, first: int,
+           n_experts: int):
+    """``routed_experts`` on the experts ``first`` to ``first + count``, the
+    weights given: the sort that puts their assignments first, then
+    ``_held_experts``."""
+    (tokens, top_k), count = picked.shape, w_gate.shape[0]
+    bound = _held_bound(tokens, top_k, count, n_experts)
+    with jax.named_scope("moe_dispatch"):
+        expert_of = picked.T.reshape(-1)  # assignment a = k * T + t
+        is_held = (expert_of >= first) & (expert_of < first + count)
+        key = jnp.where(is_held, expert_of - first, count)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        # Sorts and searches, where the whole layer scatters: a TPU
+        # serialises a scatter, and this runs twice a step under remat.
+        place = jnp.argsort(order).astype(jnp.int32)
+        starts = jnp.searchsorted(key[order], jnp.arange(count + 1),
+                                  side="left").astype(jnp.int32)
+        sizes = starts[1:] - starts[:-1]
+        # Whole buffers: a slice of the order never runs off its end.
+        order = jnp.pad(order, (0, -order.shape[0] % bound))
+    y, placed = _held_experts(bound, x, weights.T, w_gate, w_up, w_down,
+                              order, place, sizes)
+    asked = is_held.sum()
+    return y, {"picked": picked, "asked": asked, "group_sizes": placed,
+               "within_bound": (asked <= bound).astype(jnp.int32)}

@@ -402,6 +402,34 @@ def test_trains_and_feeds_the_shares_counters(accum_steps):
     assert 0.2 < asked / all_routed < 0.6
 
 
+@pytest.mark.parametrize("accum_steps", [1, 2])
+def test_a_step_feeds_the_calls_and_those_within_the_bound(accum_steps):
+    """An expert-layer call a layer and microbatch, and each within the
+    bound: 3 of 8 experts held get about three eighths of the assignments,
+    and the buffer is all of them (twice the even share is three quarters,
+    a whole row tile is more than all)."""
+    import optax
+    from ray_tpu.parallel.sharding import ShardingRules
+    names = ("ray_tpu_train_moe_calls_total",
+             "ray_tpu_train_moe_calls_within_bound_total")
+    mesh = _one_chip()
+    rules, optimizer = ShardingRules(), optax.adam(3e-3)
+    state = init_train_state(FLASH, mesh, rules, optimizer, seed=0)
+    step = make_train_step(FLASH, mesh, rules, optimizer,
+                           accum_steps=accum_steps)
+    tokens, targets = batch(FLASH, rows=2, seq=FLASH_SEQ)
+    calls = FLASH.n_moe_layers * accum_steps
+    before = [_counter(name) for name in names]
+    for _ in range(3):
+        state, metrics = step(state, {"tokens": tokens, "targets": targets})
+        assert float(metrics["moe_calls"]) == calls \
+            == float(metrics["moe_calls_within_bound"])
+    in_all, within = (
+        _counter(name) - was for name, was in zip(names, before))
+    # Fed one call late at most: after three blocking steps, two or three.
+    assert in_all == within and in_all in (2 * calls, 3 * calls)
+
+
 def test_with_every_expert_held_all_that_is_routed_is_asked():
     tokens, targets = batch(CFG)
     _, metrics = afmoe.loss_fn(drawn(CFG), CFG, tokens, targets)
