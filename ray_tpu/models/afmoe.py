@@ -296,23 +296,9 @@ def param_specs(cfg: AfmoeConfig, rules: ShardingRules) -> Dict[str, Any]:
 
 # -- forward ------------------------------------------------------------
 
-def _rmsnorm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt((x32 ** 2).mean(-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, positions, theta: float):
-    """Rotary embedding over the whole last axis of x [B, S, H, D], pairing
-    dimension i with i + D / 2 (angle pos * theta^(-2i/D)), as published."""
-    half = x.shape[-1] // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
-    cos, sin = jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
-    x32 = x.astype(jnp.float32)
-    first, second = x32[..., :half], x32[..., half:]
-    return jnp.concatenate([first * cos - second * sin,
-                            second * cos + first * sin], -1).astype(x.dtype)
+# Shared with ``models/lfm2.py``, so they live in ``models/lm.py``; under
+# these names the module's own functions call them.
+_rmsnorm, _rope = lm.rmsnorm, lm.rope
 
 
 def _attention(cfg: AfmoeConfig, sliding: bool, x, layer, positions):
@@ -338,12 +324,7 @@ def _attention(cfg: AfmoeConfig, sliding: bool, x, layer, positions):
     return jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(dt))
 
 
-def _swiglu(x, w_gate, w_up, w_down):
-    dt = x.dtype
-    gate = jnp.einsum("...d,df->...f", x, w_gate.astype(dt))
-    up = jnp.einsum("...d,df->...f", x, w_up.astype(dt))
-    return jnp.einsum("...f,fd->...d", jax.nn.silu(gate) * up,
-                      w_down.astype(dt))
+_swiglu = lm.swiglu
 
 
 def _block(cfg: AfmoeConfig, kind: str, h, layer, positions):

@@ -3,8 +3,10 @@ owns: the embedding lookup, the layer scan with rematerialisation, the
 attention dispatch (which attention runs, and how it is laid over a mesh),
 the state-space scan's, a block's tp traffic as exchanges of slices of S,
 and the chunked head and loss. ``models/gpt.py``,
-``models/deepseek.py``, ``models/granite.py`` and ``models/afmoe.py`` are
-built from these; a new family brings its config, parameters, block and head
+``models/deepseek.py``, ``models/granite.py``, ``models/afmoe.py``,
+``models/kimi_linear.py`` and ``models/lfm2.py`` are built from these, and
+from the three pieces two families share as they stand (``rmsnorm``,
+``rope``, ``swiglu``); a new family brings its config, parameters, block and head
 and is written against this module, not against another model.
 
 Where S lives on a mesh. Everything a whole model carries along S (the
@@ -527,8 +529,9 @@ def exchanged_over_tp(block, mesh, layers, layer_specs):
 
 # -- state-space scan -----------------------------------------------------
 
-def _over_batch_shards(fn, args, has_rows):
-    """``fn(*args)`` -> [B, S, H, P], per shard of the batch under a mesh
+def _over_batch_shards(fn, args, has_rows, out_rank: int = 4):
+    """``fn(*args)`` -> [B, S, ...] of ``out_rank`` axes ([B, S, H, P] if
+    not given), per shard of the batch under a mesh
     (GSPMD cannot partition a Mosaic kernel): a recurrence along S is
     independent per batch row, so each shard scans its own rows whole, with
     every head. ``has_rows`` says which arguments lead with the batch axis;
@@ -544,7 +547,7 @@ def _over_batch_shards(fn, args, has_rows):
         fn, mesh=mesh,
         in_specs=tuple(rows(a.ndim) if own else PartitionSpec(None)
                        for a, own in zip(args, has_rows)),
-        out_specs=rows(4), check_vma=False)(*args)
+        out_specs=rows(out_rank), check_vma=False)(*args)
 
 
 def state_space(u, dt, A, B, C, D, chunk: int):
@@ -562,14 +565,24 @@ def state_space(u, dt, A, B, C, D, chunk: int):
 def causal_conv(x, w, b=None):
     """Depthwise causal convolution along S of x [B, S, C] with taps w [K,
     C] and, if given, bias b [C], in float32: y_t = b + sum_k w_k x_(t - K +
-    1 + k), zeros before the first token. The short convolution of a
-    state-space layer (``models/granite.py``) and of a delta-rule layer's q,
-    k and v (``models/kimi_linear.py``)."""
-    taps, seq = w.shape[0], x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    w = w.astype(jnp.float32)
-    bias = 0.0 if b is None else b.astype(jnp.float32)
-    return bias + sum(w[k] * padded[:, k:k + seq] for k in range(taps))
+    1 + k), zeros before the first token (``ops/short_conv.py``'s
+    ``causal_conv``). Its three users: the short convolution of a
+    state-space layer (``models/granite.py``), those of a delta-rule
+    layer's q, k and v (``models/kimi_linear.py``), and, there, the
+    ``jax.numpy`` form of the gated short convolution that is a layer's
+    mixer (``short_conv``; ``models/lfm2.py``)."""
+    from ray_tpu.ops.short_conv import causal_conv as op
+    return op(x, w, b)
+
+
+def short_conv(bcx, w):
+    """The double-gated short convolution ``C * conv(B * x)`` by
+    ``ops/short_conv.py`` (the fused Pallas pass each way where the shapes
+    tile, else its ``jax.numpy`` form) over ``bcx`` [B, S, 3 d] (the chunks
+    B, C, x of one projection) with taps ``w`` [K, d] -> [B, S, d]. Under a
+    mesh the kernels run per shard of the batch, as ``state_space``'s do."""
+    from ray_tpu.ops.short_conv import short_conv as op
+    return _over_batch_shards(op, (bcx, w), (True, False), out_rank=3)
 
 
 def delta_rule(q, k, v, a, beta):
@@ -580,6 +593,38 @@ def delta_rule(q, k, v, a, beta):
     run per shard of the batch, as ``state_space``'s do."""
     from ray_tpu.ops.kda import kda
     return _over_batch_shards(kda, (q, k, v, a, beta), (True,) * 5)
+
+
+# -- pieces two families share as they stand ------------------------------
+
+def rmsnorm(x, scale, eps):
+    """RMSNorm over the last axis, statistics in float32, in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt((x32 ** 2).mean(-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope(x, positions, theta: float):
+    """Rotary embedding over the whole last axis of x [B, S, H, D], pairing
+    dimension i with i + D / 2 (angle pos * theta^(-2i/D)), as published
+    for ``models/afmoe.py`` and ``models/lfm2.py``."""
+    half = x.shape[-1] // 2
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
+    cos, sin = jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
+    x32 = x.astype(jnp.float32)
+    first, second = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([first * cos - second * sin,
+                            second * cos + first * sin], -1).astype(x.dtype)
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """``(silu(x w_gate) * x w_up) w_down`` in x's dtype."""
+    dt = x.dtype
+    gate = jnp.einsum("...d,df->...f", x, w_gate.astype(dt))
+    up = jnp.einsum("...d,df->...f", x, w_up.astype(dt))
+    return jnp.einsum("...f,fd->...d", jax.nn.silu(gate) * up,
+                      w_down.astype(dt))
 
 
 # -- head and loss --------------------------------------------------------

@@ -230,6 +230,52 @@ def test_delta_rule_compiles_at_the_published_widths(topo, backward):
     assert "vmem_limit_bytes" not in text
 
 
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["short_conv_fwd", "short_conv_fwd_and_bwd"])
+def test_gated_short_convolution_compiles_at_the_published_widths(
+        topo, backward):
+    """LFM2-24B-A2B's convolution layer at the cell's 4 x 8192 tokens: the
+    projection's [4, 8192, 6144] read in place, 2048 channels, 3 taps
+    (ops/short_conv.py). Rolls along sublanes, pieces that meet on a
+    sublane tile's edge, a second small block of the same array, and whole
+    rows double-buffered under a VMEM limit of its own: what the
+    interpreter lets through and Mosaic may not."""
+    from ray_tpu.ops import short_conv
+    from ray_tpu.parallel.collectives import kernel_census
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    bcx = jax.ShapeDtypeStruct((4, 8192, 6144), jnp.bfloat16,
+                               sharding=one_chip)
+    w = jax.ShapeDtypeStruct((3, 2048), jnp.bfloat16, sharding=one_chip)
+
+    def loss(bcx, w):
+        # The square keeps the forward alive beside the backward.
+        return (short_conv.short_conv(bcx, w).astype(jnp.float32) ** 2).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1)) if backward \
+        else short_conv.short_conv
+    text = jax.jit(fn).lower(bcx, w).compile().as_text()
+    assert kernel_census(text) == (
+        {"short_conv_fwd": 1, "short_conv_bwd": 1} if backward
+        else {"short_conv_fwd": 1})
+
+
+def test_flash_compiles_at_4_x_8k_with_grouped_kv_heads(topo):
+    """LFM2-24B-A2B's attention layer: 32 query heads over 8 KV heads of 64
+    at 4 sequences of 8192, forward and both backward kernels."""
+    from ray_tpu.parallel.collectives import kernel_census
+    q, k, v = _qkv(topo, (4, 8192, 32, 64))
+    k = v = jax.ShapeDtypeStruct((4, 8192, 8, 64), jnp.bfloat16,
+                                 sharding=k.sharding)
+
+    def loss(q, k, v):
+        return (_attend(q, k, v).astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, v).compile().as_text()
+    assert kernel_census(text) == {
+        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+
+
 # The forward kernel at every benchmark cell's attention: (B, S, H, D),
 # KV heads, Dv, window.
 CELL_ATTENTION = {
@@ -242,6 +288,7 @@ CELL_ATTENTION = {
                                             4096),
     "kimi-linear-48b-a3b, latent layer": ((1, 16384, 32, 192), 32, 128,
                                           None),
+    "lfm2-24b-a2b": ((4, 8192, 32, 64), 8, 64, None),
     # No cell's: lane-dense statistics over an output of one and a half
     # lane tiles, and at Moonlight's head sizes, which run one lane.
     "heads of 192": ((2, 4096, 8, 192), 8, 192, None),
