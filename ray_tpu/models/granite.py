@@ -28,6 +28,10 @@ Per layer, as ``GraniteMoeHybridForCausalLM`` computes it (``transformers``
     logits   = RMSNorm(h_last; g_f) wte^T / logits_scaling               tied
 
 The recurrence is ``ops/ssd.py``'s chunked scan (through ``lm.state_space``),
+the convolution with its bias and SiLU ``lm.conv_silu`` over xBC's columns of
+the in-projection's output where they lie (``ops/short_conv.py``'s fused pass
+each way where the shapes tile, else its ``jax.numpy`` form in
+float32: no split copy, no float32 padded copy),
 attention the flash kernels or ``dot`` through ``lm.attention`` with the
 model's score scale, the two kinds of layer one ``lm.scan_blocks`` over
 ``layer_types``, the head and loss ``lm.next_token_loss`` on the tied table.
@@ -248,10 +252,10 @@ def _mamba(cfg: GraniteConfig, x, layer):
     dt_, f32 = cfg.dtype, jnp.float32
     di, n = cfg.mamba_d_inner, cfg.mamba_d_state
     proj = jnp.einsum("bsd,de->bse", x, layer["w_in"].astype(dt_))
-    z, xbc, dt = jnp.split(proj, [di, di + cfg.conv_dim], axis=-1)
+    z, _, dt = jnp.split(proj, [di, di + cfg.conv_dim], axis=-1)
     with jax.named_scope("conv"):
-        xbc = jax.nn.silu(lm.causal_conv(
-            xbc, layer["conv_w"], layer["conv_b"])).astype(dt_)
+        xbc = lm.conv_silu(proj, layer["conv_w"], layer["conv_b"],
+                           start=di, width=cfg.conv_dim)
     u, B, C = jnp.split(xbc, [di, di + n], axis=-1)
     dt = jax.nn.softplus(dt.astype(f32) + layer["dt_bias"].astype(f32))
     y = lm.state_space(

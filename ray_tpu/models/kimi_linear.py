@@ -43,7 +43,9 @@ trained by the gradient, and no rule moves it here. The loss is the
 cross-entropy alone.
 
 The recurrence is ``ops/kda.py``'s chunked kernel pair (through
-``lm.delta_rule``), the short convolutions ``lm.causal_conv``, the latent
+``lm.delta_rule``), the short convolutions with their SiLU ``lm.conv_silu``
+(``ops/short_conv.py``'s fused pass each way over each of q, k, v where the
+shapes tile, else its ``jax.numpy`` form in float32), the latent
 attention and the expert FFN ``models/deepseek.py``'s own functions
 (``mla``, ``expert_ffn``: shared code, not a copy), the expert layer
 ``ops/moe.py``. A layer's kind is its FFN and its mixer together
@@ -379,8 +381,7 @@ def _kda(cfg: KimiLinearConfig, x, layer):
     def short_conv(name):
         flat = jnp.einsum("bsd,dhk->bshk", x, layer["w" + name].astype(dt)
                           ).reshape(x.shape[:2] + (heads * hd,))
-        return jax.nn.silu(lm.causal_conv(
-            flat, layer["conv_" + name])).astype(dt).reshape(split)
+        return lm.conv_silu(flat, layer["conv_" + name]).reshape(split)
 
     with jax.named_scope("conv"):
         q, k, v = short_conv("q"), short_conv("k"), short_conv("v")

@@ -566,13 +566,31 @@ def causal_conv(x, w, b=None):
     """Depthwise causal convolution along S of x [B, S, C] with taps w [K,
     C] and, if given, bias b [C], in float32: y_t = b + sum_k w_k x_(t - K +
     1 + k), zeros before the first token (``ops/short_conv.py``'s
-    ``causal_conv``). Its three users: the short convolution of a
-    state-space layer (``models/granite.py``), those of a delta-rule
-    layer's q, k and v (``models/kimi_linear.py``), and, there, the
-    ``jax.numpy`` form of the gated short convolution that is a layer's
-    mixer (``short_conv``; ``models/lfm2.py``)."""
+    ``causal_conv``). No layer calls it: it is the pre-activation that
+    the ``jax.numpy`` forms of ``conv_silu`` (the short convolutions of
+    ``models/granite.py`` and ``models/kimi_linear.py``) and of
+    ``short_conv`` (the gated one that is a layer's mixer;
+    ``models/lfm2.py``) stand on there, the kernels' oracle in the tests."""
     from ray_tpu.ops.short_conv import causal_conv as op
     return op(x, w, b)
+
+
+def conv_silu(x, w, b=None, start: int = 0, width: Optional[int] = None):
+    """``silu(b + causal_conv(x))`` over columns ``start .. start + width``
+    (all, if not given) of ``x`` [B, S, W] by ``ops/short_conv.py`` (one
+    fused Pallas pass each way over the columns where they lie, where the
+    shapes tile, else its ``jax.numpy`` form on the slice): taps ``w`` [K,
+    width], bias ``b`` [width] or None -> [B, S, width] in ``x``'s dtype,
+    products, the K-term sum and the SiLU in float32. The short convolution
+    of a state-space layer (``models/granite.py``: a slice of the
+    in-projection's output) and those of a delta-rule layer's q, k and v
+    (``models/kimi_linear.py``). Under a mesh the kernels run per shard of
+    the batch, as ``state_space``'s do."""
+    from ray_tpu.ops.short_conv import conv_silu as op
+    args = (x, w) if b is None else (x, w, b)
+    return _over_batch_shards(
+        partial(op, start=start, width=width), args,
+        (True,) + (False,) * (len(args) - 1), out_rank=3)
 
 
 def short_conv(bcx, w):

@@ -1,22 +1,28 @@
-"""The double-gated short convolution that is a layer's sequence mixer
-(LFM2's ``Lfm2ShortConv``), as one fused Pallas TPU pass each way and a
-plain ``jax.numpy`` form of the same function.
+"""Short causal convolutions, depthwise along S, as one fused Pallas TPU pass
+each way and a plain ``jax.numpy`` form of the same function. Two
+functions over one set of seam helpers:
 
-One projection gives three chunks of d channels a token, ``bcx = B | C |
-x``; depthwise over the d channels, causal, with K taps ``w`` [K, d] and
-zeros before the first token::
+``short_conv``, the double-gated convolution that is a layer's sequence
+mixer (LFM2's ``Lfm2ShortConv``). One projection gives three chunks of d
+channels a token, ``bcx = B | C | x``; with K taps ``w`` [K, d] and zeros
+before the first token::
 
     z_t = sum_{k=0..K-1} w_k (B * x)_(t-K+1+k)
     y_t = C_t * z_t
 
-No activation, no bias. Everything is elementwise but the K-term sum along
-S, so the floor is bytes: forward three chunks read and one written. As XLA
-operations (``short_conv_xla`` on ``causal_conv``, which ``lm.causal_conv``
-hands to granite's and Kimi's layers too) it is float32 copies of the
-chunks, a pad and a pass a tap.
+No activation, no bias. ``conv_silu``, the convolution in front of a
+recurrence (a Kimi delta-rule layer's q, k, v; a granite state-space layer's
+xBC): no gates, a bias ``b`` [d] or none, a SiLU on the way out::
 
-The kernels read ``bcx`` [batch, S, 3 d] where the projection left it (no
-split copies): a grid step is ``ROWS`` whole rows of one sequence, all 3 d
+    y_t = silu(b + sum_k w_k x_(t-K+1+k))
+
+Everything is elementwise but the K-term sum along S, so the floor is bytes:
+the gated forward reads three chunks and writes one, ``conv_silu``'s reads
+one and writes one. As XLA operations (``short_conv_xla``, ``conv_silu_xla``
+on ``causal_conv``) either is float32 copies, a pad and a pass a tap.
+
+The gated kernels read ``bcx`` [batch, S, 3 d] where the projection left it
+(no split copies): a grid step is ``ROWS`` whole rows of one sequence, all 3 d
 lanes, worked through ``_lanes(d)`` channels at a time in float32. The K - 1
 rows a tile needs from before it are the last of a second, small block of the
 same array (``HALO`` rows: one sublane tile of bfloat16), zeros at a
@@ -34,10 +40,27 @@ read as it lies::
 after the tile, zeros at a sequence's last. ``dw`` leaves the kernel as one
 float32 [8, d] partial sum a grid step and is added up outside.
 
-``short_conv`` is the one entry: the kernels where the shapes tile (S a
-multiple of ``ROWS``, d of 128, K at most 8), else ``short_conv_xla``, which
-is also the kernels' oracle in the tests. On backends other than the TPU the
-kernels run in interpreter mode.
+``conv_silu``'s kernels take the same tiles, halos and partial sums over
+columns ``start .. start + width`` of an ``x`` [batch, S, W] that may be
+wider (granite's xBC lies between z and dt in one projection's output): the
+blocks are whole rows of ``x`` and the kernel takes its columns out of the
+tile, ``_SILU_LANES`` at a time in a loop. The taps and the bias go in as one float32 [K + 1, width] block and
+their gradients come back as the rows of one partial sum. The backward
+forms the pre-activation again, in the tile and on the halo after it::
+
+    dz   = dy * silu'(pre)
+    dx_t = sum_k w_k dz_(t+K-1-k)
+    dw_k = sum_{b,t} dz_t x_(t-K+1+k) ;  db = sum_{b,t} dz_t
+
+``dz`` of the rows after the tile needs their ``pre``, whose taps reach back
+into the tile: that halo carries ``x`` (joined to the tile's last rows), not
+only ``dy``.
+
+``short_conv`` and ``conv_silu`` are the entries: the kernels where the
+shapes tile (S a multiple of ``ROWS``, the channels of 128, the taps (and
+bias) at most 8 rows), else the ``jax.numpy`` form, which is also the
+kernels' oracle in the tests. On backends other than the TPU the kernels run
+in interpreter mode.
 """
 
 from __future__ import annotations
@@ -62,6 +85,10 @@ _DW_ROWS = 8
 #: Blocks of whole rows double-buffered (3 MB each way at d = 2048) do not
 #: fit the 16 MB the compiler scopes a kernel by default.
 _VMEM_BYTES = 64 * 1024 * 1024
+#: Channels ``conv_silu``'s kernels work through at a time: a tile of
+#: float32 values and its K shifted copies then stay near the register file
+#: (the forward at [16384, 4096]: 0.68 / 0.59 / 0.49 ms at 512 / 256 / 128).
+_SILU_LANES = 128
 
 
 def _interpret() -> bool:
@@ -83,6 +110,13 @@ def causal_conv(x, w, b=None):
     w = w.astype(F32)
     bias = 0.0 if b is None else b.astype(F32)
     return bias + sum(w[k] * padded[:, k:k + seq] for k in range(taps))
+
+
+def conv_silu_xla(x, w, b=None):
+    """``silu(b + conv(x))`` as XLA operations, any shape: ``causal_conv``
+    and the SiLU in float32, the result in ``x``'s dtype. ``conv_silu``'s
+    kernels' oracle and their fallback."""
+    return jax.nn.silu(causal_conv(x, w, b)).astype(x.dtype)
 
 
 def short_conv_xla(bcx, w):
@@ -177,20 +211,27 @@ def _params():
         vmem_limit_bytes=_VMEM_BYTES)
 
 
-def _specs(seq: int, d: int, taps: int):
-    """BlockSpecs over the grid (batch, tiles of ``ROWS`` rows)."""
+def _specs(seq: int, d: int, taps: int, wide: int | None = None):
+    """BlockSpecs over the grid (batch, tiles of ``ROWS`` rows) of arrays
+    ``wide`` (3 d, if not given) and d channels wide, and of ``taps`` rows of
+    weights."""
     ratio, halos = ROWS // HALO, seq // HALO
+    wide = 3 * d if wide is None else wide
     return {
-        "wide": pl.BlockSpec((None, ROWS, 3 * d), lambda b, s: (b, s, 0)),
+        "wide": pl.BlockSpec((None, ROWS, wide), lambda b, s: (b, s, 0)),
         "own": pl.BlockSpec((None, ROWS, d), lambda b, s: (b, s, 0)),
         # The HALO rows before the tile (at a sequence's first tile its own
-        # first rows: masked in the kernel) and, of chunk ``lane``, after it.
+        # first rows: masked in the kernel) and, of chunk ``lane`` or of
+        # every column, after it.
         "before": pl.BlockSpec(
-            (None, HALO, 3 * d),
+            (None, HALO, wide),
             lambda b, s: (b, jnp.maximum(s * ratio - 1, 0), 0)),
         "after": lambda lane: pl.BlockSpec(
             (None, HALO, d),
             lambda b, s: (b, jnp.minimum((s + 1) * ratio, halos - 1), lane)),
+        "wide_after": pl.BlockSpec(
+            (None, HALO, wide),
+            lambda b, s: (b, jnp.minimum((s + 1) * ratio, halos - 1), 0)),
         "taps": pl.BlockSpec((taps, d), lambda b, s: (0, 0)),
         "dw": pl.BlockSpec((None, None, _DW_ROWS, d),
                            lambda b, s: (b, s, 0, 0)),
@@ -263,3 +304,144 @@ def short_conv(bcx, w):
         return short_conv_xla(bcx, w)
     with jax.named_scope("short_conv_kernels"):
         return _kernels(bcx, w)
+
+
+# -- silu(b + conv(x)): the short convolution in front of a recurrence ------
+
+def _pre(w, reached, taps: int):
+    """b + sum_k w_k x_(t-K+1+k) from ``reached[j]`` = x_(t-j); ``w`` holds
+    the taps and, if it has a row more, the bias under them."""
+    z = sum(w[k:k + 1] * reached[taps - 1 - k] for k in range(taps))
+    return z + w[taps:taps + 1] if w.shape[0] > taps else z
+
+
+def _chunks(ref, start: int, body):
+    """``body(own, at)`` for every ``_SILU_LANES`` of ``ref``'s columns in
+    turn, as a loop (one copy of the body in the kernel and in the trace,
+    not thirty-two): ``own`` the columns in ``ref``, ``at`` the same ones
+    ``start`` further on."""
+    step = _SILU_LANES
+
+    def one(c, carry):
+        body(pl.ds(pl.multiple_of(c * step, step), step),
+             pl.ds(pl.multiple_of(start + c * step, step), step))
+        return carry
+
+    jax.lax.fori_loop(0, ref.shape[1] // step, one, None)
+
+
+def _silu_fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, start: int,
+                     taps: int):
+    first = pl.program_id(1) == 0
+
+    def chunk(own, at):
+        x = x_ref[:, at].astype(F32)
+        before = jnp.where(first, 0.0, before_ref[:, at].astype(F32))
+        pre = _pre(w_ref[:, own],
+                   [_rows_before(x, before, j) for j in range(taps)], taps)
+        y_ref[:, own] = (pre * jax.nn.sigmoid(pre)).astype(y_ref.dtype)
+
+    _chunks(y_ref, start, chunk)
+
+
+def _silu_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref,
+                     w_ref, dx_ref, dw_ref, *, start: int, taps: int):
+    first = pl.program_id(1) == 0
+    last = pl.program_id(1) == pl.num_programs(1) - 1
+    rows = x_ref.shape[0]
+
+    def chunk(own, at):
+        x, w = x_ref[:, at].astype(F32), w_ref[:, own]
+
+        def dz_of(reached, dy):
+            """dy * silu'(pre), pre formed again."""
+            pre = _pre(w, reached, taps)
+            s = jax.nn.sigmoid(pre)
+            return dy.astype(F32) * s * (1.0 + pre * (1.0 - s))
+
+        before = jnp.where(first, 0.0, before_ref[:, at].astype(F32))
+        reached = [_rows_before(x, before, j) for j in range(taps)]
+        dz = dz_of(reached, dy_ref[:, own])
+        # dz of the rows after the tile needs their pre, whose taps reach
+        # back into the tile: the seam of its last rows and the halo's x.
+        seam = jnp.concatenate(
+            [x[rows - HALO:], after_ref[:, at].astype(F32)], axis=0)
+        after = jnp.where(last, 0.0, dz_of(
+            [seam[HALO:]] + [pltpu.roll(seam, j, 0)[HALO:]
+                             for j in range(1, taps)], dy_after_ref[:, own]))
+        dx_ref[:, own] = sum(
+            w[k:k + 1] * _rows_after(dz, after, taps - 1 - k)
+            for k in range(taps)).astype(dx_ref.dtype)
+        # Row k of the block is tap k's partial sum, row K the bias's.
+        row = jax.lax.broadcasted_iota(
+            jnp.int32, (_DW_ROWS, _SILU_LANES), 0)
+        sums = [dz * reached[taps - 1 - k] for k in range(taps)] + [dz]
+        dw_ref[:, own] = sum(
+            jnp.where(row == k, sums[k].sum(0, keepdims=True), 0.0)
+            for k in range(w.shape[0]))
+
+    _chunks(dx_ref, start, chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _silu_kernels(x, wb, start, width, taps):
+    batch, seq, wide = x.shape
+    spec = _specs(seq, width, wb.shape[0], wide)
+    return pl.pallas_call(
+        functools.partial(_silu_fwd_kernel, start=start, taps=taps),
+        grid=(batch, seq // ROWS),
+        in_specs=[spec["wide"], spec["before"], spec["taps"]],
+        out_specs=spec["own"],
+        out_shape=jax.ShapeDtypeStruct(x.shape[:2] + (width,), x.dtype),
+        compiler_params=_params(),
+        interpret=_interpret(),
+        name="conv_silu_fwd",
+    )(x, x, wb)
+
+
+def _silu_kernels_fwd(x, wb, start, width, taps):
+    return _silu_kernels(x, wb, start, width, taps), (x, wb)
+
+
+def _silu_kernels_bwd(start, width, taps, residuals, dy):
+    """(dx over all of ``x``'s columns, zeros outside the pass's own; the
+    taps' and the bias's gradient as they lie in ``wb``)."""
+    x, wb = residuals
+    batch, seq, wide = x.shape
+    spec = _specs(seq, width, wb.shape[0], wide)
+    dx, dw = pl.pallas_call(
+        functools.partial(_silu_bwd_kernel, start=start, taps=taps),
+        grid=(batch, seq // ROWS),
+        in_specs=[spec["wide"], spec["before"], spec["wide_after"],
+                  spec["own"], spec["after"](0), spec["taps"]],
+        out_specs=[spec["own"], spec["dw"]],
+        out_shape=[jax.ShapeDtypeStruct(dy.shape, x.dtype),
+                   jax.ShapeDtypeStruct(
+                       (batch, seq // ROWS, _DW_ROWS, width), F32)],
+        compiler_params=_params(),
+        interpret=_interpret(),
+        name="conv_silu_bwd",
+    )(x, x, x, dy, dy, wb)
+    beside = (start, x.shape[2] - start - width)
+    return (jnp.pad(dx, ((0, 0), (0, 0), beside)) if any(beside) else dx,
+            dw.sum((0, 1))[:wb.shape[0]])
+
+
+_silu_kernels.defvjp(_silu_kernels_fwd, _silu_kernels_bwd)
+
+
+def conv_silu(x, w, b=None, start: int = 0, width: int | None = None):
+    """y [batch, S, width] = ``silu(b + conv(x))`` over columns ``start ..
+    start + width`` (all, if not given) of ``x`` [batch, S, W], read where
+    they lie: taps ``w`` [K, width], bias ``b`` [width] or None; y in ``x``'s
+    dtype, products, the K-term sum and the SiLU in float32. The kernels
+    where the shapes tile (S a multiple of ``ROWS``, ``start`` and ``width``
+    of 128, the taps and the bias at most ``_DW_ROWS`` rows), else
+    ``conv_silu_xla`` on the slice."""
+    width = x.shape[2] - start if width is None else width
+    wb = w if b is None else jnp.concatenate([w, b[None]])
+    if x.shape[1] % ROWS or start % 128 or width % 128 \
+            or wb.shape[0] > _DW_ROWS:
+        return conv_silu_xla(x[..., start:start + width], w, b)
+    with jax.named_scope("conv_silu_kernels"):
+        return _silu_kernels(x, wb.astype(F32), start, width, w.shape[0])

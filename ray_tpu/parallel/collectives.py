@@ -211,30 +211,34 @@ _KERNEL_CALL = re.compile(
     r'op_name="[^"]*?([\w.\-]+)\)*/pallas_call"')
 
 
-def kernel_census(program: Any) -> Dict[str, int]:
+def kernel_census(program: Any, a_step: bool = False) -> Dict[str, int]:
     """How many calls of each Pallas kernel a program holds, by the name
     the kernel was given (``pallas_call(name=...)``). ``program`` is a
     compiled program's HLO text, where every ``tpu_custom_call`` counts, or
     a jaxpr, where every ``pallas_call`` equation does, the interpreted
     ones of a CPU trace too, sub-jaxprs looked through. A call in a loop's
     body is one call, however often the loop runs: a layer scan with its
-    backward scan holds each kernel of its block once or twice."""
+    backward scan holds each kernel of its block once or twice. With
+    ``a_step`` (a jaxpr's alone) it counts as often as its ``scan``s run it:
+    the calls one run of the program executes."""
     if isinstance(program, str):
         return dict(collections.Counter(_KERNEL_CALL.findall(program)))
     counts: collections.Counter = collections.Counter()
 
-    def walk(jaxpr):
+    def walk(jaxpr, runs):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                counts[eqn.params["name"]] += 1
+                counts[eqn.params["name"]] += runs
+            inside = runs * eqn.params["length"] \
+                if a_step and eqn.primitive.name == "scan" else runs
             for param in eqn.params.values():
                 for sub in param if isinstance(param, (list, tuple)) \
                         else (param,):
                     sub = getattr(sub, "jaxpr", sub)
                     if hasattr(sub, "eqns"):
-                        walk(sub)
+                        walk(sub, inside)
 
-    walk(getattr(program, "jaxpr", program))
+    walk(getattr(program, "jaxpr", program), 1)
     return dict(counts)
 
 

@@ -259,6 +259,84 @@ def test_gated_short_convolution_compiles_at_the_published_widths(
         else {"short_conv_fwd": 1})
 
 
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["conv_silu_fwd", "conv_silu_fwd_and_bwd"])
+@pytest.mark.parametrize("wide,start,width,seq,bias", [
+    (4096, 0, 4096, 16384, False), (8512, 4096, 4352, 32768, True)],
+    ids=["kimi-linear-48b-a3b", "granite-4.0-h-micro"])
+def test_conv_silu_compiles_at_the_published_widths(
+        topo, wide, start, width, seq, bias, backward):
+    """``silu(b + conv(x))`` with 4 taps (ops/short_conv.py ``conv_silu``):
+    one of a Kimi delta-rule layer's q, k, v, an array of its own [1, 16384,
+    4096], and a granite state-space layer's xBC with its bias, columns 4096
+    .. 8448 taken in the kernel out of tiles of 256 whole rows of the
+    in-projection's [1, 32768, 8512] (a last axis that is no whole number
+    of lane tiles); the backward's seam of the tile's last rows and the
+    halo after it."""
+    from ray_tpu.ops import short_conv
+    from ray_tpu.parallel.collectives import kernel_census
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    x, w, b = (jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+               for shape in ((1, seq, wide), (4, width), (width,)))
+    b = b if bias else None
+
+    def conv(x, w, b):
+        return short_conv.conv_silu(x, w, b, start, width)
+
+    def loss(x, w, b):
+        return (conv(x, w, b).astype(jnp.float32) ** 2).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2) if bias else (0, 1)) \
+        if backward else conv
+    text = jax.jit(fn).lower(x, w, b).compile().as_text()
+    assert kernel_census(text) == (
+        {"conv_silu_fwd": 1, "conv_silu_bwd": 1} if backward
+        else {"conv_silu_fwd": 1})
+
+
+@pytest.mark.parametrize("cell,calls", [
+    ("kimi-linear-48b-a3b-1chip.steady",
+     {"conv_silu_fwd": 24, "conv_silu_bwd": 12, "kda_fwd": 8, "kda_bwd": 4}),
+    ("granite-4.0-h-micro-1chip.steady",
+     {"conv_silu_fwd": 36, "conv_silu_bwd": 18, "ssd_fwd": 36,
+      "ssd_bwd": 18}),
+    ("lfm2-24b-a2b-1chip.steady",
+     {"short_conv_fwd": 26, "short_conv_bwd": 13}),
+])
+def test_a_cells_step_runs_the_convolutions_kernels(topo, cell, calls):
+    """The benchmark cell's own step, found the way ``benchmark/rehearse.py``
+    finds it (the configuration's file, its family's ``config`` and
+    ``abstract_state_and_step``), traced for the described chip: the fused
+    pass runs once a convolution in the forward scan, again where the
+    backward scan rematerialises the block (its output feeds the
+    recurrence's backward) and once backward: Kimi's 4 delta-rule layers x
+    q, k, v, granite's 18 state-space layers, LFM2's 13 gated mixers. A
+    one-chip mesh: the trace asks it nothing (``lm._over_batch_shards``)."""
+    from ray_tpu.parallel.collectives import kernel_census
+    here = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, here)
+    try:
+        import harness
+        found = harness.load_cell(harness.load_spec(), cell)
+        layout, program = found.config["layout"], found.config["program"]
+        family = harness.load_module("families", program["family"])
+    finally:
+        sys.path.remove(here)
+    mesh = build_mesh(MeshConfig(**layout["mesh"]),
+                      devices=list(topo.devices[:found.chips]))
+    state, step = family.abstract_state_and_step(
+        family.config(program), mesh, program)
+    tokens = jax.ShapeDtypeStruct(
+        (layout["batch"], layout["seq_len"]), jnp.int32,
+        sharding=family.batch_sharding(mesh))
+    census = kernel_census(jax.make_jaxpr(step.__wrapped__)(
+        state, {"tokens": tokens, "targets": tokens}), a_step=True)
+    assert {name: census.get(name) for name in calls} == calls
+    if "conv_silu_fwd" not in calls:
+        assert "conv_silu_fwd" not in census
+
+
 def test_flash_compiles_at_4_x_8k_with_grouped_kv_heads(topo):
     """LFM2-24B-A2B's attention layer: 32 query heads over 8 KV heads of 64
     at 4 sequences of 8192, forward and both backward kernels."""

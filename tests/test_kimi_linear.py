@@ -206,8 +206,8 @@ def test_a_dropped_term_shows(both, dropped, monkeypatch):
     elif dropped == "qk_norm":
         _kda_with(monkeypatch, q=lambda q: 3.0 * q, k=lambda k: 3.0 * k)
     elif dropped == "conv":
-        monkeypatch.setattr(lm, "causal_conv", lambda x, w, b=None:
-                            x.astype(jnp.float32))
+        monkeypatch.setattr(lm, "conv_silu", lambda x, w, b=None:
+                            jax.nn.silu(x.astype(jnp.float32)).astype(x.dtype))
     elif dropped == "output_gate":
         # sigmoid(0): a constant, where the gate differs a channel.
         params = _in_every_run(params, CFG, lambda w: dict(
