@@ -38,23 +38,35 @@ There the row r at the end of the first half lies between them, and
 ``exp(cum_t - cum_s) = exp(cum_t - cum_r) exp(cum_r - cum_s)``: two factors
 <= 1, both ``exp(-|cum - cum_r|)`` of their own row, so a level is one
 scaling of the rows and one matrix product, masked to its pairs
-(``_pair_products``). The unit lower-triangular
-``(I + A)`` is inverted by the same doubling (``_unit_lower_inverse``: the
-inverse of a block from those of its halves) in float32 products: at the
-highest precision for float32 inputs, and for bfloat16 inputs at sixteen
-bits (each operand as two bfloat16 pieces, three products of pieces: half
-the passes, and still 256 times finer than the operands around it; the
-inverse is 70 % of a forward call at the highest precision on the v5e). The
-other products run in the dtype of ``q`` with float32 accumulation; ``a``,
-``cum``, ``beta`` and the states are float32.
+(``_pair_products``).
+
+**The unit lower-triangular ``(I + A)`` is inverted once a chunk and never
+differentiated through** (``_unit_lower_inverse``). Its diagonal blocks of
+16 rows are inverted by substitution on the vector unit in float32
+(``_block_inverses``: all the blocks of a chunk side by side, fifteen
+multiply-subtracts of two registers), and the same doubling goes on from
+there (``_inverse_by_levels``: the inverse of a block from those of its
+halves, a level of 16, 32 and 64 rows two whole [L, L] products) in float32
+products: at the highest precision for float32 inputs, and for bfloat16
+inputs at sixteen bits (each operand as two bfloat16 pieces, three products
+of pieces: half the passes, and still 256 times finer than the operands
+around it). Its cotangent is the inverse's own identity (``_inverse_of``:
+``T = (I + A)^-1`` gives ``dT = -T dA T``, so ``A``'s cotangent is ``-(T^T
+ct T^T)`` on the strict lower triangle): two products of the same precision
+on the ``T`` already made, where differentiating the levels ran them again
+with two transposed products each. The other products run in the dtype of
+``q`` with float32 accumulation; ``a``, ``cum``, ``beta`` and the states are
+float32.
 
 The kernels' grid is ``(batch, heads, chunks)``, the last sequential: a grid
 step is one chunk of one head. The head's state (kept transposed, [V, K],
 so that its decay is a value a lane) stays in VMEM scratch from chunk to
 chunk as ``ops/ssd.py`` and the flash kernels carry theirs. The forward also
-writes each chunk's entry state, which the backward reads: it walks the
-chunks in reverse with the cotangent of the state carried the same way, and
-differentiates the chunk's own function (``_chunk``) where it stands.
+writes each chunk's entry state and its inverse ([L, L] float32: 268 MB a
+call at 16k tokens of 32 heads, 0.3 ms to move against 5 ms to make again),
+which the backward reads: it walks the chunks in reverse with the cotangent
+of the state carried the same way, and differentiates the chunk's own
+function (``_chunk``) where it stands, given that inverse.
 ``cum`` is made outside the kernels, by XLA, which differentiates the running
 sum too: the kernels take ``cum`` and return its cotangent. In a trace the
 kernels are ``kda_fwd`` and ``kda_bwd``, under the scope ``kda``.
@@ -82,10 +94,13 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 #: rows inside one are picked by a mask.
 _TILE = 8
 #: Positions a chunk. The published kernels use 64; on the v5e a call at
-#: 16k tokens of 32 heads of 128 takes 14.6 ms forward and 44 ms forward and
-#: backward at 128 against 20.3 and 57 at 64: half the grid steps, state
-#: updates and entry states for one more level of doubling.
+#: 16k tokens of 32 heads of 128 takes 8.45 ms forward and 20.3 ms forward
+#: and backward at 128 against 12.9 and 28.0 at 64: half the grid steps,
+#: state updates, entry states and inverses for one more level of doubling.
 CHUNK = 128
+#: Rows of the diagonal blocks of ``I + A`` that the vector unit inverts;
+#: the levels of doubling, whole [L, L] products each, go on from there.
+_BLOCK = 16
 
 
 def _interpret() -> bool:
@@ -213,34 +228,115 @@ def _mm_16_bits_bwd(operands, ct):
 _mm_16_bits.defvjp(_mm_16_bits_fwd, _mm_16_bits_bwd)
 
 
-def _unit_lower_inverse(lower, rows, cols, levels, exact: bool):
+def _times(exact: bool):
+    """The inverse's matrix product: float32 at the highest precision if
+    ``exact``, else at sixteen bits (``_mm_16_bits``)."""
+    return (lambda a, b: _mm(a, b, 1, 0, _HIGHEST)) if exact else _mm_16_bits
+
+
+def _block_inverses(lower, block: int, exact: bool):
+    """The inverses of the diagonal blocks of ``block`` rows of ``I +
+    lower``, block-diagonal [L, L], by substitution on the vector unit in
+    float32. The blocks side by side are one [block, L] array ``x`` (block b
+    in lanes ``block b`` onwards), which starts as their identities; the
+    inverse of ``I + N`` is that of its columns' elementary matrices in
+    turn, ``x <- x - N[:, j] x[j, :]`` for j = 0 .. block - 2, each a
+    multiply and subtract of the whole of ``x``. Column j of every block
+    spread over the block's lanes comes from one product with the blocks'
+    ones, for all j at once and ahead of the substitution (it reads only
+    ``lower``): exact at the highest precision, else two bfloat16 pieces."""
+    length = lower.shape[0]
+    shift = block.bit_length() - 1
+    rows = jax.lax.broadcasted_iota(jnp.int32, (length, length), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (length, length), 1)
+    same = (rows >> shift) == (cols >> shift)
+    side_by_side = jnp.where(same & (rows > cols), lower, 0.0).reshape(
+        length // block, block, length).sum(0)
+    column = jax.lax.broadcasted_iota(
+        jnp.int32, (block - 1, block, length), 0)
+    lane = jax.lax.broadcasted_iota(
+        jnp.int32, (block - 1, block, length), 2) & (block - 1)
+    picked = jnp.where(lane == column, side_by_side[None], 0.0).reshape(
+        (block - 1) * block, length)
+    if exact:
+        spread = _mm(picked, same.astype(F32), 1, 0, _HIGHEST)
+    else:
+        ones = same.astype(jnp.bfloat16)
+        spread = sum(_mm(piece, ones, 1, 0) for piece in _two_pieces(picked))
+    row = jax.lax.broadcasted_iota(jnp.int32, (block, length), 0)
+    in_block = jax.lax.broadcasted_iota(
+        jnp.int32, (block, length), 1) & (block - 1)
+    x = jnp.where(row == in_block, 1.0, 0.0).astype(F32)
+    for j in range(block - 1):
+        x = x - spread[j * block:(j + 1) * block] \
+            * jnp.where(row == j, x, 0.0).sum(0, keepdims=True)
+    return jnp.where(same, jnp.broadcast_to(
+        x[None], (length // block, block, length)).reshape(length, length),
+        0.0)
+
+
+def _inverse_by_levels(lower, exact: bool, block: int):
     """(I + lower)^-1 for a strictly lower-triangular ``lower`` [L, L]: the
     inverse of a block ``[[M1, 0], [M21, M2]]`` is ``[[T1, 0], [-T2 M21 T1,
-    T2]]``, for all blocks of a level at once as ``T - T M_off T``. In
-    float32 products at the highest precision if ``exact``, else at sixteen
-    bits (``_mm_16_bits``)."""
-    times = (lambda a, b: _mm(a, b, 1, 0, _HIGHEST)) if exact \
-        else _mm_16_bits
-    inverse = jnp.where(rows == cols, 1.0, 0.0).astype(F32)
-    for _, mask in levels:
-        inverse = inverse - times(
-            times(inverse, jnp.where(mask, lower, 0.0)), inverse)
+    T2]]``, for all blocks of a level at once as ``T - T M_off T``, from the
+    inverses of the diagonal blocks of ``block`` rows upwards (1: from the
+    identity, every level a product)."""
+    times = _times(exact)
+    rows, cols, levels = _levels(lower.shape[0])
+    inverse = jnp.where(rows == cols, 1.0, 0.0).astype(F32) if block == 1 \
+        else _block_inverses(lower, block, exact)
+    for half, mask in levels:
+        if half >= block:
+            inverse = inverse - times(
+                times(inverse, jnp.where(mask, lower, 0.0)), inverse)
     return inverse
 
 
-def _chunk(q, k, v, cum, beta, state):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _inverse_of(lower, inverse, exact: bool):
+    """``inverse``, which is T = (I + lower)^-1, as a function of ``lower``:
+    ``dT = -T dlower T``, so the cotangent of ``lower`` is ``-(T^T ct T^T)``
+    on the strictly lower triangle, two products at the precision of the
+    levels' on the T they made, and the levels are never differentiated."""
+    return inverse
+
+
+def _inverse_of_fwd(lower, inverse, exact):
+    return inverse, inverse
+
+
+def _inverse_of_bwd(exact, inverse, ct):
+    times = _times(exact)
+    return (jnp.tril(-times(times(inverse.T, ct), inverse.T), -1),
+            jnp.zeros_like(inverse))
+
+
+_inverse_of.defvjp(_inverse_of_fwd, _inverse_of_bwd)
+
+
+def _unit_lower_inverse(lower, exact: bool, made=None):
+    """(I + lower)^-1 with its cotangent by ``_inverse_of``: ``made`` where
+    an earlier call on the same ``lower`` returned it, else by blocks of
+    ``_BLOCK`` rows and the levels from there."""
+    if made is None:
+        made = _inverse_by_levels(jax.lax.stop_gradient(lower), exact,
+                                  min(_BLOCK, lower.shape[0]))
+    return _inverse_of(lower, made, exact)
+
+
+def _chunk(q, k, v, cum, beta, state, inverse=None):
     """One chunk of one head: q, k [L, K], v [L, V], cum [L, K] float32 (the
     running sum of ``a`` inside the chunk), beta [L, 1] float32, state [V,
     K] float32 (the entry state, transposed) -> (o [L, V] float32, the exit
-    state [V, K])."""
+    state [V, K], the inverse of ``I + A`` [L, L] float32). ``inverse`` is
+    that inverse where an earlier call on the same chunk returned it."""
     length = q.shape[0]
     dtype = q.dtype
     q32, k32, v32 = q.astype(F32), k.astype(F32), v.astype(F32)
     rows, cols, levels = _levels(length)
     qk, kk = _pair_products(q32, k32, cum, dtype, rows, cols, levels)
     # Sixteen bits for the inverse where everything around it has eight.
-    inverse = _unit_lower_inverse(kk * beta, rows, cols, levels,
-                                  exact=dtype != jnp.bfloat16)
+    inverse = _unit_lower_inverse(kk * beta, dtype != jnp.bfloat16, inverse)
     from_start = jnp.exp(cum)
     state_d = state.astype(dtype)
     rhs = beta * (v32 - _mm((k32 * from_start).astype(dtype), state_d, 1, 1))
@@ -250,7 +346,7 @@ def _chunk(q, k, v, cum, beta, state):
     last = jax.lax.broadcasted_iota(jnp.int32, cum.shape, 0) == length - 1
     total = jnp.where(last, cum, 0.0).sum(0, keepdims=True)       # [1, K]
     to_end = (k32 * jnp.exp(jnp.minimum(total - cum, 0.0))).astype(dtype)
-    return out, jnp.exp(total) * state + _mm(u, to_end, 0, 0)
+    return out, jnp.exp(total) * state + _mm(u, to_end, 0, 0), inverse
 
 
 # -- the chunked form in jax.numpy -----------------------------------------
@@ -299,7 +395,7 @@ def kda_chunked(q, k, v, a, beta, chunk: int = CHUNK):
     one = jax.vmap(jax.vmap(_chunk))
 
     def carry(state, xs):
-        out, state = one(*xs, state)
+        out, state, _ = one(*xs, state)
         return state, out
 
     _, out = jax.lax.scan(
@@ -314,33 +410,38 @@ def kda_chunked(q, k, v, a, beta, chunk: int = CHUNK):
 # -- the kernels -------------------------------------------------------------
 
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, o_ref, entry_ref,
-                    state_scr):
+                    inverse_ref, state_scr):
     """One chunk of one head: q/k/cum [L, K], v/o [L, V], beta [L, 1];
-    entry [V, K] is the head's state on entry, transposed."""
+    entry [V, K] is the head's state on entry, transposed, inverse [L, L]
+    the chunk's ``(I + A)^-1``."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         state_scr[...] = jnp.zeros(state_scr.shape, F32)
 
     state = state_scr[...]
     entry_ref[...] = state
-    out, state_scr[...] = _chunk(q_ref[...], k_ref[...], v_ref[...],
-                                 cum_ref[...], beta_ref[...], state)
+    out, state_scr[...], inverse_ref[...] = _chunk(
+        q_ref[...], k_ref[...], v_ref[...], cum_ref[...], beta_ref[...],
+        state)
     o_ref[...] = out.astype(o_ref.dtype)
 
 
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, entry_ref,
-                    do_ref, dq_ref, dk_ref, dv_ref, dcum_ref, dbeta_ref,
-                    dstate_scr):
+                    inverse_ref, do_ref, dq_ref, dk_ref, dv_ref, dcum_ref,
+                    dbeta_ref, dstate_scr):
     """The forward's grid step with the chunks in reverse (the index maps
     turn them round): the chunk's function is differentiated where it
-    stands, from its inputs and the entry state the forward wrote, and the
-    cotangent of the head's state is carried in ``dstate_scr``."""
+    stands, from its inputs and the entry state and the inverse the forward
+    wrote, and the cotangent of the head's state is carried in
+    ``dstate_scr``."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate_scr[...] = jnp.zeros(dstate_scr.shape, F32)
 
-    _, pullback = jax.vjp(_chunk, q_ref[...], k_ref[...], v_ref[...],
-                          cum_ref[...], beta_ref[...], entry_ref[...])
+    inverse = inverse_ref[...]
+    _, pullback = jax.vjp(lambda *xs: _chunk(*xs, inverse)[:2],
+                          q_ref[...], k_ref[...], v_ref[...], cum_ref[...],
+                          beta_ref[...], entry_ref[...])
     dq, dk, dv, dcum, dbeta, dstate_scr[...] = pullback(
         (do_ref[...].astype(F32), dstate_scr[...]))
     dq_ref[...] = dq
@@ -366,6 +467,8 @@ def _specs(chunk: int, width: int, v_width: int, n_chunks: int,
                              lambda b, h, t: (b, h, at(t), 0)),
         "state": pl.BlockSpec((None, None, None, v_width, width),
                               lambda b, h, t: (b, h, at(t), 0, 0)),
+        "inverse": pl.BlockSpec((None, None, None, chunk, chunk),
+                                lambda b, h, t: (b, h, at(t), 0, 0)),
     }
 
 
@@ -398,22 +501,26 @@ _INPUTS = ("key", "key", "value", "key", "beta")
 
 
 def _forward(q, k, v, cum, beta, chunk: int):
-    """(o [batch, S, H * V], entry states [batch, H, chunks, V, K]) by the
-    forward kernel; q, k, cum are [batch, S, H * K], v [batch, S, H * V],
-    beta [batch, H, S, 1]."""
+    """(o [batch, S, H * V], entry states [batch, H, chunks, V, K], inverses
+    [batch, H, chunks, L, L]) by the forward kernel; q, k, cum are [batch,
+    S, H * K], v [batch, S, H * V], beta [batch, H, S, 1]."""
     return _call(
         _kda_fwd_kernel, "kda_fwd", False,
-        list(zip((q, k, v, cum, beta), _INPUTS)), ("value", "state"),
+        list(zip((q, k, v, cum, beta), _INPUTS)),
+        ("value", "state", "inverse"),
         lambda *state: [jax.ShapeDtypeStruct(v.shape, v.dtype),
-                        jax.ShapeDtypeStruct(state, F32)], chunk)
+                        jax.ShapeDtypeStruct(state, F32),
+                        jax.ShapeDtypeStruct(state[:3] + (chunk, chunk), F32)],
+        chunk)
 
 
-def _backward(q, k, v, cum, beta, entry, do, chunk: int):
+def _backward(q, k, v, cum, beta, entry, inverse, do, chunk: int):
     """Cotangents (dq, dk, dv, dcum, dbeta) by the backward kernel."""
     inputs = (q, k, v, cum, beta)
     return _call(
         _kda_bwd_kernel, "kda_bwd", True,
-        list(zip(inputs + (entry, do), _INPUTS + ("state", "value"))),
+        list(zip(inputs + (entry, inverse, do),
+                 _INPUTS + ("state", "inverse", "value"))),
         _INPUTS, lambda *_: [jax.ShapeDtypeStruct(x.shape, x.dtype)
                              for x in inputs], chunk)
 
@@ -424,8 +531,8 @@ def _kda_kernels(q, k, v, cum, beta, chunk: int):
 
 
 def _kda_kernels_fwd(q, k, v, cum, beta, chunk):
-    out, entry = _forward(q, k, v, cum, beta, chunk)
-    return out, (q, k, v, cum, beta, entry)
+    out, entry, inverse = _forward(q, k, v, cum, beta, chunk)
+    return out, (q, k, v, cum, beta, entry, inverse)
 
 
 def _kda_kernels_bwd(chunk, residuals, do):

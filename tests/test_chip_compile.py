@@ -205,9 +205,11 @@ def test_state_space_scan_compiles_at_the_published_widths(topo, backward):
 def test_delta_rule_compiles_at_the_published_widths(topo, backward):
     """Kimi-Linear-48B-A3B's KDA layer at the cell's 16k tokens: 32 heads
     with keys and values of 128, chunks of ``kda.CHUNK`` (ops/kda.py). Blocks of rows
-    reshaped by sublane tiles, float32 products at the highest precision,
-    and a backward kernel that is the chunk's function differentiated inside
-    the kernel: what the interpreter lets through and Mosaic may not."""
+    reshaped by sublane tiles, the diagonal blocks' inverses side by side in
+    two registers, a forward kernel that writes each chunk's inverse [128,
+    128] beside its entry state, and a backward kernel that is the chunk's
+    function differentiated inside the kernel given that inverse: what the
+    interpreter lets through and Mosaic may not."""
     from ray_tpu.ops import kda
     from ray_tpu.parallel.collectives import kernel_census
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -294,25 +296,12 @@ def test_conv_silu_compiles_at_the_published_widths(
         else {"conv_silu_fwd": 1})
 
 
-@pytest.mark.parametrize("cell,calls", [
-    ("kimi-linear-48b-a3b-1chip.steady",
-     {"conv_silu_fwd": 24, "conv_silu_bwd": 12, "kda_fwd": 8, "kda_bwd": 4}),
-    ("granite-4.0-h-micro-1chip.steady",
-     {"conv_silu_fwd": 36, "conv_silu_bwd": 18, "ssd_fwd": 36,
-      "ssd_bwd": 18}),
-    ("lfm2-24b-a2b-1chip.steady",
-     {"short_conv_fwd": 26, "short_conv_bwd": 13}),
-])
-def test_a_cells_step_runs_the_convolutions_kernels(topo, cell, calls):
-    """The benchmark cell's own step, found the way ``benchmark/rehearse.py``
-    finds it (the configuration's file, its family's ``config`` and
-    ``abstract_state_and_step``), traced for the described chip: the fused
-    pass runs once a convolution in the forward scan, again where the
-    backward scan rematerialises the block (its output feeds the
-    recurrence's backward) and once backward: Kimi's 4 delta-rule layers x
-    q, k, v, granite's 18 state-space layers, LFM2's 13 gated mixers. A
-    one-chip mesh: the trace asks it nothing (``lm._over_batch_shards``)."""
-    from ray_tpu.parallel.collectives import kernel_census
+def _a_cells_step(topo, cell):
+    """(step, its abstract arguments) of a benchmark cell, found the way
+    ``benchmark/rehearse.py`` finds it (the configuration's file, its
+    family's ``config`` and ``abstract_state_and_step``) for the described
+    chip. A one-chip mesh: the trace asks it nothing
+    (``lm._over_batch_shards``)."""
     here = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")
     sys.path.insert(0, here)
@@ -330,11 +319,65 @@ def test_a_cells_step_runs_the_convolutions_kernels(topo, cell, calls):
     tokens = jax.ShapeDtypeStruct(
         (layout["batch"], layout["seq_len"]), jnp.int32,
         sharding=family.batch_sharding(mesh))
-    census = kernel_census(jax.make_jaxpr(step.__wrapped__)(
-        state, {"tokens": tokens, "targets": tokens}), a_step=True)
+    return step, (state, {"tokens": tokens, "targets": tokens})
+
+
+@pytest.mark.parametrize("cell,calls", [
+    ("kimi-linear-48b-a3b-1chip.steady",
+     {"conv_silu_fwd": 24, "conv_silu_bwd": 12, "kda_fwd": 8, "kda_bwd": 4}),
+    ("granite-4.0-h-micro-1chip.steady",
+     {"conv_silu_fwd": 36, "conv_silu_bwd": 18, "ssd_fwd": 36,
+      "ssd_bwd": 18}),
+    ("lfm2-24b-a2b-1chip.steady",
+     {"short_conv_fwd": 26, "short_conv_bwd": 13}),
+])
+def test_a_cells_step_runs_the_convolutions_kernels(topo, cell, calls):
+    """The benchmark cell's own step, traced for the described chip: the
+    fused pass runs once a convolution in the forward scan, again where the
+    backward scan rematerialises the block (its output feeds the
+    recurrence's backward) and once backward: Kimi's 4 delta-rule layers x
+    q, k, v, granite's 18 state-space layers, LFM2's 13 gated mixers."""
+    from ray_tpu.parallel.collectives import kernel_census
+    step, args = _a_cells_step(topo, cell)
+    census = kernel_census(jax.make_jaxpr(step.__wrapped__)(*args),
+                           a_step=True)
     assert {name: census.get(name) for name in calls} == calls
     if "conv_silu_fwd" not in calls:
         assert "conv_silu_fwd" not in census
+
+
+#: GiB the compiled Kimi step reserved as ``preallocated-temp`` before the
+#: delta rule's forward wrote its inverses (PR 38's tree: 8.17 GiB and 128
+#: MiB of another colour), and what the inverses of one layer may add.
+KIMI_TEMP_GIB, INVERSES_GIB = 8.17 + 0.125, 0.4e9 / 2 ** 30
+
+
+def test_the_kimi_cells_compiled_step_holds_the_delta_rules_pair(
+        topo, tmp_path):
+    """``kimi-linear-48b-a3b-1chip.steady``'s step compiled for the described
+    chip: ``kda_fwd`` 8 and ``kda_bwd`` 4 times a step (6 and 3 in the
+    text: the two expert delta-rule layers in a row are one scan), and the
+    inverses the forward now hands the backward (268 MB a layer, alive
+    inside one block's backward under full remat) within 0.4 GB of what
+    the step reserved before, by the compiler's memory-usage report."""
+    import glob
+    from ray_tpu.parallel.collectives import kernel_census
+    step, args = _a_cells_step(topo, "kimi-linear-48b-a3b-1chip.steady")
+    a_step = kernel_census(jax.make_jaxpr(step.__wrapped__)(*args),
+                           a_step=True)
+    assert (a_step["kda_fwd"], a_step["kda_bwd"]) == (8, 4)
+    compiled = step.lower(*args).compile(
+        compiler_options={"xla_dump_to": str(tmp_path)})
+    held = kernel_census(compiled.as_text())
+    assert (held["kda_fwd"], held["kda_bwd"]) == (6, 3)
+    (report,) = glob.glob(str(tmp_path / "*jit_step*memory-usage-report.txt"))
+    with open(report) as f:
+        found = re.findall(r"allocation (\d+): size ([0-9.]+)([KMG]?)i?B,"
+                           r"[^\n]*preallocated-temp", f.read())
+    scale = {"": 2.0 ** -30, "K": 2.0 ** -20, "M": 2.0 ** -10, "G": 1.0}
+    reserved = sum(float(n) * scale[unit]
+                   for n, unit in {i: (n, u) for i, n, u in found}.values())
+    assert KIMI_TEMP_GIB - 0.05 < reserved < KIMI_TEMP_GIB + INVERSES_GIB
 
 
 def test_flash_compiles_at_4_x_8k_with_grouped_kv_heads(topo):

@@ -173,6 +173,97 @@ def test_sixteen_bits_of_a_float32_product():
                          - a).max()) < 2 ** -15 * float(jnp.abs(a).max())
 
 
+def strongest_lower(chunk, exact):
+    """``A`` of the module text for one chunk of ``chunk`` positions of the
+    head whose channels decay at their strongest, as ``_chunk`` forms it:
+    float32 inputs if ``exact``, else bfloat16 ones."""
+    q, k, _, a, beta = (x[0, :, 0] for x in inputs(7, seq=chunk, strong=True))
+    dtype = jnp.float32 if exact else jnp.bfloat16
+    q, k = (x.astype(dtype).astype(jnp.float32) for x in (q, k))
+    with jax.default_matmul_precision("highest"):
+        _, kk = kda._pair_products(q, k, jnp.cumsum(a, 0), dtype,
+                                   *kda._levels(chunk))
+    return kk * beta[:, None]
+
+
+INVERSE_CASES = pytest.mark.parametrize("chunk", [128, 256])
+INVERSE_TOLS = pytest.mark.parametrize(
+    "exact,tol", [(True, 1e-5), (False, 3e-4)],
+    ids=["float32", "sixteen_bits"])
+
+
+@INVERSE_CASES
+@INVERSE_TOLS
+def test_the_inverses_cotangent_is_its_own_identitys(chunk, exact, tol):
+    """``_unit_lower_inverse`` hands back ``-(T^T ct T^T)`` on the strictly
+    lower triangle from the T it made: what ``jax.vjp`` finds by going
+    through every level of the doubling, without going through one."""
+    lower = strongest_lower(chunk, exact)
+    ct = jax.random.normal(jax.random.PRNGKey(8), lower.shape)
+    with jax.default_matmul_precision("highest"):
+        want, through_levels = jax.vjp(
+            partial(kda._inverse_by_levels, exact=exact, block=1), lower)
+        got, by_identity = jax.vjp(
+            partial(kda._unit_lower_inverse, exact=exact), lower)
+        want_ct, got_ct = through_levels(ct)[0], by_identity(ct)[0]
+    assert_close(got, want, tol)
+    assert_close(got_ct, want_ct, tol)
+    assert float(jnp.abs(jnp.triu(got_ct)).max()) == 0.0
+    assert "while" not in str(jax.make_jaxpr(by_identity)(ct))
+    # ... and only two products of the levels' precision: six of pieces at
+    # sixteen bits, against the levels' own and their transposes'.
+    dots = lambda pull: str(jax.make_jaxpr(pull)(ct)).count("dot_general")
+    assert dots(by_identity) == (2 if exact else 6)
+    assert dots(through_levels) > 10 * dots(by_identity)
+
+
+@INVERSE_CASES
+@INVERSE_TOLS
+def test_the_blocked_inverse_is_the_doublings(chunk, exact, tol):
+    """The diagonal blocks of ``_BLOCK`` rows by substitution and the levels
+    from there: the matrix that doubling from single rows gives, and no
+    further than it from ``numpy``'s float64 inverse."""
+    lower = strongest_lower(chunk, exact)
+    with jax.default_matmul_precision("highest"):
+        doubled = kda._inverse_by_levels(lower, exact, 1)
+        blocked = kda._inverse_by_levels(lower, exact, kda._BLOCK)
+        blocks = kda._block_inverses(lower, kda._BLOCK, exact)
+    assert_close(blocked, doubled, tol)
+    lower64 = np.asarray(lower, np.float64)
+    want = np.linalg.inv(np.eye(chunk) + lower64)
+    off = lambda x: float(np.linalg.norm(np.asarray(x, np.float64) - want))
+    assert off(blocked) < tol * np.linalg.norm(want)
+    assert off(blocked) < 2 * off(doubled) + 1e-7 * np.linalg.norm(want)
+    # The blocks alone: each the inverse of its own block, zeros between.
+    same = np.arange(chunk)[:, None] // kda._BLOCK \
+        == np.arange(chunk)[None] // kda._BLOCK
+    assert float(jnp.abs(jnp.where(same, 0.0, blocks)).max()) == 0.0
+    np.testing.assert_allclose(
+        blocks, np.linalg.inv(np.eye(chunk) + np.where(same, lower64, 0.0)),
+        atol=1e-6 if exact else 2e-5)
+
+
+def test_the_backward_kernel_reads_the_forwards_inverse():
+    """``kda_fwd`` writes a chunk's inverse beside its entry state and
+    ``kda_bwd`` takes it: [batch, H, chunks, L, L] float32 among the
+    residuals, and no level of the doubling in the backward kernel, whose
+    products are then fewer than the forward kernel's."""
+    args = inputs(1, seq=128)
+    chunk = 64
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: kda.kda(*a, chunk=chunk).sum(), argnums=(0, 1, 2, 3, 4)))(
+            *args)
+    calls = {e.params["name"]: e for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"}
+    inverses = (1, 2, 128 // chunk, chunk, chunk)
+    assert [v.aval.shape for v in calls["kda_fwd"].outvars][-1] == inverses
+    assert inverses in [v.aval.shape for v in calls["kda_bwd"].invars]
+    assert calls["kda_fwd"].outvars[-1] in calls["kda_bwd"].invars
+    dots = {name: str(e.params["jaxpr"]).count("dot_general")
+            for name, e in calls.items()}
+    assert dots["kda_bwd"] < 3 * dots["kda_fwd"]
+
+
 def test_a_delta_rule_not_an_additive_state():
     """Writing the same key twice replaces what it read: with beta 1 and no
     decay the second value comes back, not the sum."""
