@@ -1,5 +1,5 @@
-from ray_tpu.models import (afmoe, bert, deepseek, diffusion, gpt, granite,
-                            kimi_linear, lfm2, lm, t5, vit)
+from ray_tpu.models import (afmoe, bert, deepseek, diffusion, exchange, gpt,
+                            granite, kimi_linear, lfm2, lm, t5, vit)
 
-__all__ = ["afmoe", "bert", "deepseek", "diffusion", "gpt", "granite",
-           "kimi_linear", "lfm2", "lm", "t5", "vit"]
+__all__ = ["afmoe", "bert", "deepseek", "diffusion", "exchange", "gpt",
+           "granite", "kimi_linear", "lfm2", "lm", "t5", "vit"]

@@ -36,13 +36,15 @@ the config does not carry). The loss is the cross-entropy alone:
 ``load_balance_coeff`` sizes a balance term whose form the config does not
 give.
 
-What every language model here shares is ``models/lm.py``'s: the lookup, the
-layer scan over kinds of layer with remat (``scan_blocks``), the attention
-dispatch with the layer's window (``attention``: dot, or the flash kernels
-through one pair table), the chunked head and loss (``next_token_loss``).
-The expert layer is ``ops/moe.py``. A layer's kind is its FFN and its
-attention together (``dense_sliding_attention``, ``moe_full_attention``,
-...); every run of one kind is one stack of parameters and one scan.
+This module is the family's config, its table of leaves (``_shapes``) and its
+block; the rest is ``models/lm.py``'s ``Decoder``: parameters and specs from
+the table, the lookup, the layer scan over kinds of layer with remat, the
+head and loss, the expert layers' counters, and the pieces families share
+(``rmsnorm``, ``rope``, ``swiglu``, ``expert_ffn``, the attention
+dispatch with the layer's window). The expert layer is ``ops/moe.py``. A
+layer's kind is its FFN and its attention together
+(``dense_sliding_attention``, ``moe_full_attention``, ...); every run of one
+kind is one stack of parameters and one scan.
 
 **The chip's share.** ``experts_held = (first, count)`` says which of a
 layer's ``num_experts`` live here: the parameters hold those alone, the
@@ -58,41 +60,14 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, replace
-from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu._private import builtin_metrics
 from ray_tpu.models import lm
-from ray_tpu.ops.moe import routed_experts
-from ray_tpu.parallel.sharding import ShardingRules, constrain
 
 _PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
-
-#: Metrics of ``loss_fn`` that count a batch: summed over accumulation
-#: microbatches where the others are averaged (parallel/train_step.py).
-SUMMED_METRICS = ("moe_assignments", "moe_tokens", "moe_routed",
-                  "moe_calls", "moe_calls_within_bound")
-
-#: Metrics of ``loss_fn`` that feed the registry, each with what records
-#: its value there (parallel/train_step.py reads them without a sync).
-RECORDED_METRICS = {
-    "moe_assignments":
-        lambda value: builtin_metrics.train_moe_assignments().inc(value),
-    "moe_tokens":
-        lambda value: builtin_metrics.train_moe_tokens().inc(value),
-    "moe_routed":
-        lambda value: builtin_metrics.train_moe_routed().inc(value),
-    "moe_calls":
-        lambda value: builtin_metrics.train_moe_calls().inc(value),
-    "moe_calls_within_bound":
-        lambda value: builtin_metrics.train_moe_calls_within_bound().inc(
-            value),
-    "moe_load_max_over_mean":
-        lambda value: builtin_metrics.train_moe_expert_load().set(value),
-}
 
 
 @dataclass(frozen=True)
@@ -139,23 +114,13 @@ class AfmoeConfig:
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        if self.experts_held is not None:
-            object.__setattr__(self, "experts_held",
-                               tuple(self.experts_held))
-            first, count = self.experts_held
-            if first < 0 or count < 1 or first + count > self.num_experts:
-                raise ValueError(f"experts_held={self.experts_held} of "
-                                 f"{self.num_experts} experts")
+        object.__setattr__(self, "experts_held", lm.held_experts(
+            self.experts_held, self.num_experts))
         if len(self.layer_types) < self.num_hidden_layers:
             raise ValueError("layer_types is shorter than num_hidden_layers")
         unknown = set(self.layer_types) - set(_PERIOD)
         if unknown:
             raise ValueError(f"layer_types of unknown kinds {unknown}")
-
-    @property
-    def n_experts_held(self) -> int:
-        return self.num_experts if self.experts_held is None \
-            else self.experts_held[1]
 
     @property
     def layers(self) -> Tuple[str, ...]:
@@ -187,17 +152,6 @@ PRESETS: Dict[str, AfmoeConfig] = {
         dtype=jnp.float32, remat=False),
 }
 
-KINDS = tuple(ffn + kind for ffn in ("dense_", "moe_")
-              for kind in ("sliding_attention", "full_attention"))
-
-
-def runs(layers) -> Tuple[Tuple[str, str, int], ...]:
-    """(name in the parameter tree, kind, layers) of every run of one kind
-    of layer, in order: ``run00_dense_sliding_attention``, ... A run is one
-    stack of parameters and one ``lax.scan``."""
-    return tuple((f"run{i:02d}_{kind}", kind, n)
-                 for i, (kind, n) in enumerate(lm.layer_runs(layers)))
-
 
 def config(name: str, **overrides) -> AfmoeConfig:
     cfg = PRESETS[name]
@@ -208,44 +162,30 @@ def config(name: str, **overrides) -> AfmoeConfig:
 
 def _shapes(cfg: AfmoeConfig):
     """{"dense" | "moe": {leaf: (shape without the layers axis, logical
-    axes, init std or None for a vector of ones, 0.0 for zeros)}}: one
-    table for ``init`` and ``param_specs``. Window and full layers hold the
-    same leaves."""
+    axes, init: a std, or ``lm.ones`` | ``lm.zeros``)}}: one table for
+    ``init`` and ``param_specs`` (``lm.Decoder``). Window and full layers
+    hold the same leaves."""
     d, h, kv = cfg.hidden_size, cfg.num_attention_heads, \
         cfg.num_key_value_heads
     hd, std = cfg.head_dim, 0.02
     attn = {
-        "ln_in_scale": ((d,), ("embed",), None),
+        "ln_in_scale": ((d,), ("embed",), lm.ones),
         "wq": ((d, h, hd), ("embed", "heads", "head_dim"), std),
         "wk": ((d, kv, hd), ("embed", "kv_heads", "head_dim"), std),
         "wv": ((d, kv, hd), ("embed", "kv_heads", "head_dim"), std),
         "w_attn_gate": ((d, h, hd), ("embed", "heads", "head_dim"), std),
-        "q_norm_scale": ((hd,), (None,), None),
-        "k_norm_scale": ((hd,), (None,), None),
+        "q_norm_scale": ((hd,), (None,), lm.ones),
+        "k_norm_scale": ((hd,), (None,), lm.ones),
         "wo": ((h, hd, d), ("heads", "head_dim", "embed"), std),
-        "ln_post_attn_scale": ((d,), ("embed",), None),
-        "ln_pre_mlp_scale": ((d,), ("embed",), None),
-        "ln_post_mlp_scale": ((d,), ("embed",), None),
+        "ln_post_attn_scale": ((d,), ("embed",), lm.ones),
+        "ln_pre_mlp_scale": ((d,), ("embed",), lm.ones),
+        "ln_post_mlp_scale": ((d,), ("embed",), lm.ones),
     }
-
-    def swiglu(width, prefix=""):
-        return {prefix + "w_gate": ((d, width), ("embed", "mlp"), std),
-                prefix + "w_up": ((d, width), ("embed", "mlp"), std),
-                prefix + "w_down": ((width, d), ("mlp", "embed"), std)}
-
-    e, held, f = cfg.num_experts, cfg.n_experts_held, \
-        cfg.moe_intermediate_size
-    moe = {
-        "router": ((d, e), ("embed", None), std),
-        # The published expert_bias: a buffer of zeros that the gradient
-        # never moves.
-        "router_bias": ((e,), (None,), 0.0),
-        "w_gate": ((held, d, f), ("expert", "embed", "mlp"), std),
-        "w_up": ((held, d, f), ("expert", "embed", "mlp"), std),
-        "w_down": ((held, f, d), ("expert", "mlp", "embed"), std),
-        **swiglu(cfg.num_shared_experts * f, "shared_"),
-    }
-    return {"dense": dict(attn, **swiglu(cfg.intermediate_size)),
+    f = cfg.moe_intermediate_size
+    moe = lm.expert_leaves(d, cfg.num_experts, cfg.experts_held, f,
+                           shared_width=cfg.num_shared_experts * f)
+    return {"dense": dict(attn, **lm.swiglu_leaves(
+                d, cfg.intermediate_size)),
             "moe": dict(attn, **moe)}
 
 
@@ -253,53 +193,7 @@ def _leaves_of(shapes, kind: str):
     return shapes[kind.split("_", 1)[0]]
 
 
-def init(cfg: AfmoeConfig, key: jax.Array) -> Dict[str, Any]:
-    """Parameters: normal(0, 0.02) matrices, RMSNorm scales of one, a zero
-    ``expert_bias``. Every run of one kind of layer (``runs``) is a stack of
-    its own, over a leading layers axis."""
-    pd = cfg.param_dtype
-    k_embed, k_head, k_layers = jax.random.split(key, 3)
-
-    def normal(k, shape, std):
-        return (jax.random.normal(k, shape, jnp.float32) * std).astype(pd)
-
-    params = {
-        "wte": normal(k_embed, (cfg.vocab_size, cfg.hidden_size), 0.02),
-        "lnf_scale": jnp.ones((cfg.hidden_size,), pd),
-        "lm_head": normal(k_head, (cfg.hidden_size, cfg.vocab_size), 0.02),
-    }
-    shapes = _shapes(cfg)
-    for index, (run, kind, depth) in enumerate(runs(cfg.layers)):
-        leaves = _leaves_of(shapes, kind)
-        keys = jax.random.split(jax.random.fold_in(k_layers, index),
-                                len(leaves))
-        params[run] = {
-            name: jnp.ones((depth,) + shape, pd) if std is None
-            else jnp.zeros((depth,) + shape, pd) if std == 0.0
-            else normal(k, (depth,) + shape, std)
-            for k, (name, (shape, _, std)) in zip(keys, leaves.items())}
-    return params
-
-
-def param_specs(cfg: AfmoeConfig, rules: ShardingRules) -> Dict[str, Any]:
-    """PartitionSpec pytree matching init()'s structure."""
-    specs = {"wte": rules.spec("vocab", "embed"),
-             "lnf_scale": rules.spec("embed"),
-             "lm_head": rules.spec("embed", "vocab")}
-    shapes = _shapes(cfg)
-    for run, kind, _ in runs(cfg.layers):
-        specs[run] = {name: rules.spec("layers", *axes)
-                      for name, (_, axes, _) in
-                      _leaves_of(shapes, kind).items()}
-    return specs
-
-
 # -- forward ------------------------------------------------------------
-
-# Shared with ``models/lfm2.py``, so they live in ``models/lm.py``; under
-# these names the module's own functions call them.
-_rmsnorm, _rope = lm.rmsnorm, lm.rope
-
 
 def _attention(cfg: AfmoeConfig, sliding: bool, x, layer, positions):
     """Gated grouped-query attention on normed x [B, S, d] -> [B, S, d]:
@@ -310,11 +204,11 @@ def _attention(cfg: AfmoeConfig, sliding: bool, x, layer, positions):
     k = jnp.einsum("bsd,dhk->bshk", x, layer["wk"].astype(dt))
     v = jnp.einsum("bsd,dhk->bshk", x, layer["wv"].astype(dt))
     with jax.named_scope("qk_norm"):
-        q = _rmsnorm(q, layer["q_norm_scale"], cfg.rms_norm_eps)
-        k = _rmsnorm(k, layer["k_norm_scale"], cfg.rms_norm_eps)
+        q = lm.rmsnorm(q, layer["q_norm_scale"], cfg.rms_norm_eps)
+        k = lm.rmsnorm(k, layer["k_norm_scale"], cfg.rms_norm_eps)
     if sliding:
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q = lm.rope(q, positions, cfg.rope_theta)
+        k = lm.rope(k, positions, cfg.rope_theta)
     attn = lm.attention(q, k, v, cfg,
                         window=cfg.sliding_window if sliding else None)
     with jax.named_scope("attn_gate"):
@@ -324,134 +218,43 @@ def _attention(cfg: AfmoeConfig, sliding: bool, x, layer, positions):
     return jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(dt))
 
 
-_swiglu = lm.swiglu
-
-
 def _block(cfg: AfmoeConfig, kind: str, h, layer, positions):
-    """One layer of ``kind`` (``runs``). Returns (h, aux): aux is None for a
-    dense layer, else the expert layer's ``picked`` [B, S, K],
-    ``group_sizes`` [held experts], ``asked`` (assignments the router gave
-    them) and ``within_bound`` (1 where they fit ``ops/moe.py``'s one
-    buffer)."""
+    """One layer of ``kind`` (``lm.runs``). Returns (h, aux): aux is None
+    for a dense layer, else the expert layer's (``lm.expert_aux``)."""
     eps = cfg.rms_norm_eps
     ffn, attention_kind = kind.split("_", 1)
     with jax.named_scope(attention_kind):
         a = _attention(cfg, attention_kind == "sliding_attention",
-                       _rmsnorm(h, layer["ln_in_scale"], eps), layer,
+                       lm.rmsnorm(h, layer["ln_in_scale"], eps), layer,
                        positions)
-        h = h + _rmsnorm(a, layer["ln_post_attn_scale"], eps)
-    x = _rmsnorm(h, layer["ln_pre_mlp_scale"], eps)
+        h = h + lm.rmsnorm(a, layer["ln_post_attn_scale"], eps)
+    x = lm.rmsnorm(h, layer["ln_pre_mlp_scale"], eps)
     if ffn == "dense":
         with jax.named_scope("mlp"):
-            m = _swiglu(x, layer["w_gate"], layer["w_up"], layer["w_down"])
-        return h + _rmsnorm(m, layer["ln_post_mlp_scale"], eps), None
-    B, S, d = x.shape
-    routed, aux = routed_experts(
-        x.reshape(B * S, d), layer["router"], layer["router_bias"],
-        layer["w_gate"], layer["w_up"], layer["w_down"],
-        top_k=cfg.num_experts_per_tok, scaling=cfg.route_scale,
+            m = lm.swiglu(x, layer["w_gate"], layer["w_up"], layer["w_down"])
+        return h + lm.rmsnorm(m, layer["ln_post_mlp_scale"], eps), None
+    routed, shared, aux = lm.expert_ffn(
+        x, layer, top_k=cfg.num_experts_per_tok, scaling=cfg.route_scale,
         normalize=cfg.route_norm, held=cfg.experts_held)
-    with jax.named_scope("shared_expert"):
-        shared = _swiglu(x, layer["shared_w_gate"], layer["shared_w_up"],
-                         layer["shared_w_down"])
-    aux = {"picked": aux["picked"].reshape(B, S, -1),
-           "group_sizes": aux["group_sizes"],
-           # With every expert held the router's assignments are all asked,
-           # and the one buffer holds them.
-           "asked": aux.get("asked", jnp.int32(aux["picked"].size)),
-           "within_bound": aux.get("within_bound", jnp.int32(1))}
-    m = routed.reshape(B, S, d) + shared
-    return h + _rmsnorm(m, layer["ln_post_mlp_scale"], eps), aux
+    return h + lm.rmsnorm(routed + shared, layer["ln_post_mlp_scale"],
+                          eps), aux
 
 
-def _no_expert_parallelism():
-    from ray_tpu.parallel.mesh import current_mesh
-    mesh = current_mesh()
-    if mesh is not None and mesh.shape.get("ep", 1) > 1:
-        raise NotImplementedError(
-            "models/afmoe.py does not implement expert parallelism: the "
-            "mesh has ep > 1, and the expert layer (ops/moe.py) computes "
-            "the experts held here (experts_held) without an exchange. Use "
-            "ep=1 (fsdp and tp shard the expert weights).")
+_SHELL = lm.Decoder(
+    name="afmoe", shapes=_shapes, leaves_of=_leaves_of,
+    block=lambda *args: _block(*args),
+    embed_scale=lambda cfg: math.sqrt(cfg.hidden_size)
+    if cfg.mup_enabled else None,
+    experts=True,
+    metrics=lambda cfg, aux, targets: lm.moe_metrics(
+        aux, targets.size * cfg.num_experts_per_tok))
 
-
-def hidden_states(params: Dict[str, Any], cfg: AfmoeConfig,
-                  tokens: jax.Array,
-                  positions: Optional[jax.Array] = None):
-    """tokens [B, S] int32 -> (final-normed hidden [B, S, d], aux) with aux
-    the expert layers' ``picked`` [L_moe, B, S, K], ``group_sizes``
-    [L_moe, held experts], ``asked`` and ``within_bound`` [L_moe], in layer
-    order."""
-    _no_expert_parallelism()
-    if positions is None:
-        positions = lm.positions_of(tokens)
-    x = lm.embed(params["wte"], tokens, cfg.dtype)  # batch-split
-    if cfg.mup_enabled:
-        x = x * jnp.asarray(math.sqrt(cfg.hidden_size), cfg.dtype)
-    x, auxes = lm.scan_blocks(
-        cfg, {kind: partial(_block, cfg, kind) for kind in KINDS}, x,
-        [params[run] for run, _, _ in runs(cfg.layers)], positions,
-        layer_types=cfg.layers)
-    x = constrain(x, "batch", "sequence", None)
-    auxes = [aux for aux in auxes if aux is not None]
-    aux = {name: jnp.concatenate([a[name] for a in auxes])
-           for name in auxes[0]} if auxes else {}
-    return _rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps), aux
-
-
-def head(params: Dict[str, Any], cfg: AfmoeConfig, x: jax.Array):
-    """Logits [..., vocab] of final-normed hidden states x [..., d]."""
-    return jnp.einsum("...d,dv->...v", x, params["lm_head"].astype(cfg.dtype))
-
-
-def forward_with_aux(params: Dict[str, Any], cfg: AfmoeConfig,
-                     tokens: jax.Array,
-                     positions: Optional[jax.Array] = None):
-    """tokens [B, S] -> (logits [B, S, vocab], aux of ``hidden_states``)."""
-    x, aux = hidden_states(params, cfg, tokens, positions)
-    return head(params, cfg, x), aux
-
-
-def forward(params: Dict[str, Any], cfg: AfmoeConfig, tokens: jax.Array,
-            positions: Optional[jax.Array] = None) -> jax.Array:
-    return forward_with_aux(params, cfg, tokens, positions)[0]
-
-
-def loss_of_hidden(params: Dict[str, Any], cfg: AfmoeConfig, x: jax.Array,
-                   aux, targets: jax.Array,
-                   mask: Optional[jax.Array] = None
-                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """``loss_fn`` from ``hidden_states``' result (x [B, S, d], aux)."""
-    loss, metrics = lm.next_token_loss(
-        partial(head, lm.head_gathered(params, tied=False), cfg), x,
-        targets, mask, cfg.loss_chunk, 0.0)
-    if not aux:
-        return loss, metrics
-    sizes = aux["group_sizes"].astype(jnp.float32)  # [L_moe, held]
-    return loss, {
-        **metrics,
-        "moe_assignments": sizes.sum(),
-        "moe_tokens": aux["asked"].astype(jnp.float32).sum(),
-        "moe_routed": jnp.float32(
-            targets.size * cfg.num_experts_per_tok * cfg.n_moe_layers),
-        "moe_calls": jnp.float32(cfg.n_moe_layers),
-        "moe_calls_within_bound":
-            aux["within_bound"].astype(jnp.float32).sum(),
-        "moe_load_max_over_mean": (
-            sizes.max(-1) / jnp.maximum(sizes.mean(-1), 1e-9)).max(),
-    }
-
-
-def loss_fn(params: Dict[str, Any], cfg: AfmoeConfig, tokens: jax.Array,
-            targets: jax.Array, mask: Optional[jax.Array] = None
-            ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Next-token cross-entropy in fp32 (chunked by ``cfg.loss_chunk``), no
-    balance term. The metrics carry what the expert layers did:
-    ``moe_routed`` (every assignment the router made: tokens x experts per
-    token x expert layers), ``moe_tokens`` (those it gave to experts held
-    here), ``moe_assignments`` (rows the grouped matmuls computed: equal to
-    ``moe_tokens``, or something was dropped) and
-    ``moe_load_max_over_mean`` (the busiest held expert's load over the
-    held experts' mean, worst layer)."""
-    x, aux = hidden_states(params, cfg, tokens)
-    return loss_of_hidden(params, cfg, x, aux, targets, mask)
+#: ``hidden_states``' aux is the expert layers' ``picked`` [L_moe, B, S, K],
+#: ``group_sizes`` [L_moe, held experts], ``asked`` and ``within_bound``
+#: [L_moe], in layer order; ``loss_fn``'s metrics are the cross-entropy's and
+#: ``lm.moe_metrics``.
+init, param_specs = _SHELL.init, _SHELL.param_specs
+hidden_states, head = _SHELL.hidden_states, _SHELL.head
+forward, forward_with_aux = _SHELL.forward, _SHELL.forward_with_aux
+loss_of_hidden, loss_fn = _SHELL.loss_of_hidden, _SHELL.loss_fn
+SUMMED_METRICS, RECORDED_METRICS = lm.SUMMED_METRICS, lm.RECORDED_METRICS

@@ -18,7 +18,8 @@ choices for TPU:
   dynamic slicing in the hot path.
 
 The lookup, the layer scan, the attention dispatch and the chunked head and
-loss are ``models/lm.py``'s, shared with every other language model here.
+loss are ``models/lm.py``'s, shared with every other language model here;
+the sliced block's exchanges over tp are ``models/exchange.py``'s.
 
 Capability parity note: the reference has no model zoo of its own (models
 come from torch); this module is the JAX equivalent of what
@@ -38,7 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.models import lm
+from ray_tpu.models import exchange, lm
 from ray_tpu.parallel.mesh import current_rules
 from ray_tpu.parallel.sharding import ShardingRules, constrain
 
@@ -302,7 +303,7 @@ def _block(cfg: GPTConfig, x, layer, positions):
 
 
 def _block_on_slices(cfg: GPTConfig, x, layer, positions):
-    """``_block`` per shard of tp (``lm.exchanged_over_tp``): x is this
+    """``_block`` per shard of tp (``exchange.exchanged_over_tp``): x is this
     chip's slice of S, [B, S / tp, D], in and out; ``layer`` holds this
     chip's heads and columns of the MLP; positions are whole. The same
     products in the same dtypes on the same rows, in an order that lets
@@ -310,17 +311,17 @@ def _block_on_slices(cfg: GPTConfig, x, layer, positions):
     the wire, and every collective issued behind it waits for it):
 
     * the norm runs on the chip's own rows (a row of d is whole here);
-    * its rows go round the ring (``lm.gathered_product``): q, k and v of
+    * its rows go round the ring (``exchange.gathered_product``): q, k and v of
       the slice in hand first, 0.7 ms each, during which the partitioner
       gathers the next weights; then the slice is sent on beside the MLP's
       first product on it, 2.9 ms, which needs nothing else from the wire.
       The MLP is per token: its hidden activations stay in slices;
-    * q, k and v are placed once (``lm.ring_place``) for the kernels, which
-      take whole sequences and this chip's heads, as [b, h, s, k]: the
-      order the products leave them in and the kernels take them in;
-    * ``attn @ wo + ff @ w_out`` is one ``lm.scattered_product``: the other
-      chips' slices first, each partial sum sent on while this chip's own
-      slice is multiplied (0.8 + 3.2 ms), then added to what arrives. In
+    * q, k and v are placed once (``exchange.ring_place``) for the kernels,
+      which take whole sequences and this chip's heads, as [b, h, s, k]:
+      the order the products leave them in and the kernels take them in;
+    * ``attn @ wo + ff @ w_out`` is one ``exchange.scattered_product``: the
+      other chips' slices first, each partial sum sent on while this chip's
+      own slice is multiplied (0.8 + 3.2 ms), then added to what arrives. In
       the backward pass its cotangent goes back once the recomputed
       forward's exchange has landed (``landed``), and its own slice's
       products run after their recomputed inputs.
@@ -341,16 +342,16 @@ def _block_on_slices(cfg: GPTConfig, x, layer, positions):
            for w in ("wq", "wk", "wv")]
 
     def attend(parts):
-        q, k, v = (a.transpose(0, 2, 1, 3) for a in lm.ring_place(
+        q, k, v = (a.transpose(0, 2, 1, 3) for a in exchange.ring_place(
             [tuple(part[:3]) for part in parts], 2))
         attn = _attend(cfg, q, k, v, positions)
-        return lm.ring_split(attn.transpose(0, 2, 1, 3), 2)
+        return exchange.ring_split(attn.transpose(0, 2, 1, 3), 2)
 
     def attn_out(attn, layer):
         return jnp.einsum("bhsk,hkd->bsd", attn, layer["wo"].astype(dt))
 
     with jax.named_scope("attention"):
-        parts = lm.gathered_product(
+        parts = exchange.gathered_product(
             h, qkv + [lambda rows: _mlp_in(cfg, rows, layer)]
             if cfg.parallel_block else qkv)
         attn = attend(parts)
@@ -361,29 +362,30 @@ def _block_on_slices(cfg: GPTConfig, x, layer, positions):
     b_out = layer["b_out"].astype(dt)
     if cfg.parallel_block:
         with jax.named_scope("mlp"):
-            out = lm.scattered_product(
+            out = exchange.scattered_product(
                 lambda inputs, layer: attn_out(inputs[0], layer)
                 + _mlp_out(cfg, inputs[1], layer),
                 [(attn_t, part[3]) for attn_t, part in zip(attn, parts)],
                 shared, after=landed)
         return x + (out + b_out), None
     with jax.named_scope("attention"):
-        x = x + lm.scattered_product(attn_out, attn, shared, after=landed)
+        x = x + exchange.scattered_product(attn_out, attn, shared,
+                                           after=landed)
     h = _layernorm(x, layer["ln2_scale"], layer["ln2_bias"],
                    cfg.layernorm_eps)
     with jax.named_scope("mlp"):
-        parts = lm.gathered_product(
+        parts = exchange.gathered_product(
             h, [lambda rows: _mlp_in(cfg, rows, layer)])
-        out = lm.scattered_product(
+        out = exchange.scattered_product(
             lambda ff, layer: _mlp_out(cfg, ff, layer),
             [part[0] for part in parts], shared)
     return x + (out + b_out), None
 
 
 def _exchange_mesh(cfg: GPTConfig, seq_len: int):
-    """``lm.tp_exchange_mesh`` if this config's heads and MLP split over its
-    tp evenly, else None."""
-    mesh = lm.tp_exchange_mesh(cfg, seq_len)
+    """``exchange.tp_exchange_mesh`` if this config's heads and MLP split
+    over its tp evenly, else None."""
+    mesh = exchange.tp_exchange_mesh(cfg, seq_len)
     if mesh is None:
         return None
     tp = mesh.shape["tp"]
@@ -396,9 +398,9 @@ def hidden_states(params: Dict[str, Any], cfg: GPTConfig,
                   positions: Optional[jax.Array] = None) -> jax.Array:
     """tokens [B, S] int32 → final-layernormed hidden [B, S, d].
 
-    Where the mesh and S allow (``lm.tp_exchange_mesh``) the residual stream
-    is split over tp along S from the lookup to the scan's exit and the
-    blocks run on slices (``_block_on_slices``); it is gathered once,
+    Where the mesh and S allow (``exchange.tp_exchange_mesh``) the residual
+    stream is split over tp along S from the lookup to the scan's exit and
+    the blocks run on slices (``_block_on_slices``); it is gathered once,
     before the final norm and the vocabulary-parallel head."""
     if positions is None:
         positions = lm.positions_of(tokens)
@@ -406,7 +408,7 @@ def hidden_states(params: Dict[str, Any], cfg: GPTConfig,
     if mesh is None:
         block, stream = partial(_block, cfg), "sequence"
     else:
-        (block, layers), stream = lm.exchanged_over_tp(
+        (block, layers), stream = exchange.exchanged_over_tp(
             partial(_block_on_slices, cfg), mesh, layers, param_specs(
                 cfg, current_rules() or ShardingRules())["layers"]), "stream"
     x = lm.embed(params["wte"], tokens, cfg.dtype, stream)

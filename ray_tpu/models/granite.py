@@ -33,8 +33,10 @@ the in-projection's output where they lie (``ops/short_conv.py``'s fused pass
 each way where the shapes tile, else its ``jax.numpy`` form in
 float32: no split copy, no float32 padded copy),
 attention the flash kernels or ``dot`` through ``lm.attention`` with the
-model's score scale, the two kinds of layer one ``lm.scan_blocks`` over
-``layer_types``, the head and loss ``lm.next_token_loss`` on the tied table.
+model's score scale. This module is the family's config, its table of leaves
+(``_shapes``) and its block; parameters and specs from the table, the
+lookup, the scan over the two kinds of layer, the tied head and the loss are
+``lm.Decoder``'s.
 The program computes ``mamba_n_groups`` 1 and ``num_local_experts`` 0 only
 (granite-4.0-h-micro's); ``GraniteConfig`` refuses others.
 """
@@ -42,14 +44,12 @@ The program computes ``mamba_n_groups`` 1 and ``num_local_experts`` 0 only
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import lm
-from ray_tpu.parallel.sharding import ShardingRules, constrain
 
 _PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 
@@ -141,14 +141,6 @@ PRESETS: Dict[str, GraniteConfig] = {
 }
 
 
-def runs(layer_types) -> Tuple[Tuple[str, str, int], ...]:
-    """(name in the parameter tree, kind, layers) of every run of one kind
-    of layer, in order: ``run00_mamba``, ``run01_attention``, ... A run is
-    one stack of parameters and one ``lax.scan``."""
-    return tuple((f"run{i:02d}_{kind}", kind, n)
-                 for i, (kind, n) in enumerate(lm.layer_runs(layer_types)))
-
-
 def config(name: str, **overrides) -> GraniteConfig:
     cfg = PRESETS[name]
     return replace(cfg, **overrides) if overrides else cfg
@@ -156,17 +148,28 @@ def config(name: str, **overrides) -> GraniteConfig:
 
 # -- parameters ---------------------------------------------------------
 
+def _log_arange(key, shape):
+    """``A_log`` = log(1..heads) along the last axis."""
+    return jnp.broadcast_to(jnp.log(jnp.arange(
+        1, shape[-1] + 1, dtype=jnp.float32)), shape)
+
+
 def _shapes(cfg: GraniteConfig):
     """{kind: {leaf: (shape without the layers axis, logical axes, init)}}:
-    one table for ``init`` and ``param_specs``. ``init`` is a std for a
-    normal draw, or ("ones" | "zeros" | "log_arange") for a vector."""
+    one table for ``init`` and ``param_specs`` (``lm.Decoder``). ``init`` is
+    a std for a normal draw, or a callable. As the published
+    ``_init_weights`` leaves them: normal(0, 0.02) matrices, RMSNorm scales,
+    ``dt_bias`` and ``D`` of one, a zero conv bias, ``A_log`` =
+    log(1..heads); the conv's taps normal with the variance of
+    ``nn.Conv1d``'s default, as Mamba-2's own code leaves them (at 0.02 the
+    conv passes nothing on)."""
     d, f = cfg.hidden_size, cfg.shared_intermediate_size
     h, kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     di, mh = cfg.mamba_d_inner, cfg.mamba_n_heads
     std = 0.02
     shared = {
-        "ln1_scale": ((d,), ("embed",), "ones"),
-        "ln2_scale": ((d,), ("embed",), "ones"),
+        "ln1_scale": ((d,), ("embed",), lm.ones),
+        "ln2_scale": ((d,), ("embed",), lm.ones),
         # input_linear: the gated half, then the other.
         "mlp_in": ((d, 2 * f), ("embed", "mlp"), std),
         "mlp_out": ((f, d), ("mlp", "embed"), std),
@@ -177,11 +180,11 @@ def _shapes(cfg: GraniteConfig):
         # nn.Conv1d's default, uniform(+-K^-1/2), has this variance.
         "conv_w": ((cfg.mamba_d_conv, cfg.conv_dim), (None, None),
                    (3 * cfg.mamba_d_conv) ** -0.5),
-        "conv_b": ((cfg.conv_dim,), (None,), "zeros"),
-        "dt_bias": ((mh,), (None,), "ones"),
-        "A_log": ((mh,), (None,), "log_arange"),
-        "D": ((mh,), (None,), "ones"),
-        "norm_scale": ((di,), (None,), "ones"),
+        "conv_b": ((cfg.conv_dim,), (None,), lm.zeros),
+        "dt_bias": ((mh,), (None,), lm.ones),
+        "A_log": ((mh,), (None,), _log_arange),
+        "D": ((mh,), (None,), lm.ones),
+        "norm_scale": ((di,), (None,), lm.ones),
         "w_out": ((di, d), (None, "embed"), std),
     }
     attention = {
@@ -194,58 +197,7 @@ def _shapes(cfg: GraniteConfig):
             "attention": dict(shared, **attention)}
 
 
-def init(cfg: GraniteConfig, key: jax.Array) -> Dict[str, Any]:
-    """Parameters as the published ``_init_weights`` leaves them: normal(0,
-    0.02) matrices, RMSNorm scales, ``dt_bias`` and ``D`` of one, a zero
-    conv bias, ``A_log`` = log(1..heads); the conv's taps normal with the
-    variance of ``nn.Conv1d``'s default, as Mamba-2's own code leaves them
-    (at 0.02 the conv passes nothing on). Every run of one kind of layer
-    (``runs``) is a stack of its own, over a leading layers axis."""
-    pd = cfg.param_dtype
-    k_embed, k_layers = jax.random.split(key)
-
-    def leaf(k, shape, how):
-        if how == "ones":
-            return jnp.ones(shape, pd)
-        if how == "zeros":
-            return jnp.zeros(shape, pd)
-        if how == "log_arange":
-            return jnp.broadcast_to(jnp.log(jnp.arange(
-                1, shape[-1] + 1, dtype=jnp.float32)), shape).astype(pd)
-        return (jax.random.normal(k, shape, jnp.float32) * how).astype(pd)
-
-    params = {
-        "wte": leaf(k_embed, (cfg.vocab_size, cfg.hidden_size), 0.02),
-        "lnf_scale": jnp.ones((cfg.hidden_size,), pd),
-    }
-    shapes = _shapes(cfg)
-    for index, (run, kind, depth) in enumerate(runs(cfg.layers)):
-        keys = jax.random.split(jax.random.fold_in(k_layers, index),
-                                len(shapes[kind]))
-        params[run] = {
-            name: leaf(k, (depth,) + shape, how)
-            for k, (name, (shape, _, how)) in zip(keys, shapes[kind].items())}
-    return params
-
-
-def param_specs(cfg: GraniteConfig, rules: ShardingRules) -> Dict[str, Any]:
-    """PartitionSpec pytree matching init()'s structure."""
-    specs = {"wte": rules.spec("vocab", "embed"),
-             "lnf_scale": rules.spec("embed")}
-    shapes = _shapes(cfg)
-    for run, kind, _ in runs(cfg.layers):
-        specs[run] = {name: rules.spec("layers", *axes)
-                      for name, (_, axes, _) in shapes[kind].items()}
-    return specs
-
-
 # -- forward ------------------------------------------------------------
-
-def _rmsnorm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt((x32 ** 2).mean(-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
-
 
 def _mamba(cfg: GraniteConfig, x, layer):
     """The Mamba-2 mixer on normed x [B, S, d] -> [B, S, d]."""
@@ -263,7 +215,7 @@ def _mamba(cfg: GraniteConfig, x, layer):
         -jnp.exp(layer["A_log"].astype(f32)), B, C, layer["D"].astype(f32),
         cfg.mamba_chunk_size)
     gated = y.reshape(z.shape).astype(f32) * jax.nn.silu(z.astype(f32))
-    normed = _rmsnorm(gated, layer["norm_scale"], cfg.rms_norm_eps)
+    normed = lm.rmsnorm(gated, layer["norm_scale"], cfg.rms_norm_eps)
     return jnp.einsum("bse,ed->bsd", normed.astype(dt_),
                       layer["w_out"].astype(dt_))
 
@@ -295,11 +247,22 @@ def _block(cfg: GraniteConfig, kind: str, h, layer, positions):
     scale = cfg.residual_multiplier
     with jax.named_scope(kind):
         h = h + scale * _MIXERS[kind](
-            cfg, _rmsnorm(h, layer["ln1_scale"], cfg.rms_norm_eps), layer)
+            cfg, lm.rmsnorm(h, layer["ln1_scale"], cfg.rms_norm_eps), layer)
     with jax.named_scope("mlp"):
         h = h + scale * _mlp(
-            cfg, _rmsnorm(h, layer["ln2_scale"], cfg.rms_norm_eps), layer)
+            cfg, lm.rmsnorm(h, layer["ln2_scale"], cfg.rms_norm_eps), layer)
     return h, None
+
+
+_SHELL = lm.Decoder(
+    name="granite", shapes=_shapes, block=lambda *args: _block(*args),
+    tied=True, embed_scale=lambda cfg: cfg.embedding_multiplier,
+    logits_divisor=lambda cfg: cfg.logits_scaling)
+
+#: ``head`` is the tied head's, over ``logits_scaling``; no block returns
+#: aux, so ``hidden_states`` and ``loss_of_hidden`` carry none.
+init, param_specs = _SHELL.init, _SHELL.param_specs
+head, forward, loss_fn = _SHELL.head, _SHELL.forward, _SHELL.loss_fn
 
 
 def hidden_states(params: Dict[str, Any], cfg: GraniteConfig,
@@ -307,44 +270,11 @@ def hidden_states(params: Dict[str, Any], cfg: GraniteConfig,
                   positions: Optional[jax.Array] = None) -> jax.Array:
     """tokens [B, S] int32 -> final-normed hidden [B, S, d]. No layer reads
     ``positions``: the state-space layers carry the order."""
-    x = lm.embed(params["wte"], tokens, cfg.dtype)  # batch-split
-    x = x * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
-    x, _ = lm.scan_blocks(
-        cfg, {kind: partial(_block, cfg, kind) for kind in _MIXERS}, x,
-        [params[run] for run, _, _ in runs(cfg.layers)], positions,
-        layer_types=cfg.layers)
-    x = constrain(x, "batch", "sequence", None)
-    return _rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps)
-
-
-def head(params: Dict[str, Any], cfg: GraniteConfig, x: jax.Array):
-    """Logits [..., vocab] of final-normed hidden states x [..., d]: the
-    tied head's, over ``logits_scaling``. The hidden states are
-    divided, not the logits: the same numbers, and no second pass over a
-    [tokens, vocab] array."""
-    x = x / jnp.asarray(cfg.logits_scaling, x.dtype)
-    return jnp.einsum("...d,vd->...v", x, params["wte"].astype(cfg.dtype))
-
-
-def forward(params: Dict[str, Any], cfg: GraniteConfig, tokens: jax.Array,
-            positions: Optional[jax.Array] = None) -> jax.Array:
-    """tokens [B, S] -> logits [B, S, vocab]."""
-    return head(params, cfg, hidden_states(params, cfg, tokens, positions))
+    return _SHELL.hidden_states(params, cfg, tokens, positions)[0]
 
 
 def loss_of_hidden(params: Dict[str, Any], cfg: GraniteConfig, x: jax.Array,
                    targets: jax.Array, mask: Optional[jax.Array] = None
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """``loss_fn`` from ``hidden_states``' result x [B, S, d]."""
-    return lm.next_token_loss(
-        partial(head, lm.head_gathered(params, tied=True), cfg), x, targets,
-        mask, cfg.loss_chunk, 0.0)
-
-
-def loss_fn(params: Dict[str, Any], cfg: GraniteConfig, tokens: jax.Array,
-            targets: jax.Array, mask: Optional[jax.Array] = None
-            ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Next-token cross-entropy in fp32 (chunked by ``cfg.loss_chunk``) of
-    the tied head's scaled logits."""
-    return loss_of_hidden(params, cfg, hidden_states(params, cfg, tokens),
-                          targets, mask)
+    return _SHELL.loss_of_hidden(params, cfg, x, {}, targets, mask)

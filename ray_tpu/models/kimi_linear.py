@@ -25,7 +25,7 @@ RMSNorm with ``rms_norm_eps``::
                S_t  = S_t + beta_t k_t (v_t - S_t^T k_t)^T        the delta rule
                o_t  = S_t^T q_t
              m      = (RMSNorm(o; g_o) over a head  *  sigmoid((x W_ga) W_gb)) Wo
-    MLA:     models/deepseek.py's latent attention (q_lora_rank null), and with
+    MLA:     deepseek_v3's latent attention (lm.mla; q_lora_rank null), and with
              mla_use_nope no rotation of q_r, k_r: no positions
     h        = h + m
     x        = RMSNorm(h; g_2)
@@ -46,11 +46,14 @@ The recurrence is ``ops/kda.py``'s chunked kernel pair (through
 ``lm.delta_rule``), the short convolutions with their SiLU ``lm.conv_silu``
 (``ops/short_conv.py``'s fused pass each way over each of q, k, v where the
 shapes tile, else its ``jax.numpy`` form in float32), the latent
-attention and the expert FFN ``models/deepseek.py``'s own functions
-(``mla``, ``expert_ffn``: shared code, not a copy), the expert layer
-``ops/moe.py``. A layer's kind is its FFN and its mixer together
+attention and the expert FFN ``lm.mla`` and ``lm.expert_ffn`` (shared with
+``models/deepseek.py``), the expert layer ``ops/moe.py``. This module is the
+family's config, its table of leaves (``_shapes``, with the decay's two
+draws) and its block; parameters and specs from the table, the lookup, the
+layer scan, the head and loss and the expert layers' counters are
+``lm.Decoder``'s. A layer's kind is its FFN and its mixer together
 (``dense_kda``, ``moe_kda``, ``moe_mla``, ...); every run of one kind is one
-stack of parameters and one scan (``lm.scan_blocks`` over ``layers``).
+stack of parameters and one scan.
 
 **The chip's share.** ``experts_held = (first, count)`` says which of a
 layer's ``num_experts`` live here: the parameters hold those alone, the
@@ -65,41 +68,14 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, replace
-from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import builtin_metrics
-from ray_tpu.models import deepseek, lm
+from ray_tpu.models import lm
 from ray_tpu.ops.kda import decay_floor
-from ray_tpu.parallel.sharding import ShardingRules, constrain
-
-#: Metrics of ``loss_fn`` that count a batch: summed over accumulation
-#: microbatches where the others are averaged (parallel/train_step.py).
-SUMMED_METRICS = ("moe_assignments", "moe_tokens", "moe_routed",
-                  "moe_calls", "moe_calls_within_bound")
-
-#: Metrics of ``loss_fn`` that feed the registry, each with what records
-#: its value there (parallel/train_step.py reads them without a sync).
-RECORDED_METRICS = {
-    "moe_assignments":
-        lambda value: builtin_metrics.train_moe_assignments().inc(value),
-    "moe_tokens":
-        lambda value: builtin_metrics.train_moe_tokens().inc(value),
-    "moe_routed":
-        lambda value: builtin_metrics.train_moe_routed().inc(value),
-    "moe_calls":
-        lambda value: builtin_metrics.train_moe_calls().inc(value),
-    "moe_calls_within_bound":
-        lambda value: builtin_metrics.train_moe_calls_within_bound().inc(
-            value),
-    "moe_load_max_over_mean":
-        lambda value: builtin_metrics.train_moe_expert_load().set(value),
-    "kda_decay_floor":
-        lambda value: builtin_metrics.train_kda_decay_floor().set(value),
-}
 
 
 @dataclass(frozen=True)
@@ -165,13 +141,8 @@ class KimiLinearConfig:
         if isinstance(self.linear_attn_config, dict):
             object.__setattr__(self, "linear_attn_config",
                                LinearAttnConfig(**self.linear_attn_config))
-        if self.experts_held is not None:
-            object.__setattr__(self, "experts_held",
-                               tuple(self.experts_held))
-            first, count = self.experts_held
-            if first < 0 or count < 1 or first + count > self.num_experts:
-                raise ValueError(f"experts_held={self.experts_held} of "
-                                 f"{self.num_experts} experts")
+        object.__setattr__(self, "experts_held", lm.held_experts(
+            self.experts_held, self.num_experts))
         linear = self.linear_attn_config
         depth = range(1, self.num_hidden_layers + 1)
         if any((l in linear.kda_layers) == (l in linear.full_attn_layers)
@@ -179,11 +150,6 @@ class KimiLinearConfig:
             raise ValueError(
                 "every layer up to num_hidden_layers must be in exactly one "
                 "of linear_attn_config's kda_layers and full_attn_layers")
-
-    @property
-    def n_experts_held(self) -> int:
-        return self.num_experts if self.experts_held is None \
-            else self.experts_held[1]
 
     @property
     def layers(self) -> Tuple[str, ...]:
@@ -217,17 +183,6 @@ PRESETS: Dict[str, KimiLinearConfig] = {
         model_max_length=512, dtype=jnp.float32, remat=False),
 }
 
-KINDS = tuple(ffn + mixer for ffn in ("dense_", "moe_")
-              for mixer in ("kda", "mla"))
-
-
-def runs(layers) -> Tuple[Tuple[str, str, int], ...]:
-    """(name in the parameter tree, kind, layers) of every run of one kind
-    of layer, in order: ``run00_dense_kda``, ``run01_moe_kda``, ... A run is
-    one stack of parameters and one ``lax.scan``."""
-    return tuple((f"run{i:02d}_{kind}", kind, n)
-                 for i, (kind, n) in enumerate(lm.layer_runs(layers)))
-
 
 def config(name: str, **overrides) -> KimiLinearConfig:
     cfg = PRESETS[name]
@@ -236,18 +191,34 @@ def config(name: str, **overrides) -> KimiLinearConfig:
 
 # -- parameters ---------------------------------------------------------
 
+def _decay_rate(key, shape):
+    """``A_log`` as published for the gated delta rule: log U(1, 16) a
+    head."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+
+
+def _decay_bias(key, shape):
+    """``dt_bias``: the inverse softplus of a log-uniform (0.001, 0.1) a
+    channel, so that the log-decays start between -0.001 and -1.6 a step."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, math.log(0.001), math.log(0.1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 def _shapes(cfg: KimiLinearConfig):
     """{"kda" | "mla" | "dense" | "moe": {leaf: (shape without the layers
-    axis, logical axes, init)}}: one table for ``init`` and ``param_specs``;
-    a layer holds its mixer's leaves and its FFN's. ``init`` is a std for a
-    normal draw, or "ones" | "zeros" | "decay_rate" | "decay_bias"."""
+    axis, logical axes, init)}}: one table for ``init`` and ``param_specs``
+    (``lm.Decoder``); a layer holds its mixer's leaves and its FFN's.
+    ``init`` is a std for a normal draw, or a callable: matrices normal(0,
+    0.02), RMSNorm scales of one, a zero correction bias, the convolutions'
+    taps normal with the variance of ``nn.Conv1d``'s default, the decay's
+    two vectors as above."""
     d, std = cfg.hidden_size, 0.02
     linear = cfg.linear_attn_config
     kh, hd, taps = linear.num_heads, linear.head_dim, \
         linear.short_conv_kernel_size
-    h = cfg.num_attention_heads
-    norms = {"ln_in_scale": ((d,), ("embed",), "ones"),
-             "ln2_scale": ((d,), ("embed",), "ones")}
+    norms = {"ln_in_scale": ((d,), ("embed",), lm.ones),
+             "ln2_scale": ((d,), ("embed",), lm.ones)}
     heads = ("embed", "heads", "head_dim")
     kda = {
         "wq": ((d, kh, hd), heads, std),
@@ -258,106 +229,26 @@ def _shapes(cfg: KimiLinearConfig):
            for x in "qkv"},
         "w_fa": ((d, hd), ("embed", None), std),
         "w_fb": ((hd, kh, hd), (None, "heads", "head_dim"), std),
-        "A_log": ((kh,), (None,), "decay_rate"),
-        "dt_bias": ((kh, hd), (None, None), "decay_bias"),
+        "A_log": ((kh,), (None,), _decay_rate),
+        "dt_bias": ((kh, hd), (None, None), _decay_bias),
         "w_beta": ((d, kh), ("embed", None), std),
-        "o_norm_scale": ((hd,), (None,), "ones"),
+        "o_norm_scale": ((hd,), (None,), lm.ones),
         "w_ga": ((d, hd), ("embed", None), std),
         "w_gb": ((hd, kh, hd), (None, "heads", "head_dim"), std),
         "wo": ((kh, hd, d), ("heads", "head_dim", "embed"), std),
     }
-    mla = {
-        "wq": ((d, h, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim), heads,
-               std),
-        "w_kv_a": ((d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
-                   ("embed", None), std),
-        "kv_norm_scale": ((cfg.kv_lora_rank,), (None,), "ones"),
-        "w_kv_b": ((cfg.kv_lora_rank, h,
-                    cfg.qk_nope_head_dim + cfg.v_head_dim),
-                   (None, "heads", "head_dim"), std),
-        "wo": ((h, cfg.v_head_dim, d), ("heads", "head_dim", "embed"), std),
-    }
-
-    def swiglu(width, prefix=""):
-        return {prefix + "w_gate": ((d, width), ("embed", "mlp"), std),
-                prefix + "w_up": ((d, width), ("embed", "mlp"), std),
-                prefix + "w_down": ((width, d), ("mlp", "embed"), std)}
-
-    e, held, f = cfg.num_experts, cfg.n_experts_held, \
-        cfg.moe_intermediate_size
-    moe = {
-        "router": ((d, e), ("embed", None), std),
-        # The correction bias: a buffer of zeros that the gradient never
-        # moves.
-        "router_bias": ((e,), (None,), "zeros"),
-        "w_gate": ((held, d, f), ("expert", "embed", "mlp"), std),
-        "w_up": ((held, d, f), ("expert", "embed", "mlp"), std),
-        "w_down": ((held, f, d), ("expert", "mlp", "embed"), std),
-        **swiglu(cfg.num_shared_experts * f, "shared_"),
-    }
-    return {"kda": dict(norms, **kda), "mla": dict(norms, **mla),
-            "dense": swiglu(cfg.intermediate_size), "moe": moe}
+    f = cfg.moe_intermediate_size
+    return {"kda": dict(norms, **kda),
+            "mla": dict(norms, **lm.mla_leaves(cfg)),
+            "dense": lm.swiglu_leaves(d, cfg.intermediate_size),
+            "moe": lm.expert_leaves(
+                d, cfg.num_experts, cfg.experts_held, f,
+                shared_width=cfg.num_shared_experts * f)}
 
 
 def _leaves_of(shapes, kind: str):
     ffn, mixer = kind.split("_")
     return dict(shapes[mixer], **shapes[ffn])
-
-
-def init(cfg: KimiLinearConfig, key: jax.Array) -> Dict[str, Any]:
-    """Parameters: normal(0, 0.02) matrices, RMSNorm scales of one, a zero
-    correction bias, the convolutions' taps normal with the variance of
-    ``nn.Conv1d``'s default, and the decay's two vectors as published for
-    the gated delta rule: ``A_log`` = log U(1, 16) a head, ``dt_bias`` the
-    inverse softplus of a log-uniform (0.001, 0.1) a channel, so that the
-    log-decays start between -0.001 and -1.6 a step. Every run of one kind
-    of layer (``runs``) is a stack of its own, over a leading layers
-    axis."""
-    pd = cfg.param_dtype
-    k_embed, k_head, k_layers = jax.random.split(key, 3)
-
-    def leaf(k, shape, how):
-        if how == "ones":
-            return jnp.ones(shape, pd)
-        if how == "zeros":
-            return jnp.zeros(shape, pd)
-        if how == "decay_rate":
-            return jnp.log(jax.random.uniform(
-                k, shape, jnp.float32, 1.0, 16.0)).astype(pd)
-        if how == "decay_bias":
-            dt = jnp.exp(jax.random.uniform(
-                k, shape, jnp.float32, math.log(0.001), math.log(0.1)))
-            return (dt + jnp.log(-jnp.expm1(-dt))).astype(pd)
-        return (jax.random.normal(k, shape, jnp.float32) * how).astype(pd)
-
-    params = {
-        "wte": leaf(k_embed, (cfg.vocab_size, cfg.hidden_size), 0.02),
-        "lnf_scale": jnp.ones((cfg.hidden_size,), pd),
-        "lm_head": leaf(k_head, (cfg.hidden_size, cfg.vocab_size), 0.02),
-    }
-    shapes = _shapes(cfg)
-    for index, (run, kind, depth) in enumerate(runs(cfg.layers)):
-        leaves = _leaves_of(shapes, kind)
-        keys = jax.random.split(jax.random.fold_in(k_layers, index),
-                                len(leaves))
-        params[run] = {
-            name: leaf(k, (depth,) + shape, how)
-            for k, (name, (shape, _, how)) in zip(keys, leaves.items())}
-    return params
-
-
-def param_specs(cfg: KimiLinearConfig, rules: ShardingRules
-                ) -> Dict[str, Any]:
-    """PartitionSpec pytree matching init()'s structure."""
-    specs = {"wte": rules.spec("vocab", "embed"),
-             "lnf_scale": rules.spec("embed"),
-             "lm_head": rules.spec("embed", "vocab")}
-    shapes = _shapes(cfg)
-    for run, kind, _ in runs(cfg.layers):
-        specs[run] = {name: rules.spec("layers", *axes)
-                      for name, (_, axes, _) in
-                      _leaves_of(shapes, kind).items()}
-    return specs
 
 
 # -- forward ------------------------------------------------------------
@@ -399,132 +290,60 @@ def _kda(cfg: KimiLinearConfig, x, layer):
             "bsr,rhk->bshk", low, layer["w_gb"].astype(dt)).astype(f32))
     out = lm.delta_rule(q, k, v, a, beta)
     with jax.named_scope("kda_gate"):
-        gated = deepseek.rmsnorm(out.astype(f32), layer["o_norm_scale"],
-                                 cfg.rms_norm_eps) * gate
+        gated = lm.rmsnorm(out.astype(f32), layer["o_norm_scale"],
+                           cfg.rms_norm_eps) * gate
     return jnp.einsum("bshk,hkd->bsd", gated.astype(dt),
                       layer["wo"].astype(dt)), \
         decay_floor(a)
 
 
 def _block(cfg: KimiLinearConfig, kind: str, h, layer, positions):
-    """One layer of ``kind`` (``runs``). Returns (h, aux): ``decay_floor``
-    (0 for a latent layer) and, of an expert layer, ``picked`` [B, S, K],
-    ``group_sizes`` [held experts], ``asked`` (assignments the router gave
-    them) and ``within_bound`` (1 where they fit ``ops/moe.py``'s one
-    buffer)."""
+    """One layer of ``kind`` (``lm.runs``). Returns (h, aux):
+    ``decay_floor`` (0 for a latent layer) and, of an expert layer, what
+    ``lm.expert_aux`` names."""
     eps = cfg.rms_norm_eps
     ffn, mixer = kind.split("_")
-    x = deepseek.rmsnorm(h, layer["ln_in_scale"], eps)
+    x = lm.rmsnorm(h, layer["ln_in_scale"], eps)
     with jax.named_scope(mixer):
         if mixer == "kda":
             m, floor = _kda(cfg, x, layer)
         else:
-            m, floor = deepseek.mla(cfg, x, layer, positions), \
-                jnp.float32(0.0)
+            m, floor = lm.mla(cfg, x, layer, positions), jnp.float32(0.0)
         h = h + m
     aux = {"decay_floor": floor}
-    x = deepseek.rmsnorm(h, layer["ln2_scale"], eps)
+    x = lm.rmsnorm(h, layer["ln2_scale"], eps)
     if ffn == "dense":
         with jax.named_scope("mlp"):
-            return h + deepseek.swiglu(x, layer["w_gate"], layer["w_up"],
-                                       layer["w_down"]), aux
-    routed, shared, moe = deepseek.expert_ffn(
+            return h + lm.swiglu(x, layer["w_gate"], layer["w_up"],
+                                 layer["w_down"]), aux
+    routed, shared, moe = lm.expert_ffn(
         x, layer, top_k=cfg.num_experts_per_token,
         scaling=cfg.routed_scaling_factor, normalize=cfg.moe_renormalize,
         held=cfg.experts_held)
-    aux.update(picked=moe["picked"], group_sizes=moe["group_sizes"],
-               # With every expert held the router's assignments are all
-               # asked, and the one buffer holds them.
-               asked=moe.get("asked", jnp.int32(moe["picked"].size)),
-               within_bound=moe.get("within_bound", jnp.int32(1)))
-    return h + routed + shared, aux
+    return h + routed + shared, dict(aux, **moe)
 
 
-def _no_expert_parallelism():
-    from ray_tpu.parallel.mesh import current_mesh
-    mesh = current_mesh()
-    if mesh is not None and mesh.shape.get("ep", 1) > 1:
-        raise NotImplementedError(
-            "models/kimi_linear.py does not implement expert parallelism: "
-            "the mesh has ep > 1, and the expert layer (ops/moe.py) "
-            "computes the experts held here (experts_held) without an "
-            "exchange. Use ep=1 (fsdp and tp shard the expert weights).")
+def _metrics(cfg: KimiLinearConfig, aux, targets):
+    """``kda_decay_floor``: the most negative running sum of log-decays any
+    chunk of any KDA layer reached this step; and ``lm.moe_metrics``."""
+    return {"kda_decay_floor": aux["decay_floor"].min(),
+            **lm.moe_metrics(aux, targets.size * cfg.num_experts_per_token)}
 
 
-def hidden_states(params: Dict[str, Any], cfg: KimiLinearConfig,
-                  tokens: jax.Array,
-                  positions: Optional[jax.Array] = None):
-    """tokens [B, S] int32 -> (final-normed hidden [B, S, d], aux) with aux
-    ``decay_floor`` [L] and the expert layers' ``picked`` [L_moe, B, S, K],
-    ``group_sizes`` [L_moe, held experts], ``asked`` and ``within_bound``
-    [L_moe], in layer order. No layer reads ``positions`` under ``mla_use_nope``: the delta
-    rule's layers carry the order."""
-    _no_expert_parallelism()
-    if positions is None:
-        positions = lm.positions_of(tokens)
-    x = lm.embed(params["wte"], tokens, cfg.dtype)  # batch-split
-    x, auxes = lm.scan_blocks(
-        cfg, {kind: partial(_block, cfg, kind) for kind in KINDS}, x,
-        [params[run] for run, _, _ in runs(cfg.layers)], positions,
-        layer_types=cfg.layers)
-    x = constrain(x, "batch", "sequence", None)
-    aux = {name: jnp.concatenate([a[name] for a in auxes if name in a])
-           for name in sorted({name for a in auxes for name in a})}
-    return deepseek.rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps), aux
+_SHELL = lm.Decoder(
+    name="kimi_linear", shapes=_shapes, leaves_of=_leaves_of,
+    block=lambda *args: _block(*args), experts=True, metrics=_metrics)
 
-
-def head(params: Dict[str, Any], cfg: KimiLinearConfig, x: jax.Array):
-    """Logits [..., vocab] of final-normed hidden states x [..., d]."""
-    return jnp.einsum("...d,dv->...v", x, params["lm_head"].astype(cfg.dtype))
-
-
-def forward_with_aux(params: Dict[str, Any], cfg: KimiLinearConfig,
-                     tokens: jax.Array,
-                     positions: Optional[jax.Array] = None):
-    """tokens [B, S] -> (logits [B, S, vocab], aux of ``hidden_states``)."""
-    x, aux = hidden_states(params, cfg, tokens, positions)
-    return head(params, cfg, x), aux
-
-
-def forward(params: Dict[str, Any], cfg: KimiLinearConfig, tokens: jax.Array,
-            positions: Optional[jax.Array] = None) -> jax.Array:
-    return forward_with_aux(params, cfg, tokens, positions)[0]
-
-
-def loss_of_hidden(params: Dict[str, Any], cfg: KimiLinearConfig,
-                   x: jax.Array, aux, targets: jax.Array,
-                   mask: Optional[jax.Array] = None
-                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """``loss_fn`` from ``hidden_states``' result (x [B, S, d], aux)."""
-    loss, metrics = lm.next_token_loss(
-        partial(head, lm.head_gathered(params, tied=False), cfg), x,
-        targets, mask, cfg.loss_chunk, 0.0)
-    metrics = dict(metrics, kda_decay_floor=aux["decay_floor"].min())
-    if "group_sizes" not in aux:
-        return loss, metrics
-    sizes = aux["group_sizes"].astype(jnp.float32)  # [L_moe, held]
-    return loss, {
-        **metrics,
-        "moe_assignments": sizes.sum(),
-        "moe_tokens": aux["asked"].astype(jnp.float32).sum(),
-        "moe_routed": jnp.float32(
-            targets.size * cfg.num_experts_per_token * cfg.n_moe_layers),
-        "moe_calls": jnp.float32(cfg.n_moe_layers),
-        "moe_calls_within_bound":
-            aux["within_bound"].astype(jnp.float32).sum(),
-        "moe_load_max_over_mean": (
-            sizes.max(-1) / jnp.maximum(sizes.mean(-1), 1e-9)).max(),
-    }
-
-
-def loss_fn(params: Dict[str, Any], cfg: KimiLinearConfig, tokens: jax.Array,
-            targets: jax.Array, mask: Optional[jax.Array] = None
-            ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Next-token cross-entropy in fp32 (chunked by ``cfg.loss_chunk``), no
-    balance term. The metrics carry what the expert layers did
-    (``moe_routed``, ``moe_tokens``, ``moe_assignments``,
-    ``moe_load_max_over_mean``, as ``models/afmoe.py``'s) and
-    ``kda_decay_floor``: the most negative running sum of log-decays any
-    chunk of any KDA layer reached this step."""
-    x, aux = hidden_states(params, cfg, tokens)
-    return loss_of_hidden(params, cfg, x, aux, targets, mask)
+#: ``hidden_states``' aux is ``decay_floor`` [L] and the expert layers'
+#: ``picked`` [L_moe, B, S, K], ``group_sizes`` [L_moe, held experts],
+#: ``asked`` and ``within_bound`` [L_moe], in layer order. No layer reads
+#: ``positions`` under ``mla_use_nope``: the delta rule's layers carry the
+#: order.
+init, param_specs = _SHELL.init, _SHELL.param_specs
+hidden_states, head = _SHELL.hidden_states, _SHELL.head
+forward, forward_with_aux = _SHELL.forward, _SHELL.forward_with_aux
+loss_of_hidden, loss_fn = _SHELL.loss_of_hidden, _SHELL.loss_fn
+SUMMED_METRICS = lm.SUMMED_METRICS
+RECORDED_METRICS = dict(
+    lm.RECORDED_METRICS, kda_decay_floor=lambda value:
+    builtin_metrics.train_kda_decay_floor().set(value))

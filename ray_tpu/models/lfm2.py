@@ -36,15 +36,18 @@ no bias anywhere (``conv_bias`` false), every norm an RMSNorm with
 not trained by the gradient, and no rule moves it here. The loss is the
 cross-entropy alone.
 
-What every language model here shares is ``models/lm.py``'s: the lookup, the
-layer scan over kinds of layer with remat (``scan_blocks``), the attention
-dispatch (``attention``), the gated short convolution's (``short_conv``:
-``ops/short_conv.py``'s fused pass each way where the shapes tile, else its
-``jax.numpy`` form), ``rmsnorm`` / ``rope`` / ``swiglu``, the chunked head
-and loss (``next_token_loss``). The expert layer is ``ops/moe.py``. A layer's
-kind is its FFN and its mixer together (``dense_conv``, ``moe_conv``,
-``moe_full_attention``, ...); every run of one kind is one stack of
-parameters and one scan.
+This module is the family's config, its table of leaves (``_shapes``) and its
+block; the rest is ``models/lm.py``'s ``Decoder`` (parameters and specs from
+the table, the lookup, the layer scan over kinds of layer with remat, the
+tied head and the loss, the expert layers' counters) and the pieces families
+share: the attention dispatch (``attention``), the gated short convolution's
+(``short_conv``: ``ops/short_conv.py``'s fused pass each way where the shapes
+tile, else its ``jax.numpy`` form), ``rmsnorm`` / ``rope`` /
+``swiglu``. The expert layer is ``ops/moe.py``, called here: without a
+shared expert and with a bias that may be off, it is not ``lm.expert_ffn``'s
+call. A layer's kind is its FFN and its mixer together (``dense_conv``,
+``moe_conv``, ``moe_full_attention``, ...); every run of one kind is one
+stack of parameters and one scan.
 
 **The layers that run.** ``num_hidden_layers`` layers from published layer
 ``first_layer`` on, the first ``num_dense_layers`` of them dense: a cut that
@@ -64,19 +67,13 @@ implemented: the share runs without an exchange.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import lm
-# What ``parallel/train_step.py`` reads off a model module: which of
-# ``loss_fn``'s metrics count a batch, and what records each in the
-# registry. The expert layer's are ``models/afmoe.py``'s, as they stand.
-from ray_tpu.models.afmoe import RECORDED_METRICS, SUMMED_METRICS  # noqa: F401
 from ray_tpu.ops.moe import routed_experts
-from ray_tpu.parallel.sharding import ShardingRules, constrain
 
 _PUBLISHED_LAYERS = ("conv", "conv") \
     + ("full_attention", "conv", "conv", "conv") * 9 \
@@ -141,13 +138,8 @@ class Lfm2Config:
         if isinstance(self.rope_parameters, dict):
             object.__setattr__(self, "rope_parameters",
                                RopeParameters(**self.rope_parameters))
-        if self.experts_held is not None:
-            object.__setattr__(self, "experts_held",
-                               tuple(self.experts_held))
-            first, count = self.experts_held
-            if first < 0 or count < 1 or first + count > self.num_experts:
-                raise ValueError(f"experts_held={self.experts_held} of "
-                                 f"{self.num_experts} experts")
+        object.__setattr__(self, "experts_held", lm.held_experts(
+            self.experts_held, self.num_experts))
         if self.first_layer < 0 or len(self.layer_types) \
                 < self.first_layer + self.num_hidden_layers:
             raise ValueError(
@@ -166,11 +158,6 @@ class Lfm2Config:
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
-
-    @property
-    def n_experts_held(self) -> int:
-        return self.num_experts if self.experts_held is None \
-            else self.experts_held[1]
 
     @property
     def layers(self) -> Tuple[str, ...]:
@@ -200,17 +187,6 @@ PRESETS: Dict[str, Lfm2Config] = {
         max_position_embeddings=512, dtype=jnp.float32, remat=False),
 }
 
-KINDS = tuple(ffn + mixer for ffn in ("dense_", "moe_")
-              for mixer in ("conv", "full_attention"))
-
-
-def runs(layers) -> Tuple[Tuple[str, str, int], ...]:
-    """(name in the parameter tree, kind, layers) of every run of one kind
-    of layer, in order: ``run00_dense_conv``, ``run01_moe_full_attention``,
-    ... A run is one stack of parameters and one ``lax.scan``."""
-    return tuple((f"run{i:02d}_{kind}", kind, n)
-                 for i, (kind, n) in enumerate(lm.layer_runs(layers)))
-
 
 def config(name: str, **overrides) -> Lfm2Config:
     cfg = PRESETS[name]
@@ -222,13 +198,16 @@ def config(name: str, **overrides) -> Lfm2Config:
 def _shapes(cfg: Lfm2Config):
     """{"conv" | "full_attention" | "dense" | "moe": {leaf: (shape without
     the layers axis, logical axes, init)}}: one table for ``init`` and
-    ``param_specs``; a layer holds its mixer's leaves and its FFN's.
-    ``init`` is a std for a normal draw, or "ones" | "zeros"."""
+    ``param_specs`` (``lm.Decoder``); a layer holds its mixer's leaves and
+    its FFN's. ``init`` is a std for a normal draw, or ``lm.ones`` |
+    ``lm.zeros``: matrices normal(0, 0.02), RMSNorm scales of one, a zero
+    ``expert_bias``, the convolution's taps normal with the variance of
+    ``nn.Conv1d``'s default. One table ``wte`` is embedding and head."""
     d, h, kv = cfg.hidden_size, cfg.num_attention_heads, \
         cfg.num_key_value_heads
     hd, taps, std = cfg.head_dim, cfg.conv_L_cache, 0.02
-    norms = {"operator_norm_scale": ((d,), ("embed",), "ones"),
-             "ffn_norm_scale": ((d,), ("embed",), "ones")}
+    norms = {"operator_norm_scale": ((d,), ("embed",), lm.ones),
+             "ffn_norm_scale": ((d,), ("embed",), lm.ones)}
     conv = {
         # The chunks B, C, x side by side, as Lfm2ShortConv's in_proj.
         "w_in": ((d, 3 * d), ("embed", "mlp"), std),
@@ -240,75 +219,20 @@ def _shapes(cfg: Lfm2Config):
         "wq": ((d, h, hd), ("embed", "heads", "head_dim"), std),
         "wk": ((d, kv, hd), ("embed", "kv_heads", "head_dim"), std),
         "wv": ((d, kv, hd), ("embed", "kv_heads", "head_dim"), std),
-        "q_norm_scale": ((hd,), (None,), "ones"),
-        "k_norm_scale": ((hd,), (None,), "ones"),
+        "q_norm_scale": ((hd,), (None,), lm.ones),
+        "k_norm_scale": ((hd,), (None,), lm.ones),
         "wo": ((h, hd, d), ("heads", "head_dim", "embed"), std),
-    }
-    e, held, f = cfg.num_experts, cfg.n_experts_held, \
-        cfg.moe_intermediate_size
-    dense = {"w_gate": ((d, cfg.intermediate_size), ("embed", "mlp"), std),
-             "w_up": ((d, cfg.intermediate_size), ("embed", "mlp"), std),
-             "w_down": ((cfg.intermediate_size, d), ("mlp", "embed"), std)}
-    moe = {
-        "router": ((d, e), ("embed", None), std),
-        # The published expert_bias: a buffer of zeros that the gradient
-        # never moves.
-        "router_bias": ((e,), (None,), "zeros"),
-        "w_gate": ((held, d, f), ("expert", "embed", "mlp"), std),
-        "w_up": ((held, d, f), ("expert", "embed", "mlp"), std),
-        "w_down": ((held, f, d), ("expert", "mlp", "embed"), std),
     }
     return {"conv": dict(norms, **conv),
             "full_attention": dict(norms, **attention),
-            "dense": dense, "moe": moe}
+            "dense": lm.swiglu_leaves(d, cfg.intermediate_size),
+            "moe": lm.expert_leaves(d, cfg.num_experts, cfg.experts_held,
+                                    cfg.moe_intermediate_size)}
 
 
 def _leaves_of(shapes, kind: str):
     ffn, mixer = kind.split("_", 1)
     return dict(shapes[mixer], **shapes[ffn])
-
-
-def init(cfg: Lfm2Config, key: jax.Array) -> Dict[str, Any]:
-    """Parameters: normal(0, 0.02) matrices, RMSNorm scales of one, a zero
-    ``expert_bias``, the convolution's taps normal with the variance of
-    ``nn.Conv1d``'s default. One table ``wte`` is embedding and head. Every
-    run of one kind of layer (``runs``) is a stack of its own, over a
-    leading layers axis."""
-    pd = cfg.param_dtype
-    k_embed, k_layers = jax.random.split(key)
-
-    def leaf(k, shape, how):
-        if how == "ones":
-            return jnp.ones(shape, pd)
-        if how == "zeros":
-            return jnp.zeros(shape, pd)
-        return (jax.random.normal(k, shape, jnp.float32) * how).astype(pd)
-
-    params = {
-        "wte": leaf(k_embed, (cfg.vocab_size, cfg.hidden_size), 0.02),
-        "embedding_norm_scale": jnp.ones((cfg.hidden_size,), pd),
-    }
-    shapes = _shapes(cfg)
-    for index, (run, kind, depth) in enumerate(runs(cfg.layers)):
-        leaves = _leaves_of(shapes, kind)
-        keys = jax.random.split(jax.random.fold_in(k_layers, index),
-                                len(leaves))
-        params[run] = {
-            name: leaf(k, (depth,) + shape, how)
-            for k, (name, (shape, _, how)) in zip(keys, leaves.items())}
-    return params
-
-
-def param_specs(cfg: Lfm2Config, rules: ShardingRules) -> Dict[str, Any]:
-    """PartitionSpec pytree matching init()'s structure."""
-    specs = {"wte": rules.spec("vocab", "embed"),
-             "embedding_norm_scale": rules.spec("embed")}
-    shapes = _shapes(cfg)
-    for run, kind, _ in runs(cfg.layers):
-        specs[run] = {name: rules.spec("layers", *axes)
-                      for name, (_, axes, _) in
-                      _leaves_of(shapes, kind).items()}
-    return specs
 
 
 # -- forward ------------------------------------------------------------
@@ -337,17 +261,15 @@ def _attention(cfg: Lfm2Config, x, layer, positions):
         k = lm.rmsnorm(k, layer["k_norm_scale"], eps)
     with jax.named_scope("rope"):
         theta = cfg.rope_parameters.rope_theta
-        q, k = lm.rope(q, positions, theta), lm.rope(k, positions, theta)
+        q = lm.rope(q, positions, theta)
+        k = lm.rope(k, positions, theta)
     attn = lm.attention(q, k, v, cfg)
     return jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(dt))
 
 
 def _block(cfg: Lfm2Config, kind: str, h, layer, positions):
-    """One layer of ``kind`` (``runs``). Returns (h, aux): aux is None for a
-    dense layer, else the expert layer's ``picked`` [B, S, K],
-    ``group_sizes`` [held experts], ``asked`` (assignments the router gave
-    them) and ``within_bound`` (1 where they fit ``ops/moe.py``'s one
-    buffer)."""
+    """One layer of ``kind`` (``lm.runs``). Returns (h, aux): aux is None
+    for a dense layer, else the expert layer's (``lm.expert_aux``)."""
     ffn, mixer = kind.split("_", 1)
     x = lm.rmsnorm(h, layer["operator_norm_scale"], cfg.norm_eps)
     if mixer == "conv":
@@ -369,98 +291,23 @@ def _block(cfg: Lfm2Config, kind: str, h, layer, positions):
         layer["w_gate"], layer["w_up"], layer["w_down"],
         top_k=cfg.num_experts_per_tok, scaling=cfg.routed_scaling_factor,
         normalize=cfg.norm_topk_prob, held=cfg.experts_held)
-    aux = {"picked": aux["picked"].reshape(B, S, -1),
-           "group_sizes": aux["group_sizes"],
-           # With every expert held the router's assignments are all asked,
-           # and the one buffer holds them.
-           "asked": aux.get("asked", jnp.int32(aux["picked"].size)),
-           "within_bound": aux.get("within_bound", jnp.int32(1))}
+    aux = lm.expert_aux(aux, (B, S))
     return h + routed.reshape(B, S, d), aux
 
 
-def _no_expert_parallelism():
-    from ray_tpu.parallel.mesh import current_mesh
-    mesh = current_mesh()
-    if mesh is not None and mesh.shape.get("ep", 1) > 1:
-        raise NotImplementedError(
-            "models/lfm2.py does not implement expert parallelism: the "
-            "mesh has ep > 1, and the expert layer (ops/moe.py) computes "
-            "the experts held here (experts_held) without an exchange. Use "
-            "ep=1 (fsdp and tp shard the expert weights).")
+_SHELL = lm.Decoder(
+    name="lfm2", shapes=_shapes, leaves_of=_leaves_of,
+    block=lambda *args: _block(*args),
+    final_norm="embedding_norm_scale", eps="norm_eps", tied=True,
+    experts=True,
+    metrics=lambda cfg, aux, targets: lm.moe_metrics(
+        aux, targets.size * cfg.num_experts_per_tok))
 
-
-def hidden_states(params: Dict[str, Any], cfg: Lfm2Config,
-                  tokens: jax.Array,
-                  positions: Optional[jax.Array] = None):
-    """tokens [B, S] int32 -> (final-normed hidden [B, S, d], aux) with aux
-    the expert layers' ``picked`` [L_moe, B, S, K], ``group_sizes``
-    [L_moe, held experts], ``asked`` and ``within_bound`` [L_moe], in layer
-    order."""
-    _no_expert_parallelism()
-    if positions is None:
-        positions = lm.positions_of(tokens)
-    x = lm.embed(params["wte"], tokens, cfg.dtype)  # batch-split
-    x, auxes = lm.scan_blocks(
-        cfg, {kind: partial(_block, cfg, kind) for kind in KINDS}, x,
-        [params[run] for run, _, _ in runs(cfg.layers)], positions,
-        layer_types=cfg.layers)
-    x = constrain(x, "batch", "sequence", None)
-    auxes = [aux for aux in auxes if aux is not None]
-    aux = {name: jnp.concatenate([a[name] for a in auxes])
-           for name in auxes[0]} if auxes else {}
-    return lm.rmsnorm(x, params["embedding_norm_scale"], cfg.norm_eps), aux
-
-
-def head(params: Dict[str, Any], cfg: Lfm2Config, x: jax.Array):
-    """Logits [..., vocab] of final-normed hidden states x [..., d]: the
-    table's rows again."""
-    return jnp.einsum("...d,vd->...v", x, params["wte"].astype(cfg.dtype))
-
-
-def forward_with_aux(params: Dict[str, Any], cfg: Lfm2Config,
-                     tokens: jax.Array,
-                     positions: Optional[jax.Array] = None):
-    """tokens [B, S] -> (logits [B, S, vocab], aux of ``hidden_states``)."""
-    x, aux = hidden_states(params, cfg, tokens, positions)
-    return head(params, cfg, x), aux
-
-
-def forward(params: Dict[str, Any], cfg: Lfm2Config, tokens: jax.Array,
-            positions: Optional[jax.Array] = None) -> jax.Array:
-    return forward_with_aux(params, cfg, tokens, positions)[0]
-
-
-def loss_of_hidden(params: Dict[str, Any], cfg: Lfm2Config, x: jax.Array,
-                   aux, targets: jax.Array,
-                   mask: Optional[jax.Array] = None
-                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """``loss_fn`` from ``hidden_states``' result (x [B, S, d], aux)."""
-    loss, metrics = lm.next_token_loss(
-        partial(head, lm.head_gathered(params, tied=True), cfg), x,
-        targets, mask, cfg.loss_chunk, 0.0)
-    if not aux:
-        return loss, metrics
-    sizes = aux["group_sizes"].astype(jnp.float32)  # [L_moe, held]
-    return loss, {
-        **metrics,
-        "moe_assignments": sizes.sum(),
-        "moe_tokens": aux["asked"].astype(jnp.float32).sum(),
-        "moe_routed": jnp.float32(
-            targets.size * cfg.num_experts_per_tok * cfg.n_moe_layers),
-        "moe_calls": jnp.float32(cfg.n_moe_layers),
-        "moe_calls_within_bound":
-            aux["within_bound"].astype(jnp.float32).sum(),
-        "moe_load_max_over_mean": (
-            sizes.max(-1) / jnp.maximum(sizes.mean(-1), 1e-9)).max(),
-    }
-
-
-def loss_fn(params: Dict[str, Any], cfg: Lfm2Config, tokens: jax.Array,
-            targets: jax.Array, mask: Optional[jax.Array] = None
-            ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Next-token cross-entropy in fp32 (chunked by ``cfg.loss_chunk``), no
-    balance term. The metrics carry what the expert layers did
-    (``moe_routed``, ``moe_tokens``, ``moe_assignments``,
-    ``moe_load_max_over_mean``, as ``models/afmoe.py``'s)."""
-    x, aux = hidden_states(params, cfg, tokens)
-    return loss_of_hidden(params, cfg, x, aux, targets, mask)
+#: ``hidden_states``' aux is the expert layers' ``picked`` [L_moe, B, S, K],
+#: ``group_sizes`` [L_moe, held experts], ``asked`` and ``within_bound``
+#: [L_moe], in layer order; ``head`` is the table's rows again.
+init, param_specs = _SHELL.init, _SHELL.param_specs
+hidden_states, head = _SHELL.hidden_states, _SHELL.head
+forward, forward_with_aux = _SHELL.forward, _SHELL.forward_with_aux
+loss_of_hidden, loss_fn = _SHELL.loss_of_hidden, _SHELL.loss_fn
+SUMMED_METRICS, RECORDED_METRICS = lm.SUMMED_METRICS, lm.RECORDED_METRICS

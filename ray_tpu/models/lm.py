@@ -1,47 +1,70 @@
 """What a causal language model of this package is made of, and no model
 owns: the embedding lookup, the layer scan with rematerialisation, the
 attention dispatch (which attention runs, and how it is laid over a mesh),
-the state-space scan's, a block's tp traffic as exchanges of slices of S,
-and the chunked head and loss. ``models/gpt.py``,
-``models/deepseek.py``, ``models/granite.py``, ``models/afmoe.py``,
-``models/kimi_linear.py`` and ``models/lfm2.py`` are built from these, and
-from the three pieces two families share as they stand (``rmsnorm``,
-``rope``, ``swiglu``); a new family brings its config, parameters, block and head
-and is written against this module, not against another model.
+the state-space scan's, the chunked head and loss, the pieces families
+share as they stand (``rmsnorm``, the two ropes, ``swiglu``, ``mla``,
+``expert_ffn``, each with the leaves it reads) and the decoder's shell
+(``Decoder``). A block's tp traffic as exchanges of slices of S is the
+sibling ``models/exchange.py``'s. This module imports ``parallel/`` and
+``ops/`` and no model module, and no model module imports another: what a
+second family needs of a first moves here.
+
+**A family** (``models/deepseek.py``, ``granite.py``, ``afmoe.py``,
+``kimi_linear.py``, ``lfm2.py``) is three things, written against this
+module:
+
+* its config, a frozen dataclass under the published keys, with
+  ``vocab_size``, ``hidden_size``, the epsilon of its norms, the program's
+  own choices under the names ``GPTConfig`` gives them (``dtype``,
+  ``param_dtype``, ``remat``, ``remat_policy``, ``loss_chunk``,
+  ``attn_impl``, ``attn_blk_q``, ``attn_blk_k``) and, unless it names its
+  stacks itself (``Decoder.runs_of``), ``layers``: the kind of every layer
+  that runs, in order;
+* its table of leaves, ``_shapes(cfg)``: ``{leaf: (shape without the
+  layers axis, logical axes for ``ShardingRules``, init)}`` grouped as the
+  family likes, with ``leaves_of(table, kind)`` giving one layer's leaves
+  in the order they are drawn. ``init`` is the std of a normal draw or a
+  callable ``(key, shape) -> float32 array`` (``ones``, ``zeros``, a
+  family's own beside its table). The one source of parameters and specs;
+* its block, ``(cfg, kind, h, layer, positions) -> (h, aux or None)``: one
+  layer of ``kind`` on the residual stream h [B, S, d] from the layer's
+  leaves, aux a dict of arrays (an expert layer's: ``expert_aux``).
+
+``Decoder`` makes of them ``init``, ``param_specs``, ``hidden_states``,
+``head``, ``forward``, ``forward_with_aux``, ``loss_of_hidden`` and
+``loss_fn``, and the family's module binds those names (the benchmark and
+checkpoints read them there), beside ``config``, ``PRESETS`` and, where its
+loss has metrics that count or feed the registry, ``SUMMED_METRICS`` /
+``RECORDED_METRICS``: ``parallel/train_step.py`` reads both off the module
+that defines ``type(cfg)``. ``models/gpt.py`` is older than the shell and
+takes the lookup, the scan, the attention and the loss only.
 
 Where S lives on a mesh. Everything a whole model carries along S (the
 batch, q / k / v, the loss) is ``sequence``: whole, or over sp under context
 parallelism. The residual stream between blocks is stated apart
 (``embed``'s ``stream``): for a model whose block runs on slices of S
-(``exchanged_over_tp``; ``models/gpt.py``), over tp, the axis its weights
-are split over, from the lookup to the scan's exit, where it is gathered
-once for the final norm and the vocabulary-parallel head. Such a block's
-sum over tp and the gather that undoes it are then exchanges of slices
-beside the block's matmuls (``gathered_product``, ``scattered_product``)
-where an all-reduce of [B, S, d] a layer and pass ran alone. A block that
-says nothing of slices keeps the stream under ``sequence`` and the
+(``exchange.exchanged_over_tp``; ``models/gpt.py``), over tp, the axis its
+weights are split over, from the lookup to the scan's exit, where it is
+gathered once for the final norm and the vocabulary-parallel head. A block
+that says nothing of slices keeps the stream under ``sequence`` and the
 partitioner's collectives.
-
-A model's config is read here for the program's own choices only, under the
-names ``GPTConfig`` gives them: ``attn_impl``, ``attn_blk_q``,
-``attn_blk_k`` (``attention``), ``remat``, ``remat_policy``
-(``scan_blocks``). This module imports ``parallel/`` and ``ops/`` and no
-model module.
 """
 
 from __future__ import annotations
 
 import itertools
 import math
+from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from ray_tpu.parallel.sharding import _spec_dim_axes as _axes, \
-    ambient_spec, constrain
+from ray_tpu._private import builtin_metrics
+from ray_tpu.parallel.sharding import ShardingRules, \
+    _spec_dim_axes as _axes, ambient_spec, constrain
 
 
 # -- lookup and layer scan ------------------------------------------------
@@ -59,8 +82,8 @@ def embed(wte, tokens, dtype, stream: str = "sequence"):
     wherever an operation wants the batch split. ``stream`` is the rule its
     S is stated under: ``"sequence"`` (whole, or over sp under context
     parallelism), or ``"stream"`` for a model whose blocks run on slices of
-    S over tp (``exchanged_over_tp``): the lookup in a table split over
-    tp by vocabulary gives partial sums over tp, and with S over tp their
+    S over tp (``exchange.exchanged_over_tp``): the lookup in a table split
+    over tp by vocabulary gives partial sums over tp, and with S over tp their
     sum is a scatter, each chip keeping the rows its block will take.
     The lookup itself leaves d split as the table has it. Stated so first,
     rows are cut where they lie, and the change that follows is one
@@ -78,7 +101,16 @@ def layer_runs(layer_types):
             for kind, run in itertools.groupby(layer_types)]
 
 
-def scan_blocks(cfg, block, x, layers, positions, layer_types=None):
+def runs(layers) -> Tuple[Tuple[str, str, int], ...]:
+    """(name in the parameter tree, kind, layers) of every run of one kind
+    of layer in ``layers``, in order: ``run00_dense_kda``,
+    ``run01_moe_kda``, ... A run is one stack of parameters and one
+    ``lax.scan``."""
+    return tuple((f"run{i:02d}_{kind}", kind, n)
+                 for i, (kind, n) in enumerate(layer_runs(layers)))
+
+
+def scan_blocks(cfg, block, x, layers, positions, runs=None):
     """``block(x, layer, positions) -> (x, aux)`` over stacked layer
     parameters in one ``lax.scan``, each block rematerialised by
     ``cfg.remat`` / ``cfg.remat_policy``. Returns (x, aux stacked over
@@ -99,15 +131,15 @@ def scan_blocks(cfg, block, x, layers, positions, layer_types=None):
     model names in its block (``attn_q``, ``attn_k``, ``attn_v``,
     ``attn_raw``, ``ffn_in``).
 
-    A stack of several kinds of layer gives ``layer_types``, the kind of
-    every layer in order, ``block`` as a dict by kind and ``layers`` as a
-    sequence with one stack for every run of one kind, in order (a model
+    A stack of several kinds of layer gives ``runs``, the (kind, layers) of
+    every run of one kind in order (``layer_runs``), ``block`` as a dict by
+    kind and ``layers`` as a sequence with one stack for every run (a model
     keeps its parameters that way: a slice of one stack of all a kind's
     layers is a copy, and the slices' gradients a second one). Every run
     is one scan, the runs one after the other. Returns (x, [each run's
     aux])."""
-    if layer_types is not None:
-        runs = layer_runs(layer_types)
+    if runs is not None:
+        runs = list(runs)
         depths = [jax.tree.leaves(stack)[0].shape[0] for stack in layers]
         if depths != [n for _, n in runs]:
             raise ValueError(f"stacks of {depths} layers for runs {runs}")
@@ -181,11 +213,12 @@ def attention_specs(mesh, n_heads: int, n_kv_heads: int, seq_axis):
     return q_spec, kv_spec
 
 
-def _per_shard(fn, mesh, in_specs, out_specs):
+def per_shard(fn, mesh, in_specs, out_specs):
     """``fn`` per shard of ``mesh`` as the specs lay its arguments out. In a
-    block that already runs per shard of some axes (``exchanged_over_tp``:
-    the heads there are this chip's), per shard of the others, under the
-    mesh of that trace, the taken axes left out of the specs."""
+    block that already runs per shard of some axes
+    (``exchange.exchanged_over_tp``: the heads there are this chip's), per
+    shard of the others, under the mesh of that trace, the taken axes left
+    out of the specs."""
     from ray_tpu._private.jax_compat import shard_map
     inside = jax.sharding.get_abstract_mesh()
     taken = set(inside.manual_axes)
@@ -232,7 +265,7 @@ def attention(q, k, v, cfg, scale: Optional[float] = None,
             mesh, q.shape[2], k.shape[2], seq_axis=None)
         if kv_spec[2] is None:
             q_spec = kv_spec
-        return _per_shard(fn, mesh, (q_spec, kv_spec, kv_spec), q_spec)(
+        return per_shard(fn, mesh, (q_spec, kv_spec, kv_spec), q_spec)(
             q, k, v)
     if scale is not None:
         raise NotImplementedError(
@@ -267,265 +300,6 @@ def attention(q, k, v, cfg, scale: Optional[float] = None,
         return make_ulysses_attention(mesh)(q, k, v)
     raise ValueError(f"Unknown attn_impl {cfg.attn_impl!r}")
 
-
-# -- the block's tp traffic as exchanges of slices of S --------------------
-# A block whose weights are split over tp (q / k / v / ``w_in`` by columns,
-# ``wo`` / ``w_out`` by rows) takes a residual stream that is split over tp
-# too, along S. It needs every row for its column-split products and owes
-# every chip the sum of the row-split ones on that chip's rows: an
-# all-gather and a reduce-scatter of [B, S, d], which as two instructions
-# nothing hides. The helpers below write them as a ring of
-# ``ppermute``s of one slice of S (1 / tp of the rows) each, between which
-# the products on the slice in hand run, in the manner of
-# ``ops/ring_attention.py``'s K/V rotation. All run per shard, inside a
-# ``shard_map`` that binds ``axis_name`` (``exchanged_over_tp`` is it).
-# Autodiff transposes a ``ppermute`` into the reverse ``ppermute``, so the
-# recomputed forward and the backward pass are exchanges of the same kind.
-#
-# Ring step t = 0 .. tp - 1: chip c has in hand slice ``(c - t) % tp`` of
-# S, its own first. ``gathered_product`` and ``ring_split`` give their
-# results in that order; ``ring_place`` takes its slices and
-# ``scattered_product`` asks for its products in it.
-
-def _ring(axis_name):
-    """(chips on the ring, this chip's place, every chip to the next)."""
-    n = jax.lax.psum(1, axis_name)
-    return n, jax.lax.axis_index(axis_name), [(j, (j + 1) % n)
-                                              for j in range(n)]
-
-
-def gathered_product(rows, products, axis_name: str = "tp"):
-    """``[[product(slice) for product in products] for every slice of S]``
-    in the ring's order, from this chip's own ``rows`` [b, S / tp, ...].
-
-    The slice in hand goes on to the next chip before the last product on
-    it and after the others: put the longest last. The chip's transfers
-    queue behind one another, and a collective it waits for (the gather
-    of a weight that the partitioner starts one matmul ahead, the
-    synchronous one of a scan body's first matmul) then waits for the slice
-    in flight too: 0.65-1.3 ms each where the slice takes 1.6 (my chip
-    runs, PR 32). So the slice flies beside one product that is long
-    enough, whose own weights are there when it starts."""
-    n, _, onward = _ring(axis_name)
-    parts = []
-    for step in range(n):
-        first = [product(rows) for product in products[:-1]]
-        if first and step < n - 1:
-            rows, first = jax.lax.optimization_barrier((rows, first))
-        coming = jax.lax.ppermute(rows, axis_name, onward) \
-            if step < n - 1 else None
-        last = products[-1](rows)
-        if coming is not None:
-            coming, last = jax.lax.optimization_barrier((coming, last))
-        parts.append(first + [last])
-        rows = coming
-    return parts
-
-
-def _slots(axis_name):
-    """int32 [tp]: the slice of S this chip has in hand at each ring step."""
-    n, my, _ = _ring(axis_name)
-    return (my - jnp.arange(n, dtype=jnp.int32)) % n
-
-
-def _placed(parts, axis, axis_name):
-    from ray_tpu.ops.place import place_slices
-    place = partial(place_slices, axis=axis)
-    slots = _slots(axis_name)
-    inside = jax.sharding.get_abstract_mesh()
-    if len(inside.manual_axes) == len(inside.axis_names):
-        return place(parts, slots)
-    # The batch is still the partitioner's, and it cannot cut a kernel:
-    # per shard of the axes that are left, as the flash kernels run.
-    rows = jax.tree.map(
-        lambda _: PartitionSpec(ambient_spec(inside, "batch")[0]),
-        list(parts))
-    return _per_shard(place, inside, (rows, PartitionSpec()), rows[0])(
-        list(parts), slots)
-
-
-def _split(whole, axis, axis_name):
-    n, my, _ = _ring(axis_name)
-    return [jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(
-        a, ((my - step) % n) * (a.shape[axis] // n), a.shape[axis] // n,
-        axis=axis), whole) for step in range(n)]
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def ring_place(parts, axis: int = 1, axis_name: str = "tp"):
-    """A list of per-slice arrays (or trees of them: one kernel places q, k
-    and v) in the ring's order -> the array with ``axis`` (where they have
-    their slice of S) tp times as long, every slice at its own offset, in
-    one pass (``ops/place.py``: the offsets
-    are known only on the chip, and XLA's ``dynamic_update_slice`` into
-    zeros is three passes that fuse into nothing). Its cotangent is
-    ``ring_split``'s result, and the reverse."""
-    return _placed(parts, axis, axis_name)
-
-
-ring_place.defvjp(
-    lambda parts, axis, axis_name: (_placed(parts, axis, axis_name), None),
-    lambda axis, axis_name, _, whole: (_split(whole, axis, axis_name),))
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def ring_split(whole, axis: int = 1, axis_name: str = "tp"):
-    """An array (or a tree of them) with S whole along ``axis`` -> the
-    list of its slices in the ring's order: element ``step`` is the slice
-    this chip has in hand at that ring step. Reads where they lie; the
-    cotangent is one ``ring_place``."""
-    return _split(whole, axis, axis_name)
-
-
-ring_split.defvjp(
-    lambda whole, axis, axis_name: (_split(whole, axis, axis_name), None),
-    lambda axis, axis_name, _, parts: (_placed(parts, axis, axis_name),))
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _sent_on(part, after, axis_name):
-    return jax.lax.ppermute(part, axis_name, _ring(axis_name)[2])
-
-
-def _sent_on_fwd(part, after, axis_name):
-    return _sent_on(part, after, axis_name), after
-
-
-def _sent_on_bwd(axis_name, after, arrived):
-    # The cotangent goes back the way the partial sum came, and not before
-    # ``after`` is there. A barrier none of whose results is used is
-    # dropped with the order it states: ``after``'s zero cotangent is made
-    # from the barrier's (one pass over ``after``: name something small).
-    arrived, after = jax.lax.optimization_barrier((arrived, after))
-    back = [(to, frm) for frm, to in _ring(axis_name)[2]]
-    one = after[(0,) * after.ndim]
-    zero = 0 * jnp.where(jnp.isfinite(one), one, 0)
-    return (jax.lax.ppermute(arrived, axis_name, back),
-            jnp.broadcast_to(zero, after.shape))
-
-
-_sent_on.defvjp(_sent_on_fwd, _sent_on_bwd)
-
-
-def _backward_after_inputs(fn):
-    """``fn(inputs, shared)`` with its values and gradients, whose backward
-    pass does not begin before ``inputs`` are there: in a rematerialised
-    block, before they are recomputed. (``shared``, the weights, stays out
-    of the barrier: through it they would be other values than the ones
-    every other product takes, gathered a second time.)"""
-    @jax.custom_vjp
-    def tied(inputs, shared):
-        return fn(inputs, shared)
-
-    def backward(args, cotangent):
-        inputs, shared = args
-        cotangent, inputs = jax.lax.optimization_barrier((cotangent, inputs))
-        return jax.vjp(fn, inputs, shared)[1](cotangent)
-
-    tied.defvjp(lambda *args: (fn(*args), args), backward)
-    return tied
-
-
-def scattered_product(product, slices, shared, after=None,
-                      axis_name: str = "tp"):
-    """The sum over the ring of every chip's ``product(slices[step],
-    shared)`` for this chip's own slice of S: ``slices[step]`` is what the
-    product takes of the slice of ring step ``step``, ``shared`` what it
-    takes every time (the weights), and its result [b, S / tp, ...] this
-    chip's partial product for that slice. The other chips' slices come
-    first, the farthest first: each partial sum is sent on while the next
-    slice is multiplied, and this chip's own slice is multiplied last and
-    added to what arrives. For tp = 2 the two addends an all-reduce would
-    add, in the products' dtype as it adds them.
-
-    In the backward pass the cotangent of this chip's rows is there when
-    the layer's backward begins. Left alone, it is sent round the other
-    way at once, and the products of its own slice run at once: a transfer
-    in flight and matmuls carrying weight gathers, beside a rematerialised
-    block's first matmuls, which then wait for both
-    (``gathered_product``). So the own slice's products wait for their
-    forward inputs to be recomputed, and the cotangent is sent once
-    ``after`` (an array of the forward pass: name one that is there when
-    the forward's own exchange has landed) is."""
-    n, _, onward = _ring(axis_name)
-    arriving = None
-    for step in [*range(1, n), 0]:
-        if step:
-            part = product(slices[step], shared)
-        else:
-            part = _backward_after_inputs(product)(slices[0], shared)
-        if arriving is not None:
-            # The product is whole before what arrives is added to it:
-            # fused into the matmul, the addition makes the matmul wait for
-            # the arrival it was to run beside.
-            part, arriving = jax.lax.optimization_barrier((part, arriving))
-            part = part + arriving
-        if step:
-            arriving = jax.lax.ppermute(part, axis_name, onward) \
-                if after is None else _sent_on(part, after, axis_name)
-    return part
-
-
-def tp_exchange_mesh(cfg, seq_len: int):
-    """The current mesh if a block may take its tp traffic as exchanges of
-    slices of S over it, else None: a tp axis above 1 that divides S, to
-    which the rules give the residual stream's S (``stream``), the heads
-    and the MLP's width, no other axis splitting S (context parallelism has
-    its own attention), and the attention whose kernels run per shard on
-    whole sequences (``flash``). What the code can see of the mesh, the
-    rules and the shape; nothing is configured."""
-    from ray_tpu.parallel.mesh import current_mesh
-    mesh = current_mesh()
-    if mesh is None or cfg.attn_impl != "flash":
-        return None
-    sizes = dict(mesh.shape)
-    tp = sizes.get("tp", 1)
-    if tp == 1 or seq_len % tp:
-        return None
-
-    def axes(name):  # one name a call: an axis two names share stays with one
-        return _axes(ambient_spec(mesh, name)[0])
-
-    if math.prod(sizes[a] for a in axes("sequence")) > 1:
-        return None
-    return mesh if all(axes(name) == ("tp",) for name in (
-        "stream", "heads", "kv_heads", "mlp")) else None
-
-
-def exchanged_over_tp(block, mesh, layers, layer_specs):
-    """``(block, layers)`` for ``scan_blocks``: ``block(x, layer,
-    positions)`` per shard of tp alone, over the stacked ``layers``
-    prepared for it. x enters and leaves as this chip's slice of S
-    [B, S / tp, d], a layer's leaves as ``layer_specs`` (the stacked
-    leaves' PartitionSpecs, the stack's leading axis first) split them
-    over tp, positions whole. Every other axis of the mesh stays the
-    partitioner's: the batch over dp and fsdp, the weights' gathers over
-    fsdp and their gradients' reductions come out as they do without this.
-
-    A leaf tp does not split (a norm's vectors, a bias added after the
-    sum) is whole on every chip, each of which sees its own rows, so its
-    gradient is summed over tp. Those stacks go in float32 (the rows' own
-    sum is; and in bfloat16 XLA's CPU backend aborts: its
-    AllReducePromotion cannot read the reduction this leaves) and whole
-    over every axis, gathered once before the scan: a few kB a layer, and
-    gathered in the body they are synchronous collectives that wait for
-    whatever slice is in flight (``gathered_product``)."""
-    from ray_tpu._private.jax_compat import shard_map
-
-    def tp_only(spec):
-        return PartitionSpec(*("tp" if "tp" in _axes(dim) else None
-                               for dim in spec[1:]))
-
-    stream = PartitionSpec(None, "tp", None)
-    specs = jax.tree.map(tp_only, layer_specs,
-                         is_leaf=lambda s: isinstance(s, PartitionSpec))
-    layers = jax.tree.map(
-        lambda leaf, spec: leaf if "tp" in spec else constrain(
-            leaf.astype(jnp.float32), *[None] * leaf.ndim), layers, specs)
-    return shard_map(block, mesh=mesh,
-                     in_specs=(stream, specs, PartitionSpec()),
-                     out_specs=(stream, None), axis_names=frozenset({"tp"}),
-                     check_vma=False), layers
 
 # -- state-space scan -----------------------------------------------------
 
@@ -622,18 +396,31 @@ def rmsnorm(x, scale, eps):
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, positions, theta: float):
-    """Rotary embedding over the whole last axis of x [B, S, H, D], pairing
-    dimension i with i + D / 2 (angle pos * theta^(-2i/D)), as published
-    for ``models/afmoe.py`` and ``models/lfm2.py``."""
+def _rope(x, positions, theta: float, pairs):
     half = x.shape[-1] // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
     cos, sin = jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
-    x32 = x.astype(jnp.float32)
-    first, second = x32[..., :half], x32[..., half:]
+    first, second = pairs(x.astype(jnp.float32), half)
     return jnp.concatenate([first * cos - second * sin,
                             second * cos + first * sin], -1).astype(x.dtype)
+
+
+def rope(x, positions, theta: float):
+    """Rotary embedding over the whole last axis of x [B, S, H, D], pairing
+    the halves: dimension i with i + D / 2 (angle pos * theta^(-2i/D)), as
+    published for ``models/afmoe.py`` and ``models/lfm2.py``."""
+    return _rope(x, positions, theta,
+                 lambda x, half: (x[..., :half], x[..., half:]))
+
+
+def rope_interleaved(x, positions, theta: float):
+    """Rotary embedding over the whole last axis of x [B, S, H, R], pairing
+    dimension 2i with 2i+1 (angle pos * theta^(-2i/R)) as published for
+    ``deepseek_v3``; the result holds all first members, then all second
+    (q and k alike, so scores are unchanged)."""
+    return _rope(x, positions, theta,
+                 lambda x, half: (x[..., 0::2], x[..., 1::2]))
 
 
 def swiglu(x, w_gate, w_up, w_down):
@@ -643,6 +430,187 @@ def swiglu(x, w_gate, w_up, w_down):
     up = jnp.einsum("...d,df->...f", x, w_up.astype(dt))
     return jnp.einsum("...f,fd->...d", jax.nn.silu(gate) * up,
                       w_down.astype(dt))
+
+
+def swiglu_leaves(d: int, width: int, prefix: str = ""):
+    """The leaves ``swiglu`` reads, as a family's table of leaves holds
+    them (``Decoder``)."""
+    return {prefix + "w_gate": ((d, width), ("embed", "mlp"), 0.02),
+            prefix + "w_up": ((d, width), ("embed", "mlp"), 0.02),
+            prefix + "w_down": ((width, d), ("mlp", "embed"), 0.02)}
+
+
+def mla_leaves(cfg):
+    """The leaves ``mla`` reads."""
+    d, h, rank = cfg.hidden_size, cfg.num_attention_heads, cfg.kv_lora_rank
+    heads = ("heads", "head_dim")
+    return {
+        "wq": ((d, h, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim),
+               ("embed",) + heads, 0.02),
+        "w_kv_a": ((d, rank + cfg.qk_rope_head_dim), ("embed", None), 0.02),
+        "kv_norm_scale": ((rank,), (None,), ones),
+        "w_kv_b": ((rank, h, cfg.qk_nope_head_dim + cfg.v_head_dim),
+                   (None,) + heads, 0.02),
+        "wo": ((h, cfg.v_head_dim, d), heads + ("embed",), 0.02),
+    }
+
+
+def mla(cfg, x, layer, positions):
+    """Multi-head latent attention (``deepseek_v3``'s, ``q_lora_rank`` null)
+    on normed x [B, S, d] -> [B, S, d], from a layer's ``wq``, ``w_kv_a``,
+    ``kv_norm_scale``, ``w_kv_b`` and ``wo``. cfg is any config with the
+    latent keys under their published names (``models/deepseek.py``,
+    ``models/kimi_linear.py``); with ``cfg.mla_use_nope`` the "rope"
+    dimensions of q and of the shared key go unrotated."""
+    dt = cfg.dtype
+    nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dhk->bshk", x, layer["wq"].astype(dt))
+    kv_a = jnp.einsum("bsd,dr->bsr", x, layer["w_kv_a"].astype(dt))
+    latent = rmsnorm(kv_a[..., :rank], layer["kv_norm_scale"],
+                     cfg.rms_norm_eps)
+    kv = jnp.einsum("bsr,rhk->bshk", latent, layer["w_kv_b"].astype(dt))
+    rotated = (lambda x: x) if cfg.mla_use_nope else partial(
+        rope_interleaved, positions=positions, theta=cfg.rope_theta)
+    q_rope = rotated(q[..., nope:])
+    k_rope = rotated(kv_a[..., None, rank:])
+    q = jnp.concatenate([q[..., :nope], q_rope], -1)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_rope, q_rope.shape)], -1)
+    attn = attention(q, k, kv[..., nope:], cfg)
+    return jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(dt))
+
+
+# -- expert layers --------------------------------------------------------
+
+def held_experts(held, experts: int):
+    """A config's ``experts_held`` checked against its number of experts:
+    (first, count) as a tuple, or None for all of them."""
+    if held is None:
+        return None
+    first, count = held = tuple(held)
+    if first < 0 or count < 1 or first + count > experts:
+        raise ValueError(f"experts_held={held} of {experts} experts")
+    return held
+
+
+def expert_leaves(d: int, experts: int, held, width: int,
+                  shared_width: int = 0):
+    """The leaves ``expert_ffn`` reads: the router over all ``experts`` and
+    its correction bias (the published ``expert_bias`` /
+    ``e_score_correction_bias``: a buffer of zeros that the gradient never
+    moves), the SwiGLUs of ``width`` of the experts ``held`` (a config's
+    ``experts_held``: (first, count), or None for all) and, with
+    ``shared_width``, the shared experts' as one SwiGLU."""
+    count = experts if held is None else held[1]
+    leaves = {
+        "router": ((d, experts), ("embed", None), 0.02),
+        "router_bias": ((experts,), (None,), zeros),
+        "w_gate": ((count, d, width), ("expert", "embed", "mlp"), 0.02),
+        "w_up": ((count, d, width), ("expert", "embed", "mlp"), 0.02),
+        "w_down": ((count, width, d), ("expert", "mlp", "embed"), 0.02),
+    }
+    if shared_width:
+        leaves.update(swiglu_leaves(d, shared_width, "shared_"))
+    return leaves
+
+
+def expert_aux(aux, batch_shape):
+    """What a block returns of an expert layer, from ``routed_experts``'
+    aux over the flattened tokens: ``picked`` [B, S, K], ``group_sizes``
+    [held experts], ``asked`` (assignments the router gave them) and
+    ``within_bound`` (1 where they fit ``ops/moe.py``'s one buffer). With
+    every expert held the router's assignments are all asked, and the one
+    buffer holds them."""
+    return {"picked": aux["picked"].reshape(*batch_shape, -1),
+            "group_sizes": aux["group_sizes"],
+            "asked": aux.get("asked", jnp.int32(aux["picked"].size)),
+            "within_bound": aux.get("within_bound", jnp.int32(1))}
+
+
+def expert_ffn(x, layer, *, top_k: int, scaling: float, normalize: bool,
+               held):
+    """The expert layer with shared experts on normed x [B, S, d] from a
+    layer's leaves (``router``, ``router_bias``, the held experts'
+    ``w_gate`` / ``w_up`` / ``w_down``, ``shared_*``): (this chip's part of
+    the routed sum, the shared experts, ``expert_aux``), the sums [B, S, d],
+    for the caller to add in its own order. ``held`` is ``ops/moe.py``'s."""
+    from ray_tpu.ops.moe import routed_experts
+    B, S, d = x.shape
+    routed, aux = routed_experts(
+        x.reshape(B * S, d), layer["router"], layer["router_bias"],
+        layer["w_gate"], layer["w_up"], layer["w_down"],
+        top_k=top_k, scaling=scaling, normalize=normalize, held=held)
+    with jax.named_scope("shared_expert"):
+        shared = swiglu(x, layer["shared_w_gate"], layer["shared_w_up"],
+                        layer["shared_w_down"])
+    aux = expert_aux(aux, (B, S))
+    return routed.reshape(B, S, d), shared, aux
+
+
+def no_expert_parallelism(family: str):
+    """Raises on a mesh with ``ep`` > 1: no family here exchanges tokens."""
+    from ray_tpu.parallel.mesh import current_mesh
+    mesh = current_mesh()
+    if mesh is not None and mesh.shape.get("ep", 1) > 1:
+        raise NotImplementedError(
+            f"models/{family}.py does not implement expert parallelism: the "
+            "mesh has ep > 1, and the expert layer (ops/moe.py) computes "
+            "the experts held here without an exchange. Use ep=1 (fsdp and "
+            "tp shard the expert weights).")
+
+
+#: Metrics of ``moe_metrics`` that count a batch: summed over accumulation
+#: microbatches where the others are averaged (parallel/train_step.py reads
+#: ``SUMMED_METRICS`` and ``RECORDED_METRICS`` off the module that defines
+#: a config's type, so a family binds these there).
+SUMMED_METRICS = ("moe_assignments", "moe_tokens", "moe_routed",
+                  "moe_calls", "moe_calls_within_bound")
+
+#: Metrics of ``moe_metrics`` that feed the registry, each with what records
+#: its value there (parallel/train_step.py reads them without a sync).
+RECORDED_METRICS = {
+    "moe_assignments":
+        lambda value: builtin_metrics.train_moe_assignments().inc(value),
+    "moe_tokens":
+        lambda value: builtin_metrics.train_moe_tokens().inc(value),
+    "moe_routed":
+        lambda value: builtin_metrics.train_moe_routed().inc(value),
+    "moe_calls":
+        lambda value: builtin_metrics.train_moe_calls().inc(value),
+    "moe_calls_within_bound":
+        lambda value: builtin_metrics.train_moe_calls_within_bound().inc(
+            value),
+    "moe_load_max_over_mean":
+        lambda value: builtin_metrics.train_moe_expert_load().set(value),
+}
+
+
+def moe_metrics(aux, routed_a_layer: int) -> Dict[str, jax.Array]:
+    """What the expert layers did, from ``Decoder.hidden_states``' aux
+    (``expert_aux`` stacked over the expert layers): ``moe_routed`` (every
+    assignment the router made: ``routed_a_layer``, tokens x experts per
+    token, times the expert layers), ``moe_tokens`` (those it gave to
+    experts held here), ``moe_assignments`` (rows the grouped matmuls
+    computed: equal to ``moe_tokens``, or something was dropped),
+    ``moe_calls`` and ``moe_calls_within_bound`` (expert layers, and those
+    whose share fit one buffer) and ``moe_load_max_over_mean`` (the busiest
+    held expert's load over the held experts' mean, worst layer). {} of a
+    model without an expert layer."""
+    if "group_sizes" not in aux:
+        return {}
+    sizes = aux["group_sizes"].astype(jnp.float32)  # [L_moe, held]
+    calls = sizes.shape[0]
+    return {
+        "moe_assignments": sizes.sum(),
+        "moe_tokens": aux["asked"].astype(jnp.float32).sum(),
+        "moe_routed": jnp.float32(routed_a_layer * calls),
+        "moe_calls": jnp.float32(calls),
+        "moe_calls_within_bound":
+            aux["within_bound"].astype(jnp.float32).sum(),
+        "moe_load_max_over_mean": (
+            sizes.max(-1) / jnp.maximum(sizes.mean(-1), 1e-9)).max(),
+    }
 
 
 # -- head and loss --------------------------------------------------------
@@ -732,3 +700,183 @@ def next_token_loss(head, x: jax.Array, targets: jax.Array,
     loss = nll_sum / denom
     return loss, {"loss": loss, "accuracy": hit_sum / denom,
                   "perplexity": jnp.exp(jnp.minimum(loss, 20.0))}
+
+
+# -- the decoder's shell --------------------------------------------------
+
+def ones(key, shape):
+    return jnp.ones(shape, jnp.float32)
+
+
+def zeros(key, shape):
+    return jnp.zeros(shape, jnp.float32)
+
+
+def _drawn(key, shape, init, dtype):
+    """One leaf of a table: ``init`` is the std of a normal draw, or a
+    callable ``(key, shape) -> float32 array`` for anything else."""
+    if callable(init):
+        return init(key, shape).astype(dtype)
+    return (jax.random.normal(key, shape, jnp.float32) * init).astype(dtype)
+
+
+def merged_aux(auxes) -> Dict[str, jax.Array]:
+    """One aux for the model from ``scan_blocks``' list of every run's
+    (each stacked over the run's layers, or None): under every name any run
+    returns (sorted, as a scan hands a dict back), the concatenation over
+    the runs that return it, in layer order."""
+    auxes = [aux for aux in auxes if aux]
+    return {name: jnp.concatenate([aux[name] for aux in auxes if name in aux])
+            for name in sorted({name for aux in auxes for name in aux})}
+
+
+@dataclass(frozen=True)
+class Decoder:
+    """What a family hands this module to be a language model (the module
+    text, "A family"); the methods are then the same code for all of them,
+    bound in the family's module under the names the benchmark reads."""
+    #: The family's module under ``models/``, for messages.
+    name: str
+    #: cfg -> the table of leaves, in any grouping ``leaves_of`` reads.
+    shapes: Callable
+    #: (cfg, kind, h, layer, positions) -> (h, aux or None). A family hands
+    #: it over as ``lambda *args: _block(*args)``: the ``_block`` its module
+    #: holds when the model is traced, which tests and the benchmark's
+    #: fault checks replace.
+    block: Callable
+    #: (table, kind) -> {leaf: (shape without the layers axis, logical
+    #: axes, init)} of one layer of ``kind``, in the order they are drawn:
+    #: the table's entry of that name, unless a layer is made of several.
+    leaves_of: Callable = lambda table, kind: table[kind]
+    #: cfg -> ((name in the parameter tree, kind, layers) of every run).
+    runs_of: Callable = lambda cfg: runs(cfg.layers)
+    #: The final norm's leaf, and the config key of every norm's epsilon.
+    final_norm: str = "lnf_scale"
+    eps: str = "rms_norm_eps"
+    #: The head is ``wte``'s rows again, else a matrix ``lm_head``.
+    tied: bool = False
+    #: cfg -> what the looked-up rows are multiplied by (or None), and what
+    #: the final hidden states are divided by before the head.
+    embed_scale: Optional[Callable] = None
+    logits_divisor: Optional[Callable] = None
+    #: The family has expert layers: a mesh with ``ep`` > 1 is refused.
+    experts: bool = False
+    #: (cfg, aux, targets) -> the metrics ``loss_fn`` adds to the loss's.
+    metrics: Optional[Callable] = None
+
+    def _top(self, cfg):
+        """The leaves outside the layer stacks, as a table."""
+        v, d = cfg.vocab_size, cfg.hidden_size
+        top = {"wte": ((v, d), ("vocab", "embed"), 0.02),
+               self.final_norm: ((d,), ("embed",), ones)}
+        if not self.tied:
+            top["lm_head"] = ((d, v), ("embed", "vocab"), 0.02)
+        return top
+
+    def _stacks(self, cfg):
+        """[(name in the parameter tree, layers, one layer's leaves)] of
+        every run."""
+        table = self.shapes(cfg)
+        return [(run, depth, self.leaves_of(table, kind))
+                for run, kind, depth in self.runs_of(cfg)]
+
+    def init(self, cfg, key: jax.Array) -> Dict[str, Any]:
+        """Parameters in ``cfg.param_dtype`` as the tables say. ``key`` is
+        split once a drawn leaf outside the stacks (``wte``, then
+        ``lm_head`` where there is one) and once for the stacks; run
+        ``index``'s key is ``fold_in`` of that by ``index``, split once a
+        leaf in the table's order. Every run of one kind of layer is a
+        stack of its own, over a leading layers axis. A seed's parameters
+        are a contract (tests/test_init_pinned.py)."""
+        top = self._top(cfg)
+        drawn = [name for name in top if name != self.final_norm]
+        *k_top, k_layers = jax.random.split(key, len(drawn) + 1)
+        keys = dict(zip(drawn, k_top))
+        params = {name: _drawn(keys.get(name), shape, init, cfg.param_dtype)
+                  for name, (shape, _, init) in top.items()}
+        for index, (run, depth, leaves) in enumerate(self._stacks(cfg)):
+            keys = jax.random.split(jax.random.fold_in(k_layers, index),
+                                    len(leaves))
+            params[run] = {
+                name: _drawn(k, (depth,) + shape, init, cfg.param_dtype)
+                for k, (name, (shape, _, init)) in zip(keys, leaves.items())}
+        return params
+
+    def param_specs(self, cfg, rules: ShardingRules) -> Dict[str, Any]:
+        """PartitionSpec pytree matching init()'s structure."""
+        specs = {name: rules.spec(*axes)
+                 for name, (_, axes, _) in self._top(cfg).items()}
+        for run, _, leaves in self._stacks(cfg):
+            specs[run] = {name: rules.spec("layers", *axes)
+                          for name, (_, axes, _) in leaves.items()}
+        return specs
+
+    def hidden_states(self, params: Dict[str, Any], cfg, tokens: jax.Array,
+                      positions: Optional[jax.Array] = None):
+        """tokens [B, S] int32 -> (final-normed hidden [B, S, d], aux): what
+        the blocks return (``merged_aux``), each name stacked over the
+        layers that return it, in layer order."""
+        if self.experts:
+            no_expert_parallelism(self.name)
+        if positions is None:
+            positions = positions_of(tokens)
+        x = embed(params["wte"], tokens, cfg.dtype)  # batch-split
+        scale = self.embed_scale(cfg) if self.embed_scale else None
+        if scale is not None:
+            x = x * jnp.asarray(scale, cfg.dtype)
+        # A stack without layers (a model with no leading dense layer)
+        # is in the tree and not in the scan.
+        stacks = [run for run in self.runs_of(cfg) if run[2]]
+        x, auxes = scan_blocks(
+            cfg, {kind: partial(self.block, cfg, kind)
+                  for _, kind, _ in stacks}, x,
+            [params[run] for run, _, _ in stacks], positions,
+            runs=[(kind, depth) for _, kind, depth in stacks])
+        x = constrain(x, "batch", "sequence", None)
+        aux = merged_aux(auxes)
+        return rmsnorm(x, params[self.final_norm], getattr(cfg, self.eps)), \
+            aux
+
+    def head(self, params: Dict[str, Any], cfg, x: jax.Array):
+        """Logits [..., vocab] of final-normed hidden states x [..., d].
+        Where the logits are divided the hidden states are, not the
+        logits: the same numbers, and no second pass over a [tokens, vocab]
+        array."""
+        if self.logits_divisor:
+            x = x / jnp.asarray(self.logits_divisor(cfg), x.dtype)
+        if self.tied:
+            return jnp.einsum("...d,vd->...v", x,
+                              params["wte"].astype(cfg.dtype))
+        return jnp.einsum("...d,dv->...v", x,
+                          params["lm_head"].astype(cfg.dtype))
+
+    def forward_with_aux(self, params: Dict[str, Any], cfg,
+                         tokens: jax.Array,
+                         positions: Optional[jax.Array] = None):
+        """tokens [B, S] -> (logits [B, S, vocab], aux of
+        ``hidden_states``)."""
+        x, aux = self.hidden_states(params, cfg, tokens, positions)
+        return self.head(params, cfg, x), aux
+
+    def forward(self, params: Dict[str, Any], cfg, tokens: jax.Array,
+                positions: Optional[jax.Array] = None) -> jax.Array:
+        return self.forward_with_aux(params, cfg, tokens, positions)[0]
+
+    def loss_of_hidden(self, params: Dict[str, Any], cfg, x: jax.Array, aux,
+                       targets: jax.Array, mask: Optional[jax.Array] = None
+                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """``loss_fn`` from ``hidden_states``' result (x [B, S, d], aux)."""
+        loss, metrics = next_token_loss(
+            partial(self.head, head_gathered(params, self.tied), cfg), x,
+            targets, mask, cfg.loss_chunk, 0.0)
+        if self.metrics:
+            metrics = {**metrics, **self.metrics(cfg, aux, targets)}
+        return loss, metrics
+
+    def loss_fn(self, params: Dict[str, Any], cfg, tokens: jax.Array,
+                targets: jax.Array, mask: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """Next-token cross-entropy in fp32 (chunked by ``cfg.loss_chunk``),
+        no balance term, with the family's ``metrics``."""
+        x, aux = self.hidden_states(params, cfg, tokens)
+        return self.loss_of_hidden(params, cfg, x, aux, targets, mask)

@@ -1,7 +1,7 @@
 """A dropless routed-expert layer: sort, group sizes, ragged matmuls.
 
 ``routed_experts`` is the routed half of a DeepSeek-V3-style expert layer
-(``models/deepseek.py`` adds the shared expert). Every token's ``top_k``
+(``lm.expert_ffn`` adds the shared expert). Every token's ``top_k``
 assignments are computed: there is no capacity and nothing is dropped, and
 no tensor of ``tokens x experts x capacity`` exists at any size. The
 ``tokens x top_k`` assignments are sorted by expert (a stable sort), each

@@ -4,8 +4,9 @@
 times as long along ``axis`` whose block ``slots[t]`` there is the t-th
 slice:
 what a ``concatenate`` is when the order is static. A ring exchange over a
-mesh axis (``models/lm.py: gathered_product``) hands a chip the slices of S
-in an order that starts at its own place on the ring, so the order is data.
+mesh axis (``models/exchange.py: gathered_product``) hands a chip the slices
+of S in an order that starts at its own place on the ring, so the order is
+data.
 XLA's forms of it cost three to ten passes over the result on a v5e (zeros
 and one ``dynamic_update_slice`` a slice: 1.40 ms for 134 MB, and the
 updates do not fuse into the matmuls that make the slices; a ``select`` a

@@ -26,12 +26,13 @@ class ShardingRules:
     batch, q / k / v, the loss): ``"sp"`` under context parallelism, whose
     attention works on slices of S. ``stream`` is S of the residual stream
     alone, between blocks, for a model whose block takes slices of S in and
-    gives slices back (``models/lm.py: exchanged_over_tp``): over tp, the
-    axis the block's weights are split over, so that the block's sum over
-    tp and the gather that undoes it become exchanges of slices. A model
-    states the stream under it only where ``lm.tp_exchange_mesh`` finds the
-    mesh and the shape fit (tp above 1 and dividing S, ``sequence`` over
-    no axis); everywhere else the stream's S is ``sequence``."""
+    gives slices back (``models/exchange.py: exchanged_over_tp``): over tp,
+    the axis the block's weights are split over, so that the block's sum
+    over tp and the gather that undoes it become exchanges of slices. A
+    model states the stream under it only where
+    ``exchange.tp_exchange_mesh`` finds the mesh and the shape fit (tp above
+    1 and dividing S, ``sequence`` over no axis); everywhere else the
+    stream's S is ``sequence``."""
 
     batch: MeshAxes = ("dp", "fsdp")
     sequence: MeshAxes = None  # set to "sp" for context parallelism

@@ -114,10 +114,9 @@ def both_flash():
 
 
 def test_the_tiny_stack_has_all_four_kinds_of_layer():
-    assert [kind for _, kind, _ in afmoe.runs(CFG.layers)] == [
+    assert [kind for _, kind, _ in lm.runs(CFG.layers)] == [
         "dense_sliding_attention", "dense_full_attention",
         "moe_sliding_attention", "moe_full_attention"]
-    assert set(CFG.layers) == set(afmoe.KINDS)
     assert CFG.sliding_window < SEQ and FLASH.sliding_window < FLASH_SEQ
 
 
@@ -155,7 +154,7 @@ def test_gradients_match_the_reference(which, leaf, request):
 
 def _in_every_run(params, cfg, change):
     return dict(params, **{run: change(dict(params[run]))
-                           for run, _, _ in afmoe.runs(cfg.layers)})
+                           for run, _, _ in lm.runs(cfg.layers)})
 
 
 @pytest.mark.parametrize("dropped", [
@@ -169,7 +168,7 @@ def test_a_dropped_term_shows(both, dropped, monkeypatch):
     if dropped == "window":
         cfg = replace(CFG, sliding_window=10 ** 6)
     elif dropped == "rope":
-        monkeypatch.setattr(afmoe, "_rope", lambda x, positions, theta: x)
+        monkeypatch.setattr(lm, "rope", lambda x, positions, theta: x)
     elif dropped == "rope_on_full":
         plain = afmoe._attention
         # A full layer as a window layer whose window holds everything.
@@ -183,9 +182,9 @@ def test_a_dropped_term_shows(both, dropped, monkeypatch):
         params = _in_every_run(params, CFG, lambda w: dict(
             w, w_attn_gate=jnp.zeros_like(w["w_attn_gate"])))
     elif dropped == "qk_norm":
-        plain = afmoe._rmsnorm
+        plain = lm.rmsnorm
         monkeypatch.setattr(
-            afmoe, "_rmsnorm", lambda x, scale, eps:
+            lm, "rmsnorm", lambda x, scale, eps:
             x if x.ndim == 4 else plain(x, scale, eps))
     elif dropped == "route_scale":
         cfg = replace(CFG, route_scale=1.0)
@@ -230,8 +229,8 @@ def test_the_shares_add_up_to_the_uncut_layer(count):
     top_k, scale = 4, 2.448
     with jax.default_matmul_precision("highest"):
         want, picked = reference._ffn(x, w, top_k, scale, True, 0)
-        shared = afmoe._swiglu(x, w["shared_w_gate"], w["shared_w_up"],
-                               w["shared_w_down"])
+        shared = lm.swiglu(x, w["shared_w_gate"], w["shared_w_up"],
+                           w["shared_w_down"])
         total, computed = shared, 0
         for first in range(0, 16, count):
             part, aux = routed_experts(
@@ -457,7 +456,7 @@ CUT = replace(afmoe.config("trinity-large-preview"), num_hidden_layers=5,
 def test_the_cut_configuration_is_four_runs():
     """The benchmark's cut: the dense layer a window layer as published
     layer 0 is, then one period of expert layers, 3 window : 1 full."""
-    assert afmoe.runs(CUT.layers) == (
+    assert lm.runs(CUT.layers) == (
         ("run00_dense_sliding_attention", "dense_sliding_attention", 1),
         ("run01_moe_sliding_attention", "moe_sliding_attention", 2),
         ("run02_moe_full_attention", "moe_full_attention", 1),
@@ -484,13 +483,13 @@ def test_scan_blocks_over_the_four_runs(remat):
         x = lm.embed(params["wte"], tokens, cfg.dtype) * math.sqrt(
             cfg.hidden_size)
         picked = []
-        for run, kind, depth in afmoe.runs(cfg.layers):
+        for run, kind, depth in lm.runs(cfg.layers):
             for j in range(depth):
                 x, one = afmoe._block(cfg, kind, x, jax.tree.map(
                     lambda a: a[j], params[run]), lm.positions_of(tokens))
                 if one is not None:
                     picked.append(one["picked"])
-    want = afmoe._rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps)
+    want = lm.rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps)
     np.testing.assert_allclose(got, want, atol=1e-4)
     assert (aux["picked"] == jnp.stack(picked)).all()
     assert aux["group_sizes"].shape == (cfg.n_moe_layers, cfg.num_experts)

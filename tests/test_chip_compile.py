@@ -587,7 +587,8 @@ def test_step_sends_what_fsdp_x_tp_needs(topo, parallel_block):
     layout's own traffic and no more. Between blocks the residual stream
     is split over tp along S, and the block's sum over tp and the gather
     that undoes it cross as exchanges of slices [B / fsdp, S / tp, d]
-    (``lm.gathered_product``, ``lm.scattered_product``): no all-reduce,
+    (``exchange.gathered_product``, ``exchange.scattered_product``):
+    no all-reduce,
     all-gather or reduce-scatter of the hidden shape in a scan body, every
     exchange a start and a done with matmuls scheduled between, and in the
     forward body no other collective between the two (a synchronous one

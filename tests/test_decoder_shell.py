@@ -1,0 +1,276 @@
+"""``models/lm.py``'s ``Decoder`` on a toy family defined here: two kinds of
+layer, one with aux. What every family gets from the shell and none tests
+for itself: the runs' names and depths, the parameter and spec trees from
+one table, the aux merged over runs of which only some return any, the head
+and loss's variants, and the refusal of an ``ep`` mesh."""
+
+from dataclasses import dataclass, replace
+from typing import Any, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import lm
+from ray_tpu.parallel import MeshConfig, build_mesh
+from ray_tpu.parallel.mesh import set_current_mesh
+from ray_tpu.parallel.sharding import ShardingRules
+
+
+@dataclass(frozen=True)
+class ToyConfig:
+    vocab_size: int = 32
+    hidden_size: int = 8
+    layers: Tuple[str, ...] = ("plain", "counted", "counted", "plain",
+                               "counted")
+    #: Every layer returns ``floor`` too (as Kimi's ``decay_floor``).
+    floors: bool = False
+    rms_norm_eps: float = 1e-5
+    multiplier: float = 3.0
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    remat: bool = False
+    remat_policy: str = "full"
+    loss_chunk: int = 0
+
+
+def _marks(key, shape):
+    """Every layer of a stack its own number, in each of three slots."""
+    return jnp.broadcast_to(
+        jnp.arange(1.0, shape[0] + 1)[:, None], shape)
+
+
+def _shapes(cfg):
+    d = cfg.hidden_size
+    plain = {"scale": ((d,), ("embed",), lm.ones),
+             "w": ((d, d), ("embed", "mlp"), 0.5)}
+    return {"plain": plain,
+            "counted": dict(plain, bias=((d,), (None,), lm.zeros),
+                            marks=((3,), (None,), _marks))}
+
+
+def _block(cfg, kind, h, layer, positions):
+    h = h + lm.rmsnorm(h, layer["scale"], cfg.rms_norm_eps) @ layer["w"]
+    aux = {"floor": -layer["scale"].sum()} if cfg.floors else {}
+    if kind == "counted":
+        aux["sizes"] = layer["marks"].astype(jnp.int32)
+        h = h + layer["bias"]
+    return h, aux or None
+
+
+def toy(**kw):
+    return lm.Decoder(name="toy", shapes=_shapes, block=_block, **kw)
+
+
+CFG = ToyConfig()
+LAYERINGS = [("plain",), ("counted",), CFG.layers,
+             ("counted", "counted", "plain"),
+             ("plain", "plain", "counted", "plain", "plain", "plain")]
+
+
+def _tokens(cfg, batch=2, seq=6):
+    return jax.random.randint(jax.random.PRNGKey(7), (batch, seq), 0,
+                              cfg.vocab_size)
+
+
+@pytest.mark.parametrize("layers", LAYERINGS, ids=lambda l: "-".join(l))
+def test_runs_are_named_and_as_deep_as_the_layers_say(layers):
+    cfg = replace(CFG, layers=layers)
+    runs = lm.runs(cfg.layers)
+    assert [name for name, _, _ in runs] == [
+        f"run{i:02d}_{kind}" for i, (_, kind, _) in enumerate(runs)]
+    assert [kind for _, kind, n in runs for _ in range(n)] == list(layers)
+    assert all(a[1] != b[1] for a, b in zip(runs, runs[1:]))
+    params = toy().init(cfg, jax.random.PRNGKey(0))
+    assert set(params) == {"wte", "lnf_scale", "lm_head"} | {
+        name for name, _, _ in runs}
+    for name, kind, depth in runs:
+        assert set(params[name]) == set(_shapes(cfg)[kind])
+        assert {leaf.shape[0] for leaf in params[name].values()} == {depth}
+
+
+@pytest.mark.parametrize("tied", [False, True])
+def test_parameters_and_specs_come_from_one_table(tied):
+    shell = toy(tied=tied, final_norm="out_norm_scale")
+    params = shell.init(CFG, jax.random.PRNGKey(3))
+    specs = shell.param_specs(CFG, ShardingRules())
+    assert jax.tree.structure(params) == jax.tree.structure(
+        specs, is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
+    assert ("lm_head" in params) != tied
+    for run, kind, depth in lm.runs(CFG.layers):
+        for name, (shape, axes, _) in _shapes(CFG)[kind].items():
+            assert params[run][name].shape == (depth,) + shape
+            assert len(specs[run][name]) == 1 + len(axes)
+    # The draws: a std, ones, zeros and a family's own callable; the key
+    # split as ``init`` says (the stacks take the last of the split).
+    keys = jax.random.split(jax.random.PRNGKey(3), 2 if tied else 3)
+    np.testing.assert_array_equal(params["wte"], 0.02 * jax.random.normal(
+        keys[0], (CFG.vocab_size, CFG.hidden_size)))
+    run, _, depth = lm.runs(CFG.layers)[1]
+    k_scale, k_w, k_bias, k_marks = jax.random.split(
+        jax.random.fold_in(keys[-1], 1), 4)
+    np.testing.assert_array_equal(params[run]["w"], 0.5 * jax.random.normal(
+        k_w, (depth, CFG.hidden_size, CFG.hidden_size)))
+    assert np.all(np.asarray(params[run]["scale"]) == 1)
+    assert np.all(np.asarray(params[run]["bias"]) == 0)
+    np.testing.assert_array_equal(params[run]["marks"][:, 0], [1.0, 2.0])
+    assert np.all(np.asarray(params["out_norm_scale"]) == 1)
+
+
+def _numbered(params, cfg):
+    """Every counted layer's marks set to its place in the model."""
+    place, out = 0, dict(params)
+    for run, kind, depth in lm.runs(cfg.layers):
+        if kind == "counted":
+            out[run] = dict(params[run], marks=jnp.broadcast_to(
+                (place + jnp.arange(depth, dtype=jnp.float32))[:, None],
+                (depth, 3)))
+        place += depth
+    return out
+
+
+@pytest.mark.parametrize("floors", [False, True], ids=["some", "every"])
+@pytest.mark.parametrize("layers", LAYERINGS, ids=lambda l: "-".join(l))
+def test_aux_is_merged_over_the_runs_that_return_it(layers, floors):
+    """``some``: runs without aux beside runs with (afmoe with its leading
+    dense run). ``every``: every run returns ``floor`` and some ``sizes``
+    (Kimi's ``decay_floor`` beside the expert layers' aux)."""
+    cfg = replace(CFG, layers=layers, floors=floors)
+    shell = toy()
+    params = _numbered(shell.init(cfg, jax.random.PRNGKey(0)), cfg)
+    _, aux = jax.jit(lambda p, t: shell.hidden_states(p, cfg, t))(
+        params, _tokens(cfg))
+    counted = [i for i, kind in enumerate(layers) if kind == "counted"]
+    assert set(aux) == ({"floor"} if floors else set()) | (
+        {"sizes"} if counted else set())
+    if counted:
+        np.testing.assert_array_equal(aux["sizes"][:, 0], counted)
+        assert aux["sizes"].shape == (len(counted), 3)
+    if floors:
+        assert aux["floor"].shape == (len(layers),)
+
+
+def test_merged_aux_is_the_sorted_union_each_name_in_layer_order():
+    one, two = jnp.ones((1, 2)), jnp.zeros((2, 2))
+    assert lm.merged_aux([None, None]) == {}
+    merged = lm.merged_aux([{"b": one}, None, {"a": two, "b": two},
+                            {"a": one}])
+    assert list(merged) == ["a", "b"]
+    np.testing.assert_array_equal(merged["a"], jnp.concatenate([two, one]))
+    np.testing.assert_array_equal(merged["b"], jnp.concatenate([one, two]))
+
+
+def _by_hand(params, cfg, tokens, scale=None):
+    x = params["wte"][tokens]
+    if scale is not None:
+        x = x * scale
+    for run, kind, depth in lm.runs(cfg.layers):
+        for i in range(depth):
+            x, _ = _block(cfg, kind, x, jax.tree.map(
+                lambda leaf: leaf[i], params[run]), None)
+    return lm.rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps)
+
+
+@pytest.mark.parametrize("variant", ["plain", "tied", "multiplier",
+                                     "divisor", "remat"])
+def test_the_forward_pass_is_the_blocks_in_order_and_the_head(variant):
+    cfg = replace(CFG, remat=variant == "remat")
+    shell = toy(
+        tied=variant == "tied",
+        embed_scale=(lambda cfg: cfg.multiplier)
+        if variant == "multiplier" else None,
+        logits_divisor=(lambda cfg: cfg.multiplier)
+        if variant == "divisor" else None)
+    params = shell.init(cfg, jax.random.PRNGKey(1))
+    tokens = _tokens(cfg)
+    hidden = _by_hand(params, cfg, tokens,
+                      cfg.multiplier if variant == "multiplier" else None)
+    if variant == "divisor":
+        hidden = hidden / cfg.multiplier
+    want = hidden @ (params["wte"].T if variant == "tied"
+                     else params["lm_head"])
+    logits, aux = shell.forward_with_aux(params, cfg, tokens)
+    np.testing.assert_allclose(logits, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(logits, shell.forward(params, cfg, tokens))
+    assert set(aux) == {"sizes"}
+
+
+@pytest.mark.parametrize("leading", [0, 2])
+def test_a_family_names_its_stacks_and_one_may_hold_no_layer(leading):
+    """DeepSeek's two stacks under their own names; with no leading dense
+    layer the first is in the tree, with no layers, and not in the scan."""
+    shell = toy(runs_of=lambda cfg: (("first", "plain", leading),
+                                     ("rest", "counted", 3)))
+    params = shell.init(CFG, jax.random.PRNGKey(2))
+    assert params["first"]["w"].shape[0] == leading
+    assert set(shell.param_specs(CFG, ShardingRules())["first"]) == {
+        "scale", "w"}
+    cfg = replace(CFG, layers=("plain",) * leading + ("counted",) * 3)
+    by_kind = dict(params, **{run: params[stack] for (run, _, _), stack in zip(
+        lm.runs(cfg.layers), ("first", "rest") if leading else ("rest",))})
+    hidden, aux = shell.hidden_states(params, CFG, _tokens(CFG))
+    np.testing.assert_allclose(
+        hidden, _by_hand(by_kind, cfg, _tokens(CFG)), rtol=1e-5, atol=1e-5)
+    assert aux["sizes"].shape == (3, 3)
+
+
+@pytest.mark.parametrize("loss_chunk", [0, 4])
+def test_the_loss_carries_the_familys_metrics(loss_chunk):
+    cfg = replace(CFG, loss_chunk=loss_chunk)
+    shell = toy(metrics=lambda cfg, aux, targets: {
+        "toy_rows": aux["sizes"].sum() + targets.size})
+    params = _numbered(shell.init(cfg, jax.random.PRNGKey(2)), cfg)
+    tokens = _tokens(cfg)
+    targets = jnp.roll(tokens, -1, axis=1)
+    mask = jnp.ones(tokens.shape).at[:, -1].set(0)
+    loss, metrics = shell.loss_fn(params, cfg, tokens, targets, mask)
+    x, aux = shell.hidden_states(params, cfg, tokens)
+    again, _ = shell.loss_of_hidden(params, cfg, x, aux, targets, mask)
+    logp = jax.nn.log_softmax(shell.forward(params, cfg, tokens))
+    nll = -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
+    np.testing.assert_allclose(loss, (nll * mask).sum() / mask.sum(),
+                               rtol=1e-5)
+    np.testing.assert_allclose(loss, again, rtol=1e-6)
+    assert set(metrics) == {"loss", "accuracy", "perplexity", "toy_rows"}
+    assert int(metrics["toy_rows"]) == 3 * (1 + 2 + 4) + tokens.size
+    assert set(toy().loss_fn(params, cfg, tokens, targets)[1]) == {
+        "loss", "accuracy", "perplexity"}
+
+
+@pytest.mark.parametrize("experts", [True, False])
+def test_an_ep_mesh_is_refused_by_name_where_there_are_experts(experts):
+    mesh = build_mesh(MeshConfig(dp=1, fsdp=1, tp=1, ep=2),
+                      devices=jax.devices()[:2])
+    shell = toy(experts=experts)
+    params = shell.init(CFG, jax.random.PRNGKey(0))
+    set_current_mesh(mesh)
+    try:
+        if experts:
+            with pytest.raises(NotImplementedError,
+                               match=r"models/toy\.py does not implement "
+                                     "expert parallelism"):
+                shell.hidden_states(params, CFG, _tokens(CFG))
+        else:
+            shell.hidden_states(params, CFG, _tokens(CFG))
+    finally:
+        set_current_mesh(None)
+
+
+def test_moe_metrics_count_what_expert_aux_says():
+    picked = jnp.zeros((12, 2), jnp.int32)
+    whole = lm.expert_aux({"picked": picked,
+                           "group_sizes": jnp.array([20, 4])}, (3, 4))
+    assert whole["picked"].shape == (3, 4, 2)
+    assert int(whole["asked"]) == 24 and int(whole["within_bound"]) == 1
+    share = lm.expert_aux({"picked": picked, "group_sizes": jnp.array([5, 4]),
+                           "asked": jnp.int32(10),
+                           "within_bound": jnp.int32(0)}, (3, 4))
+    aux = {name: jnp.stack([whole[name], share[name]]) for name in whole}
+    metrics = {k: float(v) for k, v in lm.moe_metrics(aux, 24).items()}
+    assert metrics == {
+        "moe_assignments": 33.0, "moe_tokens": 34.0, "moe_routed": 48.0,
+        "moe_calls": 2.0, "moe_calls_within_bound": 1.0,
+        "moe_load_max_over_mean": pytest.approx(20 / 12)}
+    assert set(lm.SUMMED_METRICS) < set(metrics) == set(lm.RECORDED_METRICS)
+    assert lm.moe_metrics({"floor": jnp.zeros(3)}, 24) == {}

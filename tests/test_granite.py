@@ -130,7 +130,7 @@ def test_a_dropped_term_shows(both, dropped):
     def changed(kind, change):
         return dict(params, **{
             run: change(dict(params[run]))
-            for run, run_kind, _ in granite.runs(CFG.layers)
+            for run, run_kind, _ in lm.runs(CFG.layers)
             if run_kind == kind})
 
     if dropped in ("D", "conv_b"):
@@ -199,7 +199,7 @@ def test_the_reference_is_the_published_implementation(monkeypatch):
              "model.norm.weight": t(params["lnf_scale"])}
     d = cfg.hidden_size
     stacks = [(kind, jax.tree.map(lambda a: a[j], params[run]))
-              for run, kind, n in granite.runs(cfg.layers) for j in range(n)]
+              for run, kind, n in lm.runs(cfg.layers) for j in range(n)]
     for i, (kind, w) in enumerate(stacks):
         pre = f"model.layers.{i}."
         state[pre + "input_layernorm.weight"] = t(w["ln1_scale"])
@@ -285,8 +285,7 @@ def test_scan_blocks_over_mixed_layer_types(layer_types, remat):
     x0 = jax.random.normal(ks[-1], (4, 8))
 
     def scanned(layers):
-        x, auxes = lm.scan_blocks(cfg, blocks, x0, layers, 0.5,
-                                  layer_types=layer_types)
+        x, auxes = lm.scan_blocks(cfg, blocks, x0, layers, 0.5, runs=runs)
         return x.sum(), auxes
 
     def one_by_one(layers):
@@ -305,8 +304,7 @@ def test_scan_blocks_over_mixed_layer_types(layer_types, remat):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
     assert [aux is None for aux in auxes] == [k == "a" for k, _ in runs]
     with pytest.raises(ValueError):
-        lm.scan_blocks(cfg, blocks, x0, layers[:-1], 0.5,
-                       layer_types=layer_types)
+        lm.scan_blocks(cfg, blocks, x0, layers[:-1], 0.5, runs=runs)
 
 
 def test_trains_through_the_train_step_typed_to_no_model():

@@ -120,9 +120,8 @@ def both_flash():
 
 
 def test_the_tiny_stack_has_all_four_kinds_of_layer():
-    assert [kind for _, kind, _ in kimi_linear.runs(CFG.layers)] == [
+    assert [kind for _, kind, _ in lm.runs(CFG.layers)] == [
         "dense_kda", "dense_mla", "moe_kda", "moe_mla"]
-    assert set(CFG.layers) == set(kimi_linear.KINDS)
     # The delta rule's kernels run (interpreted): heads of 128, whole chunks.
     from ray_tpu.parallel.collectives import kernel_census
     tokens, _ = batch(CFG)
@@ -165,7 +164,7 @@ def test_gradients_match_the_reference(which, leaf, request):
 
 def _in_every_run(params, cfg, change):
     return dict(params, **{run: change(dict(params[run]))
-                           for run, _, _ in kimi_linear.runs(cfg.layers)})
+                           for run, _, _ in lm.runs(cfg.layers)})
 
 
 def _kda_with(monkeypatch, **changed):
@@ -261,7 +260,7 @@ def test_the_shares_add_up_to_the_uncut_layer(shares):
         for first in range(0, 16, count):
             share = dict(w, **{name: w[name][first:first + count]
                                for name in ("w_gate", "w_up", "w_down")})
-            routed, shared, aux = deepseek.expert_ffn(
+            routed, shared, aux = lm.expert_ffn(
                 x, share, top_k=top_k, scaling=scale, normalize=True,
                 held=(first, count))
             mine = ((picked >= first) & (picked < first + count)).sum()
@@ -317,22 +316,21 @@ def test_mla_use_nope_off_is_deepseek_as_it_was():
         nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
         q = jnp.einsum("bsd,dhk->bshk", x, layer["wq"])
         kv_a = jnp.einsum("bsd,dr->bsr", x, layer["w_kv_a"])
-        latent = deepseek.rmsnorm(kv_a[..., :rank], layer["kv_norm_scale"],
-                                  cfg.rms_norm_eps)
+        latent = lm.rmsnorm(kv_a[..., :rank], layer["kv_norm_scale"],
+                            cfg.rms_norm_eps)
         kv = jnp.einsum("bsr,rhk->bshk", latent, layer["w_kv_b"])
-        q_rope = deepseek._rope(q[..., nope:], positions, cfg.rope_theta)
-        k_rope = deepseek._rope(kv_a[..., None, rank:], positions,
-                                cfg.rope_theta)
+        q_rope = lm.rope_interleaved(q[..., nope:], positions, cfg.rope_theta)
+        k_rope = lm.rope_interleaved(kv_a[..., None, rank:], positions,
+                                     cfg.rope_theta)
         q = jnp.concatenate([q[..., :nope], q_rope], -1)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rope, q_rope.shape)], -1)
         attn = lm.attention(q, k, kv[..., nope:], cfg)
         return jnp.einsum("bshk,hkd->bsd", attn, layer["wo"])
 
-    got = jax.jit(lambda x: deepseek.mla(cfg, x, layer, positions))(x)
+    got = jax.jit(lambda x: lm.mla(cfg, x, layer, positions))(x)
     assert (got == jax.jit(as_it_was)(x)).all()
-    without = deepseek.mla(replace(cfg, mla_use_nope=True), x, layer,
-                           positions)
+    without = lm.mla(replace(cfg, mla_use_nope=True), x, layer, positions)
     assert float(jnp.abs(without - got).max()) > 1e-3
 
 
@@ -406,7 +404,7 @@ CUT = replace(kimi_linear.config("kimi-linear-48b-a3b"), num_hidden_layers=5,
 def test_the_cut_configuration_is_four_runs():
     """The benchmark's cut: published layers 1-5, the dense layer a KDA
     layer, then one period of expert layers, 3 KDA : 1 latent."""
-    assert kimi_linear.runs(CUT.layers) == (
+    assert lm.runs(CUT.layers) == (
         ("run00_dense_kda", "dense_kda", 1), ("run01_moe_kda", "moe_kda", 2),
         ("run02_moe_mla", "moe_mla", 1), ("run03_moe_kda", "moe_kda", 1))
     shapes = jax.eval_shape(partial(kimi_linear.init, CUT),
@@ -433,14 +431,14 @@ def test_scan_blocks_over_the_runs(remat):
         got, aux = kimi_linear.hidden_states(params, cfg, tokens)
         x = lm.embed(params["wte"], tokens, cfg.dtype)
         picked, floors = [], []
-        for run, kind, depth in kimi_linear.runs(cfg.layers):
+        for run, kind, depth in lm.runs(cfg.layers):
             for j in range(depth):
                 x, one = kimi_linear._block(cfg, kind, x, jax.tree.map(
                     lambda a: a[j], params[run]), lm.positions_of(tokens))
                 floors.append(one["decay_floor"])
                 if "picked" in one:
                     picked.append(one["picked"])
-    want = deepseek.rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps)
+    want = lm.rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps)
     np.testing.assert_allclose(got, want, atol=1e-4)
     assert (aux["picked"] == jnp.stack(picked)).all()
     np.testing.assert_allclose(aux["decay_floor"], jnp.stack(floors),

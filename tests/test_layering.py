@@ -1,7 +1,8 @@
 """Which way the imports point between ``ray_tpu/models``, ``parallel`` and
 ``ops``, read off the source (no JAX, nothing imported): models are built
-from ``models/lm.py``, ``parallel/`` and ``ops/``; none of those three knows
-a model, and no model reaches for another's private names."""
+from ``models/lm.py`` (with its sibling ``models/exchange.py``),
+``parallel/`` and ``ops/``; none of those knows a model, and no model
+imports another: ``lm.py`` <- family, nothing sideways."""
 
 import ast
 import pathlib
@@ -9,8 +10,9 @@ import pathlib
 import pytest
 
 PACKAGE = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu"
+SHARED = ("lm", "exchange")
 MODELS = sorted(p.stem for p in (PACKAGE / "models").glob("*.py")
-                if p.stem not in ("__init__", "lm"))
+                if p.stem not in ("__init__",) + SHARED)
 
 
 def _imports(path):
@@ -30,12 +32,12 @@ def _imports(path):
 
 
 def _model_modules(path):
-    """{name bound in the file: model it names} for every import of a
-    module of ray_tpu.models other than lm."""
+    """{name bound in the file: model it comes from} for every import of, or
+    from, a module of ray_tpu.models other than lm."""
     bound = {}
     for module, name, bound_as in _imports(path):
         model = name if module == "ray_tpu.models" else \
-            module.removeprefix("ray_tpu.models.") if name is None else None
+            module.removeprefix("ray_tpu.models.")
         if model in MODELS:
             bound[bound_as] = model
     return bound
@@ -51,7 +53,8 @@ def test_the_readers_read_what_they_are_meant_to(tmp_path):
         ("ray_tpu.models.gpt", None, "g"), ("ray_tpu.models", "gpt", "_gpt"),
         ("ray_tpu.models", "lm", "lm"),
         ("ray_tpu.models.t5", "_block", "_block")]
-    assert _model_modules(source) == {"g": "gpt", "_gpt": "gpt"}
+    assert _model_modules(source) == {"g": "gpt", "_gpt": "gpt",
+                                      "_block": "t5"}
 
 
 @pytest.mark.parametrize("directory", ["parallel", "ops"])
@@ -65,25 +68,16 @@ def test_nothing_under_models_is_imported_from_below(directory):
     assert not offenders
 
 
-def test_lm_imports_no_model():
-    path = PACKAGE / "models" / "lm.py"
+@pytest.mark.parametrize("shared", SHARED)
+def test_what_the_models_share_imports_no_model(shared):
+    path = PACKAGE / "models" / f"{shared}.py"
     assert not _model_modules(path)
     assert not [(module, name) for module, name, _ in _imports(path)
-                if module.startswith("ray_tpu.models.")
-                or module.startswith(".")]
+                if module.startswith(".")]
 
 
 @pytest.mark.parametrize("model", MODELS)
-def test_no_model_uses_another_models_private_names(model):
-    path = PACKAGE / "models" / f"{model}.py"
-    others = _model_modules(path)
-    tree = ast.parse(path.read_text())
-    reached = [
-        f"{node.value.id}.{node.attr}" for node in ast.walk(tree)
-        if isinstance(node, ast.Attribute) and node.attr.startswith("_")
-        and isinstance(node.value, ast.Name) and node.value.id in others]
-    reached += [
-        f"{module}.{name}" for module, name, _ in _imports(path)
-        if name and name.startswith("_")
-        and module.startswith("ray_tpu.models.")]
-    assert not reached
+def test_no_model_imports_another_model(model):
+    """What two families share lives in ``models/lm.py``; a family that
+    wants another's function moves it there."""
+    assert not _model_modules(PACKAGE / "models" / f"{model}.py")

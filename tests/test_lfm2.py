@@ -120,8 +120,7 @@ def both_flash():
 
 
 def test_the_tiny_stack_has_all_four_kinds_of_layer():
-    assert set(CFG.layers) == set(lfm2.KINDS)
-    assert [kind for _, kind, _ in lfm2.runs(CFG.layers)] == [
+    assert [kind for _, kind, _ in lm.runs(CFG.layers)] == [
         "dense_conv", "dense_full_attention", "moe_conv",
         "moe_full_attention", "moe_conv"]
 
@@ -175,7 +174,7 @@ def test_every_layer_dense_is_transformers_lfm2():
         model.model.embedding_norm.weight.copy_(
             as_torch(params["embedding_norm_scale"]))
         for layer, (run, kind, _) in zip(model.model.layers,
-                                         lfm2.runs(cfg.layers)):
+                                         lm.runs(cfg.layers)):
             w = jax.tree.map(lambda a: a[0], params[run])
             layer.operator_norm.weight.copy_(
                 as_torch(w["operator_norm_scale"]))
@@ -473,7 +472,7 @@ def test_the_cut_configuration_is_seven_runs():
     dense layer a convolution layer as published layer 1 is, then three
     whole periods of expert layers, 1 attention : 3 convolution. The
     benchmark's cell runs a fourth period (layers 1-17: nine runs)."""
-    assert lfm2.runs(CUT.layers) == (
+    assert lm.runs(CUT.layers) == (
         ("run00_dense_conv", "dense_conv", 1),
         ("run01_moe_full_attention", "moe_full_attention", 1),
         ("run02_moe_conv", "moe_conv", 3),
@@ -482,13 +481,13 @@ def test_the_cut_configuration_is_seven_runs():
         ("run05_moe_full_attention", "moe_full_attention", 1),
         ("run06_moe_conv", "moe_conv", 3))
     assert CUT.n_moe_layers == 12
-    deeper = lfm2.runs(replace(CUT, num_hidden_layers=17).layers)
-    assert deeper[:7] == lfm2.runs(CUT.layers) and deeper[7:] == (
+    deeper = lm.runs(replace(CUT, num_hidden_layers=17).layers)
+    assert deeper[:7] == lm.runs(CUT.layers) and deeper[7:] == (
         ("run07_moe_full_attention", "moe_full_attention", 1),
         ("run08_moe_conv", "moe_conv", 3))
     shapes = jax.eval_shape(partial(lfm2.init, CUT), jax.random.PRNGKey(0))
     assert [jax.tree.leaves(shapes[run])[0].shape[0]
-            for run, _, _ in lfm2.runs(CUT.layers)] == [1, 1, 3, 1, 3, 1, 3]
+            for run, _, _ in lm.runs(CUT.layers)] == [1, 1, 3, 1, 3, 1, 3]
     assert shapes["run02_moe_conv"]["w_gate"].shape == (3, 8, 2048, 1536)
     assert shapes["run02_moe_conv"]["router"].shape == (3, 2048, 64)
     assert shapes["run02_moe_conv"]["w_in"].shape == (3, 2048, 6144)
@@ -498,7 +497,7 @@ def test_the_cut_configuration_is_seven_runs():
     assert shapes["wte"].shape == (8192, 2048) and "lm_head" not in shapes
     # Without the offset the first thirteen published layers are eight
     # runs: two convolution layers lead, and the pattern falls a layer late.
-    assert len(lfm2.runs(replace(CUT, first_layer=0).layers)) == 8
+    assert len(lm.runs(replace(CUT, first_layer=0).layers)) == 8
 
 
 @pytest.mark.parametrize("remat", [False, True])
@@ -509,7 +508,7 @@ def test_scan_blocks_over_the_runs_is_the_layers_one_by_one(remat):
     with jax.default_matmul_precision("highest"):
         got, _ = lfm2.hidden_states(params, cfg, tokens)
         x = lm.embed(params["wte"], tokens, cfg.dtype)
-        for run, kind, depth in lfm2.runs(cfg.layers):
+        for run, kind, depth in lm.runs(cfg.layers):
             for i in range(depth):
                 x, _ = lfm2._block(cfg, kind, x, jax.tree.map(
                     lambda a: a[i], params[run]), lm.positions_of(tokens))

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import gpt, lm
+from ray_tpu.models import exchange, gpt, lm
 from ray_tpu.parallel import (MeshConfig, ShardingRules, build_mesh, dp_rules,
                               tp_fsdp_rules)
 from ray_tpu.parallel.train_step import (default_optimizer, init_train_state,
@@ -325,7 +325,7 @@ def test_place_slices_puts_every_slice_at_its_slot(axis):
 
 @pytest.mark.parametrize("tp", [2, 4])
 def test_ring_products_match_the_plain_products(tp):
-    """``lm.gathered_product`` / ``ring_place`` / ``ring_split`` /
+    """``exchange.gathered_product`` / ``ring_place`` / ``ring_split`` /
     ``scattered_product`` over a ring of tp chips against the products they
     stand for, ``(x @ w1) @ w2`` with w1 split by columns and w2 by rows,
     and against its gradients: every chip takes its slice of S in and
@@ -340,11 +340,11 @@ def test_ring_products_match_the_plain_products(tp):
                                     (4 * tp, 16), (2, 8 * tp, 16)))
 
     def on_slices(x, w1, w2):
-        parts = lm.gathered_product(x, [lambda rows: rows,
-                                        lambda rows: rows @ w1])
-        whole = lm.ring_place([rows for rows, _ in parts])  # x again
-        again = lm.ring_split(whole)
-        return whole, lm.scattered_product(
+        parts = exchange.gathered_product(x, [lambda rows: rows,
+                                              lambda rows: rows @ w1])
+        whole = exchange.ring_place([rows for rows, _ in parts])  # x again
+        again = exchange.ring_split(whole)
+        return whole, exchange.scattered_product(
             lambda inputs, w: (inputs[0] + 0 * inputs[1] @ w[0]) @ w[1],
             [(part[1], rows) for part, rows in zip(parts, again)], (w1, w2),
             after=parts[-1][1])
