@@ -882,6 +882,24 @@ def train_kda_decay_floor() -> Gauge:
         "underflows to 0 below -103.")
 
 
+def train_selective_scan_decay_floor() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_selective_scan_decay_floor",
+        "Most negative delta_t A any (token, channel, state) of any Mamba-1 "
+        "layer saw in the last recorded step (ops/selective_scan.py takes "
+        "exp of it): where a state forgets within a token.")
+
+
+def train_diff_attention_lambda_max() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_diff_attention_lambda_max",
+        "Largest lambda of any differential-attention layer in the last "
+        "recorded step (models/phi4flash.py): above 1 the subtracted "
+        "softmax map outweighs the first.")
+
+
 # -- train set-up ----------------------------------------------------------
 # A few dozen events a process (and again at every gang restart), so their
 # durations are observed whether or not anybody traces; the span beside each

@@ -1,17 +1,17 @@
 """What a causal language model of this package is made of, and no model
 owns: the embedding lookup, the layer scan with rematerialisation, the
 attention dispatch (which attention runs, and how it is laid over a mesh),
-the state-space scan's, the chunked head and loss, the pieces families
-share as they stand (``rmsnorm``, the two ropes, ``swiglu``, ``mla``,
-``expert_ffn``, each with the leaves it reads) and the decoder's shell
+the state-space scans', the chunked head and loss, the pieces families
+share as they stand (``rmsnorm``, ``layernorm``, the two ropes, ``swiglu``,
+``mla``, ``expert_ffn``, each with the leaves it reads) and the decoder's shell
 (``Decoder``). A block's tp traffic as exchanges of slices of S is the
 sibling ``models/exchange.py``'s. This module imports ``parallel/`` and
 ``ops/`` and no model module, and no model module imports another: what a
 second family needs of a first moves here.
 
 **A family** (``models/deepseek.py``, ``granite.py``, ``afmoe.py``,
-``kimi_linear.py``, ``lfm2.py``) is three things, written against this
-module:
+``kimi_linear.py``, ``lfm2.py``, ``phi4flash.py``) is three things, written
+against this module:
 
 * its config, a frozen dataclass under the published keys, with
   ``vocab_size``, ``hidden_size``, the epsilon of its norms, the program's
@@ -27,8 +27,15 @@ module:
   callable ``(key, shape) -> float32 array`` (``ones``, ``zeros``, a
   family's own beside its table). The one source of parameters and specs;
 * its block, ``(cfg, kind, h, layer, positions) -> (h, aux or None)``: one
-  layer of ``kind`` on the residual stream h [B, S, d] from the layer's
-  leaves, aux a dict of arrays (an expert layer's: ``expert_aux``).
+  layer of ``kind`` (or one unit of several layers, where neighbours
+  always differ: ``phi4flash.py``'s pairs) on the residual stream h [B, S,
+  d] from the layer's leaves, aux a dict of arrays (an expert layer's:
+  ``expert_aux``). A family whose later runs read what an earlier run
+  made says ``shares`` and takes ``shared`` as a last argument
+  (``scan_blocks``); one whose norms are LayerNorms names the final norm's
+  bias leaf (``final_norm_bias``) and calls ``layernorm`` in its blocks;
+  one whose blocks read a per-layer value no gradient moves gives
+  ``constants``.
 
 ``Decoder`` makes of them ``init``, ``param_specs``, ``hidden_states``,
 ``head``, ``forward``, ``forward_with_aux``, ``loss_of_hidden`` and
@@ -95,6 +102,11 @@ def embed(wte, tokens, dtype, stream: str = "sequence"):
     return constrain(x, "batch", stream, None)
 
 
+#: The key of a block's aux under which it hands values on to the runs
+#: behind it (``scan_blocks``, ``shares``).
+HANDED_ON = "handed_on"
+
+
 def layer_runs(layer_types):
     """[(kind, layers)] of every run of one kind in ``layer_types``."""
     return [(kind, len(list(run)))
@@ -110,7 +122,8 @@ def runs(layers) -> Tuple[Tuple[str, str, int], ...]:
                  for i, (kind, n) in enumerate(layer_runs(layers)))
 
 
-def scan_blocks(cfg, block, x, layers, positions, runs=None):
+def scan_blocks(cfg, block, x, layers, positions, runs=None,
+                shares: bool = False):
     """``block(x, layer, positions) -> (x, aux)`` over stacked layer
     parameters in one ``lax.scan``, each block rematerialised by
     ``cfg.remat`` / ``cfg.remat_policy``. Returns (x, aux stacked over
@@ -137,15 +150,31 @@ def scan_blocks(cfg, block, x, layers, positions, runs=None):
     keeps its parameters that way: a slice of one stack of all a kind's
     layers is a copy, and the slices' gradients a second one). Every run
     is one scan, the runs one after the other. Returns (x, [each run's
-    aux])."""
+    aux]).
+
+    With ``shares`` a run's block may hand values on to the runs behind it:
+    ``block(x, layer, positions, shared) -> (x, aux)``, where ``shared`` is
+    the dict of everything handed on so far and a block hands on what its
+    aux holds under ``HANDED_ON`` (a dict of arrays; of a run of several
+    layers, the last layer's). A handed-on value is one array: an output of
+    the run that made it, and to every run behind it a constant its one
+    ``lax.scan`` closes over, neither in the carry nor kept once a reading
+    layer. The scan's transpose sums the readers' cotangents, and the block
+    that made the value receives the sum."""
     if runs is not None:
         runs = list(runs)
         depths = [jax.tree.leaves(stack)[0].shape[0] for stack in layers]
         if depths != [n for _, n in runs]:
             raise ValueError(f"stacks of {depths} layers for runs {runs}")
-        auxes = []
+        auxes, shared = [], {}
         for (kind, _), stack in zip(runs, layers):
-            x, aux = scan_blocks(cfg, block[kind], x, stack, positions)
+            fn = partial(block[kind], shared=dict(shared)) if shares \
+                else block[kind]
+            x, aux = scan_blocks(cfg, fn, x, stack, positions)
+            if shares and aux and HANDED_ON in aux:
+                aux = dict(aux)
+                shared.update(jax.tree.map(lambda a: a[-1],
+                                           aux.pop(HANDED_ON)))
             auxes.append(aux)
         return x, auxes
     if cfg.remat:
@@ -336,6 +365,19 @@ def state_space(u, dt, A, B, C, D, chunk: int):
         (True, True, False, True, True, False))
 
 
+def selective_scan(xs, delta, A, B, C, D):
+    """The Mamba-1 recurrence ``H_t = exp(delta_t (x) A) * H_(t-1) + (delta_t
+    * xs_t) (x) B_t``, ``y_t = H_t C_t + D * xs_t`` by
+    ``ops/selective_scan.py``'s kernel pair. xs, delta: [B, S, C] (delta
+    positive), A: [C, N] (negative), B, C: [B, S, N], D: [C] -> [B, S, C].
+    Under a mesh the kernels run per shard of the batch, as ``state_space``'s
+    do."""
+    from ray_tpu.ops.selective_scan import selective_scan as op
+    return _over_batch_shards(
+        op, (xs, delta, A, B, C, D), (True, True, False, True, True, False),
+        out_rank=3)
+
+
 def causal_conv(x, w, b=None):
     """Depthwise causal convolution along S of x [B, S, C] with taps w [K,
     C] and, if given, bias b [C], in float32: y_t = b + sum_k w_k x_(t - K +
@@ -394,6 +436,17 @@ def rmsnorm(x, scale, eps):
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt((x32 ** 2).mean(-1, keepdims=True) + eps)
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def layernorm(x, scale, bias, eps):
+    """LayerNorm over the last axis (``nn.LayerNorm``: mean and biased
+    variance, a scale and a bias), statistics in float32, in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    mean = x32.mean(-1, keepdims=True)
+    centred = x32 - mean
+    y = centred * jax.lax.rsqrt((centred ** 2).mean(-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)
+            ).astype(x.dtype)
 
 
 def _rope(x, positions, theta: float, pairs):
@@ -753,6 +806,17 @@ class Decoder:
     #: The final norm's leaf, and the config key of every norm's epsilon.
     final_norm: str = "lnf_scale"
     eps: str = "rms_norm_eps"
+    #: The final norm's bias leaf: the family's norms are LayerNorms
+    #: (``layernorm``) and its blocks call that; None: RMSNorms.
+    final_norm_bias: Optional[str] = None
+    #: The family's blocks hand values on to the runs behind them and take
+    #: ``shared`` as their last argument (``scan_blocks``, ``shares``).
+    shares: bool = False
+    #: (cfg, a run's name in the parameter tree) -> {name: [layers, ...]}:
+    #: what the run's blocks read beside their leaves, under those names,
+    #: and no gradient moves (a function of where a layer lies in the
+    #: published model, say). None: nothing.
+    constants: Optional[Callable] = None
     #: The head is ``wte``'s rows again, else a matrix ``lm_head``.
     tied: bool = False
     #: cfg -> what the looked-up rows are multiplied by (or None), and what
@@ -769,6 +833,8 @@ class Decoder:
         v, d = cfg.vocab_size, cfg.hidden_size
         top = {"wte": ((v, d), ("vocab", "embed"), 0.02),
                self.final_norm: ((d,), ("embed",), ones)}
+        if self.final_norm_bias:
+            top[self.final_norm_bias] = ((d,), ("embed",), zeros)
         if not self.tied:
             top["lm_head"] = ((d, v), ("embed", "vocab"), 0.02)
         return top
@@ -789,7 +855,8 @@ class Decoder:
         stack of its own, over a leading layers axis. A seed's parameters
         are a contract (tests/test_init_pinned.py)."""
         top = self._top(cfg)
-        drawn = [name for name in top if name != self.final_norm]
+        drawn = [name for name in top
+                 if name not in (self.final_norm, self.final_norm_bias)]
         *k_top, k_layers = jax.random.split(key, len(drawn) + 1)
         keys = dict(zip(drawn, k_top))
         params = {name: _drawn(keys.get(name), shape, init, cfg.param_dtype)
@@ -827,15 +894,22 @@ class Decoder:
         # A stack without layers (a model with no leading dense layer)
         # is in the tree and not in the scan.
         stacks = [run for run in self.runs_of(cfg) if run[2]]
+        layers = [params[run] for run, _, _ in stacks]
+        if self.constants:
+            layers = [dict(stack, **self.constants(cfg, run))
+                      for stack, (run, _, _) in zip(layers, stacks)]
         x, auxes = scan_blocks(
             cfg, {kind: partial(self.block, cfg, kind)
-                  for _, kind, _ in stacks}, x,
-            [params[run] for run, _, _ in stacks], positions,
-            runs=[(kind, depth) for _, kind, depth in stacks])
+                  for _, kind, _ in stacks}, x, layers, positions,
+            runs=[(kind, depth) for _, kind, depth in stacks],
+            shares=self.shares)
         x = constrain(x, "batch", "sequence", None)
         aux = merged_aux(auxes)
-        return rmsnorm(x, params[self.final_norm], getattr(cfg, self.eps)), \
-            aux
+        eps = getattr(cfg, self.eps)
+        if self.final_norm_bias:
+            return layernorm(x, params[self.final_norm],
+                             params[self.final_norm_bias], eps), aux
+        return rmsnorm(x, params[self.final_norm], eps), aux
 
     def head(self, params: Dict[str, Any], cfg, x: jax.Array):
         """Logits [..., vocab] of final-normed hidden states x [..., d].

@@ -168,12 +168,15 @@ def _cutting(window, S: int):
     return int(window) if window < S else None
 
 
-def worth_keeping(S: int, Dv: int) -> bool:
+def worth_keeping(S: int, Dv: int, window=None) -> bool:
     """Whether the forward kernel's outputs are worth their bytes to a
     backward pass that could run the kernel again instead: from
-    S / Dv = 32. A head's causal forward is S^2 / (2 blk^2) tiles for
-    2 S Dv bytes of output, so a kept byte buys kernel time in proportion
-    to S / Dv: 1.0-2.2 ms a GB for each unit of it at 512 x 512 tiles of
+    keys / Dv = 32, the keys a query sees: S, or the layer's ``window``
+    where that is fewer (512 of them are 63 tiles of 512 x 512 a
+    16384-token head where causal attention is 528, for the same bytes
+    of output; 4096 over a Dv of 128 still keep). A head's causal forward
+    is S^2 / (2 blk^2) tiles for 2 S Dv bytes of output, so a kept byte
+    buys kernel time in proportion to S / Dv: 1.0-2.2 ms a GB for each unit of it at 512 x 512 tiles of
     1.05-2.28 us (``_stat_lanes``; 1.9-2.4 us before PR 34, when the
     figures that follow were taken). Measured on a v5e, step time saved
     over bytes kept (PERF.md §6, PR 30): 887 ms a GB at S / Dv = 512
@@ -183,7 +186,7 @@ def worth_keeping(S: int, Dv: int) -> bool:
     checkpoint's transfers (a save stalled the loop 10.3 s, not 8.9).
     Nothing was measured between 8 and 64; at 32 the estimate was three
     times a matmul's and with the faster forward is still 1.5-3 times."""
-    return S >= 32 * Dv
+    return (S if window is None else min(S, window)) >= 32 * Dv
 
 
 def _row_ends(row_tab):
@@ -583,7 +586,7 @@ def _fwd(q, k, v, causal, blk_q, blk_k, scale, window):
     if lse is None:
         # Ragged fallback: differentiate the jnp blockwise recurrence.
         return out, (q, k, v, None, None)
-    if worth_keeping(q.shape[1], v.shape[-1]):
+    if worth_keeping(q.shape[1], v.shape[-1], window):
         # Named once, before ``out`` goes out both as the primal and as a
         # residual, so that both are the one kept value.
         out = checkpoint_name(out, RESIDUAL_NAMES[0])

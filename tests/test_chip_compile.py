@@ -12,6 +12,7 @@ worker that runs this file, and never at import. Every test skips where it
 cannot be described (no libtpu).
 """
 
+import json
 import os
 import re
 import sys
@@ -201,6 +202,42 @@ def test_state_space_scan_compiles_at_the_published_widths(topo, backward):
 
 
 @pytest.mark.parametrize("backward", [False, True],
+                         ids=["selective_scan_fwd",
+                              "selective_scan_fwd_and_bwd"])
+def test_selective_scan_compiles_at_the_published_widths(topo, backward):
+    """Phi-4-mini-flash-reasoning's Mamba-1 layer at 16k tokens: 5120
+    channels of 16 states, chunks of 256 (ops/selective_scan.py). A row
+    spread over the sublanes from a dynamic offset, a column spread over the
+    lanes after a dynamic rotation, the chunk's states [256, 16, channels]
+    in VMEM scratch: what the interpreter lets through and Mosaic may
+    not."""
+    from ray_tpu.ops import selective_scan as op
+    from ray_tpu.parallel.collectives import kernel_census
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    batch, seq, channels, state = 1, 16384, 5120, 16
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (arg((batch, seq, channels), jnp.bfloat16),
+            arg((batch, seq, channels), jnp.bfloat16),
+            arg((channels, state), jnp.float32),
+            arg((batch, seq, state), jnp.bfloat16),
+            arg((batch, seq, state), jnp.bfloat16),
+            arg((channels,), jnp.float32))
+
+    def loss(*a):
+        return op.selective_scan(*a).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=tuple(range(6))) if backward \
+        else op.selective_scan
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert kernel_census(text) == (
+        {"selective_scan_fwd": 1, "selective_scan_bwd": 1} if backward
+        else {"selective_scan_fwd": 1})
+
+
+@pytest.mark.parametrize("backward", [False, True],
                          ids=["kda_fwd", "kda_fwd_and_bwd"])
 def test_delta_rule_compiles_at_the_published_widths(topo, backward):
     """Kimi-Linear-48B-A3B's KDA layer at the cell's 16k tokens: 32 heads
@@ -346,6 +383,76 @@ def test_a_cells_step_runs_the_convolutions_kernels(topo, cell, calls):
         assert "conv_silu_fwd" not in census
 
 
+def test_the_phi4flash_cells_step_runs_its_kernels_as_counted(topo):
+    """``phi-4-mini-flash-reasoning-1chip.steady``'s own step, traced for the
+    described chip, by the layers its configuration runs (``layers_run``):
+    the scan's and the convolution's forward twice a Mamba layer (again
+    where the backward scan rematerialises the pair) and their backward
+    once (8 + 4 at three self pairs and the middle pair); the window layers'
+    flash forward twice (63 tiles a head: its outputs are not worth
+    keeping, ``flash_attention.worth_keeping``) and the full and the cross
+    layers' once; every backward kernel once a layer."""
+    from ray_tpu.parallel.collectives import kernel_census
+    cell = "phi-4-mini-flash-reasoning-1chip.steady"
+    step, args = _a_cells_step(topo, cell)
+    here = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs")
+    with open(os.path.join(here, cell.split(".")[0] + ".json")) as f:
+        layers = json.load(f)["layers_run"]
+    mamba = sum(i % 2 == 0 and i <= 16 for i in layers)
+    window = sum(i % 2 == 1 and i < 16 for i in layers)
+    causal = sum(i % 2 == 1 and i > 16 for i in layers)
+    assert mamba >= 2 and window >= 1 and causal >= 2
+    census = kernel_census(jax.make_jaxpr(step.__wrapped__)(*args),
+                           a_step=True)
+    assert census == {
+        "selective_scan_fwd": 2 * mamba, "selective_scan_bwd": mamba,
+        "conv_silu_fwd": 2 * mamba, "conv_silu_bwd": mamba,
+        "flash_fwd_win": 2 * window, "flash_bwd_dq_win": window,
+        "flash_bwd_dkv_win": window, "flash_fwd": causal,
+        "flash_bwd_dq": causal, "flash_bwd_dkv": causal}
+
+
+def test_the_phi4flash_cells_reference_check_holds_less_than_its_step(topo):
+    """The program ``benchmark/runners/train.py`` ``_reference_check`` runs
+    on ``phi-4-mini-flash-reasoning-1chip.steady`` before the first step
+    (the family's logits of the whole sequence, 1024 positions of them
+    kept), compiled for the described chip at the cell's size: with the
+    family's head a block of the vocabulary at a time the compiler holds a
+    piece of the [16384, 200064] logits at a time (2.55 GB of temporaries by
+    ``memory_analysis``; the whole product, its copies for the gather and a
+    second product were 12.28 GB, more than the chip has beside the state of
+    fourteen layers). The chip's reading of the cell
+    (``device.peak_hbm_gb``) takes the largest arena any program reserved:
+    this one must stay under the step's 9 GB."""
+    here = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, here)
+    try:
+        import harness
+        family = harness.load_module("families", "phi4flash")
+    finally:
+        sys.path.remove(here)
+    _, (state, batch) = _a_cells_step(
+        topo, "phi-4-mini-flash-reasoning-1chip.steady")
+    with open(os.path.join(
+            here, "configs", "phi-4-mini-flash-reasoning-1chip.json")) as f:
+        cfg = family.config(json.load(f)["program"])
+    tokens = batch["tokens"]
+    where = jax.ShapeDtypeStruct((tokens.shape[0], 1024), jnp.int32,
+                                 sharding=tokens.sharding)
+
+    def program_forward(params, tokens, targets, positions):
+        logits, losses = family.logits_and_losses(params, cfg, tokens,
+                                                  targets)
+        return jnp.take_along_axis(logits, positions[..., None], axis=1
+                                   ).astype(jnp.float32), losses
+
+    compiled = jax.jit(program_forward).lower(
+        state["params"], tokens, tokens, where).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
 #: GiB the compiled Kimi step reserved as ``preallocated-temp`` before the
 #: delta rule's forward wrote its inverses (PR 38's tree: 8.17 GiB and 128
 #: MiB of another colour), and what the inverses of one layer may add.
@@ -410,6 +517,10 @@ CELL_ATTENTION = {
     "kimi-linear-48b-a3b, latent layer": ((1, 16384, 32, 192), 32, 128,
                                           None),
     "lfm2-24b-a2b": ((4, 8192, 32, 64), 8, 64, None),
+    "phi-4-mini-flash-reasoning, full and cross layers":
+        ((1, 16384, 40, 64), 40, 128, None),
+    "phi-4-mini-flash-reasoning, window layer":
+        ((1, 16384, 40, 64), 40, 128, 512),
     # No cell's: lane-dense statistics over an output of one and a half
     # lane tiles, and at Moonlight's head sizes, which run one lane.
     "heads of 192": ((2, 4096, 8, 192), 8, 192, None),
@@ -483,6 +594,35 @@ def test_flash_compiles_at_16k_with_and_without_a_window(topo, window, names):
     assert "flash_fwd_win" in text if window else "flash_fwd_win" not in text
     assert flash_mod.window_tile_census(16384, window, 512, 512)[
         "executed"] == (252 if window else 528)
+
+
+@pytest.mark.parametrize("window,names", [
+    (512, ("flash_fwd_win", "flash_bwd_dq_win", "flash_bwd_dkv_win")),
+    (None, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))])
+def test_flash_compiles_at_16k_with_heads_of_64_and_values_of_128(
+        topo, window, names):
+    """Phi-4-mini-flash-reasoning's differential attention: 40 query heads
+    of 64 against K of 64 and ``V_g`` of 128 laid out to the query heads,
+    at S = 16384: a window no wider than a tile (512: 63 executed tiles a
+    head, every one cut) and causal (528), forward and both backward
+    kernels; the window's outputs are not worth keeping, Trinity's are."""
+    from ray_tpu.parallel.collectives import kernel_census
+    q, _, _ = _qkv(topo, (1, 16384, 40, 64))
+    v = jax.ShapeDtypeStruct((1, 16384, 40, 128), jnp.bfloat16,
+                             sharding=q.sharding)
+
+    def loss(q, k, v):
+        return flash_mod.flash_attention(
+            q, k, v, True, 512, 512, None, window).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, v).compile().as_text()
+    assert kernel_census(text) == {name: 1 for name in names}
+    assert flash_mod.window_tile_census(16384, window, 512, 512)[
+        "executed"] == (63 if window else 528)
+    assert flash_mod.worth_keeping(16384, 128, window) == (window is None)
+    assert flash_mod.worth_keeping(16384, 128, 4096) \
+        and flash_mod.worth_keeping(16384, 128)
 
 
 @pytest.mark.parametrize("shape,axis", [((8, 8, 1024, 256), 2),

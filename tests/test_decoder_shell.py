@@ -274,3 +274,114 @@ def test_moe_metrics_count_what_expert_aux_says():
         "moe_load_max_over_mean": pytest.approx(20 / 12)}
     assert set(lm.SUMMED_METRICS) < set(metrics) == set(lm.RECORDED_METRICS)
     assert lm.moe_metrics({"floor": jnp.zeros(3)}, 24) == {}
+
+
+# -- values handed on, a LayerNorm with a bias, constants a layer ------------
+
+def _sharing_block(cfg, kind, h, layer, positions, shared):
+    """``counted`` layers hand on their output; ``plain`` layers add the
+    last one handed on, times their place in the published model."""
+    h, aux = _block(cfg, kind, h, layer, positions)
+    if kind == "counted":
+        aux = dict(aux, **{lm.HANDED_ON: {"kept": h}})
+    elif "kept" in shared:
+        h = h + layer["place"] * shared["kept"]
+    return h, aux
+
+
+def _places(cfg, run):
+    names = [name for name, _, _ in lm.runs(cfg.layers)]
+    depth = lm.runs(cfg.layers)[names.index(run)][2]
+    return {"place": jnp.arange(1.0, depth + 1) * (names.index(run) + 1)}
+
+
+SHARING = ("plain", "counted", "counted", "plain", "plain")
+
+
+def _sharing_by_hand(params, cfg, tokens):
+    """The sharing toy's hidden states, layer by layer, no scan."""
+    h = jnp.take(params["wte"], tokens, axis=0)
+    kept = None
+    for run, kind, depth in lm.runs(cfg.layers):
+        for i in range(depth):
+            layer = jax.tree.map(lambda a: a[i], params[run])
+            h, _ = _block(cfg, kind, h, layer, None)
+            if kind == "counted":
+                kept = h
+            elif kept is not None:
+                h = h + _places(cfg, run)["place"][i] * kept
+    return lm.rmsnorm(h, params["lnf_scale"], cfg.rms_norm_eps)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_a_run_hands_its_last_layers_values_to_the_runs_behind_it(remat):
+    """``shares``: what the last ``counted`` layer hands on reaches both
+    ``plain`` layers of the run behind it as one array, scaled by each
+    layer's own constant; values and gradients are the layers called one
+    by one, and the readers' run carries it as a constant of its scan."""
+    cfg = replace(CFG, layers=SHARING, remat=remat)
+    shell = lm.Decoder(name="toy", shapes=_shapes, block=_sharing_block,
+                       shares=True, constants=_places)
+    params = shell.init(cfg, jax.random.PRNGKey(0))
+    assert "place" not in params["run00_plain"]
+    tokens = _tokens(cfg)
+    got, _ = shell.hidden_states(params, cfg, tokens)
+    np.testing.assert_allclose(got, _sharing_by_hand(params, cfg, tokens),
+                               rtol=1e-5, atol=1e-6)
+    weight = jax.random.normal(jax.random.PRNGKey(1), got.shape)
+    grads = jax.grad(lambda p: (shell.hidden_states(p, cfg, tokens)[0]
+                                * weight).sum())(params)
+    want = jax.grad(lambda p: (_sharing_by_hand(p, cfg, tokens) * weight).sum())(
+        params)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    jaxpr = jax.make_jaxpr(lambda p: shell.hidden_states(p, cfg, tokens))(
+        params)
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in scans] == [1, 2, 2]
+    readers = scans[-1]
+    consts = [v.aval.shape for v in readers.invars[
+        :readers.params["num_consts"]]]
+    assert tokens.shape + (cfg.hidden_size,) in consts
+
+
+def test_a_family_without_shared_values_is_called_as_before():
+    """No ``shares``: the block takes five arguments, and an aux under
+    ``HANDED_ON``'s name would be a family's own business."""
+    cfg = replace(CFG, floors=True)
+    got, aux = toy().hidden_states(toy().init(cfg, jax.random.PRNGKey(0)),
+                                   cfg, _tokens(cfg))
+    assert set(aux) == {"floor", "sizes"} and got.shape[-1] == 8
+
+
+def test_a_layernorm_family_has_a_final_bias_and_the_same_draws():
+    """``final_norm_bias``: one more leaf of zeros, the other leaves drawn
+    as without it, and the final norm a LayerNorm with that bias."""
+    plain = toy().init(CFG, jax.random.PRNGKey(3))
+    shell = toy(final_norm_bias="lnf_bias")
+    params = shell.init(CFG, jax.random.PRNGKey(3))
+    assert set(params) == set(plain) | {"lnf_bias"}
+    np.testing.assert_array_equal(params["lnf_bias"], 0.0)
+    for name in plain:
+        for a, b in zip(jax.tree.leaves(params[name]),
+                        jax.tree.leaves(plain[name])):
+            np.testing.assert_array_equal(a, b)
+    assert shell.param_specs(CFG, ShardingRules())["lnf_bias"] \
+        == shell.param_specs(CFG, ShardingRules())["lnf_scale"]
+    params["lnf_bias"] = params["lnf_bias"] + 0.5
+    tokens = _tokens(CFG)
+    x, _ = shell.hidden_states(params, CFG, tokens)
+    np.testing.assert_allclose(x.mean(-1), 0.5, atol=1e-5)
+    np.testing.assert_allclose(x.var(-1), 1.0, atol=1e-3)
+
+
+def test_layernorm_is_torchs():
+    torch = pytest.importorskip("torch")
+    x = np.random.default_rng(0).normal(size=(3, 5, 16)).astype(np.float32)
+    scale, bias = (np.random.default_rng(i).normal(size=16).astype(
+        np.float32) for i in (1, 2))
+    want = torch.nn.functional.layer_norm(
+        torch.from_numpy(x), (16,), torch.from_numpy(scale),
+        torch.from_numpy(bias), 1e-5).numpy()
+    np.testing.assert_allclose(lm.layernorm(x, scale, bias, 1e-5), want,
+                               atol=1e-5)
