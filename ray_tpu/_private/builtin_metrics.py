@@ -841,6 +841,19 @@ def train_moe_routed() -> Counter:
         "the routing's work.")
 
 
+def train_moe_rows_summed() -> Counter:
+    from ray_tpu.util.metrics import Counter
+    return Counter(
+        "ray_tpu_train_moe_rows_summed_total",
+        "Rows the expert layers' way back to tokens read in the forward "
+        "pass: the rows their buffers hold where the kernel "
+        "moe_rows_to_tokens ran (then equal to "
+        "ray_tpu_train_moe_tokens_total), one a routed assignment and "
+        "buffer where the gathers did (ops/moe.py). Over "
+        "ray_tpu_train_moe_routed_total it says whether the kernel "
+        "engaged: the held share where it did, 1.0 where it did not.")
+
+
 def train_moe_calls() -> Counter:
     from ray_tpu.util.metrics import Counter
     return Counter(

@@ -250,9 +250,9 @@ _SHELL = lm.Decoder(
         aux, targets.size * cfg.num_experts_per_tok))
 
 #: ``hidden_states``' aux is the expert layers' ``picked`` [L_moe, B, S, K],
-#: ``group_sizes`` [L_moe, held experts], ``asked`` and ``within_bound``
-#: [L_moe], in layer order; ``loss_fn``'s metrics are the cross-entropy's and
-#: ``lm.moe_metrics``.
+#: ``group_sizes`` [L_moe, held experts], ``asked``, ``within_bound`` and
+#: ``rows_summed`` [L_moe], in layer order; ``loss_fn``'s metrics are the
+#: cross-entropy's and ``lm.moe_metrics``.
 init, param_specs = _SHELL.init, _SHELL.param_specs
 hidden_states, head = _SHELL.hidden_states, _SHELL.head
 forward, forward_with_aux = _SHELL.forward, _SHELL.forward_with_aux

@@ -336,9 +336,9 @@ _SHELL = lm.Decoder(
 
 #: ``hidden_states``' aux is ``decay_floor`` [L] and the expert layers'
 #: ``picked`` [L_moe, B, S, K], ``group_sizes`` [L_moe, held experts],
-#: ``asked`` and ``within_bound`` [L_moe], in layer order. No layer reads
-#: ``positions`` under ``mla_use_nope``: the delta rule's layers carry the
-#: order.
+#: ``asked``, ``within_bound`` and ``rows_summed`` [L_moe], in layer order.
+#: No layer reads ``positions`` under ``mla_use_nope``: the delta rule's
+#: layers carry the order.
 init, param_specs = _SHELL.init, _SHELL.param_specs
 hidden_states, head = _SHELL.hidden_states, _SHELL.head
 forward, forward_with_aux = _SHELL.forward, _SHELL.forward_with_aux

@@ -571,14 +571,17 @@ def expert_leaves(d: int, experts: int, held, width: int,
 def expert_aux(aux, batch_shape):
     """What a block returns of an expert layer, from ``routed_experts``'
     aux over the flattened tokens: ``picked`` [B, S, K], ``group_sizes``
-    [held experts], ``asked`` (assignments the router gave them) and
-    ``within_bound`` (1 where they fit ``ops/moe.py``'s one buffer). With
-    every expert held the router's assignments are all asked, and the one
-    buffer holds them."""
+    [held experts], ``asked`` (assignments the router gave them),
+    ``within_bound`` (1 where they fit ``ops/moe.py``'s one buffer) and
+    ``rows_summed`` (rows the way back to tokens read). With every expert
+    held the router's assignments are all asked, the one buffer holds them
+    and the weighted sum reads them all."""
+    routed = jnp.int32(aux["picked"].size)
     return {"picked": aux["picked"].reshape(*batch_shape, -1),
             "group_sizes": aux["group_sizes"],
-            "asked": aux.get("asked", jnp.int32(aux["picked"].size)),
-            "within_bound": aux.get("within_bound", jnp.int32(1))}
+            "asked": aux.get("asked", routed),
+            "within_bound": aux.get("within_bound", jnp.int32(1)),
+            "rows_summed": aux.get("rows_summed", routed)}
 
 
 def expert_ffn(x, layer, *, top_k: int, scaling: float, normalize: bool,
@@ -618,7 +621,7 @@ def no_expert_parallelism(family: str):
 #: ``SUMMED_METRICS`` and ``RECORDED_METRICS`` off the module that defines
 #: a config's type, so a family binds these there).
 SUMMED_METRICS = ("moe_assignments", "moe_tokens", "moe_routed",
-                  "moe_calls", "moe_calls_within_bound")
+                  "moe_rows_summed", "moe_calls", "moe_calls_within_bound")
 
 #: Metrics of ``moe_metrics`` that feed the registry, each with what records
 #: its value there (parallel/train_step.py reads them without a sync).
@@ -629,6 +632,8 @@ RECORDED_METRICS = {
         lambda value: builtin_metrics.train_moe_tokens().inc(value),
     "moe_routed":
         lambda value: builtin_metrics.train_moe_routed().inc(value),
+    "moe_rows_summed":
+        lambda value: builtin_metrics.train_moe_rows_summed().inc(value),
     "moe_calls":
         lambda value: builtin_metrics.train_moe_calls().inc(value),
     "moe_calls_within_bound":
@@ -646,10 +651,12 @@ def moe_metrics(aux, routed_a_layer: int) -> Dict[str, jax.Array]:
     token, times the expert layers), ``moe_tokens`` (those it gave to
     experts held here), ``moe_assignments`` (rows the grouped matmuls
     computed: equal to ``moe_tokens``, or something was dropped),
-    ``moe_calls`` and ``moe_calls_within_bound`` (expert layers, and those
-    whose share fit one buffer) and ``moe_load_max_over_mean`` (the busiest
-    held expert's load over the held experts' mean, worst layer). {} of a
-    model without an expert layer."""
+    ``moe_rows_summed`` (rows the way back to tokens read: ``moe_tokens``
+    where ``ops/moe.py``'s kernel ran, ``moe_routed`` a buffer where its
+    gathers did), ``moe_calls`` and ``moe_calls_within_bound`` (expert
+    layers, and those whose share fit one buffer) and
+    ``moe_load_max_over_mean`` (the busiest held expert's load over the held
+    experts' mean, worst layer). {} of a model without an expert layer."""
     if "group_sizes" not in aux:
         return {}
     sizes = aux["group_sizes"].astype(jnp.float32)  # [L_moe, held]
@@ -658,6 +665,7 @@ def moe_metrics(aux, routed_a_layer: int) -> Dict[str, jax.Array]:
         "moe_assignments": sizes.sum(),
         "moe_tokens": aux["asked"].astype(jnp.float32).sum(),
         "moe_routed": jnp.float32(routed_a_layer * calls),
+        "moe_rows_summed": aux["rows_summed"].astype(jnp.float32).sum(),
         "moe_calls": jnp.float32(calls),
         "moe_calls_within_bound":
             aux["within_bound"].astype(jnp.float32).sum(),

@@ -48,20 +48,40 @@ SwiGLU and the way back run over the buffer, not over ``tokens x top_k``.
 The first buffer is computed on every routing; one that gives the held
 experts more rows than it has takes the next rows in a second buffer, and
 so on, ``ceil(asked / bound)`` in all: a loop that does not run on a
-routing within the bound, and nothing is cut on any routing. The way back
-to tokens is a gather from the buffer, a slab of ``tokens`` rows a choice,
-summed in float32 in the order ``_combine`` sums; an assignment that is not
-in the buffer adds an exact zero. (Measured on the v5e on the layer alone,
-forward and backward, against a second sort of the buffer's rows by token
-with a within-run sum and one ``[tokens, d]`` gather: 38.4 against 44.4 ms
-at 16,384 x 8 rows of 2304 with 32 of 256 held, 17.5 against 19.5 at x 4
-rows of 3072 with 8 held, where all ``tokens x top_k`` rows took 51.1 and
-36.1: PERF.md, PR 36.) Forward and backward are each those buffers
-(``_held_experts`` is a ``custom_vjp``: autodiff sees neither the loop nor
-its trip count, and the backward pass multiplies a buffer's rows again
-where storing them would keep every buffer's residuals alive: under a
-block's ``remat`` that is the second forward the block would run anyway),
-and no pass scatters.
+routing within the bound, and nothing is cut on any routing.
+
+**The way back to tokens reads only the rows the buffer holds.**
+``out[t] = sum_k [w[k, t] *] rows[at[k * T + t]]`` in float32, a token's
+terms in the order ``_combine`` sums them and an assignment that is not in
+the buffer adding nothing, is the Pallas kernel ``moe_rows_to_tokens``
+wherever the shapes tile (``_token_tile``: rows of bfloat16 or float32 whose
+width is a multiple of 128, tokens a multiple of 128), in the forward's
+weighted sum and in the backward's ``d x``. A grid step is a tile of tokens
+whose float32 rows are the output block; the buffer stays in HBM, and a
+held row is fetched by an asynchronous copy as the HBM tile of 8 rows it
+lies in (what a DMA may slice; bfloat16 read as the 32-bit words that pack
+two rows), 64 fetches ahead of the sum. Which rows a tile needs it reads
+off one bit an assignment, made outside by one elementwise pass over
+``at``, so the walk costs a loop step a held row and one a word of 32
+assignments: a tile with nothing held writes zeros and fetches nothing.
+Elsewhere (the tiny presets' widths) it is ``_to_tokens_xla``, ``top_k``
+gathers of ``tokens`` rows each, of which all but the held share are exact
+zeros: the kernel's oracle, to the bit. Measured on the v5e on the call
+alone at LFM2's shapes (32,768 x 4 assignments, 16,000 held, rows of 2048):
+6.8 ms the gathers, 1.6 ms the kernel; 3.5 ms with the tile's ``at`` walked
+entry by entry, and as fast with the held rows sorted by token in XLA, at 7
+to 11 s of compile a sort: PERF.md, PR 43. (PR 36 had measured the form
+XLA allows, a second sort of the buffer's rows by token with a within-run
+sum and one ``[tokens, d]`` gather, slower than the gathers: 44.4 against
+38.4 ms a layer forward and backward at 16,384 x 8 rows of 2304 with 32 of
+256 held, where all ``tokens x top_k`` rows took 51.1: PERF.md, PR 36.)
+
+Forward and backward are each those buffers (``_held_experts`` is a
+``custom_vjp``: autodiff sees neither the loop nor its trip count, so the
+kernel needs no derivative of its own, and the backward pass multiplies a
+buffer's rows again where storing them would keep every buffer's residuals
+alive: under a block's ``remat`` that is the second forward the block would
+run anyway), and no pass scatters.
 """
 
 from __future__ import annotations
@@ -72,6 +92,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # The module, not the function ``ray_tpu.ops`` re-exports under its name:
 # one ``_interpret`` says for every kernel of this package whether a TPU
@@ -262,16 +284,158 @@ def _at(place, i, bound: int, groups):
     return jnp.where((at >= 0) & (at < groups[:-1].sum()), at, bound)
 
 
-def _to_tokens(rows, at, tokens: int, weights=None):
+def _to_tokens_xla(rows, at, tokens: int, weights=None):
     """sum_k [weights[k, t] *] rows[at[k * T + t]] in float32 -> [T, d]: a
     buffer's rows [bound, d] summed into their tokens by gathers alone, a
     slab of T rows a choice and in the order ``_combine`` sums them; an
-    assignment that is not in the buffer adds an exact zero."""
+    assignment that is not in the buffer adds an exact zero. The form of
+    shapes that do not tile, and the kernel's oracle."""
     slabs = at.reshape(-1, tokens)
     return sum(
         _rows_or_zero(rows, slabs[k]).astype(jnp.float32)
         * (1.0 if weights is None else weights[k][:, None])
         for k in range(slabs.shape[0]))
+
+
+#: ``moe_rows_to_tokens``: the tokens of a grid step, the first that divides
+#: ``tokens`` (its float32 rows are the step's output block, twice in VMEM),
+#: and the fetches in flight. Measured on the v5e at the three cells' shapes
+#: (PERF.md, PR 43).
+_TOKEN_TILES, _IN_FLIGHT = (512, 256, 128), 64
+#: Rows of one tile of a 2-D array in HBM, whatever its dtype: what a DMA
+#: may slice, so a row comes with the seven that share its tile.
+_HBM_ROWS = 8
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _token_tile(rows, at, tokens: int) -> Optional[int]:
+    """The tokens a grid step of ``moe_rows_to_tokens`` takes, or None where
+    the ``jax.numpy`` form runs: rows of bfloat16 or float32 whose width is
+    a multiple of 128, a buffer of whole HBM tiles, ``tokens`` a multiple
+    of the tile, the tile's float32 rows twice within half the stated VMEM,
+    and the held bits of every assignment within SMEM (64 Ki words)."""
+    bound, d = rows.shape
+    if (rows.dtype not in (jnp.bfloat16, jnp.float32) or d % 128
+            or bound % _HBM_ROWS or at.shape[0] > 32 * 65536):
+        return None
+    return next((tile for tile in _TOKEN_TILES if tokens % tile == 0
+                 and 2 * tile * d * 4 <= _VMEM_LIMIT // 2), None)
+
+
+def _rows_to_tokens_kernel(bits, at_ref, *refs, weighted: bool):
+    """One tile of tokens: the output block is the accumulator, zeroed and
+    then given, choice by choice and token by token, the rows ``bits`` says
+    the buffer holds. ``bits`` [K * T / 32] (SMEM, whole): bit ``t % 32``
+    of word ``(k * T + t) // 32`` is set where ``at_ref[k, t]`` (SMEM, this
+    tile's [K, tile]) is a row of the buffer. A row is fetched from HBM as
+    the tile of ``_HBM_ROWS`` rows it lies in, ``_IN_FLIGHT`` fetches ahead
+    of the sum, and added in the order fetched, which is (k, t): a token's
+    terms in the order of k."""
+    if weighted:
+        w_ref, rows_ref, out_ref, stage, q_t, q_r, q_w, sems = refs
+    else:
+        rows_ref, out_ref, stage, q_t, q_r, sems = refs
+    (top_k, tile), j = at_ref.shape, pl.program_id(0)
+    words = tile // 32
+    out_ref[...] = jnp.zeros_like(out_ref)
+    # bfloat16 rows 2p and 2p + 1 are the low and high halves of the 32-bit
+    # words of row p: read as such, a row is a slice of whole words.
+    packed = rows_ref.dtype == jnp.bfloat16
+    table = rows_ref.bitcast(jnp.uint32) if packed else rows_ref
+    fetched = stage.shape[1]
+
+    def fetch(slot, r):
+        first = pl.multiple_of((r // _HBM_ROWS) * fetched, fetched)
+        return pltpu.make_async_copy(
+            table.at[pl.ds(first, fetched)], stage.at[slot], sems.at[slot])
+
+    def add(slot):
+        fetch(slot, 0).wait()
+        t, r = q_t[slot], q_r[slot]
+        if packed:
+            word = stage[slot, pl.ds((r % _HBM_ROWS) // 2, 1), :]
+            term = jax.lax.bitcast_convert_type(
+                jnp.where(r % 2 == 1, word & jnp.uint32(0xFFFF0000),
+                          word << 16), jnp.float32)
+        else:
+            term = stage[slot, pl.ds(r % _HBM_ROWS, 1), :].astype(jnp.float32)
+        if weighted:
+            term = term * q_w[slot]
+        out_ref[pl.ds(t, 1), :] += term
+
+    def held_word(k, i, n):
+        def one(carry):
+            held, n = carry
+            low = held & -held
+            t = i * 32 + 31 - jax.lax.clz(low)
+            slot = n % _IN_FLIGHT
+
+            @pl.when(n >= _IN_FLIGHT)
+            def _():
+                add(slot)
+            r = at_ref[k, t]
+            q_t[slot], q_r[slot] = t, r
+            if weighted:
+                q_w[slot] = w_ref[k, t]
+            fetch(slot, r).start()
+            return held ^ low, n + 1
+        word = bits[(k * pl.num_programs(0) + j) * words + i]
+        return jax.lax.while_loop(lambda c: c[0] != 0, one, (word, n))[1]
+
+    n = jnp.int32(0)
+    for k in range(top_k):
+        n = jax.lax.fori_loop(0, words, partial(held_word, k), n)
+    left = jnp.minimum(n, _IN_FLIGHT)
+    jax.lax.fori_loop(
+        0, left, lambda i, _: add((n - left + i) % _IN_FLIGHT), None)
+
+
+def _rows_to_tokens(rows, at, tokens: int, weights, tile: int):
+    """``_to_tokens_xla`` as the kernel ``moe_rows_to_tokens``, to the bit:
+    the same float32 products summed in the same order with the exact zeros
+    left out, and only the held rows read."""
+    bound, d = rows.shape
+    top_k = at.shape[0] // tokens
+    weighted = weights is not None
+    held = (at < bound).reshape(-1, 32).astype(jnp.uint32)
+    bits = jax.lax.bitcast_convert_type(
+        (held << jnp.arange(32, dtype=jnp.uint32)).sum(-1, dtype=jnp.uint32),
+        jnp.int32)
+    a_tile = pl.BlockSpec((top_k, tile), lambda j, *_: (0, j),
+                          memory_space=pltpu.SMEM)
+    operands = [at.reshape(top_k, tokens)] + ([weights] if weighted else [])
+    if rows.dtype == jnp.bfloat16:
+        stage = pltpu.VMEM((_IN_FLIGHT, _HBM_ROWS // 2, d), jnp.uint32)
+    else:
+        stage = pltpu.VMEM((_IN_FLIGHT, _HBM_ROWS, d), rows.dtype)
+    return pl.pallas_call(
+        partial(_rows_to_tokens_kernel, weighted=weighted),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(tokens // tile,),
+            in_specs=[a_tile] * len(operands)
+            + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile, d), lambda j, *_: (j, 0)),
+            scratch_shapes=[stage, pltpu.SMEM((_IN_FLIGHT,), jnp.int32),
+                            pltpu.SMEM((_IN_FLIGHT,), jnp.int32)]
+            + ([pltpu.SMEM((_IN_FLIGHT,), jnp.float32)] if weighted else [])
+            + [pltpu.SemaphoreType.DMA((_IN_FLIGHT,))]),
+        out_shape=jax.ShapeDtypeStruct((tokens, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_flash._interpret(), name="moe_rows_to_tokens",
+    )(bits, *operands, rows)
+
+
+def _to_tokens(rows, at, tokens: int, weights=None):
+    """sum_k [weights[k, t] *] rows[at[k * T + t]] in float32 -> [T, d], a
+    token's terms in the order of k: a buffer's rows [bound, d] summed into
+    their tokens, an assignment with ``at`` = bound adding nothing. The
+    kernel where the shapes tile (``_token_tile``), else the gathers."""
+    tile = _token_tile(rows, at, tokens)
+    if tile is None:
+        return _to_tokens_xla(rows, at, tokens, weights)
+    return _rows_to_tokens(rows, at, tokens, weights, tile)
 
 
 def _over_buffers(one, needed):
@@ -289,8 +453,10 @@ def _over_buffers(one, needed):
 @partial(jax.jit, static_argnums=(0,))
 def _buffer_forward(bound, i, x, weights, w_gate, w_up, w_down, order, place,
                     sizes):
-    """Buffer ``i``'s part of ``_held_experts``' sum, in float32, and the
-    rows it gave each held expert."""
+    """Buffer ``i``'s part of ``_held_experts``' sum, in float32, the rows
+    it gave each held expert, and the rows its way back to tokens read: the
+    held ones where the kernel ran, one a routed assignment where the
+    gathers did."""
     tokens = x.shape[0]
     rows_of, groups = _buffer(i, bound, order, sizes)
     with jax.named_scope("moe_dispatch"):
@@ -298,8 +464,11 @@ def _buffer_forward(bound, i, x, weights, w_gate, w_up, w_down, order, place,
     with jax.named_scope("moe_experts"):
         out = _swiglu_groups(rows, w_gate, w_up, w_down, groups)
     with jax.named_scope("moe_combine"):
-        return _to_tokens(out, _at(place, i, bound, groups), tokens,
-                          weights), groups[:-1]
+        at = _at(place, i, bound, groups)
+        summed = (jnp.int32(at.shape[0])
+                  if _token_tile(out, at, tokens) is None
+                  else groups[:-1].sum())
+        return _to_tokens(out, at, tokens, weights), groups[:-1], summed
 
 
 @partial(jax.jit, static_argnums=(0,))
@@ -333,7 +502,8 @@ def _buffer_backward(bound, i, g, x, weights, w_gate, w_up, w_down, order,
 def _held_experts(bound, x, weights, w_gate, w_up, w_down, order, place,
                   sizes):
     """sum_{k: picked and held} weights[k, t] * Expert(x[t]) -> ([T, d] in
-    x's dtype, the rows each held expert was given [count]).
+    x's dtype, the rows each held expert was given [count], the rows the
+    way back to tokens read).
 
     x [T, d]; weights [K, T] float32; the held experts' w_gate, w_up
     [count, d, f], w_down [count, f, d] in x's dtype; order [K * T, padded
@@ -341,11 +511,11 @@ def _held_experts(bound, x, weights, w_gate, w_up, w_down, order, place,
     after them; place [K * T], its inverse; sizes [count], the router's
     histogram over the held experts. One buffer of ``bound`` rows at a time
     (the module text)."""
-    y, placed = _over_buffers(
+    y, placed, summed = _over_buffers(
         lambda i: _buffer_forward(bound, i, x, weights, w_gate, w_up, w_down,
                                   order, place, sizes),
         _buffers_needed(sizes.sum(), bound))
-    return y.astype(x.dtype), placed
+    return y.astype(x.dtype), placed, summed
 
 
 def _held_experts_fwd(bound, *args):
@@ -384,9 +554,12 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
     needs beyond. ``aux["group_sizes"]`` is [count], the rows of each held
     expert that the buffers placed and the grouped matmuls were given;
     ``aux["asked"]`` counts the assignments the router gave the held experts
-    (equal to that sum, or something was cut), and ``aux["within_bound"]``
-    is 1 where one buffer held them all, else 0. None, or all E held, is the
-    whole layer, at ``tokens x top_k`` rows."""
+    (equal to that sum, or something was cut), ``aux["within_bound"]`` is 1
+    where one buffer held them all, else 0, and ``aux["rows_summed"]``
+    counts the rows the buffers' way back to tokens read: the asked ones
+    where the kernel ran, ``tokens x top_k`` a buffer where the gathers
+    did. None, or all E held, is the whole layer, at ``tokens x top_k``
+    rows."""
     tokens, n_experts = x.shape[0], router.shape[-1]
     dt = x.dtype
     first = None
@@ -438,8 +611,9 @@ def _share(x, picked, weights, w_gate, w_up, w_down, first: int,
         sizes = starts[1:] - starts[:-1]
         # Whole buffers: a slice of the order never runs off its end.
         order = jnp.pad(order, (0, -order.shape[0] % bound))
-    y, placed = _held_experts(bound, x, weights.T, w_gate, w_up, w_down,
-                              order, place, sizes)
+    y, placed, summed = _held_experts(bound, x, weights.T, w_gate, w_up,
+                                      w_down, order, place, sizes)
     asked = is_held.sum()
     return y, {"picked": picked, "asked": asked, "group_sizes": placed,
-               "within_bound": (asked <= bound).astype(jnp.int32)}
+               "within_bound": (asked <= bound).astype(jnp.int32),
+               "rows_summed": summed}

@@ -487,6 +487,138 @@ def test_the_kimi_cells_compiled_step_holds_the_delta_rules_pair(
     assert KIMI_TEMP_GIB - 0.05 < reserved < KIMI_TEMP_GIB + INVERSES_GIB
 
 
+#: tokens, choices a token, width, rows of a buffer: what ``_to_tokens``
+#: is given in the three cells that hold a share of the experts.
+SHARE_SHAPES = {
+    "lfm2-24b-a2b-1chip.steady": (32768, 4, 2048, 32768),
+    "kimi-linear-48b-a3b-1chip.steady": (16384, 8, 2304, 32768),
+    "trinity-large-preview-1chip.steady": (16384, 4, 3072, 4096),
+}
+
+
+@pytest.mark.parametrize("weighted,dtype", [
+    (True, jnp.bfloat16), (False, jnp.bfloat16), (True, jnp.float32)],
+    ids=["weighted", "unweighted", "float32"])
+@pytest.mark.parametrize("cell", SHARE_SHAPES)
+def test_rows_to_tokens_compiles_at_the_share_cells_shapes(topo, cell,
+                                                           weighted, dtype):
+    """``moe_rows_to_tokens`` (ops/moe.py) at the shapes the three share
+    cells give it, the forward's weighted sum and the backward's plain one:
+    tiles of 512 tokens with their float32 rows twice in VMEM (12.6 MB at
+    Trinity's width), a row fetched as the packed pairs of the HBM tile it
+    lies in, a tile's [K, 512] entries of ``at`` and of the weights and
+    the held bits of every assignment in SMEM; and with rows of float32,
+    which no cell has (a tile of 8 whole rows a fetch: twice the stage)."""
+    from ray_tpu.ops import moe
+    from ray_tpu.parallel.collectives import kernel_census
+    tokens, top_k, d, bound = SHARE_SHAPES[cell]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    rows = jax.ShapeDtypeStruct((bound, d), dtype, sharding=one_chip)
+    at = jax.ShapeDtypeStruct((top_k * tokens,), jnp.int32,
+                              sharding=one_chip)
+    weights = (jax.ShapeDtypeStruct((top_k, tokens), jnp.float32,
+                                    sharding=one_chip),) * weighted
+    assert moe._token_tile(rows, at, tokens) == 512
+    text = jax.jit(
+        lambda rows, at, *weights: moe._to_tokens(rows, at, tokens, *weights)
+    ).lower(rows, at, *weights).compile().as_text()
+    assert kernel_census(text) == {"moe_rows_to_tokens": 1}
+
+
+@pytest.mark.parametrize("cell,layers,passes", [
+    ("lfm2-24b-a2b-1chip.steady", 16, 2),
+    ("kimi-linear-48b-a3b-1chip.steady", 4, 2),
+    ("trinity-large-preview-1chip.steady", 4, 3),
+])
+def test_a_share_cells_step_sums_rows_with_the_kernel(topo, cell, layers,
+                                                      passes):
+    """A share cell's own step, traced for the described chip: every way
+    back to tokens is the kernel, in the forward scan (the weighted sum)
+    and in the backward scan (``d x``), each the first buffer's call and
+    the call in the loop over further buffers. Where the expert layer's sum
+    is the block's output (LFM2, Kimi) the backward scan does not run the
+    layer's forward again; Trinity norms the sum, and it does."""
+    from ray_tpu.parallel.collectives import kernel_census
+    step, args = _a_cells_step(topo, cell)
+    census = kernel_census(jax.make_jaxpr(step.__wrapped__)(*args),
+                           a_step=True)
+    assert census["moe_rows_to_tokens"] == layers * passes * 2
+
+
+def test_the_lfm2_cells_compiled_step_gathers_no_slab_of_tokens(topo):
+    """``lfm2-24b-a2b-1chip.steady``'s step compiled for the described chip
+    holds the kernel wherever a buffer's rows go back to tokens, and no
+    gather of [32768, 2048] rows for it: under ``moe_combine`` what is left
+    is the cotangent's rows for the buffer (``g_rows``: one gather a
+    backward call, so one for every two calls of the kernel, the forward's
+    and the backward's ``d x``; the rematerialised forward of an expert
+    layer is dead code, its output being the block's), under
+    ``moe_dispatch`` the rows of ``x`` a call. With the ``top_k`` slabs a
+    call the parent's step held 80 and 96 of them where this holds 16 and
+    32."""
+    from ray_tpu.parallel.collectives import kernel_census
+    step, args = _a_cells_step(topo, "lfm2-24b-a2b-1chip.steady")
+    text = step.lower(*args).compile().as_text()
+    calls = kernel_census(text)["moe_rows_to_tokens"]
+
+    def gathers(scope):
+        return len(re.findall(
+            r"= bf16\[32768,2048\]\S* gather\([^\n]*"
+            rf'op_name="{scope}/gather"', text))
+    assert calls >= 4 and calls % 2 == 0
+    assert (gathers("moe_combine"), gathers("moe_dispatch")) == (
+        calls // 2, calls)
+
+
+#: sha256 (first 12) of the lowered step of every cell without a share of
+#: the experts, recorded from PR 42's tree and equal on PR 43's: what a PR
+#: that says "these cells do not move" holds itself to off the chip. A PR
+#: that means to change one of these programs records the new value here
+#: (the failing assertion prints it) and says so in CHANGES.md.
+LOWERED_STEPS = {
+    "gptj-6b-1chip.steady": "6609ff07ec2f",
+    "gptj-6b-4chip.steady": "5c05ed09a078",
+    "moonlight-16b-a3b-1chip.steady": "cda3dc002fa5",
+    "granite-4.0-h-micro-1chip.steady": "e72cae811a53",
+    "phi-4-mini-flash-reasoning-1chip.steady": "df4cd8b6c9e8",
+}
+
+
+def _lowered_digest(step, args):
+    """sha256 of ``step.lower(*args).as_text()`` (no locations), each
+    ``tpu_custom_call``'s kernel taken out of its base64 bytecode and put
+    back as the digest of its MLIR printed without debug info: the
+    bytecode carries the checkout's path and the callers' line numbers."""
+    import base64
+    import hashlib
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def kernel(match):
+        context = mlir.make_ir_context()
+        tpu.register_dialect(context)
+        context.allow_unregistered_dialects = True
+        with context:
+            body = ir.Module.parse(base64.b64decode(match.group(1))
+                                   ).operation.get_asm(enable_debug_info=False)
+        return ('\\22body\\22: \\22'
+                + hashlib.sha256(body.encode()).hexdigest() + '\\22')
+    text = re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', kernel,
+                  step.lower(*args).as_text())
+    return hashlib.sha256(text.encode()).hexdigest()[:12]
+
+
+@pytest.mark.parametrize("cell", LOWERED_STEPS)
+def test_a_step_without_a_share_is_the_program_it_was(topo, cell):
+    """The five cells whose model holds every expert or none (the GPT-J
+    cells, Moonlight's whole layer, granite, phi) lower to the text they
+    lowered to before ``ops/moe.py``'s share got its kernel: nothing they
+    run was touched."""
+    step, args = _a_cells_step(topo, cell)
+    assert _lowered_digest(step, args) == LOWERED_STEPS[cell]
+
+
 def test_flash_compiles_at_4_x_8k_with_grouped_kv_heads(topo):
     """LFM2-24B-A2B's attention layer: 32 query heads over 8 KV heads of 64
     at 4 sequences of 8192, forward and both backward kernels."""

@@ -263,14 +263,16 @@ def test_moe_metrics_count_what_expert_aux_says():
                            "group_sizes": jnp.array([20, 4])}, (3, 4))
     assert whole["picked"].shape == (3, 4, 2)
     assert int(whole["asked"]) == 24 and int(whole["within_bound"]) == 1
+    assert int(whole["rows_summed"]) == 24
     share = lm.expert_aux({"picked": picked, "group_sizes": jnp.array([5, 4]),
                            "asked": jnp.int32(10),
-                           "within_bound": jnp.int32(0)}, (3, 4))
+                           "within_bound": jnp.int32(0),
+                           "rows_summed": jnp.int32(10)}, (3, 4))
     aux = {name: jnp.stack([whole[name], share[name]]) for name in whole}
     metrics = {k: float(v) for k, v in lm.moe_metrics(aux, 24).items()}
     assert metrics == {
         "moe_assignments": 33.0, "moe_tokens": 34.0, "moe_routed": 48.0,
-        "moe_calls": 2.0, "moe_calls_within_bound": 1.0,
+        "moe_rows_summed": 34.0, "moe_calls": 2.0, "moe_calls_within_bound": 1.0,
         "moe_load_max_over_mean": pytest.approx(20 / 12)}
     assert set(lm.SUMMED_METRICS) < set(metrics) == set(lm.RECORDED_METRICS)
     assert lm.moe_metrics({"floor": jnp.zeros(3)}, 24) == {}

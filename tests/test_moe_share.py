@@ -157,6 +157,11 @@ def test_the_buffers_give_the_full_size_layer(monkeypatch, widths, routing,
     assert int(aux["asked"]) == asked == int(aux["group_sizes"].sum())
     assert (aux["group_sizes"] == want_aux["group_sizes"]).all()
     assert int(aux["within_bound"]) == (asked <= BOUND)
+    # The rows the way back read: the held ones through the kernel (widths
+    # of 128), every assignment a buffer through the gathers.
+    assert int(aux["rows_summed"]) == (
+        asked if widths[0] % 128 == 0
+        else TOKENS * TOP_K * max(1, -(-asked // BOUND)))
     scale = float(jnp.abs(want_y).max()) or 1.0
     np.testing.assert_allclose(y, want_y, atol=1e-5 * scale)
     for got, want in zip(grads, want_grads):
@@ -194,3 +199,188 @@ def test_with_every_expert_held_the_program_is_as_it_was(widths, held):
         return jax.jit(layer).lower(*args).as_text()
 
     assert lowered(moe.routed_experts) == lowered(_full_size)
+
+
+# -- the way back to tokens: the kernel against the gathers ------------------
+
+
+def _planted_at(tokens, top_k, bound, pattern, seed=0):
+    """``at`` [K * T] for a buffer of ``bound`` rows, every held assignment
+    at a row of its own, in no order. ``mixed``: a token holds none, one,
+    all K or a random few of its choices, by ``t % 4``; ``empty_tile``: as
+    mixed, but no token of 128 to 255 holds any; ``none``: every ``at`` is
+    the bound; ``full``: every row of the buffer is some assignment's."""
+    rng = np.random.default_rng(seed)
+    t = np.arange(tokens)
+    if pattern == "none":
+        held = np.zeros((top_k, tokens), bool)
+    elif pattern == "full":
+        held = np.zeros(top_k * tokens, bool)
+        held[rng.permutation(top_k * tokens)[:bound]] = True
+        held = held.reshape(top_k, tokens)
+    else:
+        held = rng.random((top_k, tokens)) < 0.4
+        held[:, t % 4 == 0] = False
+        one = t[t % 4 == 1]
+        held[:, one] = False
+        held[rng.integers(0, top_k, len(one)), one] = True
+        held[:, t % 4 == 2] = True
+        if pattern == "empty_tile":
+            held[:, 128:256] = False
+    assert held.sum() <= bound
+    at = np.full(top_k * tokens, bound, np.int32)
+    at[held.reshape(-1)] = rng.permutation(bound)[:held.sum()]
+    return jnp.asarray(at)
+
+
+#: name: (tokens, top_k, d, bound, dtype of the rows, pattern). Tokens of
+#: 384 are three grid steps of 128, 128 one.
+WAYS_BACK = {
+    "mixed": (384, 4, 256, 1024, jnp.bfloat16, "mixed"),
+    "mixed_float32": (384, 2, 128, 512, jnp.float32, "mixed"),
+    "empty_tile": (384, 4, 128, 1024, jnp.bfloat16, "empty_tile"),
+    "none": (384, 2, 128, 512, jnp.bfloat16, "none"),
+    "full": (384, 4, 128, 512, jnp.bfloat16, "full"),
+    "kimi_width": (128, 8, 2304, 512, jnp.bfloat16, "mixed"),
+    "trinity_width": (128, 4, 3072, 512, jnp.bfloat16, "mixed"),
+}
+
+
+def _short_weights(key, shape):
+    """Weights of eight significant bits: their product with a bfloat16 is
+    exact in float32. The CPU's compiler fuses a product into a sum, in the
+    kernel's program and in the gathers' not at the same places, and what
+    is held to the bit here is the sum and its order."""
+    return jax.random.uniform(key, shape, minval=0.1).astype(
+        jnp.bfloat16).astype(jnp.float32)
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "unweighted"])
+@pytest.mark.parametrize("case", list(WAYS_BACK))
+def test_the_kernel_sums_what_the_gathers_sum_to_the_bit(case, weighted):
+    """``moe_rows_to_tokens`` (interpreted) is ``_to_tokens_xla`` bit for
+    bit: with and without weights, on tokens that hold none, one and all of
+    their choices, a grid step with nothing to fetch, a buffer no
+    assignment is in and one that is full, rows of bfloat16 (fetched as
+    packed pairs) and of float32, at Kimi's and Trinity's widths."""
+    tokens, top_k, d, bound, dtype, pattern = WAYS_BACK[case]
+    at = _planted_at(tokens, top_k, bound, pattern)
+    # float32 rows of eight significant bits too, for the same reason.
+    rows = jax.random.normal(jax.random.PRNGKey(1), (bound, d)).astype(
+        jnp.bfloat16).astype(dtype)
+    weights = (_short_weights(jax.random.PRNGKey(2), (top_k, tokens))
+               if weighted else None)
+    assert moe._token_tile(rows, at, tokens) == 128
+    held = np.asarray(at).reshape(top_k, tokens) < bound
+    if pattern in ("mixed", "empty_tile"):
+        assert set(np.unique(held.sum(0))) >= {0, 1, top_k}
+    if pattern == "empty_tile":
+        assert not held[:, 128:256].any() and held[:, 256:].any()
+    got = jax.jit(moe._to_tokens, static_argnums=2)(rows, at, tokens, weights)
+    want = moe._to_tokens_xla(rows, at, tokens, weights)
+    assert got.dtype == want.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if pattern == "none":
+        assert not np.asarray(got).any()
+
+
+@pytest.mark.parametrize("shape", [
+    (96, 128, jnp.bfloat16), (128, 96, jnp.bfloat16),
+    (128, 128, jnp.float16), (512, 16384, jnp.bfloat16)],
+    ids=["tokens", "width", "dtype", "vmem"])
+def test_a_shape_that_does_not_tile_takes_the_gathers(shape):
+    """Tokens no multiple of a tile, a width no multiple of 128, a dtype
+    the kernel does not unpack: ``_to_tokens`` is the ``jax.numpy`` form,
+    chosen on what it is given and by nothing else. Rows so wide that the
+    largest tile's output block does not fit take a smaller tile."""
+    tokens, d, dtype = shape
+    rows = jnp.ones((512, d), dtype)
+    at = _planted_at(tokens, 2, 512, "mixed")
+    text = str(jax.make_jaxpr(
+        lambda rows, at: moe._to_tokens(rows, at, tokens))(rows, at))
+    if d == 16384:
+        assert moe._token_tile(rows, at, tokens) == 256
+        assert "moe_rows_to_tokens" in text
+        return
+    assert moe._token_tile(rows, at, tokens) is None
+    assert "pallas_call" not in text
+    np.testing.assert_array_equal(
+        np.asarray(moe._to_tokens(rows, at, tokens)),
+        np.asarray(moe._to_tokens_xla(rows, at, tokens)))
+
+
+@pytest.fixture
+def gathers_alone(monkeypatch):
+    """Call it, and ``_to_tokens`` is the ``jax.numpy`` form at every shape
+    until the test ends. ``_buffer_forward`` and ``_buffer_backward`` are
+    jitted: what they traced with the other form is dropped both times
+    (theirs alone: another test's file may count on what the process has
+    compiled)."""
+    def forget():
+        moe._buffer_forward.clear_cache()
+        moe._buffer_backward.clear_cache()
+
+    def switch():
+        monkeypatch.setattr(moe, "_token_tile", lambda rows, at, tokens: None)
+        forget()
+    yield switch
+    monkeypatch.undo()
+    forget()
+
+
+@pytest.mark.parametrize("routing", ["under", "over", "all", "none"])
+def test_the_layer_with_the_kernel_is_the_layer_with_the_gathers(
+        monkeypatch, gathers_alone, routing):
+    """``routed_experts(held=...)`` in bfloat16 through the kernel, forward
+    (the weighted sum) and backward (``d x``), gives the bits it gives
+    through the gathers: on a routing within the bound, on ones whose rows
+    lie in a second buffer, and on none; and counts the rows it read."""
+    both, one = ROUTINGS[routing]
+    asked = 2 * both + one
+    picked = _planted(0, both, one)
+
+    def route(x, router, bias, top_k, scaling, normalize):
+        weights = _short_weights(jax.random.PRNGKey(3), picked.shape)
+        return picked, weights * jnp.sum(router) * 0 + weights
+    monkeypatch.setattr(moe, "route", route)
+    args = [a.astype(jnp.bfloat16) for a in _layer(128, 128)]
+
+    y, aux, grads = _run(moe.routed_experts, args, 0)
+    assert int(aux["rows_summed"]) == asked == int(aux["asked"])
+    gathers_alone()
+    want_y, want_aux, want_grads = _run(moe.routed_experts, args, 0)
+    assert int(want_aux["rows_summed"]) == \
+        TOKENS * TOP_K * max(1, -(-asked // BOUND))
+    np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                  np.asarray(want_y, np.float32))
+    for got, want in zip(grads, want_grads):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+    assert bool(np.asarray(y, np.float32).any()) == bool(asked)
+
+
+@pytest.mark.parametrize("widths", [(32, 16), (128, 128)],
+                         ids=["gathers", "kernel"])
+def test_the_metrics_say_whether_the_kernel_ran(widths):
+    """``lm.moe_metrics``' ``moe_rows_summed`` on a routing within the
+    bound: ``moe_tokens`` where the way back to tokens was the kernel,
+    ``moe_routed`` where it was the gathers."""
+    from ray_tpu.models import lm
+    x, router, bias, *experts = _layer(*widths)
+    layer = dict(zip(("w_gate", "w_up", "w_down"),
+                     (w[:COUNT] for w in experts)),
+                 router=router, router_bias=bias)
+    layer.update({"shared_" + name: w[0] for name, w in zip(
+        ("w_gate", "w_up", "w_down"), experts)})
+    _, _, aux = lm.expert_ffn(x.reshape(2, TOKENS // 2, -1), layer,
+                              top_k=TOP_K, scaling=1.0, normalize=True,
+                              held=(0, COUNT))
+    assert int(aux["within_bound"]) == 1
+    metrics = lm.moe_metrics(
+        {name: jnp.stack([value, value]) for name, value in aux.items()},
+        TOKENS * TOP_K)
+    assert float(metrics["moe_routed"]) == 2 * TOKENS * TOP_K
+    assert 0 < float(metrics["moe_tokens"]) < TOKENS * TOP_K
+    assert float(metrics["moe_rows_summed"]) == float(
+        metrics["moe_tokens" if widths[0] % 128 == 0 else "moe_routed"])
