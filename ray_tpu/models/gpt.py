@@ -71,8 +71,9 @@ class GPTConfig:
     # Tokens per cross-entropy chunk (0 = unchunked). The [tokens, vocab]
     # fp32 logits and their cotangent are the single largest activation in
     # training; chunking streams them through a lax.scan so peak HBM holds
-    # one chunk instead of the full batch (each chunk's logits matmul is
-    # recomputed in backward — ~2*d*vocab extra FLOPs/token, a few percent).
+    # one chunk instead of the full batch. Nothing is recomputed for it: the
+    # one walk forms each chunk's d x and d W while its logits are there
+    # (lm.chunked_ce).
     loss_chunk: int = 0
     attn_impl: str = "dot"  # "dot" | "flash" | "ring" | "ulysses"
     # Flash-attention tile sizes. 512x512 keeps both the Q tile and the
@@ -438,7 +439,8 @@ def loss_fn(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
     """Next-token cross-entropy in fp32 (+ optional z-loss regularizer).
 
     With ``cfg.loss_chunk > 0`` the head matmul + fp32 softmax run chunked
-    (see ``lm.chunked_ce`` and GPTConfig.loss_chunk)."""
+    (see ``lm.chunked_ce`` and GPTConfig.loss_chunk); ``lm.chunked_ce``
+    hoists the head's parameters out of the closure it is given here."""
     x = hidden_states(params, cfg, tokens)
     head = partial(_head, lm.head_gathered(params, cfg.tie_embeddings), cfg)
     return lm.next_token_loss(head, x, targets, mask, cfg.loss_chunk, z_loss)

@@ -1,7 +1,9 @@
 """What a causal language model of this package is made of, and no model
 owns: the embedding lookup, the layer scan with rematerialisation, the
 attention dispatch (which attention runs, and how it is laid over a mesh),
-the state-space scans', the chunked head and loss, the pieces families
+the state-space scans', the chunked head and loss (``chunked_ce``: one walk
+over the chunks that forms the loss and, where a gradient is asked, its
+cotangents, with nothing rematerialised), the pieces families
 share as they stand (``rmsnorm``, ``layernorm``, the two ropes, ``swiglu``,
 ``mla``, ``expert_ffn``, each with the leaves it reads) and the decoder's shell
 (``Decoder``). A block's tp traffic as exchanges of slices of S is the
@@ -691,46 +693,120 @@ def ce_stats(logits: jax.Array, targets: jax.Array, mask: jax.Array,
 
 
 def chunked_ce(head, x: jax.Array, targets: jax.Array, mask32: jax.Array,
-               chunk: int, z_loss: float = 0.0
+               chunk: int, z_loss: float = 0.0,
+               weight: Optional[jax.Array] = None
                ) -> Tuple[jax.Array, jax.Array]:
-    """(Σ nll·mask, Σ hit·mask) of ``head(x)`` against targets, in fp32.
+    """(weight · Σ nll·mask, Σ hit·mask) of ``head(x)`` against targets, in
+    fp32; ``weight`` is a scalar no gradient moves (None: 1).
 
     ``head`` maps hidden [..., d] to logits [..., vocab]; x is [B, S, d].
     With ``chunk > 0`` the head matmul and the fp32 softmax run ``chunk``
-    tokens at a time under a rematerialised lax.scan, so the [tokens, vocab]
-    fp32 logits never exist whole. A chunk is a slice of S across the whole
+    tokens at a time under a lax.scan, so the [tokens, vocab] fp32 logits
+    never exist whole. A chunk is a slice of S across the whole
     batch, [B, S / n, d]: the scanned dimension is not the one the batch's
     sharding lies on, so every data shard walks its own tokens and no chip
     sees another's (chunks of whole rows put the sharding on the scanned
     dimension, and the partitioner then splits d and sums every chunk's
-    logits instead)."""
+    logits instead).
+
+    The chunked path is a ``custom_vjp`` (``_chunked_sums``) and nothing in
+    it is rematerialised: the loss is the last thing the forward pass does
+    and its cotangent on the logits is a function of the logits alone, so
+    when a gradient is asked the one walk over the chunks takes each
+    chunk's ``jax.vjp`` while its logits are there, sums the cotangents of
+    what ``head`` reads and lays out x's, and the backward pass only scales
+    them: three vocabulary-wide products a chunk (the head, d x, d W), where
+    autodiff of a checkpointed scan runs the head twice. What ``head``
+    closes over that a gradient can move (its parameters) is hoisted by
+    ``jax.closure_convert`` into explicit arguments of the ``custom_vjp``,
+    which cannot close over them: callers hand over a closure as before.
+    Reverse mode only; forward mode takes ``chunk=0``."""
     with jax.named_scope("head_loss"):
         B, S = targets.shape
+        weight = jnp.asarray(1.0 if weight is None else weight, jnp.float32)
         if not (chunk and B * S > chunk):
-            return ce_stats(head(x), targets, mask32, z_loss)
+            nll_sum, hit_sum = ce_stats(head(x), targets, mask32, z_loss)
+            return nll_sum * jax.lax.stop_gradient(weight), hit_sum
         # The fewest slices of S that hold at most ``chunk`` tokens each:
         # where ``chunk`` does not divide, the largest slice under it that
         # does, never the whole logits (the feature's memory bound stands).
         n = next((n for n in range(2, S)
                   if S % n == 0 and B * S // n <= chunk), S)
+        head, head_params = jax.closure_convert(head, jax.ShapeDtypeStruct(
+            (B * S // n, *x.shape[2:]), x.dtype))
+        return _chunked_sums(head, n, z_loss, x, head_params, targets,
+                             mask32, weight)
 
-        def slices(a):
-            a = a.reshape(B, n, S // n, *a.shape[2:]).swapaxes(0, 1)
-            return constrain(a, None, "batch", "sequence",
-                             *[None] * (a.ndim - 3))
 
-        @jax.checkpoint
-        def chunk_stats(carry, xtm):
-            # One row of tokens: [B * S / n, ...], as head and loss see it
-            # on one device too.
-            x_c, t_c, m_c = (a.reshape(-1, *a.shape[2:]) for a in xtm)
-            nll_sum, hit_sum = ce_stats(head(x_c), t_c, m_c, z_loss)
-            return (carry[0] + nll_sum, carry[1] + hit_sum), None
+def _slices(n: int, *arrays: jax.Array) -> Tuple[jax.Array, ...]:
+    """Each [B, S, ...] as n slices of S, [n, B, S / n, ...]."""
+    def slices(a):
+        B, S = a.shape[:2]
+        a = a.reshape(B, n, S // n, *a.shape[2:]).swapaxes(0, 1)
+        return constrain(a, None, "batch", "sequence",
+                         *[None] * (a.ndim - 3))
+    return tuple(slices(a) for a in arrays)
 
-        sums, _ = jax.lax.scan(
-            chunk_stats, (jnp.zeros((), jnp.float32),) * 2,
-            (slices(x), slices(targets), slices(mask32)))
-        return sums
+
+def _chunk_stats(head, z_loss, head_params, xtm):
+    """``ce_stats`` of one slice as ``_slices`` cuts them, on one row of
+    tokens [B * S / n, ...], as head and loss see it on one device too."""
+    x_c, t_c, m_c = (a.reshape(-1, *a.shape[2:]) for a in xtm)
+    return ce_stats(head(x_c, *head_params), t_c, m_c, z_loss)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _chunked_sums(head, n, z_loss, x, head_params, targets, mask32, weight):
+    """``chunked_ce``'s chunked path with no gradient asked (evaluation):
+    the walk over the chunks, nothing saved."""
+    def chunk_stats(carry, xtm):
+        nll_sum, hit_sum = _chunk_stats(head, z_loss, head_params, xtm)
+        return (carry[0] + nll_sum, carry[1] + hit_sum), None
+
+    (nll_sum, hit_sum), _ = jax.lax.scan(
+        chunk_stats, (jnp.zeros((), jnp.float32),) * 2,
+        _slices(n, x, targets, mask32))
+    return nll_sum * weight, hit_sum
+
+
+def _chunked_sums_fwd(head, n, z_loss, x, head_params, targets, mask32,
+                      weight):
+    """The same walk with a gradient asked: each chunk's ``jax.vjp`` is
+    applied to ``weight`` (the cotangent ``weight · Σ nll`` leaves on a
+    chunk's Σ nll; Σ hit moves nothing) while its logits are there. The
+    carry sums d ``head_params`` in their own dtypes, the scan lays out d x;
+    they are all the backward pass needs."""
+    def chunk_stats(carry, xtm):
+        nll_sum, hit_sum, d_params = carry
+        (nll_c, hit_c), vjp = jax.vjp(
+            lambda x_c, p: _chunk_stats(head, z_loss, p, (x_c, *xtm[1:])),
+            xtm[0], head_params)
+        d_x, d_params_c = vjp((weight, jnp.zeros((), jnp.float32)))
+        d_params = jax.tree.map(jnp.add, d_params, d_params_c)
+        return (nll_sum + nll_c, hit_sum + hit_c, d_params), d_x
+
+    zero = jnp.zeros((), jnp.float32)
+    (nll_sum, hit_sum, d_params), d_x = jax.lax.scan(
+        chunk_stats, (zero, zero, jax.tree.map(jnp.zeros_like, head_params)),
+        _slices(n, x, targets, mask32))
+    d_x = constrain(d_x.swapaxes(0, 1).reshape(x.shape),
+                    "batch", "sequence", *[None] * (x.ndim - 2))
+    # The barrier costs nothing on the device and says what is true: the
+    # backward pass starts from these, whole. Without it the compiler
+    # orders GPT-J's layer scan backward differently behind the walk and
+    # that step's arena is 0.2 GB larger than it was (off the chip and on).
+    return (nll_sum * weight, hit_sum), jax.lax.optimization_barrier(
+        (d_x, d_params))
+
+
+def _chunked_sums_bwd(head, n, z_loss, cotangents, g):
+    """g[0] (the constant 1 under ``value_and_grad`` of the loss) times what
+    the forward walk formed: no product, no scan."""
+    return (*jax.tree.map(lambda d: g[0].astype(d.dtype) * d, cotangents),
+            None, None, None)
+
+
+_chunked_sums.defvjp(_chunked_sums_fwd, _chunked_sums_bwd)
 
 
 def head_gathered(params: Dict[str, Any], tied: bool) -> Dict[str, Any]:
@@ -751,14 +827,16 @@ def next_token_loss(head, x: jax.Array, targets: jax.Array,
     ``mask`` keeps (all, if None), plus ``z_loss`` times the squared log
     partition -> (loss, {"loss", "accuracy", "perplexity"}).
 
-    ``head`` maps hidden [..., d] to logits [..., vocab], over
+    ``head`` maps hidden [..., d] to logits [..., vocab], a closure over
     ``head_gathered``'s params; x is the final hidden states [B, S, d];
-    ``chunk`` is ``chunked_ce``'s."""
+    ``chunk`` is ``chunked_ce``'s. The mean's 1 / tokens goes into
+    ``chunked_ce`` as its ``weight``, so the cotangents its forward walk
+    forms are the loss's own and the backward pass multiplies them by 1."""
     mask32 = jnp.ones(targets.shape, jnp.float32) if mask is None \
         else mask.astype(jnp.float32)
     denom = jnp.maximum(mask32.sum(), 1.0)
-    nll_sum, hit_sum = chunked_ce(head, x, targets, mask32, chunk, z_loss)
-    loss = nll_sum / denom
+    loss, hit_sum = chunked_ce(head, x, targets, mask32, chunk, z_loss,
+                               1.0 / denom)
     return loss, {"loss": loss, "accuracy": hit_sum / denom,
                   "perplexity": jnp.exp(jnp.minimum(loss, 20.0))}
 

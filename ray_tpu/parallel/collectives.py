@@ -24,7 +24,7 @@ import collections
 import math
 import re
 import sys
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 KINDS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
          "collective-permute", "collective-broadcast")
@@ -211,6 +211,16 @@ _KERNEL_CALL = re.compile(
     r'op_name="[^"]*?([\w.\-]+)\)*/pallas_call"')
 
 
+def sub_jaxprs(eqn: Any) -> Iterator[Any]:
+    """The jaxprs an equation holds in its parameters (a ``scan``'s body, a
+    ``pjit``'s, a ``cond``'s branches), closed ones opened."""
+    for param in eqn.params.values():
+        for sub in param if isinstance(param, (list, tuple)) else (param,):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
 def kernel_census(program: Any, a_step: bool = False) -> Dict[str, int]:
     """How many calls of each Pallas kernel a program holds, by the name
     the kernel was given (``pallas_call(name=...)``). ``program`` is a
@@ -231,12 +241,8 @@ def kernel_census(program: Any, a_step: bool = False) -> Dict[str, int]:
                 counts[eqn.params["name"]] += runs
             inside = runs * eqn.params["length"] \
                 if a_step and eqn.primitive.name == "scan" else runs
-            for param in eqn.params.values():
-                for sub in param if isinstance(param, (list, tuple)) \
-                        else (param,):
-                    sub = getattr(sub, "jaxpr", sub)
-                    if hasattr(sub, "eqns"):
-                        walk(sub, inside)
+            for sub in sub_jaxprs(eqn):
+                walk(sub, inside)
 
     walk(getattr(program, "jaxpr", program), 1)
     return dict(counts)

@@ -383,6 +383,45 @@ def test_a_cells_step_runs_the_convolutions_kernels(topo, cell, calls):
         assert "conv_silu_fwd" not in census
 
 
+def _wide_products_a_scan(jaxpr, width):
+    """[``dot_general``s with a dimension of ``width`` in each ``scan``'s
+    body] over the scans that hold any, sub-jaxprs looked through and a
+    scan inside a scan counted as its own."""
+    from ray_tpu.parallel.collectives import sub_jaxprs
+    found = []
+
+    def walk(jaxpr, counts):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general" and any(
+                    width in v.aval.shape for v in eqn.invars + eqn.outvars):
+                counts.append(eqn)
+            inside = [] if eqn.primitive.name == "scan" else counts
+            for sub in sub_jaxprs(eqn):
+                walk(sub, inside)
+            if inside is not counts and inside:
+                found.append(len(inside))
+
+    outside = []
+    walk(getattr(jaxpr, "jaxpr", jaxpr), outside)
+    assert not outside, outside  # the whole logits exist nowhere
+    return found
+
+
+@pytest.mark.parametrize("cell", [
+    "moonlight-16b-a3b-1chip.steady",
+    "phi-4-mini-flash-reasoning-1chip.steady", "gptj-6b-1chip.steady"])
+def test_a_cells_step_runs_three_products_a_chunk_of_the_head(topo, cell):
+    """``lm.chunked_ce`` in the cell's own traced step: one scan over the
+    chunks with the head's product and its two transposes (d x, d W) and no
+    second scan with anything as wide as the vocabulary. Autodiff of the
+    rematerialised scan it replaces traced two, of one and three: the head
+    ran again in the backward scan."""
+    step, args = _a_cells_step(topo, cell)
+    vocab = args[0]["params"]["wte"].shape[0]
+    assert _wide_products_a_scan(
+        jax.make_jaxpr(step.__wrapped__)(*args), vocab) == [3]
+
+
 def test_the_phi4flash_cells_step_runs_its_kernels_as_counted(topo):
     """``phi-4-mini-flash-reasoning-1chip.steady``'s own step, traced for the
     described chip, by the layers its configuration runs (``layers_run``):
@@ -571,16 +610,19 @@ def test_the_lfm2_cells_compiled_step_gathers_no_slab_of_tokens(topo):
 
 
 #: sha256 (first 12) of the lowered step of every cell without a share of
-#: the experts, recorded from PR 42's tree and equal on PR 43's: what a PR
-#: that says "these cells do not move" holds itself to off the chip. A PR
-#: that means to change one of these programs records the new value here
-#: (the failing assertion prints it) and says so in CHANGES.md.
+#: the experts: what a PR that says "these cells do not move" holds itself
+#: to off the chip. Recorded from PR 44's tree, which meant to move all
+#: five (``lm.chunked_ce`` forms its cotangents in the forward walk); from
+#: PR 42's tree and equal on PR 43's they were 6609ff07ec2f, 5c05ed09a078,
+#: cda3dc002fa5, e72cae811a53, df4cd8b6c9e8. A PR that means to change one
+#: of these programs records the new value here (the failing assertion
+#: prints it) and says so in CHANGES.md.
 LOWERED_STEPS = {
-    "gptj-6b-1chip.steady": "6609ff07ec2f",
-    "gptj-6b-4chip.steady": "5c05ed09a078",
-    "moonlight-16b-a3b-1chip.steady": "cda3dc002fa5",
-    "granite-4.0-h-micro-1chip.steady": "e72cae811a53",
-    "phi-4-mini-flash-reasoning-1chip.steady": "df4cd8b6c9e8",
+    "gptj-6b-1chip.steady": "b470aa16aac6",
+    "gptj-6b-4chip.steady": "42d82d54bed3",
+    "moonlight-16b-a3b-1chip.steady": "030ce9c909a1",
+    "granite-4.0-h-micro-1chip.steady": "e0101a71d47b",
+    "phi-4-mini-flash-reasoning-1chip.steady": "b8326d36469b",
 }
 
 
@@ -612,9 +654,8 @@ def _lowered_digest(step, args):
 @pytest.mark.parametrize("cell", LOWERED_STEPS)
 def test_a_step_without_a_share_is_the_program_it_was(topo, cell):
     """The five cells whose model holds every expert or none (the GPT-J
-    cells, Moonlight's whole layer, granite, phi) lower to the text they
-    lowered to before ``ops/moe.py``'s share got its kernel: nothing they
-    run was touched."""
+    cells, Moonlight's whole layer, granite, phi) lower to the text
+    recorded above: nothing they run was touched since."""
     step, args = _a_cells_step(topo, cell)
     assert _lowered_digest(step, args) == LOWERED_STEPS[cell]
 

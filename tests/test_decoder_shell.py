@@ -238,6 +238,108 @@ def test_the_loss_carries_the_familys_metrics(loss_chunk):
         "loss", "accuracy", "perplexity"}
 
 
+def _heads():
+    """{name: (head(params, x), params)}: the heads the families run. The
+    shell's tied and untied (divided or not) and GPT-J's, a matrix with a
+    bias."""
+    from ray_tpu.models import gpt
+    key = jax.random.PRNGKey(5)
+    heads = {}
+    for name, kw in {"tied": dict(tied=True), "untied": {},
+                     "tied_divided": dict(
+                         tied=True, logits_divisor=lambda cfg: 8.0),
+                     "untied_divided": dict(
+                         logits_divisor=lambda cfg: 8.0)}.items():
+        shell = toy(**kw)
+        top = shell.init(replace(CFG, layers=()), key)
+        heads[name] = (lambda p, x, shell=shell: shell.head(p, CFG, x),
+                       {leaf: top[leaf] for leaf in ("wte", "lm_head")
+                        if leaf in top and (leaf == "wte") == shell.tied})
+    cfg = gpt.config("gpt-tiny", d_model=CFG.hidden_size,
+                     vocab_size=CFG.vocab_size, dtype=jnp.float32)
+    w, b = jax.random.normal(key, (2, CFG.hidden_size, CFG.vocab_size))
+    heads["untied_bias"] = (lambda p, x: gpt._head(p, cfg, x),
+                            {"lm_head": w, "lm_head_bias": b[0]})
+    return heads
+
+
+HEADS = ("tied", "untied", "tied_divided", "untied_divided", "untied_bias")
+
+
+@pytest.mark.parametrize("masked,upstream", [(False, 1.0), (True, 3.0)])
+@pytest.mark.parametrize("chunk", [4, 7, 16])  # 7 does not divide S = 10
+@pytest.mark.parametrize("z_loss", [0.0, 1e-2])
+@pytest.mark.parametrize("head", HEADS)
+def test_the_chunked_loss_is_plain_autodiff_of_the_whole_one(
+        head, z_loss, chunk, masked, upstream):
+    """``lm.chunked_ce``'s rule (cotangents formed in the forward walk,
+    scaled in the backward pass) against ``jax.grad`` of ``ce_stats`` over
+    the whole logits: loss, metrics and every gradient leaf, under a mask
+    with zeros, a chunk that does not divide, and a cotangent that is not
+    1; and the primal alone, with no gradient asked."""
+    head, params = _heads()[head]
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.normal(size=(3, 10, CFG.hidden_size)), jnp.float32)
+    targets = jnp.asarray(rng.integers(0, CFG.vocab_size, (3, 10)), jnp.int32)
+    mask = jnp.asarray(rng.integers(0, 2, (3, 10)), jnp.float32) \
+        if masked else None
+
+    def chunked(params, x):
+        loss, metrics = lm.next_token_loss(
+            lambda h: head(params, h), x, targets, mask, chunk, z_loss)
+        return upstream * loss, metrics
+
+    def whole(params, x):
+        mask32 = jnp.ones(targets.shape) if mask is None else mask
+        nll_sum, hit_sum = lm.ce_stats(head(params, x), targets, mask32,
+                                       z_loss)
+        loss = nll_sum / mask32.sum()
+        return upstream * loss, {
+            "loss": loss, "accuracy": hit_sum / mask32.sum(),
+            "perplexity": jnp.exp(loss)}
+
+    (want_loss, want_metrics), want = jax.value_and_grad(
+        whole, (0, 1), has_aux=True)(params, x)
+    (loss, metrics), got = jax.jit(jax.value_and_grad(
+        chunked, (0, 1), has_aux=True))(params, x)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+    for name, value in want_metrics.items():
+        np.testing.assert_allclose(metrics[name], value, rtol=1e-5)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
+    # The primal: what evaluation runs.
+    loss, metrics = chunked(params, x)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+    np.testing.assert_allclose(metrics["accuracy"], want_metrics["accuracy"],
+                               rtol=1e-5)
+
+
+def test_the_chunked_loss_keeps_the_dtypes_autodiff_gives():
+    """bfloat16 hidden states under float32 parameters, as the cells train:
+    d x comes back in x's dtype and d W in the parameter's, each chunk's
+    d W rounded to it before it is summed, as the transposed scan did."""
+    _, params = _heads()["tied"]
+    rng = np.random.default_rng(12)
+    x = jnp.asarray(rng.normal(size=(2, 16, CFG.hidden_size)), jnp.bfloat16)
+    targets = jnp.asarray(rng.integers(0, CFG.vocab_size, (2, 16)), jnp.int32)
+    cfg = replace(CFG, dtype=jnp.bfloat16)
+
+    def loss(params, x, chunk):
+        return lm.next_token_loss(
+            lambda h: toy(tied=True).head(params, cfg, h), x, targets, None,
+            chunk, 0.0)[0]
+
+    got = jax.grad(loss, (0, 1))(params, x, 8)
+    want = jax.grad(loss, (0, 1))(params, x, 0)
+    assert got[1].dtype == jnp.bfloat16 and got[0]["wte"].dtype == jnp.float32
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   rtol=2e-2, atol=1e-4)
+
+
 @pytest.mark.parametrize("experts", [True, False])
 def test_an_ep_mesh_is_refused_by_name_where_there_are_experts(experts):
     mesh = build_mesh(MeshConfig(dp=1, fsdp=1, tp=1, ep=2),
