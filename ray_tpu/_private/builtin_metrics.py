@@ -913,6 +913,27 @@ def train_diff_attention_lambda_max() -> Gauge:
         "softmax map outweighs the first.")
 
 
+def train_dsa_selected_share() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_dsa_selected_share",
+        "Pairs the learned sparse attention's selections kept over the "
+        "causal pairs, counted from the selections the last recorded step "
+        "made (ops/dsa.py select; models/glm_moe_dsa.py): sum_t min(t + 1, "
+        "index_topk) over S (S + 1) / 2, or a row kept another count.")
+
+
+def train_dsa_index_loss() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_dsa_index_loss",
+        "The indexers' own loss in the last recorded step "
+        "(models/glm_moe_dsa.py): KL of the main attention's head-summed "
+        "probabilities over the selection against the softmax of the "
+        "indexer's scores there, mean over rows, summed over the layers "
+        "that own an indexer.")
+
+
 # -- train set-up ----------------------------------------------------------
 # A few dozen events a process (and again at every gang restart), so their
 # durations are observed whether or not anybody traces; the span beside each

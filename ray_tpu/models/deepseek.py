@@ -8,7 +8,8 @@ published instance behind the preset is Moonlight-16B-A3B
 Per layer, as ``DeepseekV3ForCausalLM`` computes it::
 
     x        = RMSNorm(h; g1)                          no bias anywhere
-    q        = x Wq          -> heads x (qk_nope | qk_rope)        (q_lora_rank null)
+    q        = x Wq          -> heads x (qk_nope | qk_rope)        (q_lora_rank null;
+               with a rank, RMSNorm(x W_qa; g_q) W_qb: lm.mla_leaves)
     c | k_r  = x W_kv_a      -> kv_lora_rank | qk_rope ;  c = RMSNorm(c; g_kv)
                k_r is one head, shared by all query heads
     k_n | v  = c W_kv_b      -> heads x (qk_nope | v_head)
@@ -49,7 +50,7 @@ is no exchange of tokens between chips in either model, and a mesh with an
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +70,9 @@ class DeepseekConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    #: Null as published for Moonlight: the query is one matrix. A rank
+    #: makes it two and a norm (``lm.mla_leaves``), as DeepSeek-V3 has it.
+    q_lora_rank: Optional[int] = None
     rope_theta: float = 50000.0
     #: True: the latent layer carries no positions (q_r and k_r are not
     #: rotated); the order then comes from other layers of the model.

@@ -25,7 +25,7 @@ RMSNorm with ``rms_norm_eps``::
                S_t  = S_t + beta_t k_t (v_t - S_t^T k_t)^T        the delta rule
                o_t  = S_t^T q_t
              m      = (RMSNorm(o; g_o) over a head  *  sigmoid((x W_ga) W_gb)) Wo
-    MLA:     deepseek_v3's latent attention (lm.mla; q_lora_rank null), and with
+    MLA:     deepseek_v3's latent attention (lm.mla; q_lora_rank null as published), and with
              mla_use_nope no rotation of q_r, k_r: no positions
     h        = h + m
     x        = RMSNorm(h; g_2)

@@ -12,8 +12,8 @@ sibling ``models/exchange.py``'s. This module imports ``parallel/`` and
 second family needs of a first moves here.
 
 **A family** (``models/deepseek.py``, ``granite.py``, ``afmoe.py``,
-``kimi_linear.py``, ``lfm2.py``, ``phi4flash.py``) is three things, written
-against this module:
+``kimi_linear.py``, ``lfm2.py``, ``phi4flash.py``, ``glm_moe_dsa.py``) is
+three things, written against this module:
 
 * its config, a frozen dataclass under the published keys, with
   ``vocab_size``, ``hidden_size``, the epsilon of its norms, the program's
@@ -37,7 +37,8 @@ against this module:
   (``scan_blocks``); one whose norms are LayerNorms names the final norm's
   bias leaf (``final_norm_bias``) and calls ``layernorm`` in its blocks;
   one whose blocks read a per-layer value no gradient moves gives
-  ``constants``.
+  ``constants``; one whose loss has a second term that its blocks' aux
+  carries gives ``extra_loss``.
 
 ``Decoder`` makes of them ``init``, ``param_specs``, ``hidden_states``,
 ``head``, ``forward``, ``forward_with_aux``, ``loss_of_hidden`` and
@@ -142,7 +143,10 @@ def scan_blocks(cfg, block, x, layers, positions, runs=None,
     256, where the output of a 4096-wide matmul would buy 21); below
     it, and in a block without the kernels (``dot``, a ragged
     sequence's blockwise path, a state-space layer), ``"full"`` keeps
-    nothing. ``"selective"`` keeps the same two beside the five values a
+    nothing but a learned selection of keys where a block makes one
+    (``ops/dsa.py`` ``SELECTION_NAME``: a byte a pair, so that the backward
+    pass neither searches it again nor attends over other keys than the
+    forward pass did). ``"selective"`` keeps the same two beside the five values a
     model names in its block (``attn_q``, ``attn_k``, ``attn_v``,
     ``attn_raw``, ``ffn_in``).
 
@@ -162,7 +166,9 @@ def scan_blocks(cfg, block, x, layers, positions, runs=None,
     the run that made it, and to every run behind it a constant its one
     ``lax.scan`` closes over, neither in the carry nor kept once a reading
     layer. The scan's transpose sums the readers' cotangents, and the block
-    that made the value receives the sum."""
+    that made the value receives the sum; a value of integers
+    (``models/glm_moe_dsa.py``'s selection) has no cotangent and receives
+    none."""
     if runs is not None:
         runs = list(runs)
         depths = [jax.tree.leaves(stack)[0].shape[0] for stack in layers]
@@ -180,6 +186,7 @@ def scan_blocks(cfg, block, x, layers, positions, runs=None,
             auxes.append(aux)
         return x, auxes
     if cfg.remat:
+        from ray_tpu.ops.dsa import SELECTION_NAME
         from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
         if cfg.remat_policy == "selective":
             kept = ("attn_q", "attn_k", "attn_v", "attn_raw", "ffn_in")
@@ -190,7 +197,7 @@ def scan_blocks(cfg, block, x, layers, positions, runs=None,
                 f"Unknown remat_policy {cfg.remat_policy!r}; "
                 "expected 'full' or 'selective'")
         policy = jax.checkpoint_policies.save_only_these_names(
-            *kept, *RESIDUAL_NAMES)
+            *kept, *RESIDUAL_NAMES, SELECTION_NAME)
         block = jax.checkpoint(block, policy=policy)
 
     def scan_body(x, layer):
@@ -496,12 +503,19 @@ def swiglu_leaves(d: int, width: int, prefix: str = ""):
 
 
 def mla_leaves(cfg):
-    """The leaves ``mla`` reads."""
+    """The leaves ``mla`` reads. With ``cfg.q_lora_rank`` the query is of
+    low rank (``w_q_a``, ``q_norm_scale``, ``w_q_b``); null, or a config
+    without the key, one matrix ``wq``."""
     d, h, rank = cfg.hidden_size, cfg.num_attention_heads, cfg.kv_lora_rank
+    q_rank = getattr(cfg, "q_lora_rank", None)
     heads = ("heads", "head_dim")
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    queries = {"wq": ((d, h, qk), ("embed",) + heads, 0.02)} if not q_rank \
+        else {"w_q_a": ((d, q_rank), ("embed", None), 0.02),
+              "q_norm_scale": ((q_rank,), (None,), ones),
+              "w_q_b": ((q_rank, h, qk), (None,) + heads, 0.02)}
     return {
-        "wq": ((d, h, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim),
-               ("embed",) + heads, 0.02),
+        **queries,
         "w_kv_a": ((d, rank + cfg.qk_rope_head_dim), ("embed", None), 0.02),
         "kv_norm_scale": ((rank,), (None,), ones),
         "w_kv_b": ((rank, h, cfg.qk_nope_head_dim + cfg.v_head_dim),
@@ -510,16 +524,19 @@ def mla_leaves(cfg):
     }
 
 
-def mla(cfg, x, layer, positions):
-    """Multi-head latent attention (``deepseek_v3``'s, ``q_lora_rank`` null)
-    on normed x [B, S, d] -> [B, S, d], from a layer's ``wq``, ``w_kv_a``,
-    ``kv_norm_scale``, ``w_kv_b`` and ``wo``. cfg is any config with the
-    latent keys under their published names (``models/deepseek.py``,
-    ``models/kimi_linear.py``); with ``cfg.mla_use_nope`` the "rope"
-    dimensions of q and of the shared key go unrotated."""
+def mla_qkv(cfg, x, layer, positions):
+    """``mla``'s q, k [B, S, H, nope + rope], v [B, S, H, v_head] and the
+    normed low-rank query ``c_q`` [B, S, q_lora_rank] (None where the rank
+    is null) of normed x [B, S, d]."""
     dt = cfg.dtype
     nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    q = jnp.einsum("bsd,dhk->bshk", x, layer["wq"].astype(dt))
+    c_q = None
+    if getattr(cfg, "q_lora_rank", None):
+        c_q = rmsnorm(jnp.einsum("bsd,dr->bsr", x, layer["w_q_a"].astype(dt)),
+                      layer["q_norm_scale"], cfg.rms_norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", c_q, layer["w_q_b"].astype(dt))
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, layer["wq"].astype(dt))
     kv_a = jnp.einsum("bsd,dr->bsr", x, layer["w_kv_a"].astype(dt))
     latent = rmsnorm(kv_a[..., :rank], layer["kv_norm_scale"],
                      cfg.rms_norm_eps)
@@ -532,8 +549,21 @@ def mla(cfg, x, layer, positions):
     k = jnp.concatenate(
         [kv[..., :nope],
          jnp.broadcast_to(k_rope, q_rope.shape)], -1)
-    attn = attention(q, k, kv[..., nope:], cfg)
-    return jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(dt))
+    return q, k, kv[..., nope:], c_q
+
+
+def mla(cfg, x, layer, positions):
+    """Multi-head latent attention (``deepseek_v3``'s) on normed x [B, S, d]
+    -> [B, S, d], from a layer's ``mla_leaves``: the query one matrix
+    ``wq`` where ``q_lora_rank`` is null, else ``RMSNorm(x W_qa) W_qb``.
+    cfg is any config with the latent keys under their published names
+    (``models/deepseek.py``, ``models/kimi_linear.py``,
+    ``models/glm_moe_dsa.py``, which attends over a selection and calls
+    ``mla_qkv`` itself); with ``cfg.mla_use_nope`` the "rope" dimensions of
+    q and of the shared key go unrotated."""
+    q, k, v, _ = mla_qkv(cfg, x, layer, positions)
+    attn = attention(q, k, v, cfg)
+    return jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(cfg.dtype))
 
 
 # -- expert layers --------------------------------------------------------
@@ -913,6 +943,11 @@ class Decoder:
     experts: bool = False
     #: (cfg, aux, targets) -> the metrics ``loss_fn`` adds to the loss's.
     metrics: Optional[Callable] = None
+    #: (cfg, aux, mask) -> a term the blocks' aux carries, added to the
+    #: cross-entropy: ``loss_fn`` then returns and differentiates the sum
+    #: and reports it as ``total_loss``; ``loss`` stays the cross-entropy,
+    #: which ``perplexity`` is of. None: no second term.
+    extra_loss: Optional[Callable] = None
 
     def _top(self, cfg):
         """The leaves outside the layer stacks, as a table."""
@@ -1029,6 +1064,9 @@ class Decoder:
         loss, metrics = next_token_loss(
             partial(self.head, head_gathered(params, self.tied), cfg), x,
             targets, mask, cfg.loss_chunk, 0.0)
+        if self.extra_loss:
+            loss = loss + self.extra_loss(cfg, aux, mask)
+            metrics = {**metrics, "total_loss": loss}
         if self.metrics:
             metrics = {**metrics, **self.metrics(cfg, aux, targets)}
         return loss, metrics
@@ -1037,6 +1075,7 @@ class Decoder:
                 targets: jax.Array, mask: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """Next-token cross-entropy in fp32 (chunked by ``cfg.loss_chunk``),
-        no balance term, with the family's ``metrics``."""
+        no balance term, plus the family's ``extra_loss`` where it has one,
+        with the family's ``metrics``."""
         x, aux = self.hidden_states(params, cfg, tokens)
         return self.loss_of_hidden(params, cfg, x, aux, targets, mask)

@@ -452,6 +452,97 @@ def test_the_phi4flash_cells_step_runs_its_kernels_as_counted(topo):
         "flash_bwd_dq": causal, "flash_bwd_dkv": causal}
 
 
+# Learned sparse attention (models/glm_moe_dsa.py) at GLM-5.2's widths and
+# the cell's length: 64 heads of 256 | 256 over an int8 selection, the
+# indexer's 32 heads of 128 keeping 2048 of up to 4096 keys.
+DSA_SHAPE, DSA_INDEX, DSA_TOPK = (1, 4096, 64, 256), (32, 128), 2048
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_selected_attention_compiles_at_the_published_widths(topo, backward):
+    """``ops/dsa.py``'s kernels for the described chip: the forward with the
+    selection's int8 tile for the causal mask and the head-summed
+    probabilities (heads the inner grid axis), then the two backward
+    kernels; interpret mode cannot see whether Mosaic takes an int8 tile,
+    a third scalar-prefetched table or a float32 tile resident over an
+    axis."""
+    from ray_tpu.ops import dsa
+    from ray_tpu.parallel.collectives import kernel_census
+    q, k, v = _qkv(topo, DSA_SHAPE)
+    B, S = DSA_SHAPE[:2]
+    selection = jax.ShapeDtypeStruct(
+        (B, S, S), jnp.int8, sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def fn(q, k, v, selection):
+        out, lse = dsa.selected_attention(q, k, v, selection, 512, 512, None)
+        probs = dsa.head_probs(*jax.lax.stop_gradient((q, k, lse)),
+                               selection, 512, 512)
+        return out.astype(jnp.float32).sum() + probs.sum()
+
+    fn = jax.grad(fn, (0, 1, 2)) if backward else fn
+    text = jax.jit(fn).lower(q, k, v, selection).compile().as_text()
+    # No gradient reaches the probabilities: differentiated, they are gone.
+    assert kernel_census(text) == (
+        {"dsa_fwd": 1, "dsa_bwd_dq": 1, "dsa_bwd_dkv": 1} if backward
+        else {"dsa_fwd": 1, "dsa_probs": 1})
+
+
+def test_the_indexer_and_the_selection_compile_without_a_sort(topo):
+    """The indexer's scores, the threshold search and the loss at the
+    cell's size: no ``sort`` and no ``top-k`` custom call in the compiled
+    program (the 2048th largest of a row is found by counting), and less
+    than 1.5 GB of temporaries, gradients included: the [rows, 32, S]
+    products of a block of 256 query rows, never a sequence's."""
+    from ray_tpu.ops import dsa
+    one = SingleDeviceSharding(topo.devices[0])
+    B, S = DSA_SHAPE[:2]
+    heads, width = DSA_INDEX
+    q = jax.ShapeDtypeStruct((B, S, heads, width), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((B, S, width), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((B, S, heads), jnp.float32, sharding=one)
+
+    def fn(q, k, w):
+        scores = dsa.index_scores(q, k, w)
+        selection = dsa.select(jax.lax.stop_gradient(scores), DSA_TOPK)
+        return dsa.index_loss(scores, selection.astype(jnp.float32),
+                              selection).sum(), selection
+
+    compiled = jax.jit(jax.grad(fn, (0, 1, 2), has_aux=True)).lower(
+        q, k, w).compile()
+    text = compiled.as_text()
+    assert " sort(" not in text and "TopK" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_the_glm_cells_step_runs_its_kernels_as_counted(topo):
+    """``glm-5.2-1chip.steady``'s own step, traced for the described chip,
+    by the layers its configuration runs: the selection's forward kernel
+    twice a layer (S = 4096 is under 32 x 256, its outputs are not worth
+    keeping: ``flash_attention.worth_keeping``), the two backward kernels
+    once a layer, the head-summed probabilities twice a layer that owns an
+    indexer (its loss is part of the rematerialised block), the share's way
+    back to tokens in the expert layers, and no causal flash kernel."""
+    from ray_tpu.parallel.collectives import kernel_census
+    cell = "glm-5.2-1chip.steady"
+    step, args = _a_cells_step(topo, cell)
+    here = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs")
+    with open(os.path.join(here, cell.rsplit(".", 1)[0] + ".json")) as f:
+        config = json.load(f)
+    layers = config["layers_run"]
+    owners = sum(config["indexer_types"][l] == "full" for l in layers)
+    assert len(layers) == 5 and owners == 2
+    assert config["layout"]["seq_len"] < 32 * config["v_head_dim"]
+    census = kernel_census(jax.make_jaxpr(step.__wrapped__)(*args),
+                           a_step=True)
+    assert {name: n for name, n in census.items() if name and name.startswith(
+        ("dsa_", "flash_"))} == {
+        "dsa_fwd": 2 * len(layers), "dsa_bwd_dq": len(layers),
+        "dsa_bwd_dkv": len(layers), "dsa_probs": 2 * owners}
+    assert census["moe_rows_to_tokens"] >= 4
+
+
 def test_the_phi4flash_cells_reference_check_holds_less_than_its_step(topo):
     """The program ``benchmark/runners/train.py`` ``_reference_check`` runs
     on ``phi-4-mini-flash-reasoning-1chip.steady`` before the first step

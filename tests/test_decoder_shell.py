@@ -489,3 +489,119 @@ def test_layernorm_is_torchs():
         torch.from_numpy(bias), 1e-5).numpy()
     np.testing.assert_allclose(lm.layernorm(x, scale, bias, 1e-5), want,
                                atol=1e-5)
+
+
+# -- a second term of the loss, an integer value handed on -------------------
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("loss_chunk", [0, 4])
+def test_a_familys_second_term_is_added_and_differentiated(loss_chunk, masked):
+    """``extra_loss``: what ``loss_fn`` returns, and differentiates, is the
+    cross-entropy plus the family's term of its blocks' aux; ``loss`` stays
+    the cross-entropy (``perplexity`` is of it) and ``total_loss`` is the
+    sum. A family without the hook reports no ``total_loss``."""
+    cfg = replace(CFG, floors=True, loss_chunk=loss_chunk)
+    term = lambda cfg, aux, mask: cfg.multiplier * (aux["floor"] ** 2).sum() \
+        * (1.0 if mask is None else mask.sum() / mask.size)
+    shell = toy(extra_loss=term)
+    params = shell.init(cfg, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: a + 0.1, params)
+    tokens, targets = _tokens(cfg), _tokens(cfg)[:, ::-1]
+    mask = jnp.ones(tokens.shape).at[0, :2].set(0.0) if masked else None
+    plain, plain_metrics = toy().loss_fn(params, cfg, tokens, targets, mask)
+    loss, metrics = shell.loss_fn(params, cfg, tokens, targets, mask)
+    _, aux = shell.hidden_states(params, cfg, tokens)
+    assert "total_loss" not in plain_metrics
+    np.testing.assert_allclose(metrics["loss"], plain, rtol=1e-6)
+    np.testing.assert_allclose(metrics["perplexity"], jnp.exp(plain),
+                               rtol=1e-5)
+    np.testing.assert_allclose(loss, plain + term(cfg, aux, mask), rtol=1e-6)
+    np.testing.assert_allclose(metrics["total_loss"], loss, rtol=1e-6)
+    grads = jax.grad(lambda p: shell.loss_fn(p, cfg, tokens, targets,
+                                             mask)[0])(params)
+    want = jax.grad(lambda p: toy().loss_fn(p, cfg, tokens, targets, mask)[0]
+                    + term(cfg, shell.hidden_states(p, cfg, tokens)[1],
+                           mask))(params)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+def _masking_block(cfg, kind, h, layer, positions, shared):
+    """``counted`` layers hand on which of their outputs are positive, as
+    int8; ``plain`` layers behind one keep their own output there alone."""
+    out, aux = _block(cfg, kind, h, layer, positions)
+    if kind == "counted":
+        aux = dict(aux, **{lm.HANDED_ON: {"kept": (out > 0).astype(jnp.int8)}})
+    elif "kept" in shared:
+        out = h + (out - h) * shared["kept"]
+    return out, aux
+
+
+def _masking_by_hand(params, cfg, tokens):
+    h = jnp.take(params["wte"], tokens, axis=0)
+    kept = None
+    for run, kind, depth in lm.runs(cfg.layers):
+        for i in range(depth):
+            layer = jax.tree.map(lambda a: a[i], params[run])
+            out, _ = _block(cfg, kind, h, layer, None)
+            if kind == "counted":
+                kept = (out > 0).astype(jnp.int8)
+            elif kept is not None:
+                out = h + (out - h) * kept
+            h = out
+    return lm.rmsnorm(h, params["lnf_scale"], cfg.rms_norm_eps)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_an_integer_value_is_handed_on_and_carries_no_cotangent(remat):
+    """A handed-on value may be integers (a selection, a mask): it leaves
+    its run among the scan's outputs and enters the readers' scan as a
+    constant like a float one, the transpose gives it a zero of no dtype,
+    and values and gradients are the layers called one by one."""
+    cfg = replace(CFG, layers=SHARING, remat=remat)
+    shell = lm.Decoder(name="toy", shapes=_shapes, block=_masking_block,
+                       shares=True)
+    params = shell.init(cfg, jax.random.PRNGKey(0))
+    tokens = _tokens(cfg)
+    got, _ = shell.hidden_states(params, cfg, tokens)
+    np.testing.assert_allclose(got, _masking_by_hand(params, cfg, tokens),
+                               rtol=1e-5, atol=1e-6)
+    weight = jax.random.normal(jax.random.PRNGKey(1), got.shape)
+    grads = jax.jit(jax.grad(lambda p: (shell.hidden_states(
+        p, cfg, tokens)[0] * weight).sum()))(params)
+    want = jax.grad(lambda p: (_masking_by_hand(p, cfg, tokens)
+                               * weight).sum())(params)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    jaxpr = jax.make_jaxpr(lambda p: shell.hidden_states(p, cfg, tokens))(
+        params)
+    readers = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"][-1]
+    consts = [(v.aval.shape, v.aval.dtype) for v in readers.invars[
+        :readers.params["num_consts"]]]
+    assert (tokens.shape + (cfg.hidden_size,), jnp.int8) in consts
+
+
+def test_a_kept_selection_is_not_searched_twice():
+    """``scan_blocks``' policy keeps what a block names
+    ``ops.dsa.SELECTION_NAME`` beside the flash kernel's two: under
+    ``full`` the backward pass reads the selection the forward pass made."""
+    from jax.ad_checkpoint import checkpoint_name
+    from ray_tpu.ops.dsa import SELECTION_NAME
+    calls = []
+
+    def block(cfg, kind, h, layer, positions):
+        def chosen(x):
+            calls.append(1)
+            return x > 0
+        picked = jax.pure_callback(
+            chosen, jax.ShapeDtypeStruct(h.shape, jnp.bool_),
+            jax.lax.stop_gradient(h))
+        picked = checkpoint_name(picked.astype(jnp.int8), SELECTION_NAME)
+        return h + jnp.tanh(h @ layer["w"]) * picked, None
+
+    cfg = replace(CFG, layers=("plain", "plain"), remat=True)
+    shell = lm.Decoder(name="toy", shapes=_shapes, block=block)
+    params = shell.init(cfg, jax.random.PRNGKey(0))
+    jax.block_until_ready(jax.grad(lambda p: shell.hidden_states(
+        p, cfg, _tokens(cfg))[0].sum())(params))
+    assert len(calls) == 2  # once a layer: the forward pass alone
