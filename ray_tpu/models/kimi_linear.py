@@ -43,11 +43,15 @@ trained by the gradient, and no rule moves it here. The loss is the
 cross-entropy alone.
 
 The recurrence is ``ops/kda.py``'s chunked kernel pair (through
-``lm.delta_rule``), the short convolutions with their SiLU ``lm.conv_silu``
-(``ops/short_conv.py``'s fused pass each way over each of q, k, v where the
-shapes tile, else its ``jax.numpy`` form in float32), the latent
-attention and the expert FFN ``lm.mla`` and ``lm.expert_ffn`` (shared with
-``models/deepseek.py``), the expert layer ``ops/moe.py``. This module is the
+``lm.delta_rule``), which takes q and k as the convolutions leave them and
+``a`` itself: the line ``q, k = ...`` above (the lengths, ``head_dim^-0.5``)
+and the running sums of ``a`` are the kernels' own, on a chunk's rows in
+VMEM, and nothing of them is this module's. The short convolutions with
+their SiLU are ``lm.conv_silu`` (``ops/short_conv.py``'s fused pass each way
+over each of q, k, v where the shapes tile, else its ``jax.numpy`` form in
+float32), the latent attention and the expert FFN ``lm.mla`` and
+``lm.expert_ffn`` (shared with ``models/deepseek.py``), the expert layer
+``ops/moe.py``. This module is the
 family's config, its table of leaves (``_shapes``, with the decay's two
 draws) and its block; parameters and specs from the table, the lookup, the
 layer scan, the head and loss and the expert layers' counters are
@@ -253,14 +257,6 @@ def _leaves_of(shapes, kind: str):
 
 # -- forward ------------------------------------------------------------
 
-def _unit(y, scale: float = 1.0):
-    """y [..., K] with every vector of K brought to length ``scale`` (its
-    own length held above 1e-6), in float32, in the dtype it came in."""
-    y32 = y.astype(jnp.float32)
-    return (y32 * (scale / jnp.maximum(
-        jnp.sqrt((y32 * y32).sum(-1, keepdims=True)), 1e-6))).astype(y.dtype)
-
-
 def _kda(cfg: KimiLinearConfig, x, layer):
     """Kimi Delta Attention on normed x [B, S, d] -> (m [B, S, d], the
     most negative running log-decay a chunk reaches)."""
@@ -277,7 +273,6 @@ def _kda(cfg: KimiLinearConfig, x, layer):
     with jax.named_scope("conv"):
         q, k, v = short_conv("q"), short_conv("k"), short_conv("v")
 
-    q, k = _unit(q, hd ** -0.5), _unit(k)
     with jax.named_scope("kda_gate"):
         low = jnp.einsum("bsd,dr->bsr", x, layer["w_fa"].astype(dt))
         rate = jnp.einsum("bsr,rhk->bshk", low, layer["w_fb"].astype(dt))

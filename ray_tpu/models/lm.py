@@ -431,9 +431,11 @@ def short_conv(bcx, w):
 def delta_rule(q, k, v, a, beta):
     """The gated delta rule with a decay a channel, ``S_t = Diag(exp(a_t))
     S_(t-1)``, ``S_t += beta_t k_t (v_t - S_t^T k_t)^T``, ``o_t = S_t^T
-    q_t``, by ``ops/kda.py``'s chunked kernels. q, k, a: [B, S, H, K], v:
-    [B, S, H, V], beta: [B, S, H] -> [B, S, H, V]. Under a mesh the kernels
-    run per shard of the batch, as ``state_space``'s do."""
+    q_t``, by ``ops/kda.py``'s chunked kernels, under the published kernel's
+    contract: q and k come as the layer has them, and the rule brings every
+    head's k to length 1 and q to length ``K ** -0.5`` itself. q, k, a: [B,
+    S, H, K], v: [B, S, H, V], beta: [B, S, H] -> [B, S, H, V]. Under a mesh
+    the kernels run per shard of the batch, as ``state_space``'s do."""
     from ray_tpu.ops.kda import kda
     return _over_batch_shards(kda, (q, k, v, a, beta), (True,) * 5)
 

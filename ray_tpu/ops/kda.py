@@ -11,10 +11,15 @@ first token::
 
 ``q``, ``k`` [batch, S, H, K] and ``v`` [batch, S, H, V] hold H heads, ``a``
 [batch, S, H, K] the log-decays (<= 0, float32), ``beta`` [batch, S, H] the
-write strengths (0..1, float32). The literal recurrence (``kda_recurrent``)
-is S sequential steps; the chunked form does a chunk of L steps as matrix
-products. With ``cum_t`` the sum of ``a`` from the chunk's first token to t
-(a vector of K) and ``S_0`` the state on entry, the state inside the chunk is
+write strengths (0..1, float32). **q and k come as the layer has them, not
+normalised**: every entry here first brings each head's row of k to length 1
+and of q to length ``K ** -0.5`` (a row's own length held above 1e-6, in
+float32, rounded to the dtype it came in: ``_unit_rows``), as the published
+kernel does, and the ``q_t``, ``k_t`` above are those. The literal
+recurrence (``kda_recurrent``) is S sequential steps; the chunked form does
+a chunk of L steps as matrix products. With ``cum_t`` the sum of ``a`` from
+the chunk's first token to t (a vector of K) and ``S_0`` the state on entry,
+the state inside the chunk is
 
     S_t = Diag(exp(cum_t)) S_0 + sum_{s<=t} Diag(exp(cum_t - cum_s)) k_s u_s^T
 
@@ -67,9 +72,13 @@ call at 16k tokens of 32 heads, 0.3 ms to move against 5 ms to make again),
 which the backward reads: it walks the chunks in reverse with the cotangent
 of the state carried the same way, and differentiates the chunk's own
 function (``_chunk``) where it stands, given that inverse.
-``cum`` is made outside the kernels, by XLA, which differentiates the running
-sum too: the kernels take ``cum`` and return its cotangent. In a trace the
-kernels are ``kda_fwd`` and ``kda_bwd``, under the scope ``kda``.
+The kernels take what the layer has: q and k as the convolutions leave them
+and ``a`` itself. The chunk's function normalises its [L, K] tiles of q and
+k and sums ``a`` over its rows where they lie in VMEM (``_running_sum``:
+seven shifts of the rows and adds, its cotangent the same sum from the last
+row upwards), so the backward kernel returns the cotangents of the raw q, k
+and of ``a``, and XLA runs nothing over [S, H K] around the pair. In a trace
+the kernels are ``kda_fwd`` and ``kda_bwd``, under the scope ``kda``.
 
 ``kda`` is the one entry: the kernels where the shapes tile (S a multiple of
 the chunk, K and V multiples of 128 lanes), else ``kda_chunked``, the same
@@ -118,6 +127,12 @@ def kda_recurrent(q, k, v, a, beta):
     o [batch, S, H, V]. The chunked forms' oracle."""
     q, k, v, a, beta = (x.astype(F32) for x in (q, k, v, a, beta))
     batch, _, heads, width = q.shape
+
+    def unit(x):
+        return x * jax.lax.rsqrt(
+            jnp.maximum((x * x).sum(-1, keepdims=True), 1e-12))
+
+    q, k = unit(q) * width ** -0.5, unit(k)
 
     def step(state, token):
         q_t, k_t, v_t, a_t, beta_t = token
@@ -324,14 +339,54 @@ def _unit_lower_inverse(lower, exact: bool, made=None):
     return _inverse_of(lower, made, exact)
 
 
-def _chunk(q, k, v, cum, beta, state, inverse=None):
-    """One chunk of one head: q, k [L, K], v [L, V], cum [L, K] float32 (the
-    running sum of ``a`` inside the chunk), beta [L, 1] float32, state [V,
-    K] float32 (the entry state, transposed) -> (o [L, V] float32, the exit
+def _unit_rows(y, scale: float = 1.0):
+    """y [L, K] with every row brought to length ``scale`` (its own length
+    held above 1e-6), in float32, in the dtype it came in. The floor is on
+    the sum of squares, under the root: a row of zeros stays zeros and its
+    cotangent is the output's times ``scale / 1e-6``, where a floor on the
+    root itself would hand the root's slope at 0 a 0 / 0."""
+    y32 = y.astype(F32)
+    length = jnp.sqrt(jnp.maximum((y32 * y32).sum(-1, keepdims=True), 1e-12))
+    return (y32 * (scale / length)).astype(y.dtype)
+
+
+def _sum_rows(x, upwards: bool):
+    """x [L, K] float32 -> [L, K]: row t the sum of rows 0 .. t (of rows t ..
+    L - 1 if ``upwards``), by doubling: log2 L times every row takes in the
+    row 1, 2, 4, ... before it. A sum is a tree of pairs, its rounding
+    log2 L deep where ``jnp.cumsum``'s row after row is L deep."""
+    length = x.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    by = 1
+    while by < length:
+        arrives = rows < length - by if upwards else rows >= by
+        x = x + jnp.where(arrives, jnp.roll(x, -by if upwards else by, 0),
+                          0.0)
+        by *= 2
+    return x
+
+
+@jax.custom_vjp
+def _running_sum(a):
+    """cum [L, K] float32: the running sum of ``a`` [L, K] down the chunk's
+    rows. Its cotangent is the same sum from the last row upwards."""
+    return _sum_rows(a, False)
+
+
+_running_sum.defvjp(lambda a: (_running_sum(a), None),
+                    lambda _, ct: (_sum_rows(ct, True),))
+
+
+def _chunk(q, k, v, a, beta, state, inverse=None):
+    """One chunk of one head: q, k [L, K] as the layer has them, v [L, V],
+    a [L, K] float32 (the log-decays), beta [L, 1] float32, state [V, K]
+    float32 (the entry state, transposed) -> (o [L, V] float32, the exit
     state [V, K], the inverse of ``I + A`` [L, L] float32). ``inverse`` is
     that inverse where an earlier call on the same chunk returned it."""
     length = q.shape[0]
     dtype = q.dtype
+    q, k = _unit_rows(q, q.shape[1] ** -0.5), _unit_rows(k)
+    cum = _running_sum(a)
     q32, k32, v32 = q.astype(F32), k.astype(F32), v.astype(F32)
     rows, cols, levels = _levels(length)
     qk, kk = _pair_products(q32, k32, cum, dtype, rows, cols, levels)
@@ -350,15 +405,6 @@ def _chunk(q, k, v, cum, beta, state, inverse=None):
 
 
 # -- the chunked form in jax.numpy -----------------------------------------
-
-def chunk_sums(a, chunk: int):
-    """cum [batch, S, H, K] float32: the running sum of ``a`` inside each
-    chunk of ``chunk`` positions (S a multiple of it)."""
-    batch, seq = a.shape[:2]
-    by_chunk = a.astype(F32).reshape((batch, seq // chunk, chunk)
-                                     + a.shape[2:])
-    return jnp.cumsum(by_chunk, axis=2).reshape(a.shape)
-
 
 def decay_floor(a, chunk: int = CHUNK):
     """The most negative ``cum`` any chunk reaches: the least of the chunks'
@@ -385,7 +431,6 @@ def kda_chunked(q, k, v, a, beta, chunk: int = CHUNK):
             jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
             for x in (q, k, v, a, beta))
     n = (seq + pad) // chunk
-    cum = chunk_sums(a, chunk)
 
     def by_chunk(x):
         """[batch, S, H, ...] -> [chunks, batch, H, L, ...]"""
@@ -401,7 +446,7 @@ def kda_chunked(q, k, v, a, beta, chunk: int = CHUNK):
     _, out = jax.lax.scan(
         carry, jnp.zeros((batch, heads, v.shape[-1], width), F32),
         tuple(by_chunk(x) for x in (
-            q, k, v, cum, beta.astype(F32)[..., None])))
+            q, k, v, a.astype(F32), beta.astype(F32)[..., None])))
     out = jnp.moveaxis(out, (0, 2), (1, 3)).reshape(
         batch, seq + pad, heads, v.shape[-1])
     return out[:, :seq].astype(v.dtype)
@@ -409,9 +454,9 @@ def kda_chunked(q, k, v, a, beta, chunk: int = CHUNK):
 
 # -- the kernels -------------------------------------------------------------
 
-def _kda_fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, o_ref, entry_ref,
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, o_ref, entry_ref,
                     inverse_ref, state_scr):
-    """One chunk of one head: q/k/cum [L, K], v/o [L, V], beta [L, 1];
+    """One chunk of one head: q/k/a [L, K], v/o [L, V], beta [L, 1];
     entry [V, K] is the head's state on entry, transposed, inverse [L, L]
     the chunk's ``(I + A)^-1``."""
     @pl.when(pl.program_id(2) == 0)
@@ -421,33 +466,34 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, o_ref, entry_ref,
     state = state_scr[...]
     entry_ref[...] = state
     out, state_scr[...], inverse_ref[...] = _chunk(
-        q_ref[...], k_ref[...], v_ref[...], cum_ref[...], beta_ref[...],
+        q_ref[...], k_ref[...], v_ref[...], a_ref[...], beta_ref[...],
         state)
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _kda_bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, entry_ref,
-                    inverse_ref, do_ref, dq_ref, dk_ref, dv_ref, dcum_ref,
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, entry_ref,
+                    inverse_ref, do_ref, dq_ref, dk_ref, dv_ref, da_ref,
                     dbeta_ref, dstate_scr):
     """The forward's grid step with the chunks in reverse (the index maps
     turn them round): the chunk's function is differentiated where it
-    stands, from its inputs and the entry state and the inverse the forward
-    wrote, and the cotangent of the head's state is carried in
-    ``dstate_scr``."""
+    stands (the rows' normalisation and the running sum with it: the
+    cotangents are the raw q's, k's and ``a``'s), from its inputs and the
+    entry state and the inverse the forward wrote, and the cotangent of the
+    head's state is carried in ``dstate_scr``."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate_scr[...] = jnp.zeros(dstate_scr.shape, F32)
 
     inverse = inverse_ref[...]
     _, pullback = jax.vjp(lambda *xs: _chunk(*xs, inverse)[:2],
-                          q_ref[...], k_ref[...], v_ref[...], cum_ref[...],
+                          q_ref[...], k_ref[...], v_ref[...], a_ref[...],
                           beta_ref[...], entry_ref[...])
-    dq, dk, dv, dcum, dbeta, dstate_scr[...] = pullback(
+    dq, dk, dv, da, dbeta, dstate_scr[...] = pullback(
         (do_ref[...].astype(F32), dstate_scr[...]))
     dq_ref[...] = dq
     dk_ref[...] = dk
     dv_ref[...] = dv
-    dcum_ref[...] = dcum
+    da_ref[...] = da
     dbeta_ref[...] = dbeta
 
 
@@ -500,13 +546,13 @@ def _call(kernel, name: str, reverse: bool, operands, out_kinds, out_shape,
 _INPUTS = ("key", "key", "value", "key", "beta")
 
 
-def _forward(q, k, v, cum, beta, chunk: int):
+def _forward(q, k, v, a, beta, chunk: int):
     """(o [batch, S, H * V], entry states [batch, H, chunks, V, K], inverses
-    [batch, H, chunks, L, L]) by the forward kernel; q, k, cum are [batch,
+    [batch, H, chunks, L, L]) by the forward kernel; q, k, a are [batch,
     S, H * K], v [batch, S, H * V], beta [batch, H, S, 1]."""
     return _call(
         _kda_fwd_kernel, "kda_fwd", False,
-        list(zip((q, k, v, cum, beta), _INPUTS)),
+        list(zip((q, k, v, a, beta), _INPUTS)),
         ("value", "state", "inverse"),
         lambda *state: [jax.ShapeDtypeStruct(v.shape, v.dtype),
                         jax.ShapeDtypeStruct(state, F32),
@@ -514,9 +560,9 @@ def _forward(q, k, v, cum, beta, chunk: int):
         chunk)
 
 
-def _backward(q, k, v, cum, beta, entry, inverse, do, chunk: int):
-    """Cotangents (dq, dk, dv, dcum, dbeta) by the backward kernel."""
-    inputs = (q, k, v, cum, beta)
+def _backward(q, k, v, a, beta, entry, inverse, do, chunk: int):
+    """Cotangents (dq, dk, dv, da, dbeta) by the backward kernel."""
+    inputs = (q, k, v, a, beta)
     return _call(
         _kda_bwd_kernel, "kda_bwd", True,
         list(zip(inputs + (entry, inverse, do),
@@ -526,13 +572,13 @@ def _backward(q, k, v, cum, beta, entry, inverse, do, chunk: int):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kda_kernels(q, k, v, cum, beta, chunk: int):
-    return _forward(q, k, v, cum, beta, chunk)[0]
+def _kda_kernels(q, k, v, a, beta, chunk: int):
+    return _forward(q, k, v, a, beta, chunk)[0]
 
 
-def _kda_kernels_fwd(q, k, v, cum, beta, chunk):
-    out, entry, inverse = _forward(q, k, v, cum, beta, chunk)
-    return out, (q, k, v, cum, beta, entry, inverse)
+def _kda_kernels_fwd(q, k, v, a, beta, chunk):
+    out, entry, inverse = _forward(q, k, v, a, beta, chunk)
+    return out, (q, k, v, a, beta, entry, inverse)
 
 
 def _kda_kernels_bwd(chunk, residuals, do):
@@ -544,8 +590,9 @@ _kda_kernels.defvjp(_kda_kernels_fwd, _kda_kernels_bwd)
 
 def kda(q, k, v, a, beta, chunk: int = CHUNK):
     """o [batch, S, H, V] of the recurrence at the top of this file, by
-    chunks of ``chunk`` positions. q, k [batch, S, H, K]; v [batch, S, H,
-    V]; a [batch, S, H, K] <= 0; beta [batch, S, H]. The kernels where the
+    chunks of ``chunk`` positions. q, k [batch, S, H, K] as the layer has
+    them (normalised here, q scaled by ``K ** -0.5``); v [batch, S, H, V];
+    a [batch, S, H, K] <= 0; beta [batch, S, H]. The kernels where the
     shapes tile, else ``kda_chunked``."""
     batch, seq, heads, width = q.shape
     v_width = v.shape[-1]
@@ -554,10 +601,10 @@ def kda(q, k, v, a, beta, chunk: int = CHUNK):
     if chunk < _TILE or chunk & (chunk - 1):
         raise ValueError(f"chunk={chunk}: a power of two, {_TILE} at least")
     with jax.named_scope("kda"):
-        cum = chunk_sums(a, chunk).reshape(batch, seq, heads * width)
         out = _kda_kernels(
             q.reshape(batch, seq, heads * width),
             k.astype(q.dtype).reshape(batch, seq, heads * width),
-            v.reshape(batch, seq, heads * v_width), cum,
+            v.reshape(batch, seq, heads * v_width),
+            a.astype(F32).reshape(batch, seq, heads * width),
             beta.astype(F32).swapaxes(1, 2)[..., None], chunk)
         return out.reshape(v.shape)

@@ -241,12 +241,17 @@ def test_selective_scan_compiles_at_the_published_widths(topo, backward):
                          ids=["kda_fwd", "kda_fwd_and_bwd"])
 def test_delta_rule_compiles_at_the_published_widths(topo, backward):
     """Kimi-Linear-48B-A3B's KDA layer at the cell's 16k tokens: 32 heads
-    with keys and values of 128, chunks of ``kda.CHUNK`` (ops/kda.py). Blocks of rows
-    reshaped by sublane tiles, the diagonal blocks' inverses side by side in
-    two registers, a forward kernel that writes each chunk's inverse [128,
-    128] beside its entry state, and a backward kernel that is the chunk's
-    function differentiated inside the kernel given that inverse: what the
-    interpreter lets through and Mosaic may not."""
+    with keys and values of 128, chunks of ``kda.CHUNK`` (ops/kda.py), on
+    q, k in bfloat16 as the convolutions leave them and the log-decays
+    themselves. Rows brought to unit length by a lane reduction and the
+    running sum of ``a`` as seven shifts of the chunk's rows (three of them
+    inside a sublane tile) with their adds, both differentiated in the
+    backward kernel; blocks of rows reshaped by sublane tiles, the diagonal
+    blocks' inverses side by side in two registers, a forward kernel that
+    writes each chunk's inverse [128, 128] beside its entry state, and a
+    backward kernel that is the chunk's function differentiated inside the
+    kernel given that inverse: what the interpreter lets through and Mosaic
+    may not."""
     from ray_tpu.ops import kda
     from ray_tpu.parallel.collectives import kernel_census
     one_chip = SingleDeviceSharding(topo.devices[0])

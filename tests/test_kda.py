@@ -1,10 +1,12 @@
 """ops/kda.py: the Pallas kernel pair (interpreted) against the chunked
 ``jax.numpy`` form against the literal recurrence, outputs and every
-gradient; at log-decays of -1.6 a step over several chunks, where a factor
-``exp(-cum)`` would overflow float32 inside one chunk; at a length that is
-not a multiple of the chunk; and the literal recurrence against the
-installed ``transformers``' gated delta rule where the decay is equal over a
-head's channels.
+gradient, all three on q and k as a layer has them (not normalised) and on
+the log-decays themselves; at log-decays of -1.6 a step over several
+chunks, where a factor ``exp(-cum)`` would overflow float32 inside one
+chunk; at a length that is not a multiple of the chunk; with rows of zeros
+in q and in k; the chunk's running sum against float64's; and the literal
+recurrence against the installed ``transformers``' gated delta rule where
+the decay is equal over a head's channels.
 
 Everything runs on the CPU in float32 under the highest matmul precision,
 where all three compute the same sums in another order.
@@ -23,12 +25,18 @@ ARGS = "qkvab"
 
 
 def inputs(seed, batch=1, seq=256, heads=2, width=128, strong=False,
-           v_width=None):
+           v_width=None, zero_rows=False):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     shape = (batch, seq, heads, width)
-    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q = unit(jax.random.normal(ks[0], shape)) * width ** -0.5
-    k = unit(jax.random.normal(ks[1], shape))
+    # As a layer's convolutions leave them: rows of any length (0.5 .. 3
+    # times the draw's), which the rule brings to 1 and width^-0.5 itself.
+    q = jax.random.normal(ks[0], shape) * jnp.linspace(0.5, 3.0, seq)[
+        None, :, None, None]
+    k = 0.3 * jax.random.normal(ks[1], shape)
+    if zero_rows:
+        # Rows the 1e-6 floor holds: zeros in q, in k, and in both at once.
+        q = q.at[:, 5::17].set(0.0)
+        k = k.at[:, 7::17].set(0.0).at[:, 5 + 17].set(0.0)
     v = jax.random.normal(ks[2], shape[:3] + (v_width or width,))
     # The published initialisation's range, -0.001 to -1.6 a step ...
     a = -jnp.exp(jax.random.uniform(ks[3], shape, minval=np.log(1e-3),
@@ -58,26 +66,53 @@ def assert_close(got, want, tol=1e-5):
     assert float(jnp.linalg.norm((got - want).ravel())) < tol * norm
 
 
-@pytest.fixture(scope="module")
-def strong():
-    """Four chunks of 64 at the strongest decay: kernels, chunked form and
-    recurrence."""
-    args = inputs(0, strong=True)
+def three_ways(**drawn):
+    args = inputs(0, strong=True, **drawn)
     return {"recurrent": both(kda.kda_recurrent, args),
             "chunked": both(partial(kda.kda_chunked, chunk=64), args),
             "kernels": both(partial(kda.kda, chunk=64), args)}
 
 
+@pytest.fixture(scope="module")
+def strong():
+    """Four chunks of 64 at the strongest decay: kernels, chunked form and
+    recurrence."""
+    return three_ways()
+
+
+@pytest.fixture(scope="module")
+def zero_rows():
+    """The same with rows of zeros in q and in k, which the normalisation
+    leaves zeros (their length is held above 1e-6)."""
+    return three_ways(zero_rows=True)
+
+
+DRAWS = pytest.mark.parametrize("draw", ["strong", "zero_rows"])
+
+
+@DRAWS
 @pytest.mark.parametrize("which", ["chunked", "kernels"])
-def test_outputs_match_the_recurrence_at_the_strongest_decay(strong, which):
-    assert_close(strong[which][0], strong["recurrent"][0])
+def test_outputs_match_the_recurrence_at_the_strongest_decay(
+        draw, which, request):
+    found = request.getfixturevalue(draw)
+    assert_close(found[which][0], found["recurrent"][0])
 
 
+@DRAWS
 @pytest.mark.parametrize("arg", range(5), ids=list(ARGS))
 @pytest.mark.parametrize("which", ["chunked", "kernels"])
 def test_gradients_match_the_recurrence_at_the_strongest_decay(
-        strong, which, arg):
-    assert_close(strong[which][1][arg], strong["recurrent"][1][arg])
+        draw, which, arg, request):
+    found = request.getfixturevalue(draw)
+    got, want = found[which][1][arg], found["recurrent"][1][arg]
+    if draw == "zero_rows" and arg < 2:
+        # A zero row's own gradient is the floor's slope, 1e6 times its
+        # cotangent: finite, and held on its own so that it does not hide
+        # the other rows'.
+        at = slice(5, None, 17) if arg == 0 else slice(7, None, 17)
+        assert_close(got[:, at], want[:, at])
+        got, want = got.at[:, at].set(0.0), want.at[:, at].set(0.0)
+    assert_close(got, want)
 
 
 def test_the_kernels_are_the_chunked_form(strong):
@@ -135,7 +170,8 @@ def test_no_factor_overflows_where_exp_of_minus_cum_would():
     exp(-cum) is inf in float32 there, and every factor the chunk forms
     stays finite (bfloat16 inputs, as the step runs them)."""
     args = inputs(5, seq=128, strong=True)
-    cum = kda.chunk_sums(args[3], 64)
+    cum = jax.vmap(jax.vmap(kda._running_sum, 1, 1))(
+        args[3].reshape(2, 64, 2, 128))
     assert float(cum.min()) < -100 and bool(jnp.isinf(jnp.exp(-cum)).any())
     assert float(kda.decay_floor(args[3], 64)) == pytest.approx(
         float(cum.min()), rel=1e-6)
@@ -153,6 +189,29 @@ def test_no_factor_overflows_where_exp_of_minus_cum_would():
                           argnums=(0, 1, 2, 3, 4))(*low)
     for got, ref in zip((out,) + grads, (want,) + want_grads):
         assert_close(got.astype(jnp.float32), ref, tol=0.02)
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+@pytest.mark.parametrize("upwards", [False, True], ids=["down", "upwards"])
+def test_the_running_sum_to_float32s_last_bits(chunk, upwards):
+    """``_running_sum`` (and its cotangent, the same sum from the last row
+    upwards) on log-decays of which half are -1.6 a step, against
+    ``numpy``'s cumsum in float64: every entry within two units in
+    float32's last place of its own size (down to -205 over 128 rows), and
+    no further off than ``jnp.cumsum`` in float32 is."""
+    a = np.asarray(inputs(8, seq=chunk, strong=True)[3][0, :, 0])
+    want = np.cumsum(a[::-1].astype(np.float64), 0)[::-1] if upwards \
+        else np.cumsum(a.astype(np.float64), 0)
+    if upwards:
+        got = jax.vjp(kda._running_sum, jnp.asarray(a))[1](jnp.asarray(a))[0]
+        plain = jnp.cumsum(jnp.asarray(a)[::-1], 0)[::-1]
+    else:
+        got = kda._running_sum(jnp.asarray(a))
+        plain = jnp.cumsum(jnp.asarray(a), 0)
+    assert got.dtype == jnp.float32 and float(np.abs(want).max()) > 1.5 * chunk
+    off = lambda x: np.abs(np.asarray(x, np.float64) - want)
+    assert (off(got) <= 2 * np.spacing(np.abs(want).astype(np.float32))).all()
+    assert off(got).max() <= off(plain).max()
 
 
 def test_sixteen_bits_of_a_float32_product():
@@ -179,7 +238,8 @@ def strongest_lower(chunk, exact):
     float32 inputs if ``exact``, else bfloat16 ones."""
     q, k, _, a, beta = (x[0, :, 0] for x in inputs(7, seq=chunk, strong=True))
     dtype = jnp.float32 if exact else jnp.bfloat16
-    q, k = (x.astype(dtype).astype(jnp.float32) for x in (q, k))
+    q, k = (kda._unit_rows(x.astype(dtype), scale).astype(jnp.float32)
+            for x, scale in ((q, 128 ** -0.5), (k, 1.0)))
     with jax.default_matmul_precision("highest"):
         _, kk = kda._pair_products(q, k, jnp.cumsum(a, 0), dtype,
                                    *kda._levels(chunk))
@@ -270,11 +330,12 @@ def test_a_delta_rule_not_an_additive_state():
     k = jnp.zeros((1, 2, 1, 128)).at[..., 0].set(1.0)
     v = jnp.stack([jnp.full((1, 1, 128), 1.0), jnp.full((1, 1, 128), 5.0)],
                   axis=1)
+    # q = k, which the rule brings to length 128^-0.5.
     out = kda.kda_recurrent(k, k, v, jnp.zeros_like(k), jnp.ones((1, 2, 1)))
-    np.testing.assert_allclose(out[0, 1, 0], 5.0)
+    np.testing.assert_allclose(out[0, 1, 0], 5.0 * 128 ** -0.5, rtol=1e-6)
     np.testing.assert_allclose(kda.kda_chunked(
         k, k, v, jnp.zeros_like(k), jnp.ones((1, 2, 1)), chunk=8)[0, 1, 0],
-        5.0, atol=1e-6)
+        5.0 * 128 ** -0.5, rtol=1e-5)
 
 
 def test_the_recurrence_is_transformers_gated_delta_rule():
@@ -292,9 +353,9 @@ def test_the_recurrence_is_transformers_gated_delta_rule():
         got = kda.kda_recurrent(
             q, k, v, jnp.broadcast_to(g[..., None], a.shape), beta)
     as_torch = lambda x: torch.from_numpy(np.array(x, np.float32))
-    # It scales q by width^-0.5 itself: hand it q without ours.
+    # It normalises q and k and scales q by width^-0.5 itself, as ours does.
     want, _ = torch_recurrent_gated_delta_rule(
-        as_torch(q * 32 ** 0.5), as_torch(k), as_torch(v), as_torch(g),
+        as_torch(q), as_torch(k), as_torch(v), as_torch(g),
         as_torch(beta), initial_state=None, output_final_state=False,
-        use_qk_l2norm_in_kernel=False)
+        use_qk_l2norm_in_kernel=True)
     np.testing.assert_allclose(got, want.numpy(), atol=2e-6)

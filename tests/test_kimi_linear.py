@@ -25,6 +25,7 @@ import pytest
 
 import reference_kimi_linear as reference
 from ray_tpu.models import deepseek, kimi_linear, lm
+from ray_tpu.ops import kda
 from ray_tpu.ops.moe import routed_experts
 from ray_tpu.parallel import MeshConfig, build_mesh
 from ray_tpu.parallel.train_step import init_train_state, make_train_step
@@ -203,7 +204,11 @@ def test_a_dropped_term_shows(both, dropped, monkeypatch):
     elif dropped == "beta":
         _kda_with(monkeypatch, beta=jnp.ones_like)
     elif dropped == "qk_norm":
-        _kda_with(monkeypatch, q=lambda q: 3.0 * q, k=lambda k: 3.0 * k)
+        # The rule normalises inside (ops/kda.py ``_unit_rows``, which the
+        # chunk's function calls): q and k three times their length there.
+        plain = kda._unit_rows
+        monkeypatch.setattr(kda, "_unit_rows", lambda y, scale=1.0:
+                            plain(y, 3.0 * scale))
     elif dropped == "conv":
         monkeypatch.setattr(lm, "conv_silu", lambda x, w, b=None:
                             jax.nn.silu(x.astype(jnp.float32)).astype(x.dtype))
