@@ -49,6 +49,9 @@ _fast_channel = {"bytes": 0, "acks": 0}
 # Continuous-profiler stack walks: bumped every sampler tick (hz rate),
 # folded into ray_tpu_profile_samples_total at each snapshot.
 _fast_profile = {"samples": 0}
+# component -> the sampler's worst lateness since the last flush (a max,
+# not a sum: the tick writes it without a lock, the flush takes it whole).
+_fast_sampler_lag: dict = {}
 # Alerting plane cells: state -> transition count and severity -> event
 # count. Transitions happen inside ClusterMetrics.update's merge path
 # and journal appends can ride task/spill hot paths, so both stay
@@ -103,6 +106,15 @@ def record_profile_samples(n: int) -> None:
     _fast_profile["samples"] += n
 
 
+def record_sampler_lag(component: str, late_s: float) -> None:
+    """How late one ProfilerAgent tick woke: kept where it is the worst
+    since the last flush, which sets it as
+    ``ray_tpu_loop_lag_seconds{loop="sampler.<component>"}``. A tick that
+    races the flush may show in two flushes, never in none."""
+    if late_s > _fast_sampler_lag.get(component, -1.0):
+        _fast_sampler_lag[component] = late_s
+
+
 def record_lease_immediate() -> None:
     """A lease request satisfied without waiting: lands in the lease-wait
     histogram's smallest bucket at flush time, skipping two monotonic
@@ -149,6 +161,9 @@ def flush_fast_counters() -> None:
     if n:
         _fast_profile["samples"] -= n
         profile_samples().inc(n)
+    for component in list(_fast_sampler_lag):
+        loop_lag().set(_fast_sampler_lag.pop(component),
+                       tags={"loop": f"sampler.{component}"})
     for state, n in list(_fast_alert_transitions.items()):
         if n:
             _fast_alert_transitions[state] -= n
@@ -662,7 +677,9 @@ def loop_lag() -> Gauge:
         "ray_tpu_loop_lag_seconds",
         "Scheduling lag of a control loop: how far past its intended "
         "period/deadline the loop actually woke (head membership sweep, "
-        "dashboard asyncio loop, metrics agent ticks).",
+        "dashboard asyncio loop, metrics agent ticks; sampler.<component>: "
+        "the continuous profiler's 10 Hz tick, worst since the last "
+        "flush).",
         tag_keys=("loop",))
 
 
@@ -1022,6 +1039,110 @@ class setup_stage:
         seconds = time.perf_counter() - self._t0
         self._scope.__exit__(*exc)
         leave_setup_stage(self._stage, self._outer, seconds)
+        return False
+
+
+# -- train loop: a step's call, the waits between steps, a process off the CPU
+# Always on: two observations a call of a step, two clock reads at each of
+# the loop's waits between steps. The spans beside them (``train::step``,
+# ``step::record``, ``host::tick``) record under tracing's own rule.
+
+_loop = threading.local()
+#: The loop's waits between steps, in the order ``loop_waits`` gives them.
+LOOP_WAITS = ("save", "report", "data")
+
+
+def train_step_interval_seconds() -> Histogram:
+    from ray_tpu.util.metrics import Histogram
+    return Histogram(
+        "ray_tpu_train_step_interval_seconds",
+        "Seconds from one call of a jitted train or eval step to the next "
+        "call of the same step (entry to entry): the step as the loop "
+        "lives it. A call that made a program starts the clock anew.",
+        boundaries=(0.05, 0.1, 0.25, 0.5, 1.0, 2.0, 4.0, 8.0, 30.0),
+        tag_keys=("program",))
+
+
+def train_step_dispatch_seconds() -> Histogram:
+    from ray_tpu.util.metrics import Histogram
+    return Histogram(
+        "ray_tpu_train_step_dispatch_seconds",
+        "Seconds a call of a jitted step that found its program holds the "
+        "loop's thread: entry to return, the recorder of the model's "
+        "scalars included; the device runs the step meanwhile and after.",
+        boundaries=(0.001, 0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.5, 2.0),
+        tag_keys=("program",))
+
+
+def train_loop_wait_seconds() -> Counter:
+    from ray_tpu.util.metrics import Counter
+    return Counter(
+        "ray_tpu_train_loop_wait_seconds_total",
+        "Seconds the train loop's thread spent between steps in a report "
+        "(session.report), a save (report_sharded, its ack included) or "
+        "the wait for a batch (iter_jax_batches).",
+        tag_keys=("what",))
+
+
+def train_step_stalled_seconds() -> Counter:
+    from ray_tpu.util.metrics import Counter
+    return Counter(
+        "ray_tpu_train_step_stalled_seconds_total",
+        "Seconds by which a step's interval, less its save, report and "
+        "batch, passed the median of that step's last intervals, by what "
+        "the sampler's late ticks inside it say kept the process off the "
+        "CPU (none: it ran all along).",
+        tag_keys=("program", "cause"))
+
+
+def process_late_seconds() -> Counter:
+    from ray_tpu.util.metrics import Counter
+    return Counter(
+        "ray_tpu_process_late_seconds_total",
+        "Seconds by which the continuous profiler's ticks woke late in "
+        "this process, counted from 0.02 s a tick, by cause: runqueue, "
+        "throttled, steal, pressure_cpu / _io / _memory, gc, gil, "
+        "unknown.",
+        tag_keys=("cause",))
+
+
+def loop_waits() -> tuple:
+    """This thread's running totals of ``LOOP_WAITS``, in that order."""
+    return getattr(_loop, "totals", (0.0, 0.0, 0.0))
+
+
+class loop_wait:
+    """One of the loop's waits between steps as a ``with`` block: a
+    ``tracing.start_span(span_name)`` site (yields the span, or None where
+    nothing records) whose seconds go, always, to
+    ``ray_tpu_train_loop_wait_seconds_total{what}`` and to the thread's
+    running total, which a step's call site reads at its next entry. A wait
+    inside another (a save's ack is a report) is the outer one's."""
+
+    __slots__ = ("_what", "_scope", "_outermost", "_t0")
+
+    def __init__(self, what: str, span_name: str):
+        from ray_tpu.util import tracing
+        self._what = LOOP_WAITS.index(what)
+        self._scope = tracing.start_span(span_name)
+
+    def __enter__(self):
+        self._outermost = not getattr(_loop, "waiting", False)
+        _loop.waiting = True
+        span = self._scope.__enter__()
+        self._t0 = time.perf_counter()
+        return span
+
+    def __exit__(self, *exc) -> bool:
+        seconds = time.perf_counter() - self._t0
+        self._scope.__exit__(*exc)
+        if self._outermost:
+            _loop.waiting = False
+            totals = list(loop_waits())
+            totals[self._what] += seconds
+            _loop.totals = tuple(totals)
+            train_loop_wait_seconds().inc(
+                seconds, tags={"what": LOOP_WAITS[self._what]})
         return False
 
 

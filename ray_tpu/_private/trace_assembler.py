@@ -43,6 +43,11 @@ _STAGE_BY_PREFIX = (
     # Train's loop: a save with its phases, a report, a batch.
     ("train::report_sharded", "train_save"),
     ("train::report", "train_report"),
+    # A call of the jitted step with the recorder of its scalars inside,
+    # and the continuous profiler's ticks (a tick's length is its lateness).
+    ("train::step", "train_step"),
+    ("step::record", "train_step"),
+    ("host::tick", "host_late"),
     ("ckpt::", "ckpt"),
     ("data::next_batch", "train_ingest"),
     ("data::to_device", "train_ingest"),

@@ -13,6 +13,7 @@ import queue
 import threading
 from typing import Any, Dict, Optional
 
+from ray_tpu._private import builtin_metrics
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.util import tracing
 
@@ -59,7 +60,7 @@ class _Session:
                shard: Optional[dict] = None) -> None:
         if self.stop_requested:
             raise StopSession()
-        with tracing.start_span("train::report"):
+        with builtin_metrics.loop_wait("report", "train::report"):
             result = {"metrics": dict(metrics), "checkpoint": checkpoint}
             if shard is not None:
                 result["shard"] = shard
@@ -106,7 +107,8 @@ class _Session:
         # prefetch / gather / copy / checksum / write, then the ack as a
         # nested train::report), so this span's self time is what no
         # phase accounts for.
-        with tracing.start_span("train::report_sharded") as span:
+        with builtin_metrics.loop_wait(
+                "save", "train::report_sharded") as span:
             if span is not None:
                 span.attributes.update(seq=seq, rank=self.world_rank)
             with tracing.child_span("ckpt::meta"):

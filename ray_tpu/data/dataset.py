@@ -17,6 +17,7 @@ from typing import (Any, Callable, Dict, Iterator, List, Optional, Tuple,
 import numpy as np
 
 import ray_tpu
+from ray_tpu._private import builtin_metrics
 from ray_tpu.data import aggregate as agg_mod
 from ray_tpu.data._internal.compute import resolve_compute
 from ray_tpu.data._internal.plan import (AllToAllStage, ExecutionPlan,
@@ -495,7 +496,8 @@ class Dataset:
         while True:
             # One span a batch, closed before the consumer runs: the host
             # batch, then the transfer as its child.
-            with tracing.start_span("data::next_batch") as span:
+            with builtin_metrics.loop_wait(
+                    "data", "data::next_batch") as span:
                 batch = next(batches, None)
                 if batch is None:
                     return

@@ -176,8 +176,10 @@ def _with_mesh_registered(jitted, mesh, rules, after_call=None):
     A call is the set-up stage ``first_call`` when it made a program
     (``compile_events.first_call``), ``.lower`` the stage ``aot_lower``: what
     JAX reports of tracing and lowering inside either is the step's, not
-    some other program's."""
-    program = getattr(jitted, "__name__", "step")
+    some other program's. A call that found its program is timed there too
+    (its dispatch, ``after_call`` included, and the interval since the last
+    call of this wrapped step), and is a span ``train::step``."""
+    clock = compile_events.StepClock(getattr(jitted, "__name__", "step"))
 
     def under_mesh(fn, stage, then=None):
         @functools.wraps(fn)
@@ -185,17 +187,17 @@ def _with_mesh_registered(jitted, mesh, rules, after_call=None):
             previous = mesh_mod.current_mesh(), mesh_mod.current_rules()
             mesh_mod.set_current_mesh(mesh, rules)
             try:
-                with stage():
+                with stage() as site:
                     out = fn(*args, **kwargs)
+                    if then is not None:
+                        site.after(then, out)
             finally:
                 mesh_mod.set_current_mesh(*previous)
-            if then is not None:
-                then(out)
             return out
         return call
 
     wrapped = under_mesh(
-        jitted, lambda: compile_events.first_call(jitted, program),
+        jitted, lambda: compile_events.first_call(jitted, clock),
         after_call)
     wrapped.lower = under_mesh(
         jitted.lower,
@@ -308,7 +310,7 @@ def make_eval_step(cfg: Any, mesh,
     model = _model_of(cfg, model)
     bspec = rules.spec("batch", "sequence")
 
-    def step(params, batch):
+    def eval_step(params, batch):
         batch = {
             k: jax.lax.with_sharding_constraint(
                 v, NamedSharding(mesh, bspec))
@@ -318,4 +320,5 @@ def make_eval_step(cfg: Any, mesh,
                                    batch["targets"], batch.get("mask"))
         return metrics
 
-    return _with_mesh_registered(jax.jit(step), mesh, rules)
+    # Its own name: the ``program`` of its spans and series.
+    return _with_mesh_registered(jax.jit(eval_step), mesh, rules)

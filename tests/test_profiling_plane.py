@@ -116,6 +116,300 @@ def test_profiler_agent_samples_drain_refund():
     assert again is not None and again["stacks"] == window["stacks"]
 
 
+# ---------------------------------------------------------------------------
+# The sampler's tick as the process's heartbeat: the /proc parsers, the
+# cause rule, a late tick
+# ---------------------------------------------------------------------------
+
+CPU_STAT_V2 = """usage_usec 136329162685
+user_usec 127849837835
+system_usec 8479324849
+nr_periods 1180
+nr_throttled 37
+throttled_usec 2450000
+nr_bursts 0
+burst_usec 0
+"""
+CPU_STAT_V1 = """nr_periods 204
+nr_throttled 3
+throttled_time 310000000
+nr_bursts 0
+burst_time 0
+"""
+PROC_STAT = """cpu  12784965 0 805147 69700596 66932 0 42782 123200 0 0
+cpu0 1598120 0 100643 8712574 8366 0 5347 15400 0 0
+intr 1 2 3
+"""
+PRESSURE = """some avg10=0.98 avg60=0.91 avg300=1.27 total=7897842452
+full avg10=0.00 avg60=0.00 avg300=0.00 total=12
+"""
+
+
+@pytest.mark.parametrize("parser,text,want", [
+    ("schedstat_seconds", "1176354000 55826000 2\n", (1.176354, 0.055826)),
+    ("schedstat_seconds", "", None),
+    ("schedstat_seconds", None, None),  # a missing file
+    ("throttled_seconds", CPU_STAT_V2, 2.45),
+    ("throttled_seconds", CPU_STAT_V1, 0.31),
+    ("throttled_seconds", "usage_usec 5\n", None),  # v2 with no quota
+    ("throttled_seconds", None, None),
+    ("pressure_seconds", PRESSURE, 7897.842452),
+    ("pressure_seconds", "full avg10=0.00 total=12\n", None),
+    ("pressure_seconds", None, None),
+    ("steal_seconds", "intr 1 2 3\n", None),
+    ("steal_seconds", None, None),
+])
+def test_the_proc_parsers_on_recorded_texts(parser, text, want):
+    from ray_tpu._private import profiling
+    got = getattr(profiling, parser)(text)
+    assert got == (want if want is None else pytest.approx(want))
+
+
+def test_steal_is_seconds_a_cpu(monkeypatch):
+    from ray_tpu._private import profiling
+    monkeypatch.setattr(profiling, "_CPUS", 8)
+    monkeypatch.setattr(profiling, "_CLK_TCK", 100)
+    assert profiling.steal_seconds(PROC_STAT) == pytest.approx(154.0)
+
+
+# /proc/self/cgroup, the cpu.stat files that exist, the one that is read.
+@pytest.mark.parametrize("cgroup,files,want", [
+    ("0::/jobs/a\n", {"jobs/a/cpu.stat": CPU_STAT_V2}, "jobs/a/cpu.stat"),
+    ("0::/\n", {"cpu.stat": CPU_STAT_V2}, "cpu.stat"),
+    ("4:memory:/m\n2:cpu,cpuacct:/jobs\n0::/\n",
+     {"cpu,cpuacct/jobs/cpu.stat": CPU_STAT_V1,
+      "cpu.stat": "usage_usec 5\n"}, "cpu,cpuacct/jobs/cpu.stat"),
+    # v1 mounted as ``cpu`` beside ``cpuacct``; the unified tree has no
+    # throttling to read.
+    ("2:cpuacct:/\n1:cpu:/\n0::/\n",
+     {"cpu/cpu.stat": CPU_STAT_V1, "unified/cpu.stat": "usage_usec 5\n"},
+     "cpu/cpu.stat"),
+    ("0::/gone\n", {}, None),
+    (None, {}, None),
+])
+def test_the_cgroup_s_cpu_stat_is_found(tmp_path, cgroup, files, want):
+    import os
+
+    from ray_tpu._private import profiling
+    for name, text in files.items():
+        path = tmp_path / name
+        path.parent.mkdir(parents=True, exist_ok=True)
+        path.write_text(text)
+    got = profiling.cgroup_cpu_stat(cgroup, root=str(tmp_path))
+    assert got == (want and os.path.join(str(tmp_path), want))
+
+
+def test_host_counters_read_what_this_machine_has():
+    from ray_tpu._private import profiling
+    host = profiling.HostCounters()
+    try:
+        first = host.read()
+        assert {"gc", "cpu"} <= set(first)
+        assert all(v >= 0 for v in first.values())
+        host.on_gc("start", {})
+        time.sleep(0.01)
+        host.on_gc("stop", {})
+        second = host.read()
+        assert second["gc"] - first["gc"] >= 0.01
+        if "runqueue" in first:
+            import threading
+            assert threading.get_native_id() in host.thread_cpu()
+        assert all(second[k] >= first[k] for k in first)
+    finally:
+        host.close()
+    # Its files gone (as on a machine without them), it reads the rest, and
+    # no table of threads where the kernel keeps no ``schedstat``.
+    assert set(host.read()) == {"gc", "cpu"}
+    assert host.thread_cpu() is None
+
+
+QUIET = {"runqueue": 0.001, "throttled": 0.0, "steal": 0.002, "gc": 0.0,
+         "pressure_cpu": 0.003, "pressure_io": 0.0, "pressure_memory": 0.0}
+
+
+# A tick 0.4 s late, 0.5 s after the counters were last read, and what each
+# moved by meanwhile: the thread's own runnable time is all the lateness's,
+# of the others the lateness has its four fifths.
+@pytest.mark.parametrize("deltas,want", [
+    (dict(QUIET, runqueue=0.39, pressure_cpu=0.45, cpu=0.01), "runqueue"),
+    (dict(QUIET, throttled=0.35, runqueue=0.2), "throttled"),
+    (dict(QUIET, steal=0.26), "steal"),
+    (dict(QUIET, pressure_cpu=0.3, runqueue=0.1), "pressure_cpu"),
+    (dict(QUIET, pressure_io=0.4), "pressure_io"),
+    (dict(QUIET, pressure_memory=0.25), "pressure_memory"),
+    (dict(QUIET, gc=0.38, cpu=0.4), "gc"),
+    # Not runnable, nothing moved, and the process ran: another thread
+    # held the interpreter's lock.
+    (dict(QUIET, cpu=0.41), "gil"),
+    # The same beside this machine's other tenants' pressure, which runs
+    # through the whole interval: under a quarter of the lateness is quiet.
+    (dict(QUIET, pressure_cpu=0.12, cpu=0.41), "gil"),
+    # The same with the process standing still: frozen from outside.
+    (dict(QUIET, cpu=0.0), "unknown"),
+    # Something moved, by less than half the lateness.
+    (dict(QUIET, runqueue=0.15, cpu=0.4), "unknown"),
+    (dict(QUIET, steal=0.24, cpu=0.4), "unknown"),  # 0.192 of it late
+    # A machine that counts no runnable time cannot say "not runnable".
+    ({"gc": 0.0, "cpu": 0.4}, "unknown"),
+    ({}, "unknown"),
+])
+def test_the_cause_rule_on_planted_deltas(deltas, want):
+    from ray_tpu._private import profiling
+    assert profiling.late_cause(0.4, deltas, 0.5) == want
+
+
+def test_a_short_lateness_takes_its_share_of_the_interval():
+    """A tick 34 ms late after a sleep of 100: the machine's pressure of
+    30 ms over the interval is a quarter the lateness's, the thread's own
+    34 ms of runnable time all of it."""
+    from ray_tpu._private import profiling
+    quiet = dict(QUIET, runqueue=0.0, steal=0.0, pressure_cpu=0.03)
+    assert profiling.late_cause(0.034, dict(quiet, cpu=0.094),
+                                0.134) == "gil"
+    assert profiling.late_cause(0.034, dict(quiet, cpu=0.094)) == \
+        "pressure_cpu"  # with no interval given, all of it
+    assert profiling.late_cause(0.034, dict(quiet, runqueue=0.034,
+                                            cpu=0.001), 0.134) == "runqueue"
+
+
+class _Clock:
+    """``time`` for ``profiling``: the test's own clock, which a wait
+    moves."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def perf_counter(self):
+        return self.now
+
+    monotonic = process_time = perf_counter
+
+    def time(self):
+        return 1e9 + self.now
+
+
+@pytest.fixture
+def late_loop(monkeypatch):
+    """``ProfilerAgent._loop`` run on this thread over a clock whose second
+    wait oversleeps by 0.35 s, with the runqueue counter moving by 0.3 s
+    meanwhile; returns what it left."""
+    from ray_tpu._private import builtin_metrics, profiling
+    from ray_tpu.util import tracing
+    clock, reads, walks = _Clock(), [], []
+
+    class Host:
+        on_gc = staticmethod(lambda phase, info: None)
+        close = staticmethod(lambda: None)
+        thread_cpu = staticmethod(
+            lambda: {1: clock.now / 100, 2: 2 * clock.now})
+
+        def read(self):
+            reads.append(clock.now)
+            late = sum(1 for t in reads if t > 100.5)
+            return {"runqueue": 0.3 if late else 0.0, "gc": 0.0,
+                    "steal": 0.01 * len(reads), "cpu": clock.now / 100}
+
+    agent = ProfilerAgent("test", hz=10, start=False)
+    waits = []
+
+    def wait(timeout):
+        waits.append(timeout)
+        clock.now += timeout + (0.35 if len(waits) == 2 else 0.0)
+        return len(waits) > 4
+
+    monkeypatch.setattr(profiling, "time", clock)
+    monkeypatch.setattr(profiling, "HostCounters", Host)
+    monkeypatch.setattr(profiling, "_thread_name", "thread-{}".format)
+    monkeypatch.setattr(agent._stop, "wait", wait)
+    monkeypatch.setattr(agent, "_sample_once",
+                        lambda me: walks.append(clock.now) or 3)
+    tracing.clear_spans()
+    tracing.set_sample_rate(None)
+    tracing.enable_tracing()
+    try:
+        agent._loop()
+    finally:
+        tracing.disable_tracing()
+    ticks = [s for s in tracing.get_spans() if s.name == "host::tick"]
+    tracing.clear_spans()
+    builtin_metrics.flush_fast_counters()
+    return {"agent": agent, "ticks": ticks, "walks": walks}
+
+
+def test_a_late_tick_is_one_span_with_its_cause(late_loop):
+    ticks = late_loop["ticks"]
+    # The grid's ticks, the three that the oversleep passed skipped.
+    assert [round(s.perf_start, 6) for s in ticks] == [
+        100.0, 100.1, 100.2, 100.6, 100.7]
+    assert late_loop["walks"] == pytest.approx(
+        [100.0, 100.1, 100.55, 100.6, 100.7])
+    assert [round(s.duration, 6) for s in ticks] == [0, 0, 0.35, 0, 0]
+    assert len({s.trace_id for s in ticks}) == 1  # one trace an agent
+    late = ticks[2].attributes
+    assert late == {
+        "cause": "runqueue", "runnable_s": 0.3, "gc_s": 0.0,
+        "steal_s": 0.01, "process_cpu_s": pytest.approx(0.0045),
+        "busiest_thread": "thread-2",
+        "busiest_thread_cpu_s": pytest.approx(0.9)}
+    assert all(s.attributes == {} for s in ticks if s is not ticks[2])
+
+
+def test_a_late_tick_feeds_the_counter_and_the_sampler_s_lag(late_loop):
+    series = {e["name"]: e["series"] for e in um.snapshot()}
+    assert series["ray_tpu_process_late_seconds_total"] == {
+        ("runqueue",): pytest.approx(0.35)}
+    assert series["ray_tpu_loop_lag_seconds"] == {
+        ("sampler.test",): pytest.approx(0.35)}
+    # Kept for the step's call site: what fell inside an interval.
+    agent = late_loop["agent"]
+    assert agent.late_between(100.0, 101.0) == (
+        pytest.approx(0.35), "runqueue")
+    assert agent.late_between(100.3, 100.4) == (
+        pytest.approx(0.1), "runqueue")
+    assert agent.late_between(100.6, 101.0) == (0.0, "none")
+
+
+def test_off_a_tick_leaves_no_span_and_reads_no_thread_table(monkeypatch):
+    from ray_tpu._private import profiling
+    from ray_tpu.util import tracing
+    agent = ProfilerAgent("test", hz=10, start=False)
+    host = profiling.HostCounters()
+    monkeypatch.setattr(host, "thread_cpu",
+                        lambda: pytest.fail("read with nothing recording"))
+    try:
+        now = time.perf_counter()
+        before = profiling._Reading(host.read(), None, now - 0.3)
+        before = agent._tick(now - 0.2, now, host, before)
+    finally:
+        host.close()
+    assert before.threads is None and before.at == now
+    assert [s for s in tracing.get_spans() if s.name == "host::tick"] == []
+    [(woke, late, cause)] = agent._late
+    assert woke == now and late == pytest.approx(0.2)
+
+
+def test_a_running_agent_ticks_on_its_grid():
+    """The real loop on its own thread: the gc callback comes and goes
+    with it, and its lag reaches the gauge."""
+    import gc
+
+    from ray_tpu._private import builtin_metrics
+    callbacks = len(gc.callbacks)
+    agent = ProfilerAgent("grid", hz=50)
+    try:
+        deadline = time.monotonic() + 5
+        while time.monotonic() < deadline and agent._samples < 5:
+            time.sleep(0.02)
+        assert len(gc.callbacks) == callbacks + 1
+    finally:
+        agent.stop()
+    assert len(gc.callbacks) == callbacks
+    builtin_metrics.flush_fast_counters()
+    [lag] = [e["series"] for e in um.snapshot()
+             if e["name"] == "ray_tpu_loop_lag_seconds"]
+    assert 0 <= lag[("sampler.grid",)] < 5
+
+
 def test_disabled_agent_no_thread():
     agent = ProfilerAgent("test", hz=0)
     assert not agent.enabled

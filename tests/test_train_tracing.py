@@ -118,7 +118,10 @@ def test_a_profile_turns_recording_on_and_annotates(tracing_off, tmp_path):
         jax.profiler.stop_trace()
     with tracing.start_span("ckpt::after") as span:
         assert span is None
-    spans = {s.name: s for s in tracing.get_spans()}
+    # By the prefix, as the profile below: the jitted sum's ``compile::*``
+    # and a running sampler's ``host::tick`` record under a profile too.
+    spans = {s.name: s for s in tracing.get_spans()
+             if s.name.startswith("ckpt::")}
     assert set(spans) == {"ckpt::probe", "ckpt::probe_child"}
     assert spans["ckpt::probe_child"].parent_id == \
         spans["ckpt::probe"].span_id
@@ -497,7 +500,9 @@ SETUP_TABLE = [
     ("setup::loop_start", None, LOOP),
     ("setup::mesh", None, LOOP),
     ("setup::state_init", None, LOOP),
-    ("step::first_call", None, LOOP),
+    # Every call that found its program; the first call, a root too, and
+    # the retracing one inside its ``train::step`` are the recompile test's.
+    ("train::step", None, LOOP),
 ]
 
 
@@ -561,6 +566,11 @@ def test_a_new_batch_shape_is_a_recompile_with_a_journal_row(setup_traced):
         key=lambda s: s.perf_start)
     assert first.attributes == {"program": "step", "recompile": False}
     assert again.attributes == {"program": "step", "recompile": True}
+    # The first call is a root; a later call cannot know that it will
+    # retrace, so it is a ``train::step`` with its first_call inside.
+    around = setup_traced["by_id"][again.parent_id]
+    assert first.parent_id is None and first.thread == LOOP
+    assert around.name == "train::step" and around.attributes["n"] == 22
     children = [s for s in setup_traced["spans"]
                 if s.parent_id == again.span_id]
     assert {"compile::trace", "compile::backend"} <= {
@@ -653,3 +663,357 @@ def test_grafana_has_a_panel_for_set_up_by_stage():
     exprs = [t["expr"] for p in generate_dashboard()["panels"]
              for t in p.get("targets", [])]
     assert any("ray_tpu_train_setup_seconds_sum" in e for e in exprs)
+
+
+# -- a step says what it waited for ---------------------------------------
+# ``compile_events.first_call`` times every call of a wrapped step where it
+# is made; ``builtin_metrics.loop_wait`` the loop's waits between steps.
+
+INTERVAL = "ray_tpu_train_step_interval_seconds"
+DISPATCH = "ray_tpu_train_step_dispatch_seconds"
+LOOP_WAIT = "ray_tpu_train_loop_wait_seconds_total"
+STALLED = "ray_tpu_train_step_stalled_seconds_total"
+
+
+def _series(name):
+    from ray_tpu.util import metrics
+    for entry in metrics.snapshot():
+        if entry["name"] == name:
+            return dict(entry.get("series", {}))
+    return {}
+
+
+def _one_device_mesh():
+    import jax
+    from ray_tpu.parallel import MeshConfig, build_mesh
+    return build_mesh(MeshConfig(dp=1, fsdp=1, tp=1),
+                      devices=jax.devices()[:1])
+
+
+def _wrapped(program="step", after_call=None):
+    """A jitted function behind the step's wrapper, as ``make_train_step``
+    wraps its own."""
+    import jax
+    from ray_tpu.parallel import ShardingRules
+    from ray_tpu.parallel.train_step import _with_mesh_registered
+
+    def fn(x):
+        return x + 1
+
+    fn.__name__ = program
+    return _with_mesh_registered(jax.jit(fn), _one_device_mesh(),
+                                 ShardingRules(), after_call=after_call)
+
+
+def _recording_model(seen):
+    """The least ``make_train_step`` takes as a model, with a scalar that
+    the registry records."""
+    import types
+
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec
+
+    def loss_fn(params, cfg, tokens, targets, mask):
+        loss = (params["w"] * tokens.astype(jnp.float32).mean()).sum()
+        return loss, {"loss": loss}
+
+    return types.SimpleNamespace(
+        init=lambda cfg, key: {"w": jnp.ones((4,), jnp.float32)},
+        param_specs=lambda cfg, rules: {"w": PartitionSpec()},
+        loss_fn=loss_fn, RECORDED_METRICS={"loss": seen.append})
+
+
+@pytest.fixture(scope="module")
+def five_calls():
+    """A real train step (a model with ``RECORDED_METRICS``) called five
+    times with tracing on, then five times with it off."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.parallel.train_step import (init_train_state,
+                                             make_train_step)
+    mesh = _one_device_mesh()
+    seen, out = [], {}
+    model = _recording_model(seen)
+    tokens = jnp.ones((2, 8), jnp.int32)
+    batch = {"tokens": tokens, "targets": tokens}
+    for traced in (True, False):
+        tracing.clear_spans()
+        tracing.set_sample_rate(None)
+        (tracing.enable_tracing if traced else tracing.disable_tracing)()
+        before = {name: _observations(name) for name in (INTERVAL, DISPATCH)}
+        try:
+            state = init_train_state(None, mesh, model=model)
+            step = make_train_step(None, mesh, model=model)
+            for _ in range(5):
+                state, metrics = step(state, batch)
+            jax.block_until_ready(metrics)
+        finally:
+            tracing.disable_tracing()
+        out[traced] = {
+            "spans": [s for s in tracing.get_spans()
+                      if s.thread == threading.current_thread().name],
+            "observed": {name: _observed_since(name, before[name])
+                         for name in before}}
+        tracing.clear_spans()
+    out["recorded"] = len(seen)
+    return out
+
+
+@pytest.mark.parametrize("name,count", [
+    ("step::first_call", 1), ("train::step", 4), ("step::record", 5)])
+def test_five_calls_leave_the_spans(five_calls, name, count):
+    found = [s for s in five_calls[True]["spans"] if s.name == name]
+    assert len(found) == count
+    by_id = {s.span_id: s for s in five_calls[True]["spans"]}
+    for s in found:
+        assert s.duration is not None
+        if name == "step::record":  # the call's child, whichever it was
+            assert by_id[s.parent_id].name in ("step::first_call",
+                                               "train::step")
+        else:
+            assert s.parent_id is None
+    # The newest call's scalars may still be pending when the loop ends.
+    assert five_calls["recorded"] in (8, 9, 10)
+
+
+def test_a_step_s_interval_is_the_difference_of_two_entries(five_calls):
+    steps = sorted((s for s in five_calls[True]["spans"]
+                    if s.name == "train::step"), key=lambda s: s.perf_start)
+    assert [s.attributes["n"] for s in steps] == [2, 3, 4, 5]
+    assert all(s.attributes["program"] == "step" for s in steps)
+    # The first call made the program: the interval that would span it is
+    # set-up's, and the clock starts anew at the second.
+    assert "interval_s" not in steps[0].attributes
+    for earlier, later in zip(steps, steps[1:]):
+        assert later.attributes["interval_s"] == \
+            later.perf_start - earlier.perf_start
+        assert [later.attributes[k] for k in
+                ("save_s", "report_s", "data_s")] == [0.0, 0.0, 0.0]
+        # Self time (less ``step::record``) is the dispatch proper.
+        assert later.duration < later.attributes["interval_s"]
+
+
+@pytest.mark.parametrize("traced", [True, False])
+def test_the_histograms_count_with_tracing_on_and_off(five_calls, traced):
+    assert five_calls[traced]["observed"] == {
+        DISPATCH: {("step",): 4}, INTERVAL: {("step",): 3}}
+    if not traced:
+        assert five_calls[False]["spans"] == []
+
+
+def test_two_wrapped_steps_do_not_mix_their_intervals(tracing_off):
+    import jax.numpy as jnp
+    tracing.enable_tracing()
+    train, evaluate = _wrapped("step"), _wrapped("eval_step")
+    x = jnp.zeros(())
+    for step in (train, evaluate, train, train, evaluate, train, evaluate):
+        step(x)
+        time.sleep(0.002)
+    for program, calls in (("step", 4), ("eval_step", 3)):
+        spans = sorted((s for s in tracing.get_spans()
+                        if s.name == "train::step"
+                        and s.attributes["program"] == program),
+                       key=lambda s: s.perf_start)
+        # The first call of each made its program and is no ``train::step``.
+        assert [s.attributes["n"] for s in spans] == \
+            list(range(2, calls + 1))
+        for earlier, later in zip(spans, spans[1:]):
+            assert later.attributes["interval_s"] == \
+                later.perf_start - earlier.perf_start
+
+
+def test_the_eval_step_has_a_name_of_its_own():
+    from ray_tpu.models import gpt
+    from ray_tpu.parallel.train_step import make_eval_step, make_train_step
+    mesh = _one_device_mesh()
+    cfg = gpt.config("gpt-tiny")
+    assert make_eval_step(cfg, mesh).__name__ == "eval_step"
+    assert make_train_step(cfg, mesh).__name__ == "step"
+
+
+def _waits_loop(config):
+    """Calls of a wrapped step with, between them, a report, a save, a
+    batch, and at last nothing but a sleep."""
+    import jax.numpy as jnp
+    state = {"params": {"w": jnp.ones((LEAF_ELEMS,), jnp.float32)},
+             "step": jnp.int32(0)}
+    batches = session.get_dataset_shard("train").iter_jax_batches(
+        batch_size=4)
+    step, x = _wrapped(), jnp.zeros(())
+    for _ in range(8):  # the first makes the program; then a history
+        step(x)
+    session.report({"i": 0})
+    step(x)
+    # A save that is surely longer than any stall's floor.
+    from ray_tpu.train._internal import sharded_checkpoint as sc
+    write = sc.write_shard
+
+    def slow_write(*args, **kwargs):
+        time.sleep(0.1)
+        return write(*args, **kwargs)
+
+    sc.write_shard = slow_write
+    try:
+        session.report_sharded({"i": 1}, state, extra={"step": 1})
+    finally:
+        sc.write_shard = write
+    step(x)
+    next(batches)
+    step(x)
+    time.sleep(0.2)
+    step(x)
+    step(x)
+    session.report({"done": True})
+
+
+@pytest.fixture(scope="module")
+def waits_run(tmp_path_factory):
+    import ray_tpu.data
+    from ray_tpu._private import events
+    rows, emit = [], events.emit
+
+    def capture(source, message, **kwargs):
+        rows.append((source, message, kwargs))
+        emit(source, message, **kwargs)
+
+    ray_tpu.shutdown()
+    ray_tpu.init(num_cpus=8, num_tpus=0, _memory=1e9)
+    tracing.clear_spans()
+    tracing.set_sample_rate(None)
+    tracing.enable_tracing()
+    before = _series(LOOP_WAIT), _series(STALLED)
+    events.emit = capture
+    try:
+        JaxTrainer(
+            _waits_loop, scaling_config=ScalingConfig(num_workers=1),
+            run_config=RunConfig(
+                name="waits",
+                storage_path=str(tmp_path_factory.mktemp("waits"))),
+            datasets={"train": ray_tpu.data.from_numpy(
+                [np.arange(64).reshape(16, 4)], column="x")}).fit()
+    finally:
+        events.emit = emit
+        tracing.disable_tracing()
+        ray_tpu.shutdown()
+    spans = [s for s in tracing.get_spans() if s.thread == LOOP]
+    tracing.clear_spans()
+    steps = sorted((s for s in spans if s.name == "train::step"),
+                   key=lambda s: s.perf_start)
+    return {"spans": spans, "steps": {s.attributes["n"]: s for s in steps},
+            "waited": {k[0]: v - before[0].get(k, 0.0)
+                       for k, v in _series(LOOP_WAIT).items()},
+            "stalled": {k: v - before[1].get(k, 0.0)
+                        for k, v in _series(STALLED).items()},
+            "journal": [r for r in rows if r[2].get("labels", {}).get(
+                "event") == "step_stall"]}
+
+
+# The call after the wait, the wait's part, the span that timed it.
+@pytest.mark.parametrize("n,part,span", [
+    (9, "report_s", "train::report"),
+    (10, "save_s", "train::report_sharded"),
+    (11, "data_s", "data::next_batch")])
+def test_a_wait_between_two_calls_lands_in_its_part(waits_run, n, part,
+                                                    span):
+    step = waits_run["steps"][n]
+    before = waits_run["steps"][n - 1]
+    [timed] = [s for s in waits_run["spans"] if s.name == span
+               and s.parent_id is None
+               and before.perf_start < s.perf_start < step.perf_start]
+    parts = {k: step.attributes[k] for k in ("save_s", "report_s", "data_s")}
+    # Two clock reads inside the span's own two.
+    assert 0 < parts.pop(part) <= timed.duration
+    assert parts == dict.fromkeys(parts, 0.0)  # a save's ack is the save's
+    assert step.attributes["interval_s"] > timed.duration
+
+
+def test_the_waits_feed_their_counter(waits_run):
+    waited = waits_run["waited"]
+    assert set(waited) == {"report", "save", "data"}
+    total = {part: sum(s.attributes.get(part, 0.0)
+                       for s in waits_run["steps"].values())
+             for part in ("report_s", "save_s", "data_s")}
+    assert waited["save"] == pytest.approx(total["save_s"])
+    assert waited["data"] == pytest.approx(total["data_s"])
+    # The loop's last report comes after the last call.
+    assert waited["report"] > total["report_s"] > 0
+
+
+def test_a_save_is_no_stall_and_a_sleep_is_one(waits_run):
+    """The save is the longest interval by far and its rest is a step's
+    like any; the sleep is in no part, so it is a stalled row, its cause
+    what the runtime's sampler saw meanwhile (``none`` on a quiet
+    machine)."""
+    save = waits_run["steps"][10].attributes
+    slept = waits_run["steps"][12].attributes
+    assert save["interval_s"] - save["save_s"] < 0.05
+    assert save["save_s"] > 0.1
+    assert slept["interval_s"] > 0.2
+    stalled = {row[1].split(" took ")[0]: row
+               for row in waits_run["journal"]}
+    # Call 9 was followed by the save, call 11 by the sleep (a busy machine
+    # may stall another of these millisecond steps; never the save's).
+    assert "step stalled: step call 9" not in stalled
+    row = stalled["step stalled: step call 11"]
+    assert row[0] == "train"
+    labels = row[2]["labels"]
+    assert labels["program"] == "step" and labels["event"] == "step_stall"
+    assert labels["cause"] != "unwatched"  # the runtime's sampler runs
+    assert sum(waits_run["stalled"].values()) > 0.15
+    assert all(key[0] == "step" for key in waits_run["stalled"])
+
+
+def _planted_agent(late):
+    """``global_profiler`` of a process whose sampler has seen what the
+    test says it saw."""
+    from ray_tpu._private import profiling
+    agent = profiling.ProfilerAgent("test", hz=10, start=False)
+    agent._late.extend(late)
+    return lambda: agent
+
+
+# (woke, lateness, cause) of the sampler's late ticks; the stalled interval
+# is [10.0, 11.5] on a median of 1.0, so 0.5 s over.
+@pytest.mark.parametrize("late,want", [
+    ([], (0.0, "none")),
+    # One before the interval, one across its start, one inside it.
+    ([(9.9, 0.3, "gc"), (10.1, 0.3, "runqueue"), (11.0, 0.35, "runqueue")],
+     (0.45, "runqueue")),
+    ([(10.5, 0.1, "gc"), (11.2, 0.3, "steal")], (0.4, "steal")),
+    (None, (0.0, "unwatched")),
+])
+def test_a_long_interval_is_one_row_with_the_late_ticks_inside_it(
+        monkeypatch, late, want):
+    from ray_tpu._private import events, profiling
+    from ray_tpu.parallel import compile_events
+    rows = []
+    monkeypatch.setattr(events, "emit",
+                        lambda *a, **kw: rows.append((a, kw)))
+    monkeypatch.setattr(profiling, "global_profiler",
+                        (lambda: None) if late is None
+                        else _planted_agent(late))
+    before = _series(STALLED)
+    clock = compile_events.StepClock("planted")
+    entries = [float(t) for t in range(5, 11)] + [11.5, 12.52, 13.5]
+    for t in entries:
+        interval = clock.enter(t, None)
+        if interval is not None:
+            clock.judge(*interval)
+    # Eight intervals: five of 1.0 s, then 1.5 (stalled), 1.02 (inside the
+    # floor of 0.05 s) and 0.98.
+    [((source, message), kwargs)] = rows
+    assert source == "train" and "call 6 took 1.500s" in message
+    assert f"late by {want[0]:.3f}s inside it, cause {want[1]}" in message
+    assert kwargs["labels"] == {"event": "step_stall", "program": "planted",
+                                "cause": want[1]}
+    now = _series(STALLED)
+    assert now[("planted", want[1])] - before.get(
+        ("planted", want[1]), 0.0) == pytest.approx(0.5)
+
+
+@pytest.mark.parametrize("name,stage", [
+    ("train::step", "train_step"), ("step::record", "train_step"),
+    ("host::tick", "host_late")])
+def test_the_summary_groups_the_step_and_the_tick_by_stage(name, stage):
+    assert trace_assembler.span_stage({"name": name}) == stage
