@@ -31,7 +31,11 @@ The recurrence is ``ops/ssd.py``'s chunked scan (through ``lm.state_space``),
 the convolution with its bias and SiLU ``lm.conv_silu`` over xBC's columns of
 the in-projection's output where they lie (``ops/short_conv.py``'s fused pass
 each way where the shapes tile, else its ``jax.numpy`` form in
-float32: no split copy, no float32 padded copy),
+float32: no split copy, no float32 padded copy), the line ``a =
+RMSNorm(y * silu(z); g_m)`` ``lm.gated_norm`` on y and z's columns of the
+same output (``ops/gated_norm.py``'s fused pass each way where the shapes
+tile, gate, mean of squares and scale in float32 on whole rows in VMEM, else
+its ``jax.numpy`` form),
 attention the flash kernels or ``dot`` through ``lm.attention`` with the
 model's score scale. This module is the family's config, its table of leaves
 (``_shapes``) and its block; parameters and specs from the table, the
@@ -204,7 +208,7 @@ def _mamba(cfg: GraniteConfig, x, layer):
     dt_, f32 = cfg.dtype, jnp.float32
     di, n = cfg.mamba_d_inner, cfg.mamba_d_state
     proj = jnp.einsum("bsd,de->bse", x, layer["w_in"].astype(dt_))
-    z, _, dt = jnp.split(proj, [di, di + cfg.conv_dim], axis=-1)
+    dt = proj[..., di + cfg.conv_dim:]
     with jax.named_scope("conv"):
         xbc = lm.conv_silu(proj, layer["conv_w"], layer["conv_b"],
                            start=di, width=cfg.conv_dim)
@@ -214,10 +218,11 @@ def _mamba(cfg: GraniteConfig, x, layer):
         u.reshape(u.shape[:2] + (cfg.mamba_n_heads, cfg.mamba_d_head)), dt,
         -jnp.exp(layer["A_log"].astype(f32)), B, C, layer["D"].astype(f32),
         cfg.mamba_chunk_size)
-    gated = y.reshape(z.shape).astype(f32) * jax.nn.silu(z.astype(f32))
-    normed = lm.rmsnorm(gated, layer["norm_scale"], cfg.rms_norm_eps)
-    return jnp.einsum("bse,ed->bsd", normed.astype(dt_),
-                      layer["w_out"].astype(dt_))
+    with jax.named_scope("gate_norm"):
+        normed = lm.gated_norm(
+            y.reshape(y.shape[:2] + (di,)), proj, layer["norm_scale"],
+            cfg.rms_norm_eps, gate_first=True, activation="silu")
+    return jnp.einsum("bse,ed->bsd", normed, layer["w_out"].astype(dt_))
 
 
 def _attention(cfg: GraniteConfig, x, layer):

@@ -49,7 +49,11 @@ and the running sums of ``a`` are the kernels' own, on a chunk's rows in
 VMEM, and nothing of them is this module's. The short convolutions with
 their SiLU are ``lm.conv_silu`` (``ops/short_conv.py``'s fused pass each way
 over each of q, k, v where the shapes tile, else its ``jax.numpy`` form in
-float32), the latent attention and the expert FFN ``lm.mla`` and
+float32), the line ``m = RMSNorm(o; g_o) * sigmoid(...)`` before ``Wo``
+``lm.gated_norm`` on o and the second low-rank product as the einsum leaves
+it (``ops/gated_norm.py``'s fused pass each way where the shapes tile: the
+sigmoid is the kernel's, no float32 gate is written; else its ``jax.numpy``
+form), the latent attention and the expert FFN ``lm.mla`` and
 ``lm.expert_ffn`` (shared with ``models/deepseek.py``), the expert layer
 ``ops/moe.py``. This module is the
 family's config, its table of leaves (``_shapes``, with the decay's two
@@ -281,13 +285,14 @@ def _kda(cfg: KimiLinearConfig, x, layer):
         beta = jax.nn.sigmoid(jnp.einsum(
             "bsd,dh->bsh", x, layer["w_beta"].astype(dt)).astype(f32))
         low = jnp.einsum("bsd,dr->bsr", x, layer["w_ga"].astype(dt))
-        gate = jax.nn.sigmoid(jnp.einsum(
-            "bsr,rhk->bshk", low, layer["w_gb"].astype(dt)).astype(f32))
+        gate = jnp.einsum("bsr,rhk->bshk", low, layer["w_gb"].astype(dt))
     out = lm.delta_rule(q, k, v, a, beta)
-    with jax.named_scope("kda_gate"):
-        gated = lm.rmsnorm(out.astype(f32), layer["o_norm_scale"],
-                           cfg.rms_norm_eps) * gate
-    return jnp.einsum("bshk,hkd->bsd", gated.astype(dt),
+    flat = x.shape[:2] + (heads * hd,)
+    with jax.named_scope("kda_gate"), jax.named_scope("gate_norm"):
+        gated = lm.gated_norm(
+            out.reshape(flat), gate.reshape(flat), layer["o_norm_scale"],
+            cfg.rms_norm_eps, gate_first=False, activation="sigmoid")
+    return jnp.einsum("bshk,hkd->bsd", gated.reshape(split),
                       layer["wo"].astype(dt)), \
         decay_floor(a)
 

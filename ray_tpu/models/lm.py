@@ -428,6 +428,25 @@ def short_conv(bcx, w):
     return _over_batch_shards(op, (bcx, w), (True, False), out_rank=3)
 
 
+def gated_norm(x, z, scale, eps, *, gate_first: bool, activation: str):
+    """The gate and the RMSNorm behind a recurrence by
+    ``ops/gated_norm.py`` (one fused Pallas pass each way where the shapes
+    tile, else its ``jax.numpy`` form): ``x`` [B, S, width] the recurrence's
+    output, the gate's argument the first ``width`` columns of ``z`` [B, S,
+    >= width] read where they lie, ``scale`` [group] with the groups side by
+    side along the width -> [B, S, width] in ``x``'s dtype, gate, statistics
+    and products in float32. The layer says what it computes: ``gate_first``
+    with a ``"silu"`` and one group of the whole row is a Mamba-2 layer's
+    ``RMSNorm(x * silu(z))`` (``models/granite.py``), the norm first with a
+    ``"sigmoid"`` and a group a head a delta-rule layer's ``RMSNorm(x) *
+    sigmoid(z)`` (``models/kimi_linear.py``). Under a mesh the kernels run
+    per shard of the batch, as ``state_space``'s do."""
+    from ray_tpu.ops.gated_norm import gated_norm as op
+    return _over_batch_shards(
+        partial(op, eps=eps, gate_first=gate_first, activation=activation),
+        (x, z, scale), (True, True, False), out_rank=3)
+
+
 def delta_rule(q, k, v, a, beta):
     """The gated delta rule with a decay a channel, ``S_t = Diag(exp(a_t))
     S_(t-1)``, ``S_t += beta_t k_t (v_t - S_t^T k_t)^T``, ``o_t = S_t^T
