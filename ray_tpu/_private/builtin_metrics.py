@@ -951,6 +951,39 @@ def train_dsa_index_loss() -> Gauge:
         "that own an indexer.")
 
 
+# -- EVA attention and several prediction heads ------------------------------
+# Fed as the expert layers' scalars are (models/evabyte.py).
+
+
+def train_eva_pairs_share() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_eva_pairs_share",
+        "(Query, key or summary) pairs EVA attention's mask allows over the "
+        "causal pairs, counted from the table the last recorded step's "
+        "kernels were traced with (ops/flash_attention.py eva_tile_census): "
+        "0.12112 at 32768 tokens, a window of 2048 and chunks of 16.")
+
+
+def train_eva_summary_mass() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_eva_summary_mass",
+        "Mean share of a query's softmax sum that lay on chunk summaries "
+        "in the last recorded step, over queries, heads and layers "
+        "(ops/eva.py: the forward kernel keeps the summary tiles' part of "
+        "its running sum): 0 where attention never looks past its window.")
+
+
+def train_mbp_loss() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_mbp_loss",
+        "Cross-entropy of each prediction head in the last recorded step "
+        "(models/lm.py multi_token_loss): head i predicts token t + 1 + i.",
+        tag_keys=("head",))
+
+
 # -- train set-up ----------------------------------------------------------
 # A few dozen events a process (and again at every gang restart), so their
 # durations are observed whether or not anybody traces; the span beside each

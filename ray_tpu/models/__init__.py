@@ -1,7 +1,7 @@
-from ray_tpu.models import (afmoe, bert, deepseek, diffusion, exchange,
-                            glm_moe_dsa, gpt, granite, kimi_linear, lfm2, lm,
-                            t5, vit)
+from ray_tpu.models import (afmoe, bert, deepseek, diffusion, evabyte,
+                            exchange, glm_moe_dsa, gpt, granite, kimi_linear,
+                            lfm2, lm, t5, vit)
 
-__all__ = ["afmoe", "bert", "deepseek", "diffusion", "exchange",
+__all__ = ["afmoe", "bert", "deepseek", "diffusion", "evabyte", "exchange",
            "glm_moe_dsa", "gpt", "granite", "kimi_linear", "lfm2", "lm", "t5",
            "vit"]

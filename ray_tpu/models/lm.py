@@ -12,8 +12,8 @@ sibling ``models/exchange.py``'s. This module imports ``parallel/`` and
 second family needs of a first moves here.
 
 **A family** (``models/deepseek.py``, ``granite.py``, ``afmoe.py``,
-``kimi_linear.py``, ``lfm2.py``, ``phi4flash.py``, ``glm_moe_dsa.py``) is
-three things, written against this module:
+``kimi_linear.py``, ``lfm2.py``, ``phi4flash.py``, ``glm_moe_dsa.py``,
+``evabyte.py``) is three things, written against this module:
 
 * its config, a frozen dataclass under the published keys, with
   ``vocab_size``, ``hidden_size``, the epsilon of its norms, the program's
@@ -38,7 +38,8 @@ three things, written against this module:
   bias leaf (``final_norm_bias``) and calls ``layernorm`` in its blocks;
   one whose blocks read a per-layer value no gradient moves gives
   ``constants``; one whose loss has a second term that its blocks' aux
-  carries gives ``extra_loss``.
+  carries gives ``extra_loss``; one whose hidden state feeds several
+  prediction heads gives ``pred_heads`` (``multi_token_loss``).
 
 ``Decoder`` makes of them ``init``, ``param_specs``, ``hidden_states``,
 ``head``, ``forward``, ``forward_with_aux``, ``loss_of_hidden`` and
@@ -337,6 +338,38 @@ def attention(q, k, v, cfg, scale: Optional[float] = None,
                 "axis (parallel.mesh.set_current_mesh)")
         return make_ulysses_attention(mesh)(q, k, v)
     raise ValueError(f"Unknown attn_impl {cfg.attn_impl!r}")
+
+
+def eva_attention(q, k, v, phi, mu, cfg, window: int, chunk: int):
+    """EVA attention by ``cfg.attn_impl`` (``ops/eva.py``): q's softmax over
+    the keys of its own block window of ``window`` and, under the same
+    softmax, one summary of every ``chunk`` keys of the windows before it,
+    pooled against ``phi`` with ``mu`` added to the pooled key (both [H,
+    D]). q, k [B, S, H, D], v [B, S, H, Dv] -> (out [B, S, H, Dv], mass [B,
+    H, S] float32, the share of each query's softmax sum on summaries; no
+    gradient). ``dot`` or ``flash``; under a mesh the kernels run per shard
+    of the batch and, where tp divides them, of the heads."""
+    from ray_tpu.ops import eva
+    with jax.named_scope("eva_pool"):
+        kc, vc = eva.pool(k, v, phi, mu, chunk)
+    with jax.named_scope("eva_attn"):
+        if cfg.attn_impl == "dot":
+            return eva.dot_eva_attention(q, k, v, kc, vc, window, chunk)
+        if cfg.attn_impl != "flash":
+            raise NotImplementedError(
+                f"attn_impl={cfg.attn_impl!r}: EVA attention runs as 'dot' "
+                "or 'flash' (ops/eva.py)")
+        from ray_tpu.parallel.mesh import current_mesh
+        fn = partial(eva.eva_attention, window=window, chunk=chunk,
+                     blk_q=cfg.attn_blk_q, blk_k=cfg.attn_blk_k)
+        mesh = current_mesh()
+        if mesh is None or mesh.size == 1:
+            return fn(q, k, v, kc, vc)
+        spec, _ = attention_specs(mesh, q.shape[2], k.shape[2],
+                                  seq_axis=None)
+        return per_shard(fn, mesh, (spec,) * 5,
+                         (spec, PartitionSpec(spec[0], spec[2], None)))(
+            q, k, v, kc, vc)
 
 
 # -- state-space scan -----------------------------------------------------
@@ -730,8 +763,10 @@ def moe_metrics(aux, routed_a_layer: int) -> Dict[str, jax.Array]:
 # -- head and loss --------------------------------------------------------
 
 def ce_stats(logits: jax.Array, targets: jax.Array, mask: jax.Array,
-             z_loss: float) -> Tuple[jax.Array, jax.Array]:
-    """fp32 CE pieces for one [..., vocab] logits slab → (Σ nll·m, Σ hit·m)."""
+             z_loss: float, per: int = 0) -> Tuple[jax.Array, jax.Array]:
+    """fp32 CE pieces for one [..., vocab] logits slab → (Σ nll·m, Σ hit·m),
+    the sums over all of targets' axes but the last ``per`` (prediction
+    heads, each with a sum of its own)."""
     logits = logits.astype(jnp.float32)
     logz = jax.scipy.special.logsumexp(logits, axis=-1)
     tgt_logit = jnp.take_along_axis(
@@ -740,7 +775,8 @@ def ce_stats(logits: jax.Array, targets: jax.Array, mask: jax.Array,
     if z_loss:
         nll = nll + z_loss * logz ** 2
     hits = (logits.argmax(-1) == targets).astype(jnp.float32)
-    return (nll * mask).sum(), (hits * mask).sum()
+    over = tuple(range(nll.ndim - per))
+    return (nll * mask).sum(over), (hits * mask).sum(over)
 
 
 def chunked_ce(head, x: jax.Array, targets: jax.Array, mask32: jax.Array,
@@ -751,6 +787,11 @@ def chunked_ce(head, x: jax.Array, targets: jax.Array, mask32: jax.Array,
     fp32; ``weight`` is a scalar no gradient moves (None: 1).
 
     ``head`` maps hidden [..., d] to logits [..., vocab]; x is [B, S, d].
+    Where one hidden state feeds several prediction heads, ``head`` gives
+    [..., heads, vocab], targets and mask32 are [B, S, heads] and ``weight``
+    may be [heads]: the result is then (Σ over heads of weight · Σ nll·mask,
+    (weight · Σ nll·mask, Σ hit·mask) a head, which move no gradient): one
+    walk and one cotangent for all the heads (``_totals``).
     With ``chunk > 0`` the head matmul and the fp32 softmax run ``chunk``
     tokens at a time under a lax.scan, so the [tokens, vocab] fp32 logits
     never exist whole. A chunk is a slice of S across the whole
@@ -773,11 +814,15 @@ def chunked_ce(head, x: jax.Array, targets: jax.Array, mask32: jax.Array,
     which cannot close over them: callers hand over a closure as before.
     Reverse mode only; forward mode takes ``chunk=0``."""
     with jax.named_scope("head_loss"):
-        B, S = targets.shape
+        B, S = targets.shape[:2]
+        per = targets.ndim - 2
         weight = jnp.asarray(1.0 if weight is None else weight, jnp.float32)
+        if per:
+            weight = jnp.broadcast_to(weight, targets.shape[2:])
         if not (chunk and B * S > chunk):
-            nll_sum, hit_sum = ce_stats(head(x), targets, mask32, z_loss)
-            return nll_sum * jax.lax.stop_gradient(weight), hit_sum
+            nll_sum, hit_sum = ce_stats(head(x), targets, mask32, z_loss,
+                                        per)
+            return _totals(nll_sum, hit_sum, jax.lax.stop_gradient(weight))
         # The fewest slices of S that hold at most ``chunk`` tokens each:
         # where ``chunk`` does not divide, the largest slice under it that
         # does, never the whole logits (the feature's memory bound stands).
@@ -799,11 +844,22 @@ def _slices(n: int, *arrays: jax.Array) -> Tuple[jax.Array, ...]:
     return tuple(slices(a) for a in arrays)
 
 
+def _totals(nll_sum, hit_sum, weight):
+    """What ``chunked_ce`` returns of the sums: of one head (scalars),
+    ``(nll_sum * weight, hit_sum)``; of several ([heads]), the weighted sum
+    over the heads, which is what a gradient is taken of, and the pair a
+    head beside it."""
+    if not nll_sum.ndim:
+        return nll_sum * weight, hit_sum
+    weighted = nll_sum * weight
+    return weighted.sum(), (jax.lax.stop_gradient(weighted), hit_sum)
+
+
 def _chunk_stats(head, z_loss, head_params, xtm):
     """``ce_stats`` of one slice as ``_slices`` cuts them, on one row of
     tokens [B * S / n, ...], as head and loss see it on one device too."""
     x_c, t_c, m_c = (a.reshape(-1, *a.shape[2:]) for a in xtm)
-    return ce_stats(head(x_c, *head_params), t_c, m_c, z_loss)
+    return ce_stats(head(x_c, *head_params), t_c, m_c, z_loss, t_c.ndim - 1)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
@@ -815,9 +871,9 @@ def _chunked_sums(head, n, z_loss, x, head_params, targets, mask32, weight):
         return (carry[0] + nll_sum, carry[1] + hit_sum), None
 
     (nll_sum, hit_sum), _ = jax.lax.scan(
-        chunk_stats, (jnp.zeros((), jnp.float32),) * 2,
+        chunk_stats, (jnp.zeros(targets.shape[2:], jnp.float32),) * 2,
         _slices(n, x, targets, mask32))
-    return nll_sum * weight, hit_sum
+    return _totals(nll_sum, hit_sum, weight)
 
 
 def _chunked_sums_fwd(head, n, z_loss, x, head_params, targets, mask32,
@@ -832,11 +888,11 @@ def _chunked_sums_fwd(head, n, z_loss, x, head_params, targets, mask32,
         (nll_c, hit_c), vjp = jax.vjp(
             lambda x_c, p: _chunk_stats(head, z_loss, p, (x_c, *xtm[1:])),
             xtm[0], head_params)
-        d_x, d_params_c = vjp((weight, jnp.zeros((), jnp.float32)))
+        d_x, d_params_c = vjp((weight, jnp.zeros(weight.shape, jnp.float32)))
         d_params = jax.tree.map(jnp.add, d_params, d_params_c)
         return (nll_sum + nll_c, hit_sum + hit_c, d_params), d_x
 
-    zero = jnp.zeros((), jnp.float32)
+    zero = jnp.zeros(targets.shape[2:], jnp.float32)
     (nll_sum, hit_sum, d_params), d_x = jax.lax.scan(
         chunk_stats, (zero, zero, jax.tree.map(jnp.zeros_like, head_params)),
         _slices(n, x, targets, mask32))
@@ -846,7 +902,7 @@ def _chunked_sums_fwd(head, n, z_loss, x, head_params, targets, mask32,
     # backward pass starts from these, whole. Without it the compiler
     # orders GPT-J's layer scan backward differently behind the walk and
     # that step's arena is 0.2 GB larger than it was (off the chip and on).
-    return (nll_sum * weight, hit_sum), jax.lax.optimization_barrier(
+    return _totals(nll_sum, hit_sum, weight), jax.lax.optimization_barrier(
         (d_x, d_params))
 
 
@@ -890,6 +946,49 @@ def next_token_loss(head, x: jax.Array, targets: jax.Array,
                                1.0 / denom)
     return loss, {"loss": loss, "accuracy": hit_sum / denom,
                   "perplexity": jnp.exp(jnp.minimum(loss, 20.0))}
+
+
+def shifted_targets(targets: jax.Array, mask: Optional[jax.Array],
+                    heads: int) -> Tuple[jax.Array, jax.Array]:
+    """(targets [B, S, heads] int32, mask [B, S, heads] float32) of
+    ``heads`` prediction heads from next-token targets [B, S] (``targets[:,
+    t]`` is token t + 1): head i at position t predicts token t + 1 + i,
+    ``targets[:, t + i]``, under that target's own mask, and nothing where t
+    + i is past the sequence (mask 0, target 0)."""
+    B, S = targets.shape
+    mask32 = jnp.ones((B, S), jnp.float32) if mask is None \
+        else mask.astype(jnp.float32)
+
+    def shifted(a, i):
+        return jnp.pad(a[:, i:], ((0, 0), (0, i)))
+
+    return (jnp.stack([shifted(targets, i) for i in range(heads)], -1),
+            jnp.stack([shifted(mask32, i) for i in range(heads)], -1))
+
+
+def multi_token_loss(head, x: jax.Array, targets: jax.Array,
+                     mask: Optional[jax.Array], chunk: int, heads: int
+                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The loss of ``heads`` prediction heads on one hidden state: the mean,
+    with equal weights, of every head's cross-entropy over the positions
+    whose target ``shifted_targets`` keeps -> (loss, {"loss", "accuracy",
+    "perplexity": head 0's, the next token's, as ``next_token_loss`` gives
+    them; "total_loss": the mean; "mbp_loss_<i>": head i's}).
+
+    ``head`` maps hidden [..., d] to logits [..., heads, vocab]. One walk of
+    ``chunked_ce`` over (position, head) rows, each head's 1 / (heads x its
+    tokens) as its weight; with one head this is ``next_token_loss``, bit
+    for bit."""
+    with jax.named_scope("mbp_head"):
+        targets, mask32 = shifted_targets(targets, mask, heads)
+        denom = jnp.maximum(mask32.sum((0, 1)), 1.0)  # [heads]
+        total, (weighted, hit_sum) = chunked_ce(
+            head, x, targets, mask32, chunk, 0.0, 1.0 / (heads * denom))
+    head_losses = weighted * heads
+    return total, {"loss": head_losses[0], "accuracy": hit_sum[0] / denom[0],
+                   "perplexity": jnp.exp(jnp.minimum(head_losses[0], 20.0)),
+                   "total_loss": total,
+                   **{f"mbp_loss_{i}": head_losses[i] for i in range(heads)}}
 
 
 # -- the decoder's shell --------------------------------------------------
@@ -969,16 +1068,30 @@ class Decoder:
     #: and reports it as ``total_loss``; ``loss`` stays the cross-entropy,
     #: which ``perplexity`` is of. None: no second term.
     extra_loss: Optional[Callable] = None
+    #: cfg -> how many prediction heads read one hidden state (head i
+    #: predicts token t + 1 + i): ``lm_head`` is then [d, heads x vocab],
+    #: ``head`` returns [..., heads, vocab] and the loss is
+    #: ``multi_token_loss``. None: one head, the shapes without the axis.
+    pred_heads: Optional[Callable] = None
+    #: The final norm's leaf is an offset from one (drawn zero): its scale
+    #: is ``1 + leaf``, as the family's blocks read their own.
+    unit_offset: bool = False
+    #: The head's product comes out in float32, not in ``cfg.dtype``.
+    fp32_logits: bool = False
+    #: The std ``wte`` and ``lm_head`` are drawn at: cfg -> float.
+    top_std: Callable = lambda cfg: 0.02
 
     def _top(self, cfg):
         """The leaves outside the layer stacks, as a table."""
-        v, d = cfg.vocab_size, cfg.hidden_size
-        top = {"wte": ((v, d), ("vocab", "embed"), 0.02),
-               self.final_norm: ((d,), ("embed",), ones)}
+        v, d, std = cfg.vocab_size, cfg.hidden_size, self.top_std(cfg)
+        top = {"wte": ((v, d), ("vocab", "embed"), std),
+               self.final_norm: ((d,), ("embed",),
+                                 zeros if self.unit_offset else ones)}
         if self.final_norm_bias:
             top[self.final_norm_bias] = ((d,), ("embed",), zeros)
         if not self.tied:
-            top["lm_head"] = ((d, v), ("embed", "vocab"), 0.02)
+            heads = self.pred_heads(cfg) if self.pred_heads else 1
+            top["lm_head"] = ((d, heads * v), ("embed", "vocab"), std)
         return top
 
     def _stacks(self, cfg):
@@ -1051,7 +1164,10 @@ class Decoder:
         if self.final_norm_bias:
             return layernorm(x, params[self.final_norm],
                              params[self.final_norm_bias], eps), aux
-        return rmsnorm(x, params[self.final_norm], eps), aux
+        scale = params[self.final_norm]
+        if self.unit_offset:
+            scale = 1.0 + scale.astype(jnp.float32)
+        return rmsnorm(x, scale, eps), aux
 
     def head(self, params: Dict[str, Any], cfg, x: jax.Array):
         """Logits [..., vocab] of final-normed hidden states x [..., d].
@@ -1063,8 +1179,16 @@ class Decoder:
         if self.tied:
             return jnp.einsum("...d,vd->...v", x,
                               params["wte"].astype(cfg.dtype))
-        return jnp.einsum("...d,dv->...v", x,
-                          params["lm_head"].astype(cfg.dtype))
+        if not (self.pred_heads or self.fp32_logits):
+            return jnp.einsum("...d,dv->...v", x,
+                              params["lm_head"].astype(cfg.dtype))
+        logits = jnp.einsum(
+            "...d,dv->...v", x, params["lm_head"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32 if self.fp32_logits else None)
+        if self.pred_heads:
+            logits = logits.reshape(*logits.shape[:-1], self.pred_heads(cfg),
+                                    cfg.vocab_size)
+        return logits
 
     def forward_with_aux(self, params: Dict[str, Any], cfg,
                          tokens: jax.Array,
@@ -1082,9 +1206,13 @@ class Decoder:
                        targets: jax.Array, mask: Optional[jax.Array] = None
                        ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """``loss_fn`` from ``hidden_states``' result (x [B, S, d], aux)."""
-        loss, metrics = next_token_loss(
-            partial(self.head, head_gathered(params, self.tied), cfg), x,
-            targets, mask, cfg.loss_chunk, 0.0)
+        head = partial(self.head, head_gathered(params, self.tied), cfg)
+        if self.pred_heads:
+            loss, metrics = multi_token_loss(
+                head, x, targets, mask, cfg.loss_chunk, self.pred_heads(cfg))
+        else:
+            loss, metrics = next_token_loss(head, x, targets, mask,
+                                            cfg.loss_chunk, 0.0)
         if self.extra_loss:
             loss = loss + self.extra_loss(cfg, aux, mask)
             metrics = {**metrics, "total_loss": loss}

@@ -25,6 +25,23 @@ ones; a call with a window that cuts something carries names of its own
 (``flash_fwd_win``, ``flash_bwd_dq_win``, ``flash_bwd_dkv_win``), so that a
 trace tells a window layer's kernels from a full layer's.
 
+The tables can say more than those two edges, and what they say is static
+(a function of S and a layer's sizes, nothing of the data). ``Summaries``
+is a third mask, EVA's (``ops/eva.py``): the window is a *block* (query t
+sees the keys of its own window ``W (t // W) .. t``, not the ``W`` before
+it) and the keys carry a **second source** stacked in front of them, one
+pooled key and value a chunk of ``chunk`` keys, of which a query sees those
+whose chunk lies in a window before its own. One K and one V of ``rows +
+S`` rows, one table of (Q tile, KV tile) pairs (a row of tiles walks the
+summary tiles it sees, then its own window's tiles up to the diagonal), one
+online-softmax state through both sources; a tile is all summaries or all
+keys (``rows`` is a multiple of ``blk_k``), and which it is decides the
+tile's mask. The three kernel bodies are the ones below with that mask in
+the causal one's place (under the names ``eva_fwd``, ``eva_bwd_dq``,
+``eva_bwd_dkv``: ``ops/eva.py`` makes the calls); ``eva_tile_census``
+counts the table. A call with ``window`` None or an int builds the tables
+above and lowers to the text it always did.
+
 The forward kernel walks the table Q-major, KV tiles ascending, with the
 online-softmax state in VMEM scratch, keeping the MXU fed with
 [blk_q, D] x [D, blk_k] matmuls (pallas_guide.md: grid/BlockSpec + scratch
@@ -58,6 +75,7 @@ runs anything but the kernels under this name.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -86,9 +104,63 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+@dataclasses.dataclass(frozen=True)
+class Summaries:
+    """EVA's mask over ``rows + S`` stacked key rows, given where a kernel
+    takes a ``window``: columns ``0 .. rows - 1`` are summaries (summary j
+    pools keys ``chunk j .. chunk j + chunk - 1``; rows past ``S / chunk``
+    are padding up to a whole tile and seen by nobody), column ``rows + m``
+    is key m. Query t, whose window starts at ``s = window * (t //
+    window)``, sees key m where ``s <= m <= t`` and summary j where ``chunk
+    j < s``: the chunks of the windows before its own, never one of its
+    own window (those keys it sees themselves)."""
+    window: int
+    chunk: int
+    rows: int
+
+    def __post_init__(self):
+        if self.window < 1 or self.chunk < 1 or self.window % self.chunk:
+            raise ValueError(
+                f"a window of {self.window} keys is not whole chunks of "
+                f"{self.chunk}")
+
+    def aligned(self, blk_q: int, blk_k: int) -> bool:
+        """Every row of a Q tile lies in one window and no KV tile crosses
+        a window's edge: the window's start is a scalar of the grid step
+        and the table alone keeps a row from the windows before its own."""
+        return self.window % blk_q == 0 and self.window % blk_k == 0
+
+
+def _summaries_mask(qi, ki, blk_q: int, blk_k: int, eva: Summaries):
+    """``_causal_mask`` under ``Summaries``: [blk_q, blk_k] bool for Q tile
+    qi against tile ki of the stacked keys."""
+    q_pos = qi * blk_q + jax.lax.broadcasted_iota(
+        jnp.int32, (blk_q, blk_k), 0)
+    col = ki * blk_k + jax.lax.broadcasted_iota(
+        jnp.int32, (blk_q, blk_k), 1)
+    is_summary = ki * blk_k < eva.rows  # of the whole tile
+    if eva.aligned(blk_q, blk_k):
+        # One compare: a summary tile's bound is the scalar count of
+        # summaries before the window, a key tile's the diagonal (the
+        # table holds no key tile of an earlier window).
+        seen = (qi * blk_q) // eva.window * (eva.window // eva.chunk)
+        slope = jnp.where(is_summary, 0, 1)
+        bound = jnp.where(is_summary, seen - 1, eva.rows)
+        return col <= slope * q_pos + bound
+    # A tile is all summaries or all keys, so the two masks never meet;
+    # Mosaic has no select between vectors of booleans.
+    start = q_pos - jax.lax.rem(q_pos, eva.window)
+    key = col - eva.rows
+    return (col * eva.chunk < start) & (key < 0) \
+        | (key <= q_pos) & (key >= start)
+
+
 def _causal_mask(qi, ki, blk_q: int, blk_k: int, window=None):
     """[blk_q, blk_k] bool: query row >= key column, for tiles qi and ki;
-    with a ``window`` also query row - key column < window."""
+    with a ``window`` also query row - key column < window; with
+    ``Summaries`` for a window, their mask."""
+    if isinstance(window, Summaries):
+        return _summaries_mask(qi, ki, blk_q, blk_k, window)
     q_pos = qi * blk_q + jax.lax.broadcasted_iota(
         jnp.int32, (blk_q, blk_k), 0)
     k_pos = ki * blk_k + jax.lax.broadcasted_iota(
@@ -123,11 +195,49 @@ def _tile_grid(S: int, blk_q: int, blk_k: int):
                        np.arange(S // blk_k, dtype=np.int32), indexing="ij")
 
 
+def _summaries_tiles(S: int, blk_q: int, blk_k: int, eva: Summaries):
+    """(seen, full): [S / blk_q, (rows + S) / blk_k] bool, whether a tile
+    of ``Summaries``' mask allows some pair, and whether every one. Exact:
+    each query row against each tile's first and last column, then over a
+    Q tile's rows."""
+    if eva.rows % blk_k or eva.rows * eva.chunk < S:
+        raise ValueError(
+            f"{eva.rows} summary rows in front of {S} keys: a multiple of "
+            f"the KV tile ({blk_k}) that holds S / chunk = {S // eva.chunk}")
+    t = np.arange(S, dtype=np.int64)[:, None]
+    start = t - t % eva.window
+    first = np.arange(0, eva.rows + S, blk_k, dtype=np.int64)[None, :]
+    last = first + blk_k - 1
+    summary = first < eva.rows
+    lo, hi = first - eva.rows, last - eva.rows  # as keys
+    seen = np.where(summary, first * eva.chunk < start,
+                    np.maximum(lo, start) <= np.minimum(hi, t))
+    full = np.where(summary, last * eva.chunk < start,
+                    (start <= lo) & (hi <= t))
+
+    def over_rows(a, fn):
+        return fn(a.reshape(S // blk_q, blk_q, -1), axis=1)
+
+    return over_rows(seen, np.any), over_rows(full, np.all)
+
+
 def _tile_pairs(S: int, blk_q: int, blk_k: int, causal: bool,
                 kv_major: bool, window=None):
     """The tiles with work as two int32 tables (qi_tab, ki_tab), one entry
     a grid step: Q-major with KV tiles ascending, or KV-major with Q tiles
-    ascending, so every sum a kernel carries keeps its order."""
+    ascending, so every sum a kernel carries keeps its order. Under
+    ``Summaries`` the KV tiles are those of the stacked rows, and KV-major
+    every one of them has a step (a tile nobody sees, the last window's
+    summaries or padding, gets the last Q tile: its cotangents are written,
+    as zeros)."""
+    if isinstance(window, Summaries):
+        keep = _summaries_tiles(S, blk_q, blk_k, window)[0]
+        if kv_major:
+            keep[-1, ~keep.any(0)] = True
+            ki, qi = np.nonzero(keep.T)
+        else:
+            qi, ki = np.nonzero(keep)
+        return qi.astype(np.int32), ki.astype(np.int32)
     qi, ki = _tile_grid(S, blk_q, blk_k)
     keep = ~_is_empty(qi, ki, blk_q, blk_k, window) if causal \
         else np.ones_like(qi, bool)
@@ -155,6 +265,53 @@ def window_tile_census(S: int, window, blk_q: int, blk_k: int) -> dict:
     executed = qi.size - empty
     return {"executed": executed, "diagonal": executed - full,
             "full": full, "empty": empty}
+
+
+def summary_rows(S: int, chunk: int, blk_k: int) -> int:
+    """Rows the summaries take in front of the keys: S / chunk, up to a
+    whole KV tile."""
+    return -(-(S // chunk) // blk_k) * blk_k
+
+
+def eva_tile_census(S: int, window: int, chunk: int, blk_q: int,
+                    blk_k: int) -> dict:
+    """``window_tile_census`` of ``Summaries``' table over one head of S
+    queries: ``local`` tiles of keys (``diagonal`` of them cut by the
+    diagonal or a window's edge) and ``summary`` tiles, ``executed`` their
+    sum, the length of the forward's table; ``pairs`` the (query, key or
+    summary) pairs EVA defines (the closed form) and ``summary_pairs``
+    those on summaries, ``counted_pairs`` the pairs the table's tiles allow
+    under the kernels' own mask (a whole tile counted whole, a cut one by
+    its mask: equal to ``pairs``, or table and mask are wrong),
+    ``causal_pairs`` what causal attention would touch, and
+    ``summary_columns_masked`` the share of the summary tiles' columns that
+    are computed and masked. At S = 32768, a window of 2048, chunks of 16
+    and tiles of 512 x 512: 160 local (64 diagonal) and 144 summary tiles,
+    304 where causal attention has 2,080; 65,028,096 pairs, 12.11 % of the
+    536,887,296 causal ones, 48.4 % of them summaries; of the summary
+    tiles' columns a sixth is masked."""
+    rows = summary_rows(S, chunk, blk_k)
+    eva = Summaries(window, chunk, rows)
+    seen, full = _summaries_tiles(S, blk_q, blk_k, eva)
+    summary = np.arange(seen.shape[1]) * blk_k < rows
+    t = np.arange(S, dtype=np.int64)
+    start = t - t % window
+    local_pairs = int((t - start + 1).sum())
+    summary_pairs = int((start // chunk).sum())
+    summary_tiles = int(seen[:, summary].sum())
+    with jax.ensure_compile_time_eval():
+        counted = int(full.sum()) * blk_q * blk_k + sum(
+            int(_summaries_mask(int(qi), int(ki), blk_q, blk_k, eva).sum())
+            for qi, ki in zip(*np.nonzero(seen & ~full)))
+    return {"executed": int(seen.sum()), "counted_pairs": counted,
+            "local": int(seen[:, ~summary].sum()),
+            "diagonal": int((seen & ~full)[:, ~summary].sum()),
+            "summary": summary_tiles,
+            "pairs": local_pairs + summary_pairs,
+            "summary_pairs": summary_pairs,
+            "causal_pairs": S * (S + 1) // 2,
+            "summary_columns_masked":
+                1.0 - summary_pairs / max(summary_tiles * blk_q * blk_k, 1)}
 
 
 def _cutting(window, S: int):
@@ -215,13 +372,16 @@ def _lanes(stat, width: int):
 
 def _flash_fwd_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, lse_ref,
                       m_scr, l_scr, o_scr, *, blk_q: int, blk_k: int,
-                      causal: bool, scale: float, window=None):
+                      causal: bool, scale: float, window=None, mass=None):
     """Grid: (batch*heads, pairs), Q-major, the pair axis sequential. One
     [blk_q, D] Q tile against one [blk_k, D] / [blk_k, Dv] KV tile per
     step, the online-softmax state (m, l, o) carried in VMEM scratch along
     a row of tiles, m and l [blk_q, 128] lane-dense or [blk_q, 1] as the
     call site sized them (``_stat_lanes``); o_ref [blk_q, Dv] and lse_ref
-    [1, blk_q] are written at the row's last step."""
+    [1, blk_q] are written at the row's last step. ``mass`` (under
+    ``Summaries`` alone) is one more output and one more statistic,
+    ``(mass_ref [1, blk_q], s_scr)``: the part of l that the summary tiles
+    gave, kept beside l, and at the last step its share of l."""
     t = pl.program_id(1)
     qi, ki = qi_tab[t], ki_tab[t]
     first, last = _row_ends(qi_tab)
@@ -232,6 +392,8 @@ def _flash_fwd_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         o_scr[...] = jnp.zeros(o_scr.shape, jnp.float32)
+        if mass is not None:
+            mass[1][...] = jnp.zeros(mass[1].shape, jnp.float32)
 
     q = q_ref[...].astype(jnp.float32) * scale
     k_blk = k_ref[...].astype(jnp.float32)
@@ -254,7 +416,13 @@ def _flash_fwd_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, lse_ref,
     # until the row's first real logit (every row sees itself) makes
     # corr = exp(-1e30 - m_new) = 0.0 exactly and wipes both.
     p = jnp.exp(logits - _lanes(m_new, blk_k))
-    l_scr[...] = l_scr[...] * corr + p.sum(-1, keepdims=True)
+    if mass is None:
+        l_scr[...] = l_scr[...] * corr + p.sum(-1, keepdims=True)
+    else:
+        p_sum = p.sum(-1, keepdims=True)
+        l_scr[...] = l_scr[...] * corr + p_sum
+        mass[1][...] = mass[1][...] * corr + jnp.where(
+            ki * blk_k < window.rows, p_sum, 0.0)
     o_scr[...] = o_scr[...] * _lanes(corr, Dv) + jax.lax.dot_general(
         p, v_blk, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -265,6 +433,8 @@ def _flash_fwd_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         o_ref[...] = (o_scr[...] / _lanes(l_safe, Dv)).astype(o_ref.dtype)
         lse_ref[...] = (m_scr[...] + jnp.log(l_safe))[:, 0][None, :]
+        if mass is not None:
+            mass[0][...] = (mass[1][...] / l_safe)[:, 0][None, :]
 
 
 def _flash_bwd_dq_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref,

@@ -605,3 +605,89 @@ def test_a_kept_selection_is_not_searched_twice():
     jax.block_until_ready(jax.grad(lambda p: shell.hidden_states(
         p, cfg, _tokens(cfg))[0].sum())(params))
     assert len(calls) == 2  # once a layer: the forward pass alone
+
+
+# -- several prediction heads, a unit offset, float32 logits -----------------
+
+def _heads_by_hand(shell, params, cfg, tokens, targets, mask, heads):
+    """The mean, over the heads, of each head's mean cross-entropy over the
+    positions whose target lies inside the sequence (and is kept), from
+    the whole logits."""
+    x, _ = shell.hidden_states(params, cfg, tokens)
+    logits = (x @ params["lm_head"]).reshape(*x.shape[:2], heads, -1)
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    S = targets.shape[1]
+    losses = []
+    for i in range(heads):
+        # Head i at t = 0 .. S - 1 - i against targets t + i.
+        nll = -jnp.take_along_axis(logp[:, :S - i, i], targets[:, i:, None],
+                                   axis=-1)[..., 0]
+        keep = jnp.ones_like(nll) if mask is None else mask[:, i:]
+        losses.append((nll * keep).sum() / keep.sum())
+    return jnp.stack(losses)
+
+
+@pytest.mark.parametrize("heads,loss_chunk,masked", [
+    (1, 0, False), (3, 0, True), (3, 4, False), (3, 4, True)])
+def test_prediction_heads_share_one_hidden_state(heads, loss_chunk, masked):
+    """``pred_heads``: ``lm_head`` is [d, heads x vocab], ``head`` returns
+    [..., heads, vocab], head i at t is held to token t + 1 + i, the loss is
+    the mean of the heads' and its gradient plain autodiff's; ``loss`` and
+    ``perplexity`` stay head 0's."""
+    cfg = replace(CFG, loss_chunk=loss_chunk, layers=("plain", "counted"))
+    shell = toy(pred_heads=lambda cfg: heads)
+    params = shell.init(cfg, jax.random.PRNGKey(0))
+    assert params["lm_head"].shape == (8, heads * 32)
+    tokens, targets = _tokens(cfg), _tokens(cfg)[:, ::-1]
+    assert shell.forward(params, cfg, tokens).shape == (2, 6, heads, 32)
+    mask = jnp.ones(tokens.shape).at[0, 1:3].set(0.0) if masked else None
+    (loss, metrics), grads = jax.value_and_grad(
+        lambda p: shell.loss_fn(p, cfg, tokens, targets, mask),
+        has_aux=True)(params)
+    want, want_grads = jax.value_and_grad(lambda p: _heads_by_hand(
+        shell, p, cfg, tokens, targets, mask, heads).mean())(params)
+    each = _heads_by_hand(shell, params, cfg, tokens, targets, mask, heads)
+    np.testing.assert_allclose(loss, want, rtol=1e-6)
+    np.testing.assert_allclose(metrics["total_loss"], want, rtol=1e-6)
+    np.testing.assert_allclose(metrics["loss"], each[0], rtol=1e-6)
+    np.testing.assert_allclose(metrics["perplexity"], jnp.exp(each[0]),
+                               rtol=1e-5)
+    for i in range(heads):
+        np.testing.assert_allclose(metrics[f"mbp_loss_{i}"], each[i],
+                                   rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+def test_a_unit_offset_norm_draws_zeros_and_scales_by_one_plus_them():
+    """``unit_offset``: the final norm's leaf is drawn zero, the other
+    leaves as without it, and the scale is 1 + the leaf."""
+    plain, shell = toy(), toy(unit_offset=True)
+    params = shell.init(CFG, jax.random.PRNGKey(3))
+    ones = plain.init(CFG, jax.random.PRNGKey(3))
+    np.testing.assert_array_equal(params["lnf_scale"], 0.0)
+    tokens = _tokens(CFG)
+    np.testing.assert_array_equal(
+        shell.hidden_states(params, CFG, tokens)[0],
+        plain.hidden_states(ones, CFG, tokens)[0])
+    params["lnf_scale"] = params["lnf_scale"] + 0.5
+    np.testing.assert_allclose(
+        shell.hidden_states(params, CFG, tokens)[0],
+        1.5 * plain.hidden_states(ones, CFG, tokens)[0], rtol=1e-6)
+
+
+def test_float32_logits_and_the_tops_own_std():
+    """``fp32_logits``: the head's product leaves in float32 whatever
+    ``cfg.dtype``; ``top_std``: what ``wte`` and ``lm_head`` are drawn
+    at."""
+    cfg = replace(CFG, hidden_size=256, vocab_size=512)
+    x = jnp.ones((2, 3, 256), jnp.bfloat16)
+    for fp32, dtype in ((False, jnp.bfloat16), (True, jnp.float32)):
+        shell = toy(fp32_logits=fp32)
+        params = shell.init(cfg, jax.random.PRNGKey(0))
+        assert shell.head(params, replace(cfg, dtype=jnp.bfloat16),
+                          x).dtype == dtype
+    narrow = toy(top_std=lambda cfg: 0.002).init(cfg, jax.random.PRNGKey(0))
+    for name in ("wte", "lm_head"):
+        assert abs(float(narrow[name].std()) - 0.002) < 2e-4
+        assert abs(float(params[name].std()) - 0.02) < 2e-3
