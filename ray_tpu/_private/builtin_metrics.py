@@ -1112,8 +1112,9 @@ def train_loop_wait_seconds() -> Counter:
     return Counter(
         "ray_tpu_train_loop_wait_seconds_total",
         "Seconds the train loop's thread spent between steps in a report "
-        "(session.report), a save (report_sharded, its ack included) or "
-        "the wait for a batch (iter_jax_batches).",
+        "(session.report), a save (report_sharded: until the state is in "
+        "host memory, the wait for the save before it included) or the "
+        "wait for a batch (iter_jax_batches).",
         tag_keys=("what",))
 
 

@@ -260,8 +260,9 @@ class SpillWriter:
     """A crash-safe write in parts (``SpillBackend.open_writer``), under
     :meth:`SpillBackend.write`'s contract: nothing is visible under the
     final name before :meth:`commit`; any ``OSError``, real or injected
-    at ``spill.write_error`` (evaluated at the open and at every part, so
-    ``after=k`` fails a write mid-stream), unlinks the ``.tmp``, counts
+    at ``spill.write_error`` (evaluated at the open, at every part and at
+    every :meth:`sync`, so ``after=k`` fails a write mid-stream), unlinks
+    the ``.tmp``, counts
     one write failure and surfaces as :class:`SpillFailure`. Leaving the
     ``with`` block without a commit aborts."""
 
@@ -297,6 +298,17 @@ class SpillWriter:
     def write(self, part) -> None:
         """Append one buffer (anything ``file.write`` takes)."""
         self._guarded(self._file.write, part)
+
+    def _sync(self) -> None:
+        self._file.flush()
+        os.fdatasync(self._file.fileno())
+
+    def sync(self) -> None:
+        """Wait until the parts written so far are on the disk. A long
+        write that does this as it goes leaves :meth:`commit`'s fsync
+        only the rest to wait for, and the page cache never holds more of
+        the file than what came since."""
+        self._guarded(self._sync)
 
     def commit(self) -> str:
         """fsync, rename to the final name; returns the spill URI."""

@@ -281,11 +281,13 @@ class BackendExecutor:
         return dead
 
     def _drain(self, pending: Dict[Any, int],
-               latest_checkpoint: Optional[Checkpoint],
+               latest_checkpoint: Callable[[], Optional[Checkpoint]],
                on_payload: Callable[[int, Any], None]) -> None:
         """Gather every pending ref with ``ray_tpu.wait`` (no rank-order
         blocking: whichever rank finishes — or dies — first is observed
-        first). System failures raise ``TrainingFailedError``; after
+        first), until ``pending`` is empty: ``on_payload`` may add to it.
+        System failures raise ``TrainingFailedError`` with what
+        ``latest_checkpoint()`` then names; after
         ``RAY_TPU_train_hang_timeout_s`` with no result, unresponsive
         ranks (failed liveness probe) are treated the same way."""
         import ray_tpu
@@ -305,7 +307,7 @@ class BackendExecutor:
                     except BaseException as exc:  # noqa: BLE001
                         if is_system_failure(exc):
                             raise self._system_failure(
-                                exc, latest_checkpoint) from exc
+                                exc, latest_checkpoint()) from exc
                         raise
                     on_payload(rank, payload)
                 continue
@@ -326,7 +328,7 @@ class BackendExecutor:
                     exc = TimeoutError(
                         f"train ranks {dead} failed their liveness "
                         f"probe ({why})")
-                    raise self._system_failure(exc, latest_checkpoint)
+                    raise self._system_failure(exc, latest_checkpoint())
                 # Alive but slow (XLA compile, giant step): keep waiting.
                 last_progress = time.monotonic()
 
@@ -379,9 +381,10 @@ class BackendExecutor:
                         metrics: Optional[dict]):
         """Phase two of a sharded save: commit iff EVERY rank acked a
         clean shard write under one agreed seq. Anything less — a rank
-        that reported an error, a missing ack, disagreeing seqs — fails
+        that acked an error, a missing ack, disagreeing seqs — fails
         this save attempt cleanly (the previous committed checkpoint
-        still stands) and never writes a manifest."""
+        still stands) and never writes a manifest. ``metrics`` are those
+        of the report that began the save."""
         records = [shard_acks[r] for r in sorted(shard_acks)]
         errors = {r["rank"]: r["error"] for r in records if r.get("error")}
         seqs = {int(r["seq"]) for r in records}
@@ -414,8 +417,8 @@ class BackendExecutor:
         seq = seqs.pop()
         meta = next(r["tree_meta"] for r in records if "tree_meta" in r)
         t0 = time.perf_counter()
-        # On the driver's thread, beside the loop: the ranks were let go
-        # when their acks were taken.
+        # On the driver's thread, beside the loop: the ranks went on
+        # when their state was off the device.
         with tracing.start_span("ckpt::commit") as span:
             if span is not None:
                 span.attributes.update(seq=seq, bytes=sum(
@@ -458,24 +461,54 @@ class BackendExecutor:
             starts[worker.start_training.remote(
                 train_fn, config, trial_info, checkpoint, shards,
                 ckpt_ctx, launched)] = rank
-        self._drain(starts, latest_checkpoint, lambda rank, payload: None)
+        self._drain(starts, lambda: latest_checkpoint,
+                    lambda rank, payload: None)
 
+        world = len(group.workers)
         history: List[Dict[str, Any]] = []
         final_error: Optional[BaseException] = None
         stop_sent = False
-        finished = [False] * len(group.workers)
+        finished = [False] * world
+        # Sharded saves between a rank's ack and the commit:
+        # {seq: {rank: ack item}}. A rank acks when its writer is done,
+        # rounds after the report that began the save and not in the
+        # round its peers do, so acks are gathered by seq.
+        open_saves: Dict[int, Dict[int, dict]] = {}
+
+        def close_save(seq: int) -> None:
+            nonlocal latest_checkpoint
+            acks = open_saves.pop(seq)
+            committed = self._commit_sharded(
+                {rank: item["ack"] for rank, item in acks.items()}, world,
+                acks.get(0, {}).get("metrics"))
+            if committed is not None:
+                latest_checkpoint = committed
+
+        def request(rank: int):
+            return group.workers[rank].get_next_result.remote(
+                self.result_timeout)
+
+        def on_payload(rank: int, payload: dict) -> None:
+            if "ack" not in payload:
+                round_payloads[rank] = payload
+                return
+            # An ack is not the rank's result of this round: ask again,
+            # first, so that the rank's next report is taken while the
+            # manifest is written.
+            pending[request(rank)] = rank
+            seq = int(payload["ack"]["seq"])
+            open_saves.setdefault(seq, {})[rank] = payload
+            if len(open_saves[seq]) == world:
+                close_save(seq)
+
         while not all(finished):
             # Submit one result request to every live worker, then gather
             # via wait — a dead/hung rank 0 can't stall detection of the
             # other ranks' results.
-            pending = {
-                group.workers[rank].get_next_result.remote(
-                    self.result_timeout): rank
-                for rank in range(len(group.workers)) if not finished[rank]
-            }
+            pending = {request(rank): rank
+                       for rank in range(world) if not finished[rank]}
             round_payloads: Dict[int, dict] = {}
-            self._drain(pending, latest_checkpoint,
-                        round_payloads.__setitem__)
+            self._drain(pending, lambda: latest_checkpoint, on_payload)
             for rank, payload in round_payloads.items():
                 if payload.get("timeout"):
                     final_error = TimeoutError(
@@ -507,17 +540,6 @@ class BackendExecutor:
                     else:
                         latest_checkpoint = reported
                     break
-            # Sharded saves: each live rank's payload carries its shard
-            # write ack; all acks clean -> commit the manifest.
-            shard_acks = {rank: p["shard"]
-                          for rank, p in round_payloads.items()
-                          if not p.get("finished") and p.get("shard")}
-            if shard_acks:
-                committed = self._commit_sharded(
-                    shard_acks, len(group.workers),
-                    round_payloads.get(0, {}).get("metrics"))
-                if committed is not None:
-                    latest_checkpoint = committed
             # Rank 0's stream is canonical for metrics (reference behavior);
             # rounds after rank 0 finishes aren't recorded.
             rank0 = round_payloads.get(0)
@@ -530,6 +552,10 @@ class BackendExecutor:
                     stop_sent = True
                     for worker in group.workers:
                         worker.request_stop.remote()
+        # Every rank has finished, and a rank finishes only after its last
+        # ack: a save still open lacks a rank that never began it.
+        for seq in sorted(open_saves):
+            close_save(seq)
         return Result(
             metrics=history[-1] if history else {},
             checkpoint=latest_checkpoint,

@@ -8,9 +8,11 @@ parameter/optimizer blocks through a spill backend
 writes), and the save commits in two phases:
 
 1. every rank writes its shard (atomic tmp → fsync → rename through
-   :mod:`ray_tpu._private.spill`), streamed leaf by leaf from the device
-   to the file (:func:`write_shard`), and acks it to the driver through
-   the ordinary result gather;
+   :mod:`ray_tpu._private.spill`): the train loop waits only while the
+   state leaves the device (:func:`gather_shard`); checksum, write, fsync
+   and rename run on a writer thread beside the next steps
+   (:func:`write_gathered`), which then acks the shard to the driver
+   through the result gather;
 2. only after ALL shard acks does the driver write the **manifest**
    (``train-<run>-ckpt-<seq>.manifest`` — param tree structure, per-param
    spec, mesh shape, shard → file map with per-block byte offsets and
@@ -195,6 +197,10 @@ _PANEL_BYTES = 1 << 20
 #: on one, 0.40 s on four and 0.30 s on eight (PERF.md, PR 24).
 _RELAYOUT_THREADS = 8
 _UINT = {1: np.uint8, 2: np.uint16, 4: np.uint32, 8: np.uint64}
+#: Bytes a shard's writer lets pile up before it waits for the disk: at a
+#: save of 4.86 GB every 11 s three saves' worth of unflushed pages made one
+#: fsync take 11.7 s (PERF.md, PR 50); flushed as it goes, 0.1-2 s a save.
+_SYNC_BYTES = 512 << 20
 
 
 def _crc_mul(a: int, b: int) -> int:
@@ -276,7 +282,7 @@ def extract_local_shard(flat: Dict[str, Any],
                         axes_items: AxesItems,
                         rank: int) -> Dict[str, np.ndarray]:
     """This rank's index block of every leaf, C-contiguous: the arrays
-    whose bytes :func:`write_shard` streams into the rank's shard file."""
+    whose bytes :func:`write_shard` puts into the rank's shard file."""
     axes = dict(axes_items)
     coords = rank_coords(rank, axes_items)
     out = {}
@@ -293,29 +299,23 @@ def extract_local_shard(flat: Dict[str, Any],
 # ---------------------------------------------------------------------------
 
 
-def write_shard(backend: spill.SpillBackend, run: str, seq: int, rank: int,
-                flat: Dict[str, Any], specs: Dict[str, Any],
-                axes_items: AxesItems) -> Dict[str, Any]:
-    """One rank's crash-safe shard write, streamed from the device to the
-    file. The shard file is the pure concatenation of C-order blocks (one
-    per leaf, sorted by path); all metadata — offsets, shapes, checksums
-    — rides the returned record into the manifest, so a byte-range reader
-    never parses the file.
+def gather_shard(run: str, seq: int, rank: int, flat: Dict[str, Any],
+                 specs: Dict[str, Any], axes_items: AxesItems,
+                 detach: bool = False) -> Dict[str, np.ndarray]:
+    """The first half of a rank's shard write, the only one that needs the
+    state: this rank's index block of every leaf, on the host, as a view in
+    the memory order the leaf arrived in (:func:`write_gathered` takes
+    them from here). Every device-to-host transfer is started before the
+    first leaf is read, so the runtime moves them all at once. A device
+    leaf is not copied again: the array ``np.asarray`` gives is the
+    transfer's own host memory, and stays valid when the device buffer is
+    donated or deleted. With ``detach`` a host-resident leaf (numpy, a
+    scalar) has its block copied, in the order it lies in, so that the
+    caller may change it once this returns.
 
-    Every device-to-host transfer is started before the first leaf is
-    read, so the runtime moves the later leaves while this thread
-    checksums and writes the earlier ones. Each byte is then touched
-    twice on the host, by its block's CRC and by the ``write``, both on
-    the array's own memory; the file's CRC is combined from the blocks'.
-    A block is copied only where it is not C-contiguous as it arrives
-    (:func:`_c_order_copy`). The file is fsynced and renamed before this
-    returns (``SpillBackend.open_writer``).
-
-    Spans, recorded under ``train::report_sharded`` only, one after the
-    other on this thread: ``ckpt::prefetch``; per leaf ``ckpt::gather``
-    (the wait for its transfer), ``ckpt::copy`` (only where bytes are
-    copied; ``what`` is ``slice`` or ``relayout``), ``ckpt::checksum``,
-    ``ckpt::write``; a last ``ckpt::write`` for fsync and rename.
+    Spans, recorded under ``train::report_sharded`` only:
+    ``ckpt::prefetch``, then per leaf ``ckpt::gather`` (the wait for its
+    transfer).
 
     Chaos sites, before the first byte: ``train.ckpt_shard_write_error``
     (``io_oserror`` — surfaces as :class:`spill.SpillFailure`, failing
@@ -323,7 +323,6 @@ def write_shard(backend: spill.SpillBackend, run: str, seq: int, rank: int,
     the SIGKILL-mid-save stand-in; :class:`chaos.ChaosKill` propagates so
     the rank can play dead with its shard unwritten).
     """
-    filename = shard_filename(run, seq, rank)
     try:
         if chaos.ACTIVE:
             chaos.maybe_inject("train.ckpt_shard_kill")
@@ -332,23 +331,63 @@ def write_shard(backend: spill.SpillBackend, run: str, seq: int, rank: int,
         raise
     except OSError as exc:
         raise spill.SpillFailure(
-            f"shard write of {filename} failed: {exc}") from exc
+            f"shard write of {shard_filename(run, seq, rank)} failed: "
+            f"{exc}") from exc
     axes = dict(axes_items)
     coords = rank_coords(rank, axes_items)
     paths = sorted(flat)
+    on_device = {p for p in paths if hasattr(flat[p], "copy_to_host_async")}
     with tracing.child_span("ckpt::prefetch") as span:
-        started = 0
+        # Device arrays only: numpy and scalar leaves are on the host.
         for path in paths:
-            # Device arrays only: numpy and scalar leaves are on the host.
-            if hasattr(flat[path], "copy_to_host_async"):
+            if path in on_device:
                 flat[path].copy_to_host_async()
-                started += 1
         if span is not None:
-            span.attributes.update(leaves=started)
-    blocks: Dict[str, Dict[str, Any]] = {}
-    offset = 0
+            span.attributes.update(leaves=len(on_device))
+    blocks: Dict[str, np.ndarray] = {}
+    for path in paths:
+        with tracing.child_span("ckpt::gather") as span:
+            a = np.asarray(flat[path])
+            block = _local_block(a, specs.get(path), axes, coords)
+            if detach and path not in on_device:
+                block = np.array(block, order="K")
+            blocks[path] = block
+            if span is not None:
+                span.attributes.update(leaf=path, bytes=a.nbytes)
+    return blocks
+
+
+def write_gathered(backend: spill.SpillBackend, run: str, seq: int,
+                   rank: int, blocks: Dict[str, np.ndarray]
+                   ) -> Dict[str, Any]:
+    """The second half of a rank's crash-safe shard write: host memory to
+    the file, with nothing of the state in hand but :func:`gather_shard`'s
+    blocks, so it runs as well on a thread beside the train loop. The
+    shard file is the pure concatenation of C-order blocks (one per leaf,
+    sorted by path); all metadata — offsets, shapes, checksums — rides the
+    returned record into the manifest, so a byte-range reader never parses
+    the file.
+
+    Each byte is touched twice, by its block's CRC and by the ``write``,
+    both on the array's own memory; the file's CRC is combined from the
+    blocks'. A block is copied only where it is not C-contiguous as it
+    arrived (:func:`_c_order_copy`). The writer waits for the disk every
+    ``_SYNC_BYTES`` it has written (``SpillWriter.sync``), so the unflushed
+    part of the file stays bounded, and the file is fsynced and renamed
+    before this returns (``SpillBackend.open_writer``); an ``OSError``
+    anywhere leaves no file and raises :class:`spill.SpillFailure`.
+
+    Spans, recorded under ``train::report_sharded`` only, one after the
+    other on this thread, per leaf: ``ckpt::copy`` (only where bytes are
+    copied; ``what`` is ``slice`` or ``relayout``), ``ckpt::checksum``,
+    ``ckpt::write`` (and one with ``what`` = ``sync`` where the writer
+    waits for the disk); a last ``ckpt::write`` for fsync and rename.
+    """
+    filename = shard_filename(run, seq, rank)
+    meta: Dict[str, Dict[str, Any]] = {}
+    offset = synced = 0
     file_crc = 0
-    write_s = 0.0  # seconds in file writes, fsync and rename
+    write_s = 0.0  # seconds in file writes, syncs, fsync and rename
 
     def timed(op, *args, **attributes):
         nonlocal write_s
@@ -361,12 +400,8 @@ def write_shard(backend: spill.SpillBackend, run: str, seq: int, rank: int,
         return result
 
     with backend.open_writer(filename) as writer:
-        for path in paths:
-            with tracing.child_span("ckpt::gather") as span:
-                a = np.asarray(flat[path])
-                block = _local_block(a, specs.get(path), axes, coords)
-                if span is not None:
-                    span.attributes.update(leaf=path, bytes=a.nbytes)
+        for path in sorted(blocks):
+            block = blocks[path]
             if not block.flags.c_contiguous:
                 with tracing.child_span("ckpt::copy") as span:
                     block, what = _c_order_copy(block)
@@ -378,7 +413,7 @@ def write_shard(backend: spill.SpillBackend, run: str, seq: int, rank: int,
                 raw = block.reshape(-1).view(np.uint8)
                 block_crc = zlib.crc32(raw) & 0xFFFFFFFF
                 file_crc = crc32_combine(file_crc, block_crc, raw.nbytes)
-                blocks[path] = {
+                meta[path] = {
                     "offset": offset,
                     "length": raw.nbytes,
                     "crc32": block_crc,
@@ -389,6 +424,9 @@ def write_shard(backend: spill.SpillBackend, run: str, seq: int, rank: int,
                     span.attributes.update(leaf=path, bytes=raw.nbytes)
             timed(writer.write, raw, leaf=path, bytes=raw.nbytes)
             offset += raw.nbytes
+            if offset - synced >= _SYNC_BYTES:
+                timed(writer.sync, what="sync", bytes=offset - synced)
+                synced = offset
         uri = timed(writer.commit, what="commit", bytes=offset,
                     seq=int(seq), rank=int(rank))
     try:
@@ -399,8 +437,22 @@ def write_shard(backend: spill.SpillBackend, run: str, seq: int, rank: int,
         pass
     return {"seq": int(seq), "rank": int(rank), "file": filename,
             "uri": uri, "bytes": offset,
-            "crc32": file_crc, "blocks": blocks,
+            "crc32": file_crc, "blocks": meta,
             "write_s": round(write_s, 6)}
+
+
+def write_shard(backend: spill.SpillBackend, run: str, seq: int, rank: int,
+                flat: Dict[str, Any], specs: Dict[str, Any],
+                axes_items: AxesItems) -> Dict[str, Any]:
+    """One rank's crash-safe shard write, both halves on the caller's
+    thread: :func:`gather_shard` (the state leaves the device), then
+    :func:`write_gathered` (checksum, write, fsync, rename). On return the
+    shard file exists under its final name; it belongs to a checkpoint
+    only once a manifest that names it is committed. ``report_sharded``
+    runs the first half in the train loop and the second beside it."""
+    return write_gathered(
+        backend, run, seq, rank,
+        gather_shard(run, seq, rank, flat, specs, axes_items))
 
 
 def build_tree_meta(flat: Dict[str, Any], structure: Dict[str, Any],

@@ -128,17 +128,22 @@ class TrainWorker:
                 try:
                     if launched is not None:
                         _observe_loop_start(launched, self.world_rank)
-                    result = train_fn(config) if _wants_config(train_fn) \
-                        else train_fn()
-                    sess.result_queue.put(
-                        {"finished": True, "result": result})
+                    try:
+                        result = train_fn(config) \
+                            if _wants_config(train_fn) else train_fn()
+                    finally:
+                        # However the function ended, a save in flight is
+                        # written and acked before the rank says it is
+                        # done: fit() returns with it committed or failed.
+                        sess.wait_for_writer()
+                    item = {"finished": True, "result": result}
                 except StopSession:
-                    sess.result_queue.put({"finished": True, "stopped": True})
+                    item = {"finished": True, "stopped": True}
                 except BaseException as e:  # noqa: BLE001
                     import traceback
-                    sess.result_queue.put({
-                        "finished": True, "error": e,
-                        "traceback": traceback.format_exc()})
+                    item = {"finished": True, "error": e,
+                            "traceback": traceback.format_exc()}
+                sess.result_queue.put(item)
             finally:
                 air_session._set_session(None)
 
@@ -150,14 +155,17 @@ class TrainWorker:
         """Blocks until the worker reports or finishes, then lets it
         continue. timeout=None blocks indefinitely (a dead train thread
         always pushes a finished sentinel, so this cannot hang silently);
-        pass a float to surface report gaps as {'timeout': True}."""
+        pass a float to surface report gaps as {'timeout': True}. A
+        sharded save's ack (``{"ack": shard record, "metrics": ...}``,
+        from the rank's writer thread) is an item of its own: nobody
+        waits in ``report`` for it, so it lets nobody continue."""
         self._chaos_gate("train.result_delay_ms")
         import queue as _q
         try:
             item = self.session.result_queue.get(timeout=timeout)
         except _q.Empty:
             return {"timeout": True}
-        if not item.get("finished"):
+        if not item.get("finished") and "ack" not in item:
             self.session.continue_event.set()
         return item
 

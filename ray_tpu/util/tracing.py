@@ -401,6 +401,33 @@ def child_span(name: str, attributes: Optional[Dict[str, Any]] = None):
                       parent_id=parent.span_id, attributes=attributes)
 
 
+class _AdoptedScope:
+    """Another thread's span as this thread's active context."""
+
+    __slots__ = ("_span", "_prev")
+
+    def __init__(self, span: Span):
+        self._span = span
+
+    def __enter__(self) -> Span:
+        self._prev = getattr(_state, "span", None)
+        _state.span = self._span
+        return self._span
+
+    def __exit__(self, *exc) -> bool:
+        _state.span = self._prev
+        return False
+
+
+def adopt_span(span: Optional[Span]):
+    """Work handed from one thread to another stays under the span that
+    began it: inside the ``with`` block ``span`` (open or ended, recorded
+    on any thread) is this thread's active span, so ``child_span`` sites
+    record as its direct children, each on the thread it ran on. With None
+    (nothing recorded where the work began) nothing records here."""
+    return _NO_SPAN if span is None else _AdoptedScope(span)
+
+
 def drain_finished_spans(cursor: int = 0) -> tuple:
     """Ended, not-yet-shipped spans at or after ``cursor``, as plain
     dicts, plus the new cursor (the metrics agent's incremental export:

@@ -12,8 +12,11 @@ manifest + all shards, the mock-s3 backend, and the new config knobs.
 """
 
 import os
+import queue
 import random
 import sys
+import threading
+import time
 import zlib
 
 import cloudpickle
@@ -37,6 +40,7 @@ from ray_tpu.train._internal.backend_executor import (  # noqa: E402
 from ray_tpu.train._internal.checkpoint_manager import (  # noqa: E402
     CheckpointManager)
 from ray_tpu.train.backend import BackendConfig  # noqa: E402
+from ray_tpu.util import tracing  # noqa: E402
 
 
 def _counter_total(counter, tag_substr=None):
@@ -516,28 +520,54 @@ def _write_failures():
 
 
 def _shard_session(tmp_path, run="fail"):
-    """A rank-0 session as the BackendExecutor hands it to a worker, whose
-    report returns at once (nobody drains the queue here)."""
+    """A rank-0 session as the BackendExecutor hands it to a worker, with
+    the driver's side of its queue played as ``get_next_result`` plays it:
+    every item is taken (into ``s.taken``) and a report's sender let go."""
     s = session._Session(ckpt_ctx={
         "run": run, "storage_uri": "file://" + str(tmp_path),
         "seq_base": 1})
-    s.continue_event.set()
+    s.taken = queue.Queue()
+
+    def take():
+        while True:
+            item = s.result_queue.get()
+            s.taken.put(item)
+            if "ack" not in item:
+                s.continue_event.set()
+
+    threading.Thread(target=take, daemon=True).start()
     return s
 
 
+def _report_then_ack(s):
+    """The two items of one save, once its writer is done."""
+    s.wait_for_writer()
+    items = [s.taken.get(timeout=10), s.taken.get(timeout=10)]
+    [report] = [i for i in items if "ack" not in i]
+    [ack] = [i for i in items if "ack" in i]
+    assert set(report) == {"metrics", "checkpoint"}
+    assert ack["metrics"] == report["metrics"]
+    return report, ack["ack"]
+
+
 @pytest.mark.parametrize("fault", ["chaos_after_2_leaves", "chaos_at_open",
-                                   "fsync_oserror", "write_oserror"])
+                                   "fsync_oserror", "write_oserror",
+                                   "sync_oserror"])
 def test_failed_stream_leaves_nothing_and_reports_error(tmp_path,
                                                         monkeypatch, fault):
     """An ``OSError``, real or injected at ``spill.write_error``, at the
-    open, after k leaves or at the fsync: no ``.tmp``, no shard, one write
-    failure counted, and the rank reports ``{"error": ...}``."""
+    open, after k leaves, at a sync or at the fsync: no ``.tmp``, no
+    shard, one write failure counted, and the rank's writer acks
+    ``{"error": ...}``."""
     state = {f"w{i}": np.full((8, 4), float(i), np.float32)
              for i in range(5)}
-    if fault == "fsync_oserror":
+    if fault in ("fsync_oserror", "sync_oserror"):
         def refuse(fd):
             raise OSError(28, "No space left on device")
-        monkeypatch.setattr(os, "fsync", refuse)
+        if fault == "sync_oserror":  # the wait for the disk, mid-file
+            monkeypatch.setattr(sc, "_SYNC_BYTES", 2 * 8 * 4 * 4)
+        monkeypatch.setattr(
+            os, "fsync" if fault == "fsync_oserror" else "fdatasync", refuse)
     elif fault == "write_oserror":
         real_open = open
 
@@ -566,10 +596,11 @@ def test_failed_stream_leaves_nothing_and_reports_error(tmp_path,
     s = _shard_session(tmp_path)
     try:
         s.report_sharded({"step": 1}, state)
+        report, shard = _report_then_ack(s)
     finally:
         chaos.reset()
         monkeypatch.undo()
-    shard = s.result_queue.get_nowait()["shard"]
+    assert report["metrics"] == {"step": 1}
     assert shard["seq"] == 1 and shard["rank"] == 0
     assert "spill write of train-fail-ckpt-000001.shard-0000 failed" in \
         shard["error"]
@@ -577,9 +608,8 @@ def test_failed_stream_leaves_nothing_and_reports_error(tmp_path,
     assert os.listdir(tmp_path) == []
     assert _write_failures() == before + 1
     # The next save of the same session goes through.
-    s.continue_event.set()
     s.report_sharded({"step": 2}, state)
-    shard = s.result_queue.get_nowait()["shard"]
+    _, shard = _report_then_ack(s)
     assert "error" not in shard and shard["seq"] == 2
     assert os.listdir(tmp_path) == [shard["file"]]
 
@@ -621,18 +651,23 @@ def test_chaos_kill_leaves_the_shard_unwritten(tmp_path):
             s.report_sharded({"step": 2}, _state_at(2))
     finally:
         chaos.reset()
-    assert played_dead == [True] and s.result_queue.empty()
+    assert played_dead == [True] and s.taken.empty()
     assert sorted(os.listdir(tmp_path)) == names
     latest = CheckpointManager(str(tmp_path), "fail").latest()
     assert latest.seq == 1 and latest.extra == {"step": 1}
 
 
-def test_a_reader_never_sees_a_partial_shard(tmp_path):
-    """While the leaves stream, the bytes are under ``.tmp`` only: the
-    final name appears with the rename, after the fsync."""
+def test_a_reader_never_sees_a_partial_shard(tmp_path, monkeypatch):
+    """While the leaves are written, the bytes are under ``.tmp`` only: the
+    final name appears with the rename, after the fsync. Nothing is there
+    at all while the leaves are gathered."""
     backend = spill.FileSpillBackend(str(tmp_path))
     name = sc.shard_filename("part", 1, 0)
     seen = []
+
+    def look():
+        seen.append((backend.list_files(), sorted(os.listdir(tmp_path)),
+                     backend.size_of(backend.uri_for(name))))
 
     class Watching:
         """A leaf that looks at the storage when its turn comes."""
@@ -641,15 +676,59 @@ def test_a_reader_never_sees_a_partial_shard(tmp_path):
             self.value = value
 
         def __array__(self, dtype=None, copy=None):
-            seen.append((backend.list_files(), sorted(os.listdir(tmp_path)),
-                         backend.size_of(backend.uri_for(name))))
+            look()
             return self.value
 
+    write = spill.SpillWriter.write
+
+    def watched_write(self, part):
+        look()
+        write(self, part)
+
+    monkeypatch.setattr(spill.SpillWriter, "write", watched_write)
     flat = {f"w{i}": Watching(np.full((16,), float(i))) for i in range(3)}
     record = sc.write_shard(backend, "part", 1, 0, flat, {}, [("fsdp", 1)])
-    assert seen == [([], [name + ".tmp"], None)] * 3
+    assert seen == [([], [], None)] * 3 + [([], [name + ".tmp"], None)] * 3
     assert backend.list_files() == [name]
     assert backend.size_of(record["uri"]) == record["bytes"] == 3 * 16 * 8
+
+
+def test_the_writer_waits_for_the_disk_as_it_goes(tmp_path, monkeypatch):
+    """Every ``_SYNC_BYTES`` written the writer waits until they are on
+    the disk, so the unflushed part of a shard stays bounded; the file is
+    the same bytes, and the waits are ``ckpt::write`` spans of their own."""
+    state = {f"w{i}": np.full((8, 4), float(i), np.float32)
+             for i in range(7)}  # 128 bytes a leaf
+    flat, _ = sc.flatten_tree(state)
+    backend = spill.FileSpillBackend(str(tmp_path))
+    name = sc.shard_filename("sync", 1, 0)
+    on_disk = []
+    fdatasync = os.fdatasync
+
+    def watched(fd):
+        fdatasync(fd)
+        on_disk.append(os.stat(str(tmp_path / (name + ".tmp"))).st_size)
+
+    monkeypatch.setattr(os, "fdatasync", watched)
+    monkeypatch.setattr(sc, "_SYNC_BYTES", 300)
+    tracing.clear_spans()
+    tracing.enable_tracing()
+    try:
+        with tracing.start_span("train::report_sharded"):
+            record = sc.write_shard(backend, "sync", 1, 0, flat, {},
+                                    [("fsdp", 1)])
+    finally:
+        tracing.disable_tracing()
+    writes = [s.attributes for s in tracing.get_spans()
+              if s.name == "ckpt::write"]
+    tracing.clear_spans()
+    assert on_disk == [384, 768]  # after the third leaf and the sixth
+    assert [w for w in writes if "what" in w] == [
+        {"what": "sync", "bytes": 384}, {"what": "sync", "bytes": 384},
+        {"what": "commit", "bytes": 896, "seq": 1, "rank": 0}]
+    want_bytes, want = _tobytes_shard(flat, {}, [("fsdp", 1)], 0)
+    assert backend.read(record["uri"]) == want_bytes
+    assert record["crc32"] == want["crc32"]
 
 
 def test_config_knobs_present():
@@ -877,6 +956,288 @@ def test_elastic_shrink_reshard_acceptance(ray_start_regular, monkeypatch,
     assert _trees_equal(ck.load_full(), _state_at(4))
     assert _counter_total(builtin_metrics.train_reshards(), "shrink") >= \
         shrink_before + 1
+
+
+# ---------------------------------------------------------------------------
+# The save behind the loop: report_sharded returns when the state is on the
+# host; checksum, write, fsync and ack follow on the rank's writer thread
+# ---------------------------------------------------------------------------
+
+
+def _hold_writer(monkeypatch, held=lambda seq, rank: True):
+    """Every save's second half for which ``held(seq, rank)`` waits, on
+    its writer thread, for the event this returns."""
+    gate = threading.Event()
+    write = sc.write_gathered
+
+    def gated(backend, run, seq, rank, blocks):
+        if held(seq, rank):
+            assert gate.wait(30), "nobody let the writer go"
+        return write(backend, run, seq, rank, blocks)
+
+    monkeypatch.setattr(sc, "write_gathered", gated)
+    return gate
+
+
+def _saves_observed():
+    return sum(builtin_metrics.train_ckpt_save_seconds()._counts.values())
+
+
+def _wait_until(what, seconds=20.0):
+    deadline = time.monotonic() + seconds
+    while not what():
+        assert time.monotonic() < deadline, "waited in vain"
+        time.sleep(0.005)
+
+
+def test_report_sharded_returns_before_the_shard_exists(tmp_path,
+                                                        monkeypatch):
+    gate = _hold_writer(monkeypatch)
+    s = _shard_session(tmp_path)
+    s.report_sharded({"step": 1}, _state_at(1))
+    # Back in the loop: the report is with the driver, nothing is on
+    # storage and nothing is acked.
+    assert s.taken.get(timeout=10) == {"metrics": {"step": 1},
+                                       "checkpoint": None}
+    assert os.listdir(tmp_path) == [] and s.taken.empty()
+    gate.set()
+    s.wait_for_writer()
+    ack = s.taken.get(timeout=10)
+    assert ack["metrics"] == {"step": 1} and "checkpoint" not in ack
+    assert os.listdir(tmp_path) == [ack["ack"]["file"]]
+    assert ack["ack"]["tree_meta"]["extra"] == {}
+    s.wait_for_writer()  # nothing in flight: returns at once
+
+
+@pytest.mark.parametrize("after", ["overwritten", "deleted"])
+def test_state_changed_after_the_return_restores_the_saved_bytes(
+        tmp_path, monkeypatch, after):
+    """From the return on the state is the caller's again: what reaches
+    the file is what it held at the call, host leaves and device leaves
+    alike."""
+    import jax.numpy as jnp
+    gate = _hold_writer(monkeypatch)
+    want = _state_at(3)
+    state = _state_at(3)
+    state["dev"] = jnp.arange(24, dtype=jnp.bfloat16).reshape(6, 4)
+    want["dev"] = np.array(state["dev"])
+    s = _shard_session(tmp_path, run="mine")
+    s.report_sharded({"step": 3}, state, extra={"step": 3})
+    if after == "overwritten":
+        state["w"][...] = -1.0
+        state["b"] *= 0
+        state["opt"][0][...] = 7.0
+    else:
+        state["dev"].delete()
+        del state
+    gate.set()
+    _, shard = _report_then_ack(s)
+    manifest = sc.build_manifest("mine", 1, shard["tree_meta"], [shard])
+    uri = sc.write_manifest(spill.FileSpillBackend(str(tmp_path)), "mine",
+                            1, manifest)
+    restored = ShardedCheckpoint.from_manifest_uri(uri)
+    assert restored.extra == {"step": 3}
+    assert _trees_equal(restored.load_full(), want)
+
+
+def test_a_second_save_waits_for_the_first_and_records_the_wait(
+        tmp_path, monkeypatch):
+    """One save in flight a rank: the next waits, inside its own stall
+    and under a span of its own, until the first is written and acked."""
+    gate = _hold_writer(monkeypatch, held=lambda seq, rank: seq == 1)
+    s = _shard_session(tmp_path)
+    tracing.clear_spans()
+    tracing.enable_tracing()
+    try:
+        s.report_sharded({"step": 1}, _state_at(1))
+        second = threading.Thread(
+            target=s.report_sharded, args=({"step": 2}, _state_at(2)))
+        t0 = time.perf_counter()
+        second.start()
+        second.join(0.2)
+        assert second.is_alive() and os.listdir(tmp_path) == []
+        held_s = time.perf_counter() - t0
+        gate.set()
+        second.join(30)
+        assert not second.is_alive()
+        s.wait_for_writer()
+    finally:
+        tracing.disable_tracing()
+    spans = tracing.get_spans()
+    tracing.clear_spans()
+    acks = []
+    while len(acks) < 2:
+        item = s.taken.get(timeout=10)
+        acks += [item["ack"]] if "ack" in item else []
+    assert [a["seq"] for a in acks] == [1, 2]
+    assert not any("error" in a for a in acks)
+    saves = {sp.attributes["seq"]: sp for sp in spans
+             if sp.name == "train::report_sharded"}
+    waits = {seq: [sp for sp in spans if sp.name == "ckpt::drain_wait"
+                   and sp.parent_id == save.span_id]
+             for seq, save in saves.items()}
+    assert [len(waits[1]), len(waits[2])] == [1, 1]
+    assert waits[1][0].duration < 0.05
+    assert held_s * 0.9 <= waits[2][0].duration <= saves[2].duration
+    # The first save's file was whole before the second's first byte.
+    first_done = max(sp.perf_start + sp.duration for sp in spans
+                     if sp.name == "ckpt::write"
+                     and sp.parent_id == saves[1].span_id)
+    assert first_done <= min(sp.perf_start for sp in spans
+                             if sp.name == "ckpt::gather"
+                             and sp.parent_id == saves[2].span_id)
+
+
+def test_the_commit_arrives_with_no_further_report(ray_start_regular,
+                                                   tmp_path):
+    """The ack does not ride the loop's next report: the driver commits
+    while the train function sits between two reports."""
+    seen = {}
+
+    def loop():
+        before = _saves_observed()
+        session.report_sharded({"step": 0}, _state_at(1),
+                               extra={"step": 1})
+        _wait_until(lambda: _saves_observed() > before)
+        seen["manifests"] = [n for n in os.listdir(tmp_path)
+                             if n.endswith(".manifest")]
+        session.report({"step": 1})
+
+    result = DataParallelTrainer(
+        loop, scaling_config=ScalingConfig(num_workers=1),
+        run_config=RunConfig(name="no-report",
+                             storage_path=str(tmp_path))).fit()
+    assert seen["manifests"] == [sc.manifest_filename("no-report", 1)]
+    assert [m["step"] for m in result.metrics_history] == [0, 1]
+    assert result.checkpoint.extra == {"step": 1}
+
+
+def test_a_function_that_returns_with_a_save_in_flight_yields_it(
+        ray_start_regular, tmp_path, monkeypatch):
+    """``fit()`` waits for the last save: the writer is held until the
+    train function has returned and the rank waits for it, and the
+    ``Result`` names that save, committed."""
+    gate = _hold_writer(monkeypatch)
+    order = []
+    wait = session._Session.wait_for_writer
+
+    def waited(self):
+        if order:  # not the save's own wait, for a save before it
+            order.append(("waits", sorted(os.listdir(tmp_path))))
+            gate.set()
+        wait(self)
+
+    monkeypatch.setattr(session._Session, "wait_for_writer", waited)
+
+    def loop():
+        session.report_sharded({"step": 0}, _state_at(1),
+                               extra={"step": 1})
+        order.append(("returns", sorted(os.listdir(tmp_path))))
+
+    result = DataParallelTrainer(
+        loop, scaling_config=ScalingConfig(num_workers=1),
+        run_config=RunConfig(name="last",
+                             storage_path=str(tmp_path))).fit()
+    assert order == [("returns", []), ("waits", [])]
+    assert isinstance(result.checkpoint, ShardedCheckpoint)
+    assert result.checkpoint.extra == {"step": 1}
+    assert _trees_equal(result.checkpoint.load_full(), _state_at(1))
+    assert len(result.metrics_history) == 1
+
+
+def test_a_write_error_behind_the_loop_fails_that_save_alone(
+        ray_start_regular, tmp_path):
+    """``spill.write_error`` fires on the writer thread, mid-file: an
+    ``{"error": ...}`` ack, one persist failure, no seq-2 manifest, the
+    first checkpoint still the newest, and the next save clean."""
+    failures = builtin_metrics.train_checkpoint_persist_failures()
+    seen = {}
+
+    def loop():
+        saves, failed = _saves_observed(), _counter_total(failures)
+        session.report_sharded({"step": 0}, _state_at(1),
+                               extra={"step": 1})
+        _wait_until(lambda: _saves_observed() > saves)
+        # The site is evaluated at the open, then once a part.
+        chaos.configure(
+            "io_oserror:site=spill.write_error:after=2:times=1")
+        session.report_sharded({"step": 1}, _state_at(2),
+                               extra={"step": 2})
+        _wait_until(lambda: _counter_total(failures) > failed)
+        seen["fired"] = any(op["fired"] for op in chaos.stats())
+        chaos.reset()
+        seen["failed"] = _counter_total(failures) - failed
+        seen["newest"] = CheckpointManager(
+            str(tmp_path), "behind").latest().extra
+        seen["files"] = sorted(os.listdir(tmp_path))
+        session.report_sharded({"step": 2}, _state_at(3),
+                               extra={"step": 3})
+
+    try:
+        result = DataParallelTrainer(
+            loop, scaling_config=ScalingConfig(num_workers=1),
+            run_config=RunConfig(name="behind",
+                                 storage_path=str(tmp_path))).fit()
+    finally:
+        chaos.reset()
+    assert seen["fired"] and seen["failed"] == 1
+    assert seen["newest"] == {"step": 1}
+    assert not any("000002" in n or n.endswith(".tmp")
+                   for n in seen["files"]), seen["files"]
+    assert [m["step"] for m in result.metrics_history] == [0, 1, 2]
+    assert result.checkpoint.extra == {"step": 3}
+    assert _trees_equal(result.checkpoint.load_full(), _state_at(3))
+
+
+def test_acks_of_one_seq_in_different_rounds_commit_once(
+        ray_start_regular, tmp_path, monkeypatch):
+    """World 2, rank 1's writer held: rank 0's ack is taken rounds before
+    rank 1's, both ranks report on meanwhile, and the save commits once,
+    when the second ack is in, with the metrics of the report that began
+    it."""
+    gate = _hold_writer(monkeypatch,
+                        held=lambda seq, rank: (seq, rank) == (1, 1))
+    commits = []
+    commit = BackendExecutor._commit_sharded
+
+    def counted(self, shard_acks, world, metrics):
+        handle = commit(self, shard_acks, world, metrics)
+        commits.append((sorted(shard_acks), metrics, handle,
+                        gate.is_set()))
+        return handle
+
+    monkeypatch.setattr(BackendExecutor, "_commit_sharded", counted)
+
+    def loop():
+        rank = session.get_world_rank()
+        session.report_sharded({"step": 0}, _state_at(1),
+                               extra={"step": 1})
+        if rank == 0:
+            # Rank 0's ack is queued, ahead of its next report ...
+            session._get_session().wait_for_writer()
+        session.report({"step": 1})
+        # ... so it was taken in the round of that report, and the round
+        # of this one has begun before rank 1's writer may go.
+        session.report({"step": 2})
+        if rank == 0:
+            assert not commits
+            gate.set()
+        session.report({"step": 3})
+
+    result = DataParallelTrainer(
+        loop, scaling_config=ScalingConfig(num_workers=2),
+        run_config=RunConfig(name="rounds",
+                             storage_path=str(tmp_path))).fit()
+    assert len(commits) == 1
+    ranks, metrics, handle, gate_was_open = commits[0]
+    assert ranks == [0, 1] and metrics == {"step": 0} and gate_was_open
+    assert handle is not None and handle.extra == {"step": 1}
+    assert [m["step"] for m in result.metrics_history] == [0, 1, 2, 3]
+    assert result.checkpoint.extra == {"step": 1}
+    assert result.checkpoint.world_size == 2
+    assert _trees_equal(result.checkpoint.load_full(), _state_at(1))
+    assert [n for n in os.listdir(tmp_path) if n.endswith(".manifest")] \
+        == [sc.manifest_filename("rounds", 1)]
 
 
 def test_reshard_on_restart_disabled_refuses(ray_start_regular, tmp_path):

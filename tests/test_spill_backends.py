@@ -213,6 +213,38 @@ def test_writer_chaos_error_at_any_point_leaves_nothing(tmp_path, after):
         assert backend.read(writer.commit()) == b"again"
 
 
+def test_writer_sync_puts_the_parts_so_far_on_the_disk(tmp_path,
+                                                      monkeypatch):
+    """``sync`` is a wait, not a commit: the bytes are under ``.tmp``, the
+    final name is still absent, and an ``OSError`` in it is a spill failure
+    like any other."""
+    backend = FileSpillBackend(str(tmp_path))
+    synced = []
+    fdatasync = os.fdatasync
+    monkeypatch.setattr(os, "fdatasync",
+                        lambda fd: synced.append(os.fstat(fd).st_size)
+                        or fdatasync(fd))
+    with backend.open_writer("big.bin") as writer:
+        writer.write(b"abc")
+        writer.sync()
+        assert synced == [3] and backend.list_files() == []
+        assert os.listdir(tmp_path) == ["big.bin.tmp"]
+        writer.write(b"defg")
+        assert backend.read(writer.commit()) == b"abcdefg"
+
+    def refuse(fd):
+        raise OSError(5, "Input/output error")
+
+    monkeypatch.setattr(os, "fdatasync", refuse)
+    before = _write_failures()
+    with pytest.raises(SpillFailure, match="spill write of bad.bin failed"):
+        with backend.open_writer("bad.bin") as writer:
+            writer.write(b"x")
+            writer.sync()
+    assert _write_failures() == before + 1
+    assert os.listdir(tmp_path) == ["big.bin"]
+
+
 def test_writer_left_without_commit_aborts(tmp_path):
     """Another exception than an ``OSError`` (or no commit at all) is not a
     spill failure, but the ``.tmp`` goes all the same."""

@@ -20,6 +20,7 @@ from ray_tpu.train import JaxTrainer
 from ray_tpu.util import tracing
 
 LOOP = "train-rank-0"
+WRITER = "ckpt-writer-0"  # checksum, write, fsync: beside the loop
 LEAF_ELEMS = 2 * 1024 * 1024  # 8 MB of float32 a leaf: phases, not overhead
 HEAD_SHAPE = (1024, 2048)  # 8 MB too, in the transposed memory order
 
@@ -174,12 +175,13 @@ def traced_run(tmp_path_factory):
 TABLE = [
     ("train::report_wait", "train::report", LOOP),
     ("train::report_sharded", None, LOOP),
+    ("ckpt::drain_wait", "train::report_sharded", LOOP),
     ("ckpt::meta", "train::report_sharded", LOOP),
     ("ckpt::prefetch", "train::report_sharded", LOOP),
     ("ckpt::gather", "train::report_sharded", LOOP),
-    ("ckpt::copy", "train::report_sharded", LOOP),
-    ("ckpt::checksum", "train::report_sharded", LOOP),
-    ("ckpt::write", "train::report_sharded", LOOP),
+    ("ckpt::copy", "train::report_sharded", WRITER),
+    ("ckpt::checksum", "train::report_sharded", WRITER),
+    ("ckpt::write", "train::report_sharded", WRITER),
     ("ckpt::commit", None, "driver"),
     ("ckpt::prune", "ckpt::commit", "driver"),
     ("ckpt::restore", None, "driver"),
@@ -209,51 +211,80 @@ def test_report_is_a_root_and_a_save_s_ack(traced_run):
     assert names.count("train::report_sharded") == 2
 
 
-def _children(traced_run, save):
+def _children(traced_run, save, thread=None):
     return sorted((s for s in traced_run["spans"]
-                   if s.parent_id == save.span_id),
+                   if s.parent_id == save.span_id
+                   and thread in (None, s.thread)),
                   key=lambda s: s.perf_start)
 
 
-def test_the_phases_cover_the_save(traced_run):
+# The save's direct children by the thread they ran on: how many of each
+# name; the loop's lie inside the save's span (the stall), the writer's
+# begin where it hands over and end after it.
+PHASES = {
+    LOOP: {"ckpt::drain_wait": 1, "ckpt::meta": 2, "ckpt::prefetch": 1,
+           "ckpt::gather": 4, "train::report": 1},
+    WRITER: {"ckpt::copy": 1, "ckpt::checksum": 4, "ckpt::write": 5},
+}
+
+
+@pytest.mark.parametrize("thread", [LOOP, WRITER])
+def test_the_phases_cover_the_save(traced_run, thread):
     saves = [s for s in traced_run["spans"]
              if s.name == "train::report_sharded"]
     assert [s.attributes["seq"] for s in saves] == [1, 2]
     for save in saves:
-        children = _children(traced_run, save)
-        covered = sum(s.duration for s in children)
-        assert 0.95 * save.duration <= covered <= save.duration
-        # One after the other on the loop's thread: the save's self time
-        # is a true remainder only if no two phases overlap.
+        children = _children(traced_run, save, thread)
+        assert {n: sum(s.name == n for s in children)
+                for n in PHASES[thread]} == PHASES[thread]
+        assert len(children) == sum(PHASES[thread].values())
+        # One after the other on their thread: a sum of them is a time.
         for a, b in zip(children, children[1:]):
             assert a.perf_start + a.duration <= b.perf_start
-        # Per leaf: the wait for its transfer, one checksum, one write;
-        # a copy for the one leaf that is not C-contiguous; one prefetch;
-        # a last write for fsync and rename.
-        per_name = {n: sum(s.name == n for s in children)
-                    for n in ("ckpt::prefetch", "ckpt::gather", "ckpt::copy",
-                              "ckpt::checksum", "ckpt::write")}
-        assert per_name == {"ckpt::prefetch": 1, "ckpt::gather": 4,
-                            "ckpt::copy": 1, "ckpt::checksum": 4,
-                            "ckpt::write": 5}
-        nbytes = 3 * LEAF_ELEMS * 4 + 4
-        for name in ("ckpt::gather", "ckpt::checksum"):
-            assert sum(s.attributes["bytes"] for s in children
-                       if s.name == name) == nbytes  # each byte once
-        writes = [s for s in children if s.name == "ckpt::write"]
-        assert [s.attributes.get("what") for s in writes] == \
-            [None] * 4 + ["commit"]
-        assert sum(s.attributes["bytes"] for s in writes[:-1]) == nbytes
-        assert writes[-1].attributes["bytes"] == nbytes
-        assert [s.attributes["leaf"] for s in writes[:-1]] == [
-            "params/b", "params/head", "params/w", "step"]  # sorted paths
+        end = save.perf_start + save.duration
+        covered = sum(s.duration for s in children)
+        if thread == LOOP:
+            # The stall is its phases: what none of them covers is the
+            # writer thread's start (these saves are of milliseconds, and
+            # a new thread takes the interpreter for a few).
+            assert save.duration - 0.1 <= covered <= save.duration
+            assert children[-1].perf_start + children[-1].duration <= end
+        else:
+            # Handed over once the last leaf is on the host, ahead of the
+            # report; over after the stall is.
+            gathered = [s for s in _children(traced_run, save, LOOP)
+                        if s.name == "ckpt::gather"][-1]
+            assert gathered.perf_start + gathered.duration \
+                <= children[0].perf_start
+            assert children[-1].perf_start + children[-1].duration > end
+
+
+@pytest.mark.parametrize("name", ["ckpt::gather", "ckpt::checksum",
+                                  "ckpt::write"])
+def test_each_byte_passes_each_phase_once(traced_run, name):
+    """Per leaf: the wait for its transfer, one checksum, one write, in
+    sorted path order; a last write for fsync and rename."""
+    nbytes = 3 * LEAF_ELEMS * 4 + 4
+    for save in (s for s in traced_run["spans"]
+                 if s.name == "train::report_sharded"):
+        phase = [s for s in _children(traced_run, save) if s.name == name]
+        if name == "ckpt::write":
+            assert [s.attributes.get("what") for s in phase] == \
+                [None] * 4 + ["commit"]
+            assert phase[-1].attributes["bytes"] == nbytes
+            assert phase[-1].attributes["seq"] == save.attributes["seq"]
+            phase = phase[:-1]
+        assert sum(s.attributes["bytes"] for s in phase) == nbytes
+        assert [s.attributes["leaf"] for s in phase] == [
+            "params/b", "params/head", "params/w", "step"]
 
 
 def test_prefetch_comes_first_and_counts_the_device_leaves(traced_run):
     for save in (s for s in traced_run["spans"]
                  if s.name == "train::report_sharded"):
         names = [s.name for s in _children(traced_run, save)]
-        assert names[:3] == ["ckpt::meta", "ckpt::prefetch", "ckpt::gather"]
+        assert names[:4] == ["ckpt::drain_wait", "ckpt::meta",
+                             "ckpt::prefetch", "ckpt::gather"]
         [prefetch] = [s for s in _children(traced_run, save)
                       if s.name == "ckpt::prefetch"]
         # ``head`` is a numpy leaf: nothing to start for it.
@@ -845,19 +876,19 @@ def _waits_loop(config):
         step(x)
     session.report({"i": 0})
     step(x)
-    # A save that is surely longer than any stall's floor.
+    # A save whose stall is surely longer than any stall's floor.
     from ray_tpu.train._internal import sharded_checkpoint as sc
-    write = sc.write_shard
+    gather = sc.gather_shard
 
-    def slow_write(*args, **kwargs):
+    def slow_gather(*args, **kwargs):
         time.sleep(0.1)
-        return write(*args, **kwargs)
+        return gather(*args, **kwargs)
 
-    sc.write_shard = slow_write
+    sc.gather_shard = slow_gather
     try:
         session.report_sharded({"i": 1}, state, extra={"step": 1})
     finally:
-        sc.write_shard = write
+        sc.gather_shard = gather
     step(x)
     next(batches)
     step(x)
