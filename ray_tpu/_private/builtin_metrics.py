@@ -951,8 +951,9 @@ def train_dsa_index_loss() -> Gauge:
         "that own an indexer.")
 
 
-# -- EVA attention and several prediction heads ------------------------------
-# Fed as the expert layers' scalars are (models/evabyte.py).
+# -- EVA attention and several prediction heads; block-sparse and linear ------
+# attention. Fed as the expert layers' scalars are (models/evabyte.py,
+# models/minicpm_sala.py).
 
 
 def train_eva_pairs_share() -> Gauge:
@@ -973,6 +974,46 @@ def train_eva_summary_mass() -> Gauge:
         "in the last recorded step, over queries, heads and layers "
         "(ops/eva.py: the forward kernel keeps the summary tiles' part of "
         "its running sum): 0 where attention never looks past its window.")
+
+
+def train_sala_selected_share() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_sala_selected_share",
+        "(Query, key) pairs block-sparse attention attended over the causal "
+        "pairs in the last recorded step, counted from the selection itself "
+        "(ops/infllm.py selected_pairs_share), the mean over sparse layers: "
+        "0.4346 at 16384 tokens with the top 64 blocks of 64.")
+
+
+def train_sala_live_tile_share() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_sala_live_tile_share",
+        "Tile pairs of the sparse attention kernels' causal table in which "
+        "any query of any KV group selected a key, over the table, in the "
+        "last recorded step (ops/infllm.py live_tiles): what a kernel that "
+        "skipped empty tiles' steps and fetches could leave out is 1 - it.")
+
+
+def train_sala_free_mass() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_sala_free_mass",
+        "Mean share of a query's softmax sum that lay on blocks chosen by "
+        "score and not forced (the first block, the local window), over a "
+        "stride of query rows, every head and sparse layer of the last "
+        "recorded step (ops/infllm.py free_mass): 0 would say the selection "
+        "does nothing.")
+
+
+def train_lightning_decay_floor() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_lightning_decay_floor",
+        "The least lambda^chunk of the linear-attention layers of the last "
+        "recorded step: what the steepest head keeps of its state over one "
+        "chunk of ops/lightning.py's kernels.")
 
 
 def train_mbp_loss() -> Gauge:

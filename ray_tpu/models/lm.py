@@ -13,7 +13,8 @@ second family needs of a first moves here.
 
 **A family** (``models/deepseek.py``, ``granite.py``, ``afmoe.py``,
 ``kimi_linear.py``, ``lfm2.py``, ``phi4flash.py``, ``glm_moe_dsa.py``,
-``evabyte.py``) is three things, written against this module:
+``evabyte.py``, ``minicpm_sala.py``) is three things, written against this
+module:
 
 * its config, a frozen dataclass under the published keys, with
   ``vocab_size``, ``hidden_size``, the epsilon of its norms, the program's
@@ -372,6 +373,49 @@ def eva_attention(q, k, v, phi, mu, cfg, window: int, chunk: int):
             q, k, v, kc, vc)
 
 
+def block_sparse_attention(q, k, v, cfg, sizes):
+    """Attention over the blocks each query selects by the model's own
+    scores (``ops/infllm.py``; ``sizes`` its ``Sizes``): q [B, S, H, D], k, v
+    [B, S, G, D] with H a multiple of G -> (out [B, S, H, D], {
+    ``selected_share``, ``live_tile_share``, ``free_mass``}: what the
+    selection kept of the causal pairs, of the kernels' causal tiles, and
+    the share of a query's softmax sum on blocks chosen by score; no
+    gradient). ``dot`` or ``flash``. One device: the selection is a whole
+    sequence's and no mesh axis splits it yet."""
+    from ray_tpu.ops import infllm
+    from ray_tpu.parallel.mesh import current_mesh
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "block-sparse attention (ops/infllm.py) selects over a whole "
+            f"sequence on one device; the mesh has {mesh.size}")
+    if cfg.attn_impl not in ("dot", "flash"):
+        raise NotImplementedError(
+            f"attn_impl={cfg.attn_impl!r}: block-sparse attention runs as "
+            "'dot' or 'flash' (ops/infllm.py)")
+    S, block = q.shape[1], sizes.block
+    with jax.named_scope("sala_select"):
+        selection = infllm.select(
+            infllm.block_scores(q, infllm.compress(k, sizes), sizes), sizes)
+    with jax.named_scope("sala_attention"):
+        if cfg.attn_impl == "dot":
+            out, _ = infllm.dot_selected_attention(q, k, v, selection, block)
+            tiles = (infllm.whole_tile(S, block),) * 2
+        else:
+            out, _ = infllm.selected_attention(
+                q, k, v, selection, block, cfg.attn_blk_q, cfg.attn_blk_k,
+                None, infllm.keeps_forward(S, v.shape[-1], sizes))
+            tiles = infllm.tiles(q, k, block, cfg.attn_blk_q,
+                                 cfg.attn_blk_k)
+    with jax.named_scope("sala_gauges"):
+        aux = {"selected_share": infllm.selected_pairs_share(selection,
+                                                             block),
+               "live_tile_share": infllm.live_tile_share(selection, block,
+                                                         *tiles),
+               "free_mass": infllm.free_mass(q, k, selection, sizes)}
+    return out, aux
+
+
 # -- state-space scan -----------------------------------------------------
 
 def _over_batch_shards(fn, args, has_rows, out_rank: int = 4):
@@ -405,6 +449,19 @@ def state_space(u, dt, A, B, C, D, chunk: int):
     return _over_batch_shards(
         partial(ssd, chunk=chunk), (u, dt, A, B, C, D),
         (True, True, False, True, True, False))
+
+
+def linear_attention(q, k, v, slope):
+    """Linear attention with a fixed decay a head, ``S_t = exp(-slope_h)
+    S_(t-1) + k_t v_t^T``, ``o_t = q_t S_t / sqrt(K)``, by
+    ``ops/lightning.py``'s chunked kernels. q, k [B, S, H, K], v [B, S, H,
+    V], slope [H] positive (a constant of the layer: no gradient) -> [B, S,
+    H, V]. Under a mesh the kernels run per shard of the batch, as
+    ``state_space``'s do."""
+    from ray_tpu.ops.lightning import lightning
+    with jax.named_scope("lightning"):
+        return _over_batch_shards(lightning, (q, k, v, slope),
+                                  (True, True, True, False))
 
 
 def selective_scan(xs, delta, A, B, C, D):

@@ -145,8 +145,11 @@ def test_window_tile_census_matches_the_mask(S, window, blk_q, blk_k):
         assert pairs == sorted(set(pairs))
 
 
+# The 32768 case walks 64 x 64 tiles row by row in numpy (65 s): slow since
+# PR 51 (ROADMAP Queue 3 item 8); the 16384 case holds the same code.
 @pytest.mark.parametrize("S,executed,causal", [
-    (16384, 252, 528), (32768, 540, 2080)])
+    (16384, 252, 528),
+    pytest.param(32768, 540, 2080, marks=pytest.mark.slow)])
 def test_window_tile_census_of_the_cell(S, executed, causal):
     """A window of 4096 at tiles of 512 x 512: a Q tile from the ninth on
     sees 7 full tiles and 2 cut ones."""

@@ -15,7 +15,8 @@ moved ``init``'s loops into ``models/lm.py``), from that commit's tree,
 same way, the other five's lines byte for byte as they were; ``glm_moe_dsa``'s
 by PR 45, the other six's as they were (Moonlight's and Kimi Linear's latent
 layers draw ``wq`` where they did: ``lm.mla_leaves`` with a null rank);
-``evabyte``'s by PR 49, the other seven's as they were.
+``evabyte``'s by PR 49, the other seven's as they were; ``minicpm_sala``'s
+by PR 51, the other eight's as they were.
 
 A PR that changes a family's draw on purpose records them again and says so;
 one that does not must leave this file alone."""
@@ -30,7 +31,8 @@ import pytest
 FAMILIES = {"deepseek": "deepseek-tiny", "granite": "granite-tiny",
             "afmoe": "afmoe-tiny", "kimi_linear": "kimi-linear-tiny",
             "lfm2": "lfm2-tiny", "phi4flash": "phi4flash-tiny",
-            "glm_moe_dsa": "glm-tiny", "evabyte": "evabyte-tiny"}
+            "glm_moe_dsa": "glm-tiny", "evabyte": "evabyte-tiny",
+            "minicpm_sala": "minicpm-sala-tiny"}
 PINNED = pathlib.Path(__file__).with_name("init_pinned.json")
 
 
