@@ -89,8 +89,10 @@ def _zeroed(leaf: str):
 def faults():
     """name -> (attributes to swap as (module, name, plain -> planted), the
     config's fields to replace, the parameters' change or None)."""
+    import jax
     import jax.numpy as jnp
     from ray_tpu.models import kimi_linear, lm
+    from ray_tpu.ops import kda
 
     def eight_bit(plain):
         def block(cfg, kind, h, layer, positions):
@@ -108,10 +110,14 @@ def faults():
                        None),
         "decay": (rule(a=jnp.zeros_like), {}, None),
         "beta": (rule(beta=jnp.ones_like), {}, None),
-        "qk_norm": ([(kimi_linear, "_unit", lambda _: lambda y, scale=1.0: (
+        # The rule normalises inside (``ops/kda.py``: the chunk's function
+        # calls ``_unit_rows``), and the layer's short convolutions and
+        # their SiLU are one call, ``lm.conv_silu``: the SiLU stays.
+        "qk_norm": ([(kda, "_unit_rows", lambda _: lambda y, scale=1.0: (
             y.astype(jnp.float32) * scale).astype(y.dtype))], {}, None),
-        "conv": ([(lm, "causal_conv", lambda _: lambda x, w, b=None:
-                   x.astype(jnp.float32))], {}, None),
+        "conv": ([(lm, "conv_silu", lambda _: lambda x, w, b=None:
+                   jax.nn.silu(x.astype(jnp.float32)).astype(x.dtype))], {},
+                 None),
         "output_gate": ([], {}, _zeroed("w_gb")),
         "rope_on_mla": ([], {"mla_use_nope": False}, None),
         "routed_scaling_factor": ([], {"routed_scaling_factor": 1.0}, None),

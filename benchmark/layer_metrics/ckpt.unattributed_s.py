@@ -1,5 +1,7 @@
-"""Seconds of a save that no phase accounts for: ``train::report_sharded``
-less what its child spans cover, median over the window's saves."""
+"""Seconds of a save's stall that no phase accounts for:
+``train::report_sharded`` less what its child spans on the loop's own
+thread cover (the writer's run beside the next steps and are no part of the
+stall), median over the window's saves."""
 
 import program_spans
 

@@ -12,10 +12,13 @@ and select what started inside the window the runner timed
 that added them, or a ``--trace 0`` run) leaves every reader with None.
 
 A save is one ``train::report_sharded`` span on the loop's thread; its
-phases (``ckpt::gather``, ``ckpt::copy``, ``ckpt::checksum``,
-``ckpt::write``, ``ckpt::meta``, and the ack, a nested ``train::report``)
-are its direct children. A metric "per save" is summed inside each save and
-the median is taken over the window's saves.
+phases (``ckpt::drain_wait``, ``ckpt::meta``, ``ckpt::gather``,
+``ckpt::copy``, ``ckpt::checksum``, ``ckpt::write``, and the ack, a nested
+``train::report``) are its direct children. Since the program writes behind
+the loop, the copy, checksum and write run on a writer's thread, begin
+where the save hands over and end after it: still its children by
+``parent_id``, but no part of its duration. A metric "per save" is summed
+inside each save and the median is taken over the window's saves.
 """
 
 from __future__ import annotations
@@ -62,9 +65,13 @@ def phase_seconds(record: Dict[str, Any], name: str) -> Optional[float]:
 
 def self_seconds(record: Dict[str, Any]) -> Optional[float]:
     """Seconds of a save that none of its children covers, median over the
-    saves that have children."""
+    saves that have children. Only the children on the save's own thread
+    count: those on another run beside it or after it has ended, and a sum
+    across threads is no time."""
     return harness.median(
-        save.duration - sum(s.duration for s in children)
+        save.duration - sum(
+            s.duration for s in children
+            if getattr(s, "thread", "") == getattr(save, "thread", ""))
         for save, children in saves(record) if children)
 
 

@@ -81,6 +81,9 @@ def main(argv=None) -> None:
             "min": min(t1 - t0 for t0, t1 in spans),
             "max": max(t1 - t0 for t0, t1 in spans)}
         for name, spans in record["spans"].items() if spans}
+    # Each save's stall in its order: a run's first has no writer beside it.
+    diagnostics["save_spans"] = [t1 - t0 for t0, t1 in
+                                 record["spans"].get("save", [])]
     print("diagnostics: " + json.dumps(diagnostics))
     print(json.dumps(line))
 

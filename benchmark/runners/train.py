@@ -19,6 +19,9 @@ Traffic parameters (``traffic/<mix>.json``):
     report_every  ``session.report`` every this many steps
     save_every    ``session.report_sharded`` every this many steps; 0: never
     num_to_keep   ``CheckpointConfig.num_to_keep``
+    ahead_s       seconds of steps sent to the device ahead of the one whose
+                  loss the loop waits for (a mix without saves; 0: each
+                  step's loss is read before the next is sent)
 
 A unit is one step where nothing is saved and ``save_every`` steps with
 their save where something is: rates count whole units only. The window is
@@ -28,6 +31,15 @@ inside it, and the window ends at the first that would not. The first of a
 kind has nothing to go by and always starts. A ``--trace 1`` run stops
 after ``TRACE_UNITS`` whole units if the window holds more, which keeps a
 trace of steps of seconds to a few megabytes.
+
+With ``ahead_s`` the loop is the one a user writes who logs a loss late:
+steps are sent while the device has less than ``ahead_s`` seconds of them
+queued, and the oldest one's loss is read and reported meanwhile, so that
+a host that stands still for a second or three (a one-chip machine shares
+its host's cores) leaves the device fed. A step is sent only if it is
+expected to end inside the window; when none is, nothing more is sent, the
+loop waits for all that was sent, and the clock is read after that wait:
+every step sent counts, over all of that time.
 
 The runner knows no model module: the program's config, the state and the
 step, its forward and loss, and the check of its widths against the
@@ -46,6 +58,7 @@ import shutil
 import sys
 import tempfile
 import time
+from collections import deque
 from contextlib import contextmanager
 from dataclasses import replace
 from typing import Any, Dict, List
@@ -62,6 +75,10 @@ CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
 SAVE_SECONDS = "ray_tpu_train_ckpt_save_seconds"
 SAVE_FAILURES = "ray_tpu_train_checkpoint_persist_failures"
 TRACE_UNITS = 8
+#: A read of a loss that waited this long found its step still running, so
+#: the read's end is the step's end on the device.
+BLOCKED_S = 1e-3
+COMMIT_WAIT_S = 60.0
 
 
 class Counters:
@@ -132,9 +149,11 @@ def _histogram(name: str) -> Dict[str, float]:
 
 def _save_seconds(saves: float) -> Dict[str, float]:
     """The program's save histogram once it holds ``saves`` observations:
-    the driver observes a save when it commits the manifest, a moment after
-    ``report_sharded`` has returned in the loop."""
-    deadline = time.perf_counter() + 10.0
+    the driver observes a save when it commits the manifest, which since
+    the write runs behind the loop is 7-8 s after ``report_sharded`` has
+    returned, and 11-17 s where an fsync is slow: ``COMMIT_WAIT_S`` is a
+    slow disk's room. A run without saves returns at once."""
+    deadline = time.perf_counter() + COMMIT_WAIT_S
     while True:
         seen = _histogram(SAVE_SECONDS)
         if seen["count"] >= saves or time.perf_counter() > deadline:
@@ -235,6 +254,10 @@ def train_loop(config: Dict[str, Any]) -> None:
     batch_size, seq = layout["batch"], layout["seq_len"]
     save_every = int(traffic.get("save_every", 0))
     report_every = int(traffic.get("report_every", 1))
+    ahead_s = float(traffic.get("ahead_s", 0.0))
+    if ahead_s and (save_every or traffic["data"] != "repeat"):
+        raise ValueError("ahead_s is for a mix that repeats one batch and "
+                         "saves nothing")
     # From ``fit()`` to here: the trainer starting its worker.
     setup: Dict[str, Any] = {
         "trainer_start_s": time.perf_counter() - config["t_fit"]}
@@ -321,6 +344,49 @@ def train_loop(config: Dict[str, Any]) -> None:
             with spans("report"):
                 session.report(report)
 
+    # -- the same, with steps sent ahead of the loss that is waited for ---
+    pending: deque = deque()
+    ahead: Dict[str, Any] = {"ahead_s": ahead_s, "step_s": None,
+                             "free_at": 0.0, "done_at": None,
+                             "between": [], "max_pending": 0}
+
+    def send() -> None:
+        nonlocal state
+        with spans("data"):
+            batch = next_batch()
+        with spans("step"):
+            state, metrics = step(state, batch)
+        pending.append((spans.spans["step"][-1][0], metrics))
+        ahead["max_pending"] = max(ahead["max_pending"], len(pending))
+
+    def retire() -> None:
+        """The oldest step sent: its loss read back and reported."""
+        nonlocal n_steps
+        sent_at, metrics = pending.popleft()
+        with spans("wait"):
+            loss = float(metrics["loss"])
+        t0, t1 = spans.spans["wait"][-1]
+        # Where the read waited, it ended with the step, and the device
+        # then has ``len(pending)`` steps left. Two such ends in a row are
+        # one step apart: their median is what a step is expected to take
+        # (the first step, sent to an idle device, until there is one).
+        if t1 - t0 >= BLOCKED_S:
+            if ahead["done_at"] is not None:
+                ahead["between"].append(t1 - ahead["done_at"])
+                ahead["step_s"] = harness.median(ahead["between"])
+            elif ahead["step_s"] is None:
+                ahead["step_s"] = t1 - sent_at
+            ahead["done_at"] = t1
+            ahead["free_at"] = t1 + len(pending) * ahead["step_s"]
+        else:
+            ahead["done_at"] = None
+        losses.append(loss)
+        n_steps += 1
+        if n_steps % report_every == 0:
+            with spans("report"):
+                session.report({"step": n_steps, "loss": loss,
+                                "step_s": ahead["step_s"]})
+
     # -- warm-up: every shape the window uses, and nothing else -----------
     t0 = time.perf_counter()
     before = counters.snapshot()
@@ -367,11 +433,53 @@ def train_loop(config: Dict[str, Any]) -> None:
                                  time.perf_counter() - t0)
         return True
 
+    def window_ahead() -> None:
+        """The window of a mix with ``ahead_s``. Until a step has been
+        timed nothing is known of the queue, and one step is out at a
+        time. A loss that has arrived is read and reported at once; one
+        that has not is waited for only where the queue is full."""
+        sent = 0
+        while max_units is None or sent < max_units:
+            while pending and pending[0][1]["loss"].is_ready():
+                retire()
+                unit_ends.append(time.perf_counter())
+            now = time.perf_counter()
+            step_s = ahead["step_s"]
+            if step_s is not None:
+                # What is left of the queue: the steps whose loss has not
+                # arrived, the oldest of them begun (a program that holds
+                # its own call back until the step before has ended keeps
+                # the queue shorter than this loop would).
+                left = len(pending)
+                ahead["free_at"] = min(
+                    max(ahead["free_at"], now + max(left - 1, 0) * step_s),
+                    now + left * step_s)
+            starts = max(now, ahead["free_at"])
+            if starts + (step_s or 0.0) >= window_end:
+                break
+            # One step behind the one that runs is always allowed, or a
+            # step longer than half of ``ahead_s`` would be sent to an idle
+            # device every time.
+            if pending and (step_s is None or (
+                    len(pending) >= 2 and starts + step_s - now > ahead_s)):
+                retire()
+                unit_ends.append(time.perf_counter())
+                continue
+            send()
+            sent += 1
+            ahead["free_at"] = starts + (step_s or 0.0)
+        while pending:
+            retire()
+            unit_ends.append(time.perf_counter())
+
     try:
         with spans("window"):
-            while (max_units is None or len(unit_ends) < max_units) \
-                    and whole_unit():
-                unit_ends.append(time.perf_counter())
+            if ahead_s:
+                window_ahead()
+            else:
+                while (max_units is None or len(unit_ends) < max_units) \
+                        and whole_unit():
+                    unit_ends.append(time.perf_counter())
     finally:
         if tracing:
             spans.tracing = False
@@ -398,6 +506,8 @@ def train_loop(config: Dict[str, Any]) -> None:
             "tokens_per_step": batch_size * seq,
             "steps": n_steps - warm_steps,
             "saves": len(saves),
+            "ahead": {k: ahead[k] for k in ("ahead_s", "step_s",
+                                            "max_pending")},
         },
         "spans": spans.spans,
         "in_window": in_window,

@@ -29,7 +29,8 @@ def test_end_to_end():
     assert read("end_to_end", "tokens_per_s") == 12000 / 31.0
     assert abs(read("end_to_end", "mfu")
                - (12000 / 31.0) * 37_880_070_144 / (4 * 197e12)) < 1e-12
-    assert read("end_to_end", "ckpt_stall_s") == 6.0
+    # Median of the three saves' spans (5, 7 and 6 s).
+    assert read("layer_metrics", "ckpt.stall_s") == 6.0
     assert read("end_to_end", "setup_s") == 30.0
 
 
@@ -39,15 +40,14 @@ def test_per_layer():
     assert abs(read("layer_metrics", "train.report_ms") - 2.0) < 1e-9
     assert read("layer_metrics", "train.data_wait_ms") == 4.0
     assert read("layer_metrics", "ckpt.write_s") == 2.0
-    assert read("layer_metrics", "ckpt.extract_s") == 4.0
     assert read("layer_metrics", "step.device_ms") == 1100.0
     assert read("layer_metrics", "collective.share") == 0.1
     assert read("layer_metrics", "kernel.custom_call_share") == 0.1
     assert read("layer_metrics", "device.idle_share") == 0.6
     assert read("layer_metrics", "device.peak_hbm_gb") == 12.0
-    # The whole-unit rate again, where it carries no bound: a stalled
-    # unit (the third, 11 s) counts in full.
-    assert read("layer_metrics", "train.tokens_per_s") == 12000 / 31.0
+    # The whole-unit rate under the name it has in a cell with saves: a
+    # stalled unit (the third, 11 s) counts in full.
+    assert read("end_to_end", "job_tokens_per_s") == 12000 / 31.0
     stalled = dict(RECORD, spans={"step": [[0, 1.9], [2, 16.6], [17, 18.9]]})
     assert abs(read("layer_metrics", "train.step_max_ms", stalled)
                - 14600.0) < 1e-6
@@ -58,14 +58,26 @@ def test_nothing_to_read_is_none():
         "sum": 0.0, "count": 0}, window=dict(RECORD["window"], unit_ends=[]))
     for name in ("step.device_ms", "collective.share", "device.idle_share",
                  "kernel.custom_call_share", "train.report_ms",
-                 "ckpt.write_s", "ckpt.extract_s"):
+                 "ckpt.write_s"):
         assert read("layer_metrics", name, empty) is None
     assert read("end_to_end", "tokens_per_s", empty) is None
-    assert read("end_to_end", "ckpt_stall_s", empty) is None
-    assert read("layer_metrics", "train.tokens_per_s", empty) is None
+    assert read("layer_metrics", "ckpt.stall_s", empty) is None
+    assert read("end_to_end", "job_tokens_per_s", empty) is None
     assert read("layer_metrics", "train.step_max_ms", empty) is None
     one_chip = dict(RECORD, cell=dict(RECORD["cell"], chips=1))
     assert read("layer_metrics", "collective.share", one_chip) is None
+
+
+def test_a_stalled_unit_counts_in_full():
+    """The rate is over the clock to the end of the last whole unit: a save
+    twice as long takes its seconds out of the rate, and steps that end
+    after the last whole unit are no part of it."""
+    window = dict(RECORD["window"], unit_ends=[110.0, 120.0, 142.0])
+    slow = dict(RECORD, window=window)
+    assert read("end_to_end", "job_tokens_per_s", slow) == 12000 / 42.0
+    one = dict(RECORD, window=dict(window, unit_ends=[110.0]))
+    assert read("end_to_end", "job_tokens_per_s", one) == 4000 / 10.0
+    assert read("end_to_end", "tokens_per_s", one) == 4000 / 10.0
 
 
 def test_a_cell_reports_only_its_metrics():

@@ -33,13 +33,18 @@ def test_compact_share(counters):
     assert read("moe.compact_share") is None
 
 
-def test_it_is_listed_for_the_two_cells_with_a_share():
+def test_it_is_listed_for_the_cells_with_a_share():
+    """Every cell whose configuration holds a share of the experts
+    (``experts_held``) and no other: by name, wherever the entry stands."""
     spec = harness.load_spec()
     entry, = (m for m in spec["per_layer"] if m["name"] == "moe.compact_share")
+    with_a_share = [
+        w["name"] for w in spec["workloads"]
+        if "experts_held" in harness.load_cell(
+            spec, w["name"]).config["program"]["overrides"]]
+    assert with_a_share[:2] == ["trinity-large-preview-1chip.steady",
+                                "kimi-linear-48b-a3b-1chip.steady"]
     assert entry == {
         "name": "moe.compact_share", "unit": "ratio", "better": "higher",
         "source": "program_counter", "layer": "expert layer",
-        "moves": "tokens_per_s",
-        "workloads": ["trinity-large-preview-1chip.steady",
-                      "kimi-linear-48b-a3b-1chip.steady"]}
-    assert spec["per_layer"][-1] is entry
+        "moves": "tokens_per_s", "workloads": with_a_share}

@@ -70,6 +70,21 @@ def test_the_ratio_is_over_seven_intervals_of_eight_spans(buffer):
     assert read("step.interval_max_over_median") == pytest.approx(2 / 1.5)
 
 
+def test_where_steps_are_sent_ahead_the_ratio_is_over_the_losses_reads(
+        buffer):
+    """The queue fills in a burst of ``train::step`` entries; the reads of
+    the losses end a step apart, but where the host stood still: then one
+    ends late and those behind it at once."""
+    buffer += [span("train::step", t, 0.004)
+               for t in (101.0, 102.0, 102.01, 102.02, 102.03)]
+    waits = {"wait": [[101.0, 102.0], [102.0, 103.0], [103.0, 104.0],
+                      [104.0, 106.5], [106.5, 106.5], [106.5, 107.0]]}
+    assert read("step.interval_max_over_median",
+                dict(RECORD, spans=waits)) == pytest.approx(2.5)
+    assert read("step.interval_max_over_median", dict(
+        RECORD, spans={"wait": waits["wait"][:2]})) is None
+
+
 def test_the_latest_tick_of_the_window(buffer):
     buffer += [span("host::tick", 100.0 + i / 10, late,
                     thread="ray_tpu-profiler-driver")
@@ -92,7 +107,7 @@ def test_a_batch_is_the_median_of_the_window_s(buffer):
      ".steady"),
     ("host.late_tick_max_ms", "core runtime", "tokens_per_s", "ms",
      ".steady"),
-    ("data.next_batch_ms", "Data ingest", "ckpt_stall_s", "ms", ".job"),
+    ("data.next_batch_ms", "Data ingest", "job_tokens_per_s", "ms", ".job"),
 ])
 def test_each_is_listed_for_its_cells_with_a_reader(name, layer, moves,
                                                     unit, cells):

@@ -90,6 +90,26 @@ def test_metrics_have_readers_and_legal_names():
         assert layers and all(m["moves"] in own for m in layers)
 
 
+def test_every_moves_names_an_end_to_end_metric_of_the_same_cells():
+    """A per-layer metric is reported only where the metric it moves is, so
+    a ``moves`` that names no end-to-end metric (one renamed or taken
+    away) or one of other cells silences its reader."""
+    spec = harness.load_spec()
+    cells = [w["name"] for w in spec["workloads"]]
+    end_to_end = {m["name"]: set(m.get("workloads", cells))
+                  for m in spec["end_to_end"]}
+    for metric in spec["per_layer"]:
+        assert metric["moves"] in end_to_end, metric["name"]
+        assert set(metric.get("workloads", cells)) <= \
+            end_to_end[metric["moves"]], metric["name"]
+    # Every reader file is some metric's and every metric has its file.
+    for group, kind in (("end_to_end", "end_to_end"),
+                        ("per_layer", "layer_metrics")):
+        files = {f[:-3] for f in os.listdir(os.path.join(harness.HERE, kind))
+                 if f.endswith(".py")}
+        assert files == {m["name"] for m in spec[group]}
+
+
 def test_no_file_names_a_cell_a_configuration_or_a_metric_in_code():
     spec = harness.load_spec()
     names = [w["name"] for w in spec["workloads"]] + \
