@@ -372,12 +372,23 @@ def test_gated_norm_compiles_at_the_published_widths(
         else {"gated_norm_fwd": 1})
 
 
+#: {cell: (step, its abstract arguments, the jaxpr of the step)} and the
+#: cells in the order they were loaded and traced: each once a run of this
+#: file, which the file's last case holds it to.
+_CELLS, _TRACES = {}, []
+
+
 def _a_cells_step(topo, cell):
-    """(step, its abstract arguments) of a benchmark cell, found the way
-    ``benchmark/rehearse.py`` finds it (the configuration's file, its
-    family's ``config`` and ``abstract_state_and_step``) for the described
-    chip. A one-chip mesh: the trace asks it nothing
+    """(step, its abstract arguments, the step's jaxpr) of a benchmark
+    cell, found the way ``benchmark/rehearse.py`` finds it (the
+    configuration's file, its family's ``config`` and
+    ``abstract_state_and_step``) for the described chip, loaded and traced
+    once for the module: every census reads that jaxpr, and ``step.lower``
+    (the digests', the whole-step compiles') finds the same trace in the
+    step's cache. A one-chip mesh: the trace asks it nothing
     (``lm._over_batch_shards``)."""
+    if cell in _CELLS:
+        return _CELLS[cell]
     here = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")
     sys.path.insert(0, here)
@@ -395,7 +406,15 @@ def _a_cells_step(topo, cell):
     tokens = jax.ShapeDtypeStruct(
         (layout["batch"], layout["seq_len"]), jnp.int32,
         sharding=family.batch_sharding(mesh))
-    return step, (state, {"tokens": tokens, "targets": tokens})
+    args = (state, {"tokens": tokens, "targets": tokens})
+    _TRACES.append(cell)
+    _CELLS[cell] = step, args, jax.make_jaxpr(step.__wrapped__)(*args)
+    if found.chips > 1:
+        # Traced outside the step's mesh, which a census can read and a
+        # lowering cannot use (no ``shard_map`` around the kernels):
+        # ``step.lower`` must not find this trace.
+        step.__wrapped__.clear_cache()
+    return _CELLS[cell]
 
 
 @pytest.mark.parametrize("cell,calls", [
@@ -417,9 +436,8 @@ def test_a_cells_step_runs_the_convolutions_kernels(topo, cell, calls):
     gate and norm behind the recurrence (``lm.gated_norm``) run the same
     way, once a layer of Kimi's and of granite's."""
     from ray_tpu.parallel.collectives import kernel_census
-    step, args = _a_cells_step(topo, cell)
-    census = kernel_census(jax.make_jaxpr(step.__wrapped__)(*args),
-                           a_step=True)
+    _, _, jaxpr = _a_cells_step(topo, cell)
+    census = kernel_census(jaxpr, a_step=True)
     assert {name: census.get(name) for name in calls} == calls
     for name in ("conv_silu_fwd", "gated_norm_fwd"):
         if name not in calls:
@@ -436,8 +454,8 @@ def test_no_other_cells_step_holds_the_gated_norm(topo, cell):
     (LFM2's is the third row above; phi's Mamba-1 gate has no norm and
     stays XLA's)."""
     from ray_tpu.parallel.collectives import kernel_census
-    step, args = _a_cells_step(topo, cell)
-    census = kernel_census(jax.make_jaxpr(step.__wrapped__)(*args))
+    _, _, jaxpr = _a_cells_step(topo, cell)
+    census = kernel_census(jaxpr)
     # (megablox's grouped matmul gives its calls no name.)
     assert census and not [name for name in census
                            if "gated_norm" in str(name)]
@@ -476,10 +494,9 @@ def test_a_cells_step_runs_three_products_a_chunk_of_the_head(topo, cell):
     second scan with anything as wide as the vocabulary. Autodiff of the
     rematerialised scan it replaces traced two, of one and three: the head
     ran again in the backward scan."""
-    step, args = _a_cells_step(topo, cell)
+    _, args, jaxpr = _a_cells_step(topo, cell)
     vocab = args[0]["params"]["wte"].shape[0]
-    assert _wide_products_a_scan(
-        jax.make_jaxpr(step.__wrapped__)(*args), vocab) == [3]
+    assert _wide_products_a_scan(jaxpr, vocab) == [3]
 
 
 def test_the_phi4flash_cells_step_runs_its_kernels_as_counted(topo):
@@ -493,7 +510,7 @@ def test_the_phi4flash_cells_step_runs_its_kernels_as_counted(topo):
     layers' once; every backward kernel once a layer."""
     from ray_tpu.parallel.collectives import kernel_census
     cell = "phi-4-mini-flash-reasoning-1chip.steady"
-    step, args = _a_cells_step(topo, cell)
+    _, _, jaxpr = _a_cells_step(topo, cell)
     here = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark", "configs")
     with open(os.path.join(here, cell.split(".")[0] + ".json")) as f:
@@ -502,8 +519,7 @@ def test_the_phi4flash_cells_step_runs_its_kernels_as_counted(topo):
     window = sum(i % 2 == 1 and i < 16 for i in layers)
     causal = sum(i % 2 == 1 and i > 16 for i in layers)
     assert mamba >= 2 and window >= 1 and causal >= 2
-    census = kernel_census(jax.make_jaxpr(step.__wrapped__)(*args),
-                           a_step=True)
+    census = kernel_census(jaxpr, a_step=True)
     assert census == {
         "selective_scan_fwd": 2 * mamba, "selective_scan_bwd": mamba,
         "conv_silu_fwd": 2 * mamba, "conv_silu_bwd": mamba,
@@ -585,7 +601,7 @@ def test_the_glm_cells_step_runs_its_kernels_as_counted(topo):
     back to tokens in the expert layers, and no causal flash kernel."""
     from ray_tpu.parallel.collectives import kernel_census
     cell = "glm-5.2-1chip.steady"
-    step, args = _a_cells_step(topo, cell)
+    _, _, jaxpr = _a_cells_step(topo, cell)
     here = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark", "configs")
     with open(os.path.join(here, cell.rsplit(".", 1)[0] + ".json")) as f:
@@ -594,8 +610,7 @@ def test_the_glm_cells_step_runs_its_kernels_as_counted(topo):
     owners = sum(config["indexer_types"][l] == "full" for l in layers)
     assert len(layers) == 5 and owners == 2
     assert config["layout"]["seq_len"] < 32 * config["v_head_dim"]
-    census = kernel_census(jax.make_jaxpr(step.__wrapped__)(*args),
-                           a_step=True)
+    census = kernel_census(jaxpr, a_step=True)
     assert {name: n for name, n in census.items() if name and name.startswith(
         ("dsa_", "flash_"))} == {
         "dsa_fwd": 2 * len(layers), "dsa_bwd_dq": len(layers),
@@ -623,7 +638,7 @@ def test_the_phi4flash_cells_reference_check_holds_less_than_its_step(topo):
         family = harness.load_module("families", "phi4flash")
     finally:
         sys.path.remove(here)
-    _, (state, batch) = _a_cells_step(
+    _, (state, batch), _ = _a_cells_step(
         topo, "phi-4-mini-flash-reasoning-1chip.steady")
     with open(os.path.join(
             here, "configs", "phi-4-mini-flash-reasoning-1chip.json")) as f:
@@ -666,9 +681,9 @@ def test_the_kimi_cells_compiled_step_holds_the_delta_rules_pair(
     comes back is 0.25."""
     import glob
     from ray_tpu.parallel.collectives import kernel_census
-    step, args = _a_cells_step(topo, "kimi-linear-48b-a3b-1chip.steady")
-    a_step = kernel_census(jax.make_jaxpr(step.__wrapped__)(*args),
-                           a_step=True)
+    step, args, jaxpr = _a_cells_step(
+        topo, "kimi-linear-48b-a3b-1chip.steady")
+    a_step = kernel_census(jaxpr, a_step=True)
     assert (a_step["kda_fwd"], a_step["kda_bwd"]) == (8, 4)
     compiled = step.lower(*args).compile(
         compiler_options={"xla_dump_to": str(tmp_path)})
@@ -736,9 +751,8 @@ def test_a_share_cells_step_sums_rows_with_the_kernel(topo, cell, layers,
     is the block's output (LFM2, Kimi) the backward scan does not run the
     layer's forward again; Trinity norms the sum, and it does."""
     from ray_tpu.parallel.collectives import kernel_census
-    step, args = _a_cells_step(topo, cell)
-    census = kernel_census(jax.make_jaxpr(step.__wrapped__)(*args),
-                           a_step=True)
+    _, _, jaxpr = _a_cells_step(topo, cell)
+    census = kernel_census(jaxpr, a_step=True)
     assert census["moe_rows_to_tokens"] == layers * passes * 2
 
 
@@ -755,7 +769,7 @@ def test_the_lfm2_cells_compiled_step_gathers_no_slab_of_tokens(topo):
     call the parent's step held 80 and 96 of them where this holds 16 and
     32."""
     from ray_tpu.parallel.collectives import kernel_census
-    step, args = _a_cells_step(topo, "lfm2-24b-a2b-1chip.steady")
+    step, args, _ = _a_cells_step(topo, "lfm2-24b-a2b-1chip.steady")
     text = step.lower(*args).compile().as_text()
     calls = kernel_census(text)["moe_rows_to_tokens"]
 
@@ -816,7 +830,7 @@ def test_a_step_without_a_share_is_the_program_it_was(topo, cell):
     """The five cells whose model holds every expert or none (the GPT-J
     cells, Moonlight's whole layer, granite, phi) lower to the text
     recorded above: nothing they run was touched since."""
-    step, args = _a_cells_step(topo, cell)
+    step, args, _ = _a_cells_step(topo, cell)
     assert _lowered_digest(step, args) == LOWERED_STEPS[cell]
 
 
@@ -1147,3 +1161,15 @@ def test_step_sends_what_fsdp_x_tp_needs(topo, parallel_block):
               if op["kind"] in ("all-reduce", "reduce-scatter") and wide(op)
               and len(dims(op)[0]) == 2]
     assert len(gathered) == 1 and len(summed) == 1, (gathered, summed)
+
+
+def test_every_cells_step_was_loaded_and_traced_once(topo):
+    """The file's last case: the cases above name their cells 23 times, and
+    each cell's step was built and traced the first time, and only then.
+    Alone, it names the cells itself: the count holds in any selection."""
+    named = sorted(set(LOWERED_STEPS) | set(SHARE_SHAPES))
+    first = {cell: _a_cells_step(topo, cell) for cell in named}
+    traced = list(_TRACES)
+    assert all(_a_cells_step(topo, cell) is first[cell] for cell in named)
+    assert _TRACES == traced and len(traced) == len(set(traced))
+    assert set(named) <= set(traced) == set(_CELLS)

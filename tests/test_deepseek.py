@@ -18,7 +18,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_cases
 import reference_deepseek_v3 as reference
+from family_cases import batch, drawn
 from ray_tpu.models import deepseek, gpt, lm
 from ray_tpu.ops import moe
 from ray_tpu.ops.flash_attention import flash_attention
@@ -29,111 +31,86 @@ from ray_tpu.parallel.train_step import (abstract_train_state,
                                          make_train_step)
 
 CFG = deepseek.config("deepseek-tiny")
-PUBLISHED = {"qk_nope_head_dim": CFG.qk_nope_head_dim,
-             "kv_lora_rank": CFG.kv_lora_rank, "rope_theta": CFG.rope_theta,
-             "num_experts_per_tok": CFG.num_experts_per_tok,
-             "routed_scaling_factor": CFG.routed_scaling_factor,
-             "norm_topk_prob": CFG.norm_topk_prob,
-             "rms_norm_eps": CFG.rms_norm_eps}
 SEQ = 128
+# The chip's recipe: flash kernels (interpreted), full remat, the chunked
+# loss.
+FLASH = deepseek.config("deepseek-tiny", attn_impl="flash", remat=True,
+                        loss_chunk=64)
 
 
-def _params(seed=0, bias=None):
-    """Seeded weights with every RMSNorm scale drawn around one and the
-    correction bias drawn (or given): a dropped vector or a bias that
-    reached the weights would show."""
-    params = deepseek.init(CFG, jax.random.PRNGKey(seed))
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-
-    def draw(name, leaf):
-        if name == "router_bias":
-            return 0.3 * jax.random.normal(next(keys), leaf.shape) \
-                if bias is None else jnp.broadcast_to(bias, leaf.shape)
-        if name.endswith("_scale"):
-            return leaf + 0.1 * jax.random.normal(next(keys), leaf.shape)
-        return leaf
-
-    return {k: {n: draw(n, a) for n, a in v.items()} if isinstance(v, dict)
-            else draw(k, v) for k, v in params.items()}
+def published(cfg):
+    return {"qk_nope_head_dim": cfg.qk_nope_head_dim,
+            "kv_lora_rank": cfg.kv_lora_rank, "rope_theta": cfg.rope_theta,
+            "num_experts_per_tok": cfg.num_experts_per_tok,
+            "routed_scaling_factor": cfg.routed_scaling_factor,
+            "norm_topk_prob": cfg.norm_topk_prob,
+            "rms_norm_eps": cfg.rms_norm_eps}
 
 
-def _batch(seed=0, n_seq=2):
-    rows = np.random.default_rng(seed).integers(
-        0, CFG.vocab_size, (n_seq, SEQ + 1), dtype=np.int32)
-    return jnp.asarray(rows[:, :-1]), jnp.asarray(rows[:, 1:])
+def moved(name, leaf, key):
+    """Every RMSNorm scale drawn around one and the correction bias drawn: a
+    dropped vector or a bias that reached the weights would show."""
+    if "router_bias" in name:
+        return 0.3 * jax.random.normal(key, leaf.shape)
+    if name.endswith("_scale']"):
+        return leaf + 0.1 * jax.random.normal(key, leaf.shape)
+    return leaf
 
 
-def _reference_forward(params, tokens, targets):
-    where = jnp.broadcast_to(jnp.arange(SEQ, dtype=jnp.int32), tokens.shape)
-    return reference.forward(params, tokens, targets, where,
-                             with_picked=True, **reference.arguments(PUBLISHED))
+DEEPSEEK = family_cases.Family(
+    module=deepseek, reference=reference, cfg=CFG, seq=SEQ, flash=FLASH,
+    flash_seq=SEQ, published=published, moved=moved, extras=("picked",))
+globals().update(family_cases.cases(DEEPSEEK))
+
+
+def _batch(n_seq=2):
+    return batch(CFG, SEQ, rows=n_seq)
 
 
 @pytest.mark.parametrize("what", ["logits", "loss_per_sequence", "picked"])
-def test_program_matches_reference_forward(what):
-    params, (tokens, targets) = _params(), _batch()
-    want_logits, want_loss, _, want_picked = _reference_forward(
-        params, tokens, targets)
-    with jax.default_matmul_precision("highest"):
-        if what == "logits":
-            got = deepseek.forward(params, CFG, tokens)
-            np.testing.assert_allclose(got, want_logits, atol=1e-5)
-        elif what == "picked":
-            _, aux = deepseek.forward_with_aux(params, CFG, tokens)
-            np.testing.assert_array_equal(np.sort(aux["picked"], -1),
-                                          np.sort(want_picked, -1))
-        else:
+def test_program_matches_reference_forward(both, what):
+    """To 1e-5 absolute, the loss a sequence at a time (under a mask of one
+    row), and the routing."""
+    if what == "logits":
+        np.testing.assert_allclose(*both["logits"], atol=1e-5)
+    elif what == "picked":
+        np.testing.assert_array_equal(np.sort(both["aux"]["picked"], -1),
+                                      np.sort(both["extras"][0], -1))
+    else:
+        tokens, targets = _batch()
+        with jax.default_matmul_precision("highest"):
+            masked = jax.jit(lambda p, mask: deepseek.loss_fn(
+                p, CFG, tokens, targets, mask)[0])
             for row in range(tokens.shape[0]):
-                mask = jnp.zeros(tokens.shape).at[row].set(1.0)
-                got = deepseek.loss_fn(params, CFG, tokens, targets, mask)[0]
-                np.testing.assert_allclose(got, want_loss[row], rtol=1e-5)
-
-
-def _grad_errors(got, want):
-    return {jax.tree_util.keystr(path): float(
-        jnp.linalg.norm((g - w).ravel())
-        / jnp.maximum(jnp.linalg.norm(w.ravel()), 1e-30))
-        for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want),
-                                jax.tree.leaves(got))}
-
-
-@pytest.mark.parametrize("variant", ["dot", "flash_remat_chunked"])
-def test_program_gradients_match_reference_per_leaf(variant):
-    """Every leaf, the correction bias among them (no gradient on either
-    side). The second variant is the chip's recipe: flash kernels
-    (interpreted), full remat, the chunked loss."""
-    cfg = CFG if variant == "dot" else deepseek.config(
-        "deepseek-tiny", attn_impl="flash", remat=True, loss_chunk=64)
-    params, (tokens, targets) = _params(), _batch()
-    want = jax.grad(lambda p: reference.loss(
-        p, tokens, targets, **reference.arguments(PUBLISHED)))(params)
-    with jax.default_matmul_precision("highest"):
-        got = jax.grad(lambda p: deepseek.loss_fn(
-            p, cfg, tokens, targets)[0])(params)
-    for stack in ("moe_layers",):
-        assert not np.any(got[stack]["router_bias"])
-        assert not np.any(want[stack]["router_bias"])
-    errors = {k: v for k, v in _grad_errors(got, want).items()
-              if "router_bias" not in k}
-    assert max(errors.values()) < 1e-4, errors
+                got = masked(drawn(DEEPSEEK, CFG),
+                             jnp.zeros(tokens.shape).at[row].set(1.0))
+                np.testing.assert_allclose(got, both["losses"][row],
+                                           rtol=1e-5)
 
 
 def test_skewed_bias_drops_nothing_and_builds_no_capacity_tensor():
     """One expert's bias far above the others: it takes every token, the
     busiest expert has E / K (>= 3) times the mean load, every assignment is
     still computed, and the result is still the reference's."""
+    params, (tokens, targets) = drawn(DEEPSEEK, CFG), _batch()
+    stack = params["moe_layers"]
     bias = jnp.zeros((CFG.n_routed_experts,)).at[5].set(4.0)
-    params, (tokens, targets) = _params(bias=bias), _batch()
+    params = dict(params, moe_layers=dict(
+        stack, router_bias=jnp.broadcast_to(bias, stack["router_bias"].shape)))
     asked = tokens.size * CFG.num_experts_per_tok * CFG.n_moe_layers
     with jax.default_matmul_precision("highest"):
-        logits, aux = deepseek.forward_with_aux(params, CFG, tokens)
-        _, metrics = deepseek.loss_fn(params, CFG, tokens, targets)
+        (logits, aux), (_, metrics) = jax.jit(lambda p: (
+            deepseek.forward_with_aux(p, CFG, tokens),
+            deepseek.loss_fn(p, CFG, tokens, targets)))(params)
     assert int(aux["group_sizes"].sum()) == asked
     assert float(metrics["moe_assignments"]) == asked == \
         float(metrics["moe_tokens"])
     assert float(metrics["moe_load_max_over_mean"]) >= 2.6  # E / K = 8 / 3
     assert (aux["group_sizes"][:, 5] == tokens.size).all()
-    want_logits, _, _, _ = _reference_forward(params, tokens, targets)
+    where = jnp.broadcast_to(jnp.arange(SEQ, dtype=jnp.int32), tokens.shape)
+    want_logits = reference.forward(
+        params, tokens, targets, where,
+        **reference.arguments(published(CFG)))[0]
     np.testing.assert_allclose(logits, want_logits, atol=1e-5)
 
     # No intermediate of tokens x experts x anything: the largest arrays of
@@ -259,7 +236,7 @@ def test_train_step_trains_the_new_model_and_feeds_the_counters(accum_steps):
     mesh = _one_chip()
     state = init_train_state(CFG, mesh, seed=0, model=deepseek)
     step = make_train_step(CFG, mesh, accum_steps=accum_steps, model=deepseek)
-    tokens, targets = _batch(n_seq=4)
+    tokens, targets = _batch(4)
     asked = tokens.size * CFG.num_experts_per_tok * CFG.n_moe_layers
     before = _counters()
     losses = []
@@ -320,23 +297,7 @@ def test_the_builders_find_the_model_that_defines_the_config(model, cfg):
     assert "loss" in found[1] and found == named
 
 
-def test_expert_parallel_mesh_is_refused():
-    mesh = build_mesh(MeshConfig(dp=1, fsdp=1, tp=1, ep=2),
-                      devices=jax.devices()[:2])
-    step = make_train_step(CFG, mesh, model=deepseek)
-    state = init_train_state(CFG, mesh, seed=0, model=deepseek)
-    tokens, targets = _batch()
-    with pytest.raises(NotImplementedError, match="expert parallelism"):
-        step(state, {"tokens": tokens, "targets": targets})
-
-
-def test_param_specs_match_init_and_count():
-    from ray_tpu.parallel.sharding import ShardingRules
-    params = jax.eval_shape(lambda k: deepseek.init(CFG, k),
-                            jax.random.PRNGKey(0))
-    specs = deepseek.param_specs(CFG, ShardingRules())
-    assert jax.tree.structure(params) == jax.tree.structure(
-        specs, is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
+def test_moonlight_is_sixteen_billion():
     full = deepseek.config("moonlight-16b-a3b")
     shapes = jax.eval_shape(lambda k: deepseek.init(full, k),
                             jax.random.PRNGKey(0))

@@ -12,7 +12,6 @@ gradient leaf's norm leave room for float32 reassociation and nothing else.
 """
 
 import os
-import zlib
 from dataclasses import replace
 from functools import partial
 
@@ -22,6 +21,7 @@ import numpy as np
 import pytest
 
 import reference_evabyte as reference
+from family_cases import Family, batch, compared, drawn
 from ray_tpu.models import evabyte, lm
 from ray_tpu.parallel import MeshConfig, build_mesh
 from ray_tpu.parallel.collectives import kernel_census
@@ -40,75 +40,64 @@ def published(cfg):
             "num_pred_heads": cfg.num_pred_heads}
 
 
-def drawn(cfg, seed=0):
-    """The init with every norm's offset moved off zero, ``phi`` and ``mu``
-    large enough that pooling is not the mean and ``mu`` not nothing, and
-    W_q, W_k larger: at ``init_std`` every softmax is flat, and a wrong mask
-    would move nothing."""
-    params = jax.jit(partial(evabyte.init, cfg))(jax.random.PRNGKey(seed))
-    key = jax.random.PRNGKey(seed + 1)
-
-    def moved(path, leaf):
-        name = path[-1].key
-        k = jax.random.fold_in(key, zlib.crc32(name.encode()))
-        if name.endswith("_scale"):
-            return leaf + 0.1 * jax.random.normal(k, leaf.shape)
-        if name in ("wq", "wk"):
-            return leaf * 12.0
-        if name in ("eva_phi", "eva_mu"):
-            return leaf * 4.0
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(moved, params)
+def moved(name, leaf, key):
+    """Every norm's offset off zero, ``phi`` and ``mu`` large enough that
+    pooling is not the mean and ``mu`` not nothing, and W_q, W_k larger: at
+    ``init_std`` every softmax is flat, and a wrong mask would move
+    nothing."""
+    if name.endswith("_scale']"):
+        return leaf + 0.1 * jax.random.normal(key, leaf.shape)
+    if name.endswith(("['wq']", "['wk']")):
+        return leaf * 12.0
+    if name.endswith(("['eva_phi']", "['eva_mu']")):
+        return leaf * 4.0
+    return leaf
 
 
-def batch(seed=0, rows=2, seq=SEQ):
-    toks = np.random.default_rng(seed).integers(
-        0, CFG.vocab_size, (rows, seq + 1), dtype=np.int32)
-    return jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:])
+EVA = Family(module=evabyte, reference=reference, cfg=CFG, seq=SEQ,
+             published=published, moved=moved)
 
 
 @pytest.fixture(scope="module")
 def params():
-    return drawn(CFG)
+    return drawn(EVA, CFG)
 
 
 @pytest.fixture(scope="module")
 def want(params):
-    tokens, targets = batch()
+    """The reference over the whole mask: all heads' logits, every head's
+    loss and their mean, a sequence at a time."""
+    tokens, targets = batch(CFG, SEQ)
     kw = reference.arguments(published(CFG))
-    losses, grads = jax.value_and_grad(
-        lambda p: reference.loss(p, tokens, targets, **kw))(params)
-    return {"logits": reference.logits(params, tokens, **kw),
-            "head_losses": reference.head_losses(params, tokens, targets,
-                                                 **kw),
-            "loss": losses, "grads": grads}
+    logits, head_losses, losses = jax.jit(lambda p: (
+        reference.logits(p, tokens, **kw),
+        reference.head_losses(p, tokens, targets, **kw),
+        [reference.loss(p, tokens[row:row + 1], targets[row:row + 1], **kw)
+         for row in range(2)]))(params)
+    return {"logits": logits, "head_losses": head_losses, "losses": losses}
 
 
 @pytest.mark.parametrize("cfg", [CFG, FLASH], ids=["dot", "flash"])
-def test_model_matches_reference(cfg, params, want):
+def test_model_matches_reference(cfg, want):
     """All heads' logits within 1e-3 of their RMS, every head's loss and
     their mean, every gradient leaf; ``phi`` and ``mu`` with gradient that
     is not zero."""
-    tokens, targets = batch()
-    with jax.default_matmul_precision("highest"):
-        logits = evabyte.forward(params, cfg, tokens)
-        (loss, metrics), grads = jax.value_and_grad(
-            lambda p: evabyte.loss_fn(p, cfg, tokens, targets),
-            has_aux=True)(params)
+    found = compared(EVA, cfg, SEQ, reference_of=CFG)
+    logits, want_logits = found["logits"]
     assert logits.shape == (2, SEQ, 4, CFG.vocab_size)
     assert logits.dtype == jnp.float32
-    rms = float(jnp.sqrt((want["logits"] ** 2).mean()))
-    assert float(jnp.abs(logits - want["logits"]).max()) < 1e-3 * rms
-    assert abs(float(loss) - float(want["loss"])) < 1e-5
+    assert float(jnp.abs(logits.reshape(want_logits.shape) - want_logits
+                         ).max()) < 1e-3 * found["rms"]
+    (loss, want_loss), metrics = found["loss"], found["metrics"]
+    assert abs(float(loss) - float(want_loss)) < 1e-5
     assert abs(float(metrics["total_loss"]) - float(loss)) == 0.0
     for i in range(4):
         assert abs(float(metrics[f"mbp_loss_{i}"])
                    - float(want["head_losses"][i])) < 1e-5
     assert float(metrics["loss"]) == float(metrics["mbp_loss_0"])
-    flat = jax.tree_util.tree_leaves_with_path(grads)
-    ref = dict(jax.tree_util.tree_leaves_with_path(want["grads"]))
-    for path, leaf in flat:
+    grads, want_grads = found["grads"]
+    ref = dict(jax.tree_util.tree_leaves_with_path(want_grads))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(grads):
         norm = float(jnp.linalg.norm(ref[path]))
         assert norm > 0, path
         assert float(jnp.linalg.norm(leaf - ref[path])) < 1e-4 * norm, path
@@ -120,7 +109,7 @@ def test_reference_by_stretches_is_the_whole_mask(params, want):
     """``reference.forward`` (a window at a time, as it runs at the timed
     size) against ``reference.logits`` and ``reference.loss`` over the
     whole mask."""
-    tokens, targets = batch()
+    tokens, targets = batch(CFG, SEQ)
     where = jnp.asarray([[0, 63, 64, 200, 255], [5, 100, 128, 254, 255]])
     kw = reference.arguments(published(CFG))
     sampled, loss, rms = reference.forward(params, tokens, targets, where,
@@ -130,10 +119,7 @@ def test_reference_by_stretches_is_the_whole_mask(params, want):
     np.testing.assert_allclose(sampled, picked, atol=2e-5)
     np.testing.assert_allclose(rms, jnp.sqrt((whole ** 2).mean()),
                                rtol=1e-5)
-    for row in range(2):
-        one = reference.loss(params, tokens[row:row + 1],
-                             targets[row:row + 1], **kw)
-        np.testing.assert_allclose(loss[row], one, atol=1e-5)
+    np.testing.assert_allclose(loss, jnp.stack(want["losses"]), atol=1e-5)
 
 
 @pytest.mark.parametrize("masked", [False, True])
@@ -182,13 +168,13 @@ def test_one_head_is_next_token_loss_bit_for_bit(chunk):
 def test_chunked_heads_are_the_unchunked(params):
     """The chunked walk over (position, head) rows against the whole
     logits: the loss, every head's, and the gradients."""
-    tokens, targets = batch()
+    tokens, targets = batch(CFG, SEQ)
 
     def run(chunk):
         cfg = replace(CFG, loss_chunk=chunk)
-        return jax.value_and_grad(
+        return jax.jit(jax.value_and_grad(
             lambda p: evabyte.loss_fn(p, cfg, tokens, targets),
-            has_aux=True)(params)
+            has_aux=True))(params)
 
     (a, m_a), g_a = run(0)
     (b, m_b), g_b = run(128)
@@ -210,7 +196,7 @@ def test_step_kernels_gauges_and_falling_loss():
                       devices=jax.devices()[:1])
     state = init_train_state(cfg, mesh, seed=0)
     step = make_train_step(cfg, mesh)
-    tokens, targets = batch(rows=1)
+    tokens, targets = batch(CFG, SEQ, rows=1)
     census = kernel_census(jax.make_jaxpr(
         lambda p: jax.grad(lambda p: evabyte.loss_fn(
             p, cfg, tokens, targets)[0])(p))(state["params"]), a_step=True)
@@ -236,8 +222,8 @@ def test_kernels_per_shard_of_a_mesh(params):
     """Under a mesh the kernels run per shard of the batch and the heads:
     the same loss as on one device."""
     from ray_tpu.parallel import mesh as mesh_mod
-    tokens, targets = batch()
-    want = evabyte.loss_fn(params, FLASH, tokens, targets)[0]
+    tokens, targets = batch(CFG, SEQ)
+    want = compared(EVA, FLASH, SEQ, reference_of=CFG)["loss"][0]
     mesh = build_mesh(MeshConfig(dp=2, fsdp=1, tp=2),
                       devices=jax.devices()[:4])
     previous = mesh_mod.current_mesh()

@@ -2,21 +2,13 @@
 keys an indexer selects, the selection shared by the layers behind the one
 that made it, the indexers' own loss, a chip's share of the experts) against
 a copy of the benchmark's plain reference, which selects with
-``jax.lax.top_k`` on whole rows and attends under an explicit mask; the
-selections themselves; which leaves each term of the loss moves; the 32
-shares of an expert layer adding up to the uncut layer; the sliced head; the
-counters and the gauges; ``lm.scan_blocks`` over the kinds of layer with an
-integer value handed on.
-
-Everything runs on the CPU at tiny widths in float32 under the highest
-matmul precision, the kernels interpreted, where both sides compute the same
-sums in another order: tolerances of 1e-4 (relative, on gradients: of a
-leaf's norm) leave room for float32 reassociation across a few hundred terms
-and nothing else.
+``jax.lax.top_k`` on whole rows and attends under an explicit mask, through
+``family_cases.py``; the selections themselves; which leaves each term of the
+loss moves; the 32 shares of an expert layer adding up to the uncut layer;
+the gauges; an integer value handed on through ``lm.scan_blocks``.
 """
 
 import math
-import zlib
 from dataclasses import replace
 from functools import partial
 
@@ -25,12 +17,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_cases
 import reference_glm_moe_dsa as reference
+from family_cases import batch, by_name, drawn, in_every_run
 from ray_tpu.models import deepseek, glm_moe_dsa, kimi_linear, lm
 from ray_tpu.ops import dsa
 from ray_tpu.parallel import MeshConfig, build_mesh
 from ray_tpu.parallel.train_step import init_train_state, make_train_step
-from ray_tpu.util import metrics as metrics_mod
 
 CFG = glm_moe_dsa.config("glm-tiny")
 SEQ = 64    # the top 24 of up to 64 keys
@@ -68,33 +61,64 @@ def published(cfg):
     return out
 
 
-def drawn(cfg, seed=0):
-    """The init with every vector moved off its one or zero (the correction
-    bias too: routing uneven), and the queries' second matrices larger: at
-    0.02 the main softmax and the indexer's are flat, and which keys a
-    query attends over would move nothing."""
-    params = jax.jit(partial(glm_moe_dsa.init, cfg))(
-        jax.random.PRNGKey(seed))
-    key = jax.random.PRNGKey(seed + 1)
-
-    def moved(path, leaf):
-        name = jax.tree_util.keystr(path)
-        k = jax.random.fold_in(key, zlib.crc32(name.encode()) % (2 ** 31))
-        if name.endswith("_scale']") or "ik_norm_bias" in name:
-            return leaf + 0.2 * jax.random.normal(k, leaf.shape)
-        if "router_bias" in name:
-            return 0.1 * jax.random.normal(k, leaf.shape)
-        if "w_q_b" in name or "w_iq" in name or "w_iw" in name:
-            return 6.0 * leaf
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(moved, params)
+def moved(name, leaf, key):
+    """Every vector off its one or zero (the correction bias too: routing
+    uneven), and the queries' second matrices larger: at 0.02 the main
+    softmax and the indexer's are flat, and which keys a query attends over
+    would move nothing."""
+    if name.endswith("_scale']") or "ik_norm_bias" in name:
+        return leaf + 0.2 * jax.random.normal(key, leaf.shape)
+    if "router_bias" in name:
+        return 0.1 * jax.random.normal(key, leaf.shape)
+    if "w_q_b" in name or "w_iq" in name or "w_iw" in name:
+        return 6.0 * leaf
+    return leaf
 
 
-def batch(cfg, seed=0, rows=2, seq=SEQ):
-    toks = np.random.default_rng(seed).integers(
-        0, cfg.vocab_size, (rows, seq + 1), dtype=np.int32)
-    return jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:])
+_rmsnorm = lm.rmsnorm
+
+
+def drop(dropped, params, cfg, monkeypatch):
+    if dropped == "selection":
+        monkeypatch.setattr(dsa, "select", lambda scores, topk: jnp.tril(
+            jnp.ones(scores.shape, jnp.int8)))
+    elif dropped == "relu":
+        monkeypatch.setattr(jax.nn, "relu", lambda x: x)
+    elif dropped == "index_rope":
+        monkeypatch.setattr(glm_moe_dsa, "_partly_rotated",
+                            lambda x, positions, cfg: x)
+    elif dropped == "shared_selection":
+        plain = glm_moe_dsa._block
+
+        def block(cfg, kind, h, layer, positions, shared):
+            if kind.endswith("shared"):
+                shared = {glm_moe_dsa.SELECTION: jnp.tril(jnp.ones_like(
+                    shared[glm_moe_dsa.SELECTION]))}
+            return plain(cfg, kind, h, layer, positions, shared)
+        monkeypatch.setattr(glm_moe_dsa, "_block", block)
+    elif dropped == "q_norm":
+        params = in_every_run(params, lambda stack: dict(
+            stack, q_norm_scale=jnp.ones_like(stack["q_norm_scale"])))
+    else:
+        monkeypatch.setattr(lm, "rmsnorm", lambda x, scale, eps: x if
+                            scale.shape[-1] == cfg.q_lora_rank else
+                            _rmsnorm(x, scale, eps))
+    return params, cfg
+
+
+GLM = family_cases.Family(
+    module=glm_moe_dsa, reference=reference, cfg=CFG, seq=SEQ, flash=FLASH,
+    flash_seq=FLASH_SEQ, published=published, moved=moved,
+    extras=("picked", "selections"), drop=drop, dropped=(
+        "selection", "relu", "index_rope", "shared_selection", "q_norm",
+        "q_lora_norm"),
+    top_k=CFG.num_experts_per_tok, sliced_vocab=32, accum_steps=(1,),
+    train_drawn=True,
+    wrong=(dict(first_layer=3),                 # shares what no layer makes
+           dict(first_layer=5, num_hidden_layers=5),  # past the depth
+           dict(indexer_types=("full", "none") + ("shared",) * 6),
+           dict(experts_held=(6, 4))))
+globals().update(family_cases.cases(GLM))
 
 
 def program_selections(cfg, params, tokens):
@@ -117,47 +141,20 @@ def program_selections(cfg, params, tokens):
     return seen
 
 
-def compared(cfg, seq):
-    """Program and reference on one batch: logits, both terms of the loss,
-    the routing, the selections and gradients."""
-    params = drawn(cfg)
-    tokens, targets = batch(cfg, seq=seq)
-    kw = reference.arguments(published(cfg))
-    where = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32), tokens.shape)
-    want_logits, want_loss, rms, want_picked, want_sel, want_index = \
-        reference.forward(params, tokens, targets, where, with_picked=True,
-                          with_selections=True, **kw)
+@pytest.fixture(scope="module")
+def selections():
+    tokens, _ = batch(CFG, SEQ)
+    return program_selections(CFG, drawn(GLM, CFG), tokens)
+
+
+@pytest.fixture(scope="module")
+def ce_grads():
+    """The cross-entropy's gradient alone: the indexers' term left out."""
+    tokens, targets = batch(CFG, SEQ)
     with jax.default_matmul_precision("highest"):
-        got_logits, aux = jax.jit(partial(
-            glm_moe_dsa.forward_with_aux, cfg=cfg))(params, tokens=tokens)
-        (got_loss, metrics), got_grads = jax.jit(jax.value_and_grad(
-            lambda p: glm_moe_dsa.loss_fn(p, cfg, tokens, targets),
-            has_aux=True))(params)
-        # The cross-entropy's gradient alone: the indexers' term left out.
-        ce_grads = jax.jit(jax.grad(lambda p: glm_moe_dsa.loss_fn(
-            p, replace(cfg, indexer_loss_coef=0.0), tokens, targets)[0]))(
-                params)
-    want_grads = jax.jit(jax.grad(
-        lambda p: reference.loss(p, tokens, targets, **kw)))(params)
-    return {"logits": (got_logits, want_logits), "rms": float(rms),
-            "loss": (got_loss, want_loss.mean()),
-            "index_loss": (metrics["dsa_index_loss"], want_index.mean()),
-            "metrics": metrics, "seq": seq, "cfg": cfg,
-            "picked": (aux["picked"], want_picked),
-            "selections": (program_selections(cfg, params, tokens)
-                           if cfg.attn_impl == "dot" else None,
-                           np.asarray(want_sel)),
-            "grads": (got_grads, want_grads), "ce_grads": ce_grads}
-
-
-@pytest.fixture(scope="module")
-def both():
-    return compared(CFG, SEQ)
-
-
-@pytest.fixture(scope="module")
-def both_flash():
-    return compared(FLASH, FLASH_SEQ)
+        return jax.jit(jax.grad(lambda p: glm_moe_dsa.loss_fn(
+            p, replace(CFG, indexer_loss_coef=0.0), tokens, targets)[0]))(
+                drawn(GLM, CFG))
 
 
 def test_the_tiny_stack_is_the_published_pattern():
@@ -167,35 +164,30 @@ def test_the_tiny_stack_is_the_published_pattern():
     # The selection's kernels run (interpreted) under ``flash``: a forward
     # call a run, and the head-summed probabilities where an indexer is.
     from ray_tpu.parallel.collectives import kernel_census
-    tokens, targets = batch(FLASH, seq=FLASH_SEQ)
+    tokens, targets = batch(FLASH, FLASH_SEQ)
     census = kernel_census(jax.make_jaxpr(lambda p: glm_moe_dsa.loss_fn(
-        p, replace(FLASH, remat=False), tokens, targets)[0])(drawn(FLASH)))
+        p, replace(FLASH, remat=False), tokens, targets)[0])(
+            drawn(GLM, FLASH)))
     assert census["dsa_fwd"] == 3 and census["dsa_probs"] == 2
 
 
 @pytest.mark.parametrize("which", ["both", "both_flash"])
-def test_logits_both_losses_and_routing_match_the_reference(which, request):
+def test_the_indexers_loss_is_the_references(which, request):
     found = request.getfixturevalue(which)
-    got, want = found["logits"]
-    assert found["rms"] > 0.01
-    np.testing.assert_allclose(got, want, atol=1e-3 * found["rms"])
-    np.testing.assert_allclose(*found["loss"], rtol=1e-5)
-    np.testing.assert_allclose(*found["index_loss"], rtol=1e-4)
-    metrics = found["metrics"]
+    metrics, want = found["metrics"], found["extras"][2].mean()
+    np.testing.assert_allclose(metrics["dsa_index_loss"], want, rtol=1e-4)
     # The second term is there, and ``loss`` stays the cross-entropy.
-    assert float(found["index_loss"][1]) > 1e-3
+    assert float(want) > 1e-3
     np.testing.assert_allclose(
         metrics["total_loss"],
         metrics["loss"] + metrics["dsa_index_loss"], rtol=1e-6)
-    got, want = found["picked"]
-    assert (np.sort(got, -1) == np.sort(want, -1)).all()
 
 
-def test_the_selections_are_top_k_and_shared_as_published(both):
+def test_the_selections_are_top_k_and_shared_as_published(both, selections):
     """Every row keeps exactly ``min(t + 1, k)`` keys, all causal; the
     three layers behind the dense layer attend over its selection bit for
     bit; the last layer makes its own; and all are the reference's."""
-    got, want = both["selections"]
+    got, want = selections, np.asarray(both["extras"][1])
     assert len(got) == 5
     rows = np.minimum(np.arange(SEQ) + 1, CFG.index_topk)
     causal = np.tril(np.ones((SEQ, SEQ), bool))
@@ -218,167 +210,31 @@ def test_the_selected_share_is_the_closed_form(which, request):
                                kept / (seq * (seq + 1) // 2), rtol=1e-6)
 
 
-LEAVES = sorted(jax.tree_util.keystr(path) for path, _ in
-                jax.tree_util.tree_leaves_with_path(
-                    jax.eval_shape(partial(glm_moe_dsa.init, CFG),
-                                   jax.random.PRNGKey(0))))
-
-
-def _leaf(tree, leaf):
-    return dict((jax.tree_util.keystr(p), a) for p, a in
-                jax.tree_util.tree_leaves_with_path(tree))[leaf]
-
-
-@pytest.mark.parametrize("leaf", LEAVES)
-@pytest.mark.parametrize("which", ["both", "both_flash"])
-def test_gradients_match_the_reference(which, leaf, request):
-    found = request.getfixturevalue(which)
-    got, want = (_leaf(tree, leaf) for tree in found["grads"])
-    norm = float(jnp.linalg.norm(want.ravel()))
-    if "router_bias" in leaf:  # selection only: no gradient on either side
-        assert norm == 0.0 and not np.any(got)
-        return
-    assert norm > 0.0
-    assert float(jnp.linalg.norm((got - want).ravel())) < 1e-4 * norm
-
-
-@pytest.mark.parametrize("leaf", LEAVES)
-def test_each_term_of_the_loss_moves_its_own_leaves(both, leaf):
+@pytest.mark.parametrize("leaf", family_cases.leaves(GLM))
+def test_each_term_of_the_loss_moves_its_own_leaves(both, ce_grads, leaf):
     """The indexer's leaves get their gradient from ``L_I`` alone, and no
     other leaf gets any from it."""
-    whole, ce = _leaf(both["grads"][0], leaf), _leaf(both["ce_grads"], leaf)
+    whole, ce = by_name(both["grads"][0])[leaf], by_name(ce_grads)[leaf]
     if any(f"['{name}']" in leaf for name in INDEXER):
         assert not np.any(ce) and np.any(whole)
     else:
         np.testing.assert_array_equal(whole, ce)
 
 
-def _in_every_run(params, change):
-    return {name: change(stack) if name.startswith("run") else stack
-            for name, stack in params.items()}
-
-
-@pytest.mark.parametrize("dropped", [
-    "selection", "relu", "index_rope", "shared_selection", "q_norm",
-    "q_lora_norm"])
-def test_a_dropped_term_shows(both, dropped, monkeypatch):
-    """Each of these, taken out of the program by hand, moves the logits by
-    more than fifty times the comparison's tolerance."""
-    params, cfg = drawn(CFG), CFG
-    tokens, _ = batch(CFG)
-    if dropped == "selection":
-        monkeypatch.setattr(dsa, "select", lambda scores, topk: jnp.tril(
-            jnp.ones(scores.shape, jnp.int8)))
-    elif dropped == "relu":
-        monkeypatch.setattr(jax.nn, "relu", lambda x: x)
-    elif dropped == "index_rope":
-        monkeypatch.setattr(glm_moe_dsa, "_partly_rotated",
-                            lambda x, positions, cfg: x)
-    elif dropped == "shared_selection":
-        plain = glm_moe_dsa._block
-
-        def block(cfg, kind, h, layer, positions, shared):
-            if kind.endswith("shared"):
-                shared = {glm_moe_dsa.SELECTION: jnp.tril(jnp.ones_like(
-                    shared[glm_moe_dsa.SELECTION]))}
-            return plain(cfg, kind, h, layer, positions, shared)
-        monkeypatch.setattr(glm_moe_dsa, "_block", block)
-    elif dropped == "q_norm":
-        params = _in_every_run(params, lambda stack: dict(
-            stack, q_norm_scale=jnp.ones_like(stack["q_norm_scale"])))
-    else:
-        monkeypatch.setattr(lm, "rmsnorm", lambda x, scale, eps: x if
-                            scale.shape[-1] == cfg.q_lora_rank else
-                            _rmsnorm(x, scale, eps))
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(partial(glm_moe_dsa.forward, cfg=cfg))(
-            params, tokens=tokens)
-    want = both["logits"][1]
-    assert float(jnp.abs(got - want).max()) > 0.05 * both["rms"]
-
-
-_rmsnorm = lm.rmsnorm
-
-
 # -- the share ------------------------------------------------------------
-
-def _expert_layer(experts=32, tokens=96, d=32, f=16, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 9)
-    normal = jax.random.normal
-    w = {"ln2_scale": jnp.ones((d,)),
-         "router": normal(ks[0], (d, experts)) / math.sqrt(d),
-         "router_bias": 0.2 * normal(ks[1], (experts,)),
-         "w_gate": normal(ks[2], (experts, d, f)) / math.sqrt(d),
-         "w_up": normal(ks[3], (experts, d, f)) / math.sqrt(d),
-         "w_down": normal(ks[4], (experts, f, d)) / math.sqrt(f),
-         "shared_w_gate": normal(ks[5], (d, f)) / math.sqrt(d),
-         "shared_w_up": normal(ks[6], (d, f)) / math.sqrt(d),
-         "shared_w_down": normal(ks[7], (f, d)) / math.sqrt(f)}
-    return w, normal(ks[8], (1, tokens, d))
-
 
 @pytest.mark.parametrize("shares", [32, 4, 1])
 def test_the_shares_add_up_to_the_uncut_layer(shares):
-    """The routed parts that the shares of an expert layer give (thirty-two
-    of one expert each, as the cell's thirty-two chips; four; one), plus the
-    shared expert once, are the uncut layer of the reference at 8 experts a
-    token; and every share computes exactly the assignments the router gave
-    its experts."""
-    w, h = _expert_layer()
-    top_k, scale, count = 8, 2.5, 32 // shares
-    kw = dict(top_k=top_k, scaling=scale, renormalize=True, eps=0.0,
-              first_expert=0)
-    x = reference._rmsnorm(h, w["ln2_scale"], 0.0)
-    with jax.default_matmul_precision("highest"):
-        want, picked = reference._ffn(h, w, **kw)
-        total, computed = h, 0
-        for first in range(0, 32, count):
-            share = dict(w, **{name: w[name][first:first + count]
-                               for name in ("w_gate", "w_up", "w_down")})
-            routed, shared, aux = lm.expert_ffn(
-                x, share, top_k=top_k, scaling=scale, normalize=True,
-                held=(first, count))
-            mine = ((picked >= first) & (picked < first + count)).sum()
-            assert int(aux["group_sizes"].sum()) == int(mine) \
-                == int(aux.get("asked", mine))
-            # The shared expert is every chip's alike: counted once.
-            total = total + routed + (shared if first == 0 else 0.0)
-            computed += int(mine)
-            ref_part = reference._ffn(h, share, **dict(
-                kw, first_expert=first))[0]
-            np.testing.assert_allclose(h + routed + shared, ref_part,
-                                       atol=5e-5)
-    assert computed == h.shape[1] * top_k
-    np.testing.assert_allclose(total, want, atol=1e-4)
-
-
-def test_the_sliced_heads_loss_is_the_whole_heads_on_the_slice():
-    """A slice of the vocabulary is a smaller vocabulary: on ids of the
-    slice, the cross-entropy of the model that holds the slice's rows of
-    ``wte`` and columns of the head is the whole model's with its logits
-    restricted to those columns (the indexers' term is the same in both)."""
-    held = 32
-    params = drawn(CFG)
-    tokens, targets = batch(replace(CFG, vocab_size=held))
-    sliced = dict(params, wte=params["wte"][:held],
-                  lm_head=params["lm_head"][:, :held])
-    with jax.default_matmul_precision("highest"):
-        _, metrics = jax.jit(lambda p: glm_moe_dsa.loss_fn(
-            p, replace(CFG, vocab_size=held), tokens, targets))(sliced)
-        logits = jax.jit(partial(glm_moe_dsa.forward, cfg=CFG))(
-            params, tokens=tokens)[..., :held]
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    want = -jnp.take_along_axis(logp, targets[..., None], -1).mean()
-    np.testing.assert_allclose(metrics["loss"], want, rtol=1e-6)
-    assert abs(float(metrics["loss"]) - math.log(held)) < 1.0
-    assert float(metrics["moe_routed"]) == tokens.size * 2 * 4
+    """Thirty-two shares of one expert each, as the cell's thirty-two
+    chips; four; one; at 8 experts a token."""
+    family_cases.shares_add_up(reference, 32, shares, top_k=8, scale=2.5)
 
 
 def test_a_mask_weighs_the_indexers_term_by_sequence():
     """``loss_of_hidden`` under a mask of one row is that row's loss, both
     terms (the benchmark's comparison takes a sequence at a time)."""
-    params = drawn(CFG)
-    tokens, targets = batch(CFG)
+    params = drawn(GLM, CFG)
+    tokens, targets = batch(CFG, SEQ)
     hidden, aux = jax.jit(partial(glm_moe_dsa.hidden_states, cfg=CFG))(
         params, tokens=tokens)
     per_row = [glm_moe_dsa.loss_of_hidden(
@@ -420,7 +276,7 @@ def test_a_rank_makes_the_query_two_matrices_and_a_norm():
     assert leaves["w_q_a"][0] == (CFG.hidden_size, CFG.q_lora_rank)
     assert leaves["w_q_b"][0] == (CFG.q_lora_rank, CFG.num_attention_heads,
                                   CFG.qk_nope_head_dim + CFG.qk_rope_head_dim)
-    params = drawn(CFG)
+    params = drawn(GLM, CFG)
     layer = jax.tree.map(lambda a: a[0], params["run00_dense_full"])
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, CFG.hidden_size))
     q, _, _, c_q = lm.mla_qkv(CFG, x, layer, lm.positions_of(x[..., 0]))
@@ -434,72 +290,23 @@ def test_a_rank_makes_the_query_two_matrices_and_a_norm():
         atol=1e-6)
 
 
-# -- the train step, its counters and the gauges ---------------------------
+# -- the train step's gauges ---------------------------------------------------
 
-def _one_chip():
-    return build_mesh(MeshConfig(dp=1, fsdp=1, tp=1),
-                      devices=jax.devices()[:1])
-
-
-def _series(name):
-    for entry in metrics_mod.snapshot():
-        if entry["name"] == name:
-            return sum(entry["series"].values())
-    return 0.0
-
-
-COUNTERS = ("ray_tpu_train_moe_assignments_total",
-            "ray_tpu_train_moe_tokens_total",
-            "ray_tpu_train_moe_routed_total")
-
-
-def test_trains_and_feeds_the_counters_and_the_gauges():
-    """``make_train_step`` finds the model from ``type(cfg)``: both terms
-    fall on a repeated batch (the selection's kernels, remat, the chunked
-    loss, a share of the experts), the counters say what the share did, and
-    the gauges hold the selected share (the closed form) and ``L_I``."""
-    import optax
-    from ray_tpu.parallel.sharding import ShardingRules
-    mesh = _one_chip()
-    rules, optimizer = ShardingRules(), optax.adam(3e-3)
-    state = init_train_state(FLASH, mesh, rules, optimizer, seed=0)
-    state["params"] = drawn(FLASH)
-    step = make_train_step(FLASH, mesh, rules, optimizer)
-    tokens, targets = batch(FLASH, rows=2, seq=FLASH_SEQ)
-    routed = tokens.size * FLASH.num_experts_per_tok * FLASH.n_moe_layers
+def test_the_step_feeds_the_selections_gauges():
+    """Both terms fall on a repeated batch, and the gauges hold the selected
+    share (the closed form) and ``L_I``."""
+    found = family_cases.trained(GLM, 1)
     topk = FLASH.index_topk
     share = (topk * (topk + 1) // 2 + (FLASH_SEQ - topk) * topk) / (
         FLASH_SEQ * (FLASH_SEQ + 1) // 2)
-    before = [_series(name) for name in COUNTERS]
-    losses, index_losses = [], []
-    for _ in range(3):
-        state, metrics = step(state, {"tokens": tokens, "targets": targets})
-        losses.append(float(metrics["loss"]))
-        index_losses.append(float(metrics["dsa_index_loss"]))
-        assert float(metrics["moe_routed"]) == routed
-        assert float(metrics["moe_assignments"]) == \
-            float(metrics["moe_tokens"])
-        assert 0 < float(metrics["moe_tokens"]) < routed
+    index_losses = [m["dsa_index_loss"] for m in found["metrics"]]
+    assert index_losses[-1] < index_losses[0]
+    for metrics in found["metrics"]:
         np.testing.assert_allclose(metrics["dsa_selected_share"], share,
                                    rtol=1e-6)
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    assert index_losses[-1] < index_losses[0]
-    assigned, asked, all_routed = (
-        _series(name) - was for name, was in zip(COUNTERS, before))
-    assert assigned == asked and all_routed in (2 * routed, 3 * routed)
     np.testing.assert_allclose(
-        _series("ray_tpu_train_dsa_selected_share"), share, rtol=1e-6)
-    assert _series("ray_tpu_train_dsa_index_loss") in index_losses
-
-
-def test_expert_parallel_mesh_is_refused():
-    mesh = build_mesh(MeshConfig(dp=1, fsdp=1, tp=1, ep=2),
-                      devices=jax.devices()[:2])
-    step = make_train_step(CFG, mesh)
-    state = init_train_state(CFG, mesh, seed=0)
-    tokens, targets = batch(CFG)
-    with pytest.raises(NotImplementedError, match="expert parallelism"):
-        step(state, {"tokens": tokens, "targets": targets})
+        found["gauges"]["ray_tpu_train_dsa_selected_share"], share, rtol=1e-6)
+    assert found["gauges"]["ray_tpu_train_dsa_index_loss"] in index_losses
 
 
 def test_the_kernels_refuse_a_mesh_of_several_devices():
@@ -507,7 +314,7 @@ def test_the_kernels_refuse_a_mesh_of_several_devices():
                       devices=jax.devices()[:2])
     step = make_train_step(FLASH, mesh)
     state = init_train_state(FLASH, mesh, seed=0)
-    tokens, targets = batch(FLASH, seq=FLASH_SEQ)
+    tokens, targets = batch(FLASH, FLASH_SEQ)
     with pytest.raises(NotImplementedError, match="one\n?\\s*device"):
         step(state, {"tokens": tokens, "targets": targets})
 
@@ -549,8 +356,8 @@ def test_an_integer_value_handed_on_carries_no_cotangent(both):
     same with the blocks rematerialised (the selection kept by name, not
     searched again) as without, and nothing flows back through it."""
     cfg = replace(CFG, remat=True)
-    params = drawn(cfg)
-    tokens, targets = batch(cfg)
+    params = drawn(GLM, cfg)
+    tokens, targets = batch(cfg, SEQ)
     with jax.default_matmul_precision("highest"):
         grads = jax.jit(jax.grad(lambda p: glm_moe_dsa.loss_fn(
             p, cfg, tokens, targets)[0]))(params)
@@ -564,30 +371,6 @@ def test_an_integer_value_handed_on_carries_no_cotangent(both):
     consts = [(v.aval.shape, v.aval.dtype) for v in scans[1].invars[
         :scans[1].params["num_consts"]]]
     assert ((2, SEQ, SEQ), jnp.int8) in consts
-
-
-def test_param_specs_match_init():
-    from ray_tpu.parallel.sharding import ShardingRules
-    params = jax.eval_shape(partial(glm_moe_dsa.init, CFG),
-                            jax.random.PRNGKey(0))
-    specs = glm_moe_dsa.param_specs(CFG, ShardingRules())
-    assert jax.tree.structure(params) == jax.tree.structure(
-        specs, is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
-    for leaf, spec in zip(jax.tree.leaves(params), jax.tree.leaves(
-            specs, is_leaf=lambda s: isinstance(
-                s, jax.sharding.PartitionSpec))):
-        assert len(spec) == leaf.ndim
-
-
-@pytest.mark.parametrize("wrong", [
-    dict(first_layer=3),                      # shares what no layer makes
-    dict(first_layer=5, num_hidden_layers=5),   # past the published depth
-    dict(indexer_types=("full", "none") + ("shared",) * 6),
-    dict(experts_held=(6, 4)),
-])
-def test_config_refuses_what_it_cannot_hold(wrong):
-    with pytest.raises(ValueError):
-        replace(CFG, **wrong)
 
 
 def test_deepseek_takes_a_rank_from_its_config():

@@ -1,6 +1,7 @@
 """models/granite.py (Mamba-2 layers by ops/ssd.py, grouped-query attention
 without positions, the layer scan over two kinds of layer, the tied scaled
-head) against a copy of the benchmark's plain reference; that reference
+head) against a copy of the benchmark's plain reference, through
+``family_cases.py``; that reference
 against ``transformers``' ``GraniteMoeHybridForCausalLM``; the flash kernels
 with grouped KV heads and a model's own score scale; ``lm.scan_blocks`` over
 a mixed ``layer_types``.
@@ -21,7 +22,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_cases
 import reference_granitemoehybrid as reference
+from family_cases import batch, drawn, forward_alone
 from ray_tpu.models import granite, lm
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.parallel import MeshConfig, build_mesh
@@ -43,80 +46,24 @@ def published(cfg):
             "rms_norm_eps": cfg.rms_norm_eps}
 
 
-def drawn(cfg, seed=0):
-    """The init with every vector moved off its one or zero, step sizes
-    small enough that states outlive a chunk, and Wq and Wk eight times
-    larger: at the init's scale every softmax is flat and attention is the
-    running mean of v whichever head it reads."""
-    params = granite.init(cfg, jax.random.PRNGKey(seed))
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-
-    def moved(path, leaf):
-        name = jax.tree_util.keystr(path)
-        if "dt_bias" in name:
-            return leaf - 4.0 + jax.random.normal(next(keys), leaf.shape)
-        if "wq" in name or "wk" in name:
-            return 8.0 * leaf
-        stacked = "run" in name
-        if leaf.ndim == (2 if stacked else 1):
-            return leaf + 0.2 * jax.random.normal(next(keys), leaf.shape)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(moved, params)
+def moved(name, leaf, key):
+    """Every vector off its one or zero, step sizes small enough that
+    states outlive a chunk, and Wq and Wk eight times larger: at the init's
+    scale every softmax is flat and attention is the running mean of v
+    whichever head it reads."""
+    if "dt_bias" in name:
+        return leaf - 4.0 + jax.random.normal(key, leaf.shape)
+    if "wq" in name or "wk" in name:
+        return 8.0 * leaf
+    if leaf.ndim == (2 if "run" in name else 1):
+        return leaf + 0.2 * jax.random.normal(key, leaf.shape)
+    return leaf
 
 
-def batch(cfg, seed=0, rows=2, seq=SEQ):
-    toks = np.random.default_rng(seed).integers(
-        0, cfg.vocab_size, (rows, seq + 1), dtype=np.int32)
-    return jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:])
-
-
-@pytest.fixture(scope="module")
-def both():
-    """Program and reference on one batch: logits, loss and gradients."""
-    params = drawn(CFG)
-    tokens, targets = batch(CFG)
-    kw = reference.arguments(published(CFG))
-    where = jnp.broadcast_to(jnp.arange(SEQ, dtype=jnp.int32), tokens.shape)
-    want_logits, want_loss, rms = reference.forward(
-        params, tokens, targets, where, **kw)
-    with jax.default_matmul_precision("highest"):
-        got_logits = jax.jit(partial(granite.forward, cfg=CFG))(
-            params, tokens=tokens)
-        got_loss, got_grads = jax.jit(jax.value_and_grad(
-            lambda p: granite.loss_fn(p, CFG, tokens, targets)[0]))(params)
-    want_grads = jax.grad(
-        lambda p: reference.loss(p, tokens, targets, **kw))(params)
-    return {"logits": (got_logits, want_logits), "rms": float(rms),
-            "loss": (got_loss, want_loss.mean()),
-            "grads": (got_grads, want_grads)}
-
-
-def test_logits_match_the_reference(both):
-    got, want = both["logits"]
-    assert both["rms"] > 0.01
-    np.testing.assert_allclose(got, want, atol=1e-3 * both["rms"])
-
-
-def test_loss_matches_the_reference(both):
-    got, want = both["loss"]
-    np.testing.assert_allclose(got, want, rtol=1e-5)
-
-
-LEAVES = sorted(jax.tree_util.keystr(path) for path, _ in
-                jax.tree_util.tree_leaves_with_path(
-                    jax.eval_shape(partial(granite.init, CFG),
-                                   jax.random.PRNGKey(0))))
-
-
-@pytest.mark.parametrize("leaf", LEAVES)
-def test_gradients_match_the_reference(both, leaf):
-    got, want = (dict((jax.tree_util.keystr(p), a) for p, a in
-                      jax.tree_util.tree_leaves_with_path(tree))[leaf]
-                 for tree in both["grads"])
-    norm = float(jnp.linalg.norm(want.ravel()))
-    assert norm > 0.0
-    assert float(jnp.linalg.norm((got - want).ravel())) < 1e-4 * norm
+GRANITE = family_cases.Family(
+    module=granite, reference=reference, cfg=CFG, seq=SEQ,
+    published=published, moved=moved)
+globals().update(family_cases.cases(GRANITE))
 
 
 @pytest.mark.parametrize("dropped", ["D", "conv_b", "gate", "residual",
@@ -124,9 +71,10 @@ def test_gradients_match_the_reference(both, leaf):
 def test_a_dropped_term_shows(both, dropped):
     """Each of the terms a fast path could lose moves the logits by far
     more than the agreement above allows."""
-    params = drawn(CFG)
-    tokens, _ = batch(CFG)
+    params = drawn(GRANITE, CFG)
+    tokens, _ = batch(CFG, SEQ)
     cfg = CFG
+
     def changed(kind, change):
         return dict(params, **{
             run: change(dict(params[run]))
@@ -145,8 +93,7 @@ def test_a_dropped_term_shows(both, dropped):
     else:
         params = changed("attention", lambda w: dict(
             w, wk=w["wk"][:, :, ::-1], wv=w["wv"][:, :, ::-1]))
-    with jax.default_matmul_precision("highest"):
-        got = granite.forward(params, cfg, tokens)
+    got = forward_alone(GRANITE, params, cfg, tokens)
     err = float(jnp.sqrt(((got - both["logits"][1]) ** 2).mean()))
     assert err > 0.01 * both["rms"], (dropped, err, both["rms"])
 
@@ -167,7 +114,7 @@ def test_the_reference_is_the_published_implementation(monkeypatch):
         pytest.skip("this transformers has no granitemoehybrid")
     cfg = replace(CFG, mamba_chunk_size=64)
     seq = 160
-    params = drawn(cfg, seed=3)
+    params = drawn(GRANITE, cfg, seed=3)
     hf_config = GraniteMoeHybridConfig(
         vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
         intermediate_size=cfg.shared_intermediate_size,
@@ -226,7 +173,7 @@ def test_the_reference_is_the_published_implementation(monkeypatch):
                 w["wo"].reshape(-1, d).T)
     missing, unexpected = model.load_state_dict(state, strict=False)
     assert not unexpected and not missing, (missing, unexpected)
-    tokens, targets = batch(cfg, seed=5, rows=2, seq=seq)
+    tokens, targets = batch(cfg, seq, seed=5)
     with torch.no_grad():
         want = model(torch.tensor(np.asarray(tokens, np.int64))
                      ).logits.numpy()
@@ -319,7 +266,7 @@ def test_trains_through_the_train_step_typed_to_no_model():
     rules, optimizer = ShardingRules(), optax.adam(3e-3)
     state = init_train_state(cfg, mesh, rules, optimizer, seed=0)
     step = make_train_step(cfg, mesh, rules, optimizer)
-    tokens, targets = batch(cfg, rows=1)
+    tokens, targets = batch(cfg, SEQ, rows=1)
     losses = []
     for _ in range(3):
         state, metrics = step(state, {"tokens": tokens, "targets": targets})

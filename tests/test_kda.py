@@ -176,17 +176,17 @@ def test_no_factor_overflows_where_exp_of_minus_cum_would():
     assert float(kda.decay_floor(args[3], 64)) == pytest.approx(
         float(cum.min()), rel=1e-6)
     low = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
-    out = kda.kda(*low, chunk=64)
-    grads = jax.grad(lambda *a: kda.kda(*a, chunk=64).astype(
-        jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))(*low)
+    out = jax.jit(partial(kda.kda, chunk=64))(*low)
+    grads = jax.jit(jax.grad(lambda *a: kda.kda(*a, chunk=64).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))(*low)
     assert out.dtype == jnp.bfloat16
     assert all(bool(jnp.isfinite(x.astype(jnp.float32)).all())
                for x in (out,) + grads)
     # bfloat16 inputs take the inverse's products at sixteen bits: output
     # and gradients stay where bfloat16 operands put them, 0.4 %.
-    want = kda.kda_recurrent(*low)
-    want_grads = jax.grad(lambda *a: kda.kda_recurrent(*a).sum(),
-                          argnums=(0, 1, 2, 3, 4))(*low)
+    want = jax.jit(kda.kda_recurrent)(*low)
+    want_grads = jax.jit(jax.grad(lambda *a: kda.kda_recurrent(*a).sum(),
+                                  argnums=(0, 1, 2, 3, 4)))(*low)
     for got, ref in zip((out,) + grads, (want,) + want_grads):
         assert_close(got.astype(jnp.float32), ref, tol=0.02)
 

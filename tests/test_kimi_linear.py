@@ -1,35 +1,25 @@
 """models/kimi_linear.py (Kimi Delta Attention layers through ``ops/kda.py``,
 a latent-attention layer without positions from ``models/deepseek.py``, a
 chip's share of the experts) against a copy of the benchmark's plain
-reference, whose delta rule is the literal recurrence; the eight shares of an
-expert layer adding up to the uncut layer; the sliced head; ``mla_use_nope``
-off being ``models/deepseek.py`` as it was; the counters and the decay's
-gauge; ``lm.scan_blocks`` over the kinds of layer.
-
-Everything runs on the CPU at tiny widths in float32 under the highest
-matmul precision, the kernels interpreted, where both sides compute the same
-sums in another order: tolerances of 1e-4 (relative, on gradients: of a
-leaf's norm) leave room for float32 reassociation across a few hundred terms
-and nothing else.
+reference, whose delta rule is the literal recurrence, through
+``family_cases.py``; the eight shares of an expert layer adding up to the
+uncut layer; ``mla_use_nope`` off being ``models/deepseek.py`` as it was; the
+decay's gauge; the cut configuration's four runs.
 """
 
 import math
-import zlib
 from dataclasses import replace
 from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
+import family_cases
 import reference_kimi_linear as reference
+from family_cases import batch, drawn, in_every_run
 from ray_tpu.models import deepseek, kimi_linear, lm
 from ray_tpu.ops import kda
-from ray_tpu.ops.moe import routed_experts
-from ray_tpu.parallel import MeshConfig, build_mesh
-from ray_tpu.parallel.train_step import init_train_state, make_train_step
-from ray_tpu.util import metrics as metrics_mod
 
 CFG = kimi_linear.config("kimi-linear-tiny")
 SEQ = 128   # one chunk of the delta rule's kernels; FLASH_SEQ is two
@@ -60,112 +50,18 @@ def published(cfg):
     return out
 
 
-def drawn(cfg, seed=0):
-    """The init with every vector moved off its one or zero (the correction
-    bias too: routing uneven). The decay's vectors are drawn by the init."""
-    params = kimi_linear.init(cfg, jax.random.PRNGKey(seed))
-    key = jax.random.PRNGKey(seed + 1)
-
-    def moved(path, leaf):
-        name = jax.tree_util.keystr(path)
-        k = jax.random.fold_in(key, zlib.crc32(name.encode()) % (2 ** 31))
-        if name.endswith("_scale']"):
-            return leaf + 0.2 * jax.random.normal(k, leaf.shape)
-        if "router_bias" in name:
-            return 0.1 * jax.random.normal(k, leaf.shape)
-        if "_mla']['wq']" in name or "w_kv_a" in name:
-            # Scores that spread: at 0.02 a latent layer's softmax is flat
-            # and a rotation of q and k would move nothing.
-            return 8.0 * leaf
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
-def batch(cfg, seed=0, rows=2, seq=SEQ):
-    toks = np.random.default_rng(seed).integers(
-        0, cfg.vocab_size, (rows, seq + 1), dtype=np.int32)
-    return jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:])
-
-
-def compared(cfg, seq):
-    """Program and reference on one batch: logits, loss and gradients."""
-    params = drawn(cfg)
-    tokens, targets = batch(cfg, seq=seq)
-    kw = reference.arguments(published(cfg))
-    where = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32), tokens.shape)
-    want_logits, want_loss, rms, want_picked = reference.forward(
-        params, tokens, targets, where, with_picked=True, **kw)
-    with jax.default_matmul_precision("highest"):
-        got_logits, aux = jax.jit(partial(
-            kimi_linear.forward_with_aux, cfg=cfg))(params, tokens=tokens)
-        got_loss, got_grads = jax.jit(jax.value_and_grad(
-            lambda p: kimi_linear.loss_fn(p, cfg, tokens, targets)[0]))(
-                params)
-    want_grads = jax.grad(
-        lambda p: reference.loss(p, tokens, targets, **kw))(params)
-    return {"logits": (got_logits, want_logits), "rms": float(rms),
-            "loss": (got_loss, want_loss.mean()),
-            "picked": (aux["picked"], want_picked),
-            "grads": (got_grads, want_grads)}
-
-
-@pytest.fixture(scope="module")
-def both():
-    return compared(CFG, SEQ)
-
-
-@pytest.fixture(scope="module")
-def both_flash():
-    return compared(FLASH, FLASH_SEQ)
-
-
-def test_the_tiny_stack_has_all_four_kinds_of_layer():
-    assert [kind for _, kind, _ in lm.runs(CFG.layers)] == [
-        "dense_kda", "dense_mla", "moe_kda", "moe_mla"]
-    # The delta rule's kernels run (interpreted): heads of 128, whole chunks.
-    from ray_tpu.parallel.collectives import kernel_census
-    tokens, _ = batch(CFG)
-    census = kernel_census(jax.make_jaxpr(partial(
-        kimi_linear.forward, cfg=CFG))(drawn(CFG), tokens=tokens))
-    assert census["kda_fwd"] == 2   # a call a run of KDA layers
-
-
-@pytest.mark.parametrize("which", ["both", "both_flash"])
-def test_logits_loss_and_routing_match_the_reference(which, request):
-    found = request.getfixturevalue(which)
-    got, want = found["logits"]
-    assert found["rms"] > 0.01
-    np.testing.assert_allclose(got, want, atol=1e-3 * found["rms"])
-    np.testing.assert_allclose(*found["loss"], rtol=1e-5)
-    got, want = found["picked"]
-    assert (np.sort(got, -1) == np.sort(want, -1)).all()
-
-
-LEAVES = sorted(jax.tree_util.keystr(path) for path, _ in
-                jax.tree_util.tree_leaves_with_path(
-                    jax.eval_shape(partial(kimi_linear.init, CFG),
-                                   jax.random.PRNGKey(0))))
-
-
-@pytest.mark.parametrize("leaf", LEAVES)
-@pytest.mark.parametrize("which", ["both", "both_flash"])
-def test_gradients_match_the_reference(which, leaf, request):
-    found = request.getfixturevalue(which)
-    got, want = (dict((jax.tree_util.keystr(p), a) for p, a in
-                      jax.tree_util.tree_leaves_with_path(tree))[leaf]
-                 for tree in found["grads"])
-    norm = float(jnp.linalg.norm(want.ravel()))
-    if "router_bias" in leaf:  # selection only: no gradient on either side
-        assert norm == 0.0 and not np.any(got)
-        return
-    assert norm > 0.0
-    assert float(jnp.linalg.norm((got - want).ravel())) < 1e-4 * norm
-
-
-def _in_every_run(params, cfg, change):
-    return dict(params, **{run: change(dict(params[run]))
-                           for run, _, _ in lm.runs(cfg.layers)})
+def moved(name, leaf, key):
+    """Every vector off its one or zero (the correction bias too: routing
+    uneven). The decay's vectors are drawn by the init."""
+    if name.endswith("_scale']"):
+        return leaf + 0.2 * jax.random.normal(key, leaf.shape)
+    if "router_bias" in name:
+        return 0.1 * jax.random.normal(key, leaf.shape)
+    if "_mla']['wq']" in name or "w_kv_a" in name:
+        # Scores that spread: at 0.02 a latent layer's softmax is flat
+        # and a rotation of q and k would move nothing.
+        return 8.0 * leaf
+    return leaf
 
 
 def _kda_with(monkeypatch, **changed):
@@ -181,14 +77,7 @@ def _kda_with(monkeypatch, **changed):
     monkeypatch.setattr(lm, "delta_rule", patched)
 
 
-@pytest.mark.parametrize("dropped", [
-    "delta_term", "decay", "beta", "qk_norm", "conv", "output_gate",
-    "rope_on_mla", "routed_scaling_factor", "shared_expert"])
-def test_a_dropped_term_shows(both, dropped, monkeypatch):
-    """Each of the terms a fast path could lose moves the logits by far
-    more than the agreement above allows."""
-    params, cfg = drawn(CFG), CFG
-    tokens, _ = batch(CFG)
+def drop(dropped, params, cfg, monkeypatch):
     if dropped == "delta_term":
         # S += beta k v^T alone: an additive state, as ops/ssd.py's.
         def additive(q, k, v, a, beta):
@@ -214,93 +103,57 @@ def test_a_dropped_term_shows(both, dropped, monkeypatch):
                             jax.nn.silu(x.astype(jnp.float32)).astype(x.dtype))
     elif dropped == "output_gate":
         # sigmoid(0): a constant, where the gate differs a channel.
-        params = _in_every_run(params, CFG, lambda w: dict(
+        params = in_every_run(params, lambda w: dict(
             w, w_gb=jnp.zeros_like(w["w_gb"])) if "w_gb" in w else w)
     elif dropped == "rope_on_mla":
-        cfg = replace(CFG, mla_use_nope=False)
+        cfg = replace(cfg, mla_use_nope=False)
     elif dropped == "routed_scaling_factor":
-        cfg = replace(CFG, routed_scaling_factor=1.0)
+        cfg = replace(cfg, routed_scaling_factor=1.0)
     elif dropped == "shared_expert":
-        params = _in_every_run(params, CFG, lambda w: dict(
+        params = in_every_run(params, lambda w: dict(
             w, shared_w_down=jnp.zeros_like(w["shared_w_down"]))
             if "router" in w else w)
-    with jax.default_matmul_precision("highest"):
-        got = kimi_linear.forward(params, cfg, tokens)
-    _, want = both["logits"]
-    assert float(jnp.abs(got - want).max()) > 0.05 * both["rms"]
+    return params, cfg
 
 
-# -- the share ------------------------------------------------------------
+KIMI = family_cases.Family(
+    module=kimi_linear, reference=reference, cfg=CFG, seq=SEQ, flash=FLASH,
+    flash_seq=FLASH_SEQ, published=published, moved=moved,
+    extras=("picked",), drop=drop, dropped=(
+        "delta_term", "decay", "beta", "qk_norm", "conv", "output_gate",
+        "rope_on_mla", "routed_scaling_factor", "shared_expert"),
+    top_k=CFG.num_experts_per_token, accum_steps=(1,), scan_atol=1e-4,
+    wrong=({"experts_held": (6, 4)}, {"experts_held": (0, 0)},
+           {"num_hidden_layers": 6},
+           {"linear_attn_config": {"kda_layers": [1, 2],
+                                   "full_attn_layers": [2]}}))
+globals().update(family_cases.cases(KIMI))
 
-def _expert_layer(experts=16, tokens=96, d=32, f=16, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 9)
-    normal = jax.random.normal
-    w = {"ln2_scale": jnp.ones((d,)),
-         "router": normal(ks[0], (d, experts)) / math.sqrt(d),
-         "router_bias": 0.2 * normal(ks[1], (experts,)),
-         "w_gate": normal(ks[2], (experts, d, f)) / math.sqrt(d),
-         "w_up": normal(ks[3], (experts, d, f)) / math.sqrt(d),
-         "w_down": normal(ks[4], (experts, f, d)) / math.sqrt(f),
-         "shared_w_gate": normal(ks[5], (d, f)) / math.sqrt(d),
-         "shared_w_up": normal(ks[6], (d, f)) / math.sqrt(d),
-         "shared_w_down": normal(ks[7], (f, d)) / math.sqrt(f)}
-    return w, normal(ks[8], (1, tokens, d))
+
+def test_the_tiny_stack_has_all_four_kinds_of_layer(both):
+    assert [kind for _, kind, _ in lm.runs(CFG.layers)] == [
+        "dense_kda", "dense_mla", "moe_kda", "moe_mla"]
+    # The delta rule's kernels run (interpreted): heads of 128, whole chunks.
+    from ray_tpu.parallel.collectives import kernel_census
+    tokens, _ = batch(CFG, SEQ)
+    census = kernel_census(jax.make_jaxpr(partial(
+        kimi_linear.forward, cfg=CFG))(drawn(KIMI, CFG), tokens=tokens))
+    assert census["kda_fwd"] == 2   # a call a run of KDA layers
+    # A delta-rule layer's running log-decay is negative, a latent layer's 0.
+    assert [float(floor) < 0 for floor in both["aux"]["decay_floor"]] == [
+        kind.endswith("kda") for kind in CFG.layers]
+    assert both["aux"]["group_sizes"].shape == (CFG.n_moe_layers,
+                                                CFG.num_experts)
+    shapes = jax.eval_shape(partial(kimi_linear.init, FLASH),
+                            jax.random.PRNGKey(0))
+    assert shapes["run02_moe_kda"]["w_up"].shape[1] == 3
 
 
 @pytest.mark.parametrize("shares", [8, 2, 1])
 def test_the_shares_add_up_to_the_uncut_layer(shares):
-    """The routed parts that the shares of an expert layer give (eight of 2
-    experts each, as the cell's eight chips; two; one), plus the shared
-    expert once, are the uncut layer of the reference at 8 experts a token;
-    and every share computes exactly the assignments the router gave its
-    experts."""
-    w, h = _expert_layer()
-    top_k, scale, count = 8, 2.446, 16 // shares
-    kw = dict(top_k=top_k, scaling=scale, renormalize=True, eps=0.0,
-              first_expert=0)
-    x = reference._rmsnorm(h, w["ln2_scale"], 0.0)
-    with jax.default_matmul_precision("highest"):
-        want, picked = reference._ffn(h, w, **kw)
-        total, computed = h, 0
-        for first in range(0, 16, count):
-            share = dict(w, **{name: w[name][first:first + count]
-                               for name in ("w_gate", "w_up", "w_down")})
-            routed, shared, aux = lm.expert_ffn(
-                x, share, top_k=top_k, scaling=scale, normalize=True,
-                held=(first, count))
-            mine = ((picked >= first) & (picked < first + count)).sum()
-            assert int(aux["group_sizes"].sum()) == int(mine) \
-                == int(aux.get("asked", mine))
-            # The shared expert is every chip's alike: counted once.
-            total = total + routed + (shared if first == 0 else 0.0)
-            computed += int(mine)
-            ref_part = reference._ffn(h, share, **dict(
-                kw, first_expert=first))[0]
-            np.testing.assert_allclose(h + routed + shared, ref_part,
-                                       atol=5e-5)
-    assert computed == h.shape[1] * top_k
-    np.testing.assert_allclose(total, want, atol=1e-4)
-
-
-def test_the_sliced_heads_loss_is_the_whole_heads_on_the_slice():
-    """A slice of the vocabulary is a smaller vocabulary: on ids of the
-    slice, the loss of the model that holds the slice's rows of ``wte`` and
-    columns of the head is the whole model's with its logits restricted to
-    those columns."""
-    held = 64
-    params = drawn(CFG)
-    tokens, targets = batch(replace(CFG, vocab_size=held))
-    sliced = dict(params, wte=params["wte"][:held],
-                  lm_head=params["lm_head"][:, :held])
-    with jax.default_matmul_precision("highest"):
-        got, metrics = kimi_linear.loss_fn(
-            sliced, replace(CFG, vocab_size=held), tokens, targets)
-        logits = kimi_linear.forward(params, CFG, tokens)[..., :held]
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    want = -jnp.take_along_axis(logp, targets[..., None], -1).mean()
-    np.testing.assert_allclose(got, want, rtol=1e-6)
-    assert abs(float(got) - math.log(held)) < 1.0
-    assert float(metrics["moe_routed"]) == tokens.size * 2 * 3
+    """Eight shares of 2 experts each, as the cell's eight chips; two; one;
+    at 8 experts a token."""
+    family_cases.shares_add_up(reference, 16, shares, top_k=8, scale=2.446)
 
 
 # -- models/deepseek.py, shared -------------------------------------------
@@ -339,68 +192,18 @@ def test_mla_use_nope_off_is_deepseek_as_it_was():
     assert float(jnp.abs(without - got).max()) > 1e-3
 
 
-# -- the train step, its counters and the gauge ----------------------------
+# -- the train step's gauge ---------------------------------------------------
 
-def _one_chip():
-    return build_mesh(MeshConfig(dp=1, fsdp=1, tp=1),
-                      devices=jax.devices()[:1])
-
-
-def _series(name):
-    for entry in metrics_mod.snapshot():
-        if entry["name"] == name:
-            return sum(entry["series"].values())
-    return 0.0
+def test_the_step_feeds_the_decays_gauge():
+    """The gauge holds the most negative running log-decay of the step: 128
+    steps of at most -1.6, and of at least -0.001 in some channel."""
+    found = family_cases.trained(KIMI, 1)
+    for metrics in found["metrics"]:
+        assert -205.0 < metrics["kda_decay_floor"] < -1.0
+    assert -205.0 < found["gauges"]["ray_tpu_train_kda_decay_floor"] < -1.0
 
 
-COUNTERS = ("ray_tpu_train_moe_assignments_total",
-            "ray_tpu_train_moe_tokens_total",
-            "ray_tpu_train_moe_routed_total")
-
-
-def test_trains_and_feeds_the_counters_and_the_gauge():
-    """``make_train_step`` finds the model from ``type(cfg)``: the loss
-    falls on a repeated batch (both kernel pairs, remat, the chunked loss, a
-    share of the experts), the counters say what the share did, and the
-    gauge holds the most negative running log-decay of the step."""
-    import optax
-    from ray_tpu.parallel.sharding import ShardingRules
-    mesh = _one_chip()
-    rules, optimizer = ShardingRules(), optax.adam(3e-3)
-    state = init_train_state(FLASH, mesh, rules, optimizer, seed=0)
-    step = make_train_step(FLASH, mesh, rules, optimizer)
-    tokens, targets = batch(FLASH, rows=2, seq=FLASH_SEQ)
-    routed = tokens.size * FLASH.num_experts_per_token * FLASH.n_moe_layers
-    before = [_series(name) for name in COUNTERS]
-    losses = []
-    for _ in range(3):
-        state, metrics = step(state, {"tokens": tokens, "targets": targets})
-        losses.append(float(metrics["loss"]))
-        assert float(metrics["moe_routed"]) == routed
-        assert float(metrics["moe_assignments"]) == \
-            float(metrics["moe_tokens"])
-        assert 0 < float(metrics["moe_tokens"]) < routed
-        # 128 steps of at most -1.6, and of at least -0.001 in some channel.
-        assert -205.0 < float(metrics["kda_decay_floor"]) < -1.0
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    assigned, asked, all_routed = (
-        _series(name) - was for name, was in zip(COUNTERS, before))
-    assert assigned == asked and all_routed in (2 * routed, 3 * routed)
-    assert 0.2 < asked / all_routed < 0.6
-    assert -205.0 < _series("ray_tpu_train_kda_decay_floor") < -1.0
-
-
-def test_expert_parallel_mesh_is_refused():
-    mesh = build_mesh(MeshConfig(dp=1, fsdp=1, tp=1, ep=2),
-                      devices=jax.devices()[:2])
-    step = make_train_step(CFG, mesh)
-    state = init_train_state(CFG, mesh, seed=0)
-    tokens, targets = batch(CFG)
-    with pytest.raises(NotImplementedError, match="expert parallelism"):
-        step(state, {"tokens": tokens, "targets": targets})
-
-
-# -- the layer scan over the runs -----------------------------------------
+# -- the cut configuration ------------------------------------------------
 
 CUT = replace(kimi_linear.config("kimi-linear-48b-a3b"), num_hidden_layers=5,
               experts_held=(0, 32), vocab_size=20480)
@@ -425,65 +228,16 @@ def test_the_cut_configuration_is_four_runs():
         whole.layers.count("moe_mla") == 7 and whole.layers[0] == "dense_kda"
 
 
-@pytest.mark.parametrize("remat", [False, True])
-def test_scan_blocks_over_the_runs(remat):
-    """The runs scanned, one stack a run, are the layers applied one by one
-    in order: hidden states and the layers' auxiliary outputs."""
-    cfg = replace(CFG, remat=remat)
-    params = drawn(cfg)
-    tokens, _ = batch(cfg)
-    with jax.default_matmul_precision("highest"):
-        got, aux = kimi_linear.hidden_states(params, cfg, tokens)
-        x = lm.embed(params["wte"], tokens, cfg.dtype)
-        picked, floors = [], []
-        for run, kind, depth in lm.runs(cfg.layers):
-            for j in range(depth):
-                x, one = kimi_linear._block(cfg, kind, x, jax.tree.map(
-                    lambda a: a[j], params[run]), lm.positions_of(tokens))
-                floors.append(one["decay_floor"])
-                if "picked" in one:
-                    picked.append(one["picked"])
-    want = lm.rmsnorm(x, params["lnf_scale"], cfg.rms_norm_eps)
-    np.testing.assert_allclose(got, want, atol=1e-4)
-    assert (aux["picked"] == jnp.stack(picked)).all()
-    np.testing.assert_allclose(aux["decay_floor"], jnp.stack(floors),
-                               rtol=1e-6)
-    assert [float(f) < 0 for f in floors] == [
-        kind.endswith("kda") for kind in cfg.layers]
-    assert aux["group_sizes"].shape == (cfg.n_moe_layers, cfg.num_experts)
-
-
-def test_param_specs_match_init():
-    from ray_tpu.parallel.sharding import ShardingRules
-    for cfg in (CFG, FLASH):
-        params = jax.eval_shape(partial(kimi_linear.init, cfg),
-                                jax.random.PRNGKey(0))
-        specs = kimi_linear.param_specs(cfg, ShardingRules())
-        assert jax.tree.structure(params) == jax.tree.structure(
-            specs, is_leaf=lambda s: isinstance(
-                s, jax.sharding.PartitionSpec))
-    assert params["run02_moe_kda"]["w_up"].shape[1] == 3
-
-
 def test_the_decay_starts_as_published():
     """``A_log`` = log U(1, 16) a head and ``dt_bias`` the inverse softplus
     of a log-uniform (0.001, 0.1) a channel: log-decays from -0.001 to -1.6
     a step where the projection adds nothing."""
-    stack = drawn(CFG)["run02_moe_kda"]
+    stack = drawn(KIMI, CFG)["run02_moe_kda"]
     rate = jnp.exp(stack["A_log"])
     dt = jax.nn.softplus(stack["dt_bias"])
     assert 1.0 <= float(rate.min()) and float(rate.max()) <= 16.0
     assert 0.000999 < float(dt.min()) and float(dt.max()) < 0.1001
     assert stack["dt_bias"].shape[1:] == (2, 128)
-
-
-@pytest.mark.parametrize("wrong", [
-    {"experts_held": (6, 4)}, {"experts_held": (0, 0)},
-    {"num_hidden_layers": 6},
-    {"linear_attn_config": {"kda_layers": [1, 2], "full_attn_layers": [2]}}])
-def test_config_refuses_what_it_cannot_hold(wrong):
-    with pytest.raises(ValueError):
-        replace(CFG, **wrong)
 
 
 def test_a_published_config_reads_straight_in():

@@ -14,7 +14,6 @@ leave room for float32 reassociation and nothing else.
 import math
 import os
 import sys
-import zlib
 from dataclasses import replace
 from functools import partial
 
@@ -24,6 +23,7 @@ import numpy as np
 import pytest
 
 import reference_minicpm_sala as reference
+from family_cases import Family, batch, compared, drawn, forward_alone
 from ray_tpu.models import lm, minicpm_sala
 from ray_tpu.parallel import MeshConfig, build_mesh
 from ray_tpu.parallel.collectives import kernel_census
@@ -56,45 +56,32 @@ def published(cfg):
             "dense_len": cfg.sparse_dense_len}}}
 
 
-def drawn(cfg, seed=0):
-    """The init with every norm's scale moved off one, the q/k norms' scales
-    larger (at one both softmaxes are nearly flat and a wrong selection
-    would move nothing) and W_o larger (the mixers' branches beside the
-    SwiGLU's)."""
-    params = jax.jit(partial(minicpm_sala.init, cfg))(
-        jax.random.PRNGKey(seed))
-    key = jax.random.PRNGKey(seed + 1)
-
-    def moved(path, leaf):
-        name = path[-1].key
-        k = jax.random.fold_in(key, zlib.crc32(
-            "/".join(p.key for p in path).encode()))
-        if name.endswith("_scale"):
-            leaf = leaf + 0.1 * jax.random.normal(k, leaf.shape)
-        if name in ("q_norm_scale", "k_norm_scale"):
-            return leaf * 1.7
-        return leaf * 4.0 if name == "wo" else leaf
-
-    return jax.tree_util.tree_map_with_path(moved, params)
+def moved(name, leaf, key):
+    """Every norm's scale off one, the q/k norms' scales larger (at one both
+    softmaxes are nearly flat and a wrong selection would move nothing) and
+    W_o larger (the mixers' branches beside the SwiGLU's)."""
+    if name.endswith("_scale']"):
+        leaf = leaf + 0.1 * jax.random.normal(key, leaf.shape)
+    if name.endswith(("['q_norm_scale']", "['k_norm_scale']")):
+        return leaf * 1.7
+    return leaf * 4.0 if name.endswith("['wo']") else leaf
 
 
-def batch(seq, seed=0, rows=1):
-    toks = np.random.default_rng(seed).integers(
-        0, CFG.vocab_size, (rows, seq + 1), dtype=np.int32)
-    return jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:])
+SALA = Family(module=minicpm_sala, reference=reference, cfg=CFG, seq=OVER,
+              published=published, moved=moved, rows=1)
 
 
 @pytest.fixture(scope="module")
 def params():
-    return drawn(CFG)
+    return drawn(SALA, CFG)
 
 
 @pytest.fixture(scope="module", params=[OVER, UNDER], ids=["over", "under"])
 def want(request, params):
-    """The reference's logits, loss and gradients at a length over
-    ``dense_len`` and one under it."""
+    """The reference's logits over the whole mask and the loss of them, at
+    a length over ``dense_len`` and one under it."""
     seq = request.param
-    tokens, targets = batch(seq)
+    tokens, targets = batch(CFG, seq, rows=1)
     kw = reference.arguments(published(CFG))
 
     def loss_and_logits(p):
@@ -104,41 +91,36 @@ def want(request, params):
         return nll.mean(), logits
 
     # One program: op by op the same sums take five times as long here.
-    (loss, logits), grads = jax.jit(jax.value_and_grad(
-        loss_and_logits, has_aux=True))(params)
-    return {"seq": seq, "logits": logits, "loss": loss, "grads": grads}
+    loss, logits = jax.jit(loss_and_logits)(params)
+    return {"seq": seq, "logits": logits, "loss": loss}
 
 
 @pytest.mark.parametrize("cfg", [CFG, FLASH], ids=["dot", "flash"])
-def test_model_matches_reference(cfg, params, want):
+def test_model_matches_reference(cfg, want):
     """Logits within 1e-3 of their RMS, the loss, every gradient leaf (the
     q/k norms' scales and the output norm's among them, none zero)."""
-    tokens, targets = batch(want["seq"])
-    with jax.default_matmul_precision("highest"):
-        logits = jax.jit(lambda p: minicpm_sala.forward(p, cfg, tokens))(
-            params)
-        (loss, metrics), grads = jax.jit(jax.value_and_grad(
-            lambda p: minicpm_sala.loss_fn(p, cfg, tokens, targets),
-            has_aux=True))(params)
+    found = compared(SALA, cfg, want["seq"], reference_of=CFG)
+    logits, want_logits = found["logits"]
     assert logits.shape == (1, want["seq"], CFG.vocab_size)
     assert logits.dtype == jnp.float32
-    rms = float(jnp.sqrt((want["logits"] ** 2).mean()))
-    assert float(jnp.abs(logits - want["logits"]).max()) < 1e-3 * rms
-    assert abs(float(loss) - float(want["loss"])) < 1e-5
-    ref = dict(jax.tree_util.tree_leaves_with_path(want["grads"]))
+    assert float(jnp.abs(logits - want_logits).max()) < 1e-3 * found["rms"]
+    loss, want_loss = found["loss"]
+    assert abs(float(loss) - float(want_loss)) < 1e-5
+    grads, want_grads = found["grads"]
+    ref = dict(jax.tree_util.tree_leaves_with_path(want_grads))
     for path, leaf in jax.tree_util.tree_leaves_with_path(grads):
         norm = float(jnp.linalg.norm(ref[path]))
         assert norm > 0, path
         assert float(jnp.linalg.norm(leaf - ref[path])) < 1e-4 * norm, path
     sparse = want["seq"] > CFG.sparse_dense_len
-    assert math.isnan(float(metrics["sala_free_mass"])) != sparse
+    assert math.isnan(float(found["metrics"]["sala_free_mass"])) != sparse
 
 
 def test_reference_forward_is_its_logits_and_loss(params, want):
     """``reference.forward`` (what the runner calls: sampled positions, the
     head by blocks) against ``reference.logits`` and ``reference.loss``."""
     seq = want["seq"]
-    tokens, targets = batch(seq)
+    tokens, targets = batch(CFG, seq, rows=1)
     where = jnp.asarray([[0, 30, 31, 64, seq // 2, seq - 1]])
     kw = reference.arguments(published(CFG))
     sampled, loss, rms = reference.forward(params, tokens, targets, where,
@@ -172,8 +154,9 @@ def two_layers(params):
     cfg = replace(CFG, num_hidden_layers=2)
     cut = dict(params, run01_lightning=jax.tree.map(
         lambda a: a[:1], params["run01_lightning"]))
-    tokens, _ = batch(384, seed=3)   # six blocks, of which four are kept
-    plain = minicpm_sala.forward(cut, cfg, tokens)
+    # Six blocks, of which four are kept.
+    tokens, _ = batch(CFG, 384, seed=3, rows=1)
+    plain = forward_alone(SALA, cut, cfg, tokens)
     return cfg, cut, tokens, plain
 
 
@@ -198,8 +181,8 @@ def test_a_dropped_term_moves_the_logits(script, two_layers, name):
     cfg, cut, tokens, plain = two_layers
     swaps, fields, change = script.faults()[name]
     with script._swapped(swaps):
-        got = minicpm_sala.forward(change(cut) if change else cut,
-                                   replace(cfg, **fields), tokens)
+        got = forward_alone(SALA, change(cut) if change else cut,
+                            replace(cfg, **fields), tokens)
     moved = float(jnp.sqrt(((got - plain) ** 2).mean())
                   / jnp.sqrt((plain ** 2).mean()))
     if name in script.UNSEEN:
@@ -214,7 +197,7 @@ def test_scalars_of_one_leave_the_shell_as_it_was(params):
     """``scale_emb`` 1, r = 1 and a head divisor of 1 against the same shell
     with no scalar at all (``embed_scale`` and ``logits_divisor`` None), bit
     for bit: what every other family's lowered step rests on."""
-    tokens, _ = batch(UNDER)
+    tokens, _ = batch(CFG, UNDER, rows=1)
     cfg = replace(CFG, scale_emb=1.0, dim_model_base=CFG.hidden_size,
                   scale_depth=math.sqrt(len(CFG.mixer_types)))
     assert cfg.residual_scale == 1.0
@@ -289,7 +272,7 @@ def test_step_kernels_gauges_and_falling_loss():
                       devices=jax.devices()[:1])
     state = init_train_state(cfg, mesh, seed=0)
     step = make_train_step(cfg, mesh)
-    tokens, targets = batch(OVER)
+    tokens, targets = batch(CFG, OVER, rows=1)
     census = kernel_census(jax.make_jaxpr(
         lambda p: jax.grad(lambda p: minicpm_sala.loss_fn(
             p, cfg, tokens, targets)[0])(p))(state["params"]), a_step=True)
@@ -318,7 +301,7 @@ def test_step_kernels_gauges_and_falling_loss():
 
 def test_a_mesh_of_several_devices_is_refused_over_dense_len(params):
     from ray_tpu.parallel import mesh as mesh_mod
-    tokens, _ = batch(OVER)
+    tokens, _ = batch(CFG, OVER, rows=1)
     mesh = build_mesh(MeshConfig(dp=2, fsdp=1, tp=1),
                       devices=jax.devices()[:2])
     previous = mesh_mod.current_mesh()

@@ -3,6 +3,7 @@ through buffers of a static bound, and on every routing the result is what
 the same layer gives at ``tokens x top_k`` rows (the path this file keeps
 as the reference: the layer as it stood before the buffers)."""
 
+import functools
 import math
 
 import jax
@@ -117,20 +118,39 @@ def _route_as(picked):
     return route
 
 
-def _run(layer_fn, args, first):
-    x, router, bias, *experts = args
-    cut = [w[first:first + COUNT] for w in experts]
-
-    def loss(x, router, *cut):
-        y, aux = layer_fn(x, router, bias, *cut, top_k=TOP_K, scaling=2.0,
-                          held=(first, COUNT))
+@functools.lru_cache(maxsize=None)
+def _planted_layer(layer_fn, first, route_as, patched):
+    """The compiled layer on a held run from ``first``, output, aux and the
+    five gradients, with the routing an argument: ``moe.route`` is
+    ``route_as`` of the traced picks while the layer is traced, so one
+    program serves every routing. ``patched`` (what ``_run`` finds under the
+    names of ``moe`` that this file's cases replace) keeps the programs
+    traced under a replacement apart from those traced without."""
+    def loss(picked, bias, x, router, *cut):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(moe, "route", route_as(picked))
+            y, aux = layer_fn(x, router, bias, *cut, top_k=TOP_K,
+                              scaling=2.0, held=(first, COUNT))
         return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum(), \
             (y, aux)
 
+    return jax.jit(jax.value_and_grad(loss, argnums=(2, 3, 4, 5, 6),
+                                      has_aux=True))
+
+
+def _run(layer_fn, args, first, picked, route_as=_route_as):
+    x, router, bias, *experts = args
+    cut = [w[first:first + COUNT] for w in experts]
     with jax.default_matmul_precision("highest"):
-        (_, (y, aux)), grads = jax.jit(jax.value_and_grad(
-            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(x, router, *cut)
+        (_, (y, aux)), grads = _planted_layer(
+            layer_fn, first, route_as,
+            (moe._buffers_needed, moe._token_tile))(
+                picked, bias, x, router, *cut)
     return y, aux, grads
+
+
+def _one_buffer(asked, bound):
+    return jnp.minimum(asked, 1)
 
 
 @pytest.mark.parametrize("first", [0, 9])
@@ -144,16 +164,16 @@ def test_the_buffers_give_the_full_size_layer(monkeypatch, widths, routing,
     bound, exactly at it, over it (more buffers), all on held experts and
     none on them, for a held run from expert 0 and one from the middle; the
     rows counted are the rows asked, and a buffer count cut to one counts
-    fewer on a routing over the bound."""
+    fewer on a routing over the bound. The six routings are data: a pair
+    of widths and ``first`` compiles the three programs once."""
     assert moe._held_bound(TOKENS, TOP_K, COUNT, EXPERTS) == BOUND
     both, one = ROUTINGS[routing]
     asked = 2 * both + one
     picked = _planted(first, both, one)
-    monkeypatch.setattr(moe, "route", _route_as(picked))
     args = _layer(*widths)
 
-    y, aux, grads = _run(moe.routed_experts, args, first)
-    want_y, want_aux, want_grads = _run(_full_size, args, first)
+    y, aux, grads = _run(moe.routed_experts, args, first, picked)
+    want_y, want_aux, want_grads = _run(_full_size, args, first, picked)
     assert int(aux["asked"]) == asked == int(aux["group_sizes"].sum())
     assert (aux["group_sizes"] == want_aux["group_sizes"]).all()
     assert int(aux["within_bound"]) == (asked <= BOUND)
@@ -170,9 +190,8 @@ def test_the_buffers_give_the_full_size_layer(monkeypatch, widths, routing,
     if not asked:
         assert not np.any(np.asarray(y))
 
-    monkeypatch.setattr(moe, "_buffers_needed",
-                        lambda asked, bound: jnp.minimum(asked, 1))
-    _, cut_aux, _ = _run(moe.routed_experts, args, first)
+    monkeypatch.setattr(moe, "_buffers_needed", _one_buffer)
+    _, cut_aux, _ = _run(moe.routed_experts, args, first, picked)
     assert int(cut_aux["group_sizes"].sum()) == min(asked, BOUND)
 
 
@@ -310,6 +329,10 @@ def test_a_shape_that_does_not_tile_takes_the_gathers(shape):
         np.asarray(moe._to_tokens_xla(rows, at, tokens)))
 
 
+def _no_tile(rows, at, tokens):
+    return None
+
+
 @pytest.fixture
 def gathers_alone(monkeypatch):
     """Call it, and ``_to_tokens`` is the ``jax.numpy`` form at every shape
@@ -322,34 +345,41 @@ def gathers_alone(monkeypatch):
         moe._buffer_backward.clear_cache()
 
     def switch():
-        monkeypatch.setattr(moe, "_token_tile", lambda rows, at, tokens: None)
+        monkeypatch.setattr(moe, "_token_tile", _no_tile)
         forget()
     yield switch
     monkeypatch.undo()
     forget()
 
 
-@pytest.mark.parametrize("routing", ["under", "over", "all", "none"])
-def test_the_layer_with_the_kernel_is_the_layer_with_the_gathers(
-        monkeypatch, gathers_alone, routing):
-    """``routed_experts(held=...)`` in bfloat16 through the kernel, forward
-    (the weighted sum) and backward (``d x``), gives the bits it gives
-    through the gathers: on a routing within the bound, on ones whose rows
-    lie in a second buffer, and on none; and counts the rows it read."""
-    both, one = ROUTINGS[routing]
-    asked = 2 * both + one
-    picked = _planted(0, both, one)
-
+def _short_route_as(picked):
+    """The choice planted and weights of eight significant bits (no
+    gradient to the router but zeros)."""
     def route(x, router, bias, top_k, scaling, normalize):
         weights = _short_weights(jax.random.PRNGKey(3), picked.shape)
         return picked, weights * jnp.sum(router) * 0 + weights
-    monkeypatch.setattr(moe, "route", route)
+    return route
+
+
+@pytest.mark.parametrize("routing", ["under", "over", "all", "none"])
+def test_the_layer_with_the_kernel_is_the_layer_with_the_gathers(
+        gathers_alone, routing):
+    """``routed_experts(held=...)`` in bfloat16 through the kernel, forward
+    (the weighted sum) and backward (``d x``), gives the bits it gives
+    through the gathers: on a routing within the bound, on ones whose rows
+    lie in a second buffer, and on none; and counts the rows it read. The
+    routings are data to the two programs."""
+    both, one = ROUTINGS[routing]
+    asked = 2 * both + one
+    picked = _planted(0, both, one)
     args = [a.astype(jnp.bfloat16) for a in _layer(128, 128)]
 
-    y, aux, grads = _run(moe.routed_experts, args, 0)
+    y, aux, grads = _run(moe.routed_experts, args, 0, picked,
+                         _short_route_as)
     assert int(aux["rows_summed"]) == asked == int(aux["asked"])
     gathers_alone()
-    want_y, want_aux, want_grads = _run(moe.routed_experts, args, 0)
+    want_y, want_aux, want_grads = _run(
+        moe.routed_experts, args, 0, picked, _short_route_as)
     assert int(want_aux["rows_summed"]) == \
         TOKENS * TOP_K * max(1, -(-asked // BOUND))
     np.testing.assert_array_equal(np.asarray(y, np.float32),

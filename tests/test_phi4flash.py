@@ -3,16 +3,9 @@ causal and onto another layer's K/V, gated memory units, one memory and one
 K/V made mid-stack and read by every layer behind) against the installed
 ``transformers``' ``MambaMixer.slow_forward`` and ``DiffLlamaAttention`` for
 the two mixers, and against a copy of the benchmark's plain reference for the
-whole model, every rung of the benchmark's cut included; what the middle pair
-hands on: its gradient is the sum over its readers, and a step holds one
-copy of it.
-
-Everything runs on the CPU at tiny widths in float32 under the highest
-matmul precision, the kernels interpreted, where both sides compute the same
-sums in another order: tolerances of 1e-4 (relative, on gradients: of a
-leaf's norm) leave room for float32 reassociation across a few hundred terms
-and nothing else. One comparison runs in bfloat16, loosely: it says that
-the low-precision path is the same function, not how close it is.
+whole model, through ``family_cases.py``, every rung of the benchmark's cut
+included; what the middle pair hands on: its gradient is the sum over its
+readers, and a step holds one copy of it.
 """
 
 from dataclasses import replace
@@ -23,12 +16,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_cases
 import reference_phi4flash as reference
+from family_cases import batch, drawn, forward_alone
 from ray_tpu.models import lm, phi4flash
 from ray_tpu.ops import selective_scan as scan_op
 from ray_tpu.parallel import MeshConfig, build_mesh
-from ray_tpu.parallel.train_step import init_train_state, make_train_step
-from ray_tpu.util import metrics as metrics_mod
 
 CFG = phi4flash.config("phi4flash-tiny")
 SEQ = 64
@@ -58,67 +51,34 @@ def published(cfg):
                 "mamba_dt_rank": cfg.dt_rank}}}
 
 
-def drawn(cfg, seed=0):
-    """``init`` with every vector moved off its one or zero, as the
-    benchmark's ``draw_vectors`` does, and Wq, Wk times 1.5."""
-    params = phi4flash.init(cfg, jax.random.PRNGKey(seed))
-    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
-
-    def moved(path, leaf, key):
-        name = str(path[-1].key)
-        if name.endswith(("wq", "wk")):
-            return 1.5 * leaf
-        if leaf.ndim - (len(path) > 1) == 1 or name.endswith(("A_log", "bq",
-                                                               "bk", "bv")):
-            return leaf + 0.1 * jax.random.normal(key, leaf.shape)
-        return leaf
-
-    return jax.tree.unflatten(tree, [
-        moved(path, leaf, key) for (path, leaf), key in zip(leaves, keys)])
+def moved(name, leaf, key):
+    """Every vector off its one or zero, as the benchmark's
+    ``draw_vectors`` does, and Wq, Wk times 1.5."""
+    if name.endswith(("wq']", "wk']")):
+        return 1.5 * leaf
+    if leaf.ndim == (2 if "run" in name else 1) or name.endswith(
+            ("A_log']", "bq']", "bk']", "bv']")):
+        return leaf + 0.1 * jax.random.normal(key, leaf.shape)
+    return leaf
 
 
-def batch(cfg, rows=2, seq=SEQ, seed=3):
-    data = jax.random.randint(jax.random.PRNGKey(seed), (rows, seq + 1), 0,
-                              cfg.vocab_size)
-    return data[:, :-1], data[:, 1:]
-
-
-def compared(cfg, seq, rows=2, seed=0):
-    """The program's and the reference's logits, loss and gradients."""
-    params = drawn(cfg, seed)
-    tokens, targets = batch(cfg, rows, seq)
-    where = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32), tokens.shape)
-    kw = reference.arguments(published(cfg))
-    with jax.default_matmul_precision("highest"):
-        got_logits = jax.jit(partial(phi4flash.forward, cfg=cfg))(
-            params, tokens=tokens)
-        got_loss, got_grads = jax.jit(jax.value_and_grad(
-            lambda p: phi4flash.loss_fn(p, cfg, tokens, targets)[0]))(params)
-    want_logits, want_losses, rms = reference.forward(
-        params, tokens, targets, where, **kw)
-    want_grads = jax.jit(jax.grad(
-        lambda p: reference.loss(p, tokens, targets, **kw)))(params)
-    return {"logits": (got_logits, want_logits), "rms": float(rms),
-            "loss": (float(got_loss), float(want_losses.mean())),
-            "grads": (got_grads, want_grads)}
-
-
-@pytest.fixture(scope="module")
-def both():
-    return compared(CFG, SEQ)
-
-
-@pytest.fixture(scope="module")
-def both_flash():
-    return compared(FLASH, FLASH_SEQ)
-
-
-LEAVES = sorted(
-    f"{run}/{leaf}" for run, _, _ in phi4flash._runs(CFG)
-    for leaf in phi4flash._leaves_of(phi4flash._shapes(CFG),
-                                     run.split("_", 1)[1])) \
-    + ["wte", "final_norm_scale", "final_norm_bias"]
+PHI = family_cases.Family(
+    module=phi4flash, reference=reference, cfg=CFG, seq=SEQ, flash=FLASH,
+    flash_seq=FLASH_SEQ, published=published, moved=moved,
+    reference_forward_traces=False, logits_tol=1e-4, rms_floor=0.05,
+    accum_steps=(1,), bfloat16=replace(FLASH, dtype=jnp.bfloat16),
+    flash_kernels=("selective_scan_fwd", "selective_scan_bwd",
+                   "conv_silu_fwd", "conv_silu_bwd", "flash_fwd_win",
+                   "flash_bwd_dq_win", "flash_bwd_dkv_win", "flash_fwd",
+                   "flash_bwd_dq", "flash_bwd_dkv"),
+    wrong=(dict(layers_run=(0, 1, 2)), dict(layers_run=(1, 2)),
+           dict(layers_run=(2, 3, 0, 1)), dict(layers_run=(0, 1, 6, 7)),
+           dict(layers_run=(0, 1, 8, 9)), dict(num_key_value_heads=4),
+           dict(num_attention_heads=6, num_key_value_heads=3),
+           dict(mb_per_layer=1), dict(tie_word_embeddings=False)),
+    wrong_ids=lambda wrong: "-".join(f"{k}={v}" for k, v in wrong.items()),
+    refuses=(ValueError, NotImplementedError))
+globals().update(family_cases.cases(PHI))
 
 
 def test_the_tiny_stack_has_all_three_kinds_of_pair():
@@ -128,22 +88,6 @@ def test_the_tiny_stack_has_all_three_kinds_of_pair():
     assert phi4flash._runs(phi4flash.config("phi-4-mini-flash-reasoning")) \
         == (("run00_self", "self", 8), ("run01_middle", "middle", 1),
             ("run02_cross", "cross", 7))
-
-
-def test_the_flash_size_runs_the_kernels():
-    """At the kernels' size the scan's, the convolution's and both flash
-    kernels (window and causal) are Pallas calls of the lowered step."""
-    params = jax.eval_shape(partial(phi4flash.init, FLASH),
-                            jax.random.PRNGKey(0))
-    tokens, targets = batch(FLASH, 1, FLASH_SEQ)
-    text = jax.jit(jax.grad(
-        lambda p: phi4flash.loss_fn(p, FLASH, tokens, targets)[0])).lower(
-        params).as_text(debug_info=True)
-    for kernel in ("selective_scan_fwd", "selective_scan_bwd",
-                   "conv_silu_fwd", "conv_silu_bwd", "flash_fwd_win",
-                   "flash_bwd_dq_win", "flash_bwd_dkv_win", "flash_fwd",
-                   "flash_bwd_dq", "flash_bwd_dkv"):
-        assert kernel in text, kernel
 
 
 # -- against transformers' two mixers --------------------------------------
@@ -165,7 +109,7 @@ def test_the_mamba_mixer_is_transformers_slow_forward():
         conv_kernel=cfg.mamba_d_conv, expand=cfg.mamba_expand,
         time_step_rank=cfg.dt_rank, use_bias=False, use_conv_bias=True,
         hidden_act="silu"), layer_idx=0).float().eval()
-    w = {name[2:]: leaf[0] for name, leaf in drawn(cfg)["run00_self"].items()
+    w = {name[2:]: leaf[0] for name, leaf in drawn(PHI, cfg)["run00_self"].items()
          if name.startswith("a_")}
     as_torch = lambda a: torch.from_numpy(np.array(a, np.float32))  # noqa: E731
     x = jax.random.normal(jax.random.PRNGKey(7), (2, SEQ, cfg.hidden_size))
@@ -181,7 +125,7 @@ def test_the_mamba_mixer_is_transformers_slow_forward():
         theirs.out_proj.weight.copy_(as_torch(w["w_out"].T))
         want = theirs.slow_forward(as_torch(x)).numpy()
     with jax.default_matmul_precision("highest"):
-        got, _, _ = phi4flash._mamba(cfg, x, w)
+        got, _, _ = jax.jit(partial(phi4flash._mamba, cfg))(x, w)
     rms = float(np.sqrt((want ** 2).mean()))
     assert rms > 1e-3
     np.testing.assert_allclose(got, want, atol=1e-4 * rms)
@@ -211,7 +155,7 @@ def test_differential_attention_is_diffllamas_under_the_head_permutation(
         attention_bias=True, rms_norm_eps=1e-5, attention_dropout=0.0),
         layer_idx=layer_idx).float().eval()
     w = {name[2:]: leaf[0]
-         for name, leaf in drawn(cfg)["run01_middle"].items()
+         for name, leaf in drawn(PHI, cfg)["run01_middle"].items()
          if name.startswith("b_")}
     w["subln_scale"] = jnp.ones_like(w["subln_scale"])
     # Their head j + p * heads / 2 is this model's 2j + p.
@@ -240,8 +184,8 @@ def test_differential_attention_is_diffllamas_under_the_head_permutation(
         want = theirs(as_torch(x), (ones, zeros), attention_mask=mask
                       )[0].numpy()
     with jax.default_matmul_precision("highest"):
-        got, _, _ = phi4flash._differential(
-            cfg, x, w, jnp.float32(phi4flash.lambda_init(layer_idx)))
+        got, _, _ = jax.jit(partial(phi4flash._differential, cfg))(
+            x, w, jnp.float32(phi4flash.lambda_init(layer_idx)))
     rms = float(np.sqrt((want ** 2).mean()))
     assert rms > 1e-2
     np.testing.assert_allclose(got, want, atol=1e-4 * rms)
@@ -250,34 +194,29 @@ def test_differential_attention_is_diffllamas_under_the_head_permutation(
 # -- against the reference ------------------------------------------------
 
 @pytest.mark.parametrize("which", ["both", "both_flash"])
-def test_logits_and_loss_match_the_reference(which, request):
-    found = request.getfixturevalue(which)
-    got, want = found["logits"]
-    assert found["rms"] > 0.05
-    assert float(jnp.abs(got - want).max()) < 1e-4 * found["rms"]
-    assert found["loss"][0] == pytest.approx(found["loss"][1], abs=1e-5)
+def test_the_loss_matches_to_five_places(which, request):
+    got, want = request.getfixturevalue(which)["loss"]
+    assert float(got) == pytest.approx(float(want), abs=1e-5)
 
 
-@pytest.mark.parametrize("leaf", LEAVES)
+@pytest.mark.parametrize("leaf", family_cases.leaves(PHI))
 @pytest.mark.parametrize("which", ["both", "both_flash"])
 def test_gradients_match_the_reference(which, leaf, request):
-    got, want = request.getfixturevalue(which)["grads"]
-    for key in leaf.split("/"):
-        got, want = got[key], want[key]
-    norm = float(jnp.linalg.norm(want))
-    if leaf.endswith("b_bk"):
+    got, want = (family_cases.by_name(tree) for tree in
+                 request.getfixturevalue(which)["grads"])
+    norm = float(jnp.linalg.norm(want[leaf]))
+    if leaf.endswith("b_bk']"):
         # A constant added to every key moves no softmax: zero but for
         # rounding, on both sides.
-        scale = float(jnp.linalg.norm(request.getfixturevalue(which)[
-            "grads"][1][leaf.split("/")[0]]["b_bq"]))
+        scale = float(jnp.linalg.norm(want[leaf.replace("b_bk", "b_bq")]))
         assert norm < 1e-4 * scale and \
-            float(jnp.linalg.norm(got)) < 1e-4 * scale
+            float(jnp.linalg.norm(got[leaf])) < 1e-4 * scale
         return
     assert norm > 0.0
     # A lambda's gradient is a sum over every output of terms that nearly
     # cancel (1e-4 of a bias's): ten times the room.
     room = 1e-3 if "lambda" in leaf else 1e-4
-    assert float(jnp.linalg.norm(got - want)) < room * norm
+    assert float(jnp.linalg.norm(got[leaf] - want[leaf])) < room * norm
 
 
 @pytest.mark.parametrize("rung", RUNGS)
@@ -288,7 +227,7 @@ def test_a_rung_of_the_cut_is_those_layers_of_the_whole_model(rung):
     stacks cut to them."""
     n_self, n_cross = RUNGS[rung]
     wide = replace(CFG, num_hidden_layers=32, layers_run=None)
-    params = drawn(wide)
+    params = drawn(PHI, wide)
     layers = tuple(range(2 * n_self)) + (16, 17) \
         + tuple(range(18, 18 + 2 * n_cross))
     cfg = replace(wide, layers_run=None if rung == "whole" else layers)
@@ -298,13 +237,11 @@ def test_a_rung_of_the_cut_is_those_layers_of_the_whole_model(rung):
                                        params["run00_self"]),
                run02_cross=jax.tree.map(lambda a: a[:n_cross],
                                         params["run02_cross"]))
-    tokens, targets = batch(cfg, 1)
+    tokens, targets = batch(cfg, SEQ, rows=1)
     where = jnp.broadcast_to(jnp.arange(SEQ, dtype=jnp.int32), tokens.shape)
     want, _, rms = reference.forward(cut, tokens, targets, where,
                                      **reference.arguments(published(cfg)))
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(partial(phi4flash.forward, cfg=cfg))(cut,
-                                                           tokens=tokens)
+    got = forward_alone(PHI, cut, cfg, tokens)
     assert float(jnp.abs(got - want).max()) < 1e-4 * float(rms)
     # The layers' own l0: by the place among those that run it is another
     # function wherever the cut skips a pair.
@@ -313,37 +250,34 @@ def test_a_rung_of_the_cut_is_those_layers_of_the_whole_model(rung):
             [phi4flash.lambda_init(i) for i in range(len(layers))]
 
 
-def test_bfloat16_with_the_kernels_is_the_same_function():
-    cfg = replace(FLASH, dtype=jnp.bfloat16)
-    params = drawn(cfg)
-    tokens, targets = batch(cfg, seq=FLASH_SEQ)
-    where = jnp.broadcast_to(jnp.arange(FLASH_SEQ, dtype=jnp.int32),
-                             tokens.shape)
-    want, _, rms = reference.forward(
-        params, tokens, targets, where, **reference.arguments(published(cfg)))
-    got = jax.jit(partial(phi4flash.forward, cfg=cfg))(params, tokens=tokens)
-    err = float(jnp.sqrt(((got.astype(jnp.float32) - want) ** 2).mean()))
-    assert err < 0.05 * float(rms)
-
-
 def _zeroed(params, leaf, change=jnp.zeros_like):
     return {name: dict(stack, **{leaf: change(stack[leaf])})
             if isinstance(stack, dict) and leaf in stack else stack
             for name, stack in params.items()}
 
 
+#: A cut of a published depth of 12, so that a layer's place and its index
+#: differ.
+DROP_CFG = replace(CFG, num_hidden_layers=12, sliding_window=16,
+                   layers_run=(0, 1, 2, 3, 6, 7, 10, 11))
+
+
+@pytest.fixture(scope="module")
+def cut_logits():
+    tokens, _ = batch(DROP_CFG, SEQ)
+    return forward_alone(PHI, drawn(PHI, DROP_CFG), DROP_CFG, tokens)
+
+
 @pytest.mark.parametrize("dropped", [
     "p2_not_subtracted", "subln", "one_minus_l0", "l0_of_the_cut",
     "k_pairing", "window", "memory_after_gate", "gmu_gate", "skip_d", "b_dt",
     "a_tap", "layernorm_bias", "eight_bit_residual"])
-def test_a_dropped_term_shows(dropped, monkeypatch):
-    """Each term of ISSUE 42's list taken out of the program, on a cut of
-    a published depth of 12 (so that a layer's place and its index differ):
-    the logits move by far more than float32 does (a cross layer reading
-    k, v of its own input is the benchmark's own test)."""
-    cfg = replace(CFG, num_hidden_layers=12, sliding_window=16,
-                  layers_run=(0, 1, 2, 3, 6, 7, 10, 11))
-    params, run = drawn(cfg), cfg
+def test_a_dropped_term_shows(cut_logits, dropped, monkeypatch):
+    """Each term of ISSUE 42's list taken out of the program, on
+    ``DROP_CFG``: the logits move by far more than float32 does (a cross
+    layer reading k, v of its own input is the benchmark's own test)."""
+    cfg = DROP_CFG
+    params, run = drawn(PHI, cfg), cfg
     patch = partial(monkeypatch.setattr, phi4flash)
     if dropped == "p2_not_subtracted":
         patch("_lambda", lambda layer, l0: jnp.float32(0.0))
@@ -383,11 +317,8 @@ def test_a_dropped_term_shows(dropped, monkeypatch):
             "a_tap": ("a_conv_w", lambda w: w.at[:, 0].set(0.0)),
             "layernorm_bias": ("a_ln1_bias", jnp.zeros_like)}[dropped]
         params = _zeroed(params, leaf, change)
-    tokens, _ = batch(cfg)
-    with jax.default_matmul_precision("highest"):
-        got = phi4flash.forward(params, run, tokens)
-        monkeypatch.undo()
-        want = phi4flash.forward(drawn(cfg), cfg, tokens)
+    tokens, _ = batch(cfg, SEQ)
+    got, want = forward_alone(PHI, params, run, tokens), cut_logits
     rms = float(jnp.sqrt((want ** 2).mean()))
     assert float(jnp.sqrt(((got - want) ** 2).mean())) > 2e-5 * rms
     assert float(jnp.abs(got - want).max()) > 1e-4 * rms
@@ -401,8 +332,8 @@ def test_the_shared_values_gradient_is_the_sum_over_their_readers():
     through the shell's one scan against each pair called by hand."""
     cfg = replace(CFG, num_hidden_layers=12,
                   layers_run=(0, 1, 6, 7, 8, 9, 10, 11))
-    params = drawn(cfg)
-    tokens, _ = batch(cfg, 1)
+    params = drawn(PHI, cfg)
+    tokens, _ = batch(cfg, SEQ, rows=1)
     positions = lm.positions_of(tokens)
     h = jax.random.normal(jax.random.PRNGKey(11),
                           tokens.shape + (cfg.hidden_size,))
@@ -450,8 +381,8 @@ def test_a_step_holds_one_copy_of_what_is_handed_on():
     the scans' stacked outputs nor their carries."""
     cfg = replace(FLASH, attn_impl="dot", num_hidden_layers=12,
                   layers_run=(0, 1, 6, 7, 8, 9, 10, 11))
-    params = drawn(cfg)
-    tokens, targets = batch(cfg, 1)
+    params = drawn(PHI, cfg)
+    tokens, targets = batch(cfg, SEQ, rows=1)
     jaxpr = jax.make_jaxpr(jax.grad(lambda p: phi4flash.loss_fn(
         p, cfg, tokens, targets)[0]))(params)
     m_shape = tokens.shape + (cfg.d_inner,)
@@ -472,52 +403,26 @@ def test_a_step_holds_one_copy_of_what_is_handed_on():
     assert as_constants >= 2  # the cross run's forward and its backward
 
 
-# -- training, counters, the shell -------------------------------------------
-
-def _one_chip():
-    return build_mesh(MeshConfig(dp=1, fsdp=1, tp=1),
-                      devices=jax.devices()[:1])
-
-
-def _series(name):
-    for entry in metrics_mod.snapshot():
-        if entry["name"] == name and entry["series"]:
-            return sum(entry["series"].values())
-    return None
-
+# -- training, the mesh ---------------------------------------------------------
 
 def test_trains_and_feeds_the_two_gauges():
-    """``make_train_step`` finds the model from ``type(cfg)``: the loss
-    falls on a repeated batch (flash, the scan's and the convolution's
-    kernels, remat, the chunked loss), and the two gauges say what the
-    step saw."""
-    import optax
-    from ray_tpu.parallel.sharding import ShardingRules
-    mesh = _one_chip()
-    rules, optimizer = ShardingRules(), optax.adam(3e-3)
-    state = init_train_state(FLASH, mesh, rules, optimizer, seed=0)
-    step = make_train_step(FLASH, mesh, rules, optimizer)
-    tokens, targets = batch(FLASH, rows=2, seq=FLASH_SEQ)
-    losses = []
-    for _ in range(3):
-        state, metrics = step(state, {"tokens": tokens, "targets": targets})
-        losses.append(float(metrics["loss"]))
-        # Steps of 0.001-0.1 (and what the projection adds) times rates of
-        # 1-16; l0 of the layers that run, 0.36-0.73, and a little.
-        assert -8.0 < float(metrics["selective_scan_decay_floor"]) < -0.5
-        assert 0.5 < float(metrics["diff_attention_lambda_max"]) < 1.0
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    assert -8.0 < _series("ray_tpu_train_selective_scan_decay_floor") < -0.5
-    assert 0.5 < _series("ray_tpu_train_diff_attention_lambda_max") < 1.0
+    """The two gauges say what the step saw: steps of 0.001-0.1 (and what
+    the projection adds) times rates of 1-16; l0 of the layers that run,
+    0.36-0.73, and a little."""
+    found = family_cases.trained(PHI, 1)
+    for metrics in found["metrics"]:
+        assert -8.0 < metrics["selective_scan_decay_floor"] < -0.5
+        assert 0.5 < metrics["diff_attention_lambda_max"] < 1.0
+    gauges = found["gauges"]
+    assert -8.0 < gauges["ray_tpu_train_selective_scan_decay_floor"] < -0.5
+    assert 0.5 < gauges["ray_tpu_train_diff_attention_lambda_max"] < 1.0
 
 
-def test_a_data_parallel_mesh_runs_the_kernels_per_shard():
+def test_a_data_parallel_mesh_runs_the_kernels_per_shard(both_flash):
     """Under dp = 2 the scan's, the convolution's and the flash kernels run
     per shard of the batch, and the loss is the one-device loss."""
     from ray_tpu.parallel import mesh as mesh_mod
-    params = drawn(FLASH)
-    tokens, targets = batch(FLASH, rows=2, seq=FLASH_SEQ)
-    want = float(phi4flash.loss_fn(params, FLASH, tokens, targets)[0])
+    tokens, targets = batch(FLASH, FLASH_SEQ)
     mesh = build_mesh(MeshConfig(dp=2, fsdp=1, tp=1),
                       devices=jax.devices()[:2])
     previous = mesh_mod.current_mesh()
@@ -525,40 +430,7 @@ def test_a_data_parallel_mesh_runs_the_kernels_per_shard():
     try:
         with mesh:
             got = float(jax.jit(lambda p: phi4flash.loss_fn(
-                p, FLASH, tokens, targets)[0])(params))
+                p, FLASH, tokens, targets)[0])(drawn(PHI, FLASH)))
     finally:
         mesh_mod.set_current_mesh(previous)
-    assert got == pytest.approx(want, abs=1e-5)
-
-
-def test_param_specs_match_init():
-    from ray_tpu.parallel.sharding import ShardingRules
-    params = jax.eval_shape(lambda: phi4flash.init(CFG, jax.random.PRNGKey(0)))
-    specs = phi4flash.param_specs(CFG, ShardingRules())
-    assert jax.tree.structure(params) == jax.tree.structure(
-        specs, is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
-    for leaf, spec in zip(jax.tree.leaves(params), jax.tree.leaves(
-            specs, is_leaf=lambda s: isinstance(
-                s, jax.sharding.PartitionSpec))):
-        assert len(spec) <= leaf.ndim
-
-
-@pytest.mark.parametrize("wrong", [
-    dict(layers_run=(0, 1, 2)), dict(layers_run=(1, 2)),
-    dict(layers_run=(2, 3, 0, 1)), dict(layers_run=(0, 1, 6, 7)),
-    dict(layers_run=(0, 1, 8, 9)), dict(num_key_value_heads=4),
-    dict(num_attention_heads=6, num_key_value_heads=3),
-    dict(mb_per_layer=1), dict(tie_word_embeddings=False)],
-    ids=lambda wrong: "-".join(f"{k}={v}" for k, v in wrong.items()))
-def test_config_refuses_what_it_cannot_hold(wrong):
-    with pytest.raises((ValueError, NotImplementedError)):
-        replace(CFG, **wrong)
-
-
-def test_the_reference_is_the_benchmarks_byte_for_byte():
-    import os
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "reference_phi4flash.py"), "rb") as mine, \
-            open(os.path.join(here, "..", "benchmark", "reference",
-                              "phi4flash.py"), "rb") as theirs:
-        assert mine.read() == theirs.read()
+    assert got == pytest.approx(float(both_flash["loss"][0]), abs=1e-5)

@@ -31,14 +31,17 @@ def chunks_of_128():
 
 
 def _literal(xs, delta, A, B, C, D):
-    """The recurrence token by token in a Python loop, from a zero state."""
-    state = jnp.zeros((xs.shape[0],) + A.shape)
-    ys = []
-    for t in range(xs.shape[1]):
+    """The recurrence token by token, from a zero state: a compiled loop
+    over t (``fori_loop``) of the literal arithmetic."""
+    def token(t, carry):
+        state, ys = carry
         state = jnp.exp(delta[:, t, :, None] * A) * state \
             + (delta[:, t] * xs[:, t])[..., None] * B[:, t, None, :]
-        ys.append((state * C[:, t, None, :]).sum(-1) + D * xs[:, t])
-    return jnp.stack(ys, axis=1)
+        return state, ys.at[:, t].set(
+            (state * C[:, t, None, :]).sum(-1) + D * xs[:, t])
+    return jax.lax.fori_loop(
+        0, xs.shape[1], token,
+        (jnp.zeros((xs.shape[0],) + A.shape), jnp.zeros_like(xs)))[1]
 
 
 def _data(seed, batch, seq, channels=CHANNELS):
@@ -53,8 +56,11 @@ def _data(seed, batch, seq, channels=CHANNELS):
 
 
 def _all(fn, args, g):
-    y, vjp = jax.vjp(fn, *args)
-    return (y,) + vjp(g)
+    """Value and the six cotangents, one compiled program."""
+    def run(args, g):
+        y, vjp = jax.vjp(fn, *args)
+        return (y,) + vjp(g)
+    return jax.jit(run)(args, g)
 
 
 @pytest.fixture(scope="module")

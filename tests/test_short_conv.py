@@ -77,9 +77,16 @@ def test_the_plain_form_stands_on_causal_conv():
 
 def _both(batch, seq, d, taps, dtype=jnp.float32):
     bcx, w, dy = _inputs(batch, seq, d, taps, dtype)
-    got, got_vjp = jax.vjp(sc.short_conv, bcx, w)
-    want, want_vjp = jax.vjp(sc.short_conv_xla, bcx, w)
-    return (got, want), tuple(zip(got_vjp(dy), want_vjp(dy)))
+
+    def run(fn):  # value and both cotangents, one compiled program
+        def both(bcx, w, dy):
+            out, vjp = jax.vjp(fn, bcx, w)
+            return out, vjp(dy)
+        return jax.jit(both)(bcx, w, dy)
+
+    (got, got_grads), (want, want_grads) = run(sc.short_conv), \
+        run(sc.short_conv_xla)
+    return (got, want), tuple(zip(got_grads, want_grads))
 
 
 @pytest.fixture(scope="module")
@@ -182,12 +189,17 @@ def silu_case(request):
     w = jax.random.normal(ks[1], (taps, width)) / taps ** 0.5
     b = jax.random.normal(ks[2], (width,)) if bias else None
     dy = jax.random.normal(ks[3], (batch, 3 * sc.ROWS, width)).astype(dtype)
-    got, got_vjp = jax.vjp(
-        lambda x, w, b: sc.conv_silu(x, w, b, start, width), x, w, b)
-    want, want_vjp = jax.vjp(
-        lambda x, w, b: _plain(x, w, b, start, width), x, w, b)
+
+    def run(fn):  # value and the three cotangents, one compiled program
+        def both(x, w, b, dy):
+            out, vjp = jax.vjp(lambda x, w, b: fn(x, w, b, start, width),
+                               x, w, b)
+            return out, vjp(dy)
+        return jax.jit(both)(x, w, b, dy)
+
+    (got, got_grads), (want, want_grads) = run(sc.conv_silu), run(_plain)
     tol = 1e-5 if dtype == jnp.float32 else 1e-2
-    return (got, want), tuple(zip(got_vjp(dy), want_vjp(dy))), tol, \
+    return (got, want), tuple(zip(got_grads, want_grads)), tol, \
         (x, w, b, start, width)
 
 

@@ -38,8 +38,11 @@ def _data(seed, batch, seq):
 
 
 def _all(fn, args, g):
-    y, vjp = jax.vjp(fn, *args)
-    return (y,) + vjp(g)
+    """Value and the six cotangents, one compiled program."""
+    def run(args, g):
+        y, vjp = jax.vjp(fn, *args)
+        return (y,) + vjp(g)
+    return jax.jit(run)(args, g)
 
 
 @pytest.fixture(scope="module")

@@ -757,7 +757,10 @@ def _recording_model(seen):
 @pytest.fixture(scope="module")
 def five_calls():
     """A real train step (a model with ``RECORDED_METRICS``) called five
-    times with tracing on, then five times with it off."""
+    times with tracing on, then five times with it off, by a loop whose
+    own share of an iteration is 50 ms (a sleep): a dispatch, well under
+    a millisecond, does not outlast the interval it is compared with
+    unless the machine stalls it for fifty."""
     import jax
     import jax.numpy as jnp
 
@@ -778,6 +781,7 @@ def five_calls():
             step = make_train_step(None, mesh, model=model)
             for _ in range(5):
                 state, metrics = step(state, batch)
+                time.sleep(0.05)
             jax.block_until_ready(metrics)
         finally:
             tracing.disable_tracing()
@@ -821,7 +825,14 @@ def test_a_step_s_interval_is_the_difference_of_two_entries(five_calls):
             later.perf_start - earlier.perf_start
         assert [later.attributes[k] for k in
                 ("save_s", "report_s", "data_s")] == [0.0, 0.0, 0.0]
-        # Self time (less ``step::record``) is the dispatch proper.
+        # An interval holds the dispatch it starts with: the earlier call
+        # had returned when the later began.
+        assert earlier.duration <= later.attributes["interval_s"]
+        # Self time (less ``step::record``) is the dispatch proper: a small
+        # part of a step's interval. (The later call's dispatch is no part
+        # of the interval it is compared with, and beside busy neighbours
+        # can take as long as a loop that does nothing else gives it: hence
+        # the loop's 50 ms an iteration.)
         assert later.duration < later.attributes["interval_s"]
 
 
