@@ -50,8 +50,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.flash_attention import (
-    _NEG_INF, RESIDUAL_NAMES, _from_bh, _lanes, _pick_block,
-    _row_ends, _score_scale, _stat_lanes, _tile_pairs, _to_bh, worth_keeping)
+    _NEG_INF, _STAT_LANES, RESIDUAL_NAMES, _from_bh, _lanes, _pick_block,
+    _row_ends, _score_scale, _tile_pairs, _to_bh, worth_keeping)
 
 #: The name the selection carries among a block's values: a rematerialisation
 #: policy that keeps it (``models/lm.py`` ``scan_blocks``) does not search the
@@ -368,7 +368,6 @@ def _forward(q, k, v, selection, blk_q, blk_k, scale):
     scale = _score_scale(scale, D)
     blk_q, blk_k = _blocks(S, blk_q, blk_k)
     pairs = _tile_pairs(S, blk_q, blk_k, True, False)
-    lanes = _stat_lanes(D, Dv)
     out, lse = _call(
         functools.partial(_fwd_kernel, blk_k=blk_k, scale=scale), "dsa_fwd",
         (B * H, len(pairs[0])), pairs, _live(selection, pairs, blk_q, blk_k),
@@ -386,8 +385,8 @@ def _forward(q, k, v, selection, blk_q, blk_k, scale):
             jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((blk_q, lanes), jnp.float32),
-                        pltpu.VMEM((blk_q, lanes), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((blk_q, _STAT_LANES), jnp.float32),
+                        pltpu.VMEM((blk_q, _STAT_LANES), jnp.float32),
                         pltpu.VMEM((blk_q, Dv), jnp.float32)],
         semantics=("parallel", "arbitrary"),
     )(_to_bh(q), _to_bh(k), _to_bh(v), selection)

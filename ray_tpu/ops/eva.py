@@ -55,10 +55,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.flash_attention import (
-    _NEG_INF, RESIDUAL_NAMES, Summaries, _flash_bwd_dkv_kernel,
+    _NEG_INF, _STAT_LANES, RESIDUAL_NAMES, Summaries, _flash_bwd_dkv_kernel,
     _flash_bwd_dq_kernel, _flash_fwd_kernel, _from_bh, _kv_tile, _pick_block,
-    _q_row, _q_tile, _score_scale, _stat_lanes, _tile_pairs, _tiled_call,
-    _to_bh, summary_rows, worth_keeping)
+    _q_row, _q_tile, _score_scale, _tile_pairs, _tiled_call, _to_bh,
+    summary_rows, worth_keeping)
 
 
 def keys_seen(S: int, window: int, chunk: int) -> int:
@@ -163,7 +163,6 @@ def _eva_forward(q, keys, values, eva: Summaries, blk_q: int, blk_k: int,
     Dv] -> (out [B, S, H, Dv], lse [B H, 1, S], mass [B H, 1, S])."""
     B, S, H, D = q.shape
     Dv = values.shape[-1]
-    lanes = _stat_lanes(D, Dv)
     row = jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)
     out, lse, mass = _tiled_call(
         functools.partial(_eva_fwd_kernel, blk_q=blk_q, blk_k=blk_k,
@@ -176,10 +175,10 @@ def _eva_forward(q, keys, values, eva: Summaries, blk_q: int, blk_k: int,
                    pl.BlockSpec((None, 1, blk_q), _q_row),
                    pl.BlockSpec((None, 1, blk_q), _q_row)],
         out_shape=[jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype), row, row],
-        scratch_shapes=[pltpu.VMEM((blk_q, lanes), jnp.float32),
-                        pltpu.VMEM((blk_q, lanes), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((blk_q, _STAT_LANES), jnp.float32),
+                        pltpu.VMEM((blk_q, _STAT_LANES), jnp.float32),
                         pltpu.VMEM((blk_q, Dv), jnp.float32),
-                        pltpu.VMEM((blk_q, lanes), jnp.float32)],
+                        pltpu.VMEM((blk_q, _STAT_LANES), jnp.float32)],
     )(_to_bh(q), _to_bh(keys), _to_bh(values))
     return _from_bh(out, B, H), lse, mass
 

@@ -57,7 +57,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.ops import dsa
 from ray_tpu.ops.dsa import SELECTION_NAME  # noqa: F401 - kept by remat
 from ray_tpu.ops.flash_attention import (
-    _NEG_INF, RESIDUAL_NAMES, _from_bh, _score_scale, _stat_lanes,
+    _NEG_INF, _STAT_LANES, RESIDUAL_NAMES, _from_bh, _score_scale,
     _tile_pairs, _to_bh, worth_keeping)
 
 F32 = jnp.float32
@@ -321,7 +321,6 @@ def _forward(q, k, v, selection, block, blk_q, blk_k, scale):
     scale = _score_scale(scale, D)
     blk_q, blk_k = tiles(q, k, block, blk_q, blk_k)
     pairs = _tile_pairs(S, blk_q, blk_k, True, False)
-    lanes = _stat_lanes(D, Dv)
     mask = token_mask(selection, block).reshape(B * G, S, S)
     out, lse = dsa._call(
         functools.partial(dsa._fwd_kernel, blk_k=blk_k, scale=scale),
@@ -341,8 +340,8 @@ def _forward(q, k, v, selection, block, blk_q, blk_k, scale):
             jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, S), F32),
         ],
-        scratch_shapes=[pltpu.VMEM((blk_q, lanes), F32),
-                        pltpu.VMEM((blk_q, lanes), F32),
+        scratch_shapes=[pltpu.VMEM((blk_q, _STAT_LANES), F32),
+                        pltpu.VMEM((blk_q, _STAT_LANES), F32),
                         pltpu.VMEM((blk_q, Dv), F32)],
         semantics=("parallel", "arbitrary"),
     )(_to_bh(q), _to_bh(k), _to_bh(v), mask)

@@ -790,11 +790,13 @@ def test_the_lfm2_cells_compiled_step_gathers_no_slab_of_tokens(topo):
 #: cda3dc002fa5, e72cae811a53, df4cd8b6c9e8. A PR that means to change one
 #: of these programs records the new value here (the failing assertion
 #: prints it) and says so in CHANGES.md. PR 48 meant to change granite's
-#: (``lm.gated_norm`` behind the scan; e0101a71d47b before it).
+#: (``lm.gated_norm`` behind the scan; e0101a71d47b before it), PR 57
+#: Moonlight's (the forward's statistics lane-dense at 192 | 128;
+#: 030ce9c909a1 before it).
 LOWERED_STEPS = {
     "gptj-6b-1chip.steady": "b470aa16aac6",
     "gptj-6b-4chip.steady": "42d82d54bed3",
-    "moonlight-16b-a3b-1chip.steady": "030ce9c909a1",
+    "moonlight-16b-a3b-1chip.steady": "c02822046105",
     "granite-4.0-h-micro-1chip.steady": "6dff69cbb9be",
     "phi-4-mini-flash-reasoning-1chip.steady": "b8326d36469b",
 }
@@ -869,33 +871,52 @@ CELL_ATTENTION = {
     "phi-4-mini-flash-reasoning, window layer":
         ((1, 16384, 40, 64), 40, 128, 512),
     # No cell's: lane-dense statistics over an output of one and a half
-    # lane tiles, and at Moonlight's head sizes, which run one lane.
+    # lane tiles.
     "heads of 192": ((2, 4096, 8, 192), 8, 192, None),
-    "192 | 128, lane-dense": (MLA_SHAPE, 16, MLA_V, None),
 }
 
 
-@pytest.mark.parametrize("cell", CELL_ATTENTION)
-def test_flash_forward_compiles_at_every_cells_shape(topo, cell,
-                                                     monkeypatch):
-    """The forward alone, at tiles of 512 x 512 and the statistics' lanes
-    its head sizes get (``_stat_lanes``): one Mosaic call under the
-    kernel's name, inside the scoped VMEM the compiler grants by default
-    (the call states no limit of its own)."""
-    from ray_tpu.parallel.collectives import kernel_census
-    if cell.endswith("lane-dense"):
-        monkeypatch.setattr(flash_mod, "_stat_lanes", lambda D, Dv: 128)
+def _cell_attention(cell, sharding=None):
+    """(q, k, v, window): a ``CELL_ATTENTION`` entry's abstract operands
+    and the forward's window."""
     shape, kv_heads, v_dim, window = CELL_ATTENTION[cell]
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    q, k, v = (jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+    q, k, v = (jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=sharding)
                for s in (shape, shape[:2] + (kv_heads, shape[3]),
                          shape[:2] + (kv_heads, v_dim)))
+    return q, k, v, window
+
+
+@pytest.mark.parametrize("cell", CELL_ATTENTION)
+def test_flash_forward_compiles_at_every_cells_shape(topo, cell):
+    """The forward alone, at tiles of 512 x 512, its statistics lane-dense
+    at every head size: one Mosaic call under the kernel's name, inside the
+    scoped VMEM the compiler grants by default (the call states no limit of
+    its own)."""
+    from ray_tpu.parallel.collectives import kernel_census
+    q, k, v, window = _cell_attention(
+        cell, SingleDeviceSharding(topo.devices[0]))
     text = jax.jit(lambda q, k, v: flash_mod.flash_attention(
         q, k, v, True, 512, 512, None, window)).lower(
             q, k, v).compile().as_text()
     name = "flash_fwd_win" if window else "flash_fwd"
     assert kernel_census(text) == {name: 1}
     assert "vmem_limit_bytes" not in text
+
+
+@pytest.mark.parametrize("cell", CELL_ATTENTION)
+def test_flash_forward_keeps_its_statistics_lane_dense(cell):
+    """The forward traced (no chip described, nothing compiled) at every
+    cell's head sizes: the kernel's first two scratch buffers, the running
+    maximum and sum, are [blk_q, 128] float32 whatever D and Dv are."""
+    q, k, v, window = _cell_attention(cell)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: flash_mod._flash_forward(
+        q, k, v, True, 512, 512, None, window))(q, k, v)
+    (call,) = [eqn for eqn in jaxpr.eqns
+               if eqn.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"]
+    scratch = call.params["jaxpr"].invars[-grid.num_scratch_operands:]
+    assert [(a.aval.shape, a.aval.dtype) for a in scratch[:2]] == [
+        ((512, 128), jnp.float32)] * 2
 
 
 def test_flash_compiles_at_32k_with_grouped_kv_heads(topo):

@@ -404,72 +404,70 @@ def _heads(S, H, KVH, D, Dv, seed, dtype=jnp.float32):
             jax.random.normal(ks[3], (1, S, H, Dv), dtype))
 
 
-# (S, H, KVH, D, Dv, causal, window, blk_q, blk_k, lanes): ``lanes`` of m
-# and l, None for what ``_stat_lanes`` gives the head sizes (one at
-# 192 | 128, else 128).
+# (S, H, KVH, D, Dv, causal, window, blk_q, blk_k); a name that starts with
+# ``bf16`` runs in bfloat16.
 FORWARD_CASES = {
     # The four head sizes; rows of 1, 2, 3 and 4 tiles: odd and even.
-    "causal-64": (512, 2, 2, 64, 64, True, None, 128, 128, None),
-    "causal-128": (512, 2, 2, 128, 128, True, None, 128, 128, None),
-    "causal-192-128": (512, 2, 2, 192, 128, True, None, 128, 128, None),
-    "causal-256": (512, 1, 1, 256, 256, True, None, 128, 128, None),
+    "causal-64": (512, 2, 2, 64, 64, True, None, 128, 128),
+    "causal-128": (512, 2, 2, 128, 128, True, None, 128, 128),
+    "causal-192-128": (512, 2, 2, 192, 128, True, None, 128, 128),
+    "causal-256": (512, 1, 1, 256, 256, True, None, 128, 128),
     # Rows of 1 .. 6 tiles.
-    "causal-192-128-long": (768, 1, 1, 192, 128, True, None, 128, 128, None),
+    "causal-192-128-long": (768, 1, 1, 192, 128, True, None, 128, 128),
     # All pairs: every row 3 tiles (odd), 4 (even), 1.
-    "all-pairs-odd": (384, 2, 2, 64, 64, False, None, 128, 128, None),
-    "all-pairs-even": (512, 2, 2, 128, 128, False, None, 128, 128, None),
-    "all-pairs-192-128": (640, 1, 1, 192, 128, False, None, 128, 128, None),
-    "all-pairs-256": (256, 1, 1, 256, 256, False, None, 128, 128, None),
-    "one-tile": (128, 2, 2, 64, 64, True, None, 128, 128, None),
-    "one-tile-of-256": (256, 2, 1, 128, 128, True, None, 256, 256, None),
-    "one-tile-all-pairs": (128, 1, 1, 192, 128, False, None, 128, 128, None),
+    "all-pairs-odd": (384, 2, 2, 64, 64, False, None, 128, 128),
+    "all-pairs-even": (512, 2, 2, 128, 128, False, None, 128, 128),
+    "all-pairs-192-128": (640, 1, 1, 192, 128, False, None, 128, 128),
+    "all-pairs-256": (256, 1, 1, 256, 256, False, None, 128, 128),
+    "one-tile": (128, 2, 2, 64, 64, True, None, 128, 128),
+    "one-tile-of-256": (256, 2, 1, 128, 128, True, None, 256, 256),
+    "one-tile-all-pairs": (128, 1, 1, 192, 128, False, None, 128, 128),
     # Windows of 128 / 384 / 1024 at tiles of 128 and 256.
-    "window-128-tiles-128": (1280, 1, 1, 128, 128, True, 128, 128, 128, None),
-    "window-384-tiles-128": (1280, 1, 1, 128, 128, True, 384, 128, 128, None),
-    "window-1024-tiles-128": (1280, 1, 1, 64, 64, True, 1024, 128, 128, None),
-    "window-128-tiles-256": (1280, 1, 1, 64, 64, True, 128, 256, 256, None),
-    "window-384-tiles-256": (1280, 1, 1, 192, 128, True, 384, 256, 256, None),
-    "window-1024-tiles-256": (1280, 1, 1, 128, 128, True, 1024, 256, 256,
-                              None),
-    "window-384-256": (768, 1, 1, 256, 256, True, 384, 128, 128, None),
+    "window-128-tiles-128": (1280, 1, 1, 128, 128, True, 128, 128, 128),
+    "window-384-tiles-128": (1280, 1, 1, 128, 128, True, 384, 128, 128),
+    "window-1024-tiles-128": (1280, 1, 1, 64, 64, True, 1024, 128, 128),
+    "window-128-tiles-256": (1280, 1, 1, 64, 64, True, 128, 256, 256),
+    "window-384-tiles-256": (1280, 1, 1, 192, 128, True, 384, 256, 256),
+    "window-1024-tiles-256": (1280, 1, 1, 128, 128, True, 1024, 256, 256),
+    "window-384-256": (768, 1, 1, 256, 256, True, 384, 128, 128),
     # Unequal tiles: rows of 1, 1, 2, 2 ... and of 2, 4, 6 ...
-    "q-128-k-256": (1024, 1, 1, 64, 64, True, None, 128, 256, None),
-    "q-256-k-128": (1024, 1, 1, 128, 128, True, None, 256, 128, None),
-    "q-256-k-128-window": (1024, 1, 1, 128, 128, True, 300, 256, 128, None),
-    "q-128-k-256-192-128": (768, 1, 1, 192, 128, True, None, 128, 256, None),
+    "q-128-k-256": (1024, 1, 1, 64, 64, True, None, 128, 256),
+    "q-256-k-128": (1024, 1, 1, 128, 128, True, None, 256, 128),
+    "q-256-k-128-window": (1024, 1, 1, 128, 128, True, 300, 256, 128),
+    "q-128-k-256-192-128": (768, 1, 1, 192, 128, True, None, 128, 256),
     # Query head i reads KV head i // (H // KVH).
-    "grouped-8-over-2": (384, 8, 2, 64, 64, True, None, 128, 128, None),
-    "grouped-6-over-2-window": (512, 6, 2, 128, 128, True, 200, 128, 128,
-                                None),
-    "grouped-4-over-1-192-128": (384, 4, 1, 192, 128, True, None, 128, 128,
-                                 None),
+    "grouped-8-over-2": (384, 8, 2, 64, 64, True, None, 128, 128),
+    "grouped-6-over-2-window": (512, 6, 2, 128, 128, True, 200, 128, 128),
+    "grouped-4-over-1-192-128": (384, 4, 1, 192, 128, True, None, 128, 128),
     # bfloat16 operands, as the models pass them.
-    "bf16-128": (512, 2, 1, 128, 128, True, None, 128, 128, None),
-    "bf16-192-128-window": (640, 2, 2, 192, 128, True, 256, 128, 128, None),
+    "bf16-128": (512, 2, 1, 128, 128, True, None, 128, 128),
+    "bf16-192-128-window": (640, 2, 2, 192, 128, True, 256, 128, 128),
     # Head sizes that are no multiple of 128, under and over it.
-    "causal-80": (384, 2, 2, 80, 80, True, None, 128, 128, None),
-    "causal-160": (384, 2, 1, 160, 160, True, None, 128, 128, None),
-    "causal-192-192": (512, 1, 1, 192, 192, True, None, 128, 256, None),
-    "window-320": (640, 1, 1, 320, 320, True, 200, 128, 128, None),
-    # Either layout at a head size that is given the other.
-    "one-lane-128": (640, 1, 1, 128, 128, True, None, 128, 128, 1),
-    "one-lane-64-window": (640, 1, 1, 64, 64, True, 300, 128, 128, 1),
-    "dense-192-128": (640, 1, 1, 192, 128, True, None, 128, 128, 128),
-    "dense-192-128-all-pairs": (768, 2, 1, 192, 128, False, None, 128, 256,
-                                128),
+    "causal-80": (384, 2, 2, 80, 80, True, None, 128, 128),
+    "causal-160": (384, 2, 1, 160, 160, True, None, 128, 128),
+    "causal-192-192": (512, 1, 1, 192, 192, True, None, 128, 256),
+    "window-320": (640, 1, 1, 320, 320, True, 200, 128, 128),
+    # Latent attention's head sizes once more: rows of 1 .. 5 tiles,
+    # unequal tiles over grouped heads, and the benchmark cells' own tiles
+    # of 512 x 512 (rows of one and two), causal, cut by a window and over
+    # all pairs.
+    "dense-192-128": (640, 1, 1, 192, 128, True, None, 128, 128),
+    "dense-192-128-all-pairs": (768, 2, 1, 192, 128, False, None, 128, 256),
+    "tiles-512-192-128": (1024, 2, 2, 192, 128, True, None, 512, 512),
+    "tiles-512-192-128-window": (1024, 1, 1, 192, 128, True, 700, 512, 512),
+    "tiles-512-192-128-all-pairs": (1024, 1, 1, 192, 128, False, None, 512,
+                                    512),
+    "bf16-192-128-grouped": (512, 4, 2, 192, 128, True, None, 128, 256),
 }
 
 
 @pytest.mark.parametrize("case", FORWARD_CASES)
-def test_flash_forward_equals_the_one_lane_kernel(case, monkeypatch):
+def test_flash_forward_equals_the_one_lane_kernel(case):
     """``out`` and ``lse`` of the forward kernel, bit for bit what the
     kernel with one-lane statistics gave: the same operands and types, the
     same order of every sum along a row of tiles."""
     fm = flash_mod
-    S, H, KVH, D, Dv, causal, window, blk_q, blk_k, lanes = \
-        FORWARD_CASES[case]
-    if lanes is not None:
-        monkeypatch.setattr(fm, "_stat_lanes", lambda D, Dv: lanes)
+    S, H, KVH, D, Dv, causal, window, blk_q, blk_k = FORWARD_CASES[case]
     dtype = jnp.bfloat16 if case.startswith("bf16") else jnp.float32
     q, k, v, _ = _heads(S, H, KVH, D, Dv, seed=len(case), dtype=dtype)
     out, lse = fm._flash_forward(q, k, v, causal, blk_q, blk_k, None, window)
@@ -484,12 +482,13 @@ def test_flash_forward_equals_the_one_lane_kernel(case, monkeypatch):
 @pytest.mark.parametrize("case", [
     "causal-64", "causal-128", "causal-192-128-long", "causal-256",
     "all-pairs-odd", "window-384-tiles-128", "window-384-tiles-256",
-    "q-256-k-128-window", "grouped-8-over-2", "grouped-6-over-2-window"])
+    "q-256-k-128-window", "grouped-8-over-2", "grouped-6-over-2-window",
+    "grouped-4-over-1-192-128", "q-128-k-256-192-128", "tiles-512-192-128"])
 def test_flash_gradients_through_the_forward(case):
     """The custom VJP, its backward kernels fed by the forward's ``out``
     and ``lse``, against the dot reference's gradients."""
     from ray_tpu.models import lm
-    S, H, KVH, D, Dv, causal, window, blk_q, blk_k, _ = FORWARD_CASES[case]
+    S, H, KVH, D, Dv, causal, window, blk_q, blk_k = FORWARD_CASES[case]
     with jax.default_matmul_precision("highest"):
         q, k, v, g = _heads(S, H, KVH, D, Dv, seed=len(case))
         got_out, got_vjp = jax.vjp(
