@@ -898,6 +898,29 @@ def train_moe_expert_load() -> Gauge:
         "balanced).")
 
 
+def train_moe_picked_mass() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_moe_picked_mass",
+        "Of a token's router probability (a softmax over all the experts), "
+        "the share its picked experts hold before the weights are "
+        "renormalised: the mean over tokens and expert layers of the last "
+        "recorded step (ops/moe.py route, score='softmax'). top_k / experts "
+        "is a flat router, 1.0 one whose picks hold everything.")
+
+
+def train_attn_window_tile_fill() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_attn_window_tile_fill",
+        "Of the (query, key) pairs in the tiles the flash kernels execute "
+        "for a sliding-window layer, the share the mask keeps, from "
+        "ops/flash_attention.py window_tile_census of the pair table the "
+        "last recorded step was built with (models/mellum.py "
+        "window_tile_fill): 0.667 at 16384 tokens, a window of 1024 and "
+        "tiles of 512, 0.800 at tiles of 256.")
+
+
 # -- delta-rule layers -----------------------------------------------------
 # Fed as the expert layers' scalars are (models/kimi_linear.py).
 

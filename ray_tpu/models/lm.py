@@ -13,8 +13,8 @@ second family needs of a first moves here.
 
 **A family** (``models/deepseek.py``, ``granite.py``, ``afmoe.py``,
 ``kimi_linear.py``, ``lfm2.py``, ``phi4flash.py``, ``glm_moe_dsa.py``,
-``evabyte.py``, ``minicpm_sala.py``) is three things, written against this
-module:
+``evabyte.py``, ``minicpm_sala.py``, ``mellum.py``) is three things, written
+against this module:
 
 * its config, a frozen dataclass under the published keys, with
   ``vocab_size``, ``hidden_size``, the epsilon of its norms, the program's
@@ -68,7 +68,7 @@ import itertools
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -569,21 +569,75 @@ def layernorm(x, scale, bias, eps):
             ).astype(x.dtype)
 
 
-def _rope(x, positions, theta: float, pairs):
-    half = x.shape[-1] // 2
+#: The ``rope_type`` values ``rope_table`` makes a table for.
+ROPE_TYPES = ("default", "yarn")
+
+
+def rope_table(parameters, head_dim: int):
+    """(inverse frequencies [head_dim / 2] float32, factor on cos and sin) of
+    one kind of layer from what a config publishes for it
+    (``rope_parameters``, or a bare theta): ``rope_type`` ``default`` is
+    ``theta^(-2i/D)`` and a factor of 1; ``yarn`` (``factor`` over
+    ``original_max_position_embeddings``, ``beta_fast`` 32, ``beta_slow`` 1,
+    ``attention_factor`` ``0.1 ln(factor) + 1`` where not given) blends each
+    pair's frequency with its ``factor``-th over a ramp of pairs::
+
+        d(n)  = D ln(original / (2 pi n)) / (2 ln theta)
+        low, high = floor(d(beta_fast)), ceil(d(beta_slow)), in [0, D - 1]
+        r[i]  = clip((i - low) / (high - low), 0, 1)
+        f[i]  = (1 - r[i]) theta^(-2i/D) + r[i] theta^(-2i/D) / factor
+
+    as ``transformers``' ``_compute_yarn_parameters`` does. The other
+    scalings a config may name (``linear``, ``dynamic``, ``llama3``,
+    ``longrope``) are refused."""
+    if not isinstance(parameters, Mapping):
+        parameters = {"rope_theta": parameters}
+    theta, half = parameters["rope_theta"], head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    kind = parameters.get("rope_type", "default")
+    if kind not in ROPE_TYPES:
+        raise NotImplementedError(
+            f"rope_type {kind!r}: the table is made for {ROPE_TYPES} only")
+    if kind == "default":
+        return freqs, 1.0
+    factor = parameters["factor"]
+    original = parameters["original_max_position_embeddings"]
+
+    def pair_of(rotations):
+        return head_dim * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(parameters.get("beta_fast") or 32)), 0)
+    high = min(math.ceil(pair_of(parameters.get("beta_slow") or 1)),
+               head_dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / ((high - low) or 0.001), 0.0, 1.0)
+    scaled = parameters.get("attention_factor")
+    if scaled is None:
+        scaled = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return freqs * (1.0 - ramp) + freqs / factor * ramp, float(scaled)
+
+
+def _rope(x, positions, table, pairs):
+    freqs, factor = table
+    half = x.shape[-1] // 2
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
     cos, sin = jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     first, second = pairs(x.astype(jnp.float32), half)
     return jnp.concatenate([first * cos - second * sin,
                             second * cos + first * sin], -1).astype(x.dtype)
 
 
-def rope(x, positions, theta: float):
+def rope(x, positions, theta):
     """Rotary embedding over the whole last axis of x [B, S, H, D], pairing
-    the halves: dimension i with i + D / 2 (angle pos * theta^(-2i/D)), as
-    published for ``models/afmoe.py`` and ``models/lfm2.py``."""
-    return _rope(x, positions, theta,
+    the halves: dimension i with i + D / 2, as published for
+    ``models/afmoe.py``, ``models/lfm2.py`` and ``models/mellum.py``.
+    ``theta`` is a float (angle pos * theta^(-2i/D)) or a kind of layer's
+    ``rope_parameters`` (``rope_table``: the angle from its table, cos and
+    sin times its factor)."""
+    return _rope(x, positions, rope_table(theta, x.shape[-1]),
                  lambda x, half: (x[..., :half], x[..., half:]))
 
 
@@ -592,7 +646,7 @@ def rope_interleaved(x, positions, theta: float):
     dimension 2i with 2i+1 (angle pos * theta^(-2i/R)) as published for
     ``deepseek_v3``; the result holds all first members, then all second
     (q and k alike, so scores are unchanged)."""
-    return _rope(x, positions, theta,
+    return _rope(x, positions, rope_table(theta, x.shape[-1]),
                  lambda x, half: (x[..., 0::2], x[..., 1::2]))
 
 
@@ -691,21 +745,22 @@ def held_experts(held, experts: int):
 
 
 def expert_leaves(d: int, experts: int, held, width: int,
-                  shared_width: int = 0):
-    """The leaves ``expert_ffn`` reads: the router over all ``experts`` and
-    its correction bias (the published ``expert_bias`` /
+                  shared_width: int = 0, bias: bool = True):
+    """The leaves ``expert_ffn`` reads: the router over all ``experts`` and,
+    with ``bias``, its correction bias (the published ``expert_bias`` /
     ``e_score_correction_bias``: a buffer of zeros that the gradient never
     moves), the SwiGLUs of ``width`` of the experts ``held`` (a config's
     ``experts_held``: (first, count), or None for all) and, with
     ``shared_width``, the shared experts' as one SwiGLU."""
     count = experts if held is None else held[1]
-    leaves = {
-        "router": ((d, experts), ("embed", None), 0.02),
-        "router_bias": ((experts,), (None,), zeros),
+    leaves = {"router": ((d, experts), ("embed", None), 0.02)}
+    if bias:
+        leaves["router_bias"] = ((experts,), (None,), zeros)
+    leaves.update({
         "w_gate": ((count, d, width), ("expert", "embed", "mlp"), 0.02),
         "w_up": ((count, d, width), ("expert", "embed", "mlp"), 0.02),
         "w_down": ((count, width, d), ("expert", "mlp", "embed"), 0.02),
-    }
+    })
     if shared_width:
         leaves.update(swiglu_leaves(d, shared_width, "shared_"))
     return leaves
@@ -715,34 +770,42 @@ def expert_aux(aux, batch_shape):
     """What a block returns of an expert layer, from ``routed_experts``'
     aux over the flattened tokens: ``picked`` [B, S, K], ``group_sizes``
     [held experts], ``asked`` (assignments the router gave them),
-    ``within_bound`` (1 where they fit ``ops/moe.py``'s one buffer) and
-    ``rows_summed`` (rows the way back to tokens read). With every expert
-    held the router's assignments are all asked, the one buffer holds them
-    and the weighted sum reads them all."""
+    ``within_bound`` (1 where they fit ``ops/moe.py``'s one buffer),
+    ``rows_summed`` (rows the way back to tokens read) and, of a softmax
+    router, ``picked_mass``. With every expert held the router's
+    assignments are all asked, the one buffer holds them and the weighted
+    sum reads them all."""
     routed = jnp.int32(aux["picked"].size)
-    return {"picked": aux["picked"].reshape(*batch_shape, -1),
-            "group_sizes": aux["group_sizes"],
-            "asked": aux.get("asked", routed),
-            "within_bound": aux.get("within_bound", jnp.int32(1)),
-            "rows_summed": aux.get("rows_summed", routed)}
+    out = {"picked": aux["picked"].reshape(*batch_shape, -1),
+           "group_sizes": aux["group_sizes"],
+           "asked": aux.get("asked", routed),
+           "within_bound": aux.get("within_bound", jnp.int32(1)),
+           "rows_summed": aux.get("rows_summed", routed)}
+    if "picked_mass" in aux:
+        out["picked_mass"] = aux["picked_mass"]
+    return out
 
 
 def expert_ffn(x, layer, *, top_k: int, scaling: float, normalize: bool,
-               held):
-    """The expert layer with shared experts on normed x [B, S, d] from a
-    layer's leaves (``router``, ``router_bias``, the held experts'
-    ``w_gate`` / ``w_up`` / ``w_down``, ``shared_*``): (this chip's part of
-    the routed sum, the shared experts, ``expert_aux``), the sums [B, S, d],
-    for the caller to add in its own order. ``held`` is ``ops/moe.py``'s."""
+               held, score: str = "sigmoid"):
+    """The expert layer on normed x [B, S, d] from a layer's leaves
+    (``router``, ``router_bias`` where the family has one, the held experts'
+    ``w_gate`` / ``w_up`` / ``w_down``, ``shared_*`` where it has shared
+    experts): (this chip's part of the routed sum, the shared experts or
+    None, ``expert_aux``), the sums [B, S, d], for the caller to add in its
+    own order. ``held`` and ``score`` are ``ops/moe.py``'s."""
     from ray_tpu.ops.moe import routed_experts
     B, S, d = x.shape
     routed, aux = routed_experts(
-        x.reshape(B * S, d), layer["router"], layer["router_bias"],
+        x.reshape(B * S, d), layer["router"], layer.get("router_bias"),
         layer["w_gate"], layer["w_up"], layer["w_down"],
-        top_k=top_k, scaling=scaling, normalize=normalize, held=held)
-    with jax.named_scope("shared_expert"):
-        shared = swiglu(x, layer["shared_w_gate"], layer["shared_w_up"],
-                        layer["shared_w_down"])
+        top_k=top_k, scaling=scaling, normalize=normalize, held=held,
+        score=score)
+    shared = None
+    if "shared_w_gate" in layer:
+        with jax.named_scope("shared_expert"):
+            shared = swiglu(x, layer["shared_w_gate"], layer["shared_w_up"],
+                            layer["shared_w_down"])
     aux = expert_aux(aux, (B, S))
     return routed.reshape(B, S, d), shared, aux
 

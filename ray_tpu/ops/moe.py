@@ -130,24 +130,41 @@ def grouped_matmul(rows, weights, group_sizes):
                         interpret=_flash._interpret())
 
 
-def route(x, router, bias, top_k: int, scaling: float, normalize: bool
-          ) -> Tuple[jax.Array, jax.Array]:
-    """Sigmoid scores in float32 and the ``top_k`` experts of each token.
+def route(x, router, bias, top_k: int, scaling: float, normalize: bool,
+          score: str = "sigmoid"
+          ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """Scores in float32 and the ``top_k`` experts of each token.
 
-    x [T, d], router [d, E], bias [E] -> (picked [T, K] int32, weights
-    [T, K] float32). The experts are picked by ``score + bias`` (the
-    correction bias of ``topk_method: noaux_tc``: selection only, no
-    gradient); the weights are the picked scores themselves, normalised to
-    sum to one when ``normalize``, times ``scaling``."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, picked = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+    x [T, d], router [d, E], bias [E] or None -> (picked [T, K] int32,
+    weights [T, K] float32, picked mass [T] or None). ``score`` is the
+    family's: ``"sigmoid"`` scores every expert by itself, and the experts
+    are picked by ``score + bias`` (the correction bias of ``topk_method:
+    noaux_tc``: selection only, no gradient); ``"softmax"`` scores them by a
+    softmax over all E and picks by that alone (there is no bias term), and
+    the third result is what of a token's probability its picked experts
+    hold before any normalising (a gauge: no gradient). The weights are the
+    picked scores themselves, normalised to sum to one when ``normalize``,
+    times ``scaling``."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    mass = None
+    if score == "softmax":
+        if bias is not None:
+            raise ValueError("a softmax router picks by its probabilities "
+                             "alone: no bias")
+        scores = jax.nn.softmax(logits, axis=-1)
+        top, picked = jax.lax.top_k(scores, top_k)
+        mass = jax.lax.stop_gradient(top.sum(-1))
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, picked = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+    else:
+        raise ValueError(f"score {score!r}: 'sigmoid' or 'softmax'")
     weights = jnp.take_along_axis(scores, picked, axis=-1)
     if normalize and top_k > 1:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
-    return picked, weights * scaling
+    return picked, weights * scaling, mass
 
 
 # Assignments are numbered choice-major: a = k * T + t. The [K * T, d] rows
@@ -535,16 +552,20 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
                    scaling: float, normalize: bool = True,
-                   held: Optional[Tuple[int, int]] = None
+                   held: Optional[Tuple[int, int]] = None,
+                   score: str = "sigmoid"
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """sum_i w_i Expert_i(x) over each token's ``top_k`` experts, dropless.
 
-    x [T, d] (compute dtype); router [d, E]; bias [E]; w_gate, w_up
-    [E, d, f]; w_down [E, f, d]. Expert_i is SwiGLU:
+    x [T, d] (compute dtype); router [d, E]; bias [E], or None under
+    ``score`` ``"softmax"`` (``route``); w_gate, w_up [E, d, f]; w_down [E,
+    f, d]. Expert_i is SwiGLU:
     ``(silu(x w_gate_i) * x w_up_i) w_down_i``. Returns (y [T, d], aux) with
     ``aux["picked"]`` [T, K] (the router's choice, for a reference to compare
-    with) and ``aux["group_sizes"]`` [E] (assignments each expert computed;
-    their sum is T * K, or something was dropped).
+    with), ``aux["group_sizes"]`` [E] (assignments each expert computed;
+    their sum is T * K, or something was dropped) and, of a softmax router,
+    ``aux["picked_mass"]`` (the mean over tokens of the probability the
+    picked experts hold before normalising).
 
     ``held = (first, count)``: the weights are those of experts ``first`` to
     ``first + count`` alone, [count, ...], of the router's E (the module
@@ -572,10 +593,13 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
             f"weights of {w_gate.shape[0]} experts, router of {n_experts}, "
             f"held={held}")
     with jax.named_scope("moe_route"):
-        picked, weights = route(x, router, bias, top_k, scaling, normalize)
+        picked, weights, mass = route(x, router, bias, top_k, scaling,
+                                      normalize, score)
+    gauges = {} if mass is None else {"picked_mass": mass.mean()}
     if first is not None:
-        return _share(x, picked, weights, w_gate.astype(dt), w_up.astype(dt),
-                      w_down.astype(dt), first, n_experts)
+        y, aux = _share(x, picked, weights, w_gate.astype(dt),
+                        w_up.astype(dt), w_down.astype(dt), first, n_experts)
+        return y, dict(aux, **gauges)
     with jax.named_scope("moe_dispatch"):
         expert_of = picked.T.reshape(-1)  # assignment a = k * T + t
         order = jnp.argsort(expert_of, stable=True).astype(jnp.int32)
@@ -588,7 +612,7 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
                              w_down.astype(dt), group_sizes)
     with jax.named_scope("moe_combine"):
         y = _combine(_unsort(out, order, inverse), weights.T)
-    return y, {"picked": picked, "group_sizes": group_sizes}
+    return y, {"picked": picked, "group_sizes": group_sizes, **gauges}
 
 
 def _share(x, picked, weights, w_gate, w_up, w_down, first: int,

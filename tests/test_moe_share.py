@@ -47,7 +47,7 @@ def _full_size(x, router, bias, w_gate, w_up, w_down, *, top_k, scaling,
                             interpret=True)
 
     with jax.named_scope("moe_route"):
-        picked, weights = moe.route(x, router, bias, top_k, scaling, True)
+        picked, weights, _ = moe.route(x, router, bias, top_k, scaling, True)
     with jax.named_scope("moe_dispatch"):
         expert_of = picked.T.reshape(-1)
         order = jnp.argsort(expert_of, stable=True).astype(jnp.int32)
@@ -108,13 +108,14 @@ ROUTINGS = {
 def _route_as(picked):
     """``moe.route`` with the choice planted and the weights still the
     router's own scores of it (so the router has a gradient)."""
-    def route(x, router, bias, top_k, scaling, normalize):
+    def route(x, router, bias, top_k, scaling, normalize,
+              score="sigmoid"):
         scores = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
         weights = jnp.take_along_axis(scores, picked, axis=-1)
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
-        return picked, weights * scaling
+        return picked, weights * scaling, None
     return route
 
 
@@ -355,9 +356,10 @@ def gathers_alone(monkeypatch):
 def _short_route_as(picked):
     """The choice planted and weights of eight significant bits (no
     gradient to the router but zeros)."""
-    def route(x, router, bias, top_k, scaling, normalize):
+    def route(x, router, bias, top_k, scaling, normalize,
+              score="sigmoid"):
         weights = _short_weights(jax.random.PRNGKey(3), picked.shape)
-        return picked, weights * jnp.sum(router) * 0 + weights
+        return picked, weights * jnp.sum(router) * 0 + weights, None
     return route
 
 
