@@ -17,10 +17,46 @@ three products of an expert SwiGLU forward and backward took 95.9 ms with
 ``ragged_dot`` (XLA's own grouped kernel, 53 TFLOP/s) and 52.8 ms with
 ``megablox`` at tiles of 512 x 512 x 1408 (97 TFLOP/s): PERF.md, PR 25.
 
-The backward pass of the two permutations is written out: the cotangent of
-a gather by a permutation is the gather by its inverse, where autodiff would
-emit a scatter-add, which a TPU serialises. So is that of the weighted sum,
-which would otherwise store float32 copies of ``[tokens, top_k, d]``.
+**The whole layer's row passes** (every expert held: ``tokens x top_k``
+rows, all of them some expert's). No pass scatters and none selects. The
+sort gives ``order``, a second sort its inverse ``place``, and the groups'
+sizes are ``[experts, tokens x top_k]`` comparisons summed in one fusion,
+where a scatter and a scatter-add would be serialised by a TPU, twice a
+step under remat. Every row pass is a gather that promises its indices in
+bounds (``_rows``): ``jnp.take``'s default compares each index with the
+bounds and selects over the whole ``[tokens x top_k, d]`` result. Scalars
+that follow a permutation (the weights into sorted order, ``d weights`` out
+of it) ride a sort keyed by the inverse permutation (``_permuted``): the
+chip sorts 131,072 pairs in 0.1 ms and gathers as many scalars in 0.9-1.7.
+The backward pass of both ways is written out. ``_dispatch``'s is the
+gather by ``place`` and a float32 sum over the ``top_k`` slabs, where
+autodiff would emit a scatter-add. The way back to tokens,
+``_all_to_tokens`` (the un-sort and the weighted sum, a token's terms in
+float32 in the order of k), keeps the *sorted* rows ``out`` and never the
+un-sorted ones: its backward gathers ``g`` [tokens, d] by ``order %
+tokens`` and multiplies by the sorted weights on the gather's result (``d
+out``, in sorted order, with no ``[top_k, tokens, d]`` slabs of ``w * g``
+to build and then permute), and takes ``d weights`` from ``(out *
+g_rows).sum(-1)`` back to ``[top_k, tokens]``. So under a block's ``remat``
+the second forward stops at the down product: nothing asks it for the
+un-sort or the sum. It is the share's backward (``_buffer_backward``) in
+form, and the two differ where their rows do. The whole layer holds every
+row, so its ways to and from tokens are XLA's gathers, whose cost is that
+of ``tokens x top_k`` rows either way; the share holds a fraction of them,
+and its way back is the kernel ``moe_rows_to_tokens``, which costs by the
+held row (0.1 us each: 13 ms at Mellum's 131,072 rows where the gather and
+the sum take 6.7) and wins by skipping the rest. The whole layer's three
+products stay under autodiff as ``megablox``'s own ``custom_vjp`` (the share
+runs them under ``jax.vjp`` in a loop over buffers that autodiff must not
+see; here there is one buffer and no loop to hide). What a gather costs is
+decided by where its table lies: rows of a ``[tokens, d]`` table that the
+compiler has placed in the chip's fast memory (``x`` for the dispatch,
+``g`` for the way back's backward) come at the speed of the write, 0.9 ms
+for 131,072 rows of 2304, rows of a ``[tokens x top_k, d]`` table come from
+HBM one at a time, 4.9 ms; a ``while`` between a table's producer and its
+gather (``jnp.searchsorted``'s default) sends the table back to HBM, which
+is why the sizes are not searched for here. Measured on the v5e: PERF.md,
+PR 59.
 
 **Held experts.** A chip that shares a layer's experts with others holds a
 contiguous run of them, ``held = (first, count)``, and gives
@@ -52,7 +88,7 @@ routing within the bound, and nothing is cut on any routing.
 
 **The way back to tokens reads only the rows the buffer holds.**
 ``out[t] = sum_k [w[k, t] *] rows[at[k * T + t]]`` in float32, a token's
-terms in the order ``_combine`` sums them and an assignment that is not in
+terms in the order ``_all_to_tokens`` sums them and an assignment not in
 the buffer adding nothing, is the Pallas kernel ``moe_rows_to_tokens``
 wherever the shapes tile (``_token_tile``: rows of bfloat16 or float32 whose
 width is a multiple of 128, tokens a multiple of 128), in the forward's
@@ -173,19 +209,33 @@ def route(x, router, bias, top_k: int, scaling: float, normalize: bool,
 # tile (6 -> 8) and turn every reshape into a copy.
 
 
+def _rows(table, at):
+    """table[at] by rows, every index promised in bounds (a gather with no
+    select over its result)."""
+    return table.at[at].get(mode="promise_in_bounds")
+
+
+def _permuted(values, to):
+    """out[to[i]] = values[i] for a permutation ``to``: a sort by ``to`` that
+    carries the values. What a gather by the inverse permutation gives, and
+    a TPU sorts 131,072 pairs in 0.1 ms where it gathers as many scalars in
+    0.9-1.7 (PERF.md, PR 59)."""
+    return jax.lax.sort((to, values), num_keys=1)[1]
+
+
 @jax.custom_vjp
-def _dispatch(x, order, inverse):
+def _dispatch(x, order, place):
     """Rows of x [T, d] in expert order: x[order % T]."""
-    return jnp.take(x, order % x.shape[0], axis=0)
+    return _rows(x, order % x.shape[0])
 
 
-def _dispatch_fwd(x, order, inverse):
-    return _dispatch(x, order, inverse), (inverse, x.shape[0])
+def _dispatch_fwd(x, order, place):
+    return _dispatch(x, order, place), (place, x.shape[0])
 
 
 def _dispatch_bwd(residuals, g):
-    inverse, tokens = residuals
-    back = jnp.take(g, inverse, axis=0).reshape(-1, tokens, g.shape[-1])
+    place, tokens = residuals
+    back = _rows(g, place).reshape(-1, tokens, g.shape[-1])
     return back.astype(jnp.float32).sum(0).astype(g.dtype), None, None
 
 
@@ -193,52 +243,35 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _unsort(y, order, inverse):
-    """Rows of y [K * T, d] back in assignment (choice-major) order."""
-    return jnp.take(y, inverse, axis=0)
-
-
-def _unsort_fwd(y, order, inverse):
-    return _unsort(y, order, inverse), order
-
-
-def _unsort_bwd(order, g):
-    return jnp.take(g, order, axis=0), None, None
-
-
-_unsort.defvjp(_unsort_fwd, _unsort_bwd)
-
-
-@jax.custom_vjp
-def _combine(flat, weights):
-    """sum_k weights[k, t] * flat[k * T + t, :] in float32 -> [T, d] in
-    flat's dtype. Written out with its backward pass, slab by slab, so that
-    no float32 copy of the [K * T, d] rows is ever stored: the products
-    accumulate in float32 straight from the stored dtype."""
+def _all_to_tokens(out, weights, order, place):
+    """sum_k weights[k, t] * out[place[k * T + t]] in float32, a token's
+    terms in the order of k -> [T, d] in out's dtype: every assignment's
+    row, sorted by expert, back at its token. The K slabs of [T, d] are
+    summed straight from the stored dtype, so no float32 copy of the
+    [K * T, d] rows is stored; the backward pass reads the sorted rows and
+    gathers ``g`` by token (the module text)."""
     tokens = weights.shape[1]
+    flat = _rows(out, place)
     acc = sum(flat[k * tokens:(k + 1) * tokens].astype(jnp.float32)
               * weights[k][:, None] for k in range(weights.shape[0]))
-    return acc.astype(flat.dtype)
+    return acc.astype(out.dtype)
 
 
-def _combine_fwd(flat, weights):
-    return _combine(flat, weights), (flat, weights)
+def _all_to_tokens_fwd(out, weights, order, place):
+    return _all_to_tokens(out, weights, order, place), (
+        out, weights, order, place)
 
 
-def _combine_bwd(residuals, g):
-    flat, weights = residuals
-    tokens = weights.shape[1]
-    g32 = g.astype(jnp.float32)
-    slabs = range(weights.shape[0])
-    d_flat = jnp.concatenate(
-        [(g32 * weights[k][:, None]).astype(flat.dtype) for k in slabs])
-    d_weights = jnp.stack(
-        [(flat[k * tokens:(k + 1) * tokens].astype(jnp.float32) * g32
-          ).sum(-1) for k in slabs])
-    return d_flat, d_weights
+def _all_to_tokens_bwd(residuals, g):
+    out, weights, order, place = residuals
+    g_rows = _rows(g, order % weights.shape[1]).astype(jnp.float32)
+    w_rows = _permuted(weights.reshape(-1), place)
+    d_out = (g_rows * w_rows[:, None]).astype(out.dtype)
+    d_w_rows = (out.astype(jnp.float32) * g_rows).sum(-1)
+    return d_out, _permuted(d_w_rows, order).reshape(weights.shape), None, None
 
 
-_combine.defvjp(_combine_fwd, _combine_bwd)
+_all_to_tokens.defvjp(_all_to_tokens_fwd, _all_to_tokens_bwd)
 
 
 def _swiglu_groups(rows, w_gate, w_up, w_down, group_sizes):
@@ -280,12 +313,6 @@ def _buffer(i, bound: int, order, sizes):
         [here, (bound - here.sum())[None]])
 
 
-def _rows(table, at):
-    """table[at] by rows, every index promised in bounds (a gather with no
-    select over its result)."""
-    return table.at[at].get(mode="promise_in_bounds")
-
-
 def _rows_or_zero(table, at):
     """table[at] by rows, and an exact zero where ``at`` is the table's
     length: no row, whatever row the gather read in its place."""
@@ -304,7 +331,7 @@ def _at(place, i, bound: int, groups):
 def _to_tokens_xla(rows, at, tokens: int, weights=None):
     """sum_k [weights[k, t] *] rows[at[k * T + t]] in float32 -> [T, d]: a
     buffer's rows [bound, d] summed into their tokens by gathers alone, a
-    slab of T rows a choice and in the order ``_combine`` sums them; an
+    slab of T rows a choice and in the order ``_all_to_tokens`` sums them; an
     assignment that is not in the buffer adds an exact zero. The form of
     shapes that do not tile, and the kernel's oracle."""
     slabs = at.reshape(-1, tokens)
@@ -603,15 +630,17 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
     with jax.named_scope("moe_dispatch"):
         expert_of = picked.T.reshape(-1)  # assignment a = k * T + t
         order = jnp.argsort(expert_of, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=jnp.int32))
-        group_sizes = jnp.zeros((n_experts,), jnp.int32).at[expert_of].add(1)
-        rows = _dispatch(x, order, inverse)  # [K*T, d], grouped by expert
+        place = jnp.argsort(order).astype(jnp.int32)
+        # The histogram as [E, K * T] comparisons summed in one fusion: no
+        # scatter-add, and no sorted keys to gather for a search.
+        group_sizes = (expert_of == jnp.arange(n_experts)[:, None]).sum(
+            -1, dtype=jnp.int32)
+        rows = _dispatch(x, order, place)  # [K*T, d], grouped by expert
     with jax.named_scope("moe_experts"):
         out = _swiglu_groups(rows, w_gate.astype(dt), w_up.astype(dt),
                              w_down.astype(dt), group_sizes)
     with jax.named_scope("moe_combine"):
-        y = _combine(_unsort(out, order, inverse), weights.T)
+        y = _all_to_tokens(out, weights.T, order, place)
     return y, {"picked": picked, "group_sizes": group_sizes, **gauges}
 
 
@@ -627,8 +656,8 @@ def _share(x, picked, weights, w_gate, w_up, w_down, first: int,
         is_held = (expert_of >= first) & (expert_of < first + count)
         key = jnp.where(is_held, expert_of - first, count)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        # Sorts and searches, where the whole layer scatters: a TPU
-        # serialises a scatter, and this runs twice a step under remat.
+        # Sorts and searches, no scatter: a TPU serialises a scatter, and
+        # this runs twice a step under remat.
         place = jnp.argsort(order).astype(jnp.int32)
         starts = jnp.searchsorted(key[order], jnp.arange(count + 1),
                                   side="left").astype(jnp.int32)

@@ -792,13 +792,18 @@ def test_the_lfm2_cells_compiled_step_gathers_no_slab_of_tokens(topo):
 #: prints it) and says so in CHANGES.md. PR 48 meant to change granite's
 #: (``lm.gated_norm`` behind the scan; e0101a71d47b before it), PR 57
 #: Moonlight's (the forward's statistics lane-dense at 192 | 128;
-#: 030ce9c909a1 before it).
+#: 030ce9c909a1 before it), PR 59 Moonlight's again and Mellum's, which it
+#: lists from then on (the whole expert layer's row passes: gathers that
+#: promise their indices, a sort and a comparison for the two scatters,
+#: scalars permuted by a sort, a way back to tokens whose backward reads
+#: the sorted rows; c02822046105 and 96a2c501bc69 before it).
 LOWERED_STEPS = {
     "gptj-6b-1chip.steady": "b470aa16aac6",
     "gptj-6b-4chip.steady": "42d82d54bed3",
-    "moonlight-16b-a3b-1chip.steady": "c02822046105",
+    "moonlight-16b-a3b-1chip.steady": "88ae7b5365ee",
     "granite-4.0-h-micro-1chip.steady": "6dff69cbb9be",
     "phi-4-mini-flash-reasoning-1chip.steady": "b8326d36469b",
+    "mellum2-12b-a2.5b-1chip.steady": "ebb20e464562",
 }
 
 
@@ -829,9 +834,9 @@ def _lowered_digest(step, args):
 
 @pytest.mark.parametrize("cell", LOWERED_STEPS)
 def test_a_step_without_a_share_is_the_program_it_was(topo, cell):
-    """The five cells whose model holds every expert or none (the GPT-J
-    cells, Moonlight's whole layer, granite, phi) lower to the text
-    recorded above: nothing they run was touched since."""
+    """The six cells whose model holds every expert or none (the GPT-J
+    cells, Moonlight's and Mellum's whole layers, granite, phi) lower to
+    the text recorded above: nothing they run was touched since."""
     step, args, _ = _a_cells_step(topo, cell)
     assert _lowered_digest(step, args) == LOWERED_STEPS[cell]
 
@@ -1185,7 +1190,7 @@ def test_step_sends_what_fsdp_x_tp_needs(topo, parallel_block):
 
 
 def test_every_cells_step_was_loaded_and_traced_once(topo):
-    """The file's last case: the cases above name their cells 23 times, and
+    """The file's last case: the cases above name their cells 24 times, and
     each cell's step was built and traced the first time, and only then.
     Alone, it names the cells itself: the count holds in any selection."""
     named = sorted(set(LOWERED_STEPS) | set(SHARE_SHAPES))

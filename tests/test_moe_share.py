@@ -5,6 +5,7 @@ as the reference: the layer as it stood before the buffers)."""
 
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,8 @@ def _full_size(x, router, bias, w_gate, w_up, w_down, *, top_k, scaling,
                held=None):
     """``routed_experts`` with every pass at ``tokens x top_k`` rows: sorted
     by expert, the grouped matmuls from group ``first`` on, the other rows
-    zero."""
+    zero. The row passes are ``jax.numpy`` indexing under autodiff: nothing
+    of ``ops/moe.py`` but the router and the grouped matmul."""
     n_experts, dt = router.shape[-1], x.dtype
     first, count = None, n_experts
     if held is not None and tuple(held) != (0, n_experts):
@@ -46,21 +48,16 @@ def _full_size(x, router, bias, w_gate, w_up, w_down, *, top_k, scaling,
                             (128, 128, 128), jnp.asarray(first, jnp.int32),
                             interpret=True)
 
-    with jax.named_scope("moe_route"):
-        picked, weights, _ = moe.route(x, router, bias, top_k, scaling, True)
-    with jax.named_scope("moe_dispatch"):
-        expert_of = picked.T.reshape(-1)
-        order = jnp.argsort(expert_of, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=jnp.int32))
-        group_sizes = jnp.zeros((n_experts,), jnp.int32).at[expert_of].add(1)
-        rows = moe._dispatch(x, order, inverse)
-    with jax.named_scope("moe_experts"):
-        gate = matmul(rows, w_gate.astype(dt))
-        up = matmul(rows, w_up.astype(dt))
-        out = matmul(jax.nn.silu(gate) * up, w_down.astype(dt))
-    with jax.named_scope("moe_combine"):
-        y = moe._combine(moe._unsort(out, order, inverse), weights.T)
+    picked, weights, _ = moe.route(x, router, bias, top_k, scaling, True)
+    expert_of = picked.T.reshape(-1)
+    order = jnp.argsort(expert_of, stable=True)
+    group_sizes = (expert_of[:, None] == jnp.arange(n_experts)).sum(0)
+    rows = x[order % x.shape[0]]
+    gate = matmul(rows, w_gate.astype(dt))
+    up = matmul(rows, w_up.astype(dt))
+    out = matmul(jax.nn.silu(gate) * up, w_down.astype(dt))
+    flat = out[jnp.argsort(order)].reshape(top_k, -1, out.shape[-1])
+    y = (flat.astype(jnp.float32) * weights.T[:, :, None]).sum(0).astype(dt)
     if first is None:
         return y, {"picked": picked, "group_sizes": group_sizes}
     return y, {"picked": picked,
@@ -209,16 +206,170 @@ def test_the_bound_is_twice_the_even_share_in_whole_tiles():
 @pytest.mark.parametrize("widths", [(32, 16), (128, 128)],
                          ids=["ragged_dot", "megablox"])
 def test_with_every_expert_held_the_program_is_as_it_was(widths, held):
-    """The whole layer does not go through the buffers: its lowered text is
-    that of the layer as it stood."""
+    """The whole layer does not go through the buffers: said either way,
+    its lowered text is one program, with neither the buffers' functions
+    nor their kernel in it (a share's has both)."""
     args = _layer(*widths)
 
-    def lowered(layer_fn):
-        def layer(*args):
-            return layer_fn(*args, top_k=TOP_K, scaling=2.0, held=held)
+    def lowered(held, cut=EXPERTS):
+        def layer(x, router, bias, *experts):
+            return moe.routed_experts(
+                x, router, bias, *(w[:cut] for w in experts), top_k=TOP_K,
+                scaling=2.0, held=held)
         return jax.jit(layer).lower(*args).as_text()
 
-    assert lowered(moe.routed_experts) == lowered(_full_size)
+    text = lowered(held)
+    assert text == lowered((0, EXPERTS) if held is None else None)
+    assert "_buffer_forward" not in text
+    assert "_buffer_forward" in lowered((0, COUNT), COUNT)
+
+
+# -- the whole layer: every assignment's row, by gathers alone ---------------
+
+
+def _every_expert(x, router, bias, w_gate, w_up, w_down, *, top_k, scaling,
+                  score):
+    """The layer with no sort and no group: every expert's SwiGLU on every
+    token, and a token's sum over the experts weighted by what the router
+    gave each (zero where it was not picked)."""
+    picked, weights, _ = moe.route(x, router, bias, top_k, scaling, True,
+                                   score)
+    of_expert = (weights[:, :, None] * jax.nn.one_hot(
+        picked, router.shape[-1])).sum(1)
+    gate = jnp.einsum("td,edf->etf", x, w_gate)
+    up = jnp.einsum("td,edf->etf", x, w_up)
+    out = jnp.einsum("etf,efd->etd", jax.nn.silu(gate) * up, w_down)
+    return jnp.einsum("te,etd->td", of_expert, out), {"picked": picked}
+
+
+@functools.lru_cache(maxsize=None)
+def _whole_layer(layer_fn, score):
+    """``layer_fn`` on all the experts compiled with its five gradients."""
+    def loss(x, router, bias, *experts):
+        y, aux = layer_fn(x, router, bias, *experts, top_k=TOP_K,
+                          scaling=2.0, score=score)
+        return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum(), \
+            (y, aux["picked"])
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5),
+                                      has_aux=True))
+
+
+@pytest.mark.parametrize("score", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("widths", [(32, 16), (128, 128)],
+                         ids=["ragged_dot", "megablox"])
+def test_the_whole_layer_is_every_experts_weighted_sum(widths, score):
+    """Output and the gradients to x, the router and the three expert
+    leaves of the whole layer are those of the layer computed expert by
+    expert on every token, under a sigmoid and a softmax router, at widths
+    that take ``ragged_dot`` and at widths that tile."""
+    x, router, bias, *experts = _layer(*widths)
+    bias = bias if score == "sigmoid" else None
+    with jax.default_matmul_precision("highest"):
+        (_, (y, picked)), grads = _whole_layer(moe.routed_experts, score)(
+            x, router, bias, *experts)
+        (_, (want_y, want_picked)), want_grads = _whole_layer(
+            _every_expert, score)(x, router, bias, *experts)
+    assert (picked == want_picked).all()
+    np.testing.assert_allclose(
+        y, want_y, atol=1e-5 * float(jnp.abs(want_y).max()))
+    for got, want in zip(grads, want_grads):
+        assert np.asarray(want).any()
+        np.testing.assert_allclose(
+            got, want, atol=1e-5 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_whole_layers_row_passes_are_the_gathers_autodiff_transposes(
+        dtype):
+    """``_dispatch`` and ``_all_to_tokens`` with their written-out backward
+    passes against ``jax.numpy`` indexing under autodiff: the rows, the sum
+    and the cotangents of x, of the sorted rows and of the weights [K, T].
+    In bfloat16 the forward's bits are those of the float32 sum over k."""
+    d, exact = 32, dtype == jnp.float32
+    keys = jax.random.split(jax.random.PRNGKey(4), 4)
+    expert_of = jax.random.randint(keys[0], (TOP_K * TOKENS,), 0, EXPERTS)
+    order = jnp.argsort(expert_of, stable=True).astype(jnp.int32)
+    place = jnp.argsort(order).astype(jnp.int32)
+    np.testing.assert_array_equal(moe._permuted(order, place), order[order])
+    x = jax.random.normal(keys[1], (TOKENS, d)).astype(dtype)
+    out = jax.random.normal(keys[2], (TOP_K * TOKENS, d)).astype(dtype)
+    weights = jax.random.uniform(keys[3], (TOP_K, TOKENS), minval=0.1)
+
+    def ours(x, out, weights):
+        return (moe._dispatch(x, order, place),
+                moe._all_to_tokens(out, weights, order, place))
+
+    def plain(x, out, weights):
+        flat = out[place].reshape(TOP_K, TOKENS, d).astype(jnp.float32)
+        return x[order % TOKENS], sum(
+            flat[k] * weights[k][:, None] for k in range(TOP_K)
+        ).astype(dtype)
+
+    def grads(fn):
+        def loss(*args):
+            rows, y = fn(*args)
+            wave = jnp.cos(jnp.arange(rows.size, dtype=jnp.float32))
+            return ((rows.astype(jnp.float32) * wave.reshape(rows.shape)
+                     ).sum() + (y.astype(jnp.float32)
+                                * wave[:y.size].reshape(y.shape)).sum())
+        return jax.grad(loss, argnums=(0, 1, 2))(x, out, weights)
+
+    for got, want in zip(ours(x, out, weights), plain(x, out, weights)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+    for got, want in zip(grads(ours), grads(plain)):
+        assert got.dtype == want.dtype
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=1e-6 if exact else 2e-2, atol=1e-5 if exact else 2e-2)
+
+
+@pytest.mark.parametrize("score", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("widths", [(32, 16), (128, 128)],
+                         ids=["ragged_dot", "megablox"])
+def test_the_whole_layer_lowers_to_no_scatter_and_no_select_of_rows(
+        widths, score):
+    """The whole layer's lowered text, forward and with its gradients: no
+    ``select`` over a [K * T, d] array (a gather that promises its indices
+    in bounds has none) and no ``scatter`` but the router's own (the
+    transpose of ``route``'s pick of the scores, [T, K] scalars, and what
+    the interpreted grouped matmul scatters over its groups and tiles): none
+    over the K * T assignments."""
+    x, router, bias, *experts = _layer(*widths)
+    bias = bias if score == "sigmoid" else None
+
+    def layer(x, router, *experts):
+        return moe.routed_experts(x, router, bias, *experts, top_k=TOP_K,
+                                  scaling=2.0, score=score)[0]
+
+    def routed(x, router):
+        return moe.route(x, router, bias, TOP_K, 2.0, True, score)[1]
+
+    def texts(fn, *args):
+        def loss(*args):
+            return fn(*args).sum()
+        return (jax.jit(fn).lower(*args).as_text(),
+                jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))
+                        ).lower(*args).as_text())
+
+    def scattered(text):
+        """The operand types of every scatter of the text."""
+        return re.findall(r'"stablehlo\.scatter"\(.*?\}\) : \(([^)]*)\)', text,
+                          re.DOTALL)
+
+    assigned = f"tensor<{TOP_K * TOKENS}x"
+    rows = f"{assigned}{widths[0]}x"
+    forward, backward = texts(layer, x, router, *experts)
+    _, routers_own = texts(routed, x, router)
+    assert "stablehlo.gather" in forward and rows in backward
+    for text in (forward, backward):
+        assert not [line for line in text.splitlines()
+                    if "stablehlo.select" in line and rows in line]
+        # What megablox scatters for its tiles' metadata is [E] and [tiles].
+        assert not [types for types in scattered(text) if assigned in types]
+    assert len(scattered(routers_own)) == 1
+    assert scattered(routers_own)[0] in scattered(backward)
 
 
 # -- the way back to tokens: the kernel against the gathers ------------------
