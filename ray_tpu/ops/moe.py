@@ -19,15 +19,10 @@ three products of an expert SwiGLU forward and backward took 95.9 ms with
 
 **The whole layer's row passes** (every expert held: ``tokens x top_k``
 rows, all of them some expert's). No pass scatters and none selects. The
-sort gives ``order``, a second sort its inverse ``place``, and the groups'
-sizes are ``[experts, tokens x top_k]`` comparisons summed in one fusion,
-where a scatter and a scatter-add would be serialised by a TPU, twice a
-step under remat. Every row pass is a gather that promises its indices in
-bounds (``_rows``): ``jnp.take``'s default compares each index with the
-bounds and selects over the whole ``[tokens x top_k, d]`` result. Scalars
-that follow a permutation (the weights into sorted order, ``d weights`` out
-of it) ride a sort keyed by the inverse permutation (``_permuted``): the
-chip sorts 131,072 pairs in 0.1 ms and gathers as many scalars in 0.9-1.7.
+sort gives ``order``, a second sort its inverse ``place``. Every row pass
+is a gather that promises its indices in bounds (``_rows``): ``jnp.take``'s
+default compares each index with the bounds and selects over the whole
+``[tokens x top_k, d]`` result.
 The backward pass of both ways is written out. ``_dispatch``'s is the
 gather by ``place`` and a float32 sum over the ``top_k`` slabs, where
 autodiff would emit a scatter-add. The way back to tokens,
@@ -58,6 +53,19 @@ gather (``jnp.searchsorted``'s default) sends the table back to HBM, which
 is why the sizes are not searched for here. Measured on the v5e: PERF.md,
 PR 59.
 
+**The scalars ride sorts and comparisons**, in the whole layer and in a
+share alike: none is gathered, searched for or scattered (a TPU serialises
+a scatter, gathers 131,072 scalars in 0.9-1.7 ms and sorts as many pairs in
+0.1; PERF.md, PRs 59 and 61). The groups' sizes are ``[experts, tokens x
+top_k]`` comparisons summed in one fusion (``_group_sizes``). What follows
+a permutation, the weights into expert order and ``d weights`` out of it,
+rides a sort keyed by the inverse permutation (``_permuted``); a share does
+both once a call, outside its loop over buffers, a buffer taking its slice
+of the one and laying its rows into the other (``_laid``). ``route`` picks
+its ``top_k`` scores as a sum over E of one score and zeros, whose
+transpose is a sum over K where ``take_along_axis``'s was the one scatter
+of an expert layer.
+
 **Held experts.** A chip that shares a layer's experts with others holds a
 contiguous run of them, ``held = (first, count)``, and gives
 ``routed_experts`` the weights of those alone. The router keeps its whole
@@ -74,7 +82,8 @@ Dropless on the share: every assignment to a held expert is computed, and
 
 **The share moves only its own rows.** The sort's key is the expert if it is
 held and one past the last held expert if not, so the assignments to held
-experts are the first rows of the order, and a buffer is a slice of it.
+experts are the first rows of the order, and a buffer is a slice of it (and
+of whatever else lies in that order: the weights, for the backward pass).
 The buffer has ``_held_bound`` rows: twice what even routing would give the
 held experts, ``2 * tokens * top_k * count / experts`` rounded up to the row
 tile, computed from what ``routed_experts`` sees and set nowhere. The
@@ -142,6 +151,17 @@ _flash = importlib.import_module("ray_tpu.ops.flash_attention")
 _TILE_M, _TILE_K, _TILE_N_MAX = 512, 512, 1408
 
 
+def _picked_scores(scores, picked):
+    """scores [T, E] at picked [T, K], as a sum over E of one score and
+    zeros: ``take_along_axis``'s bits, and a transpose that is a sum over K
+    where a gather's scatters. Behind a barrier, or XLA makes one sum over K
+    and E of this and ``route``'s normalising sum: a token's K terms in
+    another order, and the [T, K, E] pass twice."""
+    hot = picked[..., None] == jnp.arange(scores.shape[-1])
+    return jax.lax.optimization_barrier(
+        jnp.where(hot, scores[:, None, :], 0).sum(-1))
+
+
 def grouped_matmul(rows, weights, group_sizes):
     """rows [M, k], sorted into ``len(group_sizes)`` contiguous groups, times
     each group's own weights [E, k, n] -> [M, n] in rows' dtype. Where there
@@ -197,7 +217,7 @@ def route(x, router, bias, top_k: int, scaling: float, normalize: bool,
             scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
     else:
         raise ValueError(f"score {score!r}: 'sigmoid' or 'softmax'")
-    weights = jnp.take_along_axis(scores, picked, axis=-1)
+    weights = _picked_scores(scores, picked)
     if normalize and top_k > 1:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
     return picked, weights * scaling, mass
@@ -221,6 +241,12 @@ def _permuted(values, to):
     a TPU sorts 131,072 pairs in 0.1 ms where it gathers as many scalars in
     0.9-1.7 (PERF.md, PR 59)."""
     return jax.lax.sort((to, values), num_keys=1)[1]
+
+
+def _group_sizes(key, count: int):
+    """How many of ``key`` [K * T] are 0, 1, ... ``count - 1``, by [count,
+    K * T] comparisons summed in one fusion (the module text)."""
+    return (key == jnp.arange(count)[:, None]).sum(-1, dtype=jnp.int32)
 
 
 @jax.custom_vjp
@@ -301,16 +327,17 @@ def _buffers_needed(asked, bound: int):
     return (asked + bound - 1) // bound
 
 
-def _buffer(i, bound: int, order, sizes):
-    """Buffer ``i`` of the held rows: (the assignments of its ``bound``
-    rows, in expert order; its groups [count + 1]: the held experts' rows
-    that lie in it, then the rows past them, which no expert is given)."""
+def _buffer(i, bound: int, sizes, *in_order):
+    """Buffer ``i`` of the held rows: (its groups [count + 1]: the held
+    experts' rows that lie in it, then the rows past them, which no expert
+    is given; its ``bound`` rows of each of ``in_order``, arrays in expert
+    order of whole buffers: ``order`` itself for the rows' assignments)."""
     lo = i * bound
     ends = jnp.cumsum(sizes)
     here = (jnp.clip(ends, lo, lo + bound)
             - jnp.clip(ends - sizes, lo, lo + bound))
-    return jax.lax.dynamic_slice(order, (lo,), (bound,)), jnp.concatenate(
-        [here, (bound - here.sum())[None]])
+    return (jnp.concatenate([here, (bound - here.sum())[None]]),
+            *(jax.lax.dynamic_slice(a, (lo,), (bound,)) for a in in_order))
 
 
 def _rows_or_zero(table, at):
@@ -502,7 +529,7 @@ def _buffer_forward(bound, i, x, weights, w_gate, w_up, w_down, order, place,
     held ones where the kernel ran, one a routed assignment where the
     gathers did."""
     tokens = x.shape[0]
-    rows_of, groups = _buffer(i, bound, order, sizes)
+    groups, rows_of = _buffer(i, bound, sizes, order)
     with jax.named_scope("moe_dispatch"):
         rows = _rows(x, rows_of % tokens)
     with jax.named_scope("moe_experts"):
@@ -515,20 +542,32 @@ def _buffer_forward(bound, i, x, weights, w_gate, w_up, w_down, order, place,
         return _to_tokens(out, at, tokens, weights), groups[:-1], summed
 
 
+def _laid(values, i, bound: int, groups, length: int):
+    """``values`` [bound], a number a row of buffer ``i``, at that buffer's
+    place among ``length`` rows in expert order: exact zeros at the rows of
+    the other buffers and at those of this one that no held expert is
+    given."""
+    held = jnp.arange(bound) < groups[:-1].sum()
+    return jax.lax.dynamic_update_slice(
+        jnp.zeros(length, values.dtype), jnp.where(held, values, 0),
+        (i * bound,))
+
+
 @partial(jax.jit, static_argnums=(0,))
-def _buffer_backward(bound, i, g, x, weights, w_gate, w_up, w_down, order,
+def _buffer_backward(bound, i, g, x, w_sorted, w_gate, w_up, w_down, order,
                      place, sizes):
-    """(d x in float32, d weights, [d w_gate, d w_up, d w_down]) of the rows
-    of buffer ``i``, which it multiplies again, from g = d y."""
+    """(d x in float32, d weights in expert order [as long as ``order``],
+    [d w_gate, d w_up, d w_down]) of the rows of buffer ``i``, which it
+    multiplies again, from g = d y and ``w_sorted``, the weights in expert
+    order."""
     tokens = x.shape[0]
-    rows_of, groups = _buffer(i, bound, order, sizes)
+    groups, rows_of, w_rows = _buffer(i, bound, sizes, order, w_sorted)
     at = _at(place, i, bound, groups)
     with jax.named_scope("moe_dispatch"):
         token_of = rows_of % tokens
         rows = _rows(x, token_of)
     with jax.named_scope("moe_combine"):
         g_rows = _rows(g, token_of).astype(jnp.float32)
-        w_rows = _rows(weights.reshape(-1), rows_of)
         d_out = (g_rows * w_rows[:, None]).astype(x.dtype)
     with jax.named_scope("moe_experts"):
         out, experts_vjp = jax.vjp(
@@ -537,9 +576,9 @@ def _buffer_backward(bound, i, g, x, weights, w_gate, w_up, w_down, order,
         d_rows, *d_experts = experts_vjp(d_out)
     with jax.named_scope("moe_combine"):
         d_w_rows = (out.astype(jnp.float32) * g_rows).sum(-1)
-        d_weights = _rows_or_zero(d_w_rows, at).reshape(weights.shape)
+        d_w_sorted = _laid(d_w_rows, i, bound, groups, order.shape[0])
     with jax.named_scope("moe_dispatch"):
-        return _to_tokens(d_rows, at, tokens), d_weights, d_experts
+        return _to_tokens(d_rows, at, tokens), d_w_sorted, d_experts
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -567,11 +606,20 @@ def _held_experts_fwd(bound, *args):
 
 
 def _held_experts_bwd(bound, residuals, cotangents):
-    x, *_, sizes = residuals
-    d_x, d_weights, d_experts = _over_buffers(
-        lambda i: _buffer_backward(bound, i, cotangents[0], *residuals),
+    x, weights, w_gate, w_up, w_down, order, place, sizes = residuals
+    # ``weights`` has no entry for the rows that fill ``order`` to whole
+    # buffers.
+    with jax.named_scope("moe_combine"):
+        w_sorted = jnp.pad(_permuted(weights.reshape(-1), place),
+                           (0, order.shape[0] - place.shape[0]))
+    d_x, d_w_sorted, d_experts = _over_buffers(
+        lambda i: _buffer_backward(bound, i, cotangents[0], x, w_sorted,
+                                   w_gate, w_up, w_down, order, place, sizes),
         _buffers_needed(sizes.sum(), bound))
-    return (d_x.astype(x.dtype), d_weights, *d_experts, None, None, None)
+    with jax.named_scope("moe_combine"):
+        d_weights = _permuted(d_w_sorted, order)[:place.shape[0]]
+    return (d_x.astype(x.dtype), d_weights.reshape(weights.shape),
+            *d_experts, None, None, None)
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -631,10 +679,7 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
         expert_of = picked.T.reshape(-1)  # assignment a = k * T + t
         order = jnp.argsort(expert_of, stable=True).astype(jnp.int32)
         place = jnp.argsort(order).astype(jnp.int32)
-        # The histogram as [E, K * T] comparisons summed in one fusion: no
-        # scatter-add, and no sorted keys to gather for a search.
-        group_sizes = (expert_of == jnp.arange(n_experts)[:, None]).sum(
-            -1, dtype=jnp.int32)
+        group_sizes = _group_sizes(expert_of, n_experts)
         rows = _dispatch(x, order, place)  # [K*T, d], grouped by expert
     with jax.named_scope("moe_experts"):
         out = _swiglu_groups(rows, w_gate.astype(dt), w_up.astype(dt),
@@ -656,14 +701,13 @@ def _share(x, picked, weights, w_gate, w_up, w_down, first: int,
         is_held = (expert_of >= first) & (expert_of < first + count)
         key = jnp.where(is_held, expert_of - first, count)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        # Sorts and searches, no scatter: a TPU serialises a scatter, and
-        # this runs twice a step under remat.
         place = jnp.argsort(order).astype(jnp.int32)
-        starts = jnp.searchsorted(key[order], jnp.arange(count + 1),
-                                  side="left").astype(jnp.int32)
-        sizes = starts[1:] - starts[:-1]
-        # Whole buffers: a slice of the order never runs off its end.
-        order = jnp.pad(order, (0, -order.shape[0] % bound))
+        sizes = _group_sizes(key, count)
+        # Whole buffers: a slice of the order never runs off its end, and
+        # the rows that fill it number on, so it stays a permutation.
+        order = jnp.concatenate([order, jnp.arange(
+            order.shape[0], -(-order.shape[0] // bound) * bound,
+            dtype=jnp.int32)])
     y, placed, summed = _held_experts(bound, x, weights.T, w_gate, w_up,
                                       w_down, order, place, sizes)
     asked = is_held.sum()

@@ -5,7 +5,6 @@ as the reference: the layer as it stood before the buffers)."""
 
 import functools
 import math
-import re
 
 import jax
 import jax.numpy as jnp
@@ -325,51 +324,162 @@ def test_the_whole_layers_row_passes_are_the_gathers_autodiff_transposes(
             rtol=1e-6 if exact else 2e-2, atol=1e-5 if exact else 2e-2)
 
 
+def _moved(jaxpr, inside=()):
+    """Every equation of ``jaxpr`` and of what it calls, each with the names
+    of the jitted functions and loops it lies in: the layer's own, so not
+    the bodies of Pallas kernels (interpreted off the chip) and not
+    ``megablox``'s jitted ``gmm`` / ``tgmm``, which scatter, gather and
+    search among their [groups] and [tiles] metadata."""
+    from ray_tpu.parallel.collectives import sub_jaxprs
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        yield eqn, inside
+        if name == "pallas_call":
+            continue
+        here = inside
+        if name in ("jit", "pjit"):
+            if eqn.params["name"] in ("gmm", "tgmm"):
+                continue
+            here += (eqn.params["name"],)
+        elif name in ("while", "scan"):
+            here += (name,)
+        for sub in sub_jaxprs(eqn):
+            yield from _moved(sub, here)
+
+
+@pytest.mark.parametrize("held", [None, (0, COUNT)], ids=["whole", "share"])
 @pytest.mark.parametrize("score", ["sigmoid", "softmax"])
 @pytest.mark.parametrize("widths", [(32, 16), (128, 128)],
                          ids=["ragged_dot", "megablox"])
 def test_the_whole_layer_lowers_to_no_scatter_and_no_select_of_rows(
-        widths, score):
-    """The whole layer's lowered text, forward and with its gradients: no
-    ``select`` over a [K * T, d] array (a gather that promises its indices
-    in bounds has none) and no ``scatter`` but the router's own (the
-    transpose of ``route``'s pick of the scores, [T, K] scalars, and what
-    the interpreted grouped matmul scatters over its groups and tiles): none
-    over the K * T assignments."""
+        widths, score, held):
+    """The expert layer, whole and on a share, forward and with its
+    gradients. Its lowered text has no ``select`` over a [K * T, d] array (a
+    gather that promises its indices in bounds has none). Its equations
+    (``_moved``: the grouped matmul's own aside) hold no scatter at all, the
+    router's pick of the scores being a sum whose transpose is a sum; no
+    gather but of rows of width d (none of scalars from a rank-1 operand,
+    none of the scores); and no loop (a search is one) but the share's over
+    its buffers, once forward and once backward."""
     x, router, bias, *experts = _layer(*widths)
     bias = bias if score == "sigmoid" else None
+    if held is not None:
+        experts = [w[:COUNT] for w in experts]
 
     def layer(x, router, *experts):
         return moe.routed_experts(x, router, bias, *experts, top_k=TOP_K,
-                                  scaling=2.0, score=score)[0]
+                                  scaling=2.0, score=score, held=held)[0]
 
-    def routed(x, router):
-        return moe.route(x, router, bias, TOP_K, 2.0, True, score)[1]
+    def loss(*args):
+        return layer(*args).sum()
 
-    def texts(fn, *args):
-        def loss(*args):
-            return fn(*args).sum()
-        return (jax.jit(fn).lower(*args).as_text(),
-                jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))
-                        ).lower(*args).as_text())
-
-    def scattered(text):
-        """The operand types of every scatter of the text."""
-        return re.findall(r'"stablehlo\.scatter"\(.*?\}\) : \(([^)]*)\)', text,
-                          re.DOTALL)
-
-    assigned = f"tensor<{TOP_K * TOKENS}x"
-    rows = f"{assigned}{widths[0]}x"
-    forward, backward = texts(layer, x, router, *experts)
-    _, routers_own = texts(routed, x, router)
-    assert "stablehlo.gather" in forward and rows in backward
-    for text in (forward, backward):
+    rows = f"tensor<{TOP_K * TOKENS}x{widths[0]}x"
+    with_grads = jax.grad(loss, argnums=tuple(range(5)))
+    for fn, loops in ((layer, ["_buffer_forward"]),
+                      (with_grads, ["_buffer_forward", "_buffer_backward"])):
+        text = jax.jit(fn).lower(x, router, *experts).as_text()
+        assert "stablehlo.gather" in text
         assert not [line for line in text.splitlines()
                     if "stablehlo.select" in line and rows in line]
-        # What megablox scatters for its tiles' metadata is [E] and [tiles].
-        assert not [types for types in scattered(text) if assigned in types]
-    assert len(scattered(routers_own)) == 1
-    assert scattered(routers_own)[0] in scattered(backward)
+        moved = list(_moved(jax.make_jaxpr(fn)(x, router, *experts).jaxpr))
+        assert not [eqn for eqn, _ in moved
+                    if eqn.primitive.name.startswith("scatter")]
+        tables = {eqn.invars[0].aval.shape[1:] for eqn, _ in moved
+                  if eqn.primitive.name == "gather"}
+        assert tables == {(widths[0],)}
+        # Top-level loops alone, each calling the buffer's function.
+        assert [inside for eqn, inside in moved
+                if eqn.primitive.name in ("while", "scan")] == [
+                    () for _ in (loops if held else [])]
+        assert [eqn.params["name"] for eqn, inside in moved
+                if inside == ("while",) and "name" in eqn.params
+                ] == (loops if held else [])
+
+
+@pytest.mark.parametrize("normalize", [True, False],
+                         ids=["normalised", "as_scored"])
+@pytest.mark.parametrize("score", ["sigmoid", "softmax"])
+def test_the_routers_pick_is_the_gathers_to_the_bit(monkeypatch, score,
+                                                    normalize):
+    """``route``'s picked weights, their gradients to x and to the router,
+    the choice and the picked mass are those of ``route`` with
+    ``take_along_axis`` for its pick, under autodiff, bit for bit: a sigmoid
+    router with its bias (selection only) and a softmax router, normalised
+    and not, three choices of sixteen."""
+    x, router, bias, *_ = _layer(32, 16)
+    bias = bias if score == "sigmoid" else None
+
+    def routed():
+        def loss(x, router):
+            picked, weights, mass = moe.route(x, router, bias, 3, 2.5,
+                                              normalize, score)
+            wave = jnp.cos(jnp.arange(weights.size, dtype=jnp.float32))
+            return (weights * wave.reshape(weights.shape)).sum(), (
+                picked, weights, mass)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(x, router)
+
+    (_, got), got_grads = routed()
+    monkeypatch.setattr(moe, "_picked_scores", functools.partial(
+        jnp.take_along_axis, axis=-1))
+    (_, want), want_grads = routed()
+    assert (got[2] is None) == (score == "sigmoid")
+    for ours, theirs in zip(jax.tree.leaves((got, got_grads)),
+                            jax.tree.leaves((want, want_grads))):
+        assert np.asarray(theirs).any()
+        np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
+
+
+#: name: (routing of ``ROUTINGS``, rows of a buffer): one buffer, a full
+#: one, three, four, three whose last runs past the assignments, none.
+MOVES = {
+    "within": ("under", 512), "at": ("at", 512), "three": ("over", 256),
+    "four": ("all", 256), "past_the_end": ("all", 384), "none": ("none", 512),
+}
+
+
+@pytest.mark.parametrize("case", list(MOVES))
+def test_the_shares_scalars_ride_sorts_to_where_gathers_took_them(case):
+    """The share's scalars by comparison and by sorts against the forms
+    they replace, written out in ``jax.numpy``, to the bit: the held
+    experts' sizes (a search of the sorted keys), a buffer's weights in
+    expert order (a gather by its rows' assignments) and ``d weights`` back
+    in assignment order (a gather a buffer by ``_at`` with a select, summed
+    over the buffers), on routings of one buffer and of several."""
+    routing, bound = MOVES[case]
+    picked = _planted(0, *ROUTINGS[routing])
+    assigned = TOP_K * TOKENS
+    key = jnp.where(picked.T.reshape(-1) < COUNT, picked.T.reshape(-1), COUNT)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    place = jnp.argsort(order).astype(jnp.int32)
+    starts = jnp.searchsorted(key[order], jnp.arange(COUNT + 1), side="left")
+    sizes = moe._group_sizes(key, COUNT)
+    np.testing.assert_array_equal(sizes, starts[1:] - starts[:-1])
+    needed = int(moe._buffers_needed(sizes.sum(), bound))
+    assert needed == -(-(2 * ROUTINGS[routing][0] + ROUTINGS[routing][1])
+                       // bound)
+
+    padded = -(-assigned // bound) * bound
+    order = jnp.concatenate([order, jnp.arange(assigned, padded,
+                                               dtype=jnp.int32)])
+    keys = jax.random.split(jax.random.PRNGKey(5), 1 + max(needed, 1))
+    weights = jax.random.uniform(keys[0], (assigned,), minval=0.1)
+    w_sorted = jnp.pad(moe._permuted(weights, place), (0, padded - assigned))
+    d_w_sorted, want_d_weights = jnp.zeros(padded), jnp.zeros(assigned)
+    for i in range(max(needed, 1)):
+        groups, rows_of, w_rows = moe._buffer(i, bound, sizes, order,
+                                              w_sorted)
+        real = np.asarray(rows_of) < assigned
+        np.testing.assert_array_equal(
+            np.asarray(w_rows)[real], np.asarray(weights[rows_of])[real])
+        d_w_rows = jax.random.normal(keys[1 + i], (bound,))
+        d_w_sorted += moe._laid(d_w_rows, i, bound, groups, padded)
+        want_d_weights += moe._rows_or_zero(
+            d_w_rows, moe._at(place, i, bound, groups))
+    d_weights = moe._permuted(d_w_sorted, order)[:assigned]
+    np.testing.assert_array_equal(d_weights, want_d_weights)
+    assert int((np.asarray(d_weights) != 0).sum()) == min(
+        int(sizes.sum()), max(needed, 1) * bound)
 
 
 # -- the way back to tokens: the kernel against the gathers ------------------
