@@ -909,6 +909,17 @@ def train_moe_picked_mass() -> Gauge:
         "is a flat router, 1.0 one whose picks hold everything.")
 
 
+def train_moe_relu2_zero_share() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_moe_relu2_zero_share",
+        "Of the hidden activations the held experts computed in the last "
+        "recorded step (squared-ReLU experts: ops/moe.py, "
+        "activation='relu2'), the share the ReLU zeroed, the expert layers' "
+        "mean: about a half at random weights, 0 if the ReLU were missing, "
+        "and what a product that skips zeros could save.")
+
+
 def train_attn_window_tile_fill() -> Gauge:
     from ray_tpu.util.metrics import Gauge
     return Gauge(

@@ -1,7 +1,8 @@
 from ray_tpu.models import (afmoe, bert, deepseek, diffusion, evabyte,
                             exchange, glm_moe_dsa, gpt, granite, kimi_linear,
-                            lfm2, lm, mellum, minicpm_sala, t5, vit)
+                            lfm2, lm, mellum, minicpm_sala, nemotron_h, t5,
+                            vit)
 
 __all__ = ["afmoe", "bert", "deepseek", "diffusion", "evabyte", "exchange",
            "glm_moe_dsa", "gpt", "granite", "kimi_linear", "lfm2", "lm",
-           "mellum", "minicpm_sala", "t5", "vit"]
+           "mellum", "minicpm_sala", "nemotron_h", "t5", "vit"]

@@ -14,11 +14,13 @@ Per layer, as ``GraniteMoeHybridForCausalLM`` computes it (``transformers``
     x        = RMSNorm(h; g1)                          no bias anywhere but the conv
     mamba:   z | xBC | dt = x W_in      d_inner | d_inner + 2 d_state | heads
              xBC      = silu(conv(xBC))  depthwise, mamba_d_conv taps, causal, with bias
-             u | B | C = xBC             heads x d_head | d_state | d_state (one group)
+             u | B | C = xBC             heads x d_head | groups x d_state | groups x d_state
+                                         head i reads B, C of group i // (heads / groups); micro has one group
              dt       = softplus(dt + dt_bias) ;  A = -exp(A_log)      a scalar a head
              S_t      = exp(dt_t A) S_(t-1) + dt_t u_t B_t^T            zero before the first token
              y_t      = S_t C_t + D u_t
-             a        = RMSNorm(y * silu(z); g_m) W_out      the gate before the norm
+             a        = RMSNorm(y * silu(z); g_m) W_out      the gate before the norm, which is over the whole row
+                                                              whatever the groups (``GraniteMoeHybridRMSNormGated``)
     attention: q, k, v = x Wq, x Wk, x Wv    heads, kv heads, kv heads of hidden / heads
              a        = softmax(causal(q k^T * attention_multiplier)) v Wo
                         query head i reads KV head i // (heads / kv heads); no positions
@@ -41,7 +43,8 @@ model's score scale. This module is the family's config, its table of leaves
 (``_shapes``) and its block; parameters and specs from the table, the
 lookup, the scan over the two kinds of layer, the tied head and the loss are
 ``lm.Decoder``'s.
-The program computes ``mamba_n_groups`` 1 and ``num_local_experts`` 0 only
+``mamba_n_groups`` is passed on to ``ops/ssd.py`` as the group axis of B and
+C. The program computes ``num_local_experts`` 0 and a tied head only
 (granite-4.0-h-micro's); ``GraniteConfig`` refuses others.
 """
 
@@ -100,11 +103,12 @@ class GraniteConfig:
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        if self.mamba_n_groups != 1 or self.num_local_experts or \
-                not self.tie_word_embeddings:
+        if self.num_local_experts or not self.tie_word_embeddings:
             raise NotImplementedError(
-                "models/granite.py computes mamba_n_groups 1, "
-                "num_local_experts 0 and a tied head only")
+                "models/granite.py computes num_local_experts 0 and a tied "
+                "head only")
+        if self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError("mamba_n_groups must divide mamba_n_heads")
         if self.mamba_n_heads * self.mamba_d_head != \
                 self.mamba_expand * self.hidden_size:
             raise ValueError("mamba_n_heads * mamba_d_head must be "
@@ -127,7 +131,8 @@ class GraniteConfig:
 
     @property
     def conv_dim(self) -> int:
-        return self.mamba_d_inner + 2 * self.mamba_d_state
+        return self.mamba_d_inner + 2 * self.mamba_n_groups \
+            * self.mamba_d_state
 
 
 PRESETS: Dict[str, GraniteConfig] = {
@@ -151,12 +156,6 @@ def config(name: str, **overrides) -> GraniteConfig:
 
 
 # -- parameters ---------------------------------------------------------
-
-def _log_arange(key, shape):
-    """``A_log`` = log(1..heads) along the last axis."""
-    return jnp.broadcast_to(jnp.log(jnp.arange(
-        1, shape[-1] + 1, dtype=jnp.float32)), shape)
-
 
 def _shapes(cfg: GraniteConfig):
     """{kind: {leaf: (shape without the layers axis, logical axes, init)}}:
@@ -186,7 +185,7 @@ def _shapes(cfg: GraniteConfig):
                    (3 * cfg.mamba_d_conv) ** -0.5),
         "conv_b": ((cfg.conv_dim,), (None,), lm.zeros),
         "dt_bias": ((mh,), (None,), lm.ones),
-        "A_log": ((mh,), (None,), _log_arange),
+        "A_log": ((mh,), (None,), lm.log_arange),
         "D": ((mh,), (None,), lm.ones),
         "norm_scale": ((di,), (None,), lm.ones),
         "w_out": ((di, d), (None, "embed"), std),
@@ -206,14 +205,15 @@ def _shapes(cfg: GraniteConfig):
 def _mamba(cfg: GraniteConfig, x, layer):
     """The Mamba-2 mixer on normed x [B, S, d] -> [B, S, d]."""
     dt_, f32 = cfg.dtype, jnp.float32
-    di, n = cfg.mamba_d_inner, cfg.mamba_d_state
+    di, groups, n = cfg.mamba_d_inner, cfg.mamba_n_groups, cfg.mamba_d_state
     proj = jnp.einsum("bsd,de->bse", x, layer["w_in"].astype(dt_))
     dt = proj[..., di + cfg.conv_dim:]
     with jax.named_scope("conv"):
         xbc = lm.conv_silu(proj, layer["conv_w"], layer["conv_b"],
                            start=di, width=cfg.conv_dim)
-    u, B, C = jnp.split(xbc, [di, di + n], axis=-1)
+    u, B, C = jnp.split(xbc, [di, di + groups * n], axis=-1)
     dt = jax.nn.softplus(dt.astype(f32) + layer["dt_bias"].astype(f32))
+    B, C = (a.reshape(a.shape[:2] + (groups, n)) for a in (B, C))
     y = lm.state_space(
         u.reshape(u.shape[:2] + (cfg.mamba_n_heads, cfg.mamba_d_head)), dt,
         -jnp.exp(layer["A_log"].astype(f32)), B, C, layer["D"].astype(f32),

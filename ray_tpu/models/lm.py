@@ -127,11 +127,35 @@ def runs(layers) -> Tuple[Tuple[str, str, int], ...]:
                  for i, (kind, n) in enumerate(layer_runs(layers)))
 
 
+def rematerialised(cfg, block):
+    """``block`` rematerialised by ``cfg.remat`` / ``cfg.remat_policy``
+    (``scan_blocks`` says what each policy keeps); as it is without
+    ``cfg.remat``."""
+    if not cfg.remat:
+        return block
+    from ray_tpu.ops.dsa import SELECTION_NAME
+    from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+    if cfg.remat_policy == "selective":
+        kept = ("attn_q", "attn_k", "attn_v", "attn_raw", "ffn_in")
+    elif cfg.remat_policy == "full":
+        kept = ()
+    else:
+        raise ValueError(
+            f"Unknown remat_policy {cfg.remat_policy!r}; "
+            "expected 'full' or 'selective'")
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *kept, *RESIDUAL_NAMES, SELECTION_NAME)
+    return jax.checkpoint(block, policy=policy)
+
+
 def scan_blocks(cfg, block, x, layers, positions, runs=None,
-                shares: bool = False):
+                shares: bool = False, remat_in_block: bool = False):
     """``block(x, layer, positions) -> (x, aux)`` over stacked layer
     parameters in one ``lax.scan``, each block rematerialised by
-    ``cfg.remat`` / ``cfg.remat_policy``. Returns (x, aux stacked over
+    ``cfg.remat`` / ``cfg.remat_policy`` (``rematerialised``), unless
+    ``remat_in_block``: a block that is a unit of several layers wraps each
+    of them itself, so that a backward pass holds one layer's intermediates
+    at a time (``models/nemotron_h.py``). Returns (x, aux stacked over
     layers; None where the block returns None).
 
     ``"full"`` keeps the block's input and, of everything inside it, only
@@ -180,27 +204,16 @@ def scan_blocks(cfg, block, x, layers, positions, runs=None,
         for (kind, _), stack in zip(runs, layers):
             fn = partial(block[kind], shared=dict(shared)) if shares \
                 else block[kind]
-            x, aux = scan_blocks(cfg, fn, x, stack, positions)
+            x, aux = scan_blocks(cfg, fn, x, stack, positions,
+                                 remat_in_block=remat_in_block)
             if shares and aux and HANDED_ON in aux:
                 aux = dict(aux)
                 shared.update(jax.tree.map(lambda a: a[-1],
                                            aux.pop(HANDED_ON)))
             auxes.append(aux)
         return x, auxes
-    if cfg.remat:
-        from ray_tpu.ops.dsa import SELECTION_NAME
-        from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
-        if cfg.remat_policy == "selective":
-            kept = ("attn_q", "attn_k", "attn_v", "attn_raw", "ffn_in")
-        elif cfg.remat_policy == "full":
-            kept = ()
-        else:
-            raise ValueError(
-                f"Unknown remat_policy {cfg.remat_policy!r}; "
-                "expected 'full' or 'selective'")
-        policy = jax.checkpoint_policies.save_only_these_names(
-            *kept, *RESIDUAL_NAMES, SELECTION_NAME)
-        block = jax.checkpoint(block, policy=policy)
+    if not remat_in_block:
+        block = rematerialised(cfg, block)
 
     def scan_body(x, layer):
         with jax.named_scope("block"):
@@ -442,7 +455,8 @@ def _over_batch_shards(fn, args, has_rows, out_rank: int = 4):
 def state_space(u, dt, A, B, C, D, chunk: int):
     """The Mamba-2 recurrence ``S_t = exp(dt_t A) S_(t-1) + dt_t u_t B_t^T``,
     ``y_t = S_t C_t + D u_t`` by ``ops/ssd.py``'s chunked scan. u: [B, S, H,
-    P], dt: [B, S, H] (positive), A, D: [H], B, C: [B, S, N] -> [B, S, H,
+    P], dt: [B, S, H] (positive), A, D: [H], B, C: [B, S, G, N], head i
+    reading group ``i // (H / G)`` (or [B, S, N]: one group) -> [B, S, H,
     P]. Under a mesh the kernels run per shard of the batch, as the flash
     kernels do."""
     from ray_tpu.ops.ssd import ssd
@@ -518,22 +532,27 @@ def short_conv(bcx, w):
     return _over_batch_shards(op, (bcx, w), (True, False), out_rank=3)
 
 
-def gated_norm(x, z, scale, eps, *, gate_first: bool, activation: str):
+def gated_norm(x, z, scale, eps, *, gate_first: bool, activation: str,
+               group: Optional[int] = None):
     """The gate and the RMSNorm behind a recurrence by
     ``ops/gated_norm.py`` (one fused Pallas pass each way where the shapes
     tile, else its ``jax.numpy`` form): ``x`` [B, S, width] the recurrence's
     output, the gate's argument the first ``width`` columns of ``z`` [B, S,
-    >= width] read where they lie, ``scale`` [group] with the groups side by
-    side along the width -> [B, S, width] in ``x``'s dtype, gate, statistics
-    and products in float32. The layer says what it computes: ``gate_first``
-    with a ``"silu"`` and one group of the whole row is a Mamba-2 layer's
-    ``RMSNorm(x * silu(z))`` (``models/granite.py``), the norm first with a
+    >= width] read where they lie, the groups of ``group`` channels
+    (``scale``'s length if not given) side by side along the width,
+    ``scale`` [group] or [width] -> [B, S, width] in ``x``'s dtype, gate,
+    statistics and products in float32. The layer says what it computes:
+    ``gate_first`` with a ``"silu"`` and one group of the whole row is a
+    Mamba-2 layer's ``RMSNorm(x * silu(z))`` (``models/granite.py``), the
+    same with a group a B/C group under a scale as wide as the row Mamba-2's
+    own grouped norm (``models/nemotron_h.py``), the norm first with a
     ``"sigmoid"`` and a group a head a delta-rule layer's ``RMSNorm(x) *
     sigmoid(z)`` (``models/kimi_linear.py``). Under a mesh the kernels run
     per shard of the batch, as ``state_space``'s do."""
     from ray_tpu.ops.gated_norm import gated_norm as op
     return _over_batch_shards(
-        partial(op, eps=eps, gate_first=gate_first, activation=activation),
+        partial(op, eps=eps, gate_first=gate_first, activation=activation,
+                group=group),
         (x, z, scale), (True, True, False), out_rank=3)
 
 
@@ -659,12 +678,24 @@ def swiglu(x, w_gate, w_up, w_down):
                       w_down.astype(dt))
 
 
-def swiglu_leaves(d: int, width: int, prefix: str = ""):
+def swiglu_leaves(d: int, width: int, prefix: str = "", gated: bool = True):
     """The leaves ``swiglu`` reads, as a family's table of leaves holds
-    them (``Decoder``)."""
-    return {prefix + "w_gate": ((d, width), ("embed", "mlp"), 0.02),
+    them (``Decoder``); without ``gated``, the two ``mlp`` reads."""
+    gate = {prefix + "w_gate": ((d, width), ("embed", "mlp"), 0.02)}
+    return {**(gate if gated else {}),
             prefix + "w_up": ((d, width), ("embed", "mlp"), 0.02),
             prefix + "w_down": ((width, d), ("mlp", "embed"), 0.02)}
+
+
+def mlp(x, w_up, w_down, activation: str):
+    """``act(x w_up) w_down`` in x's dtype: two matrices and no gate,
+    ``activation`` as ``ops/moe.py`` names them (``"relu2"``: a squared
+    ReLU)."""
+    from ray_tpu.ops.moe import ACTIVATIONS
+    dt = x.dtype
+    up = jnp.einsum("...d,df->...f", x, w_up.astype(dt))
+    return jnp.einsum("...f,fd->...d", ACTIVATIONS[activation](up),
+                      w_down.astype(dt))
 
 
 def mla_leaves(cfg):
@@ -745,24 +776,28 @@ def held_experts(held, experts: int):
 
 
 def expert_leaves(d: int, experts: int, held, width: int,
-                  shared_width: int = 0, bias: bool = True):
+                  shared_width: int = 0, bias: bool = True,
+                  gated: bool = True):
     """The leaves ``expert_ffn`` reads: the router over all ``experts`` and,
     with ``bias``, its correction bias (the published ``expert_bias`` /
     ``e_score_correction_bias``: a buffer of zeros that the gradient never
     moves), the SwiGLUs of ``width`` of the experts ``held`` (a config's
     ``experts_held``: (first, count), or None for all) and, with
-    ``shared_width``, the shared experts' as one SwiGLU."""
+    ``shared_width``, the shared experts' as one SwiGLU. Without ``gated``
+    an expert, routed or shared, is two matrices (no ``w_gate``)."""
     count = experts if held is None else held[1]
     leaves = {"router": ((d, experts), ("embed", None), 0.02)}
     if bias:
         leaves["router_bias"] = ((experts,), (None,), zeros)
+    if gated:
+        leaves["w_gate"] = ((count, d, width), ("expert", "embed", "mlp"),
+                            0.02)
     leaves.update({
-        "w_gate": ((count, d, width), ("expert", "embed", "mlp"), 0.02),
         "w_up": ((count, d, width), ("expert", "embed", "mlp"), 0.02),
         "w_down": ((count, width, d), ("expert", "mlp", "embed"), 0.02),
     })
     if shared_width:
-        leaves.update(swiglu_leaves(d, shared_width, "shared_"))
+        leaves.update(swiglu_leaves(d, shared_width, "shared_", gated))
     return leaves
 
 
@@ -772,7 +807,8 @@ def expert_aux(aux, batch_shape):
     [held experts], ``asked`` (assignments the router gave them),
     ``within_bound`` (1 where they fit ``ops/moe.py``'s one buffer),
     ``rows_summed`` (rows the way back to tokens read) and, of a softmax
-    router, ``picked_mass``. With every expert held the router's
+    router, ``picked_mass``, of squared-ReLU experts ``relu2_zero_share``.
+    With every expert held the router's
     assignments are all asked, the one buffer holds them and the weighted
     sum reads them all."""
     routed = jnp.int32(aux["picked"].size)
@@ -781,31 +817,37 @@ def expert_aux(aux, batch_shape):
            "asked": aux.get("asked", routed),
            "within_bound": aux.get("within_bound", jnp.int32(1)),
            "rows_summed": aux.get("rows_summed", routed)}
-    if "picked_mass" in aux:
-        out["picked_mass"] = aux["picked_mass"]
+    out.update({name: aux[name]
+                for name in ("picked_mass", "relu2_zero_share")
+                if name in aux})
     return out
 
 
 def expert_ffn(x, layer, *, top_k: int, scaling: float, normalize: bool,
-               held, score: str = "sigmoid"):
+               held, score: str = "sigmoid", activation: str = "silu"):
     """The expert layer on normed x [B, S, d] from a layer's leaves
     (``router``, ``router_bias`` where the family has one, the held experts'
-    ``w_gate`` / ``w_up`` / ``w_down``, ``shared_*`` where it has shared
-    experts): (this chip's part of the routed sum, the shared experts or
-    None, ``expert_aux``), the sums [B, S, d], for the caller to add in its
-    own order. ``held`` and ``score`` are ``ops/moe.py``'s."""
+    ``w_gate`` (where they have a gate) / ``w_up`` / ``w_down``,
+    ``shared_*`` where it has shared experts, of the routed experts' form):
+    (this chip's part of the routed sum, the shared experts or None,
+    ``expert_aux``), the sums [B, S, d], for the caller to add in its own
+    order. ``held``, ``score`` and ``activation`` are ``ops/moe.py``'s."""
     from ray_tpu.ops.moe import routed_experts
     B, S, d = x.shape
     routed, aux = routed_experts(
         x.reshape(B * S, d), layer["router"], layer.get("router_bias"),
-        layer["w_gate"], layer["w_up"], layer["w_down"],
+        layer.get("w_gate"), layer["w_up"], layer["w_down"],
         top_k=top_k, scaling=scaling, normalize=normalize, held=held,
-        score=score)
+        score=score, activation=activation)
     shared = None
     if "shared_w_gate" in layer:
         with jax.named_scope("shared_expert"):
             shared = swiglu(x, layer["shared_w_gate"], layer["shared_w_up"],
                             layer["shared_w_down"])
+    elif "shared_w_up" in layer:
+        with jax.named_scope("shared_expert"):
+            shared = mlp(x, layer["shared_w_up"], layer["shared_w_down"],
+                         activation)
     aux = expert_aux(aux, (B, S))
     return routed.reshape(B, S, d), shared, aux
 
@@ -1117,6 +1159,13 @@ def ones(key, shape):
     return jnp.ones(shape, jnp.float32)
 
 
+def log_arange(key, shape):
+    """log(1..n) along the last axis: a state-space layer's ``A_log`` as
+    Mamba publishes it."""
+    return jnp.broadcast_to(jnp.log(jnp.arange(
+        1, shape[-1] + 1, dtype=jnp.float32)), shape)
+
+
 def zeros(key, shape):
     return jnp.zeros(shape, jnp.float32)
 
@@ -1200,6 +1249,9 @@ class Decoder:
     fp32_logits: bool = False
     #: The std ``wte`` and ``lm_head`` are drawn at: cfg -> float.
     top_std: Callable = lambda cfg: 0.02
+    #: The family's block is a unit of several layers and rematerialises
+    #: each of them itself (``rematerialised``): the scan does not wrap it.
+    remat_in_block: bool = False
 
     def _top(self, cfg):
         """The leaves outside the layer stacks, as a table."""
@@ -1277,7 +1329,7 @@ class Decoder:
             cfg, {kind: partial(self.block, cfg, kind)
                   for _, kind, _ in stacks}, x, layers, positions,
             runs=[(kind, depth) for _, kind, depth in stacks],
-            shares=self.shares)
+            shares=self.shares, remat_in_block=self.remat_in_block)
         x = constrain(x, "batch", "sequence", None)
         aux = merged_aux(auxes)
         eps = getattr(cfg, self.eps)

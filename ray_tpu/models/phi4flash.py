@@ -209,12 +209,6 @@ def _constants(cfg: Phi4FlashConfig, run: str):
 
 # -- parameters ---------------------------------------------------------
 
-def _decay_rate(key, shape):
-    """``A_log`` as published for Mamba-1: log(1..N) in every channel."""
-    return jnp.broadcast_to(
-        jnp.log(jnp.arange(1, shape[-1] + 1, dtype=jnp.float32)), shape)
-
-
 def _step_bias(key, shape):
     """``b_dt``: the inverse softplus of a step log-uniform in (0.001,
     0.1) a channel, Mamba's published initialisation."""
@@ -254,7 +248,7 @@ def _shapes(cfg: Phi4FlashConfig):
         "w_x": ((di, rank + 2 * n), ("mlp", None), std),
         "w_dt": ((rank, di), (None, "mlp"), rank ** -0.5),
         "b_dt": ((di,), (None,), _step_bias),
-        "A_log": ((di, n), (None, None), _decay_rate),
+        "A_log": ((di, n), (None, None), lm.log_arange),
         "D": ((di,), (None,), lm.ones),
         "w_out": ((di, d), ("mlp", "embed"), std),
     }

@@ -3,21 +3,27 @@ each way, and a plain ``jax.numpy`` form of the same function.
 
 A state-space or delta-rule layer ends with its recurrence's output ``x``
 gated by a second projection ``z`` and brought to unit mean square over a
-group of its channels, with a learned ``scale`` [group] a channel of the
-group. Two published layers, two orders of the same three steps::
+group of its channels, with a learned ``scale``: [group], one vector that
+every group shares, or [width], a value a channel of the row. Three published
+layers, two orders of the same three steps::
 
     gate first, a SiLU, one group of the whole row (Mamba-2's
     ``MambaRMSNormGated``, granite's):
         pre = x * silu(z) ;  out = pre * rsqrt(mean(pre^2) + eps) * scale
+    gate first, a SiLU, statistics over each of the row's groups and a scale
+    as wide as the row (Mamba-2's own ``RMSNormGated(group_size = d_inner /
+    n_groups)``, Nemotron-H's: 8 groups of 512 of 4096):
+        pre = x * silu(z) ;  out = pre * rsqrt(mean_group(pre^2) + eps) * scale
     norm first, a sigmoid, a group a head (Kimi Delta Attention's
     ``FusedRMSNormGated``):
         out = x * rsqrt(mean_group(x^2) + eps) * scale * sigmoid(z)
 
-``gate_first`` and ``activation`` say which; the group is ``scale``'s
-length. Everything is elementwise but a sum over a group's lanes, so the
-floor is bytes: the forward reads two arrays and writes one, the backward
-reads three and writes two. As XLA operations (``gated_norm_xla``) the
-chain runs in float32 through HBM several times each way.
+``gate_first`` and ``activation`` say which; the group is ``scale``'s length
+unless ``group`` says otherwise, and ``scale`` is then as long as the group
+or as the row. Everything is elementwise but a sum over a group's lanes, so
+the floor is bytes: the forward reads two arrays and writes one, the backward
+reads three and writes two. As XLA operations (``gated_norm_xla``) the chain
+runs in float32 through HBM several times each way.
 
 A grid step is ``ROWS`` whole rows of one sequence, worked through a group at
 a time and, within a group, ``_LANES`` channels at a time in float32: a
@@ -48,6 +54,7 @@ from __future__ import annotations
 
 import functools
 import importlib
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -82,18 +89,21 @@ def _interpret() -> bool:
 
 # -- the same function in jax.numpy ----------------------------------------
 
-def gated_norm_xla(x, z, scale, eps, gate_first: bool, activation: str):
+def gated_norm_xla(x, z, scale, eps, gate_first: bool, activation: str,
+                   group: Optional[int] = None):
     """The function at the top of this file as XLA operations, any shape:
-    ``x``, ``z`` [..., width], ``scale`` [group] with the groups side by
-    side along the last axis; gate, statistics and products in float32, the
+    ``x``, ``z`` [..., width], the groups of ``group`` channels (``scale``'s
+    length if not given) side by side along the last axis, ``scale``
+    [group] or [width]; gate, statistics and products in float32, the
     result in ``x``'s dtype. The kernels' oracle and their fallback."""
     gate = getattr(jax.nn, activation)(z.astype(F32))
     pre = x.astype(F32)
     if gate_first:
         pre = pre * gate
-    groups = pre.reshape(pre.shape[:-1] + (-1, scale.shape[0]))
+    group = group or scale.shape[0]
+    groups = pre.reshape(pre.shape[:-1] + (-1, group))
     out = (groups * jax.lax.rsqrt((groups ** 2).mean(-1, keepdims=True) + eps)
-           * scale.astype(F32)).reshape(pre.shape)
+           * scale.astype(F32).reshape(-1, group)).reshape(pre.shape)
     return (out if gate_first else out * gate).astype(x.dtype)
 
 
@@ -139,9 +149,18 @@ def _walk(groups: int, chunks: int, sums: int, first, between, second):
     jax.lax.fori_loop(0, groups, one_group, None)
 
 
+def _scale_lanes(scale_ref, group: int):
+    """(at, own) -> which of the two a chunk's scale is read at: its lanes
+    in the row where ``scale_ref`` [1, width] holds a value a channel, those
+    in its group where [1, group] is one vector for every group."""
+    shared = scale_ref.shape[1] == group
+    return lambda at, own: own if shared else at
+
+
 def _fwd_kernel(x_ref, z_ref, scale_ref, out_ref, pre_ref, *, eps: float,
                 gate_first: bool, silu: bool):
     group = pre_ref.shape[1]
+    scale_at = _scale_lanes(scale_ref, group)
 
     def first(at, own, acc):
         pre = x_ref[:, at].astype(F32)
@@ -154,7 +173,7 @@ def _fwd_kernel(x_ref, z_ref, scale_ref, out_ref, pre_ref, *, eps: float,
         return jax.lax.rsqrt(_mean(acc[0], group) + eps)
 
     def second(at, own, rstd):
-        out = pre_ref[:, own] * rstd * scale_ref[:, own]
+        out = pre_ref[:, own] * rstd * scale_ref[:, scale_at(at, own)]
         if not gate_first:
             out = out * _gate(z_ref[:, at].astype(F32), silu)[0]
         out_ref[:, at] = out.astype(out_ref.dtype)
@@ -167,6 +186,7 @@ def _bwd_kernel(x_ref, z_ref, scale_ref, dout_ref, dx_ref, dz_ref,
                 dscale_ref, pre_ref, s_ref, *, eps: float, gate_first: bool,
                 silu: bool):
     group = pre_ref.shape[1]
+    scale_at = _scale_lanes(scale_ref, group)
 
     def first(at, own, acc):
         pre, t = x_ref[:, at].astype(F32), dout_ref[:, at].astype(F32)
@@ -175,7 +195,7 @@ def _bwd_kernel(x_ref, z_ref, scale_ref, dout_ref, dx_ref, dz_ref,
             pre = pre * gate
         else:
             t = t * gate
-        s = t * scale_ref[:, own]
+        s = t * scale_ref[:, scale_at(at, own)]
         pre_ref[:, own], s_ref[:, own] = pre, s
         return acc[0] + pre * pre, acc[1] + s * pre
 
@@ -187,7 +207,7 @@ def _bwd_kernel(x_ref, z_ref, scale_ref, dout_ref, dx_ref, dz_ref,
 
     def second(at, own, ab):
         rstd, b = ab
-        pre, scale = pre_ref[:, own], scale_ref[:, own]
+        pre, scale = pre_ref[:, own], scale_ref[:, scale_at(at, own)]
         dpre = rstd * s_ref[:, own] - b * pre
         gate, slope = _gate(z_ref[:, at].astype(F32), silu)
         dout = dout_ref[:, at].astype(F32)
@@ -210,13 +230,13 @@ def _bwd_kernel(x_ref, z_ref, scale_ref, dout_ref, dx_ref, dz_ref,
     _walk(x_ref.shape[1] // group, group // _LANES, 2, first, stats, second)
 
 
-def _call(kernel, name, operands, outs, scratch: int, **static):
+def _call(kernel, name, operands, outs, scratch: int, group: int, **static):
     """``kernel`` over the grid (batch, tiles of ``ROWS`` rows): ``operands``
-    are ``x``, ``z`` (its first columns read), ``scale`` [1, group] and any
-    number of arrays shaped like ``x``; ``outs`` names the outputs, "rows"
-    such an array and "dscale" the partial sums; ``scratch`` float32 copies
-    of a group's rows in VMEM."""
-    x, group = operands[0], operands[2].shape[1]
+    are ``x``, ``z`` (its first columns read), ``scale`` [1, group] or [1,
+    width] and any number of arrays shaped like ``x``; ``outs`` names the
+    outputs, "rows" such an array and "dscale" the partial sums; ``scratch``
+    float32 copies of a group's rows in VMEM."""
+    x, scale = operands[0], operands[2]
     batch, seq, width = x.shape
     rows = pl.BlockSpec((None, ROWS, width), lambda b, s: (b, s, 0))
     kinds = {
@@ -229,7 +249,7 @@ def _call(kernel, name, operands, outs, scratch: int, **static):
     return pl.pallas_call(
         functools.partial(kernel, **static),
         grid=(batch, seq // ROWS),
-        in_specs=[rows, rows, pl.BlockSpec((1, group), lambda b, s: (0, 0))]
+        in_specs=[rows, rows, pl.BlockSpec(scale.shape, lambda b, s: (0, 0))]
         + [rows] * (len(operands) - 3),
         out_specs=[kinds[out][0] for out in outs],
         out_shape=[kinds[out][1] for out in outs],
@@ -242,24 +262,24 @@ def _call(kernel, name, operands, outs, scratch: int, **static):
     )(*operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _kernels(x, z, scale, eps, gate_first, silu):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _kernels(x, z, scale, eps, gate_first, silu, group):
     return _call(_fwd_kernel, "gated_norm_fwd",
-                 (x, z, scale.astype(F32)[None]), ["rows"], 1,
+                 (x, z, scale.astype(F32)[None]), ["rows"], 1, group,
                  eps=eps, gate_first=gate_first, silu=silu)[0]
 
 
-def _kernels_fwd(x, z, scale, eps, gate_first, silu):
-    return _kernels(x, z, scale, eps, gate_first, silu), (x, z, scale)
+def _kernels_fwd(x, z, scale, eps, gate_first, silu, group):
+    return _kernels(x, z, scale, eps, gate_first, silu, group), (x, z, scale)
 
 
-def _kernels_bwd(eps, gate_first, silu, operands, dout):
+def _kernels_bwd(eps, gate_first, silu, group, operands, dout):
     """(dx, dz over all of ``z``'s columns, zeros beside the gate's own,
     d scale)."""
     x, z, scale = operands
     dx, dz, dscale = _call(
         _bwd_kernel, "gated_norm_bwd", (x, z, scale.astype(F32)[None], dout),
-        ["rows", "rows", "dscale"], 2,
+        ["rows", "rows", "dscale"], 2, group,
         eps=eps, gate_first=gate_first, silu=silu)
     beside = z.shape[2] - x.shape[2]
     return (dx,
@@ -272,24 +292,27 @@ _kernels.defvjp(_kernels_fwd, _kernels_bwd)
 
 
 def gated_norm(x, z, scale, eps: float, *, gate_first: bool,
-               activation: str):
+               activation: str, group: Optional[int] = None):
     """out [batch, S, width] of the function at the top of this file: ``x``
     [batch, S, width], the gate's argument the first ``width`` columns of
-    ``z`` [batch, S, >= width], read where they lie, ``scale`` [group] with
-    the groups side by side along the width; ``activation`` is ``"silu"`` or
-    ``"sigmoid"``, and ``gate_first`` says whether the gate comes before the
-    norm or after it. out in ``x``'s dtype, ``dz`` in ``z``'s (which the
-    kernels take to be the same), gate, statistics and products in float32.
-    The kernels where the shapes tile (S a multiple of ``ROWS``, the group
-    of 128 and the width of the group), else ``gated_norm_xla`` on the
-    slice."""
-    width, group = x.shape[2], scale.shape[0]
+    ``z`` [batch, S, >= width], read where they lie, the groups of ``group``
+    channels (``scale``'s length if not given) side by side along the
+    width, ``scale`` [group] (every group's) or [width] (a value a channel);
+    ``activation`` is ``"silu"`` or ``"sigmoid"``, and ``gate_first`` says
+    whether the gate comes before the norm or after it. out in ``x``'s
+    dtype, ``dz`` in ``z``'s (which the kernels take to be the same), gate,
+    statistics and products in float32. The kernels where the shapes tile
+    (S a multiple of ``ROWS``, the group of 128), else ``gated_norm_xla`` on
+    the slice."""
+    width, group = x.shape[2], group or scale.shape[0]
     if activation not in ("silu", "sigmoid"):
         raise ValueError(f"activation {activation!r}: silu or sigmoid")
-    if x.shape[1] % ROWS or group % _LANES or width % group \
-            or z.dtype != x.dtype:
+    if width % group or scale.shape[0] not in (group, width):
+        raise ValueError(f"a scale of {scale.shape[0]} for groups of {group} "
+                         f"of {width} channels")
+    if x.shape[1] % ROWS or group % _LANES or z.dtype != x.dtype:
         return gated_norm_xla(x, z[..., :width], scale, eps, gate_first,
-                              activation)
+                              activation, group)
     with jax.named_scope("gated_norm_kernels"):
         return _kernels(x, z, scale, float(eps), gate_first,
-                        activation == "silu")
+                        activation == "silu", group)

@@ -1,21 +1,38 @@
 """A dropless routed-expert layer: sort, group sizes, ragged matmuls.
 
 ``routed_experts`` is the routed half of a DeepSeek-V3-style expert layer
-(``lm.expert_ffn`` adds the shared expert). Every token's ``top_k``
-assignments are computed: there is no capacity and nothing is dropped, and
-no tensor of ``tokens x experts x capacity`` exists at any size. The
-``tokens x top_k`` assignments are sorted by expert (a stable sort), each
+(``lm.expert_ffn`` adds the shared expert). An expert is a SwiGLU, or, with
+no gate matrix, two matrices with the activation between them
+(``activation``: Nemotron-H's squared ReLU, ``"relu2"``). Every token's
+``top_k`` assignments are computed: there is no capacity and nothing is
+dropped, and no tensor of ``tokens x experts x capacity`` exists at any size.
+The ``tokens x top_k`` assignments are sorted by expert (a stable sort), each
 expert multiplies its own contiguous group of rows (``grouped_matmul``), and
 the rows go back to token order for the weighted sum.
 
 The grouped matmul is JAX's Pallas kernel set ``megablox`` (``gmm`` and, for
-the weights' gradient, ``tgmm``) wherever the shapes tile (rows and both
-widths multiples of 128), and ``jax.lax.ragged_dot`` elsewhere (tiny test
-widths). Both cost the groups' FLOPs, not the dense ``experts x`` product. On
-the v5e, at Moonlight's widths (98,304 rows, 2048 x 1408, 64 groups), the
-three products of an expert SwiGLU forward and backward took 95.9 ms with
-``ragged_dot`` (XLA's own grouped kernel, 53 TFLOP/s) and 52.8 ms with
-``megablox`` at tiles of 512 x 512 x 1408 (97 TFLOP/s): PERF.md, PR 25.
+the weights' gradient, ``tgmm``) wherever the rows are a multiple of 128 and
+both widths multiples of 64 of at least a lane tile, and
+``jax.lax.ragged_dot`` elsewhere (tiny test widths). Both cost the groups'
+FLOPs, not the dense ``experts x`` product. On the v5e, at Moonlight's widths
+(98,304 rows, 2048 x 1408, 64 groups), the three products of an expert SwiGLU
+forward and backward took 95.9 ms with ``ragged_dot`` (XLA's own grouped
+kernel, 53 TFLOP/s) and 52.8 ms with ``megablox`` at tiles of 512 x 512 x
+1408 (97 TFLOP/s): PERF.md, PR 25. **Which widths take which path.** A width
+that is a multiple of 128 is tiled by its widest divisor up to
+``_TILE_N_MAX`` (its contraction by ``_TILE_K``, the last tile masked where
+512 does not divide it: 2304, 2688). A width that is no multiple of 128
+(Nemotron-H's experts of 1856 = 14.5 x 128) takes the same kernels with an
+irregular last tile (``_tile_n``: an output of 1856 as two tiles of 1024,
+the second cut at 832 columns; a contraction of 1856 as four of 512, the
+last masked past 320). Measured on the v5e at that cell's rows (a buffer of
+98,304 rows, 49,152 of them in 32 held groups; an expert's two products at
+2688 x 1856 with the squared ReLU between them, forward and backward):
+26.4 ms so (27.3 with tiles of 640, 28.4 with 1408), 25.3 ms with the
+weights laid out 1920 wide behind 64 zero columns and rows (exact, 3.4 %
+more product, and another parameter shape than the published one), 180.4 ms
+with ``ragged_dot``; forward alone 11.1, 10.2 and 65.3 ms (88, 96 and 15
+TFLOP/s of the 0.98 TFLOP the held rows need): PERF.md, PR 62.
 
 **The whole layer's row passes** (every expert held: ``tokens x top_k``
 rows, all of them some expert's). No pass scatters and none selects. The
@@ -87,9 +104,10 @@ of whatever else lies in that order: the weights, for the backward pass).
 The buffer has ``_held_bound`` rows: twice what even routing would give the
 held experts, ``2 * tokens * top_k * count / experts`` rounded up to the row
 tile, computed from what ``routed_experts`` sees and set nowhere. The
-gather, the three grouped matmuls (the rows of the buffer past the held
-groups are one more group that no weights multiply: exact zeros), the
-SwiGLU and the way back run over the buffer, not over ``tokens x top_k``.
+gather, the grouped matmuls (three of a SwiGLU, two of an expert without a
+gate; the rows of the buffer past the held groups are one more group that
+no weights multiply: exact zeros), the activation and the way back run over
+the buffer, not over ``tokens x top_k``.
 The first buffer is computed on every routing; one that gives the held
 experts more rows than it has takes the next rows in a second buffer, and
 so on, ``ceil(asked / bound)`` in all: a loop that does not run on a
@@ -149,6 +167,9 @@ _flash = importlib.import_module("ray_tpu.ops.flash_attention")
 #: tile: measured on the v5e at 98,304 x 2048 x 1408 (see the module text);
 #: 1024 rows, or 1024 deep at this width, do not fit the scoped VMEM.
 _TILE_M, _TILE_K, _TILE_N_MAX = 512, 512, 1408
+#: What a width of the grouped product must be a multiple of to take the
+#: kernels: half a lane tile, so that 1856 = 29 x 64 does.
+_IRREGULAR = 64
 
 
 def _picked_scores(scores, picked):
@@ -162,14 +183,28 @@ def _picked_scores(scores, picked):
         jnp.where(hot, scores[:, None, :], 0).sum(-1))
 
 
+def _tile_n(n: int) -> int:
+    """The output tile of a grouped product ``n`` wide: the widest multiple
+    of 128 up to ``_TILE_N_MAX`` that divides ``n``; of a width none divides
+    (1856 = 14.5 x 128), the one that covers ``n`` in the fewest tiles with
+    the least past the edge (1024: two tiles, the second cut at 832 columns,
+    which ``megablox`` neither reads into a sum nor writes)."""
+    tiles = range(min(n // 128 * 128, _TILE_N_MAX), 0, -128)
+    return next((t for t in tiles if n % t == 0), None) or min(
+        tiles, key=lambda t: (-(-n // t), -(-n // t) * t))
+
+
 def grouped_matmul(rows, weights, group_sizes):
     """rows [M, k], sorted into ``len(group_sizes)`` contiguous groups, times
     each group's own weights [E, k, n] -> [M, n] in rows' dtype. Where there
     are more groups than weights, the weights are the first groups': only
-    their rows are multiplied, the others come out zero."""
+    their rows are multiplied, the others come out zero. ``megablox`` where
+    M is a multiple of 128 and both widths of ``_IRREGULAR`` and at least a
+    lane tile (its last tile of k masked, its last tile of n cut at the
+    edge), else ``jax.lax.ragged_dot``."""
     (m, k), n = rows.shape, weights.shape[-1]
     given = weights.shape[0]
-    if m % 128 or k % 128 or n % 128:
+    if m % 128 or min(k, n) < 128 or k % _IRREGULAR or n % _IRREGULAR:
         if group_sizes.shape[0] > given:
             # One stretch of rows after the last weights, against zeros.
             weights = jnp.concatenate(
@@ -179,10 +214,8 @@ def grouped_matmul(rows, weights, group_sizes):
         return jax.lax.ragged_dot(rows, weights, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
     tile_m = next(t for t in (_TILE_M, 256, 128) if m % t == 0)
-    tile_n = next(t for t in range(min(n, _TILE_N_MAX), 0, -128)
-                  if n % t == 0)
     return megablox.gmm(rows, weights, group_sizes, rows.dtype,
-                        (tile_m, min(k, _TILE_K), tile_n),
+                        (tile_m, min(k // 128 * 128, _TILE_K), _tile_n(n)),
                         interpret=_flash._interpret())
 
 
@@ -300,12 +333,46 @@ def _all_to_tokens_bwd(residuals, g):
 _all_to_tokens.defvjp(_all_to_tokens_fwd, _all_to_tokens_bwd)
 
 
-def _swiglu_groups(rows, w_gate, w_up, w_down, group_sizes):
-    """Every expert's SwiGLU on its own group of the rows; the rows of groups
-    past the last weights come out exact zeros."""
+#: ``activation`` -> the function between an expert's two products.
+ACTIVATIONS = {"silu": jax.nn.silu,
+               "relu2": lambda x: jnp.square(jax.nn.relu(x))}
+
+
+def _expert_hidden(rows, w_gate, w_up, group_sizes, activation: str):
+    """Every expert's hidden activations on its own group of the rows:
+    ``act(rows w_gate) * rows w_up`` (with a SiLU, a SwiGLU's), or, of an
+    expert without a gate (``w_gate`` None), ``act(rows w_up)``."""
+    act = ACTIVATIONS[activation]
+    if w_gate is None:
+        return act(grouped_matmul(rows, w_up, group_sizes))
     gate = grouped_matmul(rows, w_gate, group_sizes)
     up = grouped_matmul(rows, w_up, group_sizes)
-    return grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes)
+    return act(gate) * up
+
+
+def _expert_groups(rows, w_gate, w_up, w_down, group_sizes, activation: str):
+    """Every expert on its own group of the rows; the rows of groups past
+    the last weights come out exact zeros (``act(0)`` is 0 for both
+    activations)."""
+    return grouped_matmul(
+        _expert_hidden(rows, w_gate, w_up, group_sizes, activation), w_down,
+        group_sizes)
+
+
+def _gauges(hidden, groups, activation: str):
+    """What a forward counts beside its sum, of the rows of ``hidden`` that
+    ``groups`` [held experts + 1] gives the held experts (all, if None).
+    Of squared-ReLU experts ``computed``, those rows' hidden activations,
+    and ``zeroed``, how many of them are exact zeros (what the ReLU leaves
+    of every negative pre-activation), in one reduction; nothing of
+    others."""
+    if activation != "relu2":
+        return {}
+    held_rows = jnp.int32(hidden.shape[0]) if groups is None \
+        else groups[:-1].sum()
+    held = jnp.arange(hidden.shape[0])[:, None] < held_rows
+    return {"zeroed": ((hidden == 0) & held).sum(dtype=jnp.int32),
+            "computed": held_rows * hidden.shape[1]}
 
 
 # -- a share of the experts: the held rows alone --------------------------
@@ -521,25 +588,28 @@ def _over_buffers(one, needed):
         one(jnp.int32(0)))
 
 
-@partial(jax.jit, static_argnums=(0,))
-def _buffer_forward(bound, i, x, weights, w_gate, w_up, w_down, order, place,
-                    sizes):
+@partial(jax.jit, static_argnums=(0, 1))
+def _buffer_forward(bound, activation, i, x, weights, w_gate, w_up, w_down,
+                    order, place, sizes):
     """Buffer ``i``'s part of ``_held_experts``' sum, in float32, the rows
-    it gave each held expert, and the rows its way back to tokens read: the
+    it gave each held expert, the rows its way back to tokens read (the
     held ones where the kernel ran, one a routed assignment where the
-    gathers did."""
+    gathers did) and ``_gauges``."""
     tokens = x.shape[0]
     groups, rows_of = _buffer(i, bound, sizes, order)
     with jax.named_scope("moe_dispatch"):
         rows = _rows(x, rows_of % tokens)
     with jax.named_scope("moe_experts"):
-        out = _swiglu_groups(rows, w_gate, w_up, w_down, groups)
+        hidden = _expert_hidden(rows, w_gate, w_up, groups, activation)
+        out = grouped_matmul(hidden, w_down, groups)
+        gauges = _gauges(hidden, groups, activation)
     with jax.named_scope("moe_combine"):
         at = _at(place, i, bound, groups)
         summed = (jnp.int32(at.shape[0])
                   if _token_tile(out, at, tokens) is None
                   else groups[:-1].sum())
-        return _to_tokens(out, at, tokens, weights), groups[:-1], summed
+        return (_to_tokens(out, at, tokens, weights), groups[:-1], summed,
+                gauges)
 
 
 def _laid(values, i, bound: int, groups, length: int):
@@ -553,9 +623,9 @@ def _laid(values, i, bound: int, groups, length: int):
         (i * bound,))
 
 
-@partial(jax.jit, static_argnums=(0,))
-def _buffer_backward(bound, i, g, x, w_sorted, w_gate, w_up, w_down, order,
-                     place, sizes):
+@partial(jax.jit, static_argnums=(0, 1))
+def _buffer_backward(bound, activation, i, g, x, w_sorted, w_gate, w_up,
+                     w_down, order, place, sizes):
     """(d x in float32, d weights in expert order [as long as ``order``],
     [d w_gate, d w_up, d w_down]) of the rows of buffer ``i``, which it
     multiplies again, from g = d y and ``w_sorted``, the weights in expert
@@ -571,7 +641,8 @@ def _buffer_backward(bound, i, g, x, w_sorted, w_gate, w_up, w_down, order,
         d_out = (g_rows * w_rows[:, None]).astype(x.dtype)
     with jax.named_scope("moe_experts"):
         out, experts_vjp = jax.vjp(
-            partial(_swiglu_groups, group_sizes=groups),
+            partial(_expert_groups, group_sizes=groups,
+                    activation=activation),
             rows, w_gate, w_up, w_down)
         d_rows, *d_experts = experts_vjp(d_out)
     with jax.named_scope("moe_combine"):
@@ -581,31 +652,32 @@ def _buffer_backward(bound, i, g, x, w_sorted, w_gate, w_up, w_down, order,
         return _to_tokens(d_rows, at, tokens), d_w_sorted, d_experts
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_experts(bound, x, weights, w_gate, w_up, w_down, order, place,
-                  sizes):
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(bound, activation, x, weights, w_gate, w_up, w_down, order,
+                  place, sizes):
     """sum_{k: picked and held} weights[k, t] * Expert(x[t]) -> ([T, d] in
     x's dtype, the rows each held expert was given [count], the rows the
-    way back to tokens read).
+    way back to tokens read, ``_gauges`` summed over the buffers).
 
-    x [T, d]; weights [K, T] float32; the held experts' w_gate, w_up
-    [count, d, f], w_down [count, f, d] in x's dtype; order [K * T, padded
+    x [T, d]; weights [K, T] float32; the held experts' w_gate (None of
+    experts without a gate), w_up [count, d, f], w_down [count, f, d] in
+    x's dtype; order [K * T, padded
     to whole buffers], the assignments sorted by held expert, the others
     after them; place [K * T], its inverse; sizes [count], the router's
     histogram over the held experts. One buffer of ``bound`` rows at a time
     (the module text)."""
-    y, placed, summed = _over_buffers(
-        lambda i: _buffer_forward(bound, i, x, weights, w_gate, w_up, w_down,
-                                  order, place, sizes),
+    y, placed, summed, gauges = _over_buffers(
+        lambda i: _buffer_forward(bound, activation, i, x, weights, w_gate,
+                                  w_up, w_down, order, place, sizes),
         _buffers_needed(sizes.sum(), bound))
-    return y.astype(x.dtype), placed, summed
+    return y.astype(x.dtype), placed, summed, gauges
 
 
-def _held_experts_fwd(bound, *args):
-    return _held_experts(bound, *args), args
+def _held_experts_fwd(bound, activation, *args):
+    return _held_experts(bound, activation, *args), args
 
 
-def _held_experts_bwd(bound, residuals, cotangents):
+def _held_experts_bwd(bound, activation, residuals, cotangents):
     x, weights, w_gate, w_up, w_down, order, place, sizes = residuals
     # ``weights`` has no entry for the rows that fill ``order`` to whole
     # buffers.
@@ -613,8 +685,9 @@ def _held_experts_bwd(bound, residuals, cotangents):
         w_sorted = jnp.pad(_permuted(weights.reshape(-1), place),
                            (0, order.shape[0] - place.shape[0]))
     d_x, d_w_sorted, d_experts = _over_buffers(
-        lambda i: _buffer_backward(bound, i, cotangents[0], x, w_sorted,
-                                   w_gate, w_up, w_down, order, place, sizes),
+        lambda i: _buffer_backward(bound, activation, i, cotangents[0], x,
+                                   w_sorted, w_gate, w_up, w_down, order,
+                                   place, sizes),
         _buffers_needed(sizes.sum(), bound))
     with jax.named_scope("moe_combine"):
         d_weights = _permuted(d_w_sorted, order)[:place.shape[0]]
@@ -628,14 +701,18 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
                    scaling: float, normalize: bool = True,
                    held: Optional[Tuple[int, int]] = None,
-                   score: str = "sigmoid"
+                   score: str = "sigmoid", activation: str = "silu"
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """sum_i w_i Expert_i(x) over each token's ``top_k`` experts, dropless.
 
     x [T, d] (compute dtype); router [d, E]; bias [E], or None under
     ``score`` ``"softmax"`` (``route``); w_gate, w_up [E, d, f]; w_down [E,
-    f, d]. Expert_i is SwiGLU:
-    ``(silu(x w_gate_i) * x w_up_i) w_down_i``. Returns (y [T, d], aux) with
+    f, d]. Expert_i is ``(act(x w_gate_i) * x w_up_i) w_down_i``, a SwiGLU
+    under ``activation`` ``"silu"``, or, with ``w_gate`` None, two matrices
+    with the activation between them, ``act(x w_up_i) w_down_i``:
+    ``"relu2"`` is a squared ReLU, and ``aux["relu2_zero_share"]`` then says
+    what share of the computed rows' hidden activations it zeroed. Returns
+    (y [T, d], aux) with
     ``aux["picked"]`` [T, K] (the router's choice, for a reference to compare
     with), ``aux["group_sizes"]`` [E] (assignments each expert computed;
     their sum is T * K, or something was dropped) and, of a softmax router,
@@ -663,17 +740,21 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
         first, count = held
         if first < 0 or count < 1 or first + count > n_experts:
             raise ValueError(f"held={held} of {n_experts} experts")
-    if w_gate.shape[0] != (n_experts if first is None else count):
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"activation {activation!r}: one of "
+                         f"{sorted(ACTIVATIONS)}")
+    if w_up.shape[0] != (n_experts if first is None else count):
         raise ValueError(
-            f"weights of {w_gate.shape[0]} experts, router of {n_experts}, "
+            f"weights of {w_up.shape[0]} experts, router of {n_experts}, "
             f"held={held}")
     with jax.named_scope("moe_route"):
         picked, weights, mass = route(x, router, bias, top_k, scaling,
                                       normalize, score)
     gauges = {} if mass is None else {"picked_mass": mass.mean()}
     if first is not None:
-        y, aux = _share(x, picked, weights, w_gate.astype(dt),
-                        w_up.astype(dt), w_down.astype(dt), first, n_experts)
+        y, aux = _share(x, picked, weights, _cast(w_gate, dt),
+                        w_up.astype(dt), w_down.astype(dt), first, n_experts,
+                        activation)
         return y, dict(aux, **gauges)
     with jax.named_scope("moe_dispatch"):
         expert_of = picked.T.reshape(-1)  # assignment a = k * T + t
@@ -682,19 +763,36 @@ def routed_experts(x, router, bias, w_gate, w_up, w_down, *, top_k: int,
         group_sizes = _group_sizes(expert_of, n_experts)
         rows = _dispatch(x, order, place)  # [K*T, d], grouped by expert
     with jax.named_scope("moe_experts"):
-        out = _swiglu_groups(rows, w_gate.astype(dt), w_up.astype(dt),
-                             w_down.astype(dt), group_sizes)
+        w_gate, w_up, w_down = (_cast(w, dt) for w in (w_gate, w_up, w_down))
+        hidden = _expert_hidden(rows, w_gate, w_up, group_sizes, activation)
+        out = grouped_matmul(hidden, w_down, group_sizes)
+        gauges.update(_shares_of(_gauges(hidden, None, activation)))
     with jax.named_scope("moe_combine"):
         y = _all_to_tokens(out, weights.T, order, place)
     return y, {"picked": picked, "group_sizes": group_sizes, **gauges}
 
 
+def _cast(weights, dtype):
+    """``weights`` in ``dtype``; None (no gate) stays None."""
+    return None if weights is None else weights.astype(dtype)
+
+
+def _shares_of(gauges):
+    """``_gauges``' counts (summed over a share's buffers) as
+    ``routed_experts``' aux has them: ``relu2_zero_share``, the zeroed of
+    the computed; {} of none."""
+    if not gauges:
+        return {}
+    return {"relu2_zero_share": gauges["zeroed"].astype(jnp.float32)
+            / jnp.maximum(gauges["computed"], 1)}
+
+
 def _share(x, picked, weights, w_gate, w_up, w_down, first: int,
-           n_experts: int):
+           n_experts: int, activation: str):
     """``routed_experts`` on the experts ``first`` to ``first + count``, the
     weights given: the sort that puts their assignments first, then
     ``_held_experts``."""
-    (tokens, top_k), count = picked.shape, w_gate.shape[0]
+    (tokens, top_k), count = picked.shape, w_up.shape[0]
     bound = _held_bound(tokens, top_k, count, n_experts)
     with jax.named_scope("moe_dispatch"):
         expert_of = picked.T.reshape(-1)  # assignment a = k * T + t
@@ -708,9 +806,11 @@ def _share(x, picked, weights, w_gate, w_up, w_down, first: int,
         order = jnp.concatenate([order, jnp.arange(
             order.shape[0], -(-order.shape[0] // bound) * bound,
             dtype=jnp.int32)])
-    y, placed, summed = _held_experts(bound, x, weights.T, w_gate, w_up,
-                                      w_down, order, place, sizes)
+    y, placed, summed, gauges = _held_experts(
+        bound, activation, x, weights.T, w_gate, w_up, w_down, order, place,
+        sizes)
     asked = is_held.sum()
     return y, {"picked": picked, "asked": asked, "group_sizes": placed,
                "within_bound": (asked <= bound).astype(jnp.int32),
-               "rows_summed": summed}
+               "rows_summed": summed,
+               **_shares_of(gauges)}

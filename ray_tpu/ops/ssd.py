@@ -9,8 +9,9 @@ Per head, with a state ``S`` [P, N] that is zero before the first token::
 
 ``u`` [batch, S, H, P] holds H heads of P channels, ``dt`` [batch, S, H] the
 step sizes (positive: after the softplus), ``A`` [H] a negative scalar a
-head, ``B`` and ``C`` [batch, S, N] one group shared by every head, ``D`` [H]
-the skip. The literal recurrence is S sequential steps; the chunked form
+head, ``B`` and ``C`` [batch, S, G, N] G groups, head i reading group ``i //
+(H / G)`` (or [batch, S, N]: one group shared by every head), ``D`` [H] the
+skip. The literal recurrence is S sequential steps; the chunked form
 does a chunk of L steps as matrix products. With ``cum_t`` the sum of
 ``dt A`` from the chunk's first position to t, inside a chunk
 
@@ -24,22 +25,26 @@ The kernels' grid is ``(batch, chunks, head blocks)``, the last two
 sequential: a grid step is one chunk of one block of ``heads_per_block``
 heads, whose [L, L] decay matrices live in VMEM only (as einsums they are a
 float32 [heads, chunks, L, L] array in HBM). The states of all heads stay in
-VMEM scratch from chunk to chunk as the flash kernels carry their sums;
-``C B^T`` is computed once a chunk, at the first head block, and kept in
-scratch for the others. The forward also writes each chunk's entry state,
-which the backward reads: it walks the chunks in reverse with the cotangent
-of the state carried the same way, and sums the cotangents of ``B`` and ``C``
-over the head blocks in its output block. ``dt A`` and its running sum are
-made outside the kernels, by XLA, which differentiates them too: the kernels
-take ``dt`` and ``cum`` and return their cotangents. Products run in the
-dtype of ``u`` with float32 accumulation; decays, states and sums are
-float32.
+VMEM scratch from chunk to chunk as the flash kernels carry their sums. **A
+head block's group**: a block never straddles two groups
+(``heads_per_block`` divides the heads of a group), so block j reads the B
+and C of group ``j // (blocks a group)`` as its operand block; ``C B^T`` is
+computed once a chunk and group, at the group's first head block, and kept
+in scratch for the group's others (with one group: once a chunk; with as many
+heads a group as a block holds, 64 heads of 64 in 8 groups, once a grid
+step). The forward also writes each chunk's entry state, which the backward
+reads: it walks the chunks in reverse with the cotangent of the state carried
+the same way, and sums the cotangents of ``B`` and ``C`` over a group's head
+blocks in that group's output block. ``dt A`` and its running sum are made
+outside the kernels, by XLA, which differentiates them too: the kernels take
+``dt`` and ``cum`` and return their cotangents. Products run in the dtype of
+``u`` with float32 accumulation; decays, states and sums are float32.
 
 ``ssd`` is the one entry: the kernels where the shapes tile (S a multiple of
-the chunk, the chunk and N multiples of 128, a block of heads a multiple of
-128 lanes), else ``ssd_chunked``, the ``jax.numpy`` form, which is also the
-kernels' oracle in the tests. On backends other than the TPU the kernels run
-in interpreter mode.
+the chunk, the chunk and N multiples of 128, a block of a group's heads a
+multiple of 128 lanes), else ``ssd_chunked``, the ``jax.numpy`` form, which
+is also the kernels' oracle in the tests. On backends other than the TPU the
+kernels run in interpreter mode.
 """
 
 from __future__ import annotations
@@ -74,31 +79,46 @@ def _chunk_sums(dt, A, chunk: int):
     return dt, jnp.cumsum(dt * A.astype(F32), axis=2)
 
 
+def _grouped(B, heads: int):
+    """B or C as [batch, S, G, N] (one group where the axis is not given),
+    with G checked against the heads it is shared among."""
+    if B.ndim == 3:
+        B = B[:, :, None, :]
+    if heads % B.shape[2]:
+        raise ValueError(f"{heads} heads over {B.shape[2]} groups of B and C")
+    return B
+
+
 def ssd_chunked(u, dt, A, B, C, D, chunk: int = 256):
     """The chunked algorithm as einsums, any length (the tail is padded with
     steps of size 0, which leave the state as it is): float32 throughout,
-    the decay matrix [batch, chunks, L, L, H] whole. The kernels' oracle
-    and the path for shapes they cannot tile."""
+    the decay matrix [batch, chunks, L, L, H] whole, the heads as [G, H / G]
+    beside their group's B and C. The kernels' oracle and the path for
+    shapes they cannot tile."""
     batch, seq, heads, width = u.shape
+    B, C = _grouped(B, heads), _grouped(C, heads)
+    groups = B.shape[2]
     pad = -seq % chunk
     if pad:
         u, dt, B, C = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
                        for a in (u, dt, B, C))
     n = (seq + pad) // chunk
-    dt_c, cum = _chunk_sums(dt, A, chunk)
-    u_c = u.astype(F32).reshape(batch, n, chunk, heads, width)
-    B_c, C_c = (a.astype(F32).reshape(batch, n, chunk, -1) for a in (B, C))
-    causal = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None]
+    by_group = (batch, n, chunk, groups, heads // groups)
+    dt_c, cum = (a.reshape(by_group) for a in _chunk_sums(dt, A, chunk))
+    u_c = u.astype(F32).reshape(by_group + (width,))
+    B_c, C_c = (a.astype(F32).reshape(batch, n, chunk, groups, -1)
+                for a in (B, C))
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None, None]
     decay = jnp.exp(jnp.where(
-        causal, cum[:, :, :, None, :] - cum[:, :, None, :, :], _NEG))
-    scores = jnp.einsum("bcln,bcsn->bcls", C_c, B_c)
-    mixed = scores[..., None] * decay * dt_c[:, :, None, :, :]
-    y = jnp.einsum("bclsh,bcshp->bclhp", mixed, u_c)
+        causal, cum[:, :, :, None] - cum[:, :, None], _NEG))
+    scores = jnp.einsum("bclgn,bcsgn->bclsg", C_c, B_c)
+    mixed = scores[..., None] * decay * dt_c[:, :, None]
+    y = jnp.einsum("bclsgr,bcsgrp->bclgrp", mixed, u_c)
     # Each chunk's own contribution to its exit state, then the entry
     # states by the recurrence over chunks.
-    total = cum[:, :, -1, :]
-    own = jnp.einsum("bclh,bclhp,bcln->bchpn",
-                     jnp.exp(total[:, :, None, :] - cum) * dt_c, u_c, B_c)
+    total = cum[:, :, -1]
+    own = jnp.einsum("bclgr,bclgrp,bclgn->bcgrpn",
+                     jnp.exp(total[:, :, None] - cum) * dt_c, u_c, B_c)
 
     def carry(state, chunk_terms):
         decay_c, own_c = chunk_terms
@@ -108,8 +128,8 @@ def ssd_chunked(u, dt, A, B, C, D, chunk: int = 256):
         carry, jnp.zeros(own.shape[:1] + own.shape[2:], F32),
         (jnp.exp(total).swapaxes(0, 1), own.swapaxes(0, 1)))
     y = y + jnp.exp(cum)[..., None] * jnp.einsum(
-        "bcln,cbhpn->bclhp", C_c, entry)
-    y = y + D.astype(F32)[:, None] * u_c
+        "bclgn,cbgrpn->bclgrp", C_c, entry)
+    y = y + D.astype(F32).reshape(groups, -1, 1) * u_c
     return y.reshape(batch, seq + pad, heads, width)[:, :seq].astype(u.dtype)
 
 
@@ -129,12 +149,13 @@ def _causal(chunk: int):
 
 def _ssd_fwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref, etot_ref,
                     y_ref, entry_ref, scores_scr, state_scr, scaled_scr, *,
-                    heads: int, width: int):
-    """One chunk of one block of ``heads`` heads. u/y [L, heads * width];
-    b/c [L, N]; cols [3, L, heads] holds (dt, cum, cum_L - cum) as columns
-    and rows [2, heads, L] (dt, cum) as rows; d [1, heads * width] is D and
-    etot [1, heads * width] exp(cum_L), a value a lane; entry [N, heads *
-    width] is the block's state on entry, transposed (the state of head h
+                    heads: int, width: int, per_group: int):
+    """One chunk of one block of ``heads`` heads, of the ``per_group`` blocks
+    that share a group's B and C. u/y [L, heads * width]; b/c [L, N], the
+    block's group's; cols [3, L, heads] holds (dt, cum, cum_L - cum) as
+    columns and rows [2, heads, L] (dt, cum) as rows; d [1, heads * width] is
+    D and etot [1, heads * width] exp(cum_L), a value a lane; entry [N, heads
+    * width] is the block's state on entry, transposed (the state of head h
     is ``[:, h * width:(h + 1) * width]``)."""
     ci, hi = pl.program_id(1), pl.program_id(2)
     chunk = u_ref.shape[0]
@@ -144,7 +165,7 @@ def _ssd_fwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref, etot_ref,
     def _():
         state_scr[hi] = jnp.zeros(state_scr.shape[1:], F32)
 
-    @pl.when(hi == 0)
+    @pl.when(hi % per_group == 0)
     def _():
         scores_scr[...] = _mm(c_ref[...], b_ref[...], 1, 1)
 
@@ -172,13 +193,14 @@ def _ssd_fwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref, etot_ref,
 def _ssd_bwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref, etot_ref,
                     entry_ref, dy_ref, du_ref, db_ref, dc_ref, dcols_ref,
                     drows_ref, scores_scr, dscores_scr, dstate_scr,
-                    scaled_scr, dys_scr, *, heads: int, width: int):
+                    scaled_scr, dys_scr, *, heads: int, width: int,
+                    per_group: int):
     """The forward's grid step with the chunks in reverse (the index maps
     turn them round): the cotangent of the block's exit state is carried in
-    ``dstate_scr``. db/dc [L, N] float32 are summed over the head blocks in
-    place; dcols [3, L, heads] are the cotangents of dt, cum and D (the
-    last to be summed over L) found as columns, drows [2, heads, L] those
-    of dt and cum found as rows."""
+    ``dstate_scr``. db/dc [L, N] float32, the group's, are summed over its
+    ``per_group`` head blocks in place; dcols [3, L, heads] are the
+    cotangents of dt, cum and D (the last to be summed over L) found as
+    columns, drows [2, heads, L] those of dt and cum found as rows."""
     ti, hi = pl.program_id(1), pl.program_id(2)
     chunk = u_ref.shape[0]
     dtype = u_ref.dtype
@@ -187,7 +209,7 @@ def _ssd_bwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref, etot_ref,
     def _():
         dstate_scr[hi] = jnp.zeros(dstate_scr.shape[1:], F32)
 
-    @pl.when(hi == 0)
+    @pl.when(hi % per_group == 0)
     def _():
         scores_scr[...] = _mm(c_ref[...], b_ref[...], 1, 1)
         dscores_scr[...] = jnp.zeros(dscores_scr.shape, F32)
@@ -244,18 +266,19 @@ def _ssd_bwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref, etot_ref,
     db_ref[...] += _mm(scaled_scr[...], dstate.astype(dtype), 1, 1)
     dstate_scr[hi] = etot_ref[...] * dstate + _mm(c_ref[...], dys_all, 0, 0)
 
-    @pl.when(hi == pl.num_programs(2) - 1)
+    @pl.when(hi % per_group == per_group - 1)
     def _():
         dscores = dscores_scr[...].astype(dtype)
         dc_ref[...] += _mm(dscores, b_ref[...], 1, 0)
         db_ref[...] += _mm(dscores, c_ref[...], 0, 0)
 
 
-def heads_per_block(heads: int, width: int) -> int:
-    """Heads a grid step takes: the most of 8, 4, 2, 1 that divide the head
-    count into blocks of whole 128-lane tiles (0 if none does)."""
+def heads_per_block(heads: int, width: int, groups: int = 1) -> int:
+    """Heads a grid step takes: the most of 8, 4, 2, 1 that divide the heads
+    of one group of B and C into blocks of whole 128-lane tiles (0 if none
+    does)."""
     return next((n for n in (8, 4, 2, 1)
-                 if heads % n == 0 and n * width % 128 == 0), 0)
+                 if heads // groups % n == 0 and n * width % 128 == 0), 0)
 
 
 def _layouts(dt_c, cum, D, block: int, width: int):
@@ -276,9 +299,11 @@ def _layouts(dt_c, cum, D, block: int, width: int):
 
 
 def _specs(chunk: int, lanes: int, state: int, block: int, n_chunks: int,
-           reverse: bool):
+           per_group: int, reverse: bool):
     """BlockSpecs over the grid (batch, chunks, head blocks), by operand
-    kind; ``reverse`` walks the chunks from the last."""
+    kind; ``reverse`` walks the chunks from the last. B and C are [batch, S,
+    G * N], head block j reading the N columns of group ``j //
+    per_group``."""
     def at(t):
         return n_chunks - 1 - t if reverse else t
 
@@ -286,7 +311,7 @@ def _specs(chunk: int, lanes: int, state: int, block: int, n_chunks: int,
         "wide": pl.BlockSpec((None, chunk, lanes),
                              lambda b, t, j: (b, at(t), j)),
         "bc": pl.BlockSpec((None, chunk, state),
-                           lambda b, t, j: (b, at(t), 0)),
+                           lambda b, t, j: (b, at(t), j // per_group)),
         "cols": lambda k: pl.BlockSpec(
             (None, None, None, k, chunk, block),
             lambda b, t, j: (b, at(t), j, 0, 0, 0)),
@@ -304,16 +329,24 @@ def _specs(chunk: int, lanes: int, state: int, block: int, n_chunks: int,
 _PARAMS = dict(dimension_semantics=("parallel", "arbitrary", "arbitrary"))
 
 
+def _flat_groups(B):
+    """B or C [batch, S, G, N] as the kernels read it, [batch, S, G * N]."""
+    return B.reshape(B.shape[:2] + (-1,))
+
+
 def _forward(u, dt_c, cum, B, C, D, block: int):
     """(y [batch, S, H * P], entry states [batch, chunks, blocks, N, block *
-    P]) by the forward kernel; u is [batch, S, H * P]."""
+    P]) by the forward kernel; u is [batch, S, H * P], B and C [batch, S, G,
+    N]."""
     batch, n, chunk, heads = dt_c.shape
-    seq, state = B.shape[1], B.shape[2]
+    groups, state = B.shape[2:]
     width = u.shape[-1] // heads
     lanes, blocks = block * width, heads // block
-    spec = _specs(chunk, lanes, state, block, n, reverse=False)
+    per_group = blocks // groups
+    spec = _specs(chunk, lanes, state, block, n, per_group, reverse=False)
     return pl.pallas_call(
-        functools.partial(_ssd_fwd_kernel, heads=block, width=width),
+        functools.partial(_ssd_fwd_kernel, heads=block, width=width,
+                          per_group=per_group),
         grid=(batch, n, blocks),
         in_specs=[spec["wide"], spec["bc"], spec["bc"], spec["cols"](3),
                   spec["rows"](2), spec["d"], spec["etot"]],
@@ -327,29 +360,31 @@ def _forward(u, dt_c, cum, B, C, D, block: int):
         compiler_params=pltpu.CompilerParams(**_PARAMS),
         interpret=_interpret(),
         name="ssd_fwd",
-    )(u, B, C, *_layouts(dt_c, cum, D, block, width))
+    )(u, _flat_groups(B), _flat_groups(C),
+      *_layouts(dt_c, cum, D, block, width))
 
 
 def _backward(u, dt_c, cum, B, C, D, entry, dy, block: int):
     """Cotangents (du, ddt, dcum [batch, chunks, L, H], dB, dC, dD) by the
     backward kernel."""
     batch, n, chunk, heads = dt_c.shape
-    state = B.shape[2]
+    groups, state = B.shape[2:]
     width = u.shape[-1] // heads
     lanes, blocks = block * width, heads // block
-    spec = _specs(chunk, lanes, state, block, n, reverse=True)
+    per_group = blocks // groups
+    spec = _specs(chunk, lanes, state, block, n, per_group, reverse=True)
     small = (batch, n, blocks)
+    flat = jax.ShapeDtypeStruct(B.shape[:2] + (groups * state,), F32)
     du, dB, dC, dcols, drows = pl.pallas_call(
-        functools.partial(_ssd_bwd_kernel, heads=block, width=width),
+        functools.partial(_ssd_bwd_kernel, heads=block, width=width,
+                          per_group=per_group),
         grid=(batch, n, blocks),
         in_specs=[spec["wide"], spec["bc"], spec["bc"], spec["cols"](3),
                   spec["rows"](2), spec["d"], spec["etot"], spec["state"],
                   spec["wide"]],
         out_specs=[spec["wide"], spec["bc"], spec["bc"], spec["cols"](3),
                    spec["rows"](2)],
-        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
-                   jax.ShapeDtypeStruct(B.shape, F32),
-                   jax.ShapeDtypeStruct(C.shape, F32),
+        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype), flat, flat,
                    jax.ShapeDtypeStruct(small + (3, chunk, block), F32),
                    jax.ShapeDtypeStruct(small + (2, block, chunk), F32)],
         scratch_shapes=[pltpu.VMEM((chunk, chunk), F32),
@@ -360,14 +395,16 @@ def _backward(u, dt_c, cum, B, C, D, entry, dy, block: int):
         compiler_params=pltpu.CompilerParams(**_PARAMS),
         interpret=_interpret(),
         name="ssd_bwd",
-    )(u, B, C, *_layouts(dt_c, cum, D, block, width), entry, dy)
+    )(u, _flat_groups(B), _flat_groups(C),
+      *_layouts(dt_c, cum, D, block, width), entry, dy)
     # [batch, chunks, blocks, k, L, block] -> k x [batch, chunks, L, H]
     from_cols = dcols.transpose(3, 0, 1, 4, 2, 5).reshape(
         3, batch, n, chunk, heads)
     from_rows = drows.transpose(3, 0, 1, 5, 2, 4).reshape(
         2, batch, n, chunk, heads)
     ddt, dcum = from_cols[0] + from_rows[0], from_cols[1] + from_rows[1]
-    return (du, ddt, dcum, dB.astype(B.dtype), dC.astype(C.dtype),
+    return (du, ddt, dcum, dB.reshape(B.shape).astype(B.dtype),
+            dC.reshape(C.shape).astype(C.dtype),
             from_cols[2].sum((0, 1, 2)).astype(D.dtype))
 
 
@@ -391,10 +428,12 @@ _ssd_kernels.defvjp(_ssd_kernels_fwd, _ssd_kernels_bwd)
 def ssd(u, dt, A, B, C, D, chunk: int = 256):
     """y [batch, S, H, P] of the recurrence at the top of this file, by
     chunks of ``chunk`` positions. u [batch, S, H, P]; dt [batch, S, H]
-    positive; A [H] negative; B, C [batch, S, N]; D [H]. The kernels where
+    positive; A [H] negative; B, C [batch, S, G, N] (or [batch, S, N]: one
+    group), head i reading group ``i // (H / G)``; D [H]. The kernels where
     the shapes tile, else ``ssd_chunked``."""
     batch, seq, heads, width = u.shape
-    block = heads_per_block(heads, width)
+    B, C = _grouped(B, heads), _grouped(C, heads)
+    block = heads_per_block(heads, width, B.shape[2])
     if seq % chunk or chunk % 128 or B.shape[-1] % 128 or not block:
         return ssd_chunked(u, dt, A, B, C, D, chunk)
     with jax.named_scope("ssd"):

@@ -803,7 +803,7 @@ LOWERED_STEPS = {
     "gptj-6b-1chip.steady": "b470aa16aac6",
     "gptj-6b-4chip.steady": "42d82d54bed3",
     "moonlight-16b-a3b-1chip.steady": "7306fc08c9c0",
-    "granite-4.0-h-micro-1chip.steady": "6dff69cbb9be",
+    "granite-4.0-h-micro-1chip.steady": "9f52f929b5af",
     "phi-4-mini-flash-reasoning-1chip.steady": "b8326d36469b",
     "mellum2-12b-a2.5b-1chip.steady": "b0cda0859e19",
 }
@@ -842,7 +842,12 @@ def test_a_step_without_a_share_is_the_program_it_was(topo, cell):
     moved Moonlight's and Mellum's by intent (``moe.route``, which every
     expert layer runs, picks its scores without a gather or a scatter); the
     two GPT-J cells', granite's and phi's hold as recorded, so nothing of a
-    cell without an expert layer moved."""
+    cell without an expert layer moved. PR 62 moved granite's by intent
+    (6dff69cbb9be before it): ``ops/ssd.py`` takes B and C with a group
+    axis, so the kernels find a head block's group by a ``%`` and a ``//``
+    and granite hands its one group over as [batch, S, 1, N]; the five
+    others hold, Moonlight's and Mellum's through the expert's form
+    (``ops/moe.py`` ``activation``) too."""
     step, args, _ = _a_cells_step(topo, cell)
     assert _lowered_digest(step, args) == LOWERED_STEPS[cell]
 
@@ -1051,6 +1056,27 @@ def test_flash_step_compiles_on_four_chips(topo):
     assert per_device < 16 * 2 ** 30, per_device
 
 
+@pytest.mark.parametrize("cell,layers,kept", [
+    ("granite-4.0-h-micro-1chip.steady", 2, True),
+    ("moonlight-16b-a3b-1chip.steady", 4, True),
+    ("gptj-6b-1chip.steady", 10, False)])
+def test_a_cells_traced_step_runs_the_flash_forward_by_what_remat_keeps(
+        topo, cell, layers, kept):
+    """The census the three whole-step compiles below read off the compiled
+    text (``slow`` since PR 62: 79 CPU-seconds), read off the cells' own
+    traced steps, which the digests' cases have traced already: the
+    forward kernel once a layer that attends where remat keeps its outputs
+    (granite's 2 attention layers of 20 at S / Dv = 512, Moonlight's 1 + 3
+    at 64), twice where a kept byte buys too little (GPT-J's 10 at 8); each
+    backward kernel once."""
+    from ray_tpu.parallel.collectives import kernel_census
+    census = kernel_census(_a_cells_step(topo, cell)[2], a_step=True)
+    assert [census[name] for name in ("flash_fwd", "flash_bwd_dq",
+                                      "flash_bwd_dkv")] == \
+        [layers if kept else 2 * layers, layers, layers]
+
+
+@pytest.mark.slow  # PR 62: the traced case above holds the census
 @pytest.mark.parametrize("shaped_like", ["granite-4.0-h-micro",
                                          "moonlight-16b-a3b", "gptj-6b"])
 def test_step_runs_the_flash_forward_once_a_layer(topo, shaped_like):

@@ -98,23 +98,17 @@ def test_a_dropped_term_shows(both, dropped):
     assert err > 0.01 * both["rms"], (dropped, err, both["rms"])
 
 
-def test_the_reference_is_the_published_implementation(monkeypatch):
-    """``reference/granitemoehybrid.py`` (the literal recurrence, here in
-    five stretches of 32 positions) against ``transformers``'
-    ``GraniteMoeHybridForCausalLM`` (``torch_forward``, the chunked form) on
-    the same seeded weights: a sequence longer than a chunk and not a
-    multiple of it."""
-    monkeypatch.setattr(reference, "SEGMENT", 32)
+def _published_logits(cfg, params, tokens):
+    """``transformers``' ``GraniteMoeHybridForCausalLM`` (``torch_forward``,
+    the chunked form, chunks of 64) on the program's parameters: logits [B,
+    S, vocab]."""
     torch = pytest.importorskip("torch")
-    transformers = pytest.importorskip("transformers")
+    pytest.importorskip("transformers")
     try:
         from transformers import (GraniteMoeHybridConfig,
                                   GraniteMoeHybridForCausalLM)
     except ImportError:
         pytest.skip("this transformers has no granitemoehybrid")
-    cfg = replace(CFG, mamba_chunk_size=64)
-    seq = 160
-    params = drawn(GRANITE, cfg, seed=3)
     hf_config = GraniteMoeHybridConfig(
         vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
         intermediate_size=cfg.shared_intermediate_size,
@@ -129,7 +123,7 @@ def test_the_reference_is_the_published_implementation(monkeypatch):
         logits_scaling=cfg.logits_scaling, num_local_experts=0,
         num_experts_per_tok=0, mamba_n_heads=cfg.mamba_n_heads,
         mamba_d_head=cfg.mamba_d_head, mamba_d_state=cfg.mamba_d_state,
-        mamba_n_groups=1, mamba_d_conv=cfg.mamba_d_conv,
+        mamba_n_groups=cfg.mamba_n_groups, mamba_d_conv=cfg.mamba_d_conv,
         mamba_expand=cfg.mamba_expand, mamba_chunk_size=64,
         mamba_conv_bias=True, mamba_proj_bias=False,
         rms_norm_eps=cfg.rms_norm_eps, position_embedding_type="nope",
@@ -173,16 +167,63 @@ def test_the_reference_is_the_published_implementation(monkeypatch):
                 w["wo"].reshape(-1, d).T)
     missing, unexpected = model.load_state_dict(state, strict=False)
     assert not unexpected and not missing, (missing, unexpected)
-    tokens, targets = batch(cfg, seq, seed=5)
     with torch.no_grad():
-        want = model(torch.tensor(np.asarray(tokens, np.int64))
+        return model(torch.tensor(np.asarray(tokens, np.int64))
                      ).logits.numpy()
+
+
+def test_the_reference_is_the_published_implementation(monkeypatch):
+    """``reference/granitemoehybrid.py`` (the literal recurrence, here in
+    five stretches of 32 positions) against ``transformers``'
+    ``GraniteMoeHybridForCausalLM`` (``torch_forward``, the chunked form) on
+    the same seeded weights: a sequence longer than a chunk and not a
+    multiple of it."""
+    monkeypatch.setattr(reference, "SEGMENT", 32)
+    cfg = replace(CFG, mamba_chunk_size=64)
+    seq = 160
+    params = drawn(GRANITE, cfg, seed=3)
+    tokens, targets = batch(cfg, seq, seed=5)
+    want = _published_logits(cfg, params, tokens)
     where = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32), tokens.shape)
     got, _, rms = reference.forward(
         params, tokens, targets, where,
         **reference.arguments(published(cfg)))
     assert float(rms) > 0.01
     np.testing.assert_allclose(got, want, atol=1e-3 * float(rms))
+
+
+def test_two_groups_of_b_and_c_are_the_published_implementations():
+    """``mamba_n_groups`` 2 (two heads of 64 a group, so that the kernels
+    take a head block a group): the program with its kernels, interpreted,
+    against ``transformers``, whose norm stays over the whole row; and the
+    groups are read: with group 1's B and C in group 0's place the logits
+    move."""
+    cfg = replace(CFG, mamba_n_groups=2)
+    params = drawn(GRANITE, cfg, seed=3)
+    tokens, _ = batch(cfg, SEQ, seed=5)
+    want = _published_logits(cfg, params, tokens)
+    got = forward_alone(GRANITE, params, cfg, tokens)
+    rms = float(np.sqrt((want ** 2).mean()))
+    assert rms > 0.01
+    np.testing.assert_allclose(got, want, atol=1e-3 * rms)
+    di, n = cfg.mamba_d_inner, cfg.mamba_d_state
+
+    def one_group(w):
+        """Group 0's B and C columns of the projection and the conv copied
+        over group 1's: every head then reads group 0."""
+        def copied(a, at):
+            for first in (at + di, at + di + 2 * n):
+                a = a.at[..., first + n:first + 2 * n].set(
+                    a[..., first:first + n])
+            return a
+        return dict(w, w_in=copied(w["w_in"], di),
+                    conv_w=copied(w["conv_w"], 0),
+                    conv_b=copied(w["conv_b"], 0))
+
+    mamba = {run: one_group(params[run])
+             for run, kind, _ in lm.runs(cfg.layers) if kind == "mamba"}
+    moved = forward_alone(GRANITE, dict(params, **mamba), cfg, tokens)
+    assert float(np.abs(moved - want).max()) > 0.05 * rms
 
 
 @pytest.mark.parametrize("blk_q,blk_k", [(128, 128), (256, 128)])
@@ -308,8 +349,8 @@ def test_state_space_runs_per_shard_under_a_mesh():
 def test_config_refuses_what_the_program_does_not_compute():
     with pytest.raises(NotImplementedError):
         replace(CFG, num_local_experts=8)
-    with pytest.raises(NotImplementedError):
-        replace(CFG, mamba_n_groups=2)
+    with pytest.raises(ValueError):
+        replace(CFG, mamba_n_groups=3)
     with pytest.raises(ValueError):
         replace(CFG, num_hidden_layers=9)
     assert granite.config("granite-4.0-h-micro").layers.count(

@@ -17,7 +17,9 @@ by PR 45, the other six's as they were (Moonlight's and Kimi Linear's latent
 layers draw ``wq`` where they did: ``lm.mla_leaves`` with a null rank);
 ``evabyte``'s by PR 49, the other seven's as they were; ``minicpm_sala``'s
 by PR 51, the other eight's as they were; ``mellum``'s by PR 58, the other
-nine's as they were.
+nine's as they were; ``nemotron_h``'s by PR 62, the other ten's as they were
+(granite's and phi's ``A_log`` is drawn by ``lm.log_arange``, the function
+their modules held).
 
 A PR that changes a family's draw on purpose records them again and says so;
 one that does not must leave this file alone."""
@@ -33,7 +35,8 @@ FAMILIES = {"deepseek": "deepseek-tiny", "granite": "granite-tiny",
             "afmoe": "afmoe-tiny", "kimi_linear": "kimi-linear-tiny",
             "lfm2": "lfm2-tiny", "phi4flash": "phi4flash-tiny",
             "glm_moe_dsa": "glm-tiny", "evabyte": "evabyte-tiny",
-            "minicpm_sala": "minicpm-sala-tiny", "mellum": "mellum-tiny"}
+            "minicpm_sala": "minicpm-sala-tiny", "mellum": "mellum-tiny",
+            "nemotron_h": "nemotron-h-tiny"}
 PINNED = pathlib.Path(__file__).with_name("init_pinned.json")
 
 

@@ -5,7 +5,10 @@ multiple of the chunk.
 
 float32 throughout: the three compute the same sums in another order, over a
 few hundred terms, so 1e-5 of each array's largest entry is reassociation
-and nothing else.
+and nothing else. B and C with a group axis (2 and 8 groups of the 16 heads,
+and 2 of 32, so that a group is one head block and two) go the same way,
+the literal recurrence then ``reference_nemotron_h``'s; one group given
+with and without its axis is the same program to the bit.
 """
 
 import jax
@@ -15,6 +18,7 @@ import pytest
 
 from ray_tpu.ops import ssd as ssd_mod
 import reference_granitemoehybrid as reference
+import reference_nemotron_h as grouped_reference
 
 
 def _recurrence(u, dt, A, B, C, D):
@@ -100,3 +104,75 @@ def test_a_state_is_carried_across_chunks():
     np.testing.assert_allclose(
         moved, _recurrence(u.at[:, :CHUNK].multiply(2.0), dt, A, B, C, D),
         atol=1e-4)
+
+
+# -- B and C in groups -------------------------------------------------------
+
+GROUPED = {"2 groups": (2, HEADS), "8 groups": (8, HEADS),
+           "2 groups, 2 blocks a group": (2, 2 * HEADS)}
+
+
+def _grouped_data(groups, heads, seq=2 * CHUNK):
+    ks = jax.random.split(jax.random.PRNGKey(groups + heads), 7)
+    u = jax.random.normal(ks[0], (1, seq, heads, WIDTH))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (1, seq, heads)) - 2.0)
+    A = -jnp.exp(0.5 * jax.random.normal(ks[2], (heads,)))
+    B = 0.3 * jax.random.normal(ks[3], (1, seq, groups, STATE))
+    C = 0.3 * jax.random.normal(ks[4], (1, seq, groups, STATE))
+    D = jax.random.normal(ks[5], (heads,))
+    return (u, dt, A, B, C, D), jax.random.normal(ks[6], u.shape)
+
+
+def _grouped_recurrence(u, dt, A, B, C, D):
+    state = jnp.zeros(u.shape[:1] + u.shape[2:] + B.shape[-1:])
+    return grouped_reference._recurrence(state, u, dt, A, B, C, D)[1]
+
+
+@pytest.fixture(scope="module")
+def grouped():
+    out = {}
+    for name, (groups, heads) in GROUPED.items():
+        args, g = _grouped_data(groups, heads)
+        assert ssd_mod.heads_per_block(heads, WIDTH, groups) == min(
+            8, heads // groups)
+        out[name] = {
+            "literal": _all(_grouped_recurrence, args, g),
+            "chunked": _all(lambda *a: ssd_mod.ssd_chunked(
+                *a, chunk=CHUNK), args, g),
+            "ssd": _all(lambda *a: ssd_mod.ssd(*a, chunk=CHUNK), args, g)}
+    return out
+
+
+@pytest.mark.parametrize("index", range(len(NAMES)), ids=NAMES)
+@pytest.mark.parametrize("against", ["literal", "chunked"])
+@pytest.mark.parametrize("name", GROUPED)
+def test_grouped_ssd_matches(grouped, name, against, index):
+    got, want = grouped[name]["ssd"][index], grouped[name][against][index]
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+
+
+def test_a_head_reads_its_own_group():
+    """Group 1's B and C replaced by group 0's moves the heads of group 1
+    and no head of group 0."""
+    (u, dt, A, B, C, D), _ = _grouped_data(2, HEADS)
+    y = ssd_mod.ssd(u, dt, A, B, C, D, chunk=CHUNK)
+    same = ssd_mod.ssd(u, dt, A, B.at[:, :, 1].set(B[:, :, 0]),
+                       C.at[:, :, 1].set(C[:, :, 0]), D, chunk=CHUNK)
+    half = HEADS // 2
+    assert (same[:, :, :half] == y[:, :, :half]).all()
+    assert float(jnp.abs(same - y)[:, :, half:].max()) > 1e-2
+    with pytest.raises(ValueError, match="groups"):
+        ssd_mod.ssd(u, dt, A, B[:, :, :1].repeat(3, 2), C, D, chunk=CHUNK)
+
+
+def test_one_group_with_and_without_its_axis_is_one_program(results):
+    """granite's call ([batch, S, N]) and the same B and C as [batch, S, 1,
+    N]: the kernels' results and every cotangent, bit for bit."""
+    args, g = _data(3 * CHUNK, 2, 3 * CHUNK)
+    u, dt, A, B, C, D = args
+    with_axis = _all(lambda *a: ssd_mod.ssd(*a, chunk=CHUNK),
+                     (u, dt, A, B[:, :, None], C[:, :, None], D), g)
+    for got, want in zip(with_axis, results[3 * CHUNK]["ssd"]):
+        assert (got.reshape(want.shape) == want).all()
