@@ -40,6 +40,25 @@ outside the kernels, by XLA, which differentiates them too: the kernels take
 ``dt`` and ``cum`` and return their cotangents. Products run in the dtype of
 ``u`` with float32 accumulation; decays, states and sums are float32.
 
+**The backward's head loop** makes one [L, L] array a head, the decay (128
+rows at a time against the columns up to theirs: the tiles above the
+diagonal are never made), and three products with it. ``dt`` rides the
+operand a head wide: ``mixed = scores * decay * dt_s`` is ``decayed =
+scores * decay`` on ``dt u``, so the cotangent of ``decayed`` is ``dY (dt
+u)^T`` out of the matrix unit, and that of ``dt u`` is ``decayed^T dY``. What
+``dt`` and ``cum`` are owed is read off products [L, P], not off squares:
+with ``g = decayed^T dY`` and ``y = decayed (dt u)`` (the forward's product
+again), ``d dt_s = u_s . g_s``, ``d cum_t = dY_t . y_t`` and ``d cum_s = -(dt
+u)_s . g_s``. The last two cancel over a chunk (the decay reads ``cum_t -
+cum_s``) and ``dA`` sums what is left over every position, so both take
+their operands as the products rounded them (``dt u`` in the dtype of
+``u``, not ``dt`` times the float32 ``u``): rounded two ways they leave a
+tenth of ``dA`` behind. Everything else of a grid step runs once over the
+block's [L, heads * P] lanes, before and after the loop; a head's ``dt``,
+``exp(cum)`` and ``exp(cum_L - cum)`` reach its lanes, and its lanes' sums
+come back as rows [heads, L], through products with 0/1 matrices, float32
+moved through them in three bfloat16 pieces (``_pieces``).
+
 ``ssd`` is the one entry: the kernels where the shapes tile (S a multiple of
 the chunk, the chunk and N multiples of 128, a block of a group's heads a
 multiple of 128 lanes), else ``ssd_chunked``, the ``jax.numpy`` form, which
@@ -190,17 +209,62 @@ def _ssd_fwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref, etot_ref,
         b_ref[...], scaled_scr[...], 0, 0)
 
 
+def _pieces(x):
+    """x float32 as three bfloat16 arrays that sum to it (to 2 ** -24 of
+    it): what moves float32 whole through a product with a 0/1 matrix."""
+    out = []
+    for _ in range(3):
+        out.append(x.astype(jnp.bfloat16))
+        x = x - out[-1].astype(F32)
+    return out
+
+
+def _of_head(rows: int, heads: int, width: int):
+    """The 0/1 matrix [rows, heads * width] that is 1 where row ``r`` (of
+    ``rows`` / ``heads`` stacks of the heads) and the lane are one head's."""
+    shape = (rows, heads * width)
+    same = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) % heads
+            == jax.lax.broadcasted_iota(jnp.int32, shape, 1) // width)
+    return same.astype(jnp.bfloat16)
+
+
+def _by_lane(rows, width: int):
+    """rows [heads, R] float32, a value a head and position, as a value a
+    lane [R, heads * width]: the pieces one under the other against the 0/1
+    matrix of the lanes' heads, one product, which adds them up."""
+    heads = rows.shape[0]
+    pieces = jnp.concatenate([p.astype(F32) for p in _pieces(rows)])
+    return _mm(pieces.astype(jnp.bfloat16), _of_head(3 * heads, heads, width),
+               0, 0)
+
+
+def _head_sums(x, width: int):
+    """x [R, heads * width] float32 summed over each head's lanes, as rows
+    [heads, R]: the 0/1 matrix of the lanes' heads on x's pieces."""
+    heads = x.shape[1] // width
+    of_head = _of_head(heads, heads, width)
+    return sum(_mm(of_head, piece, 1, 1) for piece in _pieces(x))
+
+
 def _ssd_bwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref, etot_ref,
-                    entry_ref, dy_ref, du_ref, db_ref, dc_ref, dcols_ref,
-                    drows_ref, scores_scr, dscores_scr, dstate_scr,
-                    scaled_scr, dys_scr, *, heads: int, width: int,
+                    entry_ref, dy_ref, du_ref, db_ref, dc_ref, drows_ref,
+                    dd_ref, scores_scr, dscores_scr, dstate_scr, dt_u_scr,
+                    du_scr, y_scr, *, heads: int, width: int,
                     per_group: int):
     """The forward's grid step with the chunks in reverse (the index maps
     turn them round): the cotangent of the block's exit state is carried in
     ``dstate_scr``. db/dc [L, N] float32, the group's, are summed over its
-    ``per_group`` head blocks in place; dcols [3, L, heads] are the
-    cotangents of dt, cum and D (the last to be summed over L) found as
-    columns, drows [2, heads, L] those of dt and cum found as rows."""
+    ``per_group`` head blocks in place; drows [2, heads, L] are the
+    cotangents of dt and cum, dd [1, heads * width] what each lane adds to
+    that of D.
+
+    A head's own work is its [L, L] decay and three products with it: dt
+    rides ``dt u`` [L, width], so the cotangent of ``scores * decay`` is ``dY
+    (dt u)^T``, that of ``dt u`` its transpose on dY, and the forward's
+    product made again says what cum is owed as y's exponent. Everything
+    else runs once over the block's lanes, before and after that loop: what
+    dt and cum are owed are sums over a head's lanes of products of those
+    [L, width] results."""
     ti, hi = pl.program_id(1), pl.program_id(2)
     chunk = u_ref.shape[0]
     dtype = u_ref.dtype
@@ -216,55 +280,57 @@ def _ssd_bwd_kernel(u_ref, b_ref, c_ref, cols_ref, rows_ref, d_ref, etot_ref,
         db_ref[...] = jnp.zeros(db_ref.shape, F32)
         dc_ref[...] = jnp.zeros(dc_ref.shape, F32)
 
+    dt = _by_lane(rows_ref[0], width)
+    dt_u_scr[...] = (u_ref[...].astype(F32) * dt).astype(dtype)
+    causal = _causal(chunk)
+    tiles = [slice(first, first + 128) for first in range(0, chunk, 128)]
+    for h in range(heads):
+        lanes = slice(h * width, (h + 1) * width)
+        decayed = []
+        # 128 positions t at a time, against the positions s up to theirs:
+        # the tiles above the diagonal are zeros nobody makes.
+        for t in tiles:
+            upto = slice(0, t.stop)
+            dy, dt_u = dy_ref[t, lanes], dt_u_scr[upto, lanes]
+            decay = jnp.exp(jnp.where(
+                causal[t, upto],
+                cols_ref[1, t, h:h + 1] - rows_ref[1, h:h + 1, upto], _NEG))
+            decayed.append((scores_scr[t, upto] * decay).astype(dtype))
+            dscores_scr[t, upto] += _mm(dy, dt_u, 1, 1) * decay
+            y_scr[t, lanes] = _mm(decayed[-1], dt_u, 1, 0)  # y, of the chunk
+        for k, s in enumerate(tiles):  # d (dt u), in the chunk
+            du_scr[s, lanes] = sum(
+                _mm(decayed[i][:, s], dy_ref[tiles[i], lanes], 0, 0)
+                for i in range(k, len(tiles)))
+
     state, dstate = entry_ref[...], dstate_scr[hi]
     from_state = _mm(c_ref[...], state.astype(dtype), 1, 0)    # [L, W]
     from_dstate = _mm(b_ref[...], dstate.astype(dtype), 1, 0)  # [L, W]
-    state_dots = state * dstate * etot_ref[...]                # [N, W]
-    scores, causal = scores_scr[...], _causal(chunk)
+    u, dy = u_ref[...].astype(F32), dy_ref[...].astype(F32)
+    # The weight of u_s B_s^T in the exit state is w_s dt_s; exp(cum_t) is
+    # the entry state's in y_t.
+    cum_rows = rows_ref[1]
+    w = _by_lane(jnp.exp(cum_rows[:, chunk - 1:] - cum_rows), width)
+    into_y = _by_lane(jnp.exp(cum_rows), width)
+    scaled, dys = (u * (dt * w)).astype(dtype), (dy * into_y).astype(dtype)
+    d_dt_u = du_scr[...] + w * from_dstate
+    du_ref[...] = (dt * d_dt_u + d_ref[...] * dy).astype(du_ref.dtype)
+    dd_ref[...] = (dy * u).sum(0, keepdims=True)
+    dc_ref[...] += _mm(dys, state.astype(dtype), 1, 1)
+    db_ref[...] += _mm(scaled, dstate.astype(dtype), 1, 1)
+    dstate_scr[hi] = etot_ref[...] * dstate + _mm(c_ref[...], dys, 0, 0)
+    # cum: y_t's exponent, less what position s lent every later t and the
+    # exit state, and at the last position the exponent of the whole exit
+    # state. What one position is owed another owes: the operands as the
+    # products above rounded them, so that the two cancel to float32's bits.
+    lent = scaled.astype(F32) * from_dstate
+    owed = (dy * (y_scr[...] + into_y * from_state)
+            - dt_u_scr[...].astype(F32) * du_scr[...] - lent)
+    dtotal = (lent.sum(0, keepdims=True)
+              + (etot_ref[...] * state * dstate).sum(0, keepdims=True))
     last = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
-    for h in range(heads):
-        lanes = slice(h * width, (h + 1) * width)
-        u, dy = u_ref[:, lanes], dy_ref[:, lanes]
-        u32, dy32 = u.astype(F32), dy.astype(F32)
-        dt_col, cum_col = cols_ref[0, :, h:h + 1], cols_ref[1, :, h:h + 1]
-        dt_row, cum_row = rows_ref[0, h:h + 1, :], rows_ref[1, h:h + 1, :]
-        decay = jnp.exp(jnp.where(causal, cum_col - cum_row, _NEG))
-        decayed = scores * decay
-        mixed = (decayed * dt_row).astype(dtype)
-        dmixed = _mm(dy, u, 1, 1)                               # dY u^T
-        dscores_scr[...] += dmixed * decay * dt_row
-        by_dt = dmixed * decayed                 # d mixed / d dt_s, per pair
-        by_cum = by_dt * dt_row                  # d mixed / d cum_t, -d cum_s
-        ddt_row = by_dt.sum(0, keepdims=True)
-        dcum_row = -by_cum.sum(0, keepdims=True)
-        dcum_col = by_cum.sum(1, keepdims=True)
-        # y's part from the entry state: exp(cum) (C state).
-        dys = dy32 * jnp.exp(cum_col)
-        dcum_col += (dys * from_state[:, lanes]).sum(1, keepdims=True)
-        # The exit state's part: weights w_s dt_s on u_s B_s^T.
-        w = jnp.exp(cols_ref[2, :, h:h + 1])
-        v = from_dstate[:, lanes]
-        du = _mm(mixed, dy, 0, 0) + (dt_col * w) * v \
-            + d_ref[:, lanes] * dy32
-        du_ref[:, lanes] = du.astype(du_ref.dtype)
-        by_weight = (v * u32).sum(1, keepdims=True)
-        ddt_col = by_weight * w
-        by_w = by_weight * dt_col * w
-        dtotal = by_w.sum(0, keepdims=True) + state_dots[:, lanes].sum(
-            1, keepdims=True).sum(0, keepdims=True)
-        dcum_col += jnp.where(last, dtotal, 0.0) - by_w
-        dcols_ref[0, :, h:h + 1] = ddt_col
-        dcols_ref[1, :, h:h + 1] = dcum_col
-        dcols_ref[2, :, h:h + 1] = (dy32 * u32).sum(1, keepdims=True)
-        drows_ref[0, h:h + 1, :] = ddt_row
-        drows_ref[1, h:h + 1, :] = dcum_row
-        dys_scr[:, lanes] = dys.astype(dtype)
-        scaled_scr[:, lanes] = (u32 * (dt_col * w)).astype(dtype)
-    # Products over all the block's lanes sum over its heads.
-    dys_all = dys_scr[...]
-    dc_ref[...] += _mm(dys_all, state.astype(dtype), 1, 1)
-    db_ref[...] += _mm(scaled_scr[...], dstate.astype(dtype), 1, 1)
-    dstate_scr[hi] = etot_ref[...] * dstate + _mm(c_ref[...], dys_all, 0, 0)
+    drows_ref[0] = _head_sums(u * d_dt_u, width)
+    drows_ref[1] = _head_sums(owed + jnp.where(last, dtotal, 0.0), width)
 
     @pl.when(hi % per_group == per_group - 1)
     def _():
@@ -373,39 +439,37 @@ def _backward(u, dt_c, cum, B, C, D, entry, dy, block: int):
     lanes, blocks = block * width, heads // block
     per_group = blocks // groups
     spec = _specs(chunk, lanes, state, block, n, per_group, reverse=True)
-    small = (batch, n, blocks)
     flat = jax.ShapeDtypeStruct(B.shape[:2] + (groups * state,), F32)
-    du, dB, dC, dcols, drows = pl.pallas_call(
+    du, dB, dC, drows, dD = pl.pallas_call(
         functools.partial(_ssd_bwd_kernel, heads=block, width=width,
                           per_group=per_group),
         grid=(batch, n, blocks),
         in_specs=[spec["wide"], spec["bc"], spec["bc"], spec["cols"](3),
                   spec["rows"](2), spec["d"], spec["etot"], spec["state"],
                   spec["wide"]],
-        out_specs=[spec["wide"], spec["bc"], spec["bc"], spec["cols"](3),
-                   spec["rows"](2)],
+        out_specs=[spec["wide"], spec["bc"], spec["bc"], spec["rows"](2),
+                   spec["etot"]],
         out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype), flat, flat,
-                   jax.ShapeDtypeStruct(small + (3, chunk, block), F32),
-                   jax.ShapeDtypeStruct(small + (2, block, chunk), F32)],
+                   jax.ShapeDtypeStruct((batch, n, blocks, 2, block, chunk),
+                                        F32),
+                   jax.ShapeDtypeStruct((batch, n, blocks, 1, lanes), F32)],
         scratch_shapes=[pltpu.VMEM((chunk, chunk), F32),
                         pltpu.VMEM((chunk, chunk), F32),
                         pltpu.VMEM((blocks, state, lanes), F32),
                         pltpu.VMEM((chunk, lanes), u.dtype),
-                        pltpu.VMEM((chunk, lanes), u.dtype)],
+                        pltpu.VMEM((chunk, lanes), F32),
+                        pltpu.VMEM((chunk, lanes), F32)],
         compiler_params=pltpu.CompilerParams(**_PARAMS),
         interpret=_interpret(),
         name="ssd_bwd",
     )(u, _flat_groups(B), _flat_groups(C),
       *_layouts(dt_c, cum, D, block, width), entry, dy)
-    # [batch, chunks, blocks, k, L, block] -> k x [batch, chunks, L, H]
-    from_cols = dcols.transpose(3, 0, 1, 4, 2, 5).reshape(
-        3, batch, n, chunk, heads)
-    from_rows = drows.transpose(3, 0, 1, 5, 2, 4).reshape(
+    # [batch, chunks, blocks, k, block, L] -> k x [batch, chunks, L, H]
+    ddt, dcum = drows.transpose(3, 0, 1, 5, 2, 4).reshape(
         2, batch, n, chunk, heads)
-    ddt, dcum = from_cols[0] + from_rows[0], from_cols[1] + from_rows[1]
     return (du, ddt, dcum, dB.reshape(B.shape).astype(B.dtype),
             dC.reshape(C.shape).astype(C.dtype),
-            from_cols[2].sum((0, 1, 2)).astype(D.dtype))
+            dD.sum((0, 1)).reshape(heads, width).sum(1).astype(D.dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))

@@ -798,12 +798,14 @@ def test_the_lfm2_cells_compiled_step_gathers_no_slab_of_tokens(topo):
 #: scalars permuted by a sort, a way back to tokens whose backward reads
 #: the sorted rows; c02822046105 and 96a2c501bc69 before it), PR 61 both
 #: again (``route`` picks its scores by a sum over E, so its transpose is a
-#: sum and no scatter; 88ae7b5365ee and ebb20e464562 before it).
+#: sum and no scatter; 88ae7b5365ee and ebb20e464562 before it), PR 63
+#: granite's (``ssd_bwd``'s head loop on operands a head wide; 9f52f929b5af
+#: before it).
 LOWERED_STEPS = {
     "gptj-6b-1chip.steady": "b470aa16aac6",
     "gptj-6b-4chip.steady": "42d82d54bed3",
     "moonlight-16b-a3b-1chip.steady": "7306fc08c9c0",
-    "granite-4.0-h-micro-1chip.steady": "9f52f929b5af",
+    "granite-4.0-h-micro-1chip.steady": "a72eac94c094",
     "phi-4-mini-flash-reasoning-1chip.steady": "b8326d36469b",
     "mellum2-12b-a2.5b-1chip.steady": "b0cda0859e19",
 }

@@ -3,9 +3,11 @@ CPU) against the chunked ``jax.numpy`` form and against the literal
 recurrence, forward and every cotangent, at lengths that are and are not a
 multiple of the chunk.
 
-float32 throughout: the three compute the same sums in another order, over a
+float32 there: the three compute the same sums in another order, over a
 few hundred terms, so 1e-5 of each array's largest entry is reassociation
-and nothing else. B and C with a group axis (2 and 8 groups of the 16 heads,
+and nothing else. At the bottom the cells' dtype (bfloat16 u, B, C at chunks
+of 256) against the chunked form in float32, and what the backward kernel's
+program holds. B and C with a group axis (2 and 8 groups of the 16 heads,
 and 2 of 32, so that a group is one head block and two) go the same way,
 the literal recurrence then ``reference_nemotron_h``'s; one group given
 with and without its axis is the same program to the bit.
@@ -112,9 +114,9 @@ GROUPED = {"2 groups": (2, HEADS), "8 groups": (8, HEADS),
            "2 groups, 2 blocks a group": (2, 2 * HEADS)}
 
 
-def _grouped_data(groups, heads, seq=2 * CHUNK):
+def _grouped_data(groups, heads, seq=2 * CHUNK, width=WIDTH):
     ks = jax.random.split(jax.random.PRNGKey(groups + heads), 7)
-    u = jax.random.normal(ks[0], (1, seq, heads, WIDTH))
+    u = jax.random.normal(ks[0], (1, seq, heads, width))
     dt = jax.nn.softplus(jax.random.normal(ks[1], (1, seq, heads)) - 2.0)
     A = -jnp.exp(0.5 * jax.random.normal(ks[2], (heads,)))
     B = 0.3 * jax.random.normal(ks[3], (1, seq, groups, STATE))
@@ -176,3 +178,77 @@ def test_one_group_with_and_without_its_axis_is_one_program(results):
                      (u, dt, A, B[:, :, None], C[:, :, None], D), g)
     for got, want in zip(with_axis, results[3 * CHUNK]["ssd"]):
         assert (got.reshape(want.shape) == want).all()
+
+
+# -- the cells' dtype ---------------------------------------------------------
+
+BF16 = {"8 heads of 64, one group": (8, 64, 1),
+        "64 heads of 64, eight groups": (64, 64, 8),
+        "2 heads of 128": (2, 128, 1)}
+
+
+@pytest.fixture(scope="module")
+def bf16():
+    """{case: (kernels on bfloat16 u, B, C; ``ssd_chunked`` in float32 on the
+    same values)}, two chunks of 256."""
+    out = {}
+    for name, (heads, width, groups) in BF16.items():
+        (u, dt, A, B, C, D), g = _grouped_data(groups, heads, 2 * 256, width)
+        low = jnp.bfloat16
+        args = (u.astype(low), dt, A, B.astype(low), C.astype(low), D)
+        assert ssd_mod.heads_per_block(heads, width, groups)
+        out[name] = (
+            _all(lambda *a: ssd_mod.ssd(*a, chunk=256), args, g.astype(low)),
+            _all(lambda *a: ssd_mod.ssd_chunked(*a, chunk=256),
+                 [a.astype(jnp.float32) for a in args],
+                 g.astype(low).astype(jnp.float32)))
+    return out
+
+
+@pytest.mark.parametrize("index", range(len(NAMES)), ids=NAMES)
+@pytest.mark.parametrize("name", BF16)
+def test_bfloat16_ssd_matches_the_float32_form(bf16, name, index):
+    """The kernels round ``C B^T`` times the decay, ``dt u`` and each
+    cotangent's operands to bfloat16 (8 bits) before products that
+    accumulate in float32: 2 ** -6 of each array's largest entry holds that
+    rounding (0.2-0.9 % read) and no wrong term. dA is the one to watch: cum
+    is owed a difference of two sums, and made from operands rounded in two
+    ways they read 3-14 % off."""
+    got, want = (side[index] for side in bf16[name])
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got.astype(jnp.float32), want, rtol=0,
+                               atol=2.0 ** -6 * float(jnp.abs(want).max()))
+
+
+def test_the_backward_kernel_sums_no_square():
+    """Every cotangent of dt and cum is a sum over a head's lanes of
+    products a head wide: the kernel's program makes the decay ([128, L]
+    tiles of it under an ``exp``) and sums no array of that shape, and it
+    returns du, dB, dC, the rows of dt and cum and D's lanes (no columns
+    beside the rows)."""
+    chunk, heads, width = 256, 8, 64
+    shaped = jax.ShapeDtypeStruct
+    by_chunk = shaped((1, 2, chunk, heads), jnp.float32)
+    wide = shaped((1, 2 * chunk, heads * width), jnp.bfloat16)
+    grouped = shaped((1, 2 * chunk, 1, STATE), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda *a: ssd_mod._backward(*a, heads))(
+        wide, by_chunk, by_chunk, grouped, grouped,
+        shaped((heads,), jnp.float32),
+        shaped((1, 2, 1, STATE, heads * width), jnp.float32), wide)
+
+    def equations(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+    calls = [e for e in equations(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1 and len(calls[0].outvars) == 5
+    inside = list(equations(calls[0].params["jaxpr"]))
+    of_the_decay = {e.invars[0].aval.shape for e in inside
+                    if e.primitive.name == "exp"}
+    assert (128, chunk) in of_the_decay
+    summed = {e.invars[0].aval.shape for e in inside
+              if e.primitive.name == "reduce_sum"}
+    assert summed and not summed & (of_the_decay | {(chunk, chunk)})
