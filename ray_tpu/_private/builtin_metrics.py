@@ -932,6 +932,19 @@ def train_attn_window_tile_fill() -> Gauge:
         "tiles of 512, 0.800 at tiles of 256.")
 
 
+def train_attn_gate_mean() -> Gauge:
+    from ray_tpu.util.metrics import Gauge
+    return Gauge(
+        "ray_tpu_train_attn_gate_mean",
+        "Mean over heads, tokens and layers of the sigmoid gate a head on "
+        "the attention's output in the last recorded step, a kind of layer "
+        "(models/dots3_note.py: 'full', latent attention over a learned "
+        "selection; 'window', latent attention in a sliding window). About "
+        "0.5 at random weights; stuck at exactly 0.5 or at 1 the gate is "
+        "dropped or dead.",
+        tag_keys=("kind",))
+
+
 # -- delta-rule layers -----------------------------------------------------
 # Fed as the expert layers' scalars are (models/kimi_linear.py).
 

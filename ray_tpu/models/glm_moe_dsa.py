@@ -42,10 +42,12 @@ The multi-token prediction module (``num_nextn_predict_layers``) is not
 implemented: ``lm.Decoder`` has one head.
 
 This module is the family's config, its table of leaves and its block; the
-latent attention's projections (``lm.mla_qkv``), the expert FFN
+latent attention's projections (``lm.mla_qkv``), the indexer, the attention
+over its selection and the indexers' loss (``lm.index_scores`` and what
+stands beside it, shared with ``models/dots3_note.py``), the expert FFN
 (``lm.expert_ffn``), the lookup, the layer scan, the head and the loss are
-``models/lm.py``'s, the indexer's scores, the selection, the attention over
-it and the head-summed probabilities ``ops/dsa.py``'s. A layer's kind is its
+``models/lm.py``'s, the scores' kernel, the selection, the attention over it
+and the head-summed probabilities ``ops/dsa.py``'s. A layer's kind is its
 FFN and its indexer's type together (``dense_full``, ``moe_shared``,
 ``moe_full``); every run of one kind is one stack of parameters and one
 scan, and a "full" run hands its last layer's selection on to the runs
@@ -70,7 +72,6 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu._private import builtin_metrics
 from ray_tpu.models import lm
 from ray_tpu.ops import dsa
 
@@ -202,21 +203,12 @@ def _shapes(cfg: GlmMoeDsaConfig):
     (``lm.Decoder``); a layer holds the attention's leaves, its indexer's
     where it owns one, and its FFN's. Matrices normal(0, 0.02), norm scales
     of one, the LayerNorm's bias and the correction bias zero."""
-    d, std = cfg.hidden_size, 0.02
-    heads, width = cfg.index_n_heads, cfg.index_head_dim
+    d = cfg.hidden_size
     attn = {"ln1_scale": ((d,), ("embed",), lm.ones),
             **lm.mla_leaves(cfg),
             "ln2_scale": ((d,), ("embed",), lm.ones)}
-    indexer = {
-        "w_iq": ((cfg.q_lora_rank, heads, width), (None, "heads", "head_dim"),
-                 std),
-        "w_ik": ((d, width), ("embed", None), std),
-        "ik_norm_scale": ((width,), (None,), lm.ones),
-        "ik_norm_bias": ((width,), (None,), lm.zeros),
-        "w_iw": ((d, heads), ("embed", None), std),
-    }
     f = cfg.moe_intermediate_size
-    return {"attn": attn, "full": indexer, "shared": {},
+    return {"attn": attn, "full": lm.indexer_leaves(cfg), "shared": {},
             "dense": lm.swiglu_leaves(d, cfg.intermediate_size),
             "moe": lm.expert_leaves(
                 d, cfg.n_routed_experts, cfg.experts_held, f,
@@ -230,56 +222,9 @@ def _leaves_of(shapes, kind: str):
 
 # -- forward ------------------------------------------------------------
 
-def _partly_rotated(x, positions, cfg: GlmMoeDsaConfig):
-    """x [B, S, H, E] with its first ``qk_rope_head_dim`` dimensions
-    rotated (pairs (2i, 2i+1)), the rest as they are."""
-    r = cfg.qk_rope_head_dim
-    return jnp.concatenate([lm.rope_interleaved(
-        x[..., :r], positions, cfg.rope_theta), x[..., r:]], -1)
-
-
-def _index_scores(cfg: GlmMoeDsaConfig, x, c_q, layer, positions):
-    """The indexer's scores I [B, S, S] float32 of normed x [B, S, d] and
-    the normed low-rank query c_q [B, S, q_lora_rank], neither of which its
-    gradient reaches."""
-    dt, f32 = cfg.dtype, jnp.float32
-    x, c_q = jax.lax.stop_gradient(x), jax.lax.stop_gradient(c_q)
-    q = jnp.einsum("bsr,rje->bsje", c_q, layer["w_iq"].astype(dt))
-    k = lm.layernorm(jnp.einsum("bsd,de->bse", x, layer["w_ik"].astype(dt)),
-                     layer["ik_norm_scale"], layer["ik_norm_bias"],
-                     cfg.index_norm_eps)
-    w = jnp.einsum("bsd,dj->bsj", x, layer["w_iw"].astype(dt)).astype(f32) \
-        * (cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5)
-    q = _partly_rotated(q, positions, cfg)
-    k = _partly_rotated(k[:, :, None], positions, cfg)[:, :, 0]
-    return dsa.index_scores(q, k, w)
-
-
-def _attend(cfg: GlmMoeDsaConfig, q, k, v, selection):
-    """(out, lse) of the main attention over the selection, by
-    ``cfg.attn_impl``."""
-    if cfg.attn_impl == "dot":
-        return dsa.dot_selected_attention(q, k, v, selection)
-    if cfg.attn_impl != "flash":
-        raise NotImplementedError(
-            f"attn_impl={cfg.attn_impl!r}: attention over a selection runs "
-            "as 'dot' or 'flash' (ops/dsa.py)")
-    from ray_tpu.parallel.mesh import current_mesh
-    mesh = current_mesh()
-    if mesh is not None and mesh.size > 1:
-        raise NotImplementedError(
-            "models/glm_moe_dsa.py runs the selection's kernels on one "
-            f"device; the mesh has {mesh.size}")
-    return dsa.selected_attention(q, k, v, selection, cfg.attn_blk_q,
-                                  cfg.attn_blk_k, None)
-
-
-def _head_probs(cfg: GlmMoeDsaConfig, q, k, lse, selection):
-    q, k, lse = (jax.lax.stop_gradient(a) for a in (q, k, lse))
-    if cfg.attn_impl == "dot":
-        return dsa.dot_head_probs(q, k, lse, selection)
-    return dsa.head_probs(q, k, lse, selection, cfg.attn_blk_q,
-                          cfg.attn_blk_k)
+#: What rotates the indexer's q and k: a name of this module, so that a test
+#: or a planted fault can put another in its place.
+_partly_rotated = lm.partly_rotated
 
 
 def _block(cfg: GlmMoeDsaConfig, kind: str, h, layer, positions, shared):
@@ -295,7 +240,8 @@ def _block(cfg: GlmMoeDsaConfig, kind: str, h, layer, positions, shared):
         q, k, v, c_q = lm.mla_qkv(cfg, x, layer, positions)
     if indexer == "full":
         with jax.named_scope("dsa_index"):
-            scores = _index_scores(cfg, x, c_q, layer, positions)
+            scores = lm.index_scores(cfg, x, c_q, layer, positions,
+                                    _partly_rotated)
         with jax.named_scope("dsa_select"):
             selection = checkpoint_name(
                 dsa.select(jax.lax.stop_gradient(scores), cfg.index_topk),
@@ -303,12 +249,12 @@ def _block(cfg: GlmMoeDsaConfig, kind: str, h, layer, positions, shared):
     else:
         selection = shared[SELECTION]
     with jax.named_scope("mla"):
-        attn, lse = _attend(cfg, q, k, v, selection)
+        attn, lse = lm.selected_attention(cfg, q, k, v, selection)
         h = h + jnp.einsum("bshk,hkd->bsd", attn,
                            layer["wo"].astype(cfg.dtype))
     if indexer == "full":
         with jax.named_scope("dsa_probs"):
-            probs = _head_probs(cfg, q, k, lse, selection)
+            probs = lm.selection_probs(cfg, q, k, lse, selection)
             aux = {"index_loss": dsa.index_loss(scores, probs, selection),
                    "selected": selection.astype(jnp.float32).sum(),
                    lm.HANDED_ON: {SELECTION: selection}}
@@ -324,33 +270,16 @@ def _block(cfg: GlmMoeDsaConfig, kind: str, h, layer, positions, shared):
     return h + routed + shared_expert, dict(aux, **moe)
 
 
-def _index_loss(cfg: GlmMoeDsaConfig, aux, mask):
-    """``indexer_loss_coef * L_I``: every indexer's KL, a mean over the
-    rows of the sequences of which ``mask`` keeps a token (all, if None)."""
-    per_row = aux["index_loss"].sum(0)  # [B], over the layers with one
-    if mask is None:
-        return cfg.indexer_loss_coef * per_row.mean()
-    rows = (mask.astype(jnp.float32).sum(-1) > 0).astype(jnp.float32)
-    return cfg.indexer_loss_coef * (per_row * rows).sum() \
-        / jnp.maximum(rows.sum(), 1.0)
-
-
 def _metrics(cfg: GlmMoeDsaConfig, aux, targets):
-    """``dsa_selected_share`` (pairs the selections kept over the causal
-    pairs), ``dsa_index_loss`` (``L_I`` over the whole batch) and
-    ``lm.moe_metrics``."""
-    B, S = targets.shape
-    owners = aux["selected"].shape[0]
-    return {"dsa_selected_share":
-            aux["selected"].sum() / (owners * B * (S * (S + 1) // 2)),
-            "dsa_index_loss": aux["index_loss"].sum(0).mean(),
+    """``lm.selection_metrics`` and ``lm.moe_metrics``."""
+    return {**lm.selection_metrics(aux, targets),
             **lm.moe_metrics(aux, targets.size * cfg.num_experts_per_tok)}
 
 
 _SHELL = lm.Decoder(
     name="glm_moe_dsa", shapes=_shapes, leaves_of=_leaves_of,
     block=lambda *args, **kwargs: _block(*args, **kwargs), shares=True,
-    experts=True, metrics=_metrics, extra_loss=_index_loss)
+    experts=True, metrics=_metrics, extra_loss=lm.index_loss)
 
 #: ``hidden_states``' aux is ``index_loss`` [indexers, B] and ``selected``
 #: [indexers] and the expert layers' ``picked`` [L_moe, B, S, K],
@@ -361,9 +290,4 @@ hidden_states, head = _SHELL.hidden_states, _SHELL.head
 forward, forward_with_aux = _SHELL.forward, _SHELL.forward_with_aux
 loss_of_hidden, loss_fn = _SHELL.loss_of_hidden, _SHELL.loss_fn
 SUMMED_METRICS = lm.SUMMED_METRICS
-RECORDED_METRICS = dict(
-    lm.RECORDED_METRICS,
-    dsa_selected_share=lambda value:
-        builtin_metrics.train_dsa_selected_share().set(value),
-    dsa_index_loss=lambda value:
-        builtin_metrics.train_dsa_index_loss().set(value))
+RECORDED_METRICS = {**lm.RECORDED_METRICS, **lm.SELECTION_RECORDED}

@@ -13,8 +13,8 @@ second family needs of a first moves here.
 
 **A family** (``models/deepseek.py``, ``granite.py``, ``afmoe.py``,
 ``kimi_linear.py``, ``lfm2.py``, ``phi4flash.py``, ``glm_moe_dsa.py``,
-``evabyte.py``, ``minicpm_sala.py``, ``mellum.py``) is three things, written
-against this module:
+``evabyte.py``, ``minicpm_sala.py``, ``mellum.py``, ``nemotron_h.py``,
+``dots3_note.py``) is three things, written against this module:
 
 * its config, a frozen dataclass under the published keys, with
   ``vocab_size``, ``hidden_size``, the epsilon of its norms, the program's
@@ -352,6 +352,34 @@ def attention(q, k, v, cfg, scale: Optional[float] = None,
                 "axis (parallel.mesh.set_current_mesh)")
         return make_ulysses_attention(mesh)(q, k, v)
     raise ValueError(f"Unknown attn_impl {cfg.attn_impl!r}")
+
+
+def window_tile_fill(cfg, window: int, seq_len: int) -> Optional[float]:
+    """Of the (query, key) pairs in the tiles the flash kernels execute for
+    a layer under ``window`` (``window_tile_census`` of the pair table the
+    step is built with), the share the mask keeps; None where such a layer
+    does not run the kernels on tiles (``dot``, a sequence that is one tile
+    or less, or one the window does not cut)."""
+    from ray_tpu.ops.flash_attention import window_tile_census
+    S = seq_len
+    blk_q, blk_k = min(cfg.attn_blk_q, S), min(cfg.attn_blk_k, S)
+    if cfg.attn_impl != "flash" or window >= S or S % blk_q or S % blk_k:
+        return None
+    kept = window * (window + 1) // 2 + (S - window) * window
+    executed = window_tile_census(S, window, blk_q, blk_k)["executed"]
+    return kept / (executed * blk_q * blk_k)
+
+
+def unless_nan(record):
+    """``record`` (what feeds a gauge) for a metric that reads not a number
+    where it does not apply (no layer of the kind runs): nothing is
+    recorded then."""
+    return lambda value: record(value) if value == value else None
+
+
+#: A model's ``RECORDED_METRICS`` entry for ``attn_window_tile_fill``.
+record_window_tile_fill = unless_nan(
+    lambda value: builtin_metrics.train_attn_window_tile_fill().set(value))
 
 
 def eva_attention(q, k, v, phi, mu, cfg, window: int, chunk: int):
@@ -698,47 +726,63 @@ def mlp(x, w_up, w_down, activation: str):
                       w_down.astype(dt))
 
 
-def mla_leaves(cfg):
-    """The leaves ``mla`` reads. With ``cfg.q_lora_rank`` the query is of
-    low rank (``w_q_a``, ``q_norm_scale``, ``w_q_b``); null, or a config
-    without the key, one matrix ``wq``."""
-    d, h, rank = cfg.hidden_size, cfg.num_attention_heads, cfg.kv_lora_rank
-    q_rank = getattr(cfg, "q_lora_rank", None)
+def mla_leaves(latent):
+    """The leaves ``mla`` reads, of ``latent``: a family's config, or any
+    view of one that carries a latent attention's geometry under the
+    published plain names (``hidden_size``, ``num_attention_heads``,
+    ``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``,
+    ``qk_rope_head_dim``, ``v_head_dim``; a model with two geometries hands
+    in one view a kind of layer, ``models/dots3_note.py``). With
+    ``q_lora_rank`` the query is of low rank (``w_q_a``, ``q_norm_scale``,
+    ``w_q_b``); null, or a config without the key, one matrix ``wq``."""
+    d, h, rank = latent.hidden_size, latent.num_attention_heads, \
+        latent.kv_lora_rank
+    q_rank = getattr(latent, "q_lora_rank", None)
     heads = ("heads", "head_dim")
-    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    qk = latent.qk_nope_head_dim + latent.qk_rope_head_dim
     queries = {"wq": ((d, h, qk), ("embed",) + heads, 0.02)} if not q_rank \
         else {"w_q_a": ((d, q_rank), ("embed", None), 0.02),
               "q_norm_scale": ((q_rank,), (None,), ones),
               "w_q_b": ((q_rank, h, qk), (None,) + heads, 0.02)}
     return {
         **queries,
-        "w_kv_a": ((d, rank + cfg.qk_rope_head_dim), ("embed", None), 0.02),
+        "w_kv_a": ((d, rank + latent.qk_rope_head_dim), ("embed", None),
+                   0.02),
         "kv_norm_scale": ((rank,), (None,), ones),
-        "w_kv_b": ((rank, h, cfg.qk_nope_head_dim + cfg.v_head_dim),
+        "w_kv_b": ((rank, h, latent.qk_nope_head_dim + latent.v_head_dim),
                    (None,) + heads, 0.02),
-        "wo": ((h, cfg.v_head_dim, d), heads + ("embed",), 0.02),
+        "wo": ((h, latent.v_head_dim, d), heads + ("embed",), 0.02),
     }
 
 
-def mla_qkv(cfg, x, layer, positions):
+def mla_qkv(latent, x, layer, positions):
     """``mla``'s q, k [B, S, H, nope + rope], v [B, S, H, v_head] and the
     normed low-rank query ``c_q`` [B, S, q_lora_rank] (None where the rank
-    is null) of normed x [B, S, d]."""
-    dt = cfg.dtype
-    nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    is null) of normed x [B, S, d]. ``latent`` is what ``mla_leaves`` takes,
+    with ``rope_theta``, ``rms_norm_eps``, ``dtype`` and ``mla_use_nope``;
+    where it has a ``q_lora_scale`` / ``kv_lora_scale`` that is not None,
+    the normed latent is multiplied by it before its second matrix (the
+    ``c_q`` handed back is the unscaled one)."""
+    dt = latent.dtype
+    nope, rank = latent.qk_nope_head_dim, latent.kv_lora_rank
+    q_scale = getattr(latent, "q_lora_scale", None)
+    kv_scale = getattr(latent, "kv_lora_scale", None)
     c_q = None
-    if getattr(cfg, "q_lora_rank", None):
+    if getattr(latent, "q_lora_rank", None):
         c_q = rmsnorm(jnp.einsum("bsd,dr->bsr", x, layer["w_q_a"].astype(dt)),
-                      layer["q_norm_scale"], cfg.rms_norm_eps)
-        q = jnp.einsum("bsr,rhk->bshk", c_q, layer["w_q_b"].astype(dt))
+                      layer["q_norm_scale"], latent.rms_norm_eps)
+        scaled = c_q if q_scale is None else c_q * jnp.asarray(q_scale, dt)
+        q = jnp.einsum("bsr,rhk->bshk", scaled, layer["w_q_b"].astype(dt))
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, layer["wq"].astype(dt))
     kv_a = jnp.einsum("bsd,dr->bsr", x, layer["w_kv_a"].astype(dt))
-    latent = rmsnorm(kv_a[..., :rank], layer["kv_norm_scale"],
-                     cfg.rms_norm_eps)
-    kv = jnp.einsum("bsr,rhk->bshk", latent, layer["w_kv_b"].astype(dt))
-    rotated = (lambda x: x) if cfg.mla_use_nope else partial(
-        rope_interleaved, positions=positions, theta=cfg.rope_theta)
+    c = rmsnorm(kv_a[..., :rank], layer["kv_norm_scale"],
+                latent.rms_norm_eps)
+    if kv_scale is not None:
+        c = c * jnp.asarray(kv_scale, dt)
+    kv = jnp.einsum("bsr,rhk->bshk", c, layer["w_kv_b"].astype(dt))
+    rotated = (lambda x: x) if latent.mla_use_nope else partial(
+        rope_interleaved, positions=positions, theta=latent.rope_theta)
     q_rope = rotated(q[..., nope:])
     k_rope = rotated(kv_a[..., None, rank:])
     q = jnp.concatenate([q[..., :nope], q_rope], -1)
@@ -753,13 +797,131 @@ def mla(cfg, x, layer, positions):
     -> [B, S, d], from a layer's ``mla_leaves``: the query one matrix
     ``wq`` where ``q_lora_rank`` is null, else ``RMSNorm(x W_qa) W_qb``.
     cfg is any config with the latent keys under their published names
-    (``models/deepseek.py``, ``models/kimi_linear.py``,
-    ``models/glm_moe_dsa.py``, which attends over a selection and calls
-    ``mla_qkv`` itself); with ``cfg.mla_use_nope`` the "rope" dimensions of
-    q and of the shared key go unrotated."""
+    (``models/deepseek.py``, ``models/kimi_linear.py``;
+    ``models/glm_moe_dsa.py`` and ``models/dots3_note.py`` attend over a
+    selection, the latter also in a window and behind a gate, and call
+    ``mla_qkv`` themselves); with ``cfg.mla_use_nope`` the "rope"
+    dimensions of q and of the shared key go unrotated."""
     q, k, v, _ = mla_qkv(cfg, x, layer, positions)
     attn = attention(q, k, v, cfg)
     return jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(cfg.dtype))
+
+
+# -- attention over a learned selection (ops/dsa.py) ------------------------
+# What ``models/glm_moe_dsa.py`` and ``models/dots3_note.py`` share: a layer's
+# indexer, the main attention over its selection and the indexers' loss. cfg
+# is either family's config: the ``index_*`` keys, ``q_lora_rank``,
+# ``qk_rope_head_dim`` and ``rope_theta`` (of the layers that own an
+# indexer), ``index_norm_eps``, ``indexer_loss_coef``.
+
+def indexer_leaves(cfg):
+    """The leaves of a layer's indexer (``index_scores``)."""
+    d, std = cfg.hidden_size, 0.02
+    heads, width = cfg.index_n_heads, cfg.index_head_dim
+    return {
+        "w_iq": ((cfg.q_lora_rank, heads, width), (None, "heads", "head_dim"),
+                 std),
+        "w_ik": ((d, width), ("embed", None), std),
+        "ik_norm_scale": ((width,), (None,), ones),
+        "ik_norm_bias": ((width,), (None,), zeros),
+        "w_iw": ((d, heads), ("embed", None), std),
+    }
+
+
+def partly_rotated(x, positions, cfg):
+    """x [B, S, H, E] with its first ``qk_rope_head_dim`` dimensions
+    rotated (pairs (2i, 2i+1)), the rest as they are."""
+    r = cfg.qk_rope_head_dim
+    return jnp.concatenate([rope_interleaved(
+        x[..., :r], positions, cfg.rope_theta), x[..., r:]], -1)
+
+
+def index_scores(cfg, x, c_q, layer, positions, rotated=partly_rotated):
+    """The indexer's scores I [B, S, S] float32 of normed x [B, S, d] and
+    the normed low-rank query c_q [B, S, q_lora_rank], neither of which its
+    gradient reaches; ``rotated`` is what rotates its q and k."""
+    from ray_tpu.ops import dsa
+    dt, f32 = cfg.dtype, jnp.float32
+    x, c_q = jax.lax.stop_gradient(x), jax.lax.stop_gradient(c_q)
+    q = jnp.einsum("bsr,rje->bsje", c_q, layer["w_iq"].astype(dt))
+    k = layernorm(jnp.einsum("bsd,de->bse", x, layer["w_ik"].astype(dt)),
+                  layer["ik_norm_scale"], layer["ik_norm_bias"],
+                  cfg.index_norm_eps)
+    w = jnp.einsum("bsd,dj->bsj", x, layer["w_iw"].astype(dt)).astype(f32) \
+        * (cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5)
+    q = rotated(q, positions, cfg)
+    k = rotated(k[:, :, None], positions, cfg)[:, :, 0]
+    return dsa.index_scores(q, k, w)
+
+
+def selected_attention(cfg, q, k, v, selection):
+    """(out, lse) of the main attention over the selection, by
+    ``cfg.attn_impl``."""
+    from ray_tpu.ops import dsa
+    if cfg.attn_impl == "dot":
+        return dsa.dot_selected_attention(q, k, v, selection)
+    if cfg.attn_impl != "flash":
+        raise NotImplementedError(
+            f"attn_impl={cfg.attn_impl!r}: attention over a selection runs "
+            "as 'dot' or 'flash' (ops/dsa.py)")
+    from ray_tpu.parallel.mesh import current_mesh
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "models/lm.py runs a selection's kernels on one device; the "
+            f"mesh has {mesh.size}")
+    return dsa.selected_attention(q, k, v, selection, cfg.attn_blk_q,
+                                  cfg.attn_blk_k, None)
+
+
+def selection_probs(cfg, q, k, lse, selection):
+    """The main attention's probabilities summed over the heads, on the
+    selection: the indexer's target, which no gradient reaches."""
+    from ray_tpu.ops import dsa
+    q, k, lse = (jax.lax.stop_gradient(a) for a in (q, k, lse))
+    if cfg.attn_impl == "dot":
+        return dsa.dot_head_probs(q, k, lse, selection)
+    return dsa.head_probs(q, k, lse, selection, cfg.attn_blk_q,
+                          cfg.attn_blk_k)
+
+
+def index_loss(cfg, aux, mask):
+    """``indexer_loss_coef * L_I`` (a ``Decoder``'s ``extra_loss``): every
+    indexer's KL (aux ``index_loss`` [indexers, B]), a mean over the rows of
+    the sequences of which ``mask`` keeps a token (all, if None); zero where
+    no layer with an indexer runs."""
+    if "index_loss" not in aux:
+        return jnp.float32(0.0)
+    per_row = aux["index_loss"].sum(0)  # [B], over the layers with one
+    if mask is None:
+        return cfg.indexer_loss_coef * per_row.mean()
+    rows = (mask.astype(jnp.float32).sum(-1) > 0).astype(jnp.float32)
+    return cfg.indexer_loss_coef * (per_row * rows).sum() \
+        / jnp.maximum(rows.sum(), 1.0)
+
+
+def selection_metrics(aux, targets):
+    """``dsa_selected_share`` (pairs the selections kept, aux ``selected``
+    [indexers], over the causal pairs) and ``dsa_index_loss`` (``L_I`` over
+    the whole batch); not numbers where no layer with an indexer runs."""
+    if "selected" not in aux:
+        return dict.fromkeys(("dsa_selected_share", "dsa_index_loss"),
+                             jnp.float32(jnp.nan))
+    B, S = targets.shape
+    owners = aux["selected"].shape[0]
+    return {"dsa_selected_share":
+            aux["selected"].sum() / (owners * B * (S * (S + 1) // 2)),
+            "dsa_index_loss": aux["index_loss"].sum(0).mean()}
+
+
+#: What records ``selection_metrics`` in the registry (a family's
+#: ``RECORDED_METRICS``).
+SELECTION_RECORDED = {
+    "dsa_selected_share": unless_nan(
+        lambda value: builtin_metrics.train_dsa_selected_share().set(value)),
+    "dsa_index_loss": unless_nan(
+        lambda value: builtin_metrics.train_dsa_index_loss().set(value)),
+}
 
 
 # -- expert layers --------------------------------------------------------

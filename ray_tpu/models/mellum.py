@@ -249,20 +249,10 @@ def _block(cfg: MellumConfig, kind: str, h, layer, positions):
 
 
 def window_tile_fill(cfg: MellumConfig, seq_len: int) -> Optional[float]:
-    """Of the (query, key) pairs in the tiles the flash kernels execute for
-    a window layer (``window_tile_census`` of the pair table the step is
-    built with), the share the mask keeps; None where no window layer runs
-    the kernels on tiles (``dot``, a sequence that is one tile or less, or
-    one the window does not cut)."""
-    from ray_tpu.ops.flash_attention import window_tile_census
-    S, window = seq_len, cfg.sliding_window
-    blk_q, blk_k = min(cfg.attn_blk_q, S), min(cfg.attn_blk_k, S)
-    if cfg.attn_impl != "flash" or window >= S or S % blk_q or S % blk_k \
-            or "sliding_attention" not in cfg.layers:
+    """``lm.window_tile_fill`` of the window layers; None where none runs."""
+    if "sliding_attention" not in cfg.layers:
         return None
-    kept = window * (window + 1) // 2 + (S - window) * window
-    executed = window_tile_census(S, window, blk_q, blk_k)["executed"]
-    return kept / (executed * blk_q * blk_k)
+    return lm.window_tile_fill(cfg, cfg.sliding_window, seq_len)
 
 
 def _metrics(cfg: MellumConfig, aux, targets):
@@ -292,14 +282,9 @@ loss_of_hidden, loss_fn = _SHELL.loss_of_hidden, _SHELL.loss_fn
 SUMMED_METRICS = lm.SUMMED_METRICS
 
 
-def _record_tile_fill(value: float) -> None:
-    if value == value:  # not a number: no window layer on tiles
-        builtin_metrics.train_attn_window_tile_fill().set(value)
-
-
 RECORDED_METRICS = {
     **lm.RECORDED_METRICS,
     "moe_picked_mass": lambda value:
         builtin_metrics.train_moe_picked_mass().set(value),
-    "attn_window_tile_fill": _record_tile_fill,
+    "attn_window_tile_fill": lm.record_window_tile_fill,
 }

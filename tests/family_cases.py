@@ -263,7 +263,8 @@ def trained(family, accum_steps):
     rules, optimizer = ShardingRules(), optax.adam(3e-3)
     state = init_train_state(cfg, mesh, rules, optimizer, seed=0)
     if family.train_drawn:
-        state["params"] = drawn(family, cfg)
+        # A copy: the step donates its state, and ``drawn`` is the process's.
+        state["params"] = jax.tree.map(jnp.copy, drawn(family, cfg))
     step = make_train_step(cfg, mesh, rules, optimizer,
                            accum_steps=accum_steps)
     tokens, targets = batch(cfg, family.flash_seq)

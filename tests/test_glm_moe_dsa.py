@@ -125,19 +125,19 @@ def program_selections(cfg, params, tokens):
     """Every layer's selection as the program's forward pass attends over
     it, in layer order: called back from inside the layer scans."""
     seen = []
-    plain = glm_moe_dsa._attend
+    plain = lm.selected_attention
 
     def attend(cfg, q, k, v, selection):
         jax.debug.callback(lambda s: seen.append(np.asarray(s)), selection,
                            ordered=True)
         return plain(cfg, q, k, v, selection)
 
-    glm_moe_dsa._attend = attend
+    lm.selected_attention = attend
     try:
         jax.block_until_ready(jax.jit(lambda p: glm_moe_dsa.hidden_states(
             p, replace(cfg, remat=False), tokens)[0])(params))
     finally:
-        glm_moe_dsa._attend = plain
+        lm.selected_attention = plain
     return seen
 
 

@@ -19,7 +19,9 @@ layers draw ``wq`` where they did: ``lm.mla_leaves`` with a null rank);
 by PR 51, the other eight's as they were; ``mellum``'s by PR 58, the other
 nine's as they were; ``nemotron_h``'s by PR 62, the other ten's as they were
 (granite's and phi's ``A_log`` is drawn by ``lm.log_arange``, the function
-their modules held).
+their modules held); ``dots3_note``'s by PR 64, the other eleven's as they
+were (``lm.mla_leaves`` takes the latent's geometry as a value and draws
+Moonlight's, Kimi Linear's and GLM's leaves where it did).
 
 A PR that changes a family's draw on purpose records them again and says so;
 one that does not must leave this file alone."""
@@ -36,7 +38,7 @@ FAMILIES = {"deepseek": "deepseek-tiny", "granite": "granite-tiny",
             "lfm2": "lfm2-tiny", "phi4flash": "phi4flash-tiny",
             "glm_moe_dsa": "glm-tiny", "evabyte": "evabyte-tiny",
             "minicpm_sala": "minicpm-sala-tiny", "mellum": "mellum-tiny",
-            "nemotron_h": "nemotron-h-tiny"}
+            "nemotron_h": "nemotron-h-tiny", "dots3_note": "dots3-tiny"}
 PINNED = pathlib.Path(__file__).with_name("init_pinned.json")
 
 
