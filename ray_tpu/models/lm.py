@@ -836,10 +836,31 @@ def partly_rotated(x, positions, cfg):
         x[..., :r], positions, cfg.rope_theta), x[..., r:]], -1)
 
 
+def _selection_kernels(cfg):
+    """Whether what attends over a selection runs ``ops/dsa.py``'s kernels
+    (``cfg.attn_impl`` ``"flash"``) or their ``jax.numpy`` forms
+    (``"dot"``); the kernels run whole sequences of one device."""
+    if cfg.attn_impl == "dot":
+        return False
+    if cfg.attn_impl != "flash":
+        raise NotImplementedError(
+            f"attn_impl={cfg.attn_impl!r}: attention over a selection runs "
+            "as 'dot' or 'flash' (ops/dsa.py)")
+    from ray_tpu.parallel.mesh import current_mesh
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "models/lm.py runs a selection's kernels on one device; the "
+            f"mesh has {mesh.size}")
+    return True
+
+
 def index_scores(cfg, x, c_q, layer, positions, rotated=partly_rotated):
     """The indexer's scores I [B, S, S] float32 of normed x [B, S, d] and
     the normed low-rank query c_q [B, S, q_lora_rank], neither of which its
-    gradient reaches; ``rotated`` is what rotates its q and k."""
+    gradient reaches; ``rotated`` is what rotates its q and k. By
+    ``cfg.attn_impl``, ``ops/dsa.py``'s kernels (``index_scores``) or the
+    sum written out (``dot_index_scores``)."""
     from ray_tpu.ops import dsa
     dt, f32 = cfg.dtype, jnp.float32
     x, c_q = jax.lax.stop_gradient(x), jax.lax.stop_gradient(c_q)
@@ -851,6 +872,8 @@ def index_scores(cfg, x, c_q, layer, positions, rotated=partly_rotated):
         * (cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5)
     q = rotated(q, positions, cfg)
     k = rotated(k[:, :, None], positions, cfg)[:, :, 0]
+    if not _selection_kernels(cfg):
+        return dsa.dot_index_scores(q, k, w)
     return dsa.index_scores(q, k, w)
 
 
@@ -858,18 +881,8 @@ def selected_attention(cfg, q, k, v, selection):
     """(out, lse) of the main attention over the selection, by
     ``cfg.attn_impl``."""
     from ray_tpu.ops import dsa
-    if cfg.attn_impl == "dot":
+    if not _selection_kernels(cfg):
         return dsa.dot_selected_attention(q, k, v, selection)
-    if cfg.attn_impl != "flash":
-        raise NotImplementedError(
-            f"attn_impl={cfg.attn_impl!r}: attention over a selection runs "
-            "as 'dot' or 'flash' (ops/dsa.py)")
-    from ray_tpu.parallel.mesh import current_mesh
-    mesh = current_mesh()
-    if mesh is not None and mesh.size > 1:
-        raise NotImplementedError(
-            "models/lm.py runs a selection's kernels on one device; the "
-            f"mesh has {mesh.size}")
     return dsa.selected_attention(q, k, v, selection, cfg.attn_blk_q,
                                   cfg.attn_blk_k, None)
 
@@ -879,7 +892,7 @@ def selection_probs(cfg, q, k, lse, selection):
     selection: the indexer's target, which no gradient reaches."""
     from ray_tpu.ops import dsa
     q, k, lse = (jax.lax.stop_gradient(a) for a in (q, k, lse))
-    if cfg.attn_impl == "dot":
+    if not _selection_kernels(cfg):
         return dsa.dot_head_probs(q, k, lse, selection)
     return dsa.head_probs(q, k, lse, selection, cfg.attn_blk_q,
                           cfg.attn_blk_k)

@@ -2,13 +2,26 @@
 indexer scores every causal pair, each query keeps its ``topk`` best keys,
 and the main attention runs over those alone.
 
-Four pieces, each with a ``jax.numpy`` form beside what the chip runs:
+Four pieces; the three that make products have a ``jax.numpy`` form beside
+the kernels the chip runs (``dot_index_scores``, ``dot_selected_attention``,
+``dot_head_probs``):
 
 * ``index_scores``: ``I[t, s] = sum_j w[t, j] ReLU(q[t, j] . k[s])`` for
-  ``s <= t``, float32, ``-inf`` above the diagonal. A block of query rows at
-  a time (``lax.map``, each block rematerialised in the backward pass), so
-  the ``[rows, heads, S]`` products of one block exist and never those of a
-  sequence. Differentiable in q, k and w: the indexer's loss trains it.
+  ``s <= t``, float32, ``-inf`` above the diagonal. Differentiable in q, k
+  and w: the indexer's loss trains it. Two kernels under a ``custom_vjp``
+  whose residuals are q, k and w alone. ``dsa_index_fwd`` has a step for
+  every ``[blk_q, blk_k]`` tile of the scores: it holds the tile's queries
+  of every head, and a device loop over the heads adds ``ReLU(k q_j^T)
+  w_j`` into a float32 sum in registers, a piece of the tile's keys at a
+  time; a piece above the diagonal is filled with ``-inf`` and makes no
+  product. ``dsa_index_bwd`` walks the causal tiles alone, Q-major: a head
+  of a tile makes its product again and from it ``dP_j = (P_j > 0) dI
+  w_j``, ``dq_j += dP_j k``, ``dk += dP_j^T q_j`` and ``dw_j += sum_s dI
+  ReLU(P_j)``; the sums for dq and dw stay in VMEM for a row of tiles, dk's
+  ``[S, E]`` for the whole sequence. A head's ``[blk_q, blk_k]`` products
+  exist in VMEM and nowhere else, forward or backward; the ``jax.numpy``
+  form writes a block of query rows' ``[rows, heads, S]`` to memory and
+  rematerialises it in the backward pass.
 * ``select``: the selection ``[B, S, S]`` int8, 1 where ``I[t, s]`` is among
   the ``topk`` largest of row t (every causal key while ``t < topk``). No
   sort: a row's ``topk``-th largest score is found exactly by 32 passes of
@@ -40,6 +53,7 @@ one the caller splits the batch itself (``models/glm_moe_dsa.py`` refuses).
 from __future__ import annotations
 
 import functools
+import math
 import sys
 
 import jax
@@ -59,16 +73,17 @@ from ray_tpu.ops.flash_attention import (
 #: attends over the very keys the forward pass did.
 SELECTION_NAME = "dsa_selection"
 
-#: Query rows a block of ``index_scores``.
+#: Query rows a block of ``dot_index_scores``.
 INDEX_ROWS = 256
 
 
 # -- the indexer ----------------------------------------------------------
 
-def index_scores(q, k, w, rows: int = INDEX_ROWS):
-    """q [B, S, J, E], k [B, S, E], w [B, S, J] (float32) -> I [B, S, S]
-    float32: ``sum_j w[t, j] ReLU(q[t, j] . k[s])`` where ``s <= t``, else
-    ``-inf``."""
+def dot_index_scores(q, k, w, rows: int = INDEX_ROWS):
+    """``index_scores`` written out: a block of ``rows`` query rows at a
+    time (``lax.map``, each block rematerialised in the backward pass), so
+    the ``[rows, heads, S]`` products of one block exist and never those of
+    a sequence."""
     B, S, J, E = q.shape
     rows = min(rows, S)
     while S % rows:
@@ -337,20 +352,24 @@ def _selection_tile(heads: int):
         b // heads, qi_tab[t], ki_tab[t])
 
 
-def _call(kernel, name, grid_lead, pairs, live, in_specs, out_specs,
-          out_shape, scratch_shapes, semantics):
-    qi_tab, ki_tab = pairs
+def _call(kernel, name, grid_lead, pairs, third, in_specs, out_specs,
+          out_shape, scratch_shapes, semantics, vmem_limit=None):
+    """``kernel`` over ``grid_lead`` with the pairs' two tables scalar-
+    prefetched, and a third an entry a pair where a kernel reads one (a
+    selection's kernels whether the tile selects anything, ``_live``)."""
+    tables = [jnp.asarray(table) for table in pairs]
+    tables += [] if third is None else [third]
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=grid_lead,
+            num_scalar_prefetch=len(tables), grid=grid_lead,
             in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=scratch_shapes),
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=semantics, vmem_limit_bytes=vmem_limit),
         interpret=_interpret(), name=name)
-    return functools.partial(call, jnp.asarray(qi_tab), jnp.asarray(ki_tab),
-                             live)
+    return functools.partial(call, *tables)
 
 
 def _blocks(S: int, blk_q: int, blk_k: int):
@@ -503,3 +522,241 @@ def head_probs(q, k, lse, selection, blk_q: int = 512, blk_k: int = 512,
     )(_to_bh(q), _to_bh(k), lse.reshape(B * H, 1, S), selection)
     # Tiles above the diagonal are in no grid step and hold nothing.
     return jnp.where(selection != 0, probs, 0.0)
+
+
+# -- the indexer's kernels ------------------------------------------------
+# A head's products of a tile are made keys down, queries across ([keys,
+# queries], the transpose of what is stored): the head's weights are then a
+# row, which spreads over the keys for nothing, and dw a sum down the
+# columns. q arrives head-major ([B, J, S, E]: a head of a tile is a leading
+# index) and w as [B, J, S]; ``index_scores`` turns them.
+
+#: Tiles (queries, keys) of the forward and of the backward kernel, where S
+#: has them; the forward works its tile ``_INDEX_PIECE`` keys at a time, and
+#: ``_INDEX_TURN`` heads a turn of its loop (fewer where J has no such
+#: divisor). By measurement at [8192, 64, 128] and [4096, 32, 128] (my chip
+#: runs, PR 65; PERF.md section 6).
+_INDEX_FWD_TILE, _INDEX_PIECE, _INDEX_TURN = (128, 1024), 512, 16
+_INDEX_BWD_TILE = (512, 512)
+#: What a backward step's heads may take of VMEM (their queries and the
+#: float32 sums for dq, two buffers each), and the limit the compiler is
+#: given for either kernel (a v5e core has 128 MiB).
+_INDEX_HEADS_BYTES = 40 * 1024 * 1024
+_INDEX_VMEM_LIMIT = 96 * 1024 * 1024
+
+
+def _index_tile(S: int, E: int, want):
+    """``want``'s (blk_q, blk_k) as S has them, or what ``_blocks`` raises;
+    the chip's compiler takes heads of whole lanes alone."""
+    blk_q, blk_k = _blocks(S, *want)
+    if E % 128 and not _interpret():
+        raise ValueError(
+            f"the indexer's kernels need a head width that is a multiple "
+            f"of 128 on the chip, got E={E}: use attn_impl='dot'")
+    return blk_q, blk_k
+
+
+def _index_fwd_blocks(S: int, J: int, E: int):
+    """(blk_q, blk_k, keys a piece, heads a turn of the loop)."""
+    blk_q, blk_k = _index_tile(S, E, _INDEX_FWD_TILE)
+    return blk_q, blk_k, _pick_block(blk_k, _INDEX_PIECE), \
+        math.gcd(J, _INDEX_TURN)
+
+
+def _index_bwd_blocks(S: int, J: int, E: int, itemsize: int):
+    """(blk_q, blk_k, heads a grid step): the largest divisor of J whose
+    queries and float32 dq sums, twice each, fit ``_INDEX_HEADS_BYTES``."""
+    blk_q, blk_k = _index_tile(S, E, _INDEX_BWD_TILE)
+    a_head = 2 * blk_q * E * (itemsize + 4)
+    heads = max(1, min(J, _INDEX_HEADS_BYTES // a_head))
+    while J % heads:
+        heads -= 1
+    return blk_q, blk_k, heads
+
+
+def _nt(a, b):
+    """a [m, e] . b [n, e]^T -> [m, n] float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _is_causal(query, key, rows: int, cols: int):
+    """[rows, cols]: whether key + column <= query + row."""
+    t = query + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    s = key + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    return s <= t
+
+
+def _index_fwd_kernel(qi_tab, ki_tab, load_tab, q_ref, k_ref, w_ref, o_ref,
+                      *, blk_q: int, blk_k: int, piece: int, turn: int):
+    """A step of grid (batch, tiles): q_ref [J, blk_q, E], k_ref [blk_k, E],
+    w_ref [J, blk_q] float32, o_ref [blk_q, blk_k]. Every tile of the
+    scores has a step, Q-major. The tile is worked ``piece`` keys at a
+    time, ``turn`` heads a turn of a device loop whose carry is the piece's
+    float32 sum over the heads; a piece above the diagonal is filled with
+    ``-inf`` and makes no product."""
+    t = pl.program_id(1)
+    query = qi_tab[t] * blk_q
+    for at in range(0, blk_k, piece):
+        cols = slice(at, at + piece)
+        key = ki_tab[t] * blk_k + at
+        live = key < query + blk_q
+
+        @pl.when(live)
+        def _(cols=cols, key=key):
+            k_blk = k_ref[cols, :]
+
+            def heads(i, acc):
+                for j in range(turn):
+                    j += i * turn
+                    acc = acc + jax.nn.relu(_nt(k_blk, q_ref[j])) \
+                        * w_ref[pl.ds(j, 1), :]
+                return acc
+
+            acc = jax.lax.fori_loop(
+                0, q_ref.shape[0] // turn, heads,
+                jnp.zeros((piece, blk_q), jnp.float32))
+            o_ref[:, cols] = jnp.where(
+                _is_causal(query, key, blk_q, piece), acc.T, -jnp.inf)
+
+        @pl.when(jnp.logical_not(live))
+        def _(cols=cols):
+            o_ref[:, cols] = jnp.full((blk_q, piece), -jnp.inf, jnp.float32)
+
+
+def _index_bwd_kernel(qi_tab, ki_tab, q_ref, k_ref, w_ref, g_ref, dq_ref,
+                      dk_ref, dw_ref, *, blk_q: int, blk_k: int,
+                      chunks: int):
+    """A step of grid (batch * chunks of heads, causal tiles), Q-major:
+    q_ref [heads, blk_q, E], k_ref [blk_k, E], w_ref [heads, blk_q], g_ref
+    [blk_q, blk_k] (the scores' cotangent). The three sums are the outputs'
+    float32 blocks: dq_ref [heads, blk_q, E] and dw_ref [heads, blk_q] stay
+    for a row of tiles, dk_ref [S, E] for a batch row's every step. A head
+    of a tile makes ``P = k q^T`` again, ``dP = (P > 0) g^T w`` in the
+    operands' dtype, and from it ``dk += dP q`` and ``dq += dP^T k``."""
+    t = pl.program_id(1)
+    qi, ki = qi_tab[t], ki_tab[t]
+    first, _ = _row_ends(qi_tab)
+
+    @pl.when(first)
+    def _():
+        dq_ref[...] = jnp.zeros(dq_ref.shape, jnp.float32)
+        dw_ref[...] = jnp.zeros(dw_ref.shape, jnp.float32)
+
+    @pl.when(jnp.logical_and(t == 0, pl.program_id(0) % chunks == 0))
+    def _():
+        dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
+
+    k_blk = k_ref[...]
+    # No cotangent belongs to a -inf: whatever came for it is dropped.
+    g_t = jnp.where(_is_causal(qi * blk_q, ki * blk_k, blk_q, blk_k),
+                    g_ref[...], 0.0).T
+
+    def head(j, dk):
+        q_j = q_ref[j]
+        p_t = _nt(k_blk, q_j)
+        dw_ref[pl.ds(j, 1), :] += (g_t * jnp.maximum(p_t, 0.0)).sum(
+            0, keepdims=True)
+        dp_t = jnp.where(p_t > 0, g_t * w_ref[pl.ds(j, 1), :],
+                         0.0).astype(k_blk.dtype)
+        dq_ref[j] += jax.lax.dot_general(
+            dp_t, k_blk, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return dk + jax.lax.dot_general(
+            dp_t, q_j, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    dk_ref[pl.ds(pl.multiple_of(ki * blk_k, blk_k), blk_k), :] += \
+        jax.lax.fori_loop(0, q_ref.shape[0], head,
+                          jnp.zeros(k_blk.shape, jnp.float32))
+
+
+def _index_forward(q, k, w):
+    """``index_scores``: the forward kernel on q and w head-major."""
+    B, S, J, E = q.shape
+    blk_q, blk_k, piece, turn = _index_fwd_blocks(S, J, E)
+    qi, ki = _tile_pairs(S, blk_q, blk_k, False, False)
+    # A step above the diagonal keeps the row's last tile of keys that has
+    # a causal pair where it is: the third table is the tile to load.
+    load = np.minimum(ki, ((qi + 1) * blk_q - 1) // blk_k)
+    return _call(
+        functools.partial(_index_fwd_kernel, blk_q=blk_q, blk_k=blk_k,
+                          piece=piece, turn=turn),
+        "dsa_index_fwd", (B, len(qi)), (qi, ki), jnp.asarray(load),
+        in_specs=[
+            pl.BlockSpec((None, J, blk_q, E), lambda b, t, qi_tab, ki_tab,
+                         load_tab: (b, 0, qi_tab[t], 0)),
+            pl.BlockSpec((None, blk_k, E), lambda b, t, qi_tab, ki_tab,
+                         load_tab: (b, load_tab[t], 0)),
+            pl.BlockSpec((None, J, blk_q), _q_row),
+        ],
+        out_specs=pl.BlockSpec((None, blk_q, blk_k), lambda b, t, qi_tab,
+                               ki_tab, load_tab: (b, qi_tab[t], ki_tab[t])),
+        out_shape=jax.ShapeDtypeStruct((B, S, S), jnp.float32),
+        scratch_shapes=[], semantics=("parallel", "arbitrary"),
+        vmem_limit=_INDEX_VMEM_LIMIT,
+    )(q.transpose(0, 2, 1, 3), k, w.transpose(0, 2, 1))
+
+
+def _index_backward(q, k, w, g):
+    """(dq, dk, dw) of the scores' cotangent g [B, S, S]: the backward
+    kernel on q and w head-major, its float32 sums turned back and cast."""
+    B, S, J, E = q.shape
+    blk_q, blk_k, heads = _index_bwd_blocks(S, J, E, q.dtype.itemsize)
+    chunks = J // heads
+    pairs = _tile_pairs(S, blk_q, blk_k, True, False)
+
+    def q_tile(i, t, qi_tab, ki_tab):
+        return i // chunks, i % chunks, qi_tab[t], 0
+
+    def q_row(i, t, qi_tab, ki_tab):
+        return i // chunks, i % chunks, qi_tab[t]
+
+    dq_t, dk, dw_t = _call(
+        functools.partial(_index_bwd_kernel, blk_q=blk_q, blk_k=blk_k,
+                          chunks=chunks),
+        "dsa_index_bwd", (B * chunks, len(pairs[0])), pairs, None,
+        in_specs=[
+            pl.BlockSpec((None, heads, blk_q, E), q_tile),
+            pl.BlockSpec((None, blk_k, E), lambda i, t, qi_tab, ki_tab: (
+                i // chunks, ki_tab[t], 0)),
+            pl.BlockSpec((None, heads, blk_q), q_row),
+            pl.BlockSpec((None, blk_q, blk_k), lambda i, t, qi_tab, ki_tab: (
+                i // chunks, qi_tab[t], ki_tab[t])),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, heads, blk_q, E), q_tile),
+            pl.BlockSpec((None, S, E), lambda i, t, qi_tab, ki_tab: (
+                i // chunks, 0, 0)),
+            pl.BlockSpec((None, heads, blk_q), q_row),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, J, S, E), jnp.float32),
+            jax.ShapeDtypeStruct((B, S, E), jnp.float32),
+            jax.ShapeDtypeStruct((B, J, S), jnp.float32),
+        ],
+        scratch_shapes=[], semantics=("arbitrary", "arbitrary"),
+        vmem_limit=_INDEX_VMEM_LIMIT,
+    )(q.transpose(0, 2, 1, 3), k, w.transpose(0, 2, 1), g)
+    return (dq_t.transpose(0, 2, 1, 3).astype(q.dtype), dk.astype(k.dtype),
+            dw_t.transpose(0, 2, 1).astype(w.dtype))
+
+
+@jax.custom_vjp
+def index_scores(q, k, w):
+    """q [B, S, J, E], k [B, S, E], w [B, S, J] (float32) -> I [B, S, S]
+    float32: ``sum_j w[t, j] ReLU(q[t, j] . k[s])`` where ``s <= t``, else
+    ``-inf``. Products in the operands' dtype, everything after them in
+    float32. Differentiable in q, k and w: the residuals are the three."""
+    return _index_forward(q, k, w)
+
+
+def _index_fwd(q, k, w):
+    return _index_forward(q, k, w), (q, k, w)
+
+
+def _index_bwd(residuals, g):
+    return _index_backward(*residuals, g)
+
+
+index_scores.defvjp(_index_fwd, _index_bwd)

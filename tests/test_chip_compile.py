@@ -565,12 +565,14 @@ def test_selected_attention_compiles_at_the_published_widths(topo, backward):
 
 
 def test_the_indexer_and_the_selection_compile_without_a_sort(topo):
-    """The indexer's scores, the threshold search and the loss at the
-    cell's size: no ``sort`` and no ``top-k`` custom call in the compiled
-    program (the 2048th largest of a row is found by counting), and less
-    than 1.5 GB of temporaries, gradients included: the [rows, 32, S]
-    products of a block of 256 query rows, never a sequence's."""
+    """The indexer's scores (the kernels: ``dsa_index_fwd``, and
+    ``dsa_index_bwd`` for the gradients), the threshold search and the loss
+    at the cell's size: no ``sort`` and no ``top-k`` custom call in the
+    compiled program (the 2048th largest of a row is found by counting),
+    and less than 1.5 GB of temporaries, gradients included: a head's
+    products of a tile exist in VMEM and nowhere else."""
     from ray_tpu.ops import dsa
+    from ray_tpu.parallel.collectives import kernel_census
     one = SingleDeviceSharding(topo.devices[0])
     B, S = DSA_SHAPE[:2]
     heads, width = DSA_INDEX
@@ -588,6 +590,7 @@ def test_the_indexer_and_the_selection_compile_without_a_sort(topo):
         q, k, w).compile()
     text = compiled.as_text()
     assert " sort(" not in text and "TopK" not in text
+    assert kernel_census(text) == {"dsa_index_fwd": 1, "dsa_index_bwd": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
@@ -597,7 +600,8 @@ def test_the_glm_cells_step_runs_its_kernels_as_counted(topo):
     twice a layer (S = 4096 is under 32 x 256, its outputs are not worth
     keeping: ``flash_attention.worth_keeping``), the two backward kernels
     once a layer, the head-summed probabilities twice a layer that owns an
-    indexer (its loss is part of the rematerialised block), the share's way
+    indexer (its loss is part of the rematerialised block) and so the
+    indexer's forward kernel, its backward kernel once, the share's way
     back to tokens in the expert layers, and no causal flash kernel."""
     from ray_tpu.parallel.collectives import kernel_census
     cell = "glm-5.2-1chip.steady"
@@ -614,7 +618,8 @@ def test_the_glm_cells_step_runs_its_kernels_as_counted(topo):
     assert {name: n for name, n in census.items() if name and name.startswith(
         ("dsa_", "flash_"))} == {
         "dsa_fwd": 2 * len(layers), "dsa_bwd_dq": len(layers),
-        "dsa_bwd_dkv": len(layers), "dsa_probs": 2 * owners}
+        "dsa_bwd_dkv": len(layers), "dsa_probs": 2 * owners,
+        "dsa_index_fwd": 2 * owners, "dsa_index_bwd": owners}
     assert census["moe_rows_to_tokens"] >= 4
 
 

@@ -144,8 +144,8 @@ def test_the_tiny_stack_is_the_published_pattern():
     # One key past a tile, as 513 is at 512.
     assert FLASH.sliding_window_size == FLASH.attn_blk_k + 1
     # Every full layer owns an indexer: a run's forward kernel, its two
-    # backward kernels and the probabilities' each, the window layers the
-    # flash kernels.
+    # backward kernels, the probabilities' and the indexer's pair each, the
+    # window layers the flash kernels.
     from ray_tpu.parallel.collectives import kernel_census
     tokens, targets = batch(FLASH, FLASH_SEQ, rows=1)
     census = kernel_census(jax.make_jaxpr(jax.grad(
@@ -154,6 +154,7 @@ def test_the_tiny_stack_is_the_published_pattern():
     assert {name: census[name] for name in census
             if name.startswith(("dsa_", "flash_"))} == {
         "dsa_fwd": 2, "dsa_probs": 2, "dsa_bwd_dq": 2, "dsa_bwd_dkv": 2,
+        "dsa_index_fwd": 2, "dsa_index_bwd": 2,
         "flash_fwd_win": 1, "flash_bwd_dq_win": 1, "flash_bwd_dkv_win": 1}
 
 
@@ -401,7 +402,7 @@ def test_the_selections_kernels_at_192_and_128():
     masked attention written out."""
     q, k, v, g = _qkv(1, 192, 128)
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    selection = dsa.select(dsa.index_scores(
+    selection = dsa.select(dsa.dot_index_scores(
         jax.random.normal(ks[0], (1, 256, 32, 16)),
         jax.random.normal(ks[1], (1, 256, 16)),
         jax.random.normal(ks[2], (1, 256, 32))), 100)

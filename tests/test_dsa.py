@@ -1,9 +1,13 @@
-"""ops/dsa.py: the indexer's scores against the sum written out, the
+"""ops/dsa.py: the indexer's scores in their ``jax.numpy`` form against the
+sum written out, its kernels (interpreted) against that form, values and
+gradients, the
 selection against ``jax.lax.top_k`` on whole rows (exactly ``min(t + 1, k)``
 keys a row, all causal), the three kernels of the attention over a selection
 (interpreted) against ``dot_attention`` with the same mask at heads of 256 |
 256, the head-summed probabilities against a softmax written out, and the
 indexer's loss and its gradient."""
+
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -36,13 +40,13 @@ CAUSAL = np.tril(np.ones((S, S), bool))
 
 
 def _selection(topk, seed=0):
-    return dsa.select(dsa.index_scores(*_indexer(seed)), topk)
+    return dsa.select(dsa.dot_index_scores(*_indexer(seed)), topk)
 
 
 @pytest.mark.parametrize("rows", [32, 256, 1000])
 def test_index_scores_are_the_weighted_relu_sum_under_the_diagonal(rows):
     q, k, w = _indexer()
-    got = dsa.index_scores(q, k, w, rows=rows)
+    got = dsa.dot_index_scores(q, k, w, rows=rows)
     want = jnp.einsum("bqj,bqjk->bqk", w, jax.nn.relu(
         jnp.einsum("bqje,bke->bqjk", q, k)))
     np.testing.assert_allclose(np.where(CAUSAL, got, 0.0),
@@ -51,9 +55,97 @@ def test_index_scores_are_the_weighted_relu_sum_under_the_diagonal(rows):
     assert got.dtype == jnp.float32
 
 
+#: (heads, width, length, what ``_index_fwd_blocks`` and
+#: ``_index_bwd_blocks`` give, None for their own choice): one head on one
+#: tile, the diagonal alone; several tiles of the module's own sizes (``blk_q
+#: != blk_k`` forward; three heads a head a turn of the loop, four heads all
+#: in one); tiles, pieces and chunks of heads that no length under a
+#: thousand would be given; heads of the chip's width.
+INDEX_CASES = {
+    "one_head_one_tile": (1, 16, 128, None, None),
+    "two_tiles": (3, 16, 256, None, None),
+    "own_tiles_of_512": (4, 8, 512, None, None),
+    "narrow_tiles_heads_in_chunks": (4, 16, 512, (128, 256, 128, 2),
+                                     (128, 256, 2)),
+    "wide_queries_a_head_a_step": (2, 16, 384, (128, 128, 128, 1),
+                                   (384, 128, 1)),
+    "heads_of_128": (2, 128, 256, None, None),
+}
+
+
+@pytest.fixture(params=list(INDEX_CASES))
+def index_case(request, monkeypatch):
+    """(q, k, w, the causal mask) of a case, the case's tiles in place of
+    the module's."""
+    heads, width, seq, forward, backward = INDEX_CASES[request.param]
+    if forward:
+        monkeypatch.setattr(dsa, "_index_fwd_blocks", lambda *a: forward)
+    if backward:
+        monkeypatch.setattr(dsa, "_index_bwd_blocks", lambda *a: backward)
+    ks = jax.random.split(jax.random.PRNGKey(seq + heads), 3)
+    rows = B if seq == 256 else 1
+    return (jax.random.normal(ks[0], (rows, seq, heads, width)),
+            jax.random.normal(ks[1], (rows, seq, width)),
+            jax.random.normal(ks[2], (rows, seq, heads)),
+            np.tril(np.ones((seq, seq), bool)))
+
+
+def test_the_index_kernel_is_the_sum_written_out(index_case):
+    q, k, w, causal = index_case
+    got = dsa.index_scores(q, k, w)
+    want = dsa.dot_index_scores(q, k, w)
+    assert got.dtype == jnp.float32
+    assert np.isneginf(np.asarray(got)[:, ~causal]).all()
+    np.testing.assert_allclose(np.where(causal, got, 0.0),
+                               np.where(causal, want, 0.0), atol=1e-4)
+
+
+_INDEX_GRADS = {}
+
+
+@pytest.mark.parametrize("wrt", [0, 1, 2], ids=["dq", "dk", "dw"])
+def test_the_index_kernels_gradients_are_the_sums(index_case, wrt, request):
+    """Of the indexer's own loss, which masks the scores to a selection
+    before it reads them: the cotangent is zero off the selection, above
+    the diagonal too. A case's three gradients are one backward pass, made
+    by the case's first test."""
+    q, k, w, causal = index_case
+    case = request.node.callspec.params["index_case"]
+    if case not in _INDEX_GRADS:
+        # Two keys of three under the diagonal, and the diagonal.
+        at = np.arange(causal.shape[0])
+        selection = jnp.broadcast_to(causal & (
+            ((at[:, None] + at[None, :]) % 3 > 0)
+            | (at[:, None] == at[None, :])), (q.shape[0],) + causal.shape)
+        probs = jnp.where(selection, jax.random.uniform(
+            jax.random.PRNGKey(5), selection.shape), 0.0)
+        _INDEX_GRADS[case] = [jax.grad(lambda *a: dsa.index_loss(
+            scores(*a), probs, selection).sum(), (0, 1, 2))(q, k, w)
+            for scores in (dsa.index_scores, dsa.dot_index_scores)]
+    got, want = (grads[wrt] for grads in _INDEX_GRADS[case])
+    assert got.dtype == want.dtype and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=1e-4 * float(
+        jnp.abs(want).max()) + 1e-7)
+
+
+def test_the_index_kernels_refuse_what_they_cannot_tile(monkeypatch):
+    q, k, w = _indexer(seq=200)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        dsa.index_scores(q, k, w)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        jax.grad(lambda q: dsa.index_scores(q, k, w).sum())(q)
+    # Heads of 16 are the interpreter's alone: the chip's compiler wants
+    # whole lanes.
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.flash_attention"],
+                        "_interpret", lambda: False)
+    q, k, w = _indexer()
+    with pytest.raises(ValueError, match="head width"):
+        dsa.index_scores(q, k, w)
+
+
 @pytest.mark.parametrize("topk", [1, 48, 128, 255, 256, 4096])
 def test_the_selection_is_top_k_of_every_causal_row(topk):
-    scores = dsa.index_scores(*_indexer())
+    scores = dsa.dot_index_scores(*_indexer())
     got = np.asarray(dsa.select(scores, topk))
     assert got.dtype == np.int8 and set(np.unique(got)) <= {0, 1}
     assert (got.sum(-1) == np.minimum(np.arange(S) + 1, topk)).all()
@@ -70,7 +162,8 @@ def test_the_selection_orders_negative_scores_and_zeros():
     x = jnp.asarray([-jnp.inf, -3.5, -1e-30, -0.0, 0.0, 1e-30, 2.0, jnp.inf])
     bits = np.asarray(dsa._ordered_bits(x)).astype(np.int64)
     assert (np.diff(bits) >= 0).all() and bits[3] < bits[4]
-    scores = jnp.where(CAUSAL, -jnp.abs(dsa.index_scores(*_indexer())) - 1.0,
+    scores = jnp.where(CAUSAL,
+                       -jnp.abs(dsa.dot_index_scores(*_indexer())) - 1.0,
                        -jnp.inf)
     got = np.asarray(dsa.select(scores, 48))
     assert (got.sum(-1) == np.minimum(np.arange(S) + 1, 48)).all()
@@ -151,7 +244,7 @@ def test_head_probs_are_the_softmax_summed_over_heads(topk):
 
 
 def test_the_index_loss_is_the_kl_and_its_gradient_the_difference():
-    scores = dsa.index_scores(*_indexer())
+    scores = dsa.dot_index_scores(*_indexer())
     selection = dsa.select(scores, 48)
     probs = jnp.where(selection != 0, jax.random.uniform(
         jax.random.PRNGKey(5), scores.shape), 0.0)

@@ -162,13 +162,15 @@ def test_the_tiny_stack_is_the_published_pattern():
         ("dense_full", 1), ("moe_shared", 3), ("moe_full", 1)]
     assert CFG.n_moe_layers == 4 and CFG.index_topk < SEQ
     # The selection's kernels run (interpreted) under ``flash``: a forward
-    # call a run, and the head-summed probabilities where an indexer is.
+    # call a run, and the indexer's scores and the head-summed probabilities
+    # where an indexer is.
     from ray_tpu.parallel.collectives import kernel_census
     tokens, targets = batch(FLASH, FLASH_SEQ)
     census = kernel_census(jax.make_jaxpr(lambda p: glm_moe_dsa.loss_fn(
         p, replace(FLASH, remat=False), tokens, targets)[0])(
             drawn(GLM, FLASH)))
-    assert census["dsa_fwd"] == 3 and census["dsa_probs"] == 2
+    assert census["dsa_fwd"] == 3 and census["dsa_probs"] == 2 \
+        and census["dsa_index_fwd"] == 2
 
 
 @pytest.mark.parametrize("which", ["both", "both_flash"])
