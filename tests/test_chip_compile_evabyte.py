@@ -11,44 +11,19 @@ another process that was not allowed beside it).
 import os
 import sys
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
+from chip_compile import compile_for_tpu, topo  # noqa: F401
+from ray_tpu.ops import eva
+from ray_tpu.parallel import MeshConfig, build_mesh
+from ray_tpu.parallel.collectives import kernel_census
 
-import ray_tpu.ops  # noqa: E402,F401 - loads ray_tpu.ops.flash_attention
-from ray_tpu.ops import eva  # noqa: E402
-from ray_tpu.parallel import MeshConfig, build_mesh  # noqa: E402
-from ray_tpu.parallel.collectives import kernel_census  # noqa: E402
-
-flash_mod = sys.modules["ray_tpu.ops.flash_attention"]
 CELL = "evabyte-6.5b-1chip.steady"
 # (B, S, H, D), window, chunk: EvaByte's attention at the cell's length.
 SHAPE, WINDOW, CHUNK = (1, 32768, 32, 128), 2048, 16
-
-
-@pytest.fixture(scope="module")
-def topo():
-    try:
-        from jax.experimental import topologies
-        return topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as exc:  # noqa: BLE001 - any failure means "no libtpu"
-        pytest.skip(f"v5e:2x2 topology cannot be described here: {exc!r}")
-
-
-@pytest.fixture(autouse=True)
-def compile_for_tpu(monkeypatch):
-    from jax.experimental.compilation_cache import compilation_cache
-    monkeypatch.setattr(flash_mod, "_interpret", lambda: False)
-    was_enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was_enabled)
-    compilation_cache.reset_cache()
 
 
 def _operands(topo):

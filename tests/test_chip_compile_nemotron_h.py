@@ -11,19 +11,17 @@ or its lock held by another process that was not allowed beside it).
 import os
 import sys
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
+from chip_compile import (compile_for_tpu,  # noqa: F401
+                          flash_mod, topo)
+from ray_tpu.ops import gated_norm, moe, ssd
+from ray_tpu.parallel import MeshConfig, build_mesh
+from ray_tpu.parallel.collectives import kernel_census
 
-import ray_tpu.ops  # noqa: E402,F401 - loads ray_tpu.ops.flash_attention
-from ray_tpu.ops import gated_norm, moe, ssd  # noqa: E402
-from ray_tpu.parallel import MeshConfig, build_mesh  # noqa: E402
-from ray_tpu.parallel.collectives import kernel_census  # noqa: E402
-
-flash_mod = sys.modules["ray_tpu.ops.flash_attention"]
 CELL = "nemotron-3-nano-30b-a3b-1chip.steady"
 # Two sequences of the cell's 16384: 64 state-space heads of 64 in 8 B/C
 # groups with a state of 128; a share's buffer of twice 32 of 128 experts'
@@ -32,28 +30,6 @@ B, S, HEADS, WIDTH, GROUPS, STATE = 2, 16384, 64, 64, 8, 128
 ROWS, D, EXPERT, HELD = 98304, 2688, 1856, 32
 BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
-
-
-@pytest.fixture(scope="module")
-def topo():
-    try:
-        from jax.experimental import topologies
-        return topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as exc:  # noqa: BLE001 - any failure means "no libtpu"
-        pytest.skip(f"v5e:2x2 topology cannot be described here: {exc!r}")
-
-
-@pytest.fixture(autouse=True)
-def compile_for_tpu(monkeypatch):
-    from jax.experimental.compilation_cache import compilation_cache
-    monkeypatch.setattr(flash_mod, "_interpret", lambda: False)
-    was_enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was_enabled)
-    compilation_cache.reset_cache()
 
 
 @pytest.fixture(scope="module")

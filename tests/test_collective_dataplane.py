@@ -34,13 +34,29 @@ def small_chunks(monkeypatch):
 # -- striped multi-source pulls --------------------------------------------
 
 
-def test_striped_pull_disjoint_ranges_across_sources(small_chunks):
+def test_striped_pull_disjoint_ranges_across_sources(small_chunks,
+                                                     monkeypatch):
     """Four holders of the same object each serve a share of the chunk
     ranges; the landing is byte-identical and every stripe slot moved
     bytes."""
     payload = _patterned(1 << 20)  # 16 chunks at 64 KB
     tables = [NodeObjectTable() for _ in range(4)]
     servers = [ObjectServer(t, host="127.0.0.1") for t in tables]
+    # The four fetch workers share one queue: on a busy machine the first
+    # to start can drain it before the others run. Each holds its first
+    # chunk until all four have taken one, so that which holder serves a
+    # slot is read from the striping and not from the scheduler.
+    all_started, first = threading.Barrier(4, timeout=60), threading.local()
+    fetch = dataplane._fetch_chunk
+
+    def fetch_chunk(*args):
+        if threading.current_thread().name.startswith(
+                "ray_tpu-pull-chunk-") and not hasattr(first, "taken"):
+            first.taken = True
+            all_started.wait()
+        return fetch(*args)
+
+    monkeypatch.setattr(dataplane, "_fetch_chunk", fetch_chunk)
     try:
         for t in tables:
             t.put("blob", payload)

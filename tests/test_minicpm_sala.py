@@ -182,7 +182,8 @@ def test_a_dropped_term_moves_the_logits(script, two_layers, name):
     swaps, fields, change = script.faults()[name]
     with script._swapped(swaps):
         got = forward_alone(SALA, change(cut) if change else cut,
-                            replace(cfg, **fields), tokens)
+                            replace(cfg, **fields), tokens,
+                            patched=bool(swaps))
     moved = float(jnp.sqrt(((got - plain) ** 2).mean())
                   / jnp.sqrt((plain ** 2).mean()))
     if name in script.UNSEEN:

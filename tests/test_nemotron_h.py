@@ -11,6 +11,7 @@ pattern, the cut's layers, the runs of units and the parameter counts; what
 the step's gauge reads.
 """
 
+import functools
 from dataclasses import replace
 from functools import partial
 
@@ -190,23 +191,30 @@ def test_the_parameter_counts():
 def test_the_unit_scan_is_the_layers_one_by_one(remat):
     cfg = replace(CFG, remat=remat)
     tokens, _ = batch(cfg, SEQ)
-
-    def one_by_one(params):
-        x = lm.embed(params["wte"], tokens, cfg.dtype)
-        for kind, stack, index, prefix in reference._walk(cfg.layers):
-            layer = {name[len(prefix):]: a[index]
-                     for name, a in params[stack].items()
-                     if name.startswith(prefix)}
-            x, _ = nemotron_h._layer(cfg, kind, x, layer)
-        return lm.rmsnorm(x, params["lnf_scale"], cfg.layer_norm_epsilon)
-
     with jax.default_matmul_precision("highest"):
         got, aux = jax.jit(partial(nemotron_h.hidden_states, cfg=cfg))(
             drawn(NEMOTRON, cfg), tokens=tokens)
-        want = jax.jit(one_by_one)(drawn(NEMOTRON, cfg))
-    np.testing.assert_allclose(got, want, atol=1e-4)
+    np.testing.assert_allclose(got, _one_by_one(), atol=1e-4)
     assert aux["picked"].shape == (3, 2, SEQ, 2)
     assert aux["relu2_zero_share"].shape == (3,)
+
+
+@functools.lru_cache(maxsize=None)
+def _one_by_one():
+    """One program for both scans: a layer does not read ``cfg.remat``."""
+    tokens, _ = batch(CFG, SEQ)
+
+    def one_by_one(params):
+        x = lm.embed(params["wte"], tokens, CFG.dtype)
+        for kind, stack, index, prefix in reference._walk(CFG.layers):
+            layer = {name[len(prefix):]: a[index]
+                     for name, a in params[stack].items()
+                     if name.startswith(prefix)}
+            x, _ = nemotron_h._layer(CFG, kind, x, layer)
+        return lm.rmsnorm(x, params["lnf_scale"], CFG.layer_norm_epsilon)
+
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(one_by_one)(drawn(NEMOTRON, CFG))
 
 
 # -- the grouped recurrence and the grouped norm ---------------------------

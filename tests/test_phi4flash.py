@@ -18,7 +18,7 @@ import pytest
 
 import family_cases
 import reference_phi4flash as reference
-from family_cases import batch, drawn, forward_alone
+from family_cases import Patches, batch, drawn, forward_alone
 from ray_tpu.models import lm, phi4flash
 from ray_tpu.ops import selective_scan as scan_op
 from ray_tpu.parallel import MeshConfig, build_mesh
@@ -278,7 +278,8 @@ def test_a_dropped_term_shows(cut_logits, dropped, monkeypatch):
     layer reading k, v of its own input is the benchmark's own test)."""
     cfg = DROP_CFG
     params, run = drawn(PHI, cfg), cfg
-    patch = partial(monkeypatch.setattr, phi4flash)
+    patches = Patches(monkeypatch)
+    patch = partial(patches.setattr, phi4flash)
     if dropped == "p2_not_subtracted":
         patch("_lambda", lambda layer, l0: jnp.float32(0.0))
     elif dropped == "subln":
@@ -318,7 +319,8 @@ def test_a_dropped_term_shows(cut_logits, dropped, monkeypatch):
             "layernorm_bias": ("a_ln1_bias", jnp.zeros_like)}[dropped]
         params = _zeroed(params, leaf, change)
     tokens, _ = batch(cfg, SEQ)
-    got, want = forward_alone(PHI, params, run, tokens), cut_logits
+    got = forward_alone(PHI, params, run, tokens, patched=patches.made)
+    want = cut_logits
     rms = float(jnp.sqrt((want ** 2).mean()))
     assert float(jnp.sqrt(((got - want) ** 2).mean())) > 2e-5 * rms
     assert float(jnp.abs(got - want).max()) > 1e-4 * rms

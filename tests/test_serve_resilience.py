@@ -157,7 +157,8 @@ def test_rolling_redeploy_under_load(serve_session):
     for w in workers:
         w.start()
     try:
-        time.sleep(0.3)
+        _wait_for(lambda: len(results) >= 8, timeout=60,
+                  msg="traffic to flow on v1")
 
         @serve.deployment(num_replicas=2, version="v2", name="roll")
         class V2:
@@ -166,7 +167,8 @@ def test_rolling_redeploy_under_load(serve_session):
                 return "v2"
 
         serve.run(V2.bind())
-        time.sleep(0.5)
+        _wait_for(lambda: "v2" in results or errors, timeout=60,
+                  msg="traffic to reach v2")
     finally:
         stop.set()
         for w in workers:
