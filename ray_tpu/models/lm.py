@@ -133,7 +133,7 @@ def rematerialised(cfg, block):
     ``cfg.remat``."""
     if not cfg.remat:
         return block
-    from ray_tpu.ops.dsa import SELECTION_NAME
+    from ray_tpu.ops.dsa import LOSS_GRADIENT_NAME, SELECTION_NAME
     from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
     if cfg.remat_policy == "selective":
         kept = ("attn_q", "attn_k", "attn_v", "attn_raw", "ffn_in")
@@ -144,7 +144,7 @@ def rematerialised(cfg, block):
             f"Unknown remat_policy {cfg.remat_policy!r}; "
             "expected 'full' or 'selective'")
     policy = jax.checkpoint_policies.save_only_these_names(
-        *kept, *RESIDUAL_NAMES, SELECTION_NAME)
+        *kept, *RESIDUAL_NAMES, SELECTION_NAME, LOSS_GRADIENT_NAME)
     return jax.checkpoint(block, policy=policy)
 
 
@@ -172,9 +172,18 @@ def scan_blocks(cfg, block, x, layers, positions, runs=None,
     nothing but a learned selection of keys where a block makes one
     (``ops/dsa.py`` ``SELECTION_NAME``: a byte a pair, so that the backward
     pass neither searches it again nor attends over other keys than the
-    forward pass did). ``"selective"`` keeps the same two beside the five values a
-    model names in its block (``attn_q``, ``attn_k``, ``attn_v``,
-    ``attn_raw``, ``ffn_in``).
+    forward pass did) and, where its indexer has a loss, that loss's
+    gradient by the scores (``ops/dsa.py`` ``LOSS_GRADIENT_NAME``: 4 S^2
+    bytes a layer with an indexer, for which the backward pass runs
+    neither ``dsa_probs`` nor the scores' forward kernel again: 85 ms of
+    step a GB at 128 heads of 192, and H x D_qk / 8 products a kept byte
+    whatever S, the kernel's products and the array's bytes both growing
+    as S^2; kept wherever such a loss exists, since a layer that cannot
+    hold one more [S, S] float32 array cannot run its forward pass, which
+    holds the scores, the target and the loss's temporaries at once).
+    ``"selective"`` keeps the same beside the five values a model names in
+    its block (``attn_q``, ``attn_k``, ``attn_v``, ``attn_raw``,
+    ``ffn_in``).
 
     A stack of several kinds of layer gives ``runs``, the (kind, layers) of
     every run of one kind in order (``layer_runs``), ``block`` as a dict by

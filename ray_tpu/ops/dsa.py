@@ -44,7 +44,14 @@ the kernels the chip runs (``dot_index_scores``, ``dot_selected_attention``,
   selection, which the forward kernel never forms: one more kernel
   (``dsa_probs``), the heads the inner grid axis and a ``[blk_q, blk_k]``
   float32 tile of p resident across them. ``index_loss`` is then the
-  indexer's own term, ``mean_t KL(p[t] / sum p[t] || softmax_{S_t} I[t])``.
+  indexer's own term, ``mean_t KL(p[t] / sum p[t] || softmax_{S_t} I[t])``,
+  under a rule of its own: its forward pass makes the gradient by the
+  scores, [B, S, S] float32, and names it (``LOSS_GRADIENT_NAME``), so that
+  a rematerialised block (``models/lm.py`` ``scan_blocks``) keeps 4 S^2
+  bytes a layer and its second forward pass holds neither this kernel nor
+  ``dsa_index_fwd``: ``H x D / 8`` of this kernel's products a kept byte
+  whatever S (85 ms of step a GB at 128 heads of 192, where
+  ``flash_attention.worth_keeping``'s line is 21).
 
 The kernels run whole sequences of one device; under a mesh of more than
 one the caller splits the batch itself (``models/glm_moe_dsa.py`` refuses).
@@ -72,6 +79,11 @@ from ray_tpu.ops.flash_attention import (
 #: thresholds a second time in the backward pass, and the backward pass
 #: attends over the very keys the forward pass did.
 SELECTION_NAME = "dsa_selection"
+
+#: The name of ``index_loss``'s gradient by the scores, the one array its
+#: backward pass reads: a rematerialisation policy that keeps it runs neither
+#: ``head_probs`` nor the scores' forward kernel a second time.
+LOSS_GRADIENT_NAME = "dsa_index_loss_gradient"
 
 #: Query rows a block of ``dot_index_scores``.
 INDEX_ROWS = 256
@@ -141,19 +153,43 @@ def select(scores, topk: int):
     return ((bits >= floor[..., None]) & causal).astype(jnp.int8)
 
 
-def index_loss(scores, probs, selection):
-    """``KL(p / sum p || softmax over the selection of I)`` a row, its mean
-    over a sequence's rows: [B]. scores, probs [B, S, S] float32 (probs:
-    ``head_probs``, no gradient), selection [B, S, S]."""
+def _kl(scores, probs, selection):
+    """``index_loss`` as autodiff reads it."""
     chosen = selection != 0
     masked = jnp.where(chosen, scores, -jnp.inf)
     log_q = masked - jax.scipy.special.logsumexp(masked, -1, keepdims=True)
-    p = jax.lax.stop_gradient(probs)
-    p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+    p = probs / jnp.maximum(probs.sum(-1, keepdims=True), 1e-30)
     terms = jnp.where(chosen & (p > 0),
                       p * (jnp.log(jnp.maximum(p, 1e-38))
                            - jnp.where(chosen, log_q, 0.0)), 0.0)
     return terms.sum(-1).mean(-1)
+
+
+@jax.custom_vjp
+def index_loss(scores, probs, selection):
+    """``KL(p / sum p || softmax over the selection of I)`` a row, its mean
+    over a sequence's rows: [B]. scores, probs [B, S, S] float32 (probs:
+    ``head_probs``, no gradient), selection [B, S, S]. Differentiable in
+    the scores, by a rule that makes the gradient in the forward pass:
+    ``(softmax over the selection of I - p / sum p) / S`` on the selection
+    and zero off it, as autodiff writes it, under ``LOSS_GRADIENT_NAME``.
+    The backward pass is that array times the loss's cotangent a batch row
+    and reads nothing else of the block."""
+    return _kl(scores, probs, selection)
+
+
+def _index_loss_fwd(scores, probs, selection):
+    loss, pulled = jax.vjp(lambda s: _kl(s, probs, selection), scores)
+    # A batch row's loss reads its own scores alone.
+    by_scores, = pulled(jnp.ones_like(loss))
+    return loss, checkpoint_name(by_scores, LOSS_GRADIENT_NAME)
+
+
+def _index_loss_bwd(by_scores, g):
+    return by_scores * g[:, None, None], None, None
+
+
+index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 
 # -- the main attention over the selection: jax.numpy ---------------------

@@ -199,10 +199,12 @@ def test_the_glm_cells_step_runs_its_kernels_as_counted(topo):
     by the layers its configuration runs: the selection's forward kernel
     twice a layer (S = 4096 is under 32 x 256, its outputs are not worth
     keeping: ``flash_attention.worth_keeping``), the two backward kernels
-    once a layer, the head-summed probabilities twice a layer that owns an
-    indexer (its loss is part of the rematerialised block) and so the
-    indexer's forward kernel, its backward kernel once, the share's way
-    back to tokens in the expert layers, and no causal flash kernel."""
+    once a layer, the head-summed probabilities once a layer that owns an
+    indexer (its loss is part of the rematerialised block, and ``"full"``
+    keeps the one array the loss's backward pass reads:
+    ``dsa.LOSS_GRADIENT_NAME``) and so the indexer's forward kernel, its
+    backward kernel once, the share's way back to tokens in the expert
+    layers, and no causal flash kernel."""
     from ray_tpu.parallel.collectives import kernel_census
     cell = "glm-5.2-1chip.steady"
     _, _, jaxpr = _a_cells_step(topo, cell)
@@ -218,8 +220,8 @@ def test_the_glm_cells_step_runs_its_kernels_as_counted(topo):
     assert {name: n for name, n in census.items() if name and name.startswith(
         ("dsa_", "flash_"))} == {
         "dsa_fwd": 2 * len(layers), "dsa_bwd_dq": len(layers),
-        "dsa_bwd_dkv": len(layers), "dsa_probs": 2 * owners,
-        "dsa_index_fwd": 2 * owners, "dsa_index_bwd": owners}
+        "dsa_bwd_dkv": len(layers), "dsa_probs": owners,
+        "dsa_index_fwd": owners, "dsa_index_bwd": owners}
     assert census["moe_rows_to_tokens"] >= 4
 
 

@@ -4,18 +4,23 @@ gradients, the
 selection against ``jax.lax.top_k`` on whole rows (exactly ``min(t + 1, k)``
 keys a row, all causal), the three kernels of the attention over a selection
 (interpreted) against ``dot_attention`` with the same mask at heads of 256 |
-256, the head-summed probabilities against a softmax written out, and the
-indexer's loss and its gradient."""
+256, the head-summed probabilities against a softmax written out, the indexer's
+loss and its gradient, and what a rematerialised block that holds all of
+them keeps by name (``lm.rematerialised``): the loss's own rule against
+autodiff without remat, and the calls its backward pass makes."""
 
 import sys
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import lm
 from ray_tpu.ops import dsa
+from ray_tpu.parallel.collectives import kernel_census
 
 B, S, H, D = 2, 256, 2, 256
 HEADS, WIDTH = 32, 16   # the indexer's: with few heads whole scores tie at 0
@@ -263,6 +268,145 @@ def test_the_index_loss_is_the_kl_and_its_gradient_the_difference():
         jnp.where(CAUSAL, scores, 0.0))
     np.testing.assert_allclose(
         grad, np.where(chosen, np.exp(log_q) - p, 0.0) / S, atol=1e-6)
+
+
+def _kl(scores, probs, selection):
+    """The indexer's loss as autodiff reads it, with no rule of its own."""
+    chosen = selection != 0
+    masked = jnp.where(chosen, scores, -jnp.inf)
+    log_q = masked - jax.scipy.special.logsumexp(masked, -1, keepdims=True)
+    p = jax.lax.stop_gradient(probs)
+    p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+    terms = jnp.where(chosen & (p > 0),
+                      p * (jnp.log(jnp.maximum(p, 1e-38))
+                           - jnp.where(chosen, log_q, 0.0)), 0.0)
+    return terms.sum(-1).mean(-1)
+
+
+def _indexed_block(kernels: bool, loss):
+    """A layer that owns an indexer, as ``models/glm_moe_dsa.py`` and
+    ``models/dots3_note.py`` write it: (iq, ik, iw, q, k, v) -> (the main
+    attention's output, the indexer's loss [B])."""
+    def block(iq, ik, iw, q, k, v):
+        scores = (dsa.index_scores if kernels
+                  else dsa.dot_index_scores)(iq, ik, iw)
+        selection = checkpoint_name(
+            dsa.select(jax.lax.stop_gradient(scores), 48),
+            dsa.SELECTION_NAME)
+        if kernels:
+            out, lse = dsa.selected_attention(q, k, v, selection, 128, 128,
+                                              None)
+        else:
+            out, lse = dsa.dot_selected_attention(q, k, v, selection)
+        target = [jax.lax.stop_gradient(a) for a in (q, k, lse)]
+        probs = dsa.head_probs(*target, selection, 128, 128) if kernels \
+            else dsa.dot_head_probs(*target, selection)
+        return out, loss(scores, probs, selection)
+    return block
+
+
+def _block_total(block):
+    """A scalar of both outputs, the batch rows' losses weighted apart."""
+    def total(*operands):
+        out, loss = block(*operands)
+        return jnp.square(out).mean() + (loss * jnp.array([1.0, 2.5])).sum()
+    return total
+
+
+_FULL = SimpleNamespace(remat=True, remat_policy="full")
+_BLOCK_GRADS = {}
+
+
+def _block_operands():
+    return (*_indexer(), *_qkv()[:3])
+
+
+@pytest.mark.parametrize("wrt", [0, 1, 2], ids=["dq", "dk", "dw"])
+@pytest.mark.parametrize("kernels", [True, False], ids=["kernels", "dot"])
+def test_a_rematerialised_blocks_index_gradients_are_autodiffs(kernels, wrt):
+    """The loss's own rule under ``lm.rematerialised`` against autodiff of
+    the loss written out, without remat: the value, and the gradient by the
+    indexer's q, k and w. An implementation's three gradients are one
+    backward pass each side, made by its first test."""
+    if kernels not in _BLOCK_GRADS:
+        operands = _block_operands()
+        _BLOCK_GRADS[kernels] = [jax.value_and_grad(
+            _block_total(block), (0, 1, 2))(*operands) for block in (
+                lm.rematerialised(_FULL, _indexed_block(
+                    kernels, dsa.index_loss)),
+                _indexed_block(kernels, _kl))]
+    (got_value, got), (want_value, want) = _BLOCK_GRADS[kernels]
+    np.testing.assert_allclose(got_value, want_value, rtol=1e-6)
+    assert np.abs(np.asarray(want[wrt])).max() > 1e-3
+    np.testing.assert_allclose(got[wrt], want[wrt], atol=1e-6 * float(
+        jnp.abs(want[wrt]).max()))
+
+
+def test_a_rematerialised_block_runs_the_loss_once():
+    """What ``"full"`` keeps by name of a layer with an indexer: the
+    selection and the loss's gradient by the scores. The backward pass then
+    holds neither the head-summed probabilities nor the scores' forward
+    kernel a second time (the main attention's forward kernel it does, at a
+    length below ``worth_keeping``); without the loss's name it holds
+    both."""
+    operands = _block_operands()
+    block = _indexed_block(True, dsa.index_loss)
+
+    def calls(rematerialised):
+        return kernel_census(jax.make_jaxpr(jax.grad(
+            _block_total(rematerialised), (0, 1, 2, 3, 4, 5)))(*operands))
+
+    assert calls(lm.rematerialised(_FULL, block)) == {
+        "dsa_index_fwd": 1, "dsa_index_bwd": 1, "dsa_probs": 1,
+        "dsa_fwd": 2, "dsa_bwd_dq": 1, "dsa_bwd_dkv": 1}
+    selection_alone = jax.checkpoint(
+        block, policy=jax.checkpoint_policies.save_only_these_names(
+            dsa.SELECTION_NAME))
+    assert calls(selection_alone) == {
+        "dsa_index_fwd": 2, "dsa_index_bwd": 1, "dsa_probs": 2,
+        "dsa_fwd": 2, "dsa_bwd_dq": 1, "dsa_bwd_dkv": 1}
+
+
+def test_the_index_losss_cotangent_scales_a_batch_row():
+    scores = dsa.dot_index_scores(*_indexer())
+    selection = dsa.select(scores, 48)
+    probs = jnp.where(selection != 0, jax.random.uniform(
+        jax.random.PRNGKey(5), scores.shape), 0.0)
+    loss, pulled = jax.vjp(lambda s: dsa.index_loss(s, probs, selection),
+                           scores)
+    assert loss.shape == (B,)
+    ones, = pulled(jnp.ones(B))
+    got, = pulled(jnp.array([3.0, -0.5]))
+    assert np.asarray(ones)[:, 1:].any(-1).all()   # row 0 has one key
+    np.testing.assert_array_equal(got[0], 3.0 * ones[0])
+    np.testing.assert_array_equal(got[1], -0.5 * ones[1])
+    want, = jax.vjp(lambda s: _kl(s, probs, selection),
+                    scores)[1](jnp.array([3.0, -0.5]))
+    np.testing.assert_allclose(got, want, atol=1e-6 * float(
+        jnp.abs(want).max()))
+
+
+def test_a_family_without_an_indexer_lowers_to_what_it_did(monkeypatch):
+    """One more name in the policy changes no program that does not emit
+    it: a ``deepseek_v3`` model's gradient lowers to the text it lowers to
+    under the policy without ``LOSS_GRADIENT_NAME``."""
+    from ray_tpu.models import deepseek
+    from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+    cfg = deepseek.config("deepseek-tiny", remat=True)
+    params = jax.eval_shape(lambda: deepseek.init(cfg,
+                                                  jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+
+    def lowered():
+        return jax.jit(jax.grad(lambda p, t: deepseek.loss_fn(
+            p, cfg, t, t)[0])).lower(params, tokens).as_text()
+
+    got = lowered()
+    monkeypatch.setattr(lm, "rematerialised", lambda cfg, block: (
+        jax.checkpoint(
+            block, policy=jax.checkpoint_policies.save_only_these_names(
+                *RESIDUAL_NAMES, dsa.SELECTION_NAME))))
+    assert dsa.LOSS_GRADIENT_NAME not in got and got == lowered()
 
 
 def test_a_ragged_sequence_is_refused():
