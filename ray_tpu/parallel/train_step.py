@@ -212,21 +212,24 @@ class _DeferredRecorder:
     read once they are ready, or by the next call at the latest (that step
     is then queued behind them on the device, so the read waits for nothing
     the device would not do anyway). The newest call's may therefore still
-    be pending when the loop ends."""
+    be pending when the loop ends. A name the step does not return is
+    passed over: a family's members may differ in what they have to record
+    (``models/granite.py``: expert layers or none)."""
 
     def __init__(self, recorded: Dict[str, Callable[[float], None]]) -> None:
         self.recorded = recorded
         self.pending: list = []
 
     def __call__(self, out) -> None:
-        scalars = [out[1][name] for name in self.recorded]
-        for scalar in scalars:
+        scalars = [(record, out[1][name])
+                   for name, record in self.recorded.items()
+                   if name in out[1]]
+        for _, scalar in scalars:
             scalar.copy_to_host_async()
         self.pending.append(scalars)
         while self.pending and (len(self.pending) > 1 or all(
-                scalar.is_ready() for scalar in self.pending[0])):
-            for record, scalar in zip(self.recorded.values(),
-                                      self.pending.pop(0)):
+                scalar.is_ready() for _, scalar in self.pending[0])):
+            for record, scalar in self.pending.pop(0):
                 record(float(scalar))
 
 

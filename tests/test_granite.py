@@ -100,8 +100,9 @@ def test_a_dropped_term_shows(both, dropped):
 
 def _published_logits(cfg, params, tokens):
     """``transformers``' ``GraniteMoeHybridForCausalLM`` (``torch_forward``,
-    the chunked form, chunks of 64) on the program's parameters: logits [B,
-    S, vocab]."""
+    the chunked form, chunks of 64) on the program's parameters, with the
+    experts where ``cfg`` has them (``tests/test_granite_moe.py``): logits
+    [B, S, vocab]."""
     torch = pytest.importorskip("torch")
     pytest.importorskip("transformers")
     try:
@@ -111,7 +112,7 @@ def _published_logits(cfg, params, tokens):
         pytest.skip("this transformers has no granitemoehybrid")
     hf_config = GraniteMoeHybridConfig(
         vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
-        intermediate_size=cfg.shared_intermediate_size,
+        intermediate_size=cfg.intermediate_size,
         shared_intermediate_size=cfg.shared_intermediate_size,
         num_hidden_layers=cfg.num_hidden_layers,
         layer_types=list(cfg.layers),
@@ -120,8 +121,10 @@ def _published_logits(cfg, params, tokens):
         attention_multiplier=cfg.attention_multiplier,
         embedding_multiplier=cfg.embedding_multiplier,
         residual_multiplier=cfg.residual_multiplier,
-        logits_scaling=cfg.logits_scaling, num_local_experts=0,
-        num_experts_per_tok=0, mamba_n_heads=cfg.mamba_n_heads,
+        logits_scaling=cfg.logits_scaling,
+        num_local_experts=cfg.num_local_experts,
+        num_experts_per_tok=cfg.num_experts_per_tok,
+        mamba_n_heads=cfg.mamba_n_heads,
         mamba_d_head=cfg.mamba_d_head, mamba_d_state=cfg.mamba_d_state,
         mamba_n_groups=cfg.mamba_n_groups, mamba_d_conv=cfg.mamba_d_conv,
         mamba_expand=cfg.mamba_expand, mamba_chunk_size=64,
@@ -147,6 +150,14 @@ def _published_logits(cfg, params, tokens):
         state[pre + "post_attention_layernorm.weight"] = t(w["ln2_scale"])
         state[pre + "shared_mlp.input_linear.weight"] = t(w["mlp_in"].T)
         state[pre + "shared_mlp.output_linear.weight"] = t(w["mlp_out"].T)
+        if cfg.num_local_experts:
+            # input_linear [experts, 2 f, d]: the gated half first.
+            state[pre + "block_sparse_moe.input_linear.weight"] = t(
+                jnp.concatenate([w["w_gate"], w["w_up"]], -1).swapaxes(1, 2))
+            state[pre + "block_sparse_moe.output_linear.weight"] = t(
+                w["w_down"].swapaxes(1, 2))
+            state[pre + "block_sparse_moe.router.layer.weight"] = t(
+                w["router"].T)
         if kind == "mamba":
             state[pre + "mamba.in_proj.weight"] = t(w["w_in"].T)
             state[pre + "mamba.conv1d.weight"] = t(w["conv_w"].T[:, None, :])
@@ -348,7 +359,15 @@ def test_state_space_runs_per_shard_under_a_mesh():
 
 def test_config_refuses_what_the_program_does_not_compute():
     with pytest.raises(NotImplementedError):
-        replace(CFG, num_local_experts=8)
+        replace(CFG, tie_word_embeddings=False)
+    # Experts are computed since PR 68 (tests/test_granite_moe.py): the
+    # configuration is accepted, and holds every expert unless told its
+    # share.
+    accepted = replace(CFG, num_local_experts=8, num_experts_per_tok=2)
+    assert accepted.n_moe_layers == 4 and accepted.experts_held is None
+    assert replace(accepted, experts_held=[2, 3]).experts_held == (2, 3)
+    with pytest.raises(ValueError):
+        replace(CFG, num_local_experts=8)       # 0 experts a token
     with pytest.raises(ValueError):
         replace(CFG, mamba_n_groups=3)
     with pytest.raises(ValueError):

@@ -21,7 +21,10 @@ nine's as they were; ``nemotron_h``'s by PR 62, the other ten's as they were
 (granite's and phi's ``A_log`` is drawn by ``lm.log_arange``, the function
 their modules held); ``dots3_note``'s by PR 64, the other eleven's as they
 were (``lm.mla_leaves`` takes the latent's geometry as a value and draws
-Moonlight's, Kimi Linear's and GLM's leaves where it did).
+Moonlight's, Kimi Linear's and GLM's leaves where it did); ``granite_moe``'s
+(``models/granite.py`` with experts: ``granite-moe-tiny``) by PR 68, the
+other twelve's as they were (a granite layer's expert leaves are drawn after
+its other leaves, and a model without experts has none).
 
 A PR that changes a family's draw on purpose records them again and says so;
 one that does not must leave this file alone."""
@@ -38,7 +41,10 @@ FAMILIES = {"deepseek": "deepseek-tiny", "granite": "granite-tiny",
             "lfm2": "lfm2-tiny", "phi4flash": "phi4flash-tiny",
             "glm_moe_dsa": "glm-tiny", "evabyte": "evabyte-tiny",
             "minicpm_sala": "minicpm-sala-tiny", "mellum": "mellum-tiny",
-            "nemotron_h": "nemotron-h-tiny", "dots3_note": "dots3-tiny"}
+            "nemotron_h": "nemotron-h-tiny", "dots3_note": "dots3-tiny",
+            "granite_moe": "granite-moe-tiny"}
+#: A family's second member: the module that holds it.
+MODULES = {"granite_moe": "granite"}
 PINNED = pathlib.Path(__file__).with_name("init_pinned.json")
 
 
@@ -54,7 +60,8 @@ def _leaves(family: str):
     """{"run/leaf": [shape, dtype, checksum]} of the family's tiny preset
     from ``PRNGKey(0)``, in the tree's own order."""
     import jax
-    model = importlib.import_module(f"ray_tpu.models.{family}")
+    model = importlib.import_module(
+        f"ray_tpu.models.{MODULES.get(family, family)}")
     params = model.init(model.config(FAMILIES[family]), jax.random.PRNGKey(0))
     flat, _ = jax.tree_util.tree_flatten_with_path(params)
     return {"/".join(str(key.key) for key in path):
