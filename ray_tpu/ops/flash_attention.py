@@ -22,8 +22,9 @@ diagonal are, and a tile the trailing edge cuts is masked as one the
 diagonal cuts (``window_tile_census``). With ``window=None``, or a window
 the sequence does not reach, tables, kernels and their names are the causal
 ones; a call with a window that cuts something carries names of its own
-(``flash_fwd_win``, ``flash_bwd_dq_win``, ``flash_bwd_dkv_win``), so that a
-trace tells a window layer's kernels from a full layer's.
+(``flash_fwd_win``, ``flash_bwd_win``; ``flash_bwd_dq_win`` and
+``flash_bwd_dkv_win`` where the backward runs as the pair), so that a trace
+tells a window layer's kernels from a full layer's.
 
 The tables can say more than those two edges, and what they say is static
 (a function of S and a layer's sizes, nothing of the data). ``Summaries``
@@ -54,17 +55,36 @@ was what kept the forward at 18-38 % of its roofline
 (``_flash_fwd_kernel`` has the chip's table of us a tile). Every sum keeps
 its order and every operand its type: ``out`` and ``lse`` are what one-lane
 statistics gave, bit for bit, at every head size. Nothing of length S is
-ever resident in VMEM, so the sequence length is bounded by HBM alone, and
-q/k may have another head size (D) than v and the output (Dv): latent
-attention trains with D = 192, Dv = 128.
+resident in VMEM in the forward, and q/k may have another head size (D) than
+v and the output (Dv): latent attention trains with D = 192, Dv = 128.
 
-Backward is the standard two-kernel FlashAttention scheme: a dQ kernel
-(the forward's table) and a dK/dV kernel (the table KV-major, Q tiles
-ascending from the first that sees the KV tile), both bound by their
-products (they know the shift, ``lse``, beforehand) and both recomputing
-probabilities from q, k and the saved logsumexp: O(S) memory, no S x S
-tensor ever materializes in HBM. The one dispatch is models/lm.py
-``attention``; every model reaches the kernels through it.
+Backward is one kernel, ``_flash_bwd_kernel`` (``flash_bwd``,
+``flash_bwd_win``), wherever a head's dq fits VMEM beside the tiles, which
+is every shape the benchmark has: it walks the table KV-major (Q tiles
+ascending from the first that sees the KV tile), recomputes a tile's
+probabilities once from q, k and the saved logsumexp (it knows the shift
+beforehand, so it is bound by its products), and from the one ``p`` and the
+one ``ds`` adds ``p^T dO`` into dv and ``ds^T q`` into dk, written when the
+KV tile's row ends, and ``ds k`` into the Q tile's part of dq: **five
+products a tile**. What is resident across a head's pairs is dq, [S, D]
+float32 (D up to a whole 128 lanes: 16 MiB at 32768 x 64 and at 16384 x
+192) and its result's block in the operands' dtype, zeroed at the head's
+first pair and scaled and cast once at its last; the call states
+``_BWD_VMEM_LIMIT`` (64 MiB) for it. Where that does not fit
+(``one_backward_kernel``, a pure function of the shapes: from S = 65536 at
+heads of 128 in bfloat16), backward is the standard two-kernel
+FlashAttention scheme under the names ``flash_bwd_dq`` (the forward's
+table) and ``flash_bwd_dkv`` (the KV-major table): **seven products a
+tile pair**, ``q k^T``, ``exp`` and ``dO v^T`` made twice and the six
+operands' tiles fetched twice, but nothing of length S resident, so there
+the sequence length is bounded by HBM alone. Both forms keep every sum's
+order (for a fixed Q tile the KV tiles arrive ascending in either walk) and
+every operand's type: dq, dk and dv are the same to the bit
+(tests/test_flash_window.py). Either way: O(S) memory in HBM, no S x S
+tensor ever materializes there. ``_flash_bwd_kernel`` has the chip's table
+of us a tile for the three kernels. ``ops/eva.py`` builds its own pair from
+``_flash_bwd_dq_kernel`` and ``_flash_bwd_dkv_kernel``. The one dispatch is
+models/lm.py ``attention``; every model reaches the kernels through it.
 
 On non-TPU backends the kernels run in interpreter mode so the same code
 path is testable on the CPU mesh (SURVEY.md §4: fake-TPU strategy), and
@@ -352,6 +372,36 @@ def worth_keeping(S: int, Dv: int, window=None) -> bool:
     return (S if window is None else min(S, window)) >= 32 * Dv
 
 
+# What ``_flash_bwd_kernel``'s call states as its ``vmem_limit_bytes``, half
+# of a v5e core's 128 MiB (``ops/moe.py`` states as much for its kernel).
+_BWD_VMEM_LIMIT = 64 << 20
+
+
+def one_backward_kernel(S: int, D: int, Dv: int, blk_q: int, blk_k: int,
+                        itemsize: int = 2) -> bool:
+    """Whether the backward pass runs as one kernel (``flash_bwd``: a head's
+    whole dq resident in VMEM beside the tiles) and not as the pair
+    (``flash_bwd_dq``, ``flash_bwd_dkv``: nothing of length S resident): a
+    pure function of the shapes, true where what the kernel holds fits
+    ``_BWD_VMEM_LIMIT``. Counted at a lane's 128 columns and, for the
+    operands' and results' tiles, two buffers each of ``itemsize`` bytes:
+    dq's float32 accumulator [S, D] and its result's block [S, D], the six
+    operands' tiles, dk's and dv's blocks and accumulators, and eight
+    [blk_q, blk_k] float32 temporaries (logits, p, dp, ds, the mask, two
+    transposes and room). True at every benchmark cell's shape (the
+    largest, 32768 x 64 and 16384 x 192, hold 32 MiB of dq and 9 MiB of the
+    rest); false from S = 65536 at D = 128 in bfloat16."""
+    def lanes(d):
+        return -(-d // 128) * 128
+
+    width = lanes(D) + lanes(Dv)
+    dq = S * lanes(D) * (4 + 2 * itemsize)
+    tiles = 2 * itemsize * ((blk_q + 2 * blk_k) * width + 2 * 8 * blk_q)
+    accumulators = 4 * blk_k * width
+    temporaries = 8 * 4 * blk_q * blk_k
+    return dq + tiles + accumulators + temporaries <= _BWD_VMEM_LIMIT
+
+
 def _row_ends(row_tab):
     """(first, last): whether this grid step opens / closes its row of
     tiles, a run of equal entries in ``row_tab``."""
@@ -503,10 +553,12 @@ def _flash_bwd_dq_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref,
 def _flash_bwd_dkv_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref,
                           lse_ref, delta_ref, dk_ref, dv_ref, dk_scr,
                           dv_scr, *, blk_q: int, blk_k: int, causal: bool,
-                          scale: float, window=None):
+                          scale: float, window=None, dq_of=None):
     """Grid: (batch*heads, pairs), KV-major, the pair axis sequential:
     dk/dv for one KV tile, accumulated over the Q, dO, lse and delta tiles
-    of its row (those at or after the diagonal when causal)."""
+    of its row (those at or after the diagonal when causal). ``dq_of``
+    (``_flash_bwd_kernel``'s) is called with the tile's ``ds`` and k, both
+    float32, once they are there and before the row's closing write."""
     t = pl.program_id(1)
     qi, ki = qi_tab[t], ki_tab[t]
     first, last = _row_ends(ki_tab)
@@ -537,11 +589,70 @@ def _flash_bwd_dkv_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref,
     dk_scr[...] += jax.lax.dot_general(
         ds, q_blk, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+    if dq_of is not None:
+        dq_of(ds, k)
 
     @pl.when(last)
     def _():
         dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _flash_bwd_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref, lse_ref,
+                      delta_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr,
+                      dv_scr, *, scale: float, **static):
+    """Grid: (batch*heads, pairs), KV-major as ``_flash_bwd_dkv_kernel``'s,
+    the pair axis sequential: dk/dv for one KV tile accumulated over its row
+    of Q tiles, and beside them, from the same ``p`` and ``ds``, every Q
+    tile's ``ds k`` added into a head's whole dq, ``dq_scr`` [S / blk_q,
+    blk_q, D] float32, resident across the pair axis (zeroed at a head's
+    first pair; scaled, cast and written to ``dq_ref``, whose block is the
+    head's and holds still over the axis, at its last). For a fixed Q tile
+    the KV tiles arrive ascending, as in ``_flash_bwd_dq_kernel``'s row, so
+    dq, dk and dv are the pair's, bit for bit.
+
+    us a 512 x 512 tile on a v5e, the kernels alone at the benchmark
+    cells' shapes, median of ten calls under one trace (PERF.md §6, PR 69;
+    dq, dk and dv equal to the bit on the chip as on the CPU interpreter):
+
+    =================  =====  ========  =====  ===========  =========  =====
+    D | Dv                64  64 | 128    128  128, window  192 | 128    256
+    =================  =====  ========  =====  ===========  =========  =====
+    ``flash_bwd_dq``    1.35      1.34   1.34         1.35       2.04   2.41
+    ``flash_bwd_dkv``   1.93      1.93   1.92         2.00       2.55   3.25
+    the pair            3.28      3.27   3.26         3.35       4.59   5.66
+    ``flash_bwd``       2.35      2.34   2.33         2.43       3.31   4.04
+    =================  =====  ========  =====  ===========  =========  =====
+
+    28 % off the pair at every head size: two of seven products, one
+    ``exp``, one fetch of the six tiles and one grid step. With the fifth
+    product behind the row's closing write (a conditional region, which the
+    scheduler does not move a product across) a tile cost 0.08-0.10 us
+    more at every shape: ``dq_of`` is called ahead of it.
+    """
+    t, last_t = pl.program_id(1), pl.num_programs(1) - 1
+    n_q = dq_scr.shape[0]
+
+    @pl.when(t == 0)
+    def _():
+        def zero(i, _):
+            dq_scr[i] = jnp.zeros(dq_scr.shape[1:], jnp.float32)
+        jax.lax.fori_loop(0, n_q, zero, None)
+
+    def add_dq(ds, k):
+        dq_scr[qi_tab[t]] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _flash_bwd_dkv_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, g_ref, lse_ref,
+                          delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+                          scale=scale, dq_of=add_dq, **static)
+
+    @pl.when(t == last_t)
+    def _():
+        def write(i, _):
+            dq_ref[i] = (dq_scr[i] * scale).astype(dq_ref.dtype)
+        jax.lax.fori_loop(0, n_q, write, None)
 
 
 def _repeat_heads(k, v, n_heads):
@@ -584,8 +695,12 @@ def _q_row(b, t, qi_tab, ki_tab):
     return b, 0, qi_tab[t]
 
 
+def _head(b, t, qi_tab, ki_tab):
+    return b, 0, 0, 0
+
+
 def _tiled_call(kernel, name: str, batch_heads: int, pairs, in_specs,
-                out_specs, out_shape, scratch_shapes):
+                out_specs, out_shape, scratch_shapes, vmem_limit_bytes=None):
     """One kernel over the grid (batch*heads, pairs), to be called on its
     operands: the first axis parallel, the pair axis sequential, the two
     tables scalar-prefetched."""
@@ -598,7 +713,8 @@ def _tiled_call(kernel, name: str, batch_heads: int, pairs, in_specs,
             scratch_shapes=scratch_shapes),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=_interpret(),
         name=name,
     )
@@ -683,7 +799,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
                     axis=-1)[:, None, :]  # [BH, 1, S]
     common = dict(blk_q=blk_q, blk_k=blk_k, causal=causal, scale=scale,
                   window=window)
-    # The six operands of both backward kernels, tiled alike in both.
+    # The six operands of every backward kernel, tiled alike in all.
     operands = (qf, kf, vf, gf, lse, delta)
     in_specs = [
         pl.BlockSpec((None, blk_q, D), _q_tile),
@@ -693,32 +809,45 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, blk_q: int,
         pl.BlockSpec((None, 1, blk_q), _q_row),
         pl.BlockSpec((None, 1, blk_q), _q_row),
     ]
-    dq = _tiled_call(
-        functools.partial(_flash_bwd_dq_kernel, **common),
-        _named("flash_bwd_dq", window), B * H,
-        _tile_pairs(S, blk_q, blk_k, causal, False, window),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, blk_q, D), _q_tile),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
-    )(*operands)
-
-    dk, dv = _tiled_call(
-        functools.partial(_flash_bwd_dkv_kernel, **common),
-        _named("flash_bwd_dkv", window), B * H,
-        _tile_pairs(S, blk_q, blk_k, causal, True, window),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, blk_k, D), _kv_tile),
-            pl.BlockSpec((None, blk_k, Dv), _kv_tile),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, S, Dv), v.dtype),
-        ],
+    kv_major = _tile_pairs(S, blk_q, blk_k, causal, True, window)
+    kv_out = dict(
+        out_specs=[pl.BlockSpec((None, blk_k, D), _kv_tile),
+                   pl.BlockSpec((None, blk_k, Dv), _kv_tile)],
+        out_shape=[jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
+                   jax.ShapeDtypeStruct((B * H, S, Dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((blk_k, D), jnp.float32),
-                        pltpu.VMEM((blk_k, Dv), jnp.float32)],
-    )(*operands)
+                        pltpu.VMEM((blk_k, Dv), jnp.float32)])
+    if one_backward_kernel(S, D, Dv, blk_q, blk_k, q.dtype.itemsize):
+        n_q = S // blk_q
+        dq, dk, dv = _tiled_call(
+            functools.partial(_flash_bwd_kernel, **common),
+            _named("flash_bwd", window), B * H, kv_major,
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((None, n_q, blk_q, D), _head)]
+            + kv_out["out_specs"],
+            out_shape=[jax.ShapeDtypeStruct((B * H, n_q, blk_q, D), q.dtype)]
+            + kv_out["out_shape"],
+            scratch_shapes=[pltpu.VMEM((n_q, blk_q, D), jnp.float32)]
+            + kv_out["scratch_shapes"],
+            vmem_limit_bytes=_BWD_VMEM_LIMIT,
+        )(*operands)
+        dq = dq.reshape(B * H, S, D)
+    else:
+        # A head's dq does not fit beside the tiles: the pair, with nothing
+        # of length S resident.
+        dq = _tiled_call(
+            functools.partial(_flash_bwd_dq_kernel, **common),
+            _named("flash_bwd_dq", window), B * H,
+            _tile_pairs(S, blk_q, blk_k, causal, False, window),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, blk_q, D), _q_tile),
+            out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+            scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
+        )(*operands)
+        dk, dv = _tiled_call(
+            functools.partial(_flash_bwd_dkv_kernel, **common),
+            _named("flash_bwd_dkv", window), B * H, kv_major,
+            in_specs=in_specs, **kv_out)(*operands)
 
     dq = _from_bh(dq, B, H)
     dk = _from_bh(dk, B, H)

@@ -77,6 +77,44 @@ def _attend_loss(q, k, v):
     return _attend(q, k, v).astype(jnp.float32).sum()
 
 
+# The flash kernels' operands at every benchmark cell's attention: (B, S, H,
+# D), KV heads, Dv, window.
+CELL_ATTENTION = {
+    "gptj-6b": ((8, 2048, 16, 256), 16, 256, None),
+    "gptj-6b, a shard of fsdp=2 x tp=2": ((8, 2048, 8, 256), 8, 256, None),
+    "moonlight-16b-a3b": (MLA_SHAPE, 16, MLA_V, None),
+    "granite-4.0-h-micro": ((1, 32768, 32, 64), 8, 64, None),
+    "trinity-large-preview, full layer": ((1, 16384, 48, 128), 8, 128, None),
+    "trinity-large-preview, window layer": ((1, 16384, 48, 128), 8, 128,
+                                            4096),
+    "kimi-linear-48b-a3b, latent layer": ((1, 16384, 32, 192), 32, 128,
+                                          None),
+    "lfm2-24b-a2b": ((4, 8192, 32, 64), 8, 64, None),
+    "phi-4-mini-flash-reasoning, full and cross layers":
+        ((1, 16384, 40, 64), 40, 128, None),
+    "phi-4-mini-flash-reasoning, window layer":
+        ((1, 16384, 40, 64), 40, 128, 512),
+    "mellum2-12b-a2.5b, full layer": ((1, 16384, 32, 128), 4, 128, None),
+    "mellum2-12b-a2.5b, window layer": ((1, 16384, 32, 128), 4, 128, 1024),
+    "nemotron-3-nano-30b-a3b": ((1, 16384, 32, 128), 2, 128, None),
+    "dots3-note-prev, window layer": ((1, 8192, 64, 256), 64, 128, 513),
+    "granite-4.0-h-small": ((1, 16384, 32, 128), 8, 128, None),
+    # No cell's: lane-dense statistics over an output of one and a half
+    # lane tiles.
+    "heads of 192": ((2, 4096, 8, 192), 8, 192, None),
+}
+
+
+def _cell_attention(cell, sharding=None):
+    """(q, k, v, window): a ``CELL_ATTENTION`` entry's abstract operands
+    and the forward's window."""
+    shape, kv_heads, v_dim, window = CELL_ATTENTION[cell]
+    q, k, v = (jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=sharding)
+               for s in (shape, shape[:2] + (kv_heads, shape[3]),
+                         shape[:2] + (kv_heads, v_dim)))
+    return q, k, v, window
+
+
 #: tokens, choices a token, width, rows of a buffer: what ``_to_tokens``
 #: is given in the three cells that hold a share of the experts.
 SHARE_SHAPES = {
@@ -84,6 +122,21 @@ SHARE_SHAPES = {
     "kimi-linear-48b-a3b-1chip.steady": (16384, 8, 2304, 32768),
     "trinity-large-preview-1chip.steady": (16384, 4, 3072, 4096),
 }
+
+
+def the_pair_for_each_backward(census):
+    """A step's ``kernel_census`` under the names the benchmark's counts
+    still carry (``benchmark/flops_*.py`` ``step_kernel_calls``, a
+    ``benchmark`` PR's to rename): since PR 69 a layer's backward is one
+    ``flash_bwd`` / ``flash_bwd_win`` call where the counts have a
+    ``flash_bwd_dq`` and a ``flash_bwd_dkv`` (``_win``) each."""
+    census = dict(census)
+    for suffix in ("", "_win"):
+        calls = census.pop("flash_bwd" + suffix, None)
+        if calls:
+            census["flash_bwd_dq" + suffix] = calls
+            census["flash_bwd_dkv" + suffix] = calls
+    return census
 
 
 def _lowered_digest(step, args):

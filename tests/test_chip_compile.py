@@ -173,7 +173,9 @@ def test_the_phi4flash_cells_step_runs_its_kernels_as_counted(topo):
     once (8 + 4 at three self pairs and the middle pair); the window layers'
     flash forward twice (63 tiles a head: its outputs are not worth
     keeping, ``flash_attention.worth_keeping``) and the full and the cross
-    layers' once; every backward kernel once a layer."""
+    layers' once; every backward kernel once a layer (attention's one,
+    ``flash_bwd``, where ``flash_bwd_dq`` and ``flash_bwd_dkv`` were one
+    each until PR 69)."""
     from ray_tpu.parallel.collectives import kernel_census
     cell = "phi-4-mini-flash-reasoning-1chip.steady"
     _, _, jaxpr = _a_cells_step(topo, cell)
@@ -189,9 +191,8 @@ def test_the_phi4flash_cells_step_runs_its_kernels_as_counted(topo):
     assert census == {
         "selective_scan_fwd": 2 * mamba, "selective_scan_bwd": mamba,
         "conv_silu_fwd": 2 * mamba, "conv_silu_bwd": mamba,
-        "flash_fwd_win": 2 * window, "flash_bwd_dq_win": window,
-        "flash_bwd_dkv_win": window, "flash_fwd": causal,
-        "flash_bwd_dq": causal, "flash_bwd_dkv": causal}
+        "flash_fwd_win": 2 * window, "flash_bwd_win": window,
+        "flash_fwd": causal, "flash_bwd": causal}
 
 
 def test_the_glm_cells_step_runs_its_kernels_as_counted(topo):
@@ -371,12 +372,12 @@ def test_the_lfm2_cells_compiled_step_gathers_no_slab_of_tokens(topo):
 #: granite's (``ssd_bwd``'s head loop on operands a head wide; 9f52f929b5af
 #: before it).
 LOWERED_STEPS = {
-    "gptj-6b-1chip.steady": "b470aa16aac6",
-    "gptj-6b-4chip.steady": "42d82d54bed3",
-    "moonlight-16b-a3b-1chip.steady": "7306fc08c9c0",
-    "granite-4.0-h-micro-1chip.steady": "a72eac94c094",
-    "phi-4-mini-flash-reasoning-1chip.steady": "b8326d36469b",
-    "mellum2-12b-a2.5b-1chip.steady": "b0cda0859e19",
+    "gptj-6b-1chip.steady": "11f93ff03db3",
+    "gptj-6b-4chip.steady": "367d2486e9be",
+    "moonlight-16b-a3b-1chip.steady": "9ca9faa42afe",
+    "granite-4.0-h-micro-1chip.steady": "8b588e948e41",
+    "phi-4-mini-flash-reasoning-1chip.steady": "3766d4b30ed8",
+    "mellum2-12b-a2.5b-1chip.steady": "3afaaacc42e1",
 }
 
 
@@ -393,7 +394,11 @@ def test_a_step_without_a_share_is_the_program_it_was(topo, cell):
     axis, so the kernels find a head block's group by a ``%`` and a ``//``
     and granite hands its one group over as [batch, S, 1, N]; the five
     others hold, Moonlight's and Mellum's through the expert's form
-    (``ops/moe.py`` ``activation``) too."""
+    (``ops/moe.py`` ``activation``) too. PR 69 moved all six by intent
+    (b470aa16aac6, 42d82d54bed3, 7306fc08c9c0, a72eac94c094, b8326d36469b
+    and b0cda0859e19 before it): every one attends through
+    ``ops/flash_attention.py``, whose backward is one kernel where it was
+    two."""
     step, args, _ = _a_cells_step(topo, cell)
     assert _lowered_digest(step, args) == LOWERED_STEPS[cell]
 
@@ -409,13 +414,13 @@ def test_a_cells_traced_step_runs_the_flash_forward_by_what_remat_keeps(
     traced steps, which the digests' cases have traced already: the
     forward kernel once a layer that attends where remat keeps its outputs
     (granite's 2 attention layers of 20 at S / Dv = 512, Moonlight's 1 + 3
-    at 64), twice where a kept byte buys too little (GPT-J's 10 at 8); each
-    backward kernel once."""
+    at 64), twice where a kept byte buys too little (GPT-J's 10 at 8); the
+    one backward kernel once, and nothing of the pair it replaced."""
     from ray_tpu.parallel.collectives import kernel_census
     census = kernel_census(_a_cells_step(topo, cell)[2], a_step=True)
-    assert [census[name] for name in ("flash_fwd", "flash_bwd_dq",
-                                      "flash_bwd_dkv")] == \
-        [layers if kept else 2 * layers, layers, layers]
+    assert {name: calls for name, calls in census.items()
+            if str(name).startswith("flash")} == {
+        "flash_fwd": layers if kept else 2 * layers, "flash_bwd": layers}
 
 
 def test_every_cells_step_was_loaded_and_traced_once(topo):

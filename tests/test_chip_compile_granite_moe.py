@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from chip_compile import (_lowered_digest, compile_for_tpu,  # noqa: F401
-                          flash_mod, topo)
+                          flash_mod, the_pair_for_each_backward, topo)
 from ray_tpu.ops import gated_norm, moe, short_conv, ssd
 from ray_tpu.parallel import MeshConfig, build_mesh
 from ray_tpu.parallel.collectives import kernel_census
@@ -36,7 +36,7 @@ BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
 #: The cell's lowered step (``chip_compile._lowered_digest``): a PR that
 #: means to change this program records the new value.
-LOWERED_STEP = "839633fb523f"
+LOWERED_STEP = "df9e4976030b"  # 839633fb523f until PR 69 (one flash_bwd)
 
 
 @pytest.fixture(scope="module")
@@ -191,7 +191,7 @@ def test_the_benchmarks_count_of_calls_is_the_steps(cell, benchmark_path):
     in_the_loop_too = {"moe_rows_to_tokens": calls.pop("moe_rows_to_tokens"),
                        None: calls.pop("gmm") + calls.pop("tgmm")}
     calls.update({name: 2 * n for name, n in in_the_loop_too.items()})
-    assert census == calls
+    assert the_pair_for_each_backward(census) == calls
     assert counts.keeps_forward(S, cfg.head_dim) == flash_mod.worth_keeping(
         S, cfg.head_dim)
 

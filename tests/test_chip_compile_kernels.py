@@ -13,9 +13,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from chip_compile import (MLA_SHAPE, MLA_V, SHARE_SHAPES,  # noqa: F401
-                          _attend, _attend_loss, _qkv, compile_for_tpu,
-                          flash_mod, topo)
+from chip_compile import (CELL_ATTENTION, MLA_SHAPE, MLA_V,  # noqa: F401
+                          SHARE_SHAPES, _attend, _attend_loss,
+                          _cell_attention, _qkv, compile_for_tpu, flash_mod,
+                          topo)
 
 
 # (B, S, H, D) of every head width the dense presets use, at the recorded
@@ -37,22 +38,24 @@ def test_flash_forward_compiles(topo, preset):
 
 @pytest.mark.parametrize("preset", PRESET_SHAPES)
 def test_flash_backward_compiles(topo, preset):
-    """Forward + the dq and dk/dv kernels: three Mosaic calls."""
+    """The forward and the one backward kernel: two Mosaic calls."""
+    from ray_tpu.parallel.collectives import kernel_census
     text = jax.jit(jax.grad(_attend_loss, argnums=(0, 1, 2))).lower(
         *_qkv(topo, PRESET_SHAPES[preset])).compile().as_text()
-    assert text.count("tpu_custom_call") >= 3
+    assert kernel_census(text) == {"flash_fwd": 1, "flash_bwd": 1}
 
 
 @pytest.mark.parametrize("shape,v_dim", [
     (MLA_SHAPE, MLA_V), ((2, 8192, 16, 256), 256)])
 def test_flash_compiles_at_8k_with_two_head_sizes(topo, shape, v_dim):
-    """S = 8192: K and V (in the dk/dv kernel Q and dO) of a head are
+    """S = 8192: K and V (in the backward kernel Q and dO) of a head are
     2-4 MB each and came whole into VMEM before they were streamed by the
     grid; q/k of 192 beside v of 128 is latent attention, 256 | 256 GPT-J
     at four times its context."""
+    from ray_tpu.parallel.collectives import kernel_census
     grads = jax.jit(jax.grad(_attend_loss, argnums=(0, 1, 2))).lower(
         *_qkv(topo, shape, v_dim)).compile()
-    assert grads.as_text().count("tpu_custom_call") >= 3
+    assert kernel_census(grads.as_text()) == {"flash_fwd": 1, "flash_bwd": 1}
 
 
 def test_grouped_matmul_compiles_at_the_published_widths(topo):
@@ -373,7 +376,7 @@ def test_rows_to_tokens_compiles_at_the_share_cells_shapes(topo, cell,
 
 def test_flash_compiles_at_4_x_8k_with_grouped_kv_heads(topo):
     """LFM2-24B-A2B's attention layer: 32 query heads over 8 KV heads of 64
-    at 4 sequences of 8192, forward and both backward kernels."""
+    at 4 sequences of 8192, the forward and the backward kernel."""
     from ray_tpu.parallel.collectives import kernel_census
     q, k, v = _qkv(topo, (4, 8192, 32, 64))
     k = v = jax.ShapeDtypeStruct((4, 8192, 8, 64), jnp.bfloat16,
@@ -384,14 +387,15 @@ def test_flash_compiles_at_4_x_8k_with_grouped_kv_heads(topo):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, k, v).compile().as_text()
-    assert kernel_census(text) == {
-        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    assert kernel_census(text) == {"flash_fwd": 1, "flash_bwd": 1}
 
 
 def test_flash_compiles_at_32k_with_grouped_kv_heads(topo):
     """granite-4.0-h-micro's attention layer: 32 query heads over 8 KV heads
     of 64 at S = 32768 (2,080 executed tiles a head), the model's own score
-    scale, forward and both backward kernels."""
+    scale, the forward and the backward kernel, the whole of a head's dq
+    resident: the longest and, at 16 MiB of float32 accumulator and as
+    much of its result's two buffers, the largest any cell has."""
     shape = (1, 32768, 32, 64)
     q, k, v = _qkv(topo, shape)
     k = v = jax.ShapeDtypeStruct((1, 32768, 8, 64), jnp.bfloat16,
@@ -403,18 +407,19 @@ def test_flash_compiles_at_32k_with_grouped_kv_heads(topo):
 
     grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, k, v).compile()
-    assert grads.as_text().count("tpu_custom_call") >= 3
+    from ray_tpu.parallel.collectives import kernel_census
+    assert kernel_census(grads.as_text()) == {"flash_fwd": 1, "flash_bwd": 1}
     assert flash_mod.causal_tile_census(32768, 512, 512)["executed"] == 2080
 
 
 @pytest.mark.parametrize("window,names", [
-    (4096, ("flash_fwd_win", "flash_bwd_dq_win", "flash_bwd_dkv_win")),
-    (None, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))])
+    (4096, ("flash_fwd_win", "flash_bwd_win")),
+    (None, ("flash_fwd", "flash_bwd"))])
 def test_flash_compiles_at_16k_with_and_without_a_window(topo, window, names):
     """Trinity-Large-Preview's attention layers: 48 query heads over 8 KV
     heads of 128 at S = 16384, a window layer (4096: 252 executed tiles a
     head, under the windowed kernels' own names) and a full layer (528),
-    forward and both backward kernels."""
+    the forward and the backward kernel."""
     q, k, v = _qkv(topo, (1, 16384, 48, 128))
     k = v = jax.ShapeDtypeStruct((1, 16384, 8, 128), jnp.bfloat16,
                                  sharding=k.sharding)
@@ -425,24 +430,25 @@ def test_flash_compiles_at_16k_with_and_without_a_window(topo, window, names):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, k, v).compile().as_text()
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") >= 2
     for name in names:
         assert re.search(rf"\b{name}\b", text), name
+    assert "flash_bwd_d" not in text
     assert "flash_fwd_win" in text if window else "flash_fwd_win" not in text
     assert flash_mod.window_tile_census(16384, window, 512, 512)[
         "executed"] == (252 if window else 528)
 
 
 @pytest.mark.parametrize("window,names", [
-    (512, ("flash_fwd_win", "flash_bwd_dq_win", "flash_bwd_dkv_win")),
-    (None, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))])
+    (512, ("flash_fwd_win", "flash_bwd_win")),
+    (None, ("flash_fwd", "flash_bwd"))])
 def test_flash_compiles_at_16k_with_heads_of_64_and_values_of_128(
         topo, window, names):
     """Phi-4-mini-flash-reasoning's differential attention: 40 query heads
     of 64 against K of 64 and ``V_g`` of 128 laid out to the query heads,
     at S = 16384: a window no wider than a tile (512: 63 executed tiles a
-    head, every one cut) and causal (528), forward and both backward
-    kernels; the window's outputs are not worth keeping, Trinity's are."""
+    head, every one cut) and causal (528), the forward and the backward
+    kernel; the window's outputs are not worth keeping, Trinity's are."""
     from ray_tpu.parallel.collectives import kernel_census
     q, _, _ = _qkv(topo, (1, 16384, 40, 64))
     v = jax.ShapeDtypeStruct((1, 16384, 40, 128), jnp.bfloat16,
@@ -460,6 +466,50 @@ def test_flash_compiles_at_16k_with_heads_of_64_and_values_of_128(
     assert flash_mod.worth_keeping(16384, 128, window) == (window is None)
     assert flash_mod.worth_keeping(16384, 128, 4096) \
         and flash_mod.worth_keeping(16384, 128)
+
+
+@pytest.mark.parametrize("cell", CELL_ATTENTION)
+def test_every_cells_backward_is_one_kernel(cell):
+    """The rule alone, no chip described: a head's dq fits beside the tiles
+    at every cell's (S, D, Dv) under tiles of 512 x 512."""
+    (_, S, _, D), _, Dv, _ = CELL_ATTENTION[cell]
+    assert flash_mod.one_backward_kernel(S, D, Dv, 512, 512)
+
+
+@pytest.mark.parametrize("cell", CELL_ATTENTION)
+def test_flash_backward_is_one_kernel_at_every_cells_shape(topo, cell):
+    """The backward alone (on abstract ``out`` and ``lse``) at tiles of 512
+    x 512: the call is the one Mosaic kernel under its name and states the
+    limit the rule counts against, 64 MiB."""
+    from ray_tpu.parallel.collectives import kernel_census
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q, k, v, window = _cell_attention(cell, one_chip)
+    (B, S, H, _), Dv = q.shape, v.shape[-1]
+    out = jax.ShapeDtypeStruct((B, S, H, Dv), jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32, sharding=one_chip)
+    lowered = jax.jit(lambda q, k, v, out, lse, g: flash_mod._flash_backward(
+        q, k, v, out, lse, g, True, 512, 512, None, window)).lower(
+            q, k, v, out, lse, out)
+    assert kernel_census(lowered.compile().as_text()) == {
+        "flash_bwd_win" if window else "flash_bwd": 1}
+    assert f"\\22size\\22: {64 << 20}" in lowered.as_text()
+
+
+def test_a_backward_past_the_limit_compiles_as_the_pair(topo):
+    """S = 131072 at one head of 128 (no cell's): dq's accumulator alone is
+    64 MiB, the rule says no, and the call compiles as ``flash_bwd_dq`` and
+    ``flash_bwd_dkv`` inside the default scoped VMEM, stating no limit."""
+    from ray_tpu.parallel.collectives import kernel_census
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 131072, 1, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((1, 1, 131072), jnp.float32, sharding=one_chip)
+    assert not flash_mod.one_backward_kernel(131072, 128, 128, 512, 512)
+    lowered = jax.jit(lambda q, lse: flash_mod._flash_backward(
+        q, q, q, q, lse, q, True, 512, 512)).lower(q, lse)
+    assert kernel_census(lowered.compile().as_text()) == {
+        "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    assert "scoped_memory_configs" not in lowered.as_text()
 
 
 @pytest.mark.parametrize("shape,axis", [((8, 8, 1024, 256), 2),

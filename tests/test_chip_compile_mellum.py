@@ -17,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from chip_compile import (compile_for_tpu,  # noqa: F401
-                          flash_mod, topo)
+                          flash_mod, the_pair_for_each_backward, topo)
 from ray_tpu.ops import moe
 from ray_tpu.parallel import MeshConfig, build_mesh
 from ray_tpu.parallel.collectives import kernel_census
@@ -51,7 +51,7 @@ def benchmark_path():
 @pytest.mark.parametrize("window", [WINDOW, None], ids=["window", "full"])
 def test_flash_kernels_compile_at_the_cells_shape(shaped, window, tile):
     """Eight query heads a KV head, a window of two tiles of 512 (or four
-    of 256): forward and both backward kernels, under the window's names
+    of 256): the forward and the backward kernel, under the window's names
     where there is one."""
     def attended(q, k, v):
         return flash_mod.flash_attention(
@@ -63,8 +63,7 @@ def test_flash_kernels_compile_at_the_cells_shape(shaped, window, tile):
         shaped(jnp.bfloat16, B, S, H, D), kv, kv).compile().as_text()
     suffix = "_win" if window else ""
     assert kernel_census(text) == {
-        "flash_fwd" + suffix: 1, "flash_bwd_dq" + suffix: 1,
-        "flash_bwd_dkv" + suffix: 1}
+        "flash_fwd" + suffix: 1, "flash_bwd" + suffix: 1}
 
 
 @pytest.mark.parametrize("k,n", [(WIDTH, EXPERT), (EXPERT, WIDTH)],
@@ -116,16 +115,15 @@ def test_the_cells_step_calls_the_flash_kernels_by_what_remat_keeps(cell):
     """Three window layers and one full, the window's kernels under their
     own names: the full layer's forward kernel once (a query's 16384 keys
     over heads of 128 are ``worth_keeping``, so its outputs survive remat),
-    a window layer's twice (1024 keys are not), each backward kernel once a
+    a window layer's twice (1024 keys are not), the backward kernel once a
     layer; every expert held, so the whole layer's path and no
     ``moe_rows_to_tokens``; the grouped products, which ``megablox`` names
     ``gmm`` and ``tgmm``: nine and three a layer."""
     census = kernel_census(cell[2], a_step=True)
     flash = {name: calls for name, calls in census.items()
              if str(name).startswith("flash")}
-    assert flash == {"flash_fwd_win": 6, "flash_bwd_dq_win": 3,
-                     "flash_bwd_dkv_win": 3, "flash_fwd": 1,
-                     "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    assert flash == {"flash_fwd_win": 6, "flash_bwd_win": 3,
+                     "flash_fwd": 1, "flash_bwd": 1}
     assert "moe_rows_to_tokens" not in census
     assert sum(census.values()) - sum(flash.values()) == 4 * 12
 
@@ -142,7 +140,7 @@ def test_the_benchmarks_count_of_calls_is_the_steps(cell, benchmark_path):
     census = kernel_census(jaxpr, a_step=True)
     assert {name: one["calls"] for name, one in calls.items()
             if name.startswith("flash")} == {
-        name: n for name, n in census.items()
+        name: n for name, n in the_pair_for_each_backward(census).items()
         if str(name).startswith("flash")}
     assert calls["gmm"]["calls"] + calls["tgmm"]["calls"] == sum(
         n for name, n in census.items()

@@ -155,7 +155,7 @@ def test_the_tiny_stack_is_the_published_pattern():
             if name.startswith(("dsa_", "flash_"))} == {
         "dsa_fwd": 2, "dsa_probs": 2, "dsa_bwd_dq": 2, "dsa_bwd_dkv": 2,
         "dsa_index_fwd": 2, "dsa_index_bwd": 2,
-        "flash_fwd_win": 1, "flash_bwd_dq_win": 1, "flash_bwd_dkv_win": 1}
+        "flash_fwd_win": 1, "flash_bwd_win": 1}
 
 
 def test_the_two_geometries_lie_side_by_side():

@@ -1,7 +1,9 @@
 """The flash kernels under a window (``ops/flash_attention.py``: the pair
 table's trailing edge, the cut tiles' mask, the kernels' own names) against
 ``lm.dot_attention`` with the same mask; the window's tile census against a
-count made pair by pair; and the refusals of the paths that have no window.
+count made pair by pair; the refusals of the paths that have no window; and
+the single backward kernel (``flash_bwd``, ``flash_bwd_win``) against the
+pair it replaces, to the bit, with the rule that chooses between them.
 
 The kernels run interpreted on the CPU in float32, where both sides compute
 the same sums in another order.
@@ -125,8 +127,83 @@ def test_a_window_that_cuts_has_kernel_names_of_its_own():
                 * g).sum()
 
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
-    for name in ("flash_fwd_win", "flash_bwd_dq_win", "flash_bwd_dkv_win"):
-        assert name in text
+    assert "flash_fwd_win" in text and "flash_bwd_win" in text
+    assert "flash_bwd_d" not in text
+
+
+def _backward(q, k, v, g, causal, window, blk, single):
+    """(dq, dk, dv) of ``_flash_backward`` on the forward's own ``out`` and
+    ``lse``: by the single kernel, or (``single`` false: the rule overruled)
+    by ``_flash_bwd_dq_kernel`` and ``_flash_bwd_dkv_kernel``."""
+    out, lse = flash_mod._flash_forward(q, k, v, causal, blk, blk, None,
+                                        window)
+    rule = flash_mod.one_backward_kernel
+    flash_mod.one_backward_kernel = lambda *shape: single and rule(*shape)
+    try:
+        return flash_mod._flash_backward(q, k, v, out, lse, g, causal, blk,
+                                         blk, None, window)
+    finally:
+        flash_mod.one_backward_kernel = rule
+
+
+HEAD_SIZES = [(64, 64), (64, 128), (192, 128), (256, 256)]
+#: (causal, window) at tiles of 128: every pair, the causal table, a window
+#: that cuts (inside the one tile already; tiles behind it leave the table
+#: from 3 x 3 on) and a window the sequence does not reach.
+MASKS = {"all_pairs": (False, None), "causal": (True, None),
+         "window_cuts": (True, 100), "window_out_of_reach": (True, 4096)}
+#: Query heads over KV heads by the table's tiles a side: 32 over 8 on the
+#: one-tile table (an interpreted grid step a head and pair is what a case
+#: costs), 8 over 2 at 3 x 3, a KV head a query head at 2 x 2 and 4 x 4.
+HEADS = {1: (32, 8), 2: (2, 2), 3: (8, 2), 4: (2, 2)}
+#: Head sizes x tables of 1 x 1 to 4 x 4 tiles, the masks going round so
+#: that each meets every head size and every table once; bfloat16 on the
+#: odd tables, float32 on the even.
+BACKWARD_CASES = [
+    pytest.param(d, dv, tiles, list(MASKS)[(i + tiles) % 4], HEADS[tiles],
+                 jnp.bfloat16 if tiles % 2 else jnp.float32,
+                 id=f"{d}_{dv}-{tiles}x{tiles}-{list(MASKS)[(i + tiles) % 4]}")
+    for i, (d, dv) in enumerate(HEAD_SIZES) for tiles in (1, 2, 3, 4)]
+
+
+@pytest.mark.parametrize("d,dv,tiles,mask,heads,dtype", BACKWARD_CASES)
+def test_one_backward_kernel_is_the_pair_to_the_bit(d, dv, tiles, mask,
+                                                    heads, dtype):
+    """``flash_bwd``'s dq, dk and dv against ``flash_bwd_dq``'s and
+    ``flash_bwd_dkv``'s on the same operands: for a fixed Q tile the KV
+    tiles arrive ascending in both walks, every product and sum is float32
+    in both, so nothing may differ. The short tables are where a resident
+    accumulator's revisit of a Q tile goes wrong."""
+    causal, window = MASKS[mask]
+    (h, kvh), S, blk = heads, 128 * tiles, 128
+    ks = jax.random.split(jax.random.PRNGKey(tiles), 4)
+    q, k, v, g = (jax.random.normal(key, shape, dtype) for key, shape in zip(
+        ks, [(1, S, h, d), (1, S, kvh, d), (1, S, kvh, dv), (1, S, h, dv)]))
+    one = _backward(q, k, v, g, causal, window, blk, True)
+    pair = _backward(q, k, v, g, causal, window, blk, False)
+    for name, a, b in zip(("dq", "dk", "dv"), one, pair):
+        assert a.dtype == b.dtype == dtype and a.shape == b.shape
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all() and np.abs(a).max() > 0
+        assert (a.view(np.uint32) == b.view(np.uint32)).all(), name
+
+
+def test_a_backward_whose_dq_does_not_fit_is_the_pair():
+    """S = 131072 at heads of 128: dq's float32 accumulator alone is the
+    limit's 64 MiB, so the call lowers to the two kernels that keep nothing
+    of length S resident, under their names."""
+    assert not flash_mod.one_backward_kernel(131072, 128, 128, 512, 512)
+    assert not flash_mod.one_backward_kernel(65536, 128, 128, 512, 512)
+    assert flash_mod.one_backward_kernel(32768, 128, 128, 512, 512)
+    q = jax.ShapeDtypeStruct((1, 131072, 1, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, 512, 512).astype(
+            jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
+    assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+    assert "name=flash_bwd " not in text and "flash_bwd_win" not in text
 
 
 @pytest.mark.parametrize("S,window,blk_q,blk_k", [

@@ -125,7 +125,7 @@ GRANITE_MOE = family_cases.Family(
     refuses=(ValueError, NotImplementedError), scan_atol=1e-4,
     flash_kernels=("ssd_fwd", "ssd_bwd", "conv_silu_fwd", "conv_silu_bwd",
                    "gated_norm_fwd", "gated_norm_bwd", "flash_fwd",
-                   "flash_bwd_dq", "flash_bwd_dkv", "gmm", "tgmm",
+                   "flash_bwd/", "gmm", "tgmm",
                    "moe_rows_to_tokens"))
 globals().update(family_cases.cases(GRANITE_MOE))
 

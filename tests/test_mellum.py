@@ -116,8 +116,7 @@ MELLUM = family_cases.Family(
                "full_attention": {"rope_type": "llama3",
                                   "rope_theta": 1e4}}}),
     refuses=(ValueError, NotImplementedError),
-    flash_kernels=("flash_fwd_win", "flash_bwd_dq_win", "flash_bwd_dkv_win",
-                   "flash_fwd"))
+    flash_kernels=("flash_fwd_win", "flash_bwd_win", "flash_fwd"))
 globals().update(family_cases.cases(MELLUM))
 
 

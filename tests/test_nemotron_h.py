@@ -123,7 +123,7 @@ NEMOTRON = family_cases.Family(
     refuses=(ValueError, NotImplementedError),
     flash_kernels=("ssd_fwd", "ssd_bwd", "conv_silu_fwd", "conv_silu_bwd",
                    "gated_norm_fwd", "gated_norm_bwd", "flash_fwd",
-                   "flash_bwd_dq", "flash_bwd_dkv", "gmm", "tgmm"))
+                   "flash_bwd/", "gmm", "tgmm"))
 globals().update(family_cases.cases(NEMOTRON))
 
 

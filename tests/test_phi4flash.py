@@ -69,8 +69,7 @@ PHI = family_cases.Family(
     accum_steps=(1,), bfloat16=replace(FLASH, dtype=jnp.bfloat16),
     flash_kernels=("selective_scan_fwd", "selective_scan_bwd",
                    "conv_silu_fwd", "conv_silu_bwd", "flash_fwd_win",
-                   "flash_bwd_dq_win", "flash_bwd_dkv_win", "flash_fwd",
-                   "flash_bwd_dq", "flash_bwd_dkv"),
+                   "flash_bwd_win", "flash_fwd", "flash_bwd/"),
     wrong=(dict(layers_run=(0, 1, 2)), dict(layers_run=(1, 2)),
            dict(layers_run=(2, 3, 0, 1)), dict(layers_run=(0, 1, 6, 7)),
            dict(layers_run=(0, 1, 8, 9)), dict(num_key_value_heads=4),

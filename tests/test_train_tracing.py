@@ -375,8 +375,9 @@ def test_the_lowered_step_names_its_parts():
     text = step.lower(state, {"tokens": tokens, "targets": tokens}).as_text(
         debug_info=True)
     for scope in ("block/attention", "block/mlp", "head_loss", "optimizer",
-                  "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+                  "flash_fwd", "flash_bwd"):
         assert scope in text, scope
+    assert "flash_bwd_d" not in text
 
 
 # -- set-up: stages, the step's first call, JAX's compile events -----------
